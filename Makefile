@@ -216,7 +216,7 @@ ci-local:
 # `make preflight`, commit the refreshed docs/ci_evidence/ — only then
 # is the round snapshot allowed.
 preflight:
-	@test -z "$$(git status --porcelain -- ':!docs/ci_evidence' ':!TPU_ATTEMPTS.log' ':!bench_artifacts')" \
+	@test -z "$$(git status --porcelain -- ':!docs/ci_evidence')" \
 	  || { echo "preflight: tree is dirty — commit first, then gate"; \
 	       git status --short; exit 1; }
 	$(MAKE) ci-local

@@ -1,0 +1,677 @@
+#!/usr/bin/env python3
+"""Proof that the main path starts and answers on the chip.
+
+One MCP `tools/call` travels gateway -> gRPC/UDS -> sidecar -> scheduler
+-> ContinuousBatcher -> KV manager -> jitted model. This script drives
+that path once on the TPU, through the entry point a user would call
+(`python -m ggrmcp_tpu gateway --tpu --config <file>`), at the published
+width of a model the registry has (mistral-7b, 32 layers, random weights
+from a seed), and checks what comes back by the repo's own means.
+
+    python3 chip_smoke.py                  # on the chip; never the CPU
+    python3 chip_smoke.py --cpu-rehearsal  # control flow only, tiny-llama
+
+Legs, one process holding the chip(s) at a time, each gone before the
+next starts (this parent never imports JAX or the package):
+
+  kernel      flash_attention (compiled Pallas) against attention_xla at
+              the serving shapes; on >= 4 devices also under
+              flash_attention_sharded over tensor=4.
+  serve       mistral-7b int8 synthetic weights, paged KV, one chip:
+              tools/list, greedy generate (twice: same ids), SSE
+              generatestream, a >= 1,024-token prompt, a second prompt
+              sharing >= 512 tokens with it, eight concurrent generates.
+              Chunked admission prefills into a contiguous mini cache,
+              so the long prompt must take the Pallas kernel here too.
+  default_kv  same model, paged_kv off: the contiguous shared cache.
+              Same requests, same kernel assertion; its first greedy
+              token must equal the serve leg's (same weights, same
+              prefill).
+  tp4         (>= 4 devices) mistral-7b bf16 over tensor=4, the serve
+              leg's requests, per-device bytes within 1.3x.
+
+Every leg runs its requests twice. The first pass is the probe batch
+(it may compile); during the second `compile_post_warmup` must not move.
+What is asserted about token ids is what bf16 on the chip can promise:
+the same request twice in a row (same programs, same state) returns the
+same ids. Across paths that compute the same mathematics in different
+programs - cold prefill vs page reuse, paged vs contiguous decode - the
+roundings differ and, with random weights, the largest logit changes on
+rounding; those comparisons are printed, not asserted.
+Any failed assertion, erroring request or missing TPU exits non-zero
+with the reason on the last lines; nothing is caught and continued.
+Times printed are set-up times (load, warm-up, compile), labelled with
+the device; none of them is a statement about serving speed.
+
+The last line of standard output on success is
+{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import signal
+import socket
+import subprocess
+import sys
+import time
+import urllib.request
+from concurrent.futures import ThreadPoolExecutor
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+# Each leg's config and the gateway's full log stay here (git-ignored;
+# the chip tool brings this directory back).
+WORKDIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
+
+# The contract gives 1200 s, compilation included. Stop a little short
+# so that an overrun is this script's own failure, with a reason.
+BUDGET_S = 1150.0
+T0 = time.monotonic()
+
+GENERATE = "ggrmcp_tpu_generateservice_generate"
+STREAM = "ggrmcp_tpu_generateservice_generatestream"
+MODEL_INFO = "ggrmcp_tpu_modelinfoservice_getmodelinfo"
+STATS = "ggrmcp_tpu_modelinfoservice_getservingstats"
+
+# Serving geometry per mode. The chip sizes are what fits 16 GB beside
+# 7.4 GB of int8 weights: KV is 128 KiB/token at mistral-7b, and every
+# admission program holds a mini cache of rows x max_seq next to the
+# shared one, so 8 x 2048 is 2 GiB shared + 2 GiB mini (8 x 4096 is
+# 4 + 4 and does not load).
+CHIP = {
+    "model": "mistral-7b", "slots": 8, "max_seq": 2048, "chunk": 512,
+    "long_prompt": 1100, "shared_prefix": 640, "ready_s": 900.0,
+    "call_s": 600.0,
+}
+REHEARSAL = {
+    "model": "tiny-llama", "slots": 4, "max_seq": 256, "chunk": 64,
+    "long_prompt": 150, "shared_prefix": 80, "ready_s": 300.0,
+    "call_s": 120.0,
+}
+SHORT_NEW = 8  # new tokens of the sequential requests
+CONCURRENT, CONCURRENT_NEW = 8, 32  # the batched burst
+
+
+class SmokeFailure(Exception):
+    """A leg, an assertion or a request failed; the message is the reason."""
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+def check(cond: bool, reason: str) -> None:
+    if not cond:
+        raise SmokeFailure(reason)
+
+
+def remaining() -> float:
+    left = BUDGET_S - (time.monotonic() - T0)
+    check(left > 0, f"out of time: the {BUDGET_S:.0f} s budget is spent")
+    return left
+
+
+def child_env(rehearsal: bool) -> dict:
+    env = dict(os.environ)
+    if rehearsal:
+        env["JAX_PLATFORMS"] = "cpu"
+    elif env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        # The no-argument run never asks for the CPU: with no chip the
+        # platform rule (utils/jaxenv.py) refuses to start.
+        del env["JAX_PLATFORMS"]
+    env["PYTHONPATH"] = HERE + os.pathsep + env.get("PYTHONPATH", "")
+    return env
+
+
+# ---------------------------------------------------------------------------
+# Kernel leg: a child of its own, because it needs JAX
+# ---------------------------------------------------------------------------
+
+
+def kernel_leg_child(rehearsal: bool) -> None:
+    """Runs in the child. flash_attention against attention_xla on the
+    device, at the shapes the default-KV prefill hands the dispatcher.
+
+    Tolerance, bf16 (the chip): 2e-2 absolute and relative. Both paths
+    take bf16 q/k/v and accumulate in float32, but attention_xla rounds
+    the softmax weights to bf16 before the PV matmul and the kernel
+    keeps them in float32, and both round the output to bf16 (half an
+    ulp at |x| <= 1 is 2e-3). The sum over up to 4,096 keys of weight
+    roundings of relative size 2^-9 stays an order below 2e-2; a wrong
+    mask, offset or block skip moves outputs by O(0.1-1). float32 (the
+    interpreted rehearsal): 2e-3, as tests/test_models.py."""
+    from ggrmcp_tpu.utils.jaxenv import init_runtime
+
+    init_runtime("chip_smoke kernel leg")
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ggrmcp_tpu.core.config import MeshConfig
+    from ggrmcp_tpu.ops.attention import (
+        attention_xla,
+        flash_attention,
+        flash_attention_sharded,
+    )
+    from ggrmcp_tpu.parallel import mesh as mesh_mod
+
+    devices = jax.devices()
+    dev = devices[0]
+    if rehearsal:
+        b, sq, sk, h, kvh, d = 2, 128, 256, 8, 4, 32
+        dtype, tol, windows = jnp.float32, 2e-3, (None, 64)
+        q_offset, kv_len = [0, 96], [128, 224]
+    else:
+        check(dev.platform == "tpu", f"kernel leg on {dev.platform}")
+        b, sq, sk, h, kvh, d = 2, 512, 4096, 32, 8, 128
+        dtype, tol = jnp.bfloat16, 2e-2
+        # 4096 is the served value (mistral-7b's window; it compiles
+        # the branch but cannot bind inside a 4096-key cache); 1024
+        # binds for the row whose chunk starts at 3072.
+        windows = (None, 4096, 1024)
+        q_offset, kv_len = [0, 3072], [512, 3584]
+    key = jax.random.PRNGKey(0)
+    q = jax.random.normal(key, (b, sq, h, d), dtype)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (b, sk, kvh, d), dtype)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (b, sk, kvh, d), dtype)
+    qo = jnp.asarray(q_offset, jnp.int32)
+    kl = jnp.asarray(kv_len, jnp.int32)
+
+    def compare(name, out, ref):
+        out = np.asarray(out, np.float32)
+        ref = np.asarray(ref, np.float32)
+        check(out.shape == ref.shape, f"{name}: shape {out.shape}")
+        check(bool(np.isfinite(out).all()), f"{name}: non-finite output")
+        err = float(np.abs(out - ref).max())
+        bad = np.abs(out - ref) > tol + tol * np.abs(ref)
+        say(f"  {name}: max|flash - xla| = {err:.2e} (tolerance {tol:g})")
+        check(not bad.any(), f"{name}: {int(bad.sum())} elements beyond "
+              f"tolerance {tol:g}, max error {err:.3e}")
+
+    for window in windows:
+        ref = jax.jit(
+            lambda q, k, v, qo, kl, w=window: attention_xla(
+                q, k, v, causal=True, q_offset=qo, kv_len=kl, window=w
+            )
+        )(q, k, v, qo, kl)
+        t0 = time.monotonic()
+        out = flash_attention(
+            q, k, v, causal=True, q_offset=qo, kv_len=kl, window=window,
+            interpret=rehearsal,
+        )
+        jax.block_until_ready(out)
+        say(f"  flash_attention window={window}: compiled and ran in "
+            f"{time.monotonic() - t0:.1f} s (set-up, {dev.device_kind})")
+        compare(f"q[{b},{sq},{h},{d}] k[{b},{sk},{kvh},{d}] "
+                f"window={window}", out, ref)
+        if len(devices) >= 4:
+            mesh = mesh_mod.build_mesh(MeshConfig(tensor=4), devices[:4])
+            out = jax.jit(
+                lambda q, k, v, qo, kl, w=window: flash_attention_sharded(
+                    q, k, v, mesh, causal=True, q_offset=qo, kv_len=kl,
+                    window=w, interpret=rehearsal,
+                )
+            )(q, k, v, qo, kl)
+            compare(f"sharded tensor=4 window={window}", out, ref)
+    print("LEG_RESULT " + json.dumps({
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(devices),
+    }), flush=True)
+
+
+def run_kernel_leg(rehearsal: bool) -> dict:
+    say("== leg kernel: flash_attention vs attention_xla on the device")
+    cmd = [sys.executable, os.path.abspath(__file__), "--child-kernel"]
+    if rehearsal:
+        cmd.append("--cpu-rehearsal")
+    proc = subprocess.Popen(
+        cmd, cwd=HERE, env=child_env(rehearsal), stdout=subprocess.PIPE,
+        stderr=subprocess.STDOUT, text=True, start_new_session=True,
+    )
+    try:
+        out, _ = proc.communicate(timeout=remaining())
+    except subprocess.TimeoutExpired:
+        raise SmokeFailure("kernel leg did not finish inside the budget")
+    finally:
+        stop_process(proc)
+    lines = out.splitlines()
+    result = [ln for ln in lines if ln.startswith("LEG_RESULT ")]
+    for ln in lines:
+        if not ln.startswith("LEG_RESULT "):
+            say("  | " + ln)
+    check(proc.returncode == 0 and len(result) == 1,
+          f"kernel leg failed (exit {proc.returncode}); its output is above")
+    info = json.loads(result[0][len("LEG_RESULT "):])
+    say(f"   kernel leg ok: platform={info['platform']} "
+        f"device_kind={info['kind']!r} devices={info['count']}")
+    return info
+
+
+# ---------------------------------------------------------------------------
+# Serving legs: the README's command as the child, driven over HTTP
+# ---------------------------------------------------------------------------
+
+
+def stop_process(proc: subprocess.Popen) -> None:
+    """SIGTERM the child's process group, then SIGKILL what remains."""
+    if proc.poll() is None:
+        os.killpg(proc.pid, signal.SIGTERM)
+        try:
+            proc.wait(timeout=30)
+        except subprocess.TimeoutExpired:
+            pass
+    try:
+        os.killpg(proc.pid, signal.SIGKILL)
+    except ProcessLookupError:
+        pass
+    proc.wait()
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+class Stack:
+    """`python -m ggrmcp_tpu gateway --tpu --config <file>` for one leg."""
+
+    def __init__(self, leg: str, serving: dict, geo: dict, rehearsal: bool):
+        self.leg, self.geo, self.rehearsal = leg, geo, rehearsal
+        self.serving = serving
+        self.port = free_port()
+        os.makedirs(WORKDIR, exist_ok=True)
+        self.log_path = os.path.join(WORKDIR, f"{leg}.log")
+        self.cfg_path = os.path.join(WORKDIR, f"{leg}.json")
+        config = {
+            # A probe request may compile a program (a new chunk-grid
+            # depth: 11-15 s at 7B, a cold first program more), which
+            # the gateway's 30 s defaults leave too little room for.
+            "server": {"request_timeout_s": geo["call_s"]},
+            "grpc": {"call_timeout_s": geo["call_s"]},
+            "serving": serving,
+        }
+        with open(self.cfg_path, "w") as f:
+            json.dump(config, f, indent=1)
+        self.proc: subprocess.Popen | None = None
+
+    def __enter__(self) -> "Stack":
+        cmd = [sys.executable, "-m", "ggrmcp_tpu", "gateway", "--tpu",
+               "--config", self.cfg_path, "--http-port", str(self.port)]
+        say("   $ " + " ".join(cmd[1:]))
+        say("   config: " + json.dumps(self.serving))
+        self._log = open(self.log_path, "wb")
+        t0 = time.monotonic()
+        self.proc = subprocess.Popen(
+            cmd, cwd=HERE, env=child_env(self.rehearsal), stdout=self._log,
+            stderr=subprocess.STDOUT, start_new_session=True,
+        )
+        deadline = t0 + min(self.geo["ready_s"], remaining())
+        while True:
+            if self.proc.poll() is not None:
+                raise SmokeFailure(
+                    f"{self.leg}: the gateway exited with code "
+                    f"{self.proc.returncode} before it served"
+                )
+            try:
+                tools = self.rpc("tools/list", {}, timeout=5)["tools"]
+                if any(t["name"] == GENERATE for t in tools):
+                    break
+            except (OSError, SmokeFailure):
+                pass  # not listening yet, or the sidecar not discovered
+            check(time.monotonic() < deadline,
+                  f"{self.leg}: not ready after {self.geo['ready_s']:.0f} s")
+            time.sleep(1.0)
+        self.ready_s = time.monotonic() - t0
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self.proc is not None:
+            stop_process(self.proc)
+        self._log.close()
+        if exc_type is not None:
+            say(f"---- last lines of {self.log_path}")
+            with open(self.log_path, errors="replace") as f:
+                for ln in f.readlines()[-60:]:
+                    say("  | " + ln.rstrip()[:400])
+
+    # -- HTTP ---------------------------------------------------------------
+
+    def _post(self, body: dict, timeout: float, sse: bool = False) -> bytes:
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{self.port}/",
+            data=json.dumps(body).encode(),
+            headers={
+                "Content-Type": "application/json",
+                "Accept": "text/event-stream" if sse else "application/json",
+            },
+        )
+        with urllib.request.urlopen(
+            req, timeout=min(timeout, remaining())
+        ) as resp:
+            return resp.read()
+
+    def rpc(self, method: str, params: dict, timeout: float) -> dict:
+        reply = json.loads(self._post(
+            {"jsonrpc": "2.0", "method": method, "id": 1, "params": params},
+            timeout,
+        ))
+        check("error" not in reply, f"{self.leg}: {method} -> {reply}")
+        return reply["result"]
+
+    def call(self, tool: str, arguments: dict) -> dict:
+        result = self.rpc(
+            "tools/call", {"name": tool, "arguments": arguments},
+            self.geo["call_s"],
+        )
+        check(not result.get("isError"),
+              f"{self.leg}: {tool} returned isError: {result}")
+        return json.loads(result["content"][0]["text"])
+
+    def generate(self, prompt: str, new: int) -> list[int]:
+        out = self.call(GENERATE, {
+            "prompt": prompt, "maxNewTokens": new,
+            "sampling": {"temperature": 0}, "returnTokens": True,
+        })
+        ids = out.get("tokenIds", [])
+        check(1 <= len(ids) <= new and out["finishReason"] in
+              ("length", "stop"),
+              f"{self.leg}: generate returned {out}")
+        check(all(isinstance(i, int) and i >= 0 for i in ids),
+              f"{self.leg}: token ids {ids}")
+        return ids
+
+    def stream(self, prompt: str, new: int) -> int:
+        """One generatestream over SSE; returns the number of events.
+        Token ids ride only the chunks that carry text, and random
+        weights at a 32,000 vocabulary mostly emit ids the byte
+        tokenizer has no text for, so the proof is the terminal chunk."""
+        raw = self._post({
+            "jsonrpc": "2.0", "method": "tools/call", "id": 1,
+            "params": {"name": STREAM, "arguments": {
+                "prompt": prompt, "maxNewTokens": new,
+                "sampling": {"temperature": 0}, "returnTokens": True,
+            }},
+        }, self.geo["call_s"], sse=True).decode()
+        events = [
+            (blk.split("\n", 1)[0].removeprefix("event: "),
+             json.loads(blk.split("\ndata: ", 1)[1]))
+            for blk in raw.strip().split("\n\n") if "\ndata: " in blk
+        ]
+        check(bool(events) and events[-1][0] == "result",
+              f"{self.leg}: SSE did not end in a result event: {raw[-400:]}")
+        result = events[-1][1].get("result", {})
+        check("error" not in events[-1][1] and not result.get("isError"),
+              f"{self.leg}: stream failed: {events[-1][1]}")
+        last = json.loads(result["content"][-1]["text"])
+        check(last.get("done") is True and last.get("finishReason") in
+              ("length", "stop"), f"{self.leg}: stream ended with {last}")
+        return len(events)
+
+    def stats(self) -> dict:
+        return self.call(STATS, {})
+
+    def memory(self) -> dict:
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{self.port}/debug/memory?reconcile=1",
+            timeout=min(60, remaining()),
+        ) as resp:
+            backends = json.loads(resp.read())["backends"]
+        check(len(backends) == 1, f"{self.leg}: /debug/memory {backends}")
+        return backends[0]
+
+
+def num(d: dict, key: str) -> int:
+    """proto3 JSON: int64 arrives as a string, zero not at all."""
+    return int(d.get(key, 0))
+
+
+def prompt_of(n_tokens: int, salt: str) -> str:
+    """A prompt of exactly n_tokens under the byte tokenizer (one token
+    per byte plus BOS), distinct per salt from the first byte on."""
+    body = (salt + " the quick brown fox jumps over the lazy dog;") * (
+        n_tokens // 8
+    )
+    return body[: n_tokens - 1]
+
+
+def request_pass(stack: Stack, geo: dict) -> dict:
+    """Every request shape of a leg, once. Returns the greedy ids."""
+    short = "chip smoke"
+    ids = {"short": stack.generate(short, SHORT_NEW)}
+    again = stack.generate(short, SHORT_NEW)
+    check(again == ids["short"],
+          f"{stack.leg}: greedy twice differs: {ids['short']} vs {again}")
+    events = stack.stream(short, SHORT_NEW)
+    long_prompt = prompt_of(geo["long_prompt"], "L")
+    ids["long"] = stack.generate(long_prompt, SHORT_NEW)
+    # Shares shared_prefix tokens with the long prompt, then diverges.
+    cut = geo["shared_prefix"] - 1
+    sharing = long_prompt[:cut] + prompt_of(geo["long_prompt"] - cut, "S")
+    ids["sharing"] = stack.generate(sharing, SHORT_NEW)
+    with ThreadPoolExecutor(CONCURRENT) as pool:
+        # map() re-raises a worker's failure when its result is read.
+        ids["concurrent"] = list(pool.map(
+            lambda i: stack.generate(f"c{i}", CONCURRENT_NEW),
+            range(CONCURRENT),
+        ))
+    say(f"   pass: short {len(ids['short'])} tok x2 identical, stream "
+        f"{events} events, long {geo['long_prompt']}-token prompt, sharing "
+        f"prompt ({geo['shared_prefix']} shared), {CONCURRENT} "
+        f"concurrent x {CONCURRENT_NEW} new")
+    return ids
+
+
+def serving_leg(leg: str, serving: dict, geo: dict, rehearsal: bool) -> dict:
+    say(f"== leg {leg}")
+    devices = serving["mesh"]["tensor"]
+    mesh_shape = "single" if devices == 1 else f"tensor={devices}"
+    paged = serving["batching"]["paged_kv"] == "on"
+    with Stack(leg, serving, geo, rehearsal) as stack:
+        info = stack.call(MODEL_INFO, {})
+        platform = info.get("platform", "")
+        kind = info.get("deviceKind", "")
+        say(f"   {leg}: platform={platform} device_kind={kind!r} "
+            f"devices={num(info, 'numDevices')} model={info.get('modelId')}"
+            f" — ready in {stack.ready_s:.0f} s (set-up: load + warm-up "
+            f"compile on {kind} x{num(info, 'numDevices')})")
+        check(platform == ("cpu" if rehearsal else "tpu"),
+              f"{leg}: the stack reports platform {platform!r}")
+        check(bool(kind), f"{leg}: no device_kind reported")
+        check(num(info, "numDevices") == devices,
+              f"{leg}: {num(info, 'numDevices')} devices, wanted {devices}")
+        check(info.get("modelId") == geo["model"], f"{leg}: {info}")
+
+        ready = stack.stats()
+        first = request_pass(stack, geo)  # the probe batch: may compile
+        probe = stack.stats()
+        second = request_pass(stack, geo)
+        stats = stack.stats()
+        mem = stack.memory()
+
+        say(f"   attention: {num(stats, 'attnKernelPrograms')} program(s) "
+            f"traced with the Pallas kernel ("
+            f"{num(probe, 'attnKernelPrograms') - num(ready, 'attnKernelPrograms')}"
+            f" of them by the probe batch's long prompts), "
+            f"{num(stats, 'attnKernelFallbacks')} fell back to XLA")
+        moved = num(stats, "compilePostWarmup") - num(
+            probe, "compilePostWarmup"
+        )
+        say(f"   compiles: {num(stats, 'compileCount')} in all, "
+            f"{num(probe, 'compilePostWarmup')} after warm-up during the "
+            f"probe batch, {moved} after it; compile cache hits="
+            f"{num(stats, 'compileCacheHits')} misses="
+            f"{num(stats, 'compileCacheMisses')}")
+        if paged:
+            # The second pass reuses pages (the short prompt copies the
+            # long prompt's first page for the one BOS token they
+            # share): a different program, so different roundings.
+            say(f"   cold prefill vs page reuse, same greedy ids: short="
+                f"{second['short'] == first['short']} long="
+                f"{second['long'] == first['long']}")
+        else:
+            # No reuse without pages: each of these takes the same
+            # programs in both passes. (The concurrent burst does not:
+            # how it splits into single-row and full-pool admissions
+            # depends on arrival timing.)
+            for key in ("short", "long", "sharing"):
+                check(second[key] == first[key],
+                      f"{leg}: {key} greedy ids changed between passes: "
+                      f"{first[key]} then {second[key]}")
+        for key in ("shedRequests", "replayedRequests", "replayExhausted",
+                    "timedOut", "meshSpecDowngrades",
+                    "attnKernelFallbacks"):
+            check(num(stats, key) == 0, f"{leg}: {key} = {num(stats, key)}")
+        check(stats.get("meshShape") == mesh_shape,
+              f"{leg}: mesh_shape {stats.get('meshShape')!r}, "
+              f"wanted {mesh_shape!r}")
+        if paged:
+            check(num(stats, "pagedPagesReused") > 0,
+                  f"{leg}: paged_pages_reused is 0 after prompts sharing "
+                  f"{geo['shared_prefix']} tokens")
+        if not rehearsal:
+            # Chunked admission prefills into a contiguous mini cache
+            # in every KV mode, so every leg's long prompt takes it.
+            check(num(stats, "attnKernelPrograms") > 0,
+                  f"{leg}: the long prefill did not take the compiled "
+                  f"Pallas kernel (attn_kernel_programs is 0)")
+        if moved:
+            late = [c.get("fnName") for c in mem.get("compiles", [])
+                    if c.get("postWarmup")][-moved:]
+            raise SmokeFailure(
+                f"{leg}: compile_post_warmup rose by {moved} after the "
+                f"probe batch; latest post-warm-up programs: {late}"
+            )
+        # The census is exact only between ticks: a pipelined tick
+        # still draining holds its token arrays for a moment.
+        for _ in range(10):
+            if num(mem, "unattributedBytes") == 0:
+                break
+            time.sleep(0.5)
+            mem = stack.memory()
+        per_device = [int(x) for x in mem.get("deviceBytesInUse", [])]
+        say(f"   memory: ledger {num(mem, 'totalBytes')} B, live "
+            f"{num(mem, 'liveBytes')} B, unattributed "
+            f"{num(mem, 'unattributedBytes')} B; per-device bytes_in_use "
+            f"{per_device}")
+        check(num(mem, "unattributedBytes") == 0,
+              f"{leg}: {num(mem, 'unattributedBytes')} unattributed bytes "
+              f"in {num(mem, 'unattributedArrays')} arrays")
+        if devices > 1 and not rehearsal:
+            check(len(per_device) == devices, f"{leg}: {per_device}")
+            ratio = max(per_device) / max(min(per_device), 1)
+            say(f"   per-device max/min = {ratio:.3f}")
+            check(ratio <= 1.3, f"{leg}: device memory skew {ratio:.2f} "
+                  f"> 1.3: {per_device}")
+    return first
+
+
+# ---------------------------------------------------------------------------
+# Parent
+# ---------------------------------------------------------------------------
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument(
+        "--cpu-rehearsal", action="store_true",
+        help="run the control flow on the CPU at tiny-llama size; "
+        "NOT a chip result",
+    )
+    ap.add_argument(
+        "--legs", default="",
+        help="comma-separated subset of kernel,serve,default_kv,tp4 "
+        "(debugging; the default is every leg the host can hold)",
+    )
+    ap.add_argument(
+        "--model", default="",
+        help="registry key instead of mistral-7b (debugging, e.g. llama-1b)",
+    )
+    ap.add_argument("--child-kernel", action="store_true",
+                    help=argparse.SUPPRESS)
+    args = ap.parse_args()
+    if args.child_kernel:
+        kernel_leg_child(args.cpu_rehearsal)
+        return 0
+
+    rehearsal = args.cpu_rehearsal
+    geo = dict(REHEARSAL if rehearsal else CHIP)
+    if args.model:
+        geo["model"] = args.model
+    if rehearsal:
+        say("CPU REHEARSAL: control flow at tiny-llama size on the CPU. "
+            "This is NOT a chip result.")
+    legs = [x for x in args.legs.split(",") if x]
+
+    device = {"platform": "cpu" if rehearsal else "tpu", "kind": "", "count": 1}
+    if not legs or "kernel" in legs:
+        device = run_kernel_leg(rehearsal)
+    if not legs:
+        legs = ["kernel", "serve", "default_kv"]
+        if device["count"] >= 4 and not rehearsal:
+            legs.append("tp4")
+    else:
+        say(f"NOTE: --legs {','.join(legs)}: a partial run")
+
+    batching = {
+        "max_batch_size": geo["slots"], "kv_cache_max_seq": geo["max_seq"],
+        "prefill_chunk": geo["chunk"],
+    }
+    one_chip = {
+        "model": geo["model"], "quantize": "int8",
+        "synthetic_weights": True,
+        # Every axis fixed, product 1: the first device of the host,
+        # however many it holds (parallel/mesh.py).
+        "mesh": {"tensor": 1},
+    }
+    paged_ids = None
+    if "serve" in legs:
+        paged_ids = serving_leg(
+            "serve",
+            {**one_chip, "batching": {**batching, "paged_kv": "on"}},
+            geo, rehearsal,
+        )
+    if "default_kv" in legs:
+        ids = serving_leg(
+            "default_kv",
+            {**one_chip, "batching": {**batching, "paged_kv": "off"}},
+            geo, rehearsal,
+        )
+        if paged_ids is not None:
+            a, b = paged_ids["short"], ids["short"]
+            agree = next(
+                (i for i, (x, y) in enumerate(zip(a, b)) if x != y),
+                min(len(a), len(b)),
+            )
+            say(f"   short greedy prompt, paged vs contiguous: first "
+                f"{agree} of {len(a)} ids agree")
+            # The first token comes from the same prefill (a fresh mini
+            # cache either way) under the same weights.
+            check(agree >= 1, f"paged and contiguous KV disagree on the "
+                  f"first greedy token: {a} vs {b}")
+    if "tp4" in legs:
+        serving_leg(
+            "tp4",
+            {"model": geo["model"], "mesh": {"tensor": 4},
+             "batching": {**batching, "paged_kv": "on"}},
+            geo, rehearsal,
+        )
+    say(f"all legs passed: {','.join(legs)} "
+        f"({time.monotonic() - T0:.0f} s in all, set-up included)")
+    result = {"ok": True, "device": device}
+    if rehearsal:
+        result["rehearsal"] = True
+    if args.legs:
+        result["partial"] = legs  # not the contract's full run
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    try:
+        sys.exit(main())
+    except SmokeFailure as failure:
+        print(f"CHIP SMOKE FAILED: {failure}", flush=True)
+        sys.exit(1)

@@ -144,7 +144,7 @@ def load_config(args: argparse.Namespace) -> cfgmod.Config:
         cfg.serving.speculative_draft = args.speculative_draft
     if getattr(args, "workers", None):
         cfg.server.workers = args.workers
-    cfg.validate()
+    cfg.validate(colaunch=getattr(args, "tpu", False))
     return cfg
 
 

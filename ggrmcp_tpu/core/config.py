@@ -20,6 +20,8 @@ import os
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
+from ggrmcp_tpu.utils.jaxenv import cpu_requested
+
 
 # ---------------------------------------------------------------------------
 # Server / HTTP
@@ -273,7 +275,6 @@ class MeshConfig:
     sequence: int = 1
     expert: int = 1
     stage: int = 1
-    allow_cpu_fallback: bool = True
 
 
 @dataclass
@@ -284,8 +285,7 @@ class BatchingConfig:
     prefill_chunk: int = 512
     kv_cache_max_seq: int = 4096
     # Decode steps fused into one device call (lax.scan): k× fewer
-    # host↔device round-trips per generated token — the dominant cost
-    # when the TPU is reached over a network link. Streaming chunks and
+    # host↔device round-trips per generated token. Streaming chunks and
     # new-request admission are quantized to this many tokens, and up
     # to k-1 sampled tokens per request are discarded at EOS/max_new,
     # so keep it small; 1 = the classic one-call-per-token loop (best
@@ -300,9 +300,8 @@ class BatchingConfig:
     # to the synchronous loop (same programs, same feedback); emission
     # lags one tick, and each request reserves one extra tick of cache
     # overshoot. "auto" = on when the engine's devices are TPUs (a real
-    # accelerator to overlap with; essential over a remote device
-    # link), off on CPU where host and "device" share the core and the
-    # lagged tick is pure extra compute (measured ~15% loss).
+    # accelerator to overlap with), off on CPU where host and "device"
+    # share the core and the lagged tick is pure extra compute.
     pipeline_ticks: str = "auto"  # auto | on | off
     # Length-tiered KV cache: [[max_seq, slots], ...] ascending by
     # max_seq. Empty = one contiguous pool of max_batch_size ×
@@ -1022,8 +1021,10 @@ class Config:
 
     # -- validation ---------------------------------------------------------
 
-    def validate(self) -> None:
-        """Raise ValueError on nonsense values (config.go:328-357 parity)."""
+    def validate(self, colaunch: bool = False) -> None:
+        """Raise ValueError on nonsense values (config.go:328-357 parity).
+        `colaunch`: this config is for `gateway --tpu`, whose own
+        process holds the host's chips."""
         if not (0 < self.server.port < 65536):
             raise ValueError(f"invalid HTTP port: {self.server.port}")
         if self.server.workers < 1:
@@ -1397,6 +1398,17 @@ class Config:
                              "immediately after drain)")
         if fleet.action_log < 1:
             raise ValueError("fleet.action_log must be >= 1")
+        if fleet.enabled and colaunch and not cpu_requested():
+            # One process per chip: the co-launched sidecar holds every
+            # visible chip, so a fleet child could never get one.
+            raise ValueError(
+                "fleet.enabled cannot combine with --tpu on a TPU "
+                "backend: the co-launched sidecar holds the host's chips "
+                "and a replica child process would fail or hang waiting "
+                "for them. Process replicas are one per host today "
+                "(docs/fleet.md): run one `gateway --tpu` per host, or "
+                "the fleet over CPU replicas with JAX_PLATFORMS=cpu"
+            )
         if self.serving.role not in SERVING_ROLES:
             raise ValueError(
                 f"unknown serving.role {self.serving.role!r}; "

@@ -1035,8 +1035,8 @@ class MCPHandler:
     ) -> dict[str, Any]:
         """POST /debug/profile core: fan the sidecar DebugService
         profiler capture out to every backend and return the
-        per-backend server-side artifact paths — the "minimal capture
-        FIRST" TPU-window preflight as one gateway command
+        per-backend server-side artifact paths — step 0 of the
+        preflight checklist as one gateway command
         (docs/observability.md). ?duration_ms= bounds the window
         (sidecar clamps to [10, 60000]); ?label= names the dump
         (sanitized server-side, never a path)."""

@@ -120,6 +120,11 @@ _SERVING_HELP = {
     "mesh_devices": "devices in the serving mesh",
     "mesh_spec_downgrades":
         "sharding specs downgraded to replication (0 = true TP serving)",
+    "attn_kernel_programs":
+        "traced programs with the Pallas prefill kernel in them",
+    "attn_kernel_fallbacks":
+        "traced programs that wanted the Pallas kernel and took XLA "
+        "(shapes did not shard over the mesh)",
     "tick_phase_admit_ms":
         "cumulative tick time in queue drain + admission prefill (ms)",
     "tick_phase_sync_ms":

@@ -74,9 +74,9 @@ def latest_step(checkpoint_dir: str) -> Optional[int]:
 
 def train(cfg: TrainingConfig) -> "TrainState":  # noqa: F821
     """Run the loop; returns the final (host-fetched) TrainState."""
-    from ggrmcp_tpu.utils.jaxenv import apply_platform_env
+    from ggrmcp_tpu.utils.jaxenv import init_runtime
 
-    apply_platform_env()  # operator's JAX_PLATFORMS is authoritative
+    init_runtime("trainer")
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding

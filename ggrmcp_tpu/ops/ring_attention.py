@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ggrmcp_tpu.utils.jax_compat import pcast, shard_map
-
 from ggrmcp_tpu.ops.attention import NEG_INF, attention_xla
 
 _SEQ_SPEC = P(None, "sequence", None, None)
@@ -55,9 +53,8 @@ def _ring_local(
     l0 = jnp.zeros((b, h, sl, 1), jnp.float32)
     acc0 = jnp.zeros((b, sl, h, d), jnp.float32)
     # Mark the accumulators as varying over the ring axis so the scan
-    # carry types line up (shard_map varying-axis typing; identity on
-    # a jax without pcast — utils/jax_compat.py).
-    m0, l0, acc0 = pcast(
+    # carry types line up (shard_map varying-axis typing).
+    m0, l0, acc0 = jax.lax.pcast(
         (m0, l0, acc0), (axis_name,), to="varying"
     )
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -117,7 +114,7 @@ def ring_attention(
         raise ValueError(
             f"sequence length {q.shape[1]} not divisible by {axis} axis {n}"
         )
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_local, axis_name=axis, n=n, causal=causal, window=window
         ),
@@ -177,7 +174,7 @@ def ulysses_attention(
         raise ValueError(f"head count {q.shape[2]} not divisible by {axis}={n}")
     if q.shape[1] % n != 0:
         raise ValueError(f"sequence {q.shape[1]} not divisible by {axis}={n}")
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ulysses_local, axis_name=axis, causal=causal, window=window
         ),

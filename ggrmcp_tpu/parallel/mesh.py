@@ -68,9 +68,22 @@ def build_mesh(
     cfg: Optional[MeshConfig] = None,
     devices: Optional[Sequence[jax.Device]] = None,
 ) -> Mesh:
-    """Build the named mesh over `devices` (default: all)."""
+    """Build the named mesh over `devices` (default: all). A config
+    whose axes are all fixed and multiply to FEWER devices than the
+    host holds takes the first N and says which — how a four-chip host
+    is asked for one chip (`tensor: 1`). An inferred axis (0) still
+    soaks up every device."""
     cfg = cfg or MeshConfig()
     devs = list(devices) if devices is not None else list(jax.devices())
+    want = math.prod(
+        getattr(cfg, a) for a in AXES
+    )  # 0 when any axis is inferred
+    if 0 < want < len(devs):
+        logger.info(
+            "mesh: config asks for %d of %d visible device(s); using "
+            "ids %s", want, len(devs), [d.id for d in devs[:want]],
+        )
+        devs = devs[:want]
     sizes = resolve_axis_sizes(cfg, len(devs))
     shape = tuple(sizes[a] for a in AXES)
     arr = np.array(devs).reshape(shape)
@@ -82,11 +95,6 @@ def build_mesh(
         devs[0].platform,
     )
     return mesh
-
-
-def single_device_mesh() -> Mesh:
-    """A 1-device mesh with all axes of size 1 (CPU fallback / v5e-1)."""
-    return build_mesh(MeshConfig(tensor=1), [jax.devices()[0]])
 
 
 # ---------------------------------------------------------------------------

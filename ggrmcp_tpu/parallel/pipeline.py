@@ -42,7 +42,6 @@ from ggrmcp_tpu.models import common
 from ggrmcp_tpu.models import llama as llama_mod
 from ggrmcp_tpu.ops import quant
 from ggrmcp_tpu.parallel import mesh as mesh_mod
-from ggrmcp_tpu.utils.jax_compat import shard_map
 
 
 def stage_count(mesh: Mesh) -> int:
@@ -123,7 +122,7 @@ def pipeline_layers(
 
     layer_specs = jax.tree_util.tree_map(lambda _: P("stage"), layers)
     fwd = partial(_pipelined, cfg=cfg, fam=fam, num_stages=S, num_micro=M)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         fwd,
         mesh=mesh,
         axis_names={"stage"},
@@ -307,7 +306,7 @@ def pipeline_forward_cached(
         _pipelined_cached, cfg=cfg, fam=fam, num_stages=S_stages,
         num_micro=M, mb=mb, ring=ring,
     )
-    out, new_k, new_v = shard_map(
+    out, new_k, new_v = jax.shard_map(
         fwd,
         mesh=mesh,
         axis_names={"stage"},

@@ -38,14 +38,8 @@ from ggrmcp_tpu.models.common import count_params
 from ggrmcp_tpu.ops import quant
 from ggrmcp_tpu.ops.sampling import SamplingConfig, sample
 from ggrmcp_tpu.parallel import mesh as mesh_mod
-from ggrmcp_tpu.utils.jaxenv import apply_platform_env
 
 logger = logging.getLogger("ggrmcp.serving.engine")
-
-# Engines are the first jax consumers in every entry path; make the
-# operator's JAX_PLATFORMS env var authoritative before any backend
-# initializes (see utils/jaxenv.py).
-apply_platform_env()
 
 
 def bucket_len(n: int, minimum: int = 32, maximum: int = 1 << 20) -> int:
@@ -1413,4 +1407,5 @@ def _model_info(engine, family: str) -> dict:
         "mesh": {k: v for k, v in sizes.items() if v > 1},
         "num_devices": int(engine.mesh.devices.size),
         "platform": engine.mesh.devices.flat[0].platform,
+        "device_kind": engine.mesh.devices.flat[0].device_kind,
     }

@@ -852,7 +852,9 @@ class ProcessReplicaFactory:
             env=self.env if self.env is not None else dict(os.environ),
             cwd=self.cwd,
             stdout=asyncio.subprocess.PIPE,
-            stderr=asyncio.subprocess.DEVNULL,
+            # stderr is inherited: a replica that dies at start-up
+            # (no device, bad config) must say why where the operator
+            # is looking.
         )
         try:
             line = await asyncio.wait_for(
@@ -1010,7 +1012,9 @@ async def _worker_main() -> None:
     _logging.basicConfig(level=_logging.WARNING, stream=sys.stderr)
     from ggrmcp_tpu.core.config import BatchingConfig, ServingConfig
     from ggrmcp_tpu.serving.sidecar import Sidecar
+    from ggrmcp_tpu.utils.jaxenv import init_runtime
 
+    init_runtime("fleet replica worker")
     env = os.environ
     paged = env.get("GGRMCP_FLEET_WORKER_PAGED", "off")
     serving = ServingConfig(

@@ -84,7 +84,10 @@ async def _supervise_sidecar(
     rng = random.Random(0)
     while True:
         sidecar = state["sidecar"]
-        await sidecar.server.wait_for_termination()
+        # Shielded: cancelling this watcher (clean shutdown) must not
+        # cancel the server's own shutdown future, which the stop()
+        # that follows awaits.
+        await asyncio.shield(sidecar.server.wait_for_termination())
         logger.error(
             "co-launched sidecar on %s terminated unexpectedly; "
             "restarting (max %d attempts)",
@@ -200,5 +203,8 @@ async def _run(
 
 
 def run_gateway_with_sidecar(cfg: Config, extra_targets: list[str] | None = None) -> None:
+    from ggrmcp_tpu.utils.jaxenv import init_runtime
+
     setup_logging(cfg)
+    init_runtime("gateway --tpu")
     asyncio.run(_run(cfg, extra_targets or []))

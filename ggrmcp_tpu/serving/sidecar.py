@@ -977,9 +977,13 @@ class Sidecar:
         # steady-state post-warmup recompiles (fields 101-105,
         # gateway_backend_compile_*). Exported here, not per batcher:
         # jax's hooks are process-global, exactly like the watcher.
+        from ggrmcp_tpu.ops.attention import dispatch_stats
         from ggrmcp_tpu.serving.compile_watcher import watcher
 
         stats.update(watcher.stats())
+        # Which attention implementation the traced programs took —
+        # trace-time and process-wide, like the compile counters.
+        stats.update(dispatch_stats())
         if self.batcher is None and self.embedding is not None:
             # Embed-only sidecar: no batcher stats, but the weights
             # component is still real — exported from the embed
@@ -1027,6 +1031,7 @@ class Sidecar:
             mesh=info["mesh"],
             num_devices=info["num_devices"],
             platform=info["platform"],
+            device_kind=info["device_kind"],
         )
 
     # ------------------------------------------------------------------
@@ -1220,7 +1225,15 @@ class Sidecar:
                 ))
                 host_total += int(info.get("bytes", 0))
         cstats = watcher.stats()
+        # The allocator's own per-chip figure (None on backends that
+        # report no memory stats, e.g. CPU).
+        device_bytes = [
+            int(ms["bytes_in_use"])
+            for ms in (d.memory_stats() for d in engine.mesh.devices.flat)
+            if ms is not None
+        ]
         return serving_pb2.MemoryResponse(
+            device_bytes_in_use=device_bytes,
             components=components,
             total_bytes=total,
             host=host_components,
@@ -1435,8 +1448,10 @@ def _apply_stops(text: str, stops: list[str], finish: str) -> tuple[str, str]:
 
 def run(cfg: Config) -> None:
     from ggrmcp_tpu.gateway.app import setup_logging
+    from ggrmcp_tpu.utils.jaxenv import init_runtime
 
     setup_logging(cfg)
+    init_runtime("sidecar")
 
     async def main():
         sidecar = Sidecar(cfg.serving)

@@ -73,8 +73,7 @@ class TieredBatcher:
             # pool size for THIS tier (0 = off). A tier whose workload
             # can't produce poolable prompts (e.g. a short headline
             # tier under the pool's min length) shouldn't pay the
-            # pool's HBM or its warmup compiles — which are minutes
-            # over a remote-compile TPU link.
+            # pool's HBM or its warmup compiles.
             max_seq, slots = tier[0], tier[1]
             tier_cfg = dataclasses.replace(
                 cfg, max_batch_size=int(slots),

@@ -1,4 +1,4 @@
-"""Version-tolerant asyncio surface (same spirit as jax_compat).
+"""Version-tolerant asyncio surface.
 
 `asyncio.timeout` landed in Python 3.11; the gateway hot paths are
 written against it, but baked images can run 3.10. `async_timeout`

@@ -4,8 +4,10 @@ Times `flash_attention` (compiled Pallas) against `attention_xla`
 across sequence lengths at Llama-1B-like shapes, prints a markdown
 table (docs/perf_attention.md) and a suggested FLASH_MIN_SEQ crossover.
 
-Run on the real TPU:  python scripts/bench_attention.py
-CPU smoke (interpret): JAX_PLATFORMS=cpu python scripts/bench_attention.py --seqs 256
+Run on the TPU:  python scripts/bench_attention.py
+CPU smoke of the script's control flow (the kernel interpreted, so its
+timings mean nothing):
+  JAX_PLATFORMS=cpu python scripts/bench_attention.py --seqs 256 --interpret
 """
 
 from __future__ import annotations
@@ -22,9 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ggrmcp_tpu.ops.attention import attention_xla, flash_attention
-from ggrmcp_tpu.utils.jaxenv import apply_platform_env
-
-apply_platform_env()
+from ggrmcp_tpu.utils.jaxenv import init_runtime
 
 
 def _time(fn, *args, iters: int = 20, warmup: int = 3, **kw) -> float:
@@ -53,8 +53,13 @@ def main() -> None:
     )
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument(
+        "--interpret", action="store_true",
+        help="run the Pallas kernel in the interpreter (CPU smoke only)",
+    )
     args = ap.parse_args()
 
+    init_runtime("bench_attention")
     dev = jax.devices()[0]
     print(f"platform={dev.platform} kind={dev.device_kind}")
     print(
@@ -88,7 +93,8 @@ def main() -> None:
 
         t_xla = _time(xla_jit, q, k_rep, v_rep, causal=True, iters=args.iters)
         t_flash = _time(
-            flash_attention, q, kk, vv, causal=True, iters=args.iters
+            flash_attention, q, kk, vv, causal=True, iters=args.iters,
+            interpret=args.interpret,
         )
         speedup = t_xla / t_flash if t_flash else float("inf")
         if crossover is None and speedup >= 1.0:
@@ -116,15 +122,12 @@ def main() -> None:
     if win_src is not None and win_src[0] >= 512:
         s, q, kk, vv, t_full = win_src
         t_win = _time(flash_attention, q, kk, vv, causal=True,
-                      window=s // 2, iters=args.iters)
+                      window=s // 2, iters=args.iters,
+                      interpret=args.interpret)
         print(
             f"\nwindowed flash @ S={s}, W={s // 2}: full={t_full:.3f}ms "
             f"windowed={t_win:.3f}ms ({t_full / max(t_win, 1e-9):.2f}x)"
         )
-    # Completion marker: the platform= header prints before any
-    # measurement, so artifact validity checks (scripts/tpu_watch.sh
-    # have_attn) need proof the table actually finished.
-    print("\nATTN-BENCH-COMPLETE", flush=True)
 
 
 if __name__ == "__main__":

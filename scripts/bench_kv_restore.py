@@ -8,10 +8,9 @@ it: a prefill forward over page_size tokens. This instrument measures
 both sides per page-count on THIS machine and reports the crossover,
 so the byte budget and page size can be tuned from data instead of
 faith. On CPU the "H2D copy" is a memcpy and prefill is slow, so
-restore wins everywhere; the interesting run is a TPU window
-(JAX_PLATFORMS unset), where the PCIe/ICI copy has real cost and the
-MXU makes recompute cheap — re-run there before trusting the CPU
-numbers (same caveat discipline as scripts/bench_attention.py).
+restore wins everywhere; the run that means something is on the TPU
+(JAX_PLATFORMS unset), where the H2D copy has real cost and the MXU
+makes recompute cheap.
 
 Usage:
   JAX_PLATFORMS=cpu python scripts/bench_kv_restore.py
@@ -25,7 +24,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 from pathlib import Path
@@ -46,6 +44,10 @@ def main() -> int:
 
     import jax
     import numpy as np
+
+    from ggrmcp_tpu.utils.jaxenv import init_runtime
+
+    init_runtime("bench_kv_restore")
 
     from ggrmcp_tpu.core.config import (
         BatchingConfig,
@@ -161,5 +163,4 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     raise SystemExit(main())

@@ -93,8 +93,8 @@ class TestKVQuantNumerics:
 
 class TestSyntheticWeights:
     """serving.synthetic_weights: direct-int8 random init for perf
-    staging of models whose dense init exceeds chip HBM (llama3-8b on
-    v5e-1; tpu_watch stage e)."""
+    staging of models whose dense init exceeds chip HBM (llama3-8b or
+    mistral-7b on one v5e chip; chip_smoke.py serves the latter)."""
 
     def test_requires_int8_and_no_checkpoint(self):
         cfg = cfgmod.default()

@@ -139,16 +139,18 @@ class TestOps:
 
     def test_dispatcher_flash_mesh_route_and_fallback(self):
         """attention(..., use_flash=True, flash_mesh=...) must take the
-        sharded route for shardable shapes and silently fall back to
-        XLA for per-call shapes the mesh can't take (odd batch)."""
+        sharded route for shardable shapes and fall back to XLA —
+        counted, never silently — for per-call shapes the mesh can't
+        take (odd batch)."""
         from functools import partial
 
         from ggrmcp_tpu.core.config import MeshConfig
-        from ggrmcp_tpu.ops.attention import attention
+        from ggrmcp_tpu.ops.attention import attention, dispatch_counts
         from ggrmcp_tpu.parallel import mesh as mesh_mod
 
         mesh = mesh_mod.build_mesh(MeshConfig(data=2, tensor=4))
         key = jax.random.PRNGKey(11)
+        before = dict(dispatch_counts)
 
         def run(b):
             q = jax.random.normal(key, (b, 128, 8, 32))
@@ -159,13 +161,31 @@ class TestOps:
                 causal=True,
             )
             out = jax.jit(
-                partial(attention, use_flash=True, flash_mesh=mesh)
+                partial(
+                    attention, use_flash=True, flash_mesh=mesh,
+                    interpret=True,
+                )
             )(q, k, v)
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                        atol=2e-3, rtol=2e-3)
 
-        run(4)  # shardable → flash_attention_sharded (interpret on CPU)
-        run(3)  # batch 3 % data 2 != 0 → silent XLA fallback
+        run(4)  # shardable → flash_attention_sharded
+        assert dispatch_counts["flash_sharded"] == (
+            before.get("flash_sharded", 0) + 1
+        )
+        run(3)  # batch 3 % data 2 != 0 → XLA fallback, on the record
+        assert dispatch_counts["xla_fallback"] == (
+            before.get("xla_fallback", 0) + 1
+        )
+
+    def test_flash_compiles_unless_interpret_is_asked_for(self):
+        """No interpreter fallback: off-TPU the default (compiled)
+        kernel refuses instead of quietly running interpreted."""
+        q = jnp.zeros((1, 128, 2, 32))
+        with pytest.raises(Exception, match="(?i)interpret|tpu|mosaic"):
+            jax.block_until_ready(flash_attention(q, q, q))
+        out = flash_attention(q, q, q, interpret=True)
+        assert out.shape == q.shape
 
     def test_flash_sharded_rejects_bad_shapes(self):
         from ggrmcp_tpu.core.config import MeshConfig

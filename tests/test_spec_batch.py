@@ -353,9 +353,13 @@ class TestSampledLossless:
         )
 
     async def test_plain_temperature_distribution(self, nano_engine):
+        # 28 waves: under this install's (jax 0.9.0) seeded init EOS is
+        # the modal FIRST token at p = 0.60, so six rows in ten stop
+        # with no pair to count; 14 waves left 87 conditional samples
+        # of the 150 _check asks for.
         pairs = await _second_token_pairs(
             nano_engine, SamplingConfig(temperature=1.0),
-            waves=14, rows=64,
+            waves=28, rows=64,
         )
         self._check(nano_engine, pairs)
 
@@ -366,7 +370,7 @@ class TestSampledLossless:
         k = 3
         pairs = await _second_token_pairs(
             nano_engine, SamplingConfig(temperature=1.0, top_k=k),
-            waves=14, rows=64,
+            waves=28, rows=64,
         )
 
         def topk_mask(probs):
