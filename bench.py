@@ -217,9 +217,8 @@ import tempfile
 import time
 
 # Pure-python percentile helpers (no jax import — safe for the isolated
-# proxy phase): the ceil-based nearest-rank formula shared with
-# ContinuousBatcher.lat_percentiles (pct = the rounded reporting
-# wrapper). The previous hand-rolled `int(n*p)-1` read ~p98 at n=63 and
+# proxy phase): the ceil-based nearest-rank formula (pct = the rounded
+# reporting wrapper). The previous hand-rolled `int(n*p)-1` read ~p98 at n=63 and
 # indexed -1 at n<2.
 from ggrmcp_tpu.utils.stats import nearest_rank, pct
 
@@ -1173,8 +1172,6 @@ async def _run_bench() -> dict:
     ticktime = {
         "ticks": sb.get("ticks", 0),
         "decode_steps_per_tick": tick_steps,
-        "tick_dispatch_ms_avg": avg("tick_dispatch_ms", "ticks"),
-        "tick_collect_ms_avg": avg("tick_collect_ms", "tick_collects"),
         # Tick-phase attribution (serving/flight_recorder.py
         # PhaseTimer): mean ms/tick per phase — admit/sync/
         # dispatch/wait/host partition each collected tick's
@@ -1188,12 +1185,7 @@ async def _run_bench() -> dict:
             for p in PHASE_NAMES
         },
         "admit_rounds": sb.get("admit_rounds", 0),
-        "admit_ms_avg": avg("admit_ms", "admit_rounds"),
-        "admit_ms_max": sb.get("admit_ms_max", 0.0),
-        "queue_ms_p50": sb.get("queue_ms_p50", 0.0),
-        "queue_ms_p99": sb.get("queue_ms_p99", 0.0),
-        "service_ms_p50": sb.get("service_ms_p50", 0.0),
-        "service_ms_p99": sb.get("service_ms_p99", 0.0),
+        "admit_ms_avg": avg("tick_phase_admit_ms", "admit_rounds"),
         "timed_out": sb.get("timed_out", 0),
         # Overload/replay lifecycle counters: nonzero shed means
         # the run was shaped by bounded admission
@@ -1217,8 +1209,7 @@ async def _run_bench() -> dict:
         ticktime["ttft_ms_p50"] = pct(ttfts, 0.5)
         ticktime["ttft_ms_p99"] = pct(ttfts, 0.99)
     if queues:
-        # Record-sourced (same window as ttft), overriding the
-        # stats() snapshot percentiles read above.
+        # Record-sourced (same window as ttft).
         ticktime["queue_ms_p50"] = pct(queues, 0.5)
         ticktime["queue_ms_p99"] = pct(queues, 0.99)
 
@@ -3391,10 +3382,6 @@ async def _disagg_bench() -> dict:
                 "short_p99_ms": round(nearest_rank(short_lat, 0.99), 1),
                 "long_p99_ms": round(nearest_rank(long_lat, 0.99), 1),
                 "ttft_p99_ms_le": ttft_p99(stats0, stats1),
-                "decode_stall_ms_max": max(
-                    (stat(e, "decodeStallMsMax") for e in stats1.values()),
-                    default=0.0,
-                ),
                 "disagg_prefills": sum(
                     c.get("disagg_prefills", 0) for c in routing.values()
                 ),
@@ -3434,10 +3421,6 @@ async def _disagg_bench() -> dict:
         "disagg_split_ttft_p99_ratio": round(
             split["ttft_p99_ms_le"] / best_mixed["ttft_p99_ms_le"], 3
         ) if best_mixed["ttft_p99_ms_le"] else 0.0,
-        "disagg_split_stall_ratio": round(
-            split["decode_stall_ms_max"]
-            / best_mixed["decode_stall_ms_max"], 3
-        ) if best_mixed["decode_stall_ms_max"] else 0.0,
     }
 
 

@@ -863,7 +863,8 @@ class MCPHandler:
         originating batcher's `source` label ("" flat pool,
         "tier-<max_seq>", "spec"). `kind` is "ticks" or "requests";
         framework-free, shared by the aiohttp handler and the fast
-        lane. The ticks body carries a `fields` help table
+        lane. The ticks body also carries the admission rounds and the
+        executor hand-offs around those ticks, and a `fields` help table
         (metrics.tick_field_help — the proto-drift-enforced descriptor
         set) so the record keys are self-describing. `tenant` filters
         request records to one tenant's lifecycle (server-side, like
@@ -890,17 +891,25 @@ class MCPHandler:
                 # protojson omits empty repeated fields AND zero/empty
                 # scalars — a flat-pool record carries no "source" key
                 # at all, hence the .get default in the filter.
-                records = entry.get(kind, [])
-                if source:
-                    records = [
-                        r for r in records
-                        if r.get("source", "") == source
-                    ]
-                backends.append({
+                # The admission and hand-off rings ride with the ticks
+                # (the loop's turn around each tick), filtered alike.
+                kinds = (
+                    ("ticks", "admissions", "handoffs")
+                    if kind == "ticks" else (kind,)
+                )
+                backend = {
                     "target": entry["target"],
                     "enabled": entry.get("enabled", False),
-                    kind: records,
-                })
+                }
+                for k in kinds:
+                    records = entry.get(k, [])
+                    if source:
+                        records = [
+                            r for r in records
+                            if r.get("source", "") == source
+                        ]
+                    backend[k] = records
+                backends.append(backend)
         body: dict[str, Any] = {"backends": backends}
         if trace_id:
             body["traceId"] = trace_id

@@ -63,27 +63,12 @@ _SERVING_HELP = {
     "ticks": "decode ticks dispatched",
     "tick_collects": "decode tick token collects",
     "admit_rounds": "admission rounds run",
-    "tick_dispatch_ms": "cumulative host-side tick launch time (ms)",
-    "tick_collect_ms":
-        "cumulative blocking token-pull time (device wait + transfer, ms)",
-    "admit_ms": "cumulative admission-round wall time (ms)",
-    "admit_ms_max": "worst single admission round (ms)",
-    "queue_ms_p50": "median admission-queue wait, recent requests (ms)",
-    "queue_ms_p99": "p99 admission-queue wait, recent requests (ms)",
-    "service_ms_p50": "median on-device service time, recent requests (ms)",
-    "service_ms_p99": "p99 on-device service time, recent requests (ms)",
     "spec_ticks": "continuous-batcher speculative draft/verify ticks",
     "spec_drafted": "draft tokens proposed by the spec tick",
     "spec_accepted": "draft tokens accepted by the spec tick",
     "interleaved_chunks": "prefill chunks fused into decode ticks",
     "interleaved_admissions":
         "requests admitted via tick-interleaved prefill",
-    "decode_stall_ms_p50":
-        "median gap between a live slot's token emissions",
-    "decode_stall_ms_p99":
-        "p99 gap between a live slot's token emissions",
-    "decode_stall_ms_max":
-        "worst gap between a live slot's token emissions",
     "queued_tokens": "prompt tokens held by queued requests",
     "timed_out": "requests expired in queue past queue_deadline_ms",
     "shed_requests":
@@ -136,6 +121,33 @@ _SERVING_HELP = {
         "cumulative tick time in device wait + transfer (ms)",
     "tick_phase_host_ms":
         "cumulative tick time in emission/finish bookkeeping (ms)",
+    # The tick loop's turn, partitioned (batching._in_executor): the
+    # four contiguous parts of every executor call of the batcher
+    # loop, summed only while it had work. Monotone: rate() them, or
+    # read them as deltas over a window.
+    "loop_exec_wait_ms_sum":
+        "cumulative ms from submitting an executor call to its start "
+        "on the thread (executor queue + the batcher's lock)",
+    "loop_exec_wait_ms_count": "executor calls of the batcher loop",
+    "loop_work_ms_sum":
+        "cumulative ms inside the executor calls (what the tick "
+        "phases divide)",
+    "loop_lag_ms_sum":
+        "cumulative ms from an executor call's end to the loop "
+        "coroutine running again (the event loop serving HTTP/gRPC)",
+    "loop_lag_ms_count": "executor calls of the batcher loop",
+    "loop_host_ms_sum":
+        "cumulative ms of loop-side python between executor calls "
+        "(queue sweep, admission batching, host ops; parked time "
+        "excluded)",
+    "loop_busy_ms_sum":
+        "exec_wait + work + lag + host: the loop's whole turn while "
+        "it had work",
+    "rpc_generate_ms_sum":
+        "cumulative ms unary Generate spent in the sidecar handler, "
+        "over calls that returned a result",
+    "rpc_generate_ms_count":
+        "unary Generate calls that returned a result",
     # Disaggregated prefill/decode serving (serving.role): the
     # sidecar→sidecar KV page-shipping plane. The role itself is a
     # string field and exports info-style beside mesh_shape.
@@ -275,6 +287,12 @@ _SERVING_HIST_HELP = {
     "ttft_ms": "backend time-to-first-token (ms), true histogram",
     "e2e_ms": "backend submit-to-terminal-chunk latency (ms)",
     "queue_ms": "backend admission-queue wait (ms)",
+    "pending_ms":
+        "queue wait, first half: submit to the pop that put the "
+        "request into an admission batch (ms)",
+    "prefill_ms":
+        "queue wait, second half: that pop to slot activation — the "
+        "executor hand-off plus the admission program (ms)",
     "tick_duration_ms": "decode tick dispatch-to-collect latency (ms)",
     "tick_phase_admit_ms": "per-tick admit-phase time (ms)",
     "tick_phase_sync_ms": "per-tick host-state-sync time (ms)",

@@ -45,7 +45,11 @@ from ggrmcp_tpu.ops.sampling import (
 from ggrmcp_tpu.serving.adapter_arena import AdapterExhaustedError
 from ggrmcp_tpu.serving.engine import bucket_len, fit_request
 from ggrmcp_tpu.serving import tensors
-from ggrmcp_tpu.serving.flight_recorder import PHASE_NAMES, FlightRecorder
+from ggrmcp_tpu.serving.flight_recorder import (
+    PHASE_NAMES,
+    FlightRecorder,
+    PhaseTimer,
+)
 from ggrmcp_tpu.serving.pages import PageAllocator, PageExhaustedError
 from ggrmcp_tpu.serving.scheduler import (
     Scheduler,
@@ -53,8 +57,7 @@ from ggrmcp_tpu.serving.scheduler import (
     retry_after_for,
 )
 from ggrmcp_tpu.serving.slo import SloAccount, TenantTable
-from ggrmcp_tpu.utils import failpoints
-from ggrmcp_tpu.utils.stats import pct
+from ggrmcp_tpu.utils import failpoints, tracing
 
 logger = logging.getLogger("ggrmcp.serving.batching")
 
@@ -218,10 +221,14 @@ class _Request:
     # row an in-flight request is decoding under.
     adapter_lease: object = None
     # Latency accounting (perf_counter seconds): submit → activation
-    # is queue time, activation → terminal chunk is service time.
+    # is queue time, split at t_pop — the pop that put the request into
+    # its admission batch (the LAST one, when a prefill budget, a
+    # replay or a preemption requeued it): submit → pop is time spent
+    # pending, pop → activation the executor hand-off plus the
+    # admission program (flight_recorder pending_ms / prefill_ms).
     t_submit: float = 0.0
+    t_pop: float = 0.0
     t_admit: float = 0.0
-    queue_ms: float = 0.0
     # Flight-recorder lifecycle (serving/flight_recorder.py): the
     # gateway trace id this request decodes under (join key into the
     # span and tick rings), the first-token stamp TTFT derives from,
@@ -276,7 +283,7 @@ class ContinuousBatcher:
     # values (strings included: mesh_shape) reports them once instead
     # of summing a constant per tier.
     MAX_STAT_KEYS = (
-        "admit_ms_max", "tp_chips", "mesh_devices", "mesh_shape",
+        "tp_chips", "mesh_devices", "mesh_shape",
         "mesh_spec_downgrades",
         # Engine-level memory-ledger components: every tier reads the
         # same process-wide weight/LoRA arrays — max of identical
@@ -632,24 +639,10 @@ class ContinuousBatcher:
         self.prefix_hits = 0
         self.prefix_misses = 0
 
-        # Per-tick timing breakdown (all cumulative ms / counts; the
-        # bench artifact and /stats derive averages). dispatch = host
-        # time to build+launch a tick (async under JAX — device compute
-        # is NOT included); collect = blocking host pull of a tick's
-        # tokens (device wait + transfer); admit = executor time for a
-        # whole admission round (device prefill + activation).
-        self.timing = {
-            "tick_dispatch_ms": 0.0,
-            "tick_collect_ms": 0.0,
-            "admit_ms": 0.0,
-            "admit_ms_max": 0.0,  # worst single admission round
-            "ticks": 0,
-            "collects": 0,
-            "admit_rounds": 0,
-        }
-        # (queue_ms, service_ms) per completed request — queue = submit
-        # to slot activation, service = activation to terminal chunk.
-        self._lat_records: deque = deque(maxlen=4096)
+        # Tick / collect / admission-round counts. All TIMES come from
+        # one clock per tick, the flight recorder's PhaseTimer
+        # (phase_ms below; an admission round carries its own timer).
+        self.timing = {"ticks": 0, "collects": 0, "admit_rounds": 0}
         # Decode-stall histogram: wall-clock gaps (ms) between
         # consecutive token emissions to a slot while its request is
         # live — the per-slot observable the prefill-interleave mode
@@ -717,6 +710,25 @@ class ContinuousBatcher:
         # tick window shows the admission work that preceded it.
         self.phase_ms = dict.fromkeys(PHASE_NAMES, 0.0)
         self._admit_phase_ms = 0.0
+        # The loop's turn, partitioned with nothing left over
+        # (_in_executor): cumulative ms of the four contiguous parts of
+        # every executor call — exec_wait (submitted → started on the
+        # thread), work (started → ended; what the tick phases
+        # divide), lag (ended → this coroutine running again) and host
+        # (resumed → the next submission: loop-side python) — and the
+        # number of calls. `_loop_resumed` is the open host interval's
+        # start, None while the loop is parked with nothing to do, so
+        # idle time belongs to no part.
+        self.loop_ms = dict.fromkeys(
+            ("exec_wait", "work", "lag", "host"), 0.0
+        )
+        self.loop_calls = 0
+        self._loop_resumed: Optional[float] = None
+        # What the admission round in progress ran (program families in
+        # dispatch order, prompt tokens served from reused KV): filled
+        # by the admission paths, read into its AdmissionRecord.
+        self._adm_families: list[str] = []
+        self._adm_reused = 0
 
         # jitted: one decode tick for the whole slot pool (params ride
         # as an argument — a closed-over weight tree would be lowered
@@ -2123,7 +2135,6 @@ class ContinuousBatcher:
         slot.done = False
         slot.reserved = False
         request.t_admit = time.perf_counter()
-        request.queue_ms = (request.t_admit - request.t_submit) * 1000.0
         if request.parked:
             # Resume completes a preempt cycle (serving/scheduler.py):
             # the parked request is decoding again, its demoted pages
@@ -2528,19 +2539,71 @@ class ContinuousBatcher:
             self._loop_ref = asyncio.get_running_loop()
             self._task = self._loop_ref.create_task(self._loop())
 
-    def _in_executor(self, loop, fn, *args):
+    # What an executor call of the loop is, by the function it runs
+    # (HandoffRecord.kind); anything else is a queued host op.
+    _HANDOFF_KINDS = {
+        "_tick_step": "tick", "_prefill_into_slots": "admit",
+        "_drain_inflight": "drain", "_preempt_slots": "preempt",
+    }
+
+    async def _in_executor(self, loop, fn, *args):
         """Run one of the loop's device-bound calls in the executor.
         Cancelling the loop task abandons the await, not the thread:
         the lock is how stop() waits for a call already running, and
         the _stopping check keeps one that had not started yet from
-        running after stop() returned."""
+        running after stop() returned.
+
+        Four stamps per call partition the loop's turn: submitted
+        (here), started (on the thread, lock held), ended (on the
+        thread), resumed (this coroutine runs again). They feed
+        loop_ms and one HandoffRecord; a cancelled call records
+        nothing."""
+        stamps = [0.0, 0.0]
 
         def call():
             with self._exec_lock:
-                if not self._stopping:
-                    return fn(*args)
+                stamps[0] = stamps[1] = time.perf_counter()
+                try:
+                    if not self._stopping:
+                        return fn(*args)
+                finally:
+                    stamps[1] = time.perf_counter()
 
-        return loop.run_in_executor(None, call)
+        tick_seq = self.timing["ticks"] + 1
+        submitted = time.perf_counter()
+        host_s = (
+            submitted - self._loop_resumed
+            if self._loop_resumed is not None else 0.0
+        )
+        try:
+            return await loop.run_in_executor(None, call)
+        finally:
+            resumed = time.perf_counter()
+            if stamps[0]:  # the call ran (not cancelled in the queue)
+                started, ended = stamps
+                self.loop_ms["host"] += host_s * 1000.0
+                self.loop_ms["exec_wait"] += (started - submitted) * 1000.0
+                self.loop_ms["work"] += (ended - started) * 1000.0
+                self.loop_ms["lag"] += (resumed - ended) * 1000.0
+                self.loop_calls += 1
+                self.recorder.note_handoff(
+                    self.loop_calls,
+                    self._HANDOFF_KINDS.get(
+                        getattr(fn, "__name__", ""), "host_op"
+                    ),
+                    host_s * 1000.0, submitted, started, ended, resumed,
+                    tick_seq,
+                )
+            self._loop_resumed = resumed
+
+    def _loop_park(self) -> None:
+        """The loop is about to wait with nothing to do: close the
+        open host interval, so idle time belongs to no part."""
+        if self._loop_resumed is not None:
+            self.loop_ms["host"] += (
+                time.perf_counter() - self._loop_resumed
+            ) * 1000.0
+            self._loop_resumed = None
 
     async def stop(self) -> None:
         self._stopping = True
@@ -2791,50 +2854,18 @@ class ContinuousBatcher:
             total += self.dcache.k.nbytes + self.dcache.v.nbytes
         return total
 
-    def lat_snapshot(self) -> list[tuple[float, float]]:
-        """Snapshot of recent (queue_ms, service_ms) records (the
-        tiered facade concatenates these across tiers)."""
-        return list(self._lat_records)
-
     def stall_snapshot(self) -> list[float]:
         """Snapshot of recent decode-stall samples (ms between
-        consecutive emissions to a live slot); concatenated across
-        tiers by the tiered facade, like lat_snapshot."""
+        consecutive emissions to a live slot) — the in-process view the
+        interleave tests and bench.py's stall columns read;
+        concatenated across tiers by the tiered facade."""
         return list(self._stall_records)
 
-    @staticmethod
-    def stall_percentiles(records: list[float]) -> dict:
-        """Decode-stall histogram summary — the admission-induced gap
-        distribution prefill_interleave bounds to ~one chunk. pct is
-        the shared ceil-based nearest-rank reporter (utils/stats.py),
-        one formula for batcher, bench, and flight-recorder output."""
-        return {
-            "decode_stall_ms_p50": pct(records, 0.5),
-            "decode_stall_ms_p99": pct(records, 0.99),
-            "decode_stall_ms_max": (
-                round(max(records), 2) if records else 0.0
-            ),
-        }
-
-    @staticmethod
-    def lat_percentiles(records: list[tuple[float, float]]) -> dict:
-        """Queue/service percentiles from (queue_ms, service_ms)
-        records — the queue-time vs device-time split the SLO policy
-        is judged on (pct: shared nearest-rank, utils/stats.py)."""
-        qs = [r[0] for r in records]
-        ss = [r[1] for r in records]
-        return {
-            "queue_ms_p50": pct(qs, 0.5), "queue_ms_p99": pct(qs, 0.99),
-            "service_ms_p50": pct(ss, 0.5), "service_ms_p99": pct(ss, 0.99),
-        }
-
     def stats(self) -> dict:
-        """Live counters + latency percentiles + flight-recorder
-        histograms for the ServingStats RPC / diagnostics."""
+        """Live counters + flight-recorder histograms for the
+        ServingStats RPC / diagnostics."""
         return {
             **self.counter_stats(),
-            **self.lat_percentiles(self.lat_snapshot()),
-            **self.stall_percentiles(self.stall_snapshot()),
             **self.recorder.histogram_stats(),
             # Structured (repeated-message) SLO/tenant fragments ride
             # OUTSIDE counter_stats: the tiered facade's sum-by-key
@@ -2857,7 +2888,8 @@ class ContinuousBatcher:
         DebugService.GetFlightRecord body (sidecar) and the bench's
         TTFT source. `tenant` narrows the REQUEST records to one
         tenant's (ticks are shared across tenants and stay unfiltered,
-        matching the FlightRecordRequest.tenant contract)."""
+        matching the FlightRecordRequest.tenant contract). The
+        admission and hand-off rings ride beside it: loop_snapshot."""
         ticks = self.recorder.tick_snapshot()
         requests = self.recorder.request_snapshot()
         if trace_id:
@@ -2866,6 +2898,21 @@ class ContinuousBatcher:
         if tenant:
             requests = [r for r in requests if r.tenant == tenant]
         return ticks[-max(1, max_ticks):], requests[-max(1, max_requests):]
+
+    def loop_snapshot(
+        self, max_records: int = 128, trace_id: str = ""
+    ) -> tuple[list, list]:
+        """(admission records, hand-off records), oldest first, each
+        bounded like the ticks. A trace id narrows the admissions to
+        the rounds that admitted it and leaves the hand-offs out (they
+        carry none)."""
+        admissions = self.recorder.admission_snapshot()
+        handoffs = self.recorder.handoff_snapshot()
+        if trace_id:
+            admissions = [a for a in admissions if trace_id in a.trace_ids]
+            handoffs = []
+        n = max(1, max_records)
+        return admissions[-n:], handoffs[-n:]
 
     def request_record(self, trace_id: str):
         """Latest flight-recorder request record for a trace id (the
@@ -3014,16 +3061,11 @@ class ContinuousBatcher:
             "grammar_jump_tokens": self.grammar_jump_tokens,
             "grammar_jump_runs": self.grammar_jump_runs,
             "grammar_jump_fallbacks": self.grammar_jump_fallbacks,
-            # Per-tick timing breakdown (cumulative ms + counts):
-            # dispatch = host-side tick launch, collect = blocking
-            # token pull (device wait + transfer), admit = full
-            # admission rounds including device prefill.
+            # Ticks dispatched, ticks collected, admission rounds: the
+            # divisors of the phase sums below.
             "ticks": t["ticks"],
             "tick_collects": t["collects"],
             "admit_rounds": t["admit_rounds"],
-            "tick_dispatch_ms": round(t["tick_dispatch_ms"], 2),
-            "tick_collect_ms": round(t["tick_collect_ms"], 2),
-            "admit_ms": round(t["admit_ms"], 2),
             # Tick-phase attribution (flight recorder PhaseTimer;
             # cumulative ms over collected ticks, divide by
             # tick_collects for per-tick means): admit = queue drain +
@@ -3037,10 +3079,19 @@ class ContinuousBatcher:
                 f"tick_phase_{p}_ms": round(self.phase_ms[p], 2)
                 for p in PHASE_NAMES
             },
-            # Worst single admission round — what the p50_budget_ms
-            # cap bounds. NOT summable: the tiered facade takes the
-            # max across tiers.
-            "admit_ms_max": round(t["admit_ms_max"], 2),
+            # The loop's turn (_in_executor): the four contiguous
+            # parts of every executor call, summed while the batcher
+            # had work, and busy = their sum by construction. work is
+            # what the tick phases divide; the other three are the
+            # hand-offs between event loop and executor, where the
+            # device can idle and no phase looks.
+            "loop_exec_wait_ms_sum": self.loop_ms["exec_wait"],
+            "loop_exec_wait_ms_count": self.loop_calls,
+            "loop_work_ms_sum": self.loop_ms["work"],
+            "loop_lag_ms_sum": self.loop_ms["lag"],
+            "loop_lag_ms_count": self.loop_calls,
+            "loop_host_ms_sum": self.loop_ms["host"],
+            "loop_busy_ms_sum": sum(self.loop_ms.values()),
         }
 
     # -- the loop -----------------------------------------------------------
@@ -3090,7 +3141,9 @@ class ContinuousBatcher:
                 self._wake.clear()
                 if not self.pending.empty() or self._host_ops:
                     continue
+                self._loop_park()
                 await self._wake.wait()
+                self._loop_resumed = time.perf_counter()
                 continue
             # One batched decode tick (device-bound → executor).
             try:
@@ -3108,6 +3161,15 @@ class ContinuousBatcher:
     def _drain_inflight(self) -> None:
         while self._inflight:
             self._tick_collect_one()
+
+    def _tick_collect_one(self) -> None:
+        """Collect the oldest in-flight tick (a `ggrmcp.tick.collect`
+        span in the profiler's trace while a capture runs)."""
+        rec = self._inflight[0][3]
+        with tracing.annotation(
+            "ggrmcp.tick.collect", seq=rec.seq if rec is not None else 0
+        ):
+            self._collect_tick()
 
     def _record_terminal(self, request: _Request, reason: str) -> None:
         """Flight-record a request's terminal outcome — called on EVERY
@@ -3170,6 +3232,7 @@ class ContinuousBatcher:
             tenant=request.tenant,
             qos_class=request.qos_class,
             slo_violated=outcome == "violated",
+            t_pop=request.t_pop,
         )
 
     def _replay_or_fail(self, request: _Request) -> None:
@@ -3581,9 +3644,15 @@ class ContinuousBatcher:
                         # interleaved chunk work) for stragglers.
                         request = self.pending.get_nowait()
                     else:
-                        request = await asyncio.wait_for(
-                            self.pending.get(), timeout=timeout
-                        )
+                        # Idle pool, nothing batched yet: waiting here
+                        # for a first arrival is parking, not work.
+                        self._loop_park()
+                        try:
+                            request = await asyncio.wait_for(
+                                self.pending.get(), timeout=timeout
+                            )
+                        finally:
+                            self._loop_resumed = time.perf_counter()
                 except (asyncio.TimeoutError, asyncio.QueueEmpty):
                     break
                 if request.cancelled:
@@ -3614,6 +3683,7 @@ class ContinuousBatcher:
                     self.sched.budget_deferrals += 1
                     capped = True
                     break
+                request.t_pop = time.perf_counter()
                 batch.append(request)
                 tok_sum += len(request.prompt)
             if not batch:
@@ -3737,26 +3807,74 @@ class ContinuousBatcher:
     def _prefill_into_slots(
         self, slots_idx: list[int], batch: list[_Request]
     ) -> None:
+        """One admission round (an executor work item): route the
+        batch (_route_admission), and account for it on ONE clock — the
+        round's PhaseTimer gives its duration to the next tick's admit
+        phase, the per-row cost EMA and the round's AdmissionRecord
+        (family that ran, rows, tokens, trace ids, the tick it
+        precedes). While a profile capture runs the round is also a
+        `ggrmcp.admit` span in the profiler's own trace."""
+        timer = PhaseTimer()
+        seq = self.timing["admit_rounds"] + 1
+        tick_seq = self.timing["ticks"] + 1
+        with tracing.annotation("ggrmcp.admit", seq=seq, tick=tick_seq):
+            # Chaos hooks: admission latency (admit_slow, arm with ms=)
+            # and admission failure (admit_fail) — the latter exercises
+            # _admit's blast-radius-scaled batch-failure handling.
+            failpoints.evaluate("admit_slow")
+            failpoints.evaluate("admit_fail")
+            if self.sched is not None:
+                # Resume pre-pass: reacquire released adapter pins (and
+                # filter rows that cannot) BEFORE any block table or
+                # cache row is touched for them.
+                self._resume_reacquire(slots_idx, batch)
+                if not batch:
+                    return
+            self._adm_families, self._adm_reused = [], 0
+            queued, shed_rows = self._route_admission(slots_idx, batch)
+        dt = timer.mark("admit")
+        self.timing["admit_rounds"] = seq
+        # Phase attribution: this round's executor time seeds the NEXT
+        # tick record's admit phase (queue drain + admission prefill
+        # belong to the tick window they precede).
+        self._admit_phase_ms += dt
+        self.recorder.note_admission(
+            timer, "+".join(self._adm_families),
+            [r.trace_id for r in batch if r.trace_id],
+            rows=len(batch),
+            prompt_tokens=sum(len(r.prompt) for r in batch),
+            reused_tokens=self._adm_reused,
+            tick_seq=tick_seq, seq=seq,
+        )
+        # Interleave-queued rows ran no prefill here — feeding their
+        # ~zero cost into the EMA would let the p50_budget_ms cap admit
+        # unbounded short-prompt bursts on the strength of cheap
+        # enqueues.
+        prefilled = len(batch) - queued - shed_rows
+        if prefilled:
+            self._admit_ema_ms = (
+                0.7 * self._admit_ema_ms + 0.3 * dt / prefilled
+            )
+
+    def _admission_ran(self, family: str, reused_tokens: int = 0) -> None:
+        """An admission path notes the program family it dispatched
+        (consecutive repeats fold) and the prompt tokens it took from
+        reused KV, for the round's AdmissionRecord."""
+        if not self._adm_families or self._adm_families[-1] != family:
+            self._adm_families.append(family)
+        self._adm_reused += reused_tokens
+
+    def _route_admission(
+        self, slots_idx: list[int], batch: list[_Request]
+    ) -> tuple[int, int]:
         """Route each admission. Short cold prompts fuse into one
         prefill call (_prefill_fused); prefix-pool hits group by
         identical step geometry and long prompts group wholesale, each
         group admitted by ONE fused chunked device call
         (_admit_chunked_group). Only a prefix hit whose suffix needs a
         multi-step bridge plan (rare: pooled prefix + suffix longer
-        than prefill_chunk) falls back to the serial per-row path."""
-        # Chaos hooks: admission latency (admit_slow, arm with ms=) and
-        # admission failure (admit_fail) — the latter exercises
-        # _admit's blast-radius-scaled batch-failure handling.
-        failpoints.evaluate("admit_slow")
-        failpoints.evaluate("admit_fail")
-        if self.sched is not None:
-            # Resume pre-pass: reacquire released adapter pins (and
-            # filter rows that cannot) BEFORE any block table or cache
-            # row is touched for them.
-            self._resume_reacquire(slots_idx, batch)
-            if not batch:
-                return
-        t0 = time.perf_counter()
+        than prefill_chunk) falls back to the serial per-row path.
+        Returns (rows queued for interleaved chunks, rows shed)."""
         fused_slots: list[int] = []
         fused_batch: list[_Request] = []
         pfx_groups: dict[tuple, list[tuple[int, _Request]]] = {}
@@ -3870,6 +3988,7 @@ class ContinuousBatcher:
                     key = (entry, start, steps[0][1])
                     pfx_groups.setdefault(key, []).append((sl, req))
                 else:
+                    self._admission_ran("chunk_steps", start)
                     self._prefill_chunked(sl, req, pfx)
             elif len(req.prompt) > self.cfg.prefill_chunk:
                 if ilv:
@@ -3879,6 +3998,7 @@ class ContinuousBatcher:
                     self.slots[sl].reserved = True
                     self._ilv_pending.append(_IlvRow(req, sl, len(req.prompt)))
                     self.interleaved_admissions += 1
+                    self._admission_ran("interleave_queued")
                     queued += 1
                 else:
                     long_rows.append((sl, req))
@@ -3919,23 +4039,7 @@ class ContinuousBatcher:
                     self._pfx_pool, self.cache, jnp.int32(slot),
                     jnp.int32(entry), jnp.int32(len(key)),
                 ))
-        dt = (time.perf_counter() - t0) * 1000.0
-        self.timing["admit_ms"] += dt
-        self.timing["admit_ms_max"] = max(self.timing["admit_ms_max"], dt)
-        self.timing["admit_rounds"] += 1
-        # Phase attribution: this round's executor time seeds the NEXT
-        # tick record's admit phase (queue drain + admission prefill
-        # belong to the tick window they precede).
-        self._admit_phase_ms += dt
-        # Interleave-queued rows ran no prefill here — feeding their
-        # ~zero cost into the EMA would let the p50_budget_ms cap admit
-        # unbounded short-prompt bursts on the strength of cheap
-        # enqueues.
-        prefilled = len(batch) - queued - shed_rows
-        if prefilled:
-            self._admit_ema_ms = (
-                0.7 * self._admit_ema_ms + 0.3 * dt / prefilled
-            )
+        return queued, shed_rows
 
     def _admit_chunked_group(
         self,
@@ -3989,6 +4093,9 @@ class ContinuousBatcher:
             g0s[j] = self._g0(req)
         if pfx is not None:
             self.prefix_hits += len(rows)
+            self._admission_ran("chunked_pfx", start * len(rows))
+        else:
+            self._admission_ran("chunked")
         g_allow, g_trans = self._grammar_tables()
         self._sync_tables()
         self._cache_at_risk = True
@@ -4055,6 +4162,7 @@ class ContinuousBatcher:
             ps[j] = req.sampling.top_p
             adapters[j] = req.adapter
             g0s[j] = self._g0(req)
+        self._admission_ran("paged_pfx", scan_start * len(rows))
         g_allow, g_trans = self._grammar_tables()
         self._sync_tables()
         self._cache_at_risk = True
@@ -4112,6 +4220,7 @@ class ContinuousBatcher:
             valid[row] = True
             adapters[row] = req.adapter
             g0s[row] = self._g0(req)
+        self._admission_ran("single" if single else "full")
         g_allow, g_trans = self._grammar_tables()
         self._sync_tables()
         self._cache_at_risk = True
@@ -4154,22 +4263,26 @@ class ContinuousBatcher:
         # a real device failure at tick dispatch — _loop's handler
         # replays the victims (utils/failpoints.py).
         failpoints.evaluate("tick_fail")
-        if self._spec:
-            if self._ilv_busy():
-                self._tick_spec_dispatch(chunk=True)
+        # While a profile capture runs, dispatch and collect are spans
+        # in the profiler's own trace, tagged with the tick's seq (the
+        # key into the tick ring); otherwise one attribute check each.
+        with tracing.annotation(
+            "ggrmcp.tick.dispatch", seq=self.timing["ticks"] + 1
+        ):
+            if self._spec:
+                self._tick_spec_dispatch(chunk=self._ilv_busy())
+            elif self._jump_max and bool(self.jump_ok.any()):
+                # Jump-ahead tick only while some live slot can
+                # actually jump (a constrained, non-degraded request):
+                # unconstrained workloads keep the plain tick's
+                # steps_per_tick scan and pay ZERO jump overhead. Both
+                # program families are warmed, so alternating
+                # dispatchers never recompiles.
+                self._tick_dispatch_jump(chunk=self._ilv_busy())
+            elif self._ilv_busy():
+                self._tick_dispatch_chunk()
             else:
-                self._tick_spec_dispatch()
-        elif self._jump_max and bool(self.jump_ok.any()):
-            # Jump-ahead tick only while some live slot can actually
-            # jump (a constrained, non-degraded request): unconstrained
-            # workloads keep the plain tick's steps_per_tick scan and
-            # pay ZERO jump overhead. Both program families are warmed,
-            # so alternating dispatchers never recompiles.
-            self._tick_dispatch_jump(chunk=self._ilv_busy())
-        elif self._ilv_busy():
-            self._tick_dispatch_chunk()
-        else:
-            self._tick_dispatch()
+                self._tick_dispatch()
         depth = 1 if self._pipeline else 0
         while len(self._inflight) > depth:
             self._tick_collect_one()
@@ -4203,7 +4316,6 @@ class ContinuousBatcher:
         )
 
     def _tick_dispatch(self) -> None:
-        t0 = time.perf_counter()
         step0 = self.step_counter
         self.step_counter += self._steps_per_tick
         active = np.array([s.active for s in self.slots], bool)
@@ -4242,7 +4354,6 @@ class ContinuousBatcher:
         # N+1's junk row for the old request is collected.
         owners = [s.request if s.active else None for s in self.slots]
         self._inflight.append((toks, None, owners, rec, "plain"))
-        self.timing["tick_dispatch_ms"] += (time.perf_counter() - t0) * 1000.0
         self.timing["ticks"] += 1
         if rec is not None:
             rec.phases.mark("dispatch")
@@ -4256,7 +4367,6 @@ class ContinuousBatcher:
         device-resident, so spec ticks pipeline exactly like plain
         ones; the host pulls (emit, count) at collect and advances each
         slot by its accepted count."""
-        t0 = time.perf_counter()
         step0 = self.step_counter
         # gamma+1 target positions per round — decode_steps counts
         # positions processed, and the per-round RNG tag (step0+1)
@@ -4326,7 +4436,6 @@ class ContinuousBatcher:
             pass
         owners = [s.request if s.active else None for s in self.slots]
         self._inflight.append((toks, counts, owners, rec, "spec"))
-        self.timing["tick_dispatch_ms"] += (time.perf_counter() - t0) * 1000.0
         self.timing["ticks"] += 1
         self.spec_ticks += 1
         if chunk:
@@ -4385,7 +4494,6 @@ class ContinuousBatcher:
         final chunk this was finish right after (merge + first-token
         sample + activation — one small device call each, once per
         admission)."""
-        t0 = time.perf_counter()
         step0 = self.step_counter
         self.step_counter += self._steps_per_tick
         active = np.array([s.active for s in self.slots], bool)
@@ -4424,7 +4532,6 @@ class ContinuousBatcher:
             pass
         owners = [s.request if s.active else None for s in self.slots]
         self._inflight.append((toks, None, owners, rec, "plain"))
-        self.timing["tick_dispatch_ms"] += (time.perf_counter() - t0) * 1000.0
         self.timing["ticks"] += 1
         self._ilv_advance(sel)
         if rec is not None:
@@ -4441,7 +4548,6 @@ class ContinuousBatcher:
         the host pulls (emit, count) at collect, validates each run
         against its own arena walk, and advances each slot by its run
         length + 1."""
-        t0 = time.perf_counter()
         step0 = self.step_counter
         # 1 + jump_max positions processed per row; the sample's RNG
         # tag (step0 + 1) stays unique across ticks.
@@ -4505,7 +4611,6 @@ class ContinuousBatcher:
             pass
         owners = [s.request if s.active else None for s in self.slots]
         self._inflight.append((toks, counts, owners, rec, "jump"))
-        self.timing["tick_dispatch_ms"] += (time.perf_counter() - t0) * 1000.0
         self.timing["ticks"] += 1
         if chunk:
             self._ilv_advance(sel)
@@ -4537,12 +4642,11 @@ class ContinuousBatcher:
         if self._spec:
             self._spec_admit_rows([(st.slot, req)])
 
-    def _tick_collect_one(self) -> None:
+    def _collect_tick(self) -> None:
         """Pull the oldest in-flight tick's tokens to the host and emit
         them. Rows whose owner no longer holds the slot (finished — and
         possibly re-admitted — since dispatch) are dropped: their
         tokens are the junk a parked slot keeps sampling."""
-        t0 = time.perf_counter()
         toks_dev, counts_dev, owners, rec, kind = self._inflight.popleft()
         toks = np.asarray(toks_dev)  # [B, steps_per_tick | gamma+1 | J+1]
         # counts is the spec tick's per-row accepted+1 (or the jump
@@ -4554,7 +4658,6 @@ class ContinuousBatcher:
             # device compute + transfer, plus the deliberate one-tick
             # lag (and the next tick's host work) under pipelining.
             rec.phases.mark("wait")
-        self.timing["tick_collect_ms"] += (time.perf_counter() - t0) * 1000.0
         self.timing["collects"] += 1
         finished = 0
         drafted = accepted = 0
@@ -4719,10 +4822,6 @@ class ContinuousBatcher:
             # must not count the slot as still active.
             slot.active = False
             slot.request = None
-            self._lat_records.append((
-                request.queue_ms,
-                (time.perf_counter() - request.t_admit) * 1000.0,
-            ))
             # Freeze the row so it stops influencing shared state
             # (cache row stays, masked by length on reuse). The host
             # grammar-state mirror resets too; the device twin keeps

@@ -1,20 +1,22 @@
-"""Engine flight recorder: bounded rings of per-tick and per-request
-lifecycle records plus fixed-bucket latency histograms.
+"""Engine flight recorder: bounded rings of per-tick, per-admission,
+per-hand-off and per-request records plus fixed-bucket latency
+histograms.
 
 The postmortem layer (ISSUE 3 / SURVEY.md §5.1): the batcher's existing
 counters say HOW MUCH happened; this module records WHAT happened —
 what the batcher did at tick N (composition, duration, lifecycle-event
-deltas, participating trace ids) and why THIS request was slow
-(t_submit → t_admit → t_first_token → t_finish, from which ttft_ms /
-queue_ms / e2e_ms / decode_tps derive). One trace id walks gateway span
-→ request record → tick records.
+deltas, participating trace ids), which admission round preceded it,
+how the loop's turn was handed between event loop and executor, and why
+THIS request was slow (t_submit → t_pop → t_admit → t_first_token →
+t_finish, from which pending_ms / prefill_ms / queue_ms / ttft_ms /
+e2e_ms / decode_tps derive). One trace id walks gateway span → request
+record → admission record → tick records.
 
-The histograms are the aggregatable counterpart of the in-process
-p50/p99 gauges ServingStats has carried since round 4: fixed log-spaced
-bucket counters (core/config.py::LATENCY_BUCKET_BOUNDS_MS) that the
-gateway renders as true Prometheus `_bucket`/`_sum`/`_count` series, so
-PromQL can sum across backends and compute windowed quantiles — which
-point-in-time snapshot percentiles fundamentally cannot do.
+The histograms are fixed log-spaced bucket counters
+(core/config.py::LATENCY_BUCKET_BOUNDS_MS) that the gateway renders as
+true Prometheus `_bucket`/`_sum`/`_count` series, so PromQL can sum
+across backends and compute windowed quantiles — and a benchmark reads
+sum/count as deltas over its window.
 
 Threading: records are appended from the batcher's serialized executor
 calls and (for queue-side terminal events) the event loop; deque
@@ -49,35 +51,45 @@ PHASE_NAMES = ("admit", "sync", "dispatch", "wait", "host")
 
 # The latencies the recorder distributes: the four lifecycle histograms
 # (ServingStatsResponse 34-45), one histogram per tick phase (fields
-# 67-81), and the inter-token-latency (TPOT) histogram (106-108) —
-# per finished request, the mean gap between consecutive token
-# emissions, derived from the existing first/last lifecycle stamps.
+# 67-81), the inter-token-latency (TPOT) histogram (106-108) — per
+# finished request, the mean gap between consecutive token emissions,
+# derived from the existing first/last lifecycle stamps — and the two
+# halves of queue_ms (144-149): pending (submit → the pop that put the
+# request into an admission batch) and prefill (that pop → activation).
 # Keys double as the stats() field prefixes:
 # <name>_bucket / <name>_sum / <name>_count.
 HISTOGRAM_NAMES = ("ttft_ms", "e2e_ms", "queue_ms", "tick_duration_ms") + tuple(
     f"tick_phase_{p}_ms" for p in PHASE_NAMES
-) + ("tpot_ms",)
+) + ("tpot_ms", "pending_ms", "prefill_ms")
 
 
 class PhaseTimer:
     """Contiguous segment timer: mark(phase) charges the time since the
-    previous mark to `phase`. Because segments are contiguous from t0,
-    the accumulated phases always sum to (last - t0) exactly — the
-    closure property the tick-phase acceptance test asserts. Repeated
-    marks of the same phase accumulate."""
+    previous mark to `phase` and keeps where that segment started, so
+    every phase is an INTERVAL on the clock the timer was opened on
+    (t0 is paired with a wall stamp by whoever owns the timer).
+    Because segments are contiguous from t0, the accumulated phases
+    always sum to (last - t0) exactly — the closure property the
+    tick-phase acceptance test asserts. Repeated marks of the same
+    phase accumulate in `acc` and stay separate in `marks`."""
 
-    __slots__ = ("t0", "last", "acc")
+    __slots__ = ("t0", "last", "acc", "marks")
 
     def __init__(self) -> None:
         self.t0 = self.last = time.perf_counter()
         self.acc: dict = {}
+        # (phase, start) per mark, perf_counter seconds; a segment ends
+        # where the next one starts, the last at `last`.
+        self.marks: list = []
 
-    def mark(self, phase: str) -> None:
+    def mark(self, phase: str) -> float:
+        """Close the open segment as `phase`; returns its length (ms)."""
         now = time.perf_counter()
-        self.acc[phase] = (
-            self.acc.get(phase, 0.0) + (now - self.last) * 1000.0
-        )
+        ms = (now - self.last) * 1000.0
+        self.acc[phase] = self.acc.get(phase, 0.0) + ms
+        self.marks.append((phase, self.last))
         self.last = now
+        return ms
 
 
 @dataclasses.dataclass
@@ -124,6 +136,11 @@ class TickRecord:
     phase_dispatch_ms: float = 0.0
     phase_wait_ms: float = 0.0
     phase_host_ms: float = 0.0
+    # The same phases as intervals: (phase, start offset in ms from
+    # t_mono/t_wall) per PhaseTimer mark, in order; each ends where the
+    # next starts, the last at duration_ms - phase_admit_ms. Settled at
+    # tick_done (proto phase_marks / phase_mark_start_ms).
+    marks: list = dataclasses.field(default_factory=list)
     # Device-memory ledger snapshot at dispatch (component -> bytes;
     # empty when the ledger is off) — the timeline's counter-track
     # source (proto memory_components/memory_component_bytes).
@@ -158,10 +175,79 @@ class TickRecord:
             "phaseDispatchMs": round(self.phase_dispatch_ms, 3),
             "phaseWaitMs": round(self.phase_wait_ms, 3),
             "phaseHostMs": round(self.phase_host_ms, 3),
+            "phaseMarks": [p for p, _ in self.marks],
+            "phaseMarkStartMs": [round(ms, 3) for _, ms in self.marks],
             "memoryComponents": list(self.memory),
             "memoryComponentBytes": [
                 int(b) for b in self.memory.values()
             ],
+        }
+
+
+@dataclasses.dataclass
+class AdmissionRecord:
+    """One admission round — a _prefill_into_slots executor call
+    (protos/serving.proto AdmissionRecord). The span whose duration the
+    request-level prefill_ms is mostly made of; `tick_seq` is the tick
+    it precedes (whose admit phase carries this round's time)."""
+
+    seq: int
+    t_wall: float
+    t_mono: float
+    duration_ms: float
+    family: str
+    rows: int
+    prompt_tokens: int
+    reused_tokens: int
+    trace_ids: list
+    tick_seq: int
+    source: str = ""
+
+    def to_dict(self) -> dict:
+        return {
+            "seq": self.seq,
+            "tWall": round(self.t_wall, 6),
+            "durationMs": round(self.duration_ms, 3),
+            "family": self.family,
+            "rows": self.rows,
+            "promptTokens": self.prompt_tokens,
+            "reusedTokens": self.reused_tokens,
+            "traceIds": self.trace_ids,
+            "tickSeq": self.tick_seq,
+            "source": self.source,
+        }
+
+
+@dataclasses.dataclass
+class HandoffRecord:
+    """One executor call of the batcher loop with the hand-offs around
+    it (protos/serving.proto HandoffRecord): host_ms of loop-side
+    python before the submission at t_wall/t_mono, then exec_wait,
+    work and lag, contiguous. The per-call form of the ServingStats
+    loop_*_ms sums."""
+
+    seq: int
+    kind: str
+    t_wall: float
+    t_mono: float
+    host_ms: float
+    exec_wait_ms: float
+    work_ms: float
+    lag_ms: float
+    tick_seq: int
+    source: str = ""
+
+    def to_dict(self) -> dict:
+        return {
+            "seq": self.seq,
+            "kind": self.kind,
+            "tWall": round(self.t_wall, 6),
+            "hostMs": round(self.host_ms, 3),
+            "execWaitMs": round(self.exec_wait_ms, 3),
+            "workMs": round(self.work_ms, 3),
+            "lagMs": round(self.lag_ms, 3),
+            "tickSeq": self.tick_seq,
+            "source": self.source,
         }
 
 
@@ -173,6 +259,11 @@ class RequestRecord:
     trace_id: str
     t_submit: float  # wall-clock epoch seconds
     queue_ms: float
+    # queue_ms split where it splits: submit → the pop that put the
+    # request into an admission batch, and that pop → activation.
+    # pending_ms + prefill_ms == queue_ms for every record.
+    pending_ms: float
+    prefill_ms: float
     ttft_ms: float
     e2e_ms: float
     prompt_tokens: int
@@ -200,6 +291,8 @@ class RequestRecord:
             "traceId": self.trace_id,
             "tSubmit": round(self.t_submit, 6),
             "queueMs": round(self.queue_ms, 3),
+            "pendingMs": round(self.pending_ms, 3),
+            "prefillMs": round(self.prefill_ms, 3),
             "ttftMs": round(self.ttft_ms, 3),
             "e2eMs": round(self.e2e_ms, 3),
             "promptTokens": self.prompt_tokens,
@@ -248,6 +341,10 @@ class FlightRecorder:
         self.enabled = bool(cfg.enabled)
         self.source = source
         self._ticks: deque = deque(maxlen=max(1, int(cfg.tick_ring)))
+        # Admission rounds and executor hand-offs ride beside the ticks
+        # (a loop turn is a few of each per tick), bounded alike.
+        self._admissions: deque = deque(maxlen=max(1, int(cfg.tick_ring)))
+        self._handoffs: deque = deque(maxlen=max(1, int(cfg.tick_ring)))
         self._requests: deque = deque(maxlen=max(1, int(cfg.request_ring)))
         self._bounds = tuple(float(b) for b in cfg.bucket_bounds_ms)
         self._hists = {
@@ -333,6 +430,10 @@ class FlightRecorder:
             rec.phase_dispatch_ms = acc.get("dispatch", 0.0)
             rec.phase_wait_ms = acc.get("wait", 0.0)
             rec.phase_host_ms = acc.get("host", 0.0)
+            rec.marks = [
+                (phase, (start - rec.t_mono) * 1000.0)
+                for phase, start in rec.phases.marks
+            ]
             # t_mono == the timer's t0, so this equals the phase sum
             # exactly (the closure contract the acceptance test pins).
             rec.duration_ms = rec.phase_admit_ms + (
@@ -367,11 +468,14 @@ class FlightRecorder:
         tenant: str = "",
         qos_class: str = "",
         slo_violated: bool = False,
+        t_pop: float = 0.0,
     ) -> None:
         """Record a request's terminal chunk; derives ttft/queue/e2e
-        and feeds the histograms. Stamps that never happened (a timeout
-        that was never admitted) stay 0 in the record and are skipped
-        by their histograms — a queue-death must not pollute the TTFT
+        (and queue's two halves from `t_pop`, the stamp of the pop that
+        put the request into its admission batch) and feeds the
+        histograms. Stamps that never happened (a timeout that was
+        never admitted) stay 0 in the record and are skipped by their
+        histograms — a queue-death must not pollute the TTFT
         distribution with zeros."""
         if not self.enabled:
             return
@@ -380,6 +484,11 @@ class FlightRecorder:
         # queue-deadline clock) while t_first keeps its original stamp,
         # so the splits can otherwise go negative for replayed requests.
         queue_ms = max(0.0, (t_admit - t_submit) * 1000.0) if t_admit else 0.0
+        # pending is clamped into [0, queue_ms] (the same replay case,
+        # and a path that stamps no pop) and prefill is the rest, so
+        # the two always add up to queue_ms.
+        pending_ms = min(queue_ms, max(0.0, (t_pop - t_submit) * 1000.0))
+        prefill_ms = queue_ms - pending_ms
         ttft_ms = max(0.0, (t_first - t_submit) * 1000.0) if t_first else 0.0
         e2e_ms = max(0.0, (now - t_submit) * 1000.0)
         decode_s = (now - t_first) if t_first else 0.0
@@ -387,6 +496,8 @@ class FlightRecorder:
             trace_id=trace_id,
             t_submit=time.time() - e2e_ms / 1000.0,
             queue_ms=queue_ms,
+            pending_ms=pending_ms,
+            prefill_ms=prefill_ms,
             ttft_ms=ttft_ms,
             e2e_ms=e2e_ms,
             prompt_tokens=prompt_tokens,
@@ -407,6 +518,8 @@ class FlightRecorder:
                 self._hists["ttft_ms"].observe(ttft_ms)
             if t_admit:
                 self._hists["queue_ms"].observe(queue_ms)
+                self._hists["pending_ms"].observe(pending_ms)
+                self._hists["prefill_ms"].observe(prefill_ms)
             self._hists["e2e_ms"].observe(e2e_ms)
             if t_first and tokens > 1:
                 # TPOT: mean inter-token gap over the decode span,
@@ -418,10 +531,75 @@ class FlightRecorder:
                     decode_s * 1000.0 / (tokens - 1)
                 )
 
+    def note_admission(
+        self,
+        timer: PhaseTimer,
+        family: str,
+        batch_trace_ids: list,
+        rows: int,
+        prompt_tokens: int,
+        reused_tokens: int,
+        tick_seq: int,
+        seq: int,
+    ) -> None:
+        """Record one admission round from its PhaseTimer (t0 = the
+        round's start, last = its end; the wall stamp is paired here,
+        at the end)."""
+        if not self.enabled:
+            return
+        self._admissions.append(AdmissionRecord(
+            seq=seq,
+            t_wall=time.time() - (time.perf_counter() - timer.t0),
+            t_mono=timer.t0,
+            duration_ms=(timer.last - timer.t0) * 1000.0,
+            family=family,
+            rows=rows,
+            prompt_tokens=prompt_tokens,
+            reused_tokens=reused_tokens,
+            trace_ids=batch_trace_ids,
+            tick_seq=tick_seq,
+            source=self.source,
+        ))
+
+    def note_handoff(
+        self,
+        seq: int,
+        kind: str,
+        host_ms: float,
+        t_submitted: float,
+        t_started: float,
+        t_ended: float,
+        t_resumed: float,
+        tick_seq: int,
+    ) -> None:
+        """Record one executor call's four stamps (perf_counter
+        seconds) as a hand-off record; the wall stamp of the submission
+        is paired here, on resume."""
+        if not self.enabled:
+            return
+        self._handoffs.append(HandoffRecord(
+            seq=seq,
+            kind=kind,
+            t_wall=time.time() - (time.perf_counter() - t_submitted),
+            t_mono=t_submitted,
+            host_ms=host_ms,
+            exec_wait_ms=(t_started - t_submitted) * 1000.0,
+            work_ms=(t_ended - t_started) * 1000.0,
+            lag_ms=(t_resumed - t_ended) * 1000.0,
+            tick_seq=tick_seq,
+            source=self.source,
+        ))
+
     # -- snapshots ----------------------------------------------------------
 
     def tick_snapshot(self) -> list:
         return list(self._ticks)
+
+    def admission_snapshot(self) -> list:
+        return list(self._admissions)
+
+    def handoff_snapshot(self) -> list:
+        return list(self._handoffs)
 
     def request_snapshot(self) -> list:
         return list(self._requests)

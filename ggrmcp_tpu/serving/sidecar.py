@@ -164,6 +164,9 @@ class Sidecar:
                 "and no kv_tiers: KV pages are the transfer format "
                 "and page import needs one arena (docs/paged_kv.md)"
             )
+        # Unary Generate's own time, [sum of ms, calls that returned a
+        # result] (ServingStats rpc_generate_ms_sum/_count).
+        self._rpc_generate_ms = [0.0, 0]
         self._transfer_stats = dict.fromkeys(
             (
                 "kv_transfers_sent", "kv_transfers_received",
@@ -458,9 +461,11 @@ class Sidecar:
             # Disaggregated prefill leg: prefill only, ship the pages,
             # return "transferred" — the gateway re-issues the request
             # to the peer, whose admission skips prefill entirely.
-            return await self._prefill_and_ship(
+            shipped = await self._prefill_and_ship(
                 request, context, prompt, trace_id, t0
             )
+            self._generate_returned(t0)
+            return shipped
         max_new = request.max_new_tokens or 64
         max_new = min(max_new, self.serving.batching.max_decode_steps)
         seed = request.sampling.seed or 0
@@ -576,8 +581,18 @@ class Sidecar:
             prompt_tokens=len(prompt),
             completion_tokens=len(token_ids),
             model_id=self.generation.cfg.name,
-            compute_ms=(time.perf_counter() - t0) * 1000,
+            compute_ms=self._generate_returned(t0),
         )
+
+    def _generate_returned(self, t0: float) -> float:
+        """A unary Generate is about to return a result: its time in
+        this handler (ms since `t0`, the stamp taken at entry) joins
+        rpc_generate_ms_sum/_count. With the batcher's e2e_ms this is
+        the sidecar's own part of what the gateway adds to a call."""
+        ms = (time.perf_counter() - t0) * 1000
+        self._rpc_generate_ms[0] += ms
+        self._rpc_generate_ms[1] += 1
+        return ms
 
     async def generate_stream(self, request: serving_pb2.GenerateRequest, context):
         assert self.generation is not None and self.batcher is not None
@@ -972,6 +987,9 @@ class Sidecar:
         # it scores load from.
         stats["role"] = getattr(self.serving, "role", "mixed")
         stats.update(self._transfer_stats)
+        stats["rpc_generate_ms_sum"], stats["rpc_generate_ms_count"] = (
+            self._rpc_generate_ms
+        )
         # Compile watcher (serving/compile_watcher.py): process-level
         # XLA compile counters — count/wall/cache outcomes and the
         # steady-state post-warmup recompiles (fields 101-105,
@@ -1090,6 +1108,8 @@ class Sidecar:
         max_requests = request.max_requests or 128
         ticks: list = []
         requests: list = []
+        admissions: list = []
+        handoffs: list = []
         enabled = False
         if self.batcher is not None:
             enabled = any(
@@ -1098,6 +1118,9 @@ class Sidecar:
             )
             ticks, requests = self.batcher.flight_snapshot(
                 max_ticks, max_requests, request.trace_id, request.tenant
+            )
+            admissions, handoffs = self.batcher.loop_snapshot(
+                max_ticks, request.trace_id
             )
         if self.spec_batcher is not None:
             enabled = enabled or self.spec_batcher.recorder.enabled
@@ -1144,6 +1167,9 @@ class Sidecar:
                     phase_dispatch_ms=t.phase_dispatch_ms,
                     phase_wait_ms=t.phase_wait_ms,
                     phase_host_ms=t.phase_host_ms,
+                    phase_marks=[p for p, _ in t.marks],
+                    phase_mark_start_ms=[ms for _, ms in t.marks],
+                    jump_tokens=t.jump_tokens, jump_runs=t.jump_runs,
                     memory_components=list(t.memory),
                     memory_component_bytes=[
                         int(b) for b in t.memory.values()
@@ -1154,7 +1180,9 @@ class Sidecar:
             requests=[
                 serving_pb2.RequestRecord(
                     trace_id=r.trace_id, t_submit=r.t_submit,
-                    queue_ms=r.queue_ms, ttft_ms=r.ttft_ms, e2e_ms=r.e2e_ms,
+                    queue_ms=r.queue_ms, pending_ms=r.pending_ms,
+                    prefill_ms=r.prefill_ms,
+                    ttft_ms=r.ttft_ms, e2e_ms=r.e2e_ms,
                     prompt_tokens=r.prompt_tokens, tokens=r.tokens,
                     finish_reason=r.finish_reason, decode_tps=r.decode_tps,
                     first_tick=r.first_tick, last_tick=r.last_tick,
@@ -1163,6 +1191,27 @@ class Sidecar:
                     slo_violated=r.slo_violated,
                 )
                 for r in requests
+            ],
+            admissions=[
+                serving_pb2.AdmissionRecord(
+                    seq=a.seq, t_wall=a.t_wall, t_mono=a.t_mono,
+                    duration_ms=a.duration_ms, family=a.family,
+                    rows=a.rows, prompt_tokens=a.prompt_tokens,
+                    reused_tokens=a.reused_tokens,
+                    trace_ids=a.trace_ids, tick_seq=a.tick_seq,
+                    source=a.source,
+                )
+                for a in admissions
+            ],
+            handoffs=[
+                serving_pb2.HandoffRecord(
+                    seq=h.seq, kind=h.kind, t_wall=h.t_wall,
+                    t_mono=h.t_mono, host_ms=h.host_ms,
+                    exec_wait_ms=h.exec_wait_ms, work_ms=h.work_ms,
+                    lag_ms=h.lag_ms, tick_seq=h.tick_seq,
+                    source=h.source,
+                )
+                for h in handoffs
             ],
             enabled=enabled,
         )
@@ -1225,15 +1274,20 @@ class Sidecar:
                 ))
                 host_total += int(info.get("bytes", 0))
         cstats = watcher.stats()
-        # The allocator's own per-chip figure (None on backends that
-        # report no memory stats, e.g. CPU).
-        device_bytes = [
-            int(ms["bytes_in_use"])
-            for ms in (d.memory_stats() for d in engine.mesh.devices.flat)
+        # The allocator's own per-chip figures, now and at its high-water
+        # mark (None on backends that report no memory stats, e.g. CPU).
+        device_stats = [
+            ms for ms in (d.memory_stats() for d in engine.mesh.devices.flat)
             if ms is not None
         ]
         return serving_pb2.MemoryResponse(
-            device_bytes_in_use=device_bytes,
+            device_bytes_in_use=[
+                int(ms["bytes_in_use"]) for ms in device_stats
+            ],
+            device_peak_bytes_in_use=[
+                int(ms["peak_bytes_in_use"]) for ms in device_stats
+                if "peak_bytes_in_use" in ms
+            ],
             components=components,
             total_bytes=total,
             host=host_components,

@@ -226,20 +226,14 @@ class TieredBatcher:
         return records
 
     def stats(self) -> dict:
-        """Aggregated ServingStats across tiers: counters sum;
-        queue/service (and decode-stall) percentiles are computed ONCE
-        over the concatenated per-tier records (summing a p50 is
-        meaningless, and per-tier percentile sorts would be wasted
-        work on every scrape); histogram bucket counts merge
-        elementwise (histograms, unlike percentiles, ARE summable —
-        the whole point of exporting them)."""
+        """Aggregated ServingStats across tiers: counters sum (each
+        tier runs its own loop, so the loop_*_ms parts sum too);
+        histogram bucket counts merge elementwise (histograms ARE
+        summable — the whole point of exporting them)."""
         from ggrmcp_tpu.serving.flight_recorder import FlightRecorder
         from ggrmcp_tpu.serving.slo import SloAccount, TenantTable
 
         per_tier = [t.counter_stats() for t in self.tiers]
-        records: list = []
-        for t in self.tiers:
-            records.extend(t.lat_snapshot())
         return {
             **{
                 key: (
@@ -249,8 +243,6 @@ class TieredBatcher:
                 )
                 for key in per_tier[0]
             },
-            **ContinuousBatcher.lat_percentiles(records),
-            **ContinuousBatcher.stall_percentiles(self.stall_snapshot()),
             **FlightRecorder.merge_histogram_stats(
                 [t.recorder.histogram_stats() for t in self.tiers]
             ),
@@ -283,6 +275,22 @@ class TieredBatcher:
         ticks.sort(key=lambda r: r.t_wall)
         requests.sort(key=lambda r: r.t_submit)
         return ticks[-max(1, max_ticks):], requests[-max(1, max_requests):]
+
+    def loop_snapshot(
+        self, max_records: int = 128, trace_id: str = ""
+    ) -> tuple[list, list]:
+        """Merged per-tier admission and hand-off records, ordered by
+        wall-clock stamp (same contract as flight_snapshot)."""
+        admissions: list = []
+        handoffs: list = []
+        for tier in self.tiers:
+            t_adm, t_hand = tier.loop_snapshot(max_records, trace_id)
+            admissions.extend(t_adm)
+            handoffs.extend(t_hand)
+        admissions.sort(key=lambda r: r.t_wall)
+        handoffs.sort(key=lambda r: r.t_wall)
+        n = max(1, max_records)
+        return admissions[-n:], handoffs[-n:]
 
     def request_record(self, trace_id: str):
         for tier in self.tiers:

@@ -7,21 +7,26 @@ merging the stack's three existing rings — gateway spans
 
 Layout: the gateway is one process row (pid 1) with one thread per
 trace id, so concurrent calls never overlap on a track; each backend is
-its own process row with one "ticks" thread per source batcher (flat
-pool / KV tier), one row per request lifecycle, and instant markers for
+its own process row with "ticks" threads per source batcher (flat pool
+/ KV tier; two lanes, odd and even seq, because a pipelined tick is
+still in flight while the next one is dispatched), an "admissions"
+thread (one slice per admission round, where it was), a "loop" thread
+(every executor call of the batcher loop as four contiguous slices:
+host / exec_wait / work / lag — the hand-offs between event loop and
+executor), one row per request lifecycle, and instant markers for
 lifecycle events (shed / replay / queue timeout, derived from the
 cumulative counters snapshotted in consecutive tick records, plus
 terminal request failures — a chaos run's injected failpoints surface
-here). Tick slices nest their phase attribution (admit / sync /
-dispatch / wait / host — the PhaseTimer partition of duration_ms) as
-child slices, so "where did this tick's budget go" is visible at a
-glance.
+here). Tick slices nest their phases (sync / dispatch / wait / host)
+as child slices AT THE INTERVALS the PhaseTimer marked, so "where did
+this tick's budget go" is visible at a glance; the admit phase is the
+admission slices that precede the tick.
 
-Clock alignment: every tick record carries a PAIRED wall/mono stamp
-taken at dispatch (t_wall, t_mono). All durations on the sidecar side
-are monotonic-derived (the PhaseTimer), and each record's wall stamp
-anchors them on the shared wall-clock axis; gateway spans and request
-records already carry wall stamps (span.start_unix,
+Clock alignment: every tick, admission and hand-off record carries a
+PAIRED wall/mono stamp (t_wall, t_mono). All durations and offsets on
+the sidecar side are monotonic-derived (the PhaseTimer), and each
+record's wall stamp anchors them on the shared wall-clock axis; gateway
+spans and request records already carry wall stamps (span.start_unix,
 RequestRecord.t_submit). One wall axis therefore spans gateway and
 sidecar without assuming their monotonic clocks share an epoch.
 
@@ -33,11 +38,14 @@ from __future__ import annotations
 
 from typing import Any, Optional
 
-# Tick phases in wall-clock order within a tick; mirrored from
-# serving/flight_recorder.py::PHASE_NAMES (kept literal here so the
-# gateway does not import the recorder — protojson keys are the
-# contract between the two processes).
-_PHASES = ("admit", "sync", "dispatch", "wait", "host")
+# The four contiguous parts of an executor call (HandoffRecord), in
+# wall-clock order; host precedes the submission stamp (kept literal
+# here so the gateway does not import the recorder — protojson keys are
+# the contract between the two processes).
+_HANDOFF_PARTS = (
+    ("host", "hostMs"), ("exec_wait", "execWaitMs"),
+    ("work", "workMs"), ("lag", "lagMs"),
+)
 
 # Lifecycle counters whose per-tick deltas become instant events.
 _LIFECYCLE = (
@@ -101,53 +109,65 @@ def _span_events(spans: list, events: list) -> None:
 
 
 def _tick_events(ticks: list, pid: int, events: list) -> None:
-    """Tick records → one "ticks <source>" thread per source batcher:
-    a parent slice per tick with its phase partition nested as child
-    slices, lifecycle-counter deltas as instant markers, and counter
-    ("C") tracks for the paged-arena occupancy and the device-memory
-    ledger's per-component bytes — HBM pressure on the same time axis
-    as the phases (Perfetto renders each counter name as its own
-    track; the multi-series memory counter stacks its components)."""
-    tids: dict[str, int] = {}
+    """Tick records → "ticks <source>" threads per source batcher (two
+    lanes by seq parity: under pipelined dispatch tick N is in flight
+    until N+1 has been dispatched, and slices that overlap without
+    nesting cannot share a track): a slice per tick from its dispatch
+    stamp to its collect, its phases nested as child slices at the
+    intervals the PhaseTimer marked, lifecycle-counter deltas as
+    instant markers, and counter ("C") tracks for the paged-arena
+    occupancy and the device-memory ledger's per-component bytes — HBM
+    pressure on the same time axis as the phases (Perfetto renders
+    each counter name as its own track; the multi-series memory
+    counter stacks its components)."""
+    tids: dict[tuple, int] = {}
     prev: dict[str, dict] = {}  # source -> previous record's counters
     for tick in sorted(ticks, key=lambda t: _f(t.get("tWall"))):
         source = str(tick.get("source", ""))
-        tid = tids.get(source)
+        lane = int(_f(tick.get("seq"))) % 2
+        tid = tids.get((source, lane))
         if tid is None:
-            tid = tids[source] = len(tids) + 1
+            tid = tids[(source, lane)] = len(tids) + 1
             events.append(_meta(
-                pid, tid, "thread_name", f"ticks {source or 'pool'}"
+                pid, tid, "thread_name",
+                f"ticks {source or 'pool'} ({'even' if lane == 0 else 'odd'})",
             ))
-        phases = {p: _f(tick.get(f"phase{p.title()}Ms")) for p in _PHASES}
-        duration_ms = _f(tick.get("durationMs"))
-        # t_wall is stamped at dispatch — the admit phase precedes it,
-        # so the attributed tick window opens admit_ms earlier.
-        start_us = _us(_f(tick.get("tWall")) - phases["admit"] / 1000.0)
+        # The record's stamp is the dispatch; the admit phase is the
+        # admission rounds before it (their own slices), so the tick's
+        # own interval is duration_ms less that phase.
+        start_us = _us(_f(tick.get("tWall")))
+        dur_us = _us(
+            (_f(tick.get("durationMs")) - _f(tick.get("phaseAdmitMs")))
+            / 1000.0
+        )
         args = {
             k: tick.get(k)
             for k in (
                 "seq", "activeSlots", "admitted", "finished",
                 "interleavedRows", "traceIds", "specDrafted",
-                "specAccepted", "kvPagesInUse",
+                "specAccepted", "kvPagesInUse", "phaseAdmitMs",
             )
             if k in tick
         }
         events.append({
             "ph": "X", "cat": "tick",
             "name": f"tick {tick.get('seq', '?')}",
-            "ts": start_us, "dur": _us(duration_ms / 1000.0),
+            "ts": start_us, "dur": dur_us,
             "pid": pid, "tid": tid, "args": args,
         })
-        cursor = start_us
-        for phase in _PHASES:
-            dur_us = _us(phases[phase] / 1000.0)
-            if dur_us > 0:
+        names = tick.get("phaseMarks") or []
+        starts = [_us(_f(ms) / 1000.0) for ms in (
+            tick.get("phaseMarkStartMs") or []
+        )]
+        for i, (phase, offset_us) in enumerate(zip(names, starts)):
+            end_us = starts[i + 1] if i + 1 < len(starts) else dur_us
+            if end_us > offset_us:
                 events.append({
-                    "ph": "X", "cat": "tick.phase", "name": phase,
-                    "ts": cursor, "dur": dur_us, "pid": pid, "tid": tid,
-                    "args": {"ms": round(phases[phase], 3)},
+                    "ph": "X", "cat": "tick.phase", "name": str(phase),
+                    "ts": start_us + offset_us, "dur": end_us - offset_us,
+                    "pid": pid, "tid": tid,
+                    "args": {"ms": (end_us - offset_us) / 1000.0},
                 })
-            cursor += dur_us
         last = prev.setdefault(source, {})
         for key, label in _LIFECYCLE:
             value = _f(tick.get(key))
@@ -182,6 +202,71 @@ def _tick_events(ticks: list, pid: int, events: list) -> None:
                     str(c): _f(v) for c, v in zip(comps, values)
                 },
             })
+
+
+def _admission_events(admissions: list, pid: int, events: list) -> None:
+    """Admission records → one "admissions" thread per sidecar: a slice
+    per admission round where it was (rounds are serialized executor
+    work items, so they never overlap), named by the program family
+    that ran and carrying the tick it precedes and the trace ids it
+    admitted — the cause links of the request rows' prefill time."""
+    tids: dict[str, int] = {}
+    for adm in sorted(admissions, key=lambda a: _f(a.get("tWall"))):
+        source = str(adm.get("source", ""))
+        tid = tids.get(source)
+        if tid is None:
+            # Below the compile row (999), above the tick tracks.
+            tid = tids[source] = 900 + len(tids)
+            events.append(_meta(
+                pid, tid, "thread_name", f"admissions {source or 'pool'}"
+            ))
+        events.append({
+            "ph": "X", "cat": "admission",
+            "name": f"admit {adm.get('family', '') or '-'}",
+            "ts": _us(_f(adm.get("tWall"))),
+            "dur": _us(_f(adm.get("durationMs")) / 1000.0),
+            "pid": pid, "tid": tid,
+            "args": {
+                k: adm.get(k) for k in (
+                    "seq", "family", "rows", "promptTokens",
+                    "reusedTokens", "traceIds", "tickSeq", "source",
+                ) if k in adm
+            },
+        })
+
+
+def _handoff_events(handoffs: list, pid: int, events: list) -> None:
+    """Hand-off records → one "loop" thread per sidecar: every executor
+    call of the batcher loop as four contiguous slices — host (loop-
+    side python before the submission stamp), exec_wait, work, lag —
+    the loop's turn with nothing left over. Gaps between one call's
+    lag and the next call's host are time the loop was parked."""
+    tids: dict[str, int] = {}
+    for rec in sorted(handoffs, key=lambda h: _f(h.get("tWall"))):
+        source = str(rec.get("source", ""))
+        tid = tids.get(source)
+        if tid is None:
+            tid = tids[source] = 950 + len(tids)
+            events.append(_meta(
+                pid, tid, "thread_name", f"loop {source or 'pool'}"
+            ))
+        cursor = _us(
+            _f(rec.get("tWall")) - _f(rec.get("hostMs")) / 1000.0
+        )
+        for part, key in _HANDOFF_PARTS:
+            dur_us = _us(_f(rec.get(key)) / 1000.0)
+            if dur_us > 0:
+                events.append({
+                    "ph": "X", "cat": "loop",
+                    "name": f"{part} ({rec.get('kind', '?')})",
+                    "ts": cursor, "dur": dur_us,
+                    "pid": pid, "tid": tid,
+                    "args": {
+                        "seq": rec.get("seq"),
+                        "tickSeq": rec.get("tickSeq"),
+                    },
+                })
+            cursor += dur_us
 
 
 def _compile_events(compiles: list, pid: int, events: list) -> None:
@@ -233,6 +318,8 @@ def _request_events(requests: list, pid: int, events: list) -> None:
             "args": {
                 "traceId": trace_id,
                 "queueMs": _f(req.get("queueMs")),
+                "pendingMs": _f(req.get("pendingMs")),
+                "prefillMs": _f(req.get("prefillMs")),
                 "ttftMs": _f(req.get("ttftMs")),
                 "promptTokens": int(_f(req.get("promptTokens"))),
                 "tokens": int(_f(req.get("tokens"))),
@@ -296,6 +383,8 @@ def build_timeline(
             continue
         events.append(_meta(pid, 0, "process_name", f"sidecar {target}"))
         _tick_events(entry.get("ticks", []), pid, events)
+        _admission_events(entry.get("admissions", []), pid, events)
+        _handoff_events(entry.get("handoffs", []), pid, events)
         _compile_events(entry.get("compiles", []), pid, events)
         _request_events(entry.get("requests", []), pid, events)
     # Stable per-track ordering: metadata first, then by start time;

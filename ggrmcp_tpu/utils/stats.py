@@ -2,8 +2,7 @@
 gateway/bench paths).
 
 One formula for every latency percentile the project reports: the
-ceil-based nearest-rank used by ContinuousBatcher.lat_percentiles since
-round 4. bench.py previously hand-rolled `int(n*p)-1`, which reads ~p98
+ceil-based nearest-rank. bench.py previously hand-rolled `int(n*p)-1`, which reads ~p98
 at n=63 and indexes -1 at n<2 (round-5 issue list)."""
 
 from __future__ import annotations

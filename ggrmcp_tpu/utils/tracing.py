@@ -146,18 +146,42 @@ def trace_id_from_metadata(metadata) -> str:
     return ""
 
 
+# True while profile_capture holds a profiler session open (it sets and
+# clears it; the sidecar admits one capture at a time). The program's
+# hot-path spans check this one name and cost nothing else when off.
+capture_running = False
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(name: str, **stats: Any):
+    """A host span in the PROFILER's own trace, so the program's spans
+    sit next to the device operations on one clock:
+    `jax.profiler.TraceAnnotation(name, **stats)` while a capture runs,
+    a shared no-op context otherwise."""
+    if not capture_running:
+        return _NO_ANNOTATION
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **stats)
+
+
 def profile_capture(duration_ms: float, output_dir: Optional[str] = None) -> str:
     """Capture a JAX profiler trace for `duration_ms` (blocking) and
     return the dump directory. The deep device-level hook behind the
-    sidecar's DebugService.Profile RPC."""
+    sidecar's DebugService.Profile RPC. For its duration the program's
+    annotation() spans are live."""
+    global capture_running
     import tempfile
 
     import jax
 
     out = output_dir or tempfile.mkdtemp(prefix="ggrmcp-profile-")
     jax.profiler.start_trace(out)
+    capture_running = True
     try:
         time.sleep(max(duration_ms, 0) / 1000.0)
     finally:
+        capture_running = False
         jax.profiler.stop_trace()
     return out

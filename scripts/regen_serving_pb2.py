@@ -5,8 +5,8 @@ canonical path on machines that do).
 
 This is a deliberately small compiler for the subset of proto3 the
 project's contracts use: messages with scalar / repeated / message /
-map<scalar,scalar> fields, and services with unary or server-streaming
-methods. It parses the .proto into a FileDescriptorProto, serializes it
+map<scalar,scalar> fields and reserved numbers, and services with
+unary or server-streaming methods. It parses the .proto into a FileDescriptorProto, serializes it
 (byte-identical to protoc's output for this subset — field descriptors
 carry name/number/label/type in field-number order and no json_name,
 exactly like protoc), and emits the same generated-module shape the
@@ -139,6 +139,15 @@ def _parse_message(name, body, package, err) -> dpb.DescriptorProto:
     for stmt in body.split(";"):
         stmt = stmt.strip()
         if not stmt:
+            continue
+        if stmt.startswith("reserved "):
+            # `reserved 14 to 20, 23;` -> [start, end) ranges, as protoc
+            # writes them (field names are not supported, nor needed).
+            for part in stmt[len("reserved "):].split(","):
+                lo, _, hi = part.partition(" to ")
+                msg.reserved_range.add(
+                    start=int(lo), end=int(hi or lo) + 1
+                )
             continue
         m = _FIELD_RE.match(stmt + ";")
         if m is None:

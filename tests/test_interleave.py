@@ -189,9 +189,16 @@ class TestStallBound:
             f"{admission_s * 1000.0:.0f}ms — decode stalled for the "
             f"full prefill"
         )
-        pct = batcher.stall_percentiles(stalls)
-        assert pct["decode_stall_ms_max"] == round(worst_ms, 2)
-        assert pct["decode_stall_ms_p99"] <= pct["decode_stall_ms_max"]
+        # The long request's prefill rode the ticks: its admission round
+        # only queued it, and its wait shows as prefill, not pending.
+        assert "interleave_queued" in [
+            r.family for r in batcher.recorder.admission_snapshot()
+        ]
+        long_rec = max(
+            batcher.recorder.request_snapshot(),
+            key=lambda r: r.prompt_tokens,
+        )
+        assert long_rec.prefill_ms > long_rec.pending_ms
 
 
 class TestConfig:
@@ -219,9 +226,8 @@ class TestConfig:
             f.name
             for f in serving_pb2.ServingStatsResponse.DESCRIPTOR.fields
         }
-        for key in (
-            "interleaved_chunks", "interleaved_admissions",
-            "decode_stall_ms_p50", "decode_stall_ms_p99",
-            "decode_stall_ms_max",
-        ):
+        for key in ("interleaved_chunks", "interleaved_admissions"):
             assert key in fields
+        # The stall gauges were lifetime percentiles with no reader
+        # (PR 26): the records stay in process (stall_snapshot).
+        assert not {k for k in fields if k.startswith("decode_stall")}

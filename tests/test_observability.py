@@ -112,6 +112,8 @@ class TestProtoDrift:
             "ttft_ms", "e2e_ms", "queue_ms", "tick_duration_ms",
             # Inter-token latency (fields 106-108).
             "tpot_ms",
+            # queue_ms split at the admission pop (fields 144-149).
+            "pending_ms", "prefill_ms",
             # Tick-phase attribution: one histogram per phase, rendered
             # as ONE gateway_backend_tick_phase_ms{phase} family.
             *(f"tick_phase_{p}_ms"
@@ -171,6 +173,14 @@ class TestProtoDrift:
             "compile_count", "compile_ms", "compile_cache_hits",
             "compile_cache_misses", "compile_post_warmup",
         } <= gauges
+        # The loop-turn partition and the sidecar's own Generate time
+        # are monotone sum/count pairs without buckets: plain gauges.
+        assert {
+            "loop_exec_wait_ms_sum", "loop_exec_wait_ms_count",
+            "loop_work_ms_sum", "loop_lag_ms_sum", "loop_lag_ms_count",
+            "loop_host_ms_sum", "loop_busy_ms_sum",
+            "rpc_generate_ms_sum", "rpc_generate_ms_count",
+        } <= gauges
 
         metrics = GatewayMetrics()
         if metrics.registry is None:
@@ -204,6 +214,32 @@ class TestProtoDrift:
         rendered = metrics.render()[0].decode()
         assert 'mesh_shape="tensor=2"' not in rendered
         assert 'target="t1"' not in rendered
+
+    @pytest.mark.parametrize("name", [
+        "tick_dispatch_ms", "tick_collect_ms", "admit_ms", "admit_ms_max",
+        "queue_ms_p50", "queue_ms_p99", "service_ms_p50",
+        "service_ms_p99", "decode_stall_ms_p50", "decode_stall_ms_p99",
+        "decode_stall_ms_max",
+    ])
+    def test_retired_fields_are_gone_and_their_numbers_reserved(self, name):
+        """PR 26's inventory: the second tick clocks and the lifetime
+        percentile gauges had no reader. Their numbers stay reserved,
+        so no later field can take one and be misread by an old peer."""
+        from ggrmcp_tpu.gateway.metrics import serving_gauge_names
+        from ggrmcp_tpu.rpc.pb import serving_pb2
+
+        desc = serving_pb2.ServingStatsResponse.DESCRIPTOR
+        assert name not in desc.fields_by_name
+        assert name not in serving_gauge_names()
+        from google.protobuf import descriptor_pb2
+
+        proto = descriptor_pb2.DescriptorProto()
+        desc.CopyToProto(proto)
+        reserved = {
+            n for r in proto.reserved_range for n in range(r.start, r.end)
+        }
+        assert reserved == {14, 15, 16, 17, 18, 19, 20, 23, 26, 27, 28}
+        assert not reserved & {f.number for f in desc.fields}
 
     def test_flight_recorder_stats_match_proto_fields(self):
         """histogram_stats() keys must be exact proto field names —
@@ -274,7 +310,8 @@ class TestScrapeValidity:
         assert bucket_vals == sorted(bucket_vals)
         # Descriptor-driven gauges rendered too.
         assert families["gateway_backend_active_slots"].samples
-        assert families["gateway_backend_tick_dispatch_ms"].samples
+        assert families["gateway_backend_tick_phase_dispatch_ms"].samples
+        assert families["gateway_backend_loop_busy_ms_sum"].samples
 
     def test_stale_target_drops_histograms(self):
         metrics = self._populated_metrics()
@@ -421,6 +458,91 @@ class TestTraceLinkedPostmortems:
             )
 
             list(text_string_to_metric_families(text))
+
+
+class TestTimePartitionSurfaces:
+    """ISSUE 26 end to end through the gateway: the rings behind
+    /debug/ticks, the new ServingStats pairs, the allocator's peak."""
+
+    @pytest.mark.parametrize("impl", ["fastlane", "aiohttp"])
+    async def test_debug_ticks_carries_admissions_and_handoffs(self, impl):
+        trace_id = f"trace-adm-{impl}"
+        async with observed_env(impl) as (_side, _gw, client):
+            await _generate_call(client, trace_id, max_new=6)
+            body = await (await client.get("/debug/ticks")).json()
+            [backend] = body["backends"]
+            [adm] = backend["admissions"]
+            assert adm["family"] == "single" and adm["traceIds"] == [trace_id]
+            assert float(adm["durationMs"]) > 0
+            kinds = {h["kind"] for h in backend["handoffs"]}
+            assert {"admit", "tick"} <= kinds
+            tick = backend["ticks"][0]
+            assert tick["phaseMarks"][0] == "sync"
+            assert len(tick["phaseMarks"]) == len(tick["phaseMarkStartMs"])
+            # Filtered to the trace: its admission stays, hand-offs
+            # (which carry no trace id) are left out.
+            body = await (await client.get(
+                "/debug/ticks", params={"trace_id": trace_id}
+            )).json()
+            [backend] = body["backends"]
+            assert [a["seq"] for a in backend["admissions"]] == [adm["seq"]]
+            assert backend["handoffs"] == []
+            # The request record carries both halves of its queue time.
+            body = await (await client.get(
+                "/debug/requests", params={"trace_id": trace_id}
+            )).json()
+            [rec] = body["backends"][0]["requests"]
+            assert float(rec["pendingMs"]) + float(
+                rec["prefillMs"]
+            ) == pytest.approx(float(rec["queueMs"]), abs=2e-3)
+            # And the timeline draws all of it.
+            doc = await (await client.get("/debug/timeline")).json()
+            cats = {e.get("cat") for e in doc["traceEvents"]}
+            assert {"admission", "loop", "tick.phase"} <= cats
+
+    async def test_stats_carry_the_loop_partition_and_rpc_time(self):
+        async with observed_env("fastlane") as (_side, _gw, client):
+            for i in range(2):
+                await _generate_call(client, f"trace-loop-{i}", max_new=6)
+            [s] = (await (await client.get("/stats")).json())["serving"]
+            parts = sum(float(s[k]) for k in (
+                "loopExecWaitMsSum", "loopWorkMsSum", "loopLagMsSum",
+                "loopHostMsSum",
+            ))
+            assert parts == pytest.approx(float(s["loopBusyMsSum"]))
+            assert s["loopLagMsCount"] == s["loopExecWaitMsCount"]
+            assert s["pendingMsCount"] == s["prefillMsCount"] == "2"
+            assert float(s["pendingMsSum"]) + float(
+                s["prefillMsSum"]
+            ) == pytest.approx(float(s["queueMsSum"]))
+            # The sidecar's handler contains the batcher's e2e.
+            assert s["rpcGenerateMsCount"] == "2"
+            assert float(s["rpcGenerateMsSum"]) >= float(s["e2eMsSum"])
+
+    async def test_memory_reports_a_peak_only_where_the_backend_has_one(self):
+        """The CPU allocator reports no memory stats: both per-device
+        lists are absent, never zero-filled."""
+        async with observed_env("fastlane") as (_side, _gw, client):
+            body = await (await client.get(
+                "/debug/memory", params={"reconcile": "0"}
+            )).json()
+            [backend] = body["backends"]
+            assert "devicePeakBytesInUse" not in backend
+            assert "deviceBytesInUse" not in backend
+
+    def test_peak_rides_beside_bytes_in_use(self):
+        """Unit for the chip's shape: the handler copies both allocator
+        figures per device, in mesh order."""
+        from ggrmcp_tpu.rpc.pb import serving_pb2
+
+        msg = serving_pb2.MemoryResponse(
+            device_bytes_in_use=[10, 11],
+            device_peak_bytes_in_use=[12, 13],
+        )
+        from google.protobuf import json_format
+
+        body = json_format.MessageToDict(msg)
+        assert body["devicePeakBytesInUse"] == ["12", "13"]
 
 
 class TestServingStatsHistogramFlow:
