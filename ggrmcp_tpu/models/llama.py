@@ -34,6 +34,7 @@ from ggrmcp_tpu.ops.quant import (
     QuantizedArray,
     dequantize,
     embed_lookup,
+    kv_map,
     quantize,
 )
 from ggrmcp_tpu.ops.quant import matmul as qmatmul
@@ -236,7 +237,13 @@ class PagedKVCache(NamedTuple):
     STORAGE is indirected, which is what lets any number of slots
     reference the pages of a shared prompt prefix. Table entries equal
     to n_pages are the unmapped SENTINEL: gathers clip (the junk is
-    masked by `length`), scatters drop (mode="drop")."""
+    masked by `length`), scatters drop (mode="drop").
+
+    Inside `forward` the arena is LOOP-CARRIED through the layer scan
+    and updated in place, indexed [layer, page, offset]: one scatter
+    writes the step's K/V, one gather reads the slots' views, and no
+    layer's [n_pages, page, KVH, Dh] plane is ever sliced out or
+    stacked back (docs/paged_kv.md "Inside the jitted tick")."""
 
     k: jnp.ndarray  # [L, n_pages, page, KVH, Dh] (or QuantizedArray)
     v: jnp.ndarray
@@ -280,18 +287,20 @@ def paged_cache_specs() -> PagedKVCache:
     return PagedKVCache(k=spec, v=spec, table=P(), length=P())
 
 
-def paged_view(arena, table: jnp.ndarray):
-    """Gather a contiguous per-slot view out of a paged arena: one
-    layer's [N, P, KVH, Dh] pages + [B, W] tables → [B, W·P, KVH, Dh],
-    where view position j is absolute position j (W·P == S_max).
-    Sentinel entries clip to a real page; the junk is masked by the
-    caller's kv_len exactly like a contiguous cache's tail garbage.
-    Works on QuantizedArray arenas (values + scales gather alike)."""
-    from ggrmcp_tpu.ops.quant import kv_map
-
+def paged_view(arena, table: jnp.ndarray, layer: jnp.ndarray):
+    """Gather one layer's contiguous per-slot view straight out of the
+    whole paged arena: [L, N, P, KVH, Dh] pages + [B, W] tables + a
+    scalar layer index → [B, W·P, KVH, Dh], where view position j is
+    absolute position j (W·P == S_max). ONE gather indexed
+    [layer, page]: the layer's [N, P, KVH, Dh] plane is never sliced
+    out, so inside the tick the arena stays a loop-carried buffer that
+    is only scattered into and gathered from. Sentinel entries clip to
+    a real page; the junk is masked by the caller's kv_len exactly like
+    a contiguous cache's tail garbage. Works on QuantizedArray arenas
+    (values + scales gather alike)."""
     def gather(a):
-        v = a[jnp.minimum(table, a.shape[0] - 1)]  # [B, W, P, ...]
-        return v.reshape(table.shape[0], -1, *a.shape[2:])
+        v = a[layer, jnp.minimum(table, a.shape[1] - 1)]  # [B, W, P, ...]
+        return v.reshape(table.shape[0], -1, *a.shape[3:])
 
     return kv_map(gather, arena)
 
@@ -299,8 +308,6 @@ def paged_view(arena, table: jnp.ndarray):
 def paged_view_layers(arena, table: jnp.ndarray):
     """`paged_view` for a full [L, N, P, KVH, Dh] arena (batcher-side
     admission gathers): → [L, B, W·P, KVH, Dh]."""
-    from ggrmcp_tpu.ops.quant import kv_map
-
     def gather(a):
         v = a[:, jnp.minimum(table, a.shape[1] - 1)]  # [L, B, W, P, ...]
         return v.reshape(a.shape[0], table.shape[0], -1, *a.shape[3:])
@@ -327,6 +334,7 @@ def attention_block(
     ring: bool = False,
     lora_idx: Optional[jnp.ndarray] = None,  # [B] adapter ids
     page_table: Optional[jnp.ndarray] = None,  # [B, W] paged block table
+    layer: Optional[jnp.ndarray] = None,  # scalar layer index (paged)
 ):
     """Pre-norm GQA attention with residual; shared by the dense and MoE
     decoder families. Returns (x + attn, (cache_k, cache_v) or None).
@@ -334,12 +342,17 @@ def attention_block(
     kernel reads shared heads in place; the XLA path contracts
     grouped for decode and repeats only for long queries).
 
-    `page_table` (paged KV, docs/paged_kv.md): cache_k/v are a page
-    ARENA [N, P, KVH, Dh] instead of per-slot rows. Writes scatter the
-    step's K/V through the table (position j → page table[b, j // P],
-    offset j % P; sentinel entries drop), reads attend a table-gathered
-    [B, W·P] view — positions, masks, and numerics are identical to the
-    contiguous cache, so paged-on/off greedy outputs are bit-identical.
+    `page_table` (paged KV, docs/paged_kv.md): cache_k/v are the WHOLE
+    page arena [L, N, P, KVH, Dh] — every layer's pages, not this
+    layer's slice — and `layer` is this layer's index into it. One
+    scatter indexed [layer, page, offset] writes the step's K/V through
+    the table (position j → page table[b, j // P], offset j % P;
+    sentinel entries drop) and one gather indexed [layer, page] reads a
+    [B, W·P] view (`paged_view`), so the arena the caller carries
+    through its layer loop is updated in place and no layer's plane is
+    ever materialised. Positions, masks, and numerics are identical to
+    the contiguous cache, so paged-on/off greedy outputs are
+    bit-identical. The returned (cache_k, cache_v) are the whole arenas.
     Shared (refcounted) pages are never written: the host allocator
     guarantees every write position ≥ the owner's prompt length lands
     in pages it owns exclusively (serving/pages.py invariants). Paged
@@ -384,19 +397,14 @@ def attention_block(
 
     if cache_k is not None and page_table is not None:
         # Paged arena: scatter the step's K/V through the block table
-        # and attend a table-gathered contiguous view. Sentinel table
-        # entries (parked slots, unmapped tail) drop the write; active
-        # rows only ever write pages they own exclusively.
+        # into this layer's pages of the whole arena and attend a
+        # table-gathered contiguous view. Sentinel table entries
+        # (parked slots, unmapped tail) drop the write; active rows
+        # only ever write pages they own exclusively.
         assert not ring, "paged KV does not compose with kv_ring"
-        p_sz = (
-            cache_k.q.shape[1]
-            if isinstance(cache_k, QuantizedArray) else cache_k.shape[1]
-        )
+        assert layer is not None, "paged KV needs the layer index"
+        _, n_pg, p_sz = cache_k.shape[:3]
         width = page_table.shape[1]
-        n_pg = (
-            cache_k.q.shape[0]
-            if isinstance(cache_k, QuantizedArray) else cache_k.shape[0]
-        )
         write_pos = cache_len[:, None] + jnp.arange(s)[None, :]  # [B, S]
         w_idx = write_pos // p_sz
         # Positions past the table width map to the sentinel, NOT to a
@@ -412,30 +420,23 @@ def attention_block(
             n_pg,
         )
         w_off = write_pos % p_sz
+
+        def write(arena, new):  # in place on the carried arena
+            return arena.at[layer, w_page, w_off].set(
+                new.astype(arena.dtype), mode="drop"
+            )
+
         if isinstance(cache_k, QuantizedArray):
             # Int8 pages: same value+scale scatter as the contiguous
             # int8 cache, indirected through the table.
-            qk = quantize(k, axis=-1)
-            qv = quantize(v, axis=-1)
-            cache_k = QuantizedArray(
-                q=cache_k.q.at[w_page, w_off].set(qk.q, mode="drop"),
-                scale=cache_k.scale.at[w_page, w_off].set(
-                    qk.scale.astype(cache_k.scale.dtype), mode="drop"
-                ),
-            )
-            cache_v = QuantizedArray(
-                q=cache_v.q.at[w_page, w_off].set(qv.q, mode="drop"),
-                scale=cache_v.scale.at[w_page, w_off].set(
-                    qv.scale.astype(cache_v.scale.dtype), mode="drop"
-                ),
-            )
-            k_all = dequantize(paged_view(cache_k, page_table))
-            v_all = dequantize(paged_view(cache_v, page_table))
+            cache_k = kv_map(write, cache_k, quantize(k, axis=-1))
+            cache_v = kv_map(write, cache_v, quantize(v, axis=-1))
+            k_all = dequantize(paged_view(cache_k, page_table, layer))
+            v_all = dequantize(paged_view(cache_v, page_table, layer))
         else:
-            cache_k = cache_k.at[w_page, w_off].set(k, mode="drop")
-            cache_v = cache_v.at[w_page, w_off].set(v, mode="drop")
-            k_all = paged_view(cache_k, page_table)
-            v_all = paged_view(cache_v, page_table)
+            cache_k, cache_v = write(cache_k, k), write(cache_v, v)
+            k_all = paged_view(cache_k, page_table, layer)
+            v_all = paged_view(cache_v, page_table, layer)
         kv_len = cache_len + s
         q_offset = cache_len
         k_positions = None
@@ -566,11 +567,12 @@ def _layer(
     ring: bool = False,
     lora_idx: Optional[jnp.ndarray] = None,
     page_table: Optional[jnp.ndarray] = None,
+    layer: Optional[jnp.ndarray] = None,
 ):
     x, new_cache = attention_block(
         x, layer_params, cfg, positions, cache_k, cache_v, cache_len,
         use_flash=use_flash, flash_mesh=flash_mesh, attn_impl=attn_impl,
-        ring=ring, lora_idx=lora_idx, page_table=page_table,
+        ring=ring, lora_idx=lora_idx, page_table=page_table, layer=layer,
     )
 
     # SwiGLU MLP
@@ -604,13 +606,17 @@ def forward(
     `lora_idx`: [B] per-row adapter ids when `params["layers"]` carries
     stacked LoRA factors (ops/lora.py); None or absent factors = base.
 
-    A `PagedKVCache` (batching.paged_kv) threads through identically —
-    k/v are the page arena and the block table rides scan-invariant
-    into every layer's attention (attention_block `page_table`).
+    A `PagedKVCache` (batching.paged_kv) is the one cache the layer
+    scan CARRIES instead of scanning in and stacking out: the whole
+    [L, N, P, KVH, Dh] arena rides the carry beside `x`, each layer
+    scatters and gathers it at [layer, page, offset]
+    (attention_block `page_table` / `layer`), and XLA updates it in
+    place — a decode step moves the step's own K/V and the gathered
+    views, never the arena or a layer's plane. The block table rides
+    scan-invariant.
 
     Returns (logits [B, S, V], updated cache or None).
     """
-    paged = isinstance(cache, PagedKVCache)
     b, s = tokens.shape
     x = embed_lookup(params["embed"], tokens, cfg.jnp_dtype)  # [B, S, D]
 
@@ -633,6 +639,24 @@ def forward(
 
         x, _ = jax.lax.scan(body, x, layers)
         new_cache = None
+    elif isinstance(cache, PagedKVCache):
+
+        def body(carry, scanned):
+            x, ck, cv = carry
+            layer_params, layer = scanned
+            x, (ck, cv) = _layer(
+                x, layer_params, cfg, positions, ck, cv, cache.length,
+                use_flash=use_flash, flash_mesh=flash_mesh,
+                attn_impl=attn_impl, ring=ring, lora_idx=lora_idx,
+                page_table=cache.table, layer=layer,
+            )
+            return (x, ck, cv), None
+
+        (x, new_k, new_v), _ = jax.lax.scan(
+            body, (x, cache.k, cache.v),
+            (layers, jnp.arange(cache.k.shape[0])),
+        )
+        new_cache = cache._replace(k=new_k, v=new_v, length=cache.length + s)
     else:
 
         def body(x, scanned):
@@ -641,18 +665,11 @@ def forward(
                 x, layer_params, cfg, positions, ck, cv, cache.length,
                 use_flash=use_flash, flash_mesh=flash_mesh,
                 attn_impl=attn_impl, ring=ring, lora_idx=lora_idx,
-                page_table=cache.table if paged else None,
             )
             return x, (ck, cv)
 
         x, (new_k, new_v) = jax.lax.scan(body, x, (layers, cache.k, cache.v))
-        if paged:
-            new_cache = PagedKVCache(
-                k=new_k, v=new_v, table=cache.table,
-                length=cache.length + s,
-            )
-        else:
-            new_cache = KVCache(k=new_k, v=new_v, length=cache.length + s)
+        new_cache = KVCache(k=new_k, v=new_v, length=cache.length + s)
 
     x = common.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["lm_head"]
