@@ -879,6 +879,11 @@ class ServingConfig:
     # scales — halves KV HBM and the per-step KV bandwidth, doubling
     # context/slot headroom; decode attention takes the XLA path so
     # the cast+scale fuse into the matmuls). Composes with `quantize`.
+    # "fp8": float8_e4m3fn storage without scales, for the latent
+    # family's cache only (models/mla_moe.py; refused for the others
+    # in engine._UNSUPPORTED): half the arena's bytes again, for twice
+    # the sessions or context, at 4 significant bits. What that costs
+    # in accuracy, as read on the chip: docs/paged_kv.md.
     kv_cache_dtype: str = ""
     # Benchmark staging: initialize the int8-quantized weight structure
     # DIRECTLY with synthetic values (random int8 + small scales)
@@ -1590,10 +1595,10 @@ class Config:
                 f"unknown serving.quantize {self.serving.quantize!r}; "
                 f"supported: 'int8'"
             )
-        if self.serving.kv_cache_dtype not in QUANTIZE_MODES:
+        if self.serving.kv_cache_dtype not in (*QUANTIZE_MODES, "fp8"):
             raise ValueError(
                 f"unknown serving.kv_cache_dtype "
-                f"{self.serving.kv_cache_dtype!r}; supported: 'int8'"
+                f"{self.serving.kv_cache_dtype!r}; supported: 'int8', 'fp8'"
             )
         if self.serving.synthetic_weights:
             if self.serving.quantize != "int8":

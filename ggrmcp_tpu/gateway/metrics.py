@@ -148,6 +148,21 @@ _SERVING_HELP = {
         "over calls that returned a result",
     "rpc_generate_ms_count":
         "unary Generate calls that returned a result",
+    "moe_experts_hit":
+        "distinct experts a valid token reached, summed over expert "
+        "layers and decode steps (latent-attention family)",
+    "moe_load_max_sum":
+        "largest routed-pair load of one expert, summed over expert "
+        "layers and decode steps",
+    "moe_routed_pairs":
+        "routed (token, expert) pairs the decode ticks computed",
+    "moe_layer_steps":
+        "(expert layer, decode step) instances the moe_* sums cover",
+    "prefill_tokens_computed":
+        "prompt tokens the admission programs computed",
+    "prefill_tokens_reused":
+        "prompt tokens admissions took from shared pages or a prefix "
+        "entry instead of computing them",
     # Disaggregated prefill/decode serving (serving.role): the
     # sidecar→sidecar KV page-shipping plane. The role itself is a
     # string field and exports info-style beside mesh_shape.

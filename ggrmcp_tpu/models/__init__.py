@@ -1,37 +1,44 @@
 """Model registry: name → (family, config).
 
 The serving sidecar resolves `ServingConfig.model` here. Families:
-"llama" (dense generation), "moe" (sparse-MoE generation, served by the
-same engine), and "bert" (embeddings).
+"llama" (dense generation), "moe" (Mixtral-style sparse-MoE generation),
+"mla_moe" (latent attention + sigmoid-routed and shared experts), all
+three served by the same engine, and "bert" (embeddings).
 """
 
 from __future__ import annotations
 
 from typing import Any
 
-from ggrmcp_tpu.models import bert, llama, moe
+from ggrmcp_tpu.models import bert, llama, mla_moe, moe
+
+_FAMILIES = {"llama": llama, "moe": moe, "mla_moe": mla_moe, "bert": bert}
 
 
 def get_model(name: str) -> tuple[str, Any]:
-    if name in llama.CONFIGS:
-        return "llama", llama.CONFIGS[name]
-    if name in moe.CONFIGS:
-        return "moe", moe.CONFIGS[name]
-    if name in bert.CONFIGS:
-        return "bert", bert.CONFIGS[name]
+    for family, module in _FAMILIES.items():
+        if name in module.CONFIGS:
+            return family, module.CONFIGS[name]
     raise KeyError(
-        f"unknown model {name!r}; available: "
-        f"{sorted([*llama.CONFIGS, *moe.CONFIGS, *bert.CONFIGS])}"
+        f"unknown model {name!r}; available: {available_models()}"
     )
 
 
 def available_models() -> list[str]:
-    return sorted([*llama.CONFIGS, *moe.CONFIGS, *bert.CONFIGS])
+    return sorted(n for m in _FAMILIES.values() for n in m.CONFIGS)
 
 
 def family_module(cfg):
-    """The decoder family module (llama or moe) implementing the shared
-    init_params / param_specs / forward / cache_specs contract for
-    `cfg`. Single dispatch point — engines, trainers and the pipeline
-    all resolve the family here."""
+    """The decoder family module (llama, moe or mla_moe) implementing
+    the shared init_params / param_specs / forward / cache_specs
+    contract for `cfg`, told from the config's type. Single dispatch
+    point — engines, trainers and the pipeline all resolve the family
+    here."""
+    if isinstance(cfg, mla_moe.MlaMoeConfig):
+        return mla_moe
     return moe if isinstance(cfg, moe.MoEConfig) else llama
+
+
+def family_name(cfg) -> str:
+    module = family_module(cfg)
+    return next(n for n, m in _FAMILIES.items() if m is module)

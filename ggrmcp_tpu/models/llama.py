@@ -65,6 +65,14 @@ class LlamaConfig(common.ModelConfig):
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
 
+    @property
+    def kv_planes(self) -> tuple:
+        """(heads, width) of what one token keeps in the cache's K
+        plane and in its V plane. A family that caches something else
+        overrides this (models/mla_moe.py: one latent plane, an empty
+        V plane); every cache constructor sizes the planes from it."""
+        return ((self.num_kv_heads, self.head_dim),) * 2
+
 
 # Known configurations. llama3-8b mirrors the published Llama-3-8B
 # architecture (the BASELINE.md target model on v5e-8).
@@ -189,6 +197,28 @@ def activation_spec() -> P:
 # ---------------------------------------------------------------------------
 
 
+def _zero_planes(cfg: LlamaConfig, lead: tuple, kv_dtype: str) -> tuple:
+    """The K and the V plane of an empty cache, `lead + (heads, width)`
+    each as `cfg.kv_planes` sizes them; "int8" = values int8 with
+    per-position/head scales in the model dtype; "fp8" = plain
+    float8_e4m3fn planes (4 significant bits, no scales)."""
+    if kv_dtype not in ("", "int8", "fp8"):
+        raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
+    dtype = jnp.float8_e4m3fn if kv_dtype == "fp8" else cfg.jnp_dtype
+
+    def plane(heads_width):
+        shape = lead + tuple(heads_width)
+        if kv_dtype == "int8":
+            return QuantizedArray(
+                q=jnp.zeros(shape, jnp.int8),
+                scale=jnp.zeros(shape[:-1] + (1,), dtype),
+            )
+        return jnp.zeros(shape, dtype)
+
+    k_plane, v_plane = cfg.kv_planes
+    return plane(k_plane), plane(v_plane)
+
+
 class KVCache(NamedTuple):
     k: jnp.ndarray  # [L, B, S_max, KVH, Dh]
     v: jnp.ndarray  # [L, B, S_max, KVH, Dh]
@@ -201,25 +231,9 @@ class KVCache(NamedTuple):
         """kv_dtype "" = model dtype; "int8" = quantized KV (values
         int8, per-position/head scales in the model dtype — halves KV
         HBM and decode KV bandwidth; serving.kv_cache_dtype)."""
-        shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
-        dtype = cfg.jnp_dtype
-        if kv_dtype == "int8":
-            def leaf():
-                return QuantizedArray(
-                    q=jnp.zeros(shape, jnp.int8),
-                    scale=jnp.zeros(shape[:-1] + (1,), dtype),
-                )
-            return cls(
-                k=leaf(), v=leaf(),
-                length=jnp.zeros((batch,), jnp.int32),
-            )
-        if kv_dtype:
-            raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
-        return cls(
-            k=jnp.zeros(shape, dtype),
-            v=jnp.zeros(shape, dtype),
-            length=jnp.zeros((batch,), jnp.int32),
-        )
+        k, v = _zero_planes(
+            cfg, (cfg.num_layers, batch, max_len), kv_dtype)
+        return cls(k=k, v=v, length=jnp.zeros((batch,), jnp.int32))
 
 
 def cache_specs() -> KVCache:
@@ -257,22 +271,8 @@ class PagedKVCache(NamedTuple):
     ) -> "PagedKVCache":
         assert max_len % page_size == 0, "page_size must divide max_len"
         width = max_len // page_size
-        shape = (
-            cfg.num_layers, n_pages, page_size, cfg.num_kv_heads,
-            cfg.head_dim,
-        )
-        dtype = cfg.jnp_dtype
-        if kv_dtype == "int8":
-            def leaf():
-                return QuantizedArray(
-                    q=jnp.zeros(shape, jnp.int8),
-                    scale=jnp.zeros(shape[:-1] + (1,), dtype),
-                )
-            k, v = leaf(), leaf()
-        elif kv_dtype:
-            raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
-        else:
-            k, v = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+        k, v = _zero_planes(
+            cfg, (cfg.num_layers, n_pages, page_size), kv_dtype)
         return cls(
             k=k, v=v,
             table=jnp.full((batch, width), n_pages, jnp.int32),
@@ -300,7 +300,8 @@ def paged_view(arena, table: jnp.ndarray, layer: jnp.ndarray):
     (values + scales gather alike)."""
     def gather(a):
         v = a[layer, jnp.minimum(table, a.shape[1] - 1)]  # [B, W, P, ...]
-        return v.reshape(table.shape[0], -1, *a.shape[3:])
+        return v.reshape(  # sizes spelt out: a plane may be empty
+            table.shape[0], table.shape[1] * a.shape[2], *a.shape[3:])
 
     return kv_map(gather, arena)
 
@@ -310,7 +311,9 @@ def paged_view_layers(arena, table: jnp.ndarray):
     admission gathers): → [L, B, W·P, KVH, Dh]."""
     def gather(a):
         v = a[:, jnp.minimum(table, a.shape[1] - 1)]  # [L, B, W, P, ...]
-        return v.reshape(a.shape[0], table.shape[0], -1, *a.shape[3:])
+        return v.reshape(
+            a.shape[0], table.shape[0], table.shape[1] * a.shape[2],
+            *a.shape[3:])
 
     return kv_map(gather, arena)
 

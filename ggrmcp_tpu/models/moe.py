@@ -45,6 +45,7 @@ from ggrmcp_tpu.models.llama import (  # noqa: F401
     activation_spec,
     attention_block,
     cache_specs,
+    paged_cache_specs,
 )
 
 Params = common.Params

@@ -846,13 +846,15 @@ class ServiceDiscoverer:
         self,
         duration_ms: int = 1000,
         label: str = "",
-        timeout_s: float = 90.0,
+        timeout_s: float = 600.0,
     ) -> list[dict[str, Any]]:
         """Fan the sidecar DebugService.Profile capture out to every
         healthy backend — the POST /debug/profile body (per-backend
         server-side artifact paths). The timeout covers the capture
         window itself (the RPC blocks for duration_ms), with headroom
-        for profiler start/stop."""
+        for profiler start/stop: writing out a window full of
+        while-loop operations (a long admission's block walks) takes
+        the profiler far longer than the window lasted."""
         arguments: dict[str, Any] = {}
         if duration_ms:
             arguments["durationMs"] = int(duration_ms)

@@ -610,6 +610,27 @@ class ContinuousBatcher:
         # and parked slots never compete for expert capacity.
         self.fam = getattr(engine, "fam", llama_mod)
         self._is_moe = self.fam is not llama_mod
+        # What a family's module says its forward can do (the batcher
+        # asks the module, never which family it is). HEAD_AT_INDEX:
+        # the head at one position a row on request (`logit_idx`; a
+        # [16, 512, V] logits block is 4 GB at a 128k vocabulary).
+        # ROUTING_STATS: a step's routing counts (`with_stats`), which
+        # ride the tick's token array as three extra rows
+        # (_decode_scan) and are summed at collect time: experts hit,
+        # the largest load of an expert, routed pairs, each summed over
+        # expert layers and steps. DEEP_GRID_CHUNKS: a cold prompt of
+        # more chunks than this is a deep grid; its chunk count rounds
+        # up to a power of two and it is admitted alone
+        # (_route_admission, _admit_chunked_group). None: every grid
+        # keeps its exact depth and its group.
+        self._head_at_index = getattr(self.fam, "HEAD_AT_INDEX", False)
+        self._routing_stats = getattr(self.fam, "ROUTING_STATS", False)
+        self._deep_grid = getattr(self.fam, "DEEP_GRID_CHUNKS", None)
+        self.moe_counts = {"experts_hit": 0, "load_max": 0, "pairs": 0,
+                           "layer_steps": 0}
+        # Prompt tokens admission programs computed, and prompt tokens
+        # they took from pages or a prefix entry instead.
+        self.prefill_tokens = {"computed": 0, "reused": 0}
 
         # Prefix (prompt-KV) cache: pool entries shaped like mini-cache
         # rows so a hit is ONE dynamic_update_slice into the admission
@@ -1281,12 +1302,15 @@ class ContinuousBatcher:
         # Fresh prefill → engine.prefill_forward (handles MoE validity
         # and the sequence-parallel long-chunk path).
         valid = jnp.arange(s)[None, :] < true_len[:, None]
+        last = jnp.maximum(true_len - 1, 0)
         logits, mini = self.engine.prefill_forward(
-            params, tokens, mini, valid=valid, lora_idx=adapters
+            params, tokens, mini, valid=valid, lora_idx=adapters,
+            logit_idx=last if self._head_at_index else None,
         )
+        if self._head_at_index:  # logits [R, 1, V]: each row's last
+            last = jnp.zeros_like(last)
         first = self._first_token_impl(
-            logits, jnp.maximum(true_len - 1, 0), seeds, temps, ks, ps,
-            g0, g_allow, g_trans,
+            logits, last, seeds, temps, ks, ps, g0, g_allow, g_trans,
         )
         return first, mini
 
@@ -1357,11 +1381,14 @@ class ContinuousBatcher:
                 valid = (off + jnp.arange(c))[None, :] < true_len[:, None]
             else:
                 valid = None
+            idx = jnp.clip(last - off, 0, c - 1)
             logits, mini = self.engine.decode_forward(
                 params, chunk, mini, valid=valid, ring=self._ring,
                 lora_idx=adapters,
+                logit_idx=idx if self._head_at_index else None,
             )
-            idx = jnp.clip(last - off, 0, c - 1)
+            if self._head_at_index:  # logits [B, 1, V], at idx already
+                idx = jnp.zeros_like(idx)
             sel = jnp.take_along_axis(
                 logits, idx[:, None, None], axis=1
             )[:, 0]
@@ -1496,26 +1523,30 @@ class ContinuousBatcher:
         advances the per-row DFA state via a table gather — the
         constrained step never leaves the device (rows at state 0, the
         accept-all state, are numerically untouched). Returns
-        (toks [B, steps], cache, gstate_out [B])."""
+        (toks [B, steps], cache, gstate_out [B]); for the latent
+        family toks is [B + 3, steps], the last three rows each step's
+        routing counts (models/mla_moe.py::forward `with_stats`)."""
 
         def body(carry, i):
             cur, gs, cache = carry
-            logits, cache = self.engine.decode_forward(
+            logits, cache, *counts = self.engine.decode_forward(
                 params, cur[:, None], cache,
                 valid=active[:, None] if self._is_moe else None,
                 ring=self._ring,
                 lora_idx=adapters,
+                with_stats=self._routing_stats,
             )
             nxt, gs = masked_sample_dynamic(
                 logits[:, -1], seeds, step + i, temps, ks, ps,
                 gs, g_allow, g_trans,
             )
-            return (nxt, gs, cache), nxt
+            out = jnp.concatenate([nxt, *counts]) if counts else nxt
+            return (nxt, gs, cache), out
 
         (_, gstate, cache), toks = jax.lax.scan(
             body, (tokens, gstate, cache), jnp.arange(self._steps_per_tick)
         )
-        return toks.T, cache, gstate  # [B, steps_per_tick], ..., [B]
+        return toks.T, cache, gstate  # [B (+3), steps_per_tick], ..., [B]
 
     def _tick_impl(
         self, params, tokens, cache, seeds, step, temps, ks, ps, active,
@@ -3092,6 +3123,19 @@ class ContinuousBatcher:
             "loop_lag_ms_count": self.loop_calls,
             "loop_host_ms_sum": self.loop_ms["host"],
             "loop_busy_ms_sum": sum(self.loop_ms.values()),
+            # Expert routing of the decode ticks (the latent-attention
+            # family; 0 elsewhere), each summed over expert layers and
+            # decode steps: distinct experts a valid token reached,
+            # the largest load of one expert, routed pairs computed,
+            # and the (layer, step) count that divides them.
+            "moe_experts_hit": self.moe_counts["experts_hit"],
+            "moe_load_max_sum": self.moe_counts["load_max"],
+            "moe_routed_pairs": self.moe_counts["pairs"],
+            "moe_layer_steps": self.moe_counts["layer_steps"],
+            # Prompt tokens the admission programs computed, against
+            # prompt tokens taken from shared pages or a prefix entry.
+            "prefill_tokens_computed": self.prefill_tokens["computed"],
+            "prefill_tokens_reused": self.prefill_tokens["reused"],
         }
 
     # -- the loop -----------------------------------------------------------
@@ -3838,11 +3882,14 @@ class ContinuousBatcher:
         # tick record's admit phase (queue drain + admission prefill
         # belong to the tick window they precede).
         self._admit_phase_ms += dt
+        prompt_tokens = sum(len(r.prompt) for r in batch)
+        self.prefill_tokens["reused"] += self._adm_reused
+        self.prefill_tokens["computed"] += prompt_tokens - self._adm_reused
         self.recorder.note_admission(
             timer, "+".join(self._adm_families),
             [r.trace_id for r in batch if r.trace_id],
             rows=len(batch),
-            prompt_tokens=sum(len(r.prompt) for r in batch),
+            prompt_tokens=prompt_tokens,
             reused_tokens=self._adm_reused,
             tick_seq=tick_seq, seq=seq,
         )
@@ -4006,7 +4053,26 @@ class ContinuousBatcher:
                 fused_slots.append(sl)
                 fused_batch.append(req)
         if long_rows:
-            self._admit_chunked_group(long_rows)
+            # A family with deep grids (`_deep_grid`) admits a prompt
+            # past that many chunks one row a call, in arrival order: a
+            # group's rows all finish with its deepest, so whether two
+            # long prompts that arrived a millisecond apart were popped
+            # together or not would move the first one's first token by
+            # a whole prefill (and, in a closed loop, every later
+            # admission with it). Shallow prompts, and every prompt of
+            # a family without deep grids, share one [R, T, C] call.
+            c = min(self.cfg.prefill_chunk, self.max_seq)
+            deep = [
+                self._deep_grid is not None
+                and len(req.prompt) > self._deep_grid * c
+                for _, req in long_rows
+            ]
+            shallow = [row for row, d in zip(long_rows, deep) if not d]
+            if shallow:
+                self._admit_chunked_group(shallow)
+            for row, d in zip(long_rows, deep):
+                if d:
+                    self._admit_chunked_group([row])
         for (entry, start, width), group in pfx_groups.items():
             self._admit_chunked_group(group, pfx=(entry, start, width))
         if fused_batch:
@@ -4065,6 +4131,14 @@ class ContinuousBatcher:
             c = min(self.cfg.prefill_chunk, self.max_seq)
             n_max = max(len(req.prompt) for _, req in rows)
             t_steps = max(1, -(-n_max // c))
+            if self._deep_grid is not None and t_steps > self._deep_grid:
+                # Deep grids round up to a power of two: a long-context
+                # deployment compiles log2(S_max / chunk) programs, not
+                # one for every chunk count. The padding chunks run
+                # with no valid token, which the family's attention
+                # walk skips.
+                t_steps = min(
+                    bucket_len(t_steps, minimum=1), -(-self.max_seq // c))
             start = 0
             r = min(b, bucket_len(len(rows), minimum=1))
         else:
@@ -4342,7 +4416,7 @@ class ContinuousBatcher:
         # Device-side feedback for the next tick; no host sync. Grammar
         # state rides the same way: the scan's final per-row states
         # feed the next dispatch without materializing.
-        self._cur_dev = toks[:, -1]
+        self._cur_dev = toks[:len(self.slots), -1]
         self._gstate_dev = gstate_out
         try:
             toks.copy_to_host_async()
@@ -4524,7 +4598,7 @@ class ContinuousBatcher:
             jnp.asarray(c_tl), jnp.asarray(c_valid), jnp.asarray(c_adapt),
             self._gstate_dev, g_allow, g_trans,
         )
-        self._cur_dev = toks[:, -1]
+        self._cur_dev = toks[:len(self.slots), -1]
         self._gstate_dev = gstate_out
         try:
             toks.copy_to_host_async()
@@ -4653,6 +4727,13 @@ class ContinuousBatcher:
         # tick's forced-run length + 1; None on plain ticks): emission
         # truncates to it.
         counts = None if counts_dev is None else np.asarray(counts_dev)
+        if self._routing_stats and counts is None:
+            extra = toks[len(self.slots):]  # [3, steps]: hit, max, pairs
+            for name, row in zip(("experts_hit", "load_max", "pairs"), extra):
+                self.moe_counts[name] += int(row.sum())
+            self.moe_counts["layer_steps"] += (
+                extra.shape[1] * self.engine.cfg.num_expert_layers)
+            toks = toks[:len(self.slots)]
         if rec is not None:
             # Everything since the dispatch mark was in-flight wait:
             # device compute + transfer, plus the deliberate one-tick

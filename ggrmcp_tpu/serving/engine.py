@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ggrmcp_tpu.core.config import ServingConfig
 from ggrmcp_tpu.models import bert as bert_mod
 from ggrmcp_tpu.models import llama as llama_mod
+from ggrmcp_tpu.models import mla_moe as mla_moe_mod
 from ggrmcp_tpu.models import moe as moe_mod
 from ggrmcp_tpu.models.common import count_params
 from ggrmcp_tpu.ops import quant
@@ -116,6 +117,56 @@ def _sharded_init(init_fn, specs, mesh: Mesh, key, observer=None):
     return params
 
 
+# What a family cannot be composed with yet, and why. The ONE place a
+# composition is refused by family (GenerationEngine._check_family,
+# run first in the constructor); the dense llama family has every
+# feature but the float8 cache.
+_MOE_ROUTING = (
+    "its capacity dispatch is batch-global, so a row's output depends "
+    "on the other rows"
+)
+_LATENT_CACHE = (
+    "these paths move K/V pairs of [kv_heads, head_dim]; this family "
+    "caches one latent plane"
+)
+_FP8_CACHE = (
+    "a float8 plane is read by the latent family's block walk only; "
+    "these families' attention reads int8 pages through their scales"
+)
+_UNSUPPORTED = {
+    "llama": {"kv_cache_dtype fp8": _FP8_CACHE},
+    "moe": {
+        "kv_cache_dtype fp8": _FP8_CACHE,
+        "lora": "the adapter delta sits on the dense family's fused qkv",
+        "pipeline-parallel serving (mesh.stage > 1)": _MOE_ROUTING,
+        "speculative decoding (speculative_draft)": (
+            _MOE_ROUTING + ", which breaks lossless verification"
+        ),
+        "batching.paged_kv": (
+            "its forward scans the cache in and out a layer and has no "
+            "block-table path; the dropless mla_moe family has"
+        ),
+    },
+    "mla_moe": {
+        "lora": "the adapter delta sits on the dense family's fused qkv",
+        "pipeline-parallel serving (mesh.stage > 1)": (
+            "the staged forward runs one homogeneous layer stack"
+        ),
+        "speculative decoding (speculative_draft)": (
+            "the verify window would have to rewind latent pages"
+        ),
+        "kv_ring": "the model has no sliding window",
+        "batching.kv_tiers": _LATENT_CACHE,
+        "batching.paged_kv_host_bytes (the host tier)": _LATENT_CACHE,
+        "a non-mixed serving.role (KV export/import)": _LATENT_CACHE,
+        "quantize / synthetic_weights": (
+            "the weights are served in bf16; int8 matmuls are wired "
+            "into the dense family's projections only"
+        ),
+    },
+}
+
+
 class GenerationEngine:
     """Decoder-family generation (dense Llama or sparse MoE): prefill +
     decode + fused generate. The family module supplies init_params /
@@ -134,6 +185,7 @@ class GenerationEngine:
         self.cfg = cfg
         self.fam = family_module(cfg)
         self.serving = serving or ServingConfig()
+        self._check_family(mesh)
         if self.serving.failpoints:
             # Deterministic fault injection (utils/failpoints.py):
             # config-armed here, at the serving plane's root, so every
@@ -419,8 +471,6 @@ class GenerationEngine:
                 "lora.registry and lora.adapters are mutually exclusive "
                 "(config.validate mirrors this)"
             )
-        if self.fam is not llama_mod:
-            raise ValueError("lora serving supports dense Llama only")
         if self.pp_serving:
             raise ValueError(
                 "lora does not compose with pipeline-parallel serving "
@@ -635,8 +685,54 @@ class GenerationEngine:
 
         self._sp_attn = sp_attn
 
+    def _check_family(self, mesh) -> None:
+        """Refuse, by name, every composition this model's family does
+        not have (`_UNSUPPORTED`)."""
+        from ggrmcp_tpu.models import family_name
+
+        family = family_name(self.cfg)
+        refused = _UNSUPPORTED.get(family)
+        if not refused:
+            return
+        sv, bt = self.serving, self.serving.batching
+        stages = (
+            mesh.shape.get("stage", 1) if mesh is not None
+            else max(1, int(getattr(sv.mesh, "stage", 1) or 1))
+        )
+        asked = {
+            "lora": bool(sv.lora.adapters)
+            or bool(getattr(sv.lora, "registry", "")),
+            "pipeline-parallel serving (mesh.stage > 1)": stages > 1,
+            "speculative decoding (speculative_draft)": bool(
+                sv.speculative_draft),
+            "kv_ring": bool(getattr(sv, "kv_ring", False)),
+            "batching.kv_tiers": bool(bt.kv_tiers),
+            "batching.paged_kv_host_bytes (the host tier)": bool(
+                bt.paged_kv_host_bytes),
+            "a non-mixed serving.role (KV export/import)": getattr(
+                sv, "role", "mixed") != "mixed",
+            "quantize / synthetic_weights": bool(sv.quantize)
+            or bool(sv.synthetic_weights),
+            "kv_cache_dtype fp8": sv.kv_cache_dtype == "fp8",
+        }
+        for feature in refused:
+            if asked.get(feature):
+                self._refuse(feature)
+
+    def _refuse(self, feature: str) -> None:
+        """Raise if `_UNSUPPORTED` lists `feature` for this family."""
+        from ggrmcp_tpu.models import family_name
+
+        family = family_name(self.cfg)
+        why = _UNSUPPORTED.get(family, {}).get(feature)
+        if why:
+            raise ValueError(
+                f"{feature} is not supported for the {family} family "
+                f"(model {self.cfg.name}): {why}"
+            )
+
     def prefill_forward(self, params, tokens, cache, valid=None,
-                        lora_idx=None):
+                        lora_idx=None, logit_idx=None):
         """fam.forward for FRESH prefill (cache written from offset 0 —
         the attn_impl contract, models/llama.py::attention_block).
         Dispatches to the sequence-parallel path when configured and
@@ -659,7 +755,8 @@ class GenerationEngine:
                 lora_idx=lora_idx,
             )
         return self.decode_forward(
-            params, tokens, cache, valid=valid, lora_idx=lora_idx
+            params, tokens, cache, valid=valid, lora_idx=lora_idx,
+            logit_idx=logit_idx,
         )
 
     def _init_pp_serving(self) -> None:
@@ -672,12 +769,7 @@ class GenerationEngine:
 
         self._pp = pp_mod
         self._pp_n = mesh_mod.axis_size(self.mesh, "stage")
-        self.pp_serving = self._pp_n > 1 and self.fam is llama_mod
-        if self._pp_n > 1 and self.fam is not llama_mod:
-            raise ValueError(
-                "pipeline-parallel serving supports dense Llama only "
-                "(MoE expert dispatch is batch-global per layer block)"
-            )
+        self.pp_serving = self._pp_n > 1  # llama only: _check_family
         if self.pp_serving and self.cfg.num_layers % self._pp_n != 0:
             raise ValueError(
                 f"{self.cfg.num_layers} layers not divisible by "
@@ -691,7 +783,8 @@ class GenerationEngine:
             self._sp_attn = None
 
     def decode_forward(
-        self, params, tokens, cache, valid=None, ring=False, lora_idx=None
+        self, params, tokens, cache, valid=None, ring=False, lora_idx=None,
+        logit_idx=None, with_stats=False,
     ):
         """fam.forward for decode/extension steps (cache already has
         history). Dispatches to the staged path under PP. `ring` is
@@ -699,10 +792,19 @@ class GenerationEngine:
         ring-capacity caches), not the engine: the engine's own
         contiguous request-sized caches keep ring=False. `lora_idx`:
         [B] per-row adapter ids (dense Llama, non-PP — the engine
-        rejects LoRA configs elsewhere)."""
+        rejects LoRA configs elsewhere). `logit_idx` / `with_stats`:
+        the mla_moe family's one-position head and routing counts
+        (models/mla_moe.py::forward); callers pass them to that family
+        only, which takes no `ring` and no `lora_idx` (_check_family
+        refuses both)."""
         if self.pp_serving:
             return self._pp.pipeline_forward_cached(
                 params, self.cfg, tokens, cache, self.mesh, ring=ring
+            )
+        if self.fam is mla_moe_mod:
+            return self.fam.forward(
+                params, self.cfg, tokens, cache, valid=valid,
+                logit_idx=logit_idx, with_stats=with_stats,
             )
         if self.fam is moe_mod:
             return self.fam.forward(
@@ -731,12 +833,6 @@ class GenerationEngine:
                 "speculative decoding is not supported under "
                 "pipeline-parallel serving (the draft/verify loop would "
                 "run the layer scan against stage-sharded weights)"
-            )
-        if self.fam is moe_mod:
-            raise ValueError(
-                "speculative decoding supports dense decoder targets "
-                "only (MoE routing is batch-global, which breaks the "
-                "lossless verification guarantee)"
             )
         family, dcfg = models_mod.get_model(self.serving.speculative_draft)
         if family != "llama":
@@ -1005,22 +1101,20 @@ class GenerationEngine:
         other = cfg is not None
         cfg = cfg or self.cfg
         fam = fam or self.fam
-        kv_shape = (
-            cfg.num_layers, batch, max_len,
-            cfg.num_kv_heads, cfg.head_dim,
-        )
+        lead = (cfg.num_layers, batch, max_len)
         specs = (
             self._pp.cache_specs_pp() if self.pp_serving and not other
             else fam.cache_specs()
         )
-        scale_shape = kv_shape[:-1] + (1,)
         observe = partial(self._observe_cache_spec, "kv_cache")
 
-        def kv_spec(spec):
+        def kv_spec(spec, plane):
+            kv_shape = lead + tuple(plane)
+            scale_shape = kv_shape[:-1] + (1,)
             adapted = mesh_mod.compatible_spec(
                 spec, kv_shape, self.mesh, on_downgrade=observe
             )
-            if not self.kv_dtype:
+            if self.kv_dtype != "int8":
                 return adapted
             # Quantized leaf: the scale tree mirrors the values
             # (quantize_specs pattern); its size-1 last axis drops any
@@ -1030,9 +1124,10 @@ class GenerationEngine:
                 scale=mesh_mod.compatible_spec(spec, scale_shape, self.mesh),
             )
 
+        k_plane, v_plane = cfg.kv_planes
         specs = llama_mod.KVCache(
-            k=kv_spec(specs.k),
-            v=kv_spec(specs.v),
+            k=kv_spec(specs.k, k_plane),
+            v=kv_spec(specs.v, v_plane),
             length=mesh_mod.compatible_spec(specs.length, (batch,), self.mesh),
         )
         with self.mesh:
@@ -1052,28 +1147,26 @@ class GenerationEngine:
         """Mesh-sharded paged KV arena + block tables (batching.paged_kv,
         docs/paged_kv.md). Pages shard heads over `tensor` only — a page
         is shared across slots, so the page axis cannot ride a batch
-        axis. Dense-Llama, non-PP serving only (the batcher validates;
-        the staged forward doesn't thread block tables)."""
+        axis. What a page holds is the family's (`cfg.kv_planes`): K/V
+        of [kv_heads, head_dim], or one latent plane. Non-PP serving
+        only (the staged forward doesn't thread block tables)."""
         if self.pp_serving:
             raise ValueError(
                 "paged_kv does not compose with pipeline-parallel "
                 "serving (the staged forward has no block-table path)"
             )
-        if self.fam is not llama_mod:
-            raise ValueError("paged_kv supports dense Llama only")
-        kv_shape = (
-            self.cfg.num_layers, n_pages, page_size,
-            self.cfg.num_kv_heads, self.cfg.head_dim,
-        )
-        scale_shape = kv_shape[:-1] + (1,)
-        raw = llama_mod.paged_cache_specs()
+        self._refuse("batching.paged_kv")  # asked for by the batcher
+        lead = (self.cfg.num_layers, n_pages, page_size)
+        raw = self.fam.paged_cache_specs()
         observe = partial(self._observe_cache_spec, "paged_kv_arena")
 
-        def kv_spec(spec):
+        def kv_spec(spec, plane):
+            kv_shape = lead + tuple(plane)
+            scale_shape = kv_shape[:-1] + (1,)
             adapted = mesh_mod.compatible_spec(
                 spec, kv_shape, self.mesh, on_downgrade=observe
             )
-            if not self.kv_dtype:
+            if self.kv_dtype != "int8":
                 return adapted
             return quant.QuantizedArray(
                 q=adapted,
@@ -1082,8 +1175,9 @@ class GenerationEngine:
                 ),
             )
 
+        k_plane, v_plane = self.cfg.kv_planes
         specs = llama_mod.PagedKVCache(
-            k=kv_spec(raw.k), v=kv_spec(raw.v),
+            k=kv_spec(raw.k, k_plane), v=kv_spec(raw.v, v_plane),
             table=raw.table, length=raw.length,
         )
         with self.mesh:
@@ -1300,7 +1394,9 @@ class GenerationEngine:
                 self.adapter_arena.release(lease)
 
     def model_info(self) -> dict:
-        return _model_info(self, "moe" if self.fam is moe_mod else "llama")
+        from ggrmcp_tpu.models import family_name
+
+        return _model_info(self, family_name(self.cfg))
 
 
 class EmbeddingEngine:

@@ -101,7 +101,7 @@ class Sidecar:
                 params = self._restore_params(model_cfg, family, mesh)
         self.family = family
         self.spec_batcher = None
-        if family in ("llama", "moe"):
+        if family != "bert":  # every decoder family
             self.generation = GenerationEngine(
                 model_cfg, self.serving, mesh=mesh, params=params
             )
@@ -205,7 +205,7 @@ class Sidecar:
             params = restore(path)
             logger.info("restored params from %s (host-side; PP mesh)", path)
             return params
-        if family in ("llama", "moe"):
+        if family != "bert":  # every decoder family
             from ggrmcp_tpu.models import family_module
 
             fam = family_module(model_cfg)

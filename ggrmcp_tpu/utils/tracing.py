@@ -177,7 +177,12 @@ def profile_capture(duration_ms: float, output_dir: Optional[str] = None) -> str
     import jax
 
     out = output_dir or tempfile.mkdtemp(prefix="ggrmcp-profile-")
-    jax.profiler.start_trace(out)
+    # No Python call tracer: under it every call of every thread is an
+    # event (most of a capture's host events, written out while the
+    # process serves). The host's side is the annotation() spans.
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(out, profiler_options=options)
     capture_running = True
     try:
         time.sleep(max(duration_ms, 0) / 1000.0)

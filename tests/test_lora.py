@@ -118,7 +118,7 @@ class TestEngineLora:
             )
 
     def test_gates(self):
-        with pytest.raises(ValueError, match="dense Llama"):
+        with pytest.raises(ValueError, match="lora is not supported for the moe family"):
             from ggrmcp_tpu.models import moe
 
             GenerationEngine(

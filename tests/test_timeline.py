@@ -575,7 +575,9 @@ class TestTraceAnnotation:
         seen: list = []
         monkeypatch.setattr(
             jax.profiler, "start_trace",
-            lambda out: seen.append(("start", tracing.capture_running)),
+            lambda out, profiler_options: seen.append(
+                ("start", tracing.capture_running)
+                if profiler_options.python_tracer_level == 0 else "python traced"),
         )
         monkeypatch.setattr(
             jax.profiler, "stop_trace",
