@@ -16,7 +16,10 @@ next starts (this parent never imports JAX or the package):
 
   kernel      flash_attention (compiled Pallas) against attention_xla at
               the serving shapes; on >= 4 devices also under
-              flash_attention_sharded over tensor=4.
+              flash_attention_sharded over tensor=4. Then
+              paged_decode_attention against the gathered view
+              (paged_view + attention_xla) at mistral's widths: 8 rows,
+              page 16, mixed lengths, a freed slot.
   serve       mistral-7b int8 synthetic weights, paged KV, one chip:
               tools/list, greedy generate (twice: same ids), SSE
               generatestream, a >= 1,024-token prompt, a second prompt
@@ -141,7 +144,10 @@ def kernel_leg_child(rehearsal: bool) -> None:
     ulp at |x| <= 1 is 2e-3). The sum over up to 4,096 keys of weight
     roundings of relative size 2^-9 stays an order below 2e-2; a wrong
     mask, offset or block skip moves outputs by O(0.1-1). float32 (the
-    interpreted rehearsal): 2e-3, as tests/test_models.py."""
+    interpreted rehearsal): 2e-3, as tests/test_models.py. The same
+    tolerance holds paged_decode_attention to the gathered view: it
+    rounds the weights to bf16 as attention_xla does, in another order
+    of blocks."""
     from ggrmcp_tpu.utils.jaxenv import init_runtime
 
     init_runtime("chip_smoke kernel leg")
@@ -150,10 +156,13 @@ def kernel_leg_child(rehearsal: bool) -> None:
     import numpy as np
 
     from ggrmcp_tpu.core.config import MeshConfig
+    from ggrmcp_tpu.models.llama import paged_view
     from ggrmcp_tpu.ops.attention import (
         attention_xla,
         flash_attention,
         flash_attention_sharded,
+        paged_decode_attention,
+        paged_decode_attention_sharded,
     )
     from ggrmcp_tpu.parallel import mesh as mesh_mod
 
@@ -186,7 +195,7 @@ def kernel_leg_child(rehearsal: bool) -> None:
         check(bool(np.isfinite(out).all()), f"{name}: non-finite output")
         err = float(np.abs(out - ref).max())
         bad = np.abs(out - ref) > tol + tol * np.abs(ref)
-        say(f"  {name}: max|flash - xla| = {err:.2e} (tolerance {tol:g})")
+        say(f"  {name}: max|kernel - xla| = {err:.2e} (tolerance {tol:g})")
         check(not bad.any(), f"{name}: {int(bad.sum())} elements beyond "
               f"tolerance {tol:g}, max error {err:.3e}")
 
@@ -215,6 +224,63 @@ def kernel_leg_child(rehearsal: bool) -> None:
                 )
             )(q, k, v, qo, kl)
             compare(f"sharded tensor=4 window={window}", out, ref)
+
+    # The decode tick's read: each row's own pages out of the whole
+    # arena against the gathered full-width view. Lengths on both sides
+    # of a page and of a block of the walk, a short row, a full one,
+    # and a freed slot (length kept, table unmapped), whose output the
+    # batcher drops: it is left out of the comparison.
+    if rehearsal:
+        layers, rows, page, width, sq = 2, 4, 8, 8, 1
+        lens, freed, windows = [5, 17, 64, 30], 3, (None, 12)
+    else:
+        layers, rows, page, width, sq = 4, 8, 16, 128, 1
+        lens = [1, 16, 129, 300, 1100, 2048, 1801, 700]
+        freed, windows = 7, (None, 4096, 512)
+    n_pages = rows * width
+    ka = jax.random.normal(
+        jax.random.fold_in(key, 3), (layers, n_pages, page, kvh, d), dtype)
+    va = jax.random.normal(
+        jax.random.fold_in(key, 4), (layers, n_pages, page, kvh, d), dtype)
+    qd = jax.random.normal(
+        jax.random.fold_in(key, 5), (rows, sq, h, d), dtype)
+    table = np.random.default_rng(0).permutation(n_pages).reshape(
+        rows, width)
+    table[freed] = n_pages
+    table = jnp.asarray(table, jnp.int32)
+    kl = jnp.asarray(lens, jnp.int32)
+    layer = jnp.int32(layers - 1)
+    live = np.arange(rows) != freed
+    for window in windows:
+        ref = jax.jit(
+            lambda q, ka, va, t, kl, ly, w=window: attention_xla(
+                q, paged_view(ka, t, ly), paged_view(va, t, ly),
+                causal=True, q_offset=kl - sq, kv_len=kl, window=w,
+            )
+        )(qd, ka, va, table, kl, layer)
+        t0 = time.monotonic()
+        out = paged_decode_attention(
+            qd, ka, va, table, kl, layer, window=window,
+            interpret=rehearsal,
+        )
+        jax.block_until_ready(out)
+        say(f"  paged_decode_attention window={window}: compiled and ran "
+            f"in {time.monotonic() - t0:.1f} s (set-up, {dev.device_kind})")
+        name = (f"paged decode q[{rows},{sq},{h},{d}] arena[{layers},"
+                f"{n_pages},{page},{kvh},{d}] window={window}")
+        compare(name, np.asarray(out)[live], np.asarray(ref)[live])
+        check(not np.asarray(out, np.float32)[freed].any(),
+              f"{name}: the freed slot's output is not zero")
+        if len(devices) >= 4:
+            out = jax.jit(
+                lambda q, ka, va, t, kl, ly, w=window:
+                paged_decode_attention_sharded(
+                    q, ka, va, t, kl, ly, mesh, window=w,
+                    interpret=rehearsal,
+                )
+            )(qd, ka, va, table, kl, layer)
+            compare(f"paged decode sharded tensor=4 window={window}",
+                    np.asarray(out)[live], np.asarray(ref)[live])
     print("LEG_RESULT " + json.dumps({
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices),
@@ -222,7 +288,8 @@ def kernel_leg_child(rehearsal: bool) -> None:
 
 
 def run_kernel_leg(rehearsal: bool) -> dict:
-    say("== leg kernel: flash_attention vs attention_xla on the device")
+    say("== leg kernel: flash_attention and paged_decode_attention vs "
+        "attention_xla on the device")
     cmd = [sys.executable, os.path.abspath(__file__), "--child-kernel"]
     if rehearsal:
         cmd.append("--cpu-rehearsal")

@@ -106,7 +106,8 @@ _SERVING_HELP = {
     "mesh_spec_downgrades":
         "sharding specs downgraded to replication (0 = true TP serving)",
     "attn_kernel_programs":
-        "traced programs with the Pallas prefill kernel in them",
+        "traced programs with a Pallas attention kernel in them "
+        "(prefill, paged decode)",
     "attn_kernel_fallbacks":
         "traced programs that wanted the Pallas kernel and took XLA "
         "(shapes did not shard over the mesh)",

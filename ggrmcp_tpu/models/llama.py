@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ggrmcp_tpu.models import common
-from ggrmcp_tpu.ops.attention import attention
+from ggrmcp_tpu.ops.attention import attention, paged_decode
 from ggrmcp_tpu.ops.quant import (
     QuantizedArray,
     dequantize,
@@ -255,9 +255,11 @@ class PagedKVCache(NamedTuple):
 
     Inside `forward` the arena is LOOP-CARRIED through the layer scan
     and updated in place, indexed [layer, page, offset]: one scatter
-    writes the step's K/V, one gather reads the slots' views, and no
-    layer's [n_pages, page, KVH, Dh] plane is ever sliced out or
-    stacked back (docs/paged_kv.md "Inside the jitted tick")."""
+    writes the step's K/V, then either the paged-decode kernel walks
+    each slot's pages in place (a decode-shaped step on the TPU) or
+    one gather reads the slots' views, and no layer's
+    [n_pages, page, KVH, Dh] plane is ever sliced out or stacked back
+    (docs/paged_kv.md "Inside the jitted tick")."""
 
     k: jnp.ndarray  # [L, n_pages, page, KVH, Dh] (or QuantizedArray)
     v: jnp.ndarray
@@ -350,16 +352,23 @@ def attention_block(
     layer's slice — and `layer` is this layer's index into it. One
     scatter indexed [layer, page, offset] writes the step's K/V through
     the table (position j → page table[b, j // P], offset j % P;
-    sentinel entries drop) and one gather indexed [layer, page] reads a
-    [B, W·P] view (`paged_view`), so the arena the caller carries
-    through its layer loop is updated in place and no layer's plane is
-    ever materialised. Positions, masks, and numerics are identical to
-    the contiguous cache, so paged-on/off greedy outputs are
-    bit-identical. The returned (cache_k, cache_v) are the whole arenas.
+    sentinel entries drop). The read is chosen by `ops.attention.
+    paged_decode` from platform, storage and query count: a
+    decode-shaped step (S <= 8) over a plain arena on the TPU runs the
+    paged-decode kernel, which walks each row's pages in place up to
+    its own length; anything else (int8/fp8 pages, a prefill chunk,
+    the CPU) does one gather indexed [layer, page] into a [B, W·P] view
+    (`paged_view`) for the XLA attention path. Either way the arena the
+    caller carries through its layer loop is updated in place and no
+    layer's plane is ever materialised. Positions and masks are
+    identical to the contiguous cache; on the gathered path (every CPU
+    run) so are the numerics, and paged-on/off greedy outputs are
+    bit-identical; the kernel sums the softmax block by block, so on
+    the chip its bf16 roundings differ, as the prefill kernel's do. The
+    returned (cache_k, cache_v) are the whole arenas.
     Shared (refcounted) pages are never written: the host allocator
     guarantees every write position ≥ the owner's prompt length lands
-    in pages it owns exclusively (serving/pages.py invariants). Paged
-    reads always take the XLA attention path.
+    in pages it owns exclusively (serving/pages.py invariants).
 
     `ring=True` (sliding-window serving): the cache's sequence dim is a
     RING of capacity C — writes land at `pos % C` and attention masks
@@ -398,6 +407,7 @@ def attention_block(
     q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
 
+    paged_out = k_all = v_all = None
     if cache_k is not None and page_table is not None:
         # Paged arena: scatter the step's K/V through the block table
         # into this layer's pages of the whole arena and attend a
@@ -438,15 +448,24 @@ def attention_block(
             v_all = dequantize(paged_view(cache_v, page_table, layer))
         else:
             cache_k, cache_v = write(cache_k, k), write(cache_v, v)
-            k_all = paged_view(cache_k, page_table, layer)
-            v_all = paged_view(cache_v, page_table, layer)
+            if attn_impl is None:
+                # A decode-shaped step on the TPU reads the written
+                # arena in place, each row's pages up to its own
+                # length; anything else (None) gathers the view.
+                paged_out = paged_decode(
+                    q, cache_k, cache_v, page_table, cache_len + s, layer,
+                    window=cfg.sliding_window, use_flash=use_flash,
+                    flash_mesh=flash_mesh,
+                )
+            if paged_out is None:
+                k_all = paged_view(cache_k, page_table, layer)
+                v_all = paged_view(cache_v, page_table, layer)
         kv_len = cache_len + s
         q_offset = cache_len
         k_positions = None
         k_step, v_step = k, v
-        use_flash = False  # gathered view → XLA path (flash would need
-        # a block-table-aware kernel; the dispatcher never auto-picks
-        # it here)
+        use_flash = False  # a gathered view takes the XLA path: the
+        # prefill kernel is never auto-picked here
     elif cache_k is not None:
         # Write new K/V at each sequence's current length, then attend
         # over the full cache prefix. Scatter via one-hot matmul-free
@@ -523,7 +542,9 @@ def attention_block(
         k_step, v_step = k, v
         k_positions = None
 
-    if attn_impl is not None:
+    if paged_out is not None:
+        attn_out = paged_out
+    elif attn_impl is not None:
         # Sequence-parallel fresh-prefill: attend over this chunk's
         # keys (contract above). Ring/Ulysses expect equal head counts;
         # sliding-window models pass the window through (ring masks by
@@ -612,10 +633,11 @@ def forward(
     A `PagedKVCache` (batching.paged_kv) is the one cache the layer
     scan CARRIES instead of scanning in and stacking out: the whole
     [L, N, P, KVH, Dh] arena rides the carry beside `x`, each layer
-    scatters and gathers it at [layer, page, offset]
-    (attention_block `page_table` / `layer`), and XLA updates it in
-    place — a decode step moves the step's own K/V and the gathered
-    views, never the arena or a layer's plane. The block table rides
+    scatters into it at [layer, page, offset] and reads it there
+    (attention_block `page_table` / `layer`: the paged-decode kernel,
+    or a gathered view), and XLA updates it in place — a decode step
+    moves the step's own K/V and the live pages (or the gathered
+    views), never the arena or a layer's plane. The block table rides
     scan-invariant.
 
     Returns (logits [B, S, V], updated cache or None).

@@ -1,7 +1,7 @@
-"""Attention ops: XLA-fused reference path and a Pallas flash-attention
-TPU kernel.
+"""Attention ops: XLA-fused reference path, a Pallas flash-attention
+TPU kernel for prefill and a Pallas paged-decode kernel.
 
-Two implementations with one contract:
+Two implementations with one contract, and a third for paged decode:
 
 - `attention_xla` — einsum + masked softmax. XLA fuses this well and it
   is the correct choice for short sequences, decode steps (q_len == 1),
@@ -20,6 +20,12 @@ Two implementations with one contract:
   k blocks; `kv_len` bounds the k loop per batch. Compiled by default;
   `interpret=True` (CPU tests) runs the same kernel body in the Pallas
   interpreter and must be asked for.
+- `paged_decode_attention` — the decode tick's read of a paged K/V
+  arena [L, N, P, KVH, D]: each row walks its own block table up to
+  its own length, page blocks DMA'd out of HBM two deep, instead of a
+  full-width [B, W*P] view being gathered every layer. `paged_decode`
+  picks it from platform, storage dtype and query count, or returns
+  None and the caller gathers the view for `attention`.
 
 `attention` picks per call: flash for long prefill on TPU (crossover
 threshold FLASH_MIN_SEQ — an op-count estimate, not yet measured;
@@ -41,6 +47,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -411,6 +418,326 @@ def flash_attention_sharded(
 
 
 # ---------------------------------------------------------------------------
+# Paged decode: walk each row's block table inside the kernel
+# ---------------------------------------------------------------------------
+
+# Query positions a step the paged-decode kernel takes: the decode tick
+# (1) and the small static windows of the jump and speculative ticks.
+# A prefill chunk's queries keep the gathered view.
+PAGED_DECODE_MAX_SQ = GQA_GROUPED_MAX_SQ
+
+# Pages a block of the walk holds at most (one DMA a page, K and V,
+# two blocks in flight), and the score elements a block may have: a
+# wider query window walks narrower blocks.
+PAGED_DECODE_BLOCK_PAGES = 16
+_PAGED_DECODE_BLOCK_SCORES = 64 * 1024
+
+
+def _paged_block_pages(rows: int, page_cols: int, width: int) -> int:
+    fit = _PAGED_DECODE_BLOCK_SCORES // (rows * page_cols)
+    return max(1, min(PAGED_DECODE_BLOCK_PAGES, fit, width))
+
+
+def _paged_decode_kernel(
+    table_ref,  # SMEM [B, W] int32 — page ids; n_pages = unmapped
+    len_ref,  # SMEM [B] int32 — keys a row holds, this step's included
+    layer_ref,  # SMEM [1] int32
+    q_ref,  # VMEM [B, S*H, D] — queries, (position, head) major
+    bias_ref,  # VMEM [S*H, cols] f32 — 0 where a block's column is of
+    # the row's KV group, NEG_INF elsewhere
+    tok_ref,  # VMEM [1, cols] int32 — a column's token within a block
+    qi_ref,  # VMEM [S*H, 1] int32 — a row's query position in the step
+    k_hbm,  # HBM [L, N, P*KVH, D] — the whole arena, never copied
+    v_hbm,
+    o_ref,  # VMEM [B, S*H, D]
+    k_buf,  # VMEM [2, block_pages, P*KVH, D]
+    v_buf,
+    sems,  # DMA [2 (k, v), 2 (slot)]
+    state,  # SMEM [2] int32 — slot of the next block; whether the
+    # previous row already started this row's first block
+    *,
+    sq: int,
+    page: int,
+    block_pages: int,
+    window: Optional[int],
+):
+    """One row of the batch a grid step: online softmax over the row's
+    own pages, `block_pages` at a time, the next block (or the next
+    row's first) in flight while this one is computed. All of a row's
+    heads are contracted against every (token, kv head) column of a
+    block at once; columns of another KV group carry a bias of NEG_INF.
+    Blocks every query of the row sees whole take no position mask."""
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    width = table_ref.shape[1]
+    n_pages = k_hbm.shape[1]
+    layer = layer_ref[0]
+    rows, cols = bias_ref.shape
+    block_tokens = block_pages * page
+
+    @pl.when(b == 0)
+    def _():
+        # Pages a short last block does not fetch keep what the buffer
+        # held; their weights are exactly 0, so what they hold has to
+        # be finite. From here on it is arena pages.
+        v_buf[...] = jnp.zeros_like(v_buf)
+        state[0] = 0
+        state[1] = 0
+
+    def walk(row):  # -> (first block, end block, pages)
+        kv_len = len_ref[row]
+        # A freed slot keeps its length and has its table unmapped.
+        live = (kv_len > 0) & (table_ref[row, 0] < n_pages)
+        pages = jnp.minimum((kv_len + page - 1) // page, width)
+        end = (pages + block_pages - 1) // block_pages
+        first = 0
+        if window is not None:
+            first = jnp.maximum(kv_len - sq - window + 1, 0) // block_tokens
+        return first, jnp.maximum(jnp.where(live, end, first), first), pages
+
+    def copies(row, blk, slot, pages, wait=False):
+        base = blk * block_pages
+
+        def one(j):
+            pg = jnp.minimum(table_ref[row, base + j], n_pages - 1)
+            for kv, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                cp = pltpu.make_async_copy(
+                    hbm.at[layer, pg], buf.at[slot, j], sems.at[kv, slot]
+                )
+                (cp.wait if wait else cp.start)()
+
+        @pl.when(base + block_pages <= pages)
+        def _():  # a whole block: no test a page
+            for j in range(block_pages):
+                one(j)
+
+        @pl.when(base + block_pages > pages)
+        def _():
+            for j in range(block_pages):
+                pl.when(base + j < pages)(functools.partial(one, j))
+
+    first, end, pages = walk(b)
+    slot0 = state[0]
+
+    @pl.when((end > first) & (state[1] == 0))
+    def _():
+        copies(b, first, slot0, pages)
+
+    nxt = jnp.minimum(b + 1, n_rows - 1)
+    n_first, n_end, n_pages_row = walk(nxt)
+    chain = (b + 1 < n_rows) & (n_end > n_first) & (end > first)
+
+    q = q_ref[b]
+    scale = q.shape[-1] ** -0.5
+    q_pos = len_ref[b] - sq + qi_ref[...]  # [rows, 1]
+
+    def block(i, carry, masked):
+        m_prev, l_prev, acc_prev = carry
+        slot = (slot0 + i - first) % 2
+
+        @pl.when(i + 1 < end)
+        def _():
+            copies(b, i + 1, 1 - slot, pages)
+
+        @pl.when((i + 1 == end) & chain)
+        def _():
+            copies(nxt, n_first, 1 - slot, n_pages_row)
+
+        copies(b, i, slot, pages, wait=True)
+        k_blk = k_buf[slot].reshape(cols, k_buf.shape[-1])
+        v_blk = v_buf[slot].reshape(cols, v_buf.shape[-1])
+        scores = jax.lax.dot_general(
+            q, k_blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale + bias_ref[...]  # [rows, cols]
+        if masked:
+            k_pos = i * block_tokens + tok_ref[...]  # [1, cols]
+            mask = k_pos <= q_pos
+            if window is not None:
+                mask &= k_pos > q_pos - window
+            scores = jnp.where(mask, scores, NEG_INF)
+        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)
+        if masked:  # a query may see no key of this block at all
+            p = jnp.where(scores > NEG_INF / 2, p, 0.0)
+        l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+        acc_new = acc_prev * alpha + jnp.dot(
+            p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32
+        )
+        return m_new, l_new, acc_new
+
+    # Blocks below the first query's position are whole for every
+    # query of the row; with a window that can bind, none is.
+    whole = first if window is not None else jnp.clip(
+        (len_ref[b] - sq) // block_tokens, first, end
+    )
+    carry = (
+        jnp.full((rows, 1), NEG_INF, jnp.float32),
+        jnp.zeros((rows, 1), jnp.float32),
+        jnp.zeros((rows, q.shape[-1]), jnp.float32),
+    )
+    carry = jax.lax.fori_loop(
+        first, whole, functools.partial(block, masked=False), carry)
+    _, l, acc = jax.lax.fori_loop(
+        whole, end, functools.partial(block, masked=True), carry)
+    # A row that walked nothing (freed slot, length 0) emits zeros.
+    o_ref[b] = jnp.where(
+        l > 0.0, acc / jnp.maximum(l, 1e-30), 0.0
+    ).astype(o_ref.dtype)
+    state[0] = (slot0 + end - first) % 2
+    state[1] = chain.astype(jnp.int32)
+
+
+def _paged_decode_vmem_bytes(
+    batch: int, rows: int, d: int, block_pages: int, page_rows: int,
+    itemsize: int,
+) -> int:
+    """VMEM the paged-decode kernel needs, told to the compiler: the K
+    and V block buffers, two slots each; q and o for every row
+    (double-buffered by the pipeline); the float32 scores, weights,
+    bias and masks of one block and the accumulator; 4 MiB of headroom
+    for Mosaic's own scratch. 3.9 MiB + headroom at mistral's widths."""
+    cols = block_pages * page_rows
+    bufs = 2 * 2 * block_pages * page_rows * d * itemsize
+    qo = 2 * 2 * batch * rows * d * itemsize
+    work = 4 * (6 * rows * cols + 2 * rows * d)
+    return bufs + qo + work + (4 << 20)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("window", "block_pages", "interpret")
+)
+def paged_decode_attention(
+    q: jnp.ndarray,  # [B, S, H, D] — S <= PAGED_DECODE_MAX_SQ
+    k_arena: jnp.ndarray,  # [L, N, P, KVH, D] — every layer's pages
+    v_arena: jnp.ndarray,
+    table: jnp.ndarray,  # [B, W] int32 page ids (N = unmapped)
+    kv_len: jnp.ndarray,  # [B] keys a row holds, this step's included
+    layer: jnp.ndarray,  # scalar layer index
+    window: Optional[int] = None,
+    block_pages: Optional[int] = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Causal attention of the last S positions of every row against
+    that row's own pages, read in place out of the whole arena: the
+    block table, the lengths and the layer index are scalar-prefetched,
+    the arenas stay in HBM, and each row's walk stops at its own
+    `kv_len` (a row of length 0 or with an unmapped table walks
+    nothing and returns zeros). Query i of a row sits at position
+    kv_len - S + i. What `llama.paged_view` + `attention_xla` compute
+    over the [B, W*P] view, without the view. Compiled for the TPU
+    unless `interpret=True` (CPU tests) asks for the interpreter."""
+    b, sq, h, d = q.shape
+    n_layers, n_pages, page, kvh, _ = k_arena.shape
+    width = table.shape[1]
+    assert h % kvh == 0, f"q heads {h} not a multiple of kv heads {kvh}"
+    if window is not None and window >= width * page:
+        window = None  # cannot bind inside a row's W*P positions
+    rows, page_rows = sq * h, page * kvh
+    if block_pages is None:
+        block_pages = _paged_block_pages(rows, page_rows, width)
+    kernel = functools.partial(
+        _paged_decode_kernel, sq=sq, page=page, block_pages=block_pages,
+        window=window,
+    )
+    # What a row or a column of a block's scores stands for, as
+    # constants of the program (integer division of whole score blocks
+    # inside the kernel cost more than the walk): column c of a block
+    # is (token c // KVH, kv head c % KVH), row r (query r // H, head
+    # r % H).
+    col, row = np.arange(block_pages * page_rows), np.arange(rows)
+    same_group = (row % h // (h // kvh))[:, None] == (col % kvh)[None, :]
+    bias = np.where(same_group, 0.0, NEG_INF).astype(np.float32)
+    tok = (col // kvh).astype(np.int32)[None, :]
+    qi = (row // h).astype(np.int32)[:, None]
+
+    def whole(shape):  # in VMEM once for every row: fetched once
+        return pl.BlockSpec(shape, lambda bi, *_: (0,) * len(shape))
+
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    row_block = whole((b, rows, d))
+    buf = pltpu.VMEM((2, block_pages, page_rows, d), k_arena.dtype)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[
+                row_block, whole(bias.shape), whole(tok.shape),
+                whole(qi.shape), any_space, any_space,
+            ],
+            out_specs=row_block,
+            scratch_shapes=[
+                buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # Rows in order on one core: a row starts the next row's
+            # first block, and the buffers' slot carries over.
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_paged_decode_vmem_bytes(
+                b, rows, d, block_pages, page_rows, k_arena.dtype.itemsize
+            ),
+        ),
+        name="paged_decode_attention",
+        interpret=interpret,
+    )(
+        table.astype(jnp.int32), kv_len.astype(jnp.int32),
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        q.reshape(b, rows, d), bias, tok, qi,
+        k_arena.reshape(n_layers, n_pages, page_rows, d),
+        v_arena.reshape(n_layers, n_pages, page_rows, d),
+    )
+    return out.reshape(b, sq, h, d)
+
+
+def paged_decode_attention_sharded(
+    q: jnp.ndarray,  # [B, S, H, D]
+    k_arena: jnp.ndarray,  # [L, N, P, KVH, D]
+    v_arena: jnp.ndarray,
+    table: jnp.ndarray,
+    kv_len: jnp.ndarray,
+    layer: jnp.ndarray,
+    mesh,
+    window: Optional[int] = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """`paged_decode_attention` on a multi-device mesh, one kernel per
+    shard: heads over `tensor` as `llama.paged_cache_specs` shards the
+    arena (a shard's query heads are exactly the groups of its KV
+    heads), tables and lengths replicated; pages are shared by every
+    slot, so nothing shards over a batch axis. Manual over EVERY mesh
+    axis, as `flash_attention_sharded` is; `tensor` must divide the KV
+    head count. VMEM a shard: `_paged_decode_vmem_bytes` at its local
+    head counts."""
+    from jax.sharding import PartitionSpec as P
+
+    t_ax = mesh.shape.get("tensor", 1)
+    if k_arena.shape[3] % t_ax != 0:
+        raise ValueError(
+            f"kv heads {k_arena.shape[3]} not divisible by tensor axis {t_ax}"
+        )
+    qspec = P(None, None, "tensor", None)
+    aspec = P(None, None, None, "tensor", None)
+
+    def local(q, ka, va, tb, kl, ly):
+        return paged_decode_attention(
+            q, ka, va, tb, kl, ly, window=window, interpret=interpret
+        )
+
+    return jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(qspec, aspec, aspec, P(), P(), P()),
+        out_specs=qspec,
+        check_vma=False,
+    )(q, k_arena, v_arena, table, kv_len, layer)
+
+
+# ---------------------------------------------------------------------------
 # Dispatcher
 # ---------------------------------------------------------------------------
 
@@ -423,11 +750,14 @@ FLASH_MIN_SEQ = 256
 # Which implementation each kernel-eligible call took, counted at TRACE
 # time (the dispatcher is Python that runs once per traced program, so
 # this counts programs, not executions): "flash" / "flash_sharded" =
-# the Pallas kernel is in the program; "xla_fallback" = the call wanted
-# the kernel and its shapes did not shard over the mesh. Process-wide,
-# like the compile watcher: the sidecar exports it beside
-# mesh_spec_downgrades (attn_kernel_programs / attn_kernel_fallbacks),
-# and it is how chip_smoke.py knows a prefill took the compiled kernel.
+# the Pallas prefill kernel is in the program; "paged_decode" = the
+# paged-decode kernel is (a layer scan traces its body once, so one a
+# forward call a tick program holds); "xla_fallback" = the call wanted
+# a kernel and its shapes did not shard over the mesh, or the engine
+# runs none on its mesh. Process-wide, like the compile watcher: the sidecar
+# exports it beside mesh_spec_downgrades (attn_kernel_programs /
+# attn_kernel_fallbacks), and it is how chip_smoke.py knows a prefill
+# took the compiled kernel.
 dispatch_counts: collections.Counter = collections.Counter()
 
 
@@ -436,9 +766,14 @@ def dispatch_stats() -> dict:
     return {
         "attn_kernel_programs": (
             dispatch_counts["flash"] + dispatch_counts["flash_sharded"]
+            + dispatch_counts["paged_decode"]
         ),
         "attn_kernel_fallbacks": dispatch_counts["xla_fallback"],
     }
+
+
+def _on_tpu() -> bool:
+    return jax.devices()[0].platform == "tpu"
 
 
 def attention(
@@ -478,7 +813,7 @@ def attention(
         use_flash = False
     if use_flash is None:
         use_flash = (
-            jax.devices()[0].platform == "tpu"
+            _on_tpu()
             and sq >= FLASH_MIN_SEQ
             and sq % 128 == 0
             and sk % 128 == 0
@@ -519,4 +854,70 @@ def attention(
     return attention_xla(
         q, k, v, causal=causal, q_offset=q_offset, kv_len=kv_len,
         window=window, k_positions=k_positions,
+    )
+
+
+def paged_decode(
+    q: jnp.ndarray,  # [B, S, H, D]
+    k_arena: jnp.ndarray,  # [L, N, P, KVH, D]
+    v_arena: jnp.ndarray,
+    table: jnp.ndarray,  # [B, W]
+    kv_len: jnp.ndarray,  # [B] — the step's keys included
+    layer: jnp.ndarray,
+    window: Optional[int] = None,
+    use_flash: Optional[bool] = None,
+    flash_mesh=None,
+) -> Optional[jnp.ndarray]:
+    """The paged-decode kernel where the step is its kind, else None
+    and the caller gathers its view (`llama.paged_view`) for
+    `attention`. Chosen from what the call can see, no option: a TPU;
+    an arena stored in the queries' own dtype (quantized arenas never
+    get here; a float8 arena is turned away here); at most
+    PAGED_DECODE_MAX_SQ query positions (a prefill chunk keeps the
+    view); a page Mosaic can slice out of the arena (a head of whole
+    128-lane rows, 8 or more (token, kv head) rows a page). On a
+    multi-device mesh the kernel runs per shard (`flash_mesh`, as for
+    the prefill kernel). A step of that kind on a mesh that cannot
+    run the kernel — the engine turned kernels off because the mesh
+    does not shard them (`use_flash=False`), or `tensor` does not
+    divide the KV heads — takes the view too, logged and counted as a
+    fallback."""
+    (_, sq, _, d), (_, _, page, kvh, _) = q.shape, k_arena.shape
+    t_ax = 1 if flash_mesh is None else flash_mesh.shape.get("tensor", 1)
+    if (
+        not _on_tpu()
+        or k_arena.dtype != q.dtype
+        or sq > PAGED_DECODE_MAX_SQ
+        or d % 128 != 0
+        or (page * kvh) % (8 * t_ax) != 0
+    ):
+        return None
+    why = ""
+    if use_flash is False:
+        why = "the engine runs no attention kernel on this mesh"
+    elif kvh % t_ax != 0:
+        why = f"kv heads {kvh} not divisible by tensor axis {t_ax}"
+    if why:
+        dispatch_counts["xla_fallback"] += 1
+        logger.warning(
+            "attention: paged decode q%s arena%s wanted the Pallas kernel "
+            "(%s) — this program gathers the full-width view instead "
+            "(watch gauge attn_kernel_fallbacks)",
+            tuple(q.shape), tuple(k_arena.shape), why,
+        )
+        return None
+    dispatch_counts["paged_decode"] += 1
+    logger.info(
+        "attention: paged-decode Pallas kernel%s for q%s arena%s table%s "
+        "window=%s",
+        " per shard" if flash_mesh is not None else "",
+        tuple(q.shape), tuple(k_arena.shape), tuple(table.shape), window,
+    )
+    if flash_mesh is not None:
+        return paged_decode_attention_sharded(
+            q, k_arena, v_arena, table, kv_len, layer, flash_mesh,
+            window=window,
+        )
+    return paged_decode_attention(
+        q, k_arena, v_arena, table, kv_len, layer, window=window
     )

@@ -11,8 +11,10 @@ analysis and every instruction whose result has the arena's
 `[L, N, P, KVH, Dh]` shape or one layer's `[N, P, KVH, Dh]` shape (the
 gathered view has the latter too when N = slots x table width, as in
 the benchmark's configuration). PR 27 read from it that the arena is
-scattered into and gathered from in place; a paged-decode kernel
-(ROADMAP A1(b)) should show up here as a `tpu_custom_call`.
+scattered into and gathered from in place; since PR 29 the gathers are
+gone on the TPU and the paged-decode kernel shows up as a
+`tpu_custom_call` named `paged_decode_attention` reading the scattered
+arena through a bitcast.
 """
 
 from __future__ import annotations
@@ -81,9 +83,11 @@ def main() -> int:
     )
     for line in hlo.splitlines():
         m = instr.match(line)
-        if m and m.group(2).endswith((plane, arena)) and m.group(3) not in (
+        if not m:
+            continue
+        if m.group(2).endswith((plane, arena)) and m.group(3) not in (
             "parameter", "get-tuple-element", "bitcast",
-        ):
+        ) or "paged_decode_attention" in m.group(1):
             print(*m.groups())
     return 0
 

@@ -106,3 +106,7 @@ class TestChipSmoke:
         assert result["device"]["platform"] == "cpu"
         assert "all legs passed: kernel,serve,default_kv" in proc.stdout
         assert "paged vs contiguous: first 8 of 8 ids agree" in proc.stdout
+        # The kernel leg walked both kernels, the paged-decode one with
+        # and without a window that binds.
+        assert proc.stdout.count("paged decode q[4,1,8,32]") == 2
+        assert "paged_decode_attention window=12" in proc.stdout
