@@ -10,7 +10,6 @@ CPU_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
   test-tp test-analysis \
   test-disagg test-fleet test-mem test-kvtier test-lora-arena test-slo \
   test-sched \
-  bench-cpu \
   smoke e2e lint graftlint ci-local preflight clean
 
 # Regenerate pb2 modules from protos/ (committed; rerun after editing).
@@ -100,13 +99,6 @@ test-paged:
 test-tp:
 	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=2 \
 	  $(PY) -m pytest tests/ -q -m tp
-
-# CPU smoke of the full bench, including the mixed long-prompt+decode
-# workload phase (interleaved prefill on — A/B the serialized baseline
-# with GGRMCP_BENCH_INTERLEAVE=off; compare mixed_decode_stall_p99_ms).
-bench-cpu:
-	GGRMCP_BENCH_CPU=1 GGRMCP_BENCH_SESSIONS=8 GGRMCP_BENCH_CALLS=24 \
-	  GGRMCP_BENCH_INTERLEAVE=on $(PY) bench.py
 
 # End-to-end smoke: graft entry + multichip dry run on the CPU mesh.
 smoke:
@@ -199,7 +191,7 @@ test-sched:
 
 # ruff if present (baked CI image installs it; the TPU image may not).
 lint:
-	@command -v ruff >/dev/null 2>&1 && ruff check ggrmcp_tpu tests bench.py \
+	@command -v ruff >/dev/null 2>&1 && ruff check ggrmcp_tpu tests \
 	  || echo "ruff not installed; skipping"
 
 # CI-equivalent run with a committed transcript (docs/ci_evidence/):

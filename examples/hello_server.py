@@ -56,8 +56,7 @@ def serve(port: int, uds: str = "", workers: int = 4) -> None:
         bound = server.add_insecure_port(f"0.0.0.0:{port}")
         target = f"localhost:{bound}"
     server.start()
-    # Machine-readable for harnesses that pass --port 0 / --uds
-    # (bench.py dials the printed target verbatim).
+    # Machine-readable for harnesses that pass --port 0 / --uds.
     print(f"TARGET={target}", flush=True)
     logging.info("hello-service listening on %s", target)
     server.wait_for_termination()

@@ -310,15 +310,14 @@ class BatchingConfig:
     # (serving/tiered.py).
     kv_tiers: list = field(default_factory=list)
     # Paged KV cache (docs/paged_kv.md): "on" replaces the contiguous
-    # per-slot rows AND the slot-granular prefix pool with one device
-    # arena of fixed-size pages per layer, per-slot block tables, and a
-    # host-side refcounted allocator (serving/pages.py) — token-level,
-    # page-aligned prefix sharing with copy-on-write at the divergent
-    # page and LRU reuse of refcount-0 pages. Greedy outputs are
-    # bit-identical to "off" (the contiguous path, kept as the provable
-    # baseline). Supersedes prefix_cache_entries (validate() rejects
-    # the combination with a clear error); mutually exclusive with
-    # kv_ring; dense-Llama, non-pipeline serving only.
+    # per-slot rows with one device arena of fixed-size pages per
+    # layer, per-slot block tables, and a host-side refcounted
+    # allocator (serving/pages.py) — token-level, page-aligned prefix
+    # sharing with copy-on-write at the divergent page and LRU reuse of
+    # refcount-0 pages: the one prefix-reuse mechanism. Greedy outputs
+    # are bit-identical to "off" (the contiguous path, kept as the
+    # provable baseline). Mutually exclusive with kv_ring; dense-Llama
+    # and latent-attention families, non-pipeline serving only.
     paged_kv: str = "off"  # off | on
     # Page granularity in tokens. Smaller pages share shorter common
     # prefixes and waste less tail space; larger pages mean smaller
@@ -353,23 +352,6 @@ class BatchingConfig:
     # sets should set this). When full, demotions keep landing in RAM
     # — the file just stops growing.
     paged_kv_host_file_bytes: int = 0
-    # Prefix (prompt-KV) cache: a device-resident pool of recently seen
-    # prompt prefixes; an admission whose prompt starts with a cached
-    # prefix reuses its KV and prefills only the suffix — the
-    # system-prompt case. 0 entries = off (serving/batching.py).
-    # NOTE (slot-granular pool only — paged_kv=on replaces this pool
-    # with token-level page sharing and rejects nonzero entries): with
-    # kv_tiers, EACH tier owns an independent pool (tiers share no
-    # mutable state): HBM is tiers × entries × max_seq of KV and a
-    # prefix shared across tiers is stored once per tier. Budget
-    # entries accordingly when tiering — or turn on paged_kv, where a
-    # tier's arena stores every shared prefix exactly once at token
-    # granularity and the thrash cliff the slot pool hits when the
-    # preamble working set outgrows its entries disappears
-    # (docs/BENCH.md §"Prefix-pool thrash regime").
-    prefix_cache_entries: int = 0
-    prefix_cache_max_seq: int = 512  # per-entry KV capacity (tokens)
-    prefix_cache_min_seq: int = 64  # don't pool prefixes shorter than this
     # Latency SLO (SURVEY.md §7 hard part #2 — the batch-window vs p50
     # tradeoff). p50_budget_ms > 0 caps admission-induced decode
     # stalls: while slots are decoding, an admission round admits at
@@ -415,19 +397,6 @@ class BatchingConfig:
     # requests that exhaust the budget see finish_reason "error". 0 =
     # fail every victim immediately (the pre-replay behavior).
     tick_retry_limit: int = 1
-    # Speculative decoding INSIDE the continuous batcher
-    # (docs/speculative.md): "on" + a configured serving.speculative_
-    # draft makes every decode tick one fixed-shape draft/verify round
-    # — gamma draft steps against a per-slot draft KV cache, then ONE
-    # (gamma+1)-position target verify over the shared slot pool, with
-    # variable advance expressed as per-slot length-pointer arithmetic
-    # (never dynamic shapes). Greedy rows stay bitwise identical to
-    # spec-off; sampled rows (incl. top-k/top-p) are rejection-sampled
-    # losslessly over the filtered distributions; grammar-constrained
-    # rows verify against the DFA mask. "off" (default) keeps the
-    # plain tick; the side SpeculativeBatcher micro-path then serves
-    # draft-eligible unary calls as before.
-    speculative: str = "off"  # off | on
 
 
 # decode_steps_per_tick="auto" resolves to this on TPU meshes: with
@@ -848,9 +817,8 @@ class ServingConfig:
     # Unix-domain-socket listen path. When set, the sidecar binds
     # `unix:{uds_path}` instead of TCP. The co-located deployment
     # (gateway --tpu) defaults to a private UDS because the hop is
-    # loopback-only by construction and a UDS round trip costs
-    # measurably less shared-core CPU than TCP loopback
-    # (docs/BENCH.md proxy-phase table).
+    # loopback-only by construction and a UDS round trip costs less
+    # shared-core CPU than TCP loopback.
     uds_path: str = ""
     # `--tpu` co-launch transport: auto-generate a per-process UDS for
     # the gateway→sidecar hop (uds_path, when set, pins the path).
@@ -898,18 +866,21 @@ class ServingConfig:
     # window + prefill_chunk - 1 instead of the full context, and
     # generation length is bounded by the model's RoPE range, not KV
     # HBM (docs/kv_ring_design.md). Batcher-path only; incompatible
-    # with kv_tiers and the prefix pool; composes with int8 KV and
-    # pipeline serving (validate() below, tests/test_pp_serving.py).
+    # with kv_tiers; composes with int8 KV and pipeline serving
+    # (validate() below, tests/test_pp_serving.py).
     kv_ring: bool = False
     # Speculative decoding: registry key of a small dense draft model
-    # sharing the target's vocab ("" → off). With
-    # batching.speculative=on the draft rides INSIDE the continuous
-    # batcher — every decode tick verifies `speculative_gamma` drafted
-    # tokens per target forward against the shared slot pool
-    # (docs/speculative.md; the saturation-workload shape). With it off,
-    # draft-eligible unary calls take the side micro-batcher
-    # (serving/spec_batcher.py) — whole-generation device programs,
-    # best for latency-sensitive low-concurrency greedy traffic.
+    # sharing the target's vocab ("" → off). A configured draft IS the
+    # switch: the draft rides INSIDE the continuous batcher, where
+    # every decode tick is one fixed-shape draft/verify round —
+    # `speculative_gamma` draft steps against a per-slot draft KV
+    # cache, then ONE (gamma+1)-position target verify over the shared
+    # slot pool, with variable advance expressed as per-slot
+    # length-pointer arithmetic (never dynamic shapes). Greedy rows
+    # stay bitwise identical to the plain tick; sampled rows (incl.
+    # top-k/top-p) are rejection-sampled losslessly over the filtered
+    # distributions; grammar-constrained rows verify against the DFA
+    # mask (docs/speculative.md).
     speculative_draft: str = ""
     speculative_gamma: int = 4
     # Sequence-parallel prefill over the mesh `sequence` axis: "ring"
@@ -1443,14 +1414,9 @@ class Config:
                 )
         if self.serving.speculative_gamma < 1:
             raise ValueError("speculative_gamma must be >= 1")
-        if self.serving.batching.speculative not in ("off", "on"):
-            raise ValueError("batching.speculative must be 'off' or 'on'")
-        if (
-            self.serving.batching.speculative == "on"
-            and self.serving.kv_ring
-        ):
+        if self.serving.speculative_draft and self.serving.kv_ring:
             raise ValueError(
-                "batching.speculative does not compose with kv_ring: the "
+                "speculative_draft does not compose with kv_ring: the "
                 "draft slot-pool cache is contiguous and the (gamma+1)-"
                 "position verify assumes the contiguous length mask"
             )
@@ -1475,15 +1441,13 @@ class Config:
         tiers = self.serving.batching.kv_tiers
         if tiers:
             if not all(
-                isinstance(t, (list, tuple)) and len(t) in (2, 3)
+                isinstance(t, (list, tuple)) and len(t) == 2
                 and int(t[0]) > 0 and int(t[1]) > 0
-                and (len(t) == 2 or int(t[2]) >= 0)
                 for t in tiers
             ):
                 raise ValueError(
                     "batching.kv_tiers entries must be [max_seq, slots] "
-                    "or [max_seq, slots, prefix_entries] with positive "
-                    "max_seq/slots and prefix_entries >= 0"
+                    "with positive max_seq/slots"
                 )
             seqs = [int(t[0]) for t in tiers]
             if seqs != sorted(seqs) or len(set(seqs)) != len(seqs):
@@ -1513,13 +1477,6 @@ class Config:
                     "capacity, a page table maps them — one indirection "
                     "scheme per cache"
                 )
-            if batching.prefix_cache_entries:
-                raise ValueError(
-                    "batching.paged_kv supersedes the slot-granular "
-                    "prefix pool: set prefix_cache_entries to 0 "
-                    "(page-aligned prefix sharing is built into the "
-                    "paged allocator — docs/paged_kv.md)"
-                )
             if batching.kv_cache_max_seq % page:
                 raise ValueError(
                     f"batching.paged_kv_page_size ({page}) must divide "
@@ -1531,12 +1488,6 @@ class Config:
                     raise ValueError(
                         f"batching.paged_kv_page_size ({page}) must "
                         f"divide every tier max_seq (tier {int(t[0])})"
-                    )
-                if len(t) > 2 and int(t[2]) > 0:
-                    raise ValueError(
-                        "batching.paged_kv supersedes per-tier prefix "
-                        "pools: kv_tiers prefix_entries must be 0 "
-                        "under paging"
                     )
         if batching.paged_kv_host_bytes < 0:
             raise ValueError(
@@ -1567,15 +1518,6 @@ class Config:
                 "batching.paged_kv_host_file_bytes caps the file-tier "
                 "log: set paged_kv_host_path"
             )
-        if batching.prefix_cache_entries < 0:
-            raise ValueError("prefix_cache_entries must be >= 0")
-        if batching.prefix_cache_entries:
-            if batching.prefix_cache_min_seq < 1:
-                raise ValueError("prefix_cache_min_seq must be >= 1")
-            if batching.prefix_cache_max_seq < batching.prefix_cache_min_seq:
-                raise ValueError(
-                    "prefix_cache_max_seq must be >= prefix_cache_min_seq"
-                )
         if self.serving.sp_prefill not in ("", "ring", "ulysses"):
             raise ValueError(
                 f"unknown serving.sp_prefill {self.serving.sp_prefill!r}; "
@@ -1619,11 +1561,6 @@ class Config:
                 raise ValueError(
                     "kv_ring and kv_tiers are mutually exclusive (a "
                     "ring has ONE capacity: window + prefill_chunk - 1)"
-                )
-            if self.serving.batching.prefix_cache_entries:
-                raise ValueError(
-                    "kv_ring does not compose with the prefix pool "
-                    "(pooled prefixes assume a contiguous layout)"
                 )
             # mesh.stage > 1 composes (round 3): the staged forward
             # threads the ring layout into each stage's cache block.

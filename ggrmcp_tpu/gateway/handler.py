@@ -861,7 +861,7 @@ class MCPHandler:
         filterable by the trace id a tool call echoed in X-Trace-Id —
         the span → request record → tick records walk — and by the
         originating batcher's `source` label ("" flat pool,
-        "tier-<max_seq>", "spec"). `kind` is "ticks" or "requests";
+        "tier-<max_seq>"). `kind` is "ticks" or "requests";
         framework-free, shared by the aiohttp handler and the fast
         lane. The ticks body also carries the admission rounds and the
         executor hand-offs around those ticks, and a `fields` help table

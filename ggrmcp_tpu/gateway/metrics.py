@@ -56,10 +56,12 @@ _SERVING_HELP = {
     "prefix_cache_hits": "prefix cache hits",
     "prefix_cache_misses": "prefix cache misses",
     "decode_steps": "fused decode steps issued",
-    "speculative_calls": "speculative device calls",
-    "speculative_requests": "requests served speculatively",
-    "speculative_drafted": "side micro-batcher draft tokens proposed",
-    "speculative_accepted": "side micro-batcher draft tokens accepted",
+    # No writer: the proto keeps the four field numbers, the gauges
+    # read 0 (the spec tick reports through spec_* below).
+    "speculative_calls": "reserved, reads 0",
+    "speculative_requests": "reserved, reads 0",
+    "speculative_drafted": "reserved, reads 0",
+    "speculative_accepted": "reserved, reads 0",
     "ticks": "decode ticks dispatched",
     "tick_collects": "decode tick token collects",
     "admit_rounds": "admission rounds run",
@@ -193,8 +195,7 @@ _SERVING_HELP = {
         "ledger: paged per-slot device block-table bytes",
     "memory_draft_cache_bytes":
         "ledger: speculative draft slot-pool KV bytes",
-    "memory_prefix_pool_bytes":
-        "ledger: slot-granular prefix-pool KV bytes (paged off)",
+    "memory_prefix_pool_bytes": "reserved, reads 0 (no such component)",
     "memory_ilv_mini_bytes":
         "ledger: interleaved-admission mini-cache bytes",
     "memory_grammar_arena_bytes":

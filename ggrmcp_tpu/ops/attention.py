@@ -28,11 +28,11 @@ Two implementations with one contract, and a third for paged decode:
   None and the caller gathers the view for `attention`.
 
 `attention` picks per call: flash for long prefill on TPU (crossover
-threshold FLASH_MIN_SEQ — an op-count estimate, not yet measured;
-scripts/bench_attention.py measures it), XLA otherwise. Every choice
-that involves the kernel is recorded (`dispatch_counts`): a call that
-wanted the kernel and fell to XLA because its shapes do not shard is
-logged and counted, never silent. Shapes are
+threshold FLASH_MIN_SEQ — an op-count estimate, not yet measured),
+XLA otherwise. Every choice that involves the kernel is recorded
+(`dispatch_counts`): a call that wanted the kernel and fell to XLA
+because its shapes do not shard is logged and counted, never silent.
+Shapes are
 [batch, seq, heads, head_dim]; K/V may carry fewer (KV) heads — the
 flash kernel reads them in place, and attention_xla contracts them
 grouped for decode-shaped queries (repeating only for long ones).
@@ -743,8 +743,8 @@ def paged_decode_attention_sharded(
 
 # Prefill sequences at least this long go through the Pallas kernel on
 # TPU; below it the fused XLA path wins (kernel launch + padding costs).
-# PROVENANCE: op-count estimate, not measured — scripts/bench_attention.py
-# measures the crossover on the chip (docs/perf_attention.md).
+# PROVENANCE: op-count estimate, not measured on the chip
+# (docs/perf_attention.md).
 FLASH_MIN_SEQ = 256
 
 # Which implementation each kernel-eligible call took, counted at TRACE

@@ -9,22 +9,22 @@ Two per-row acceptance modes share one program:
 - Greedy (temperature 0): exact-match — a proposed token is accepted
   iff the target's argmax at that position equals it, so the emitted
   sequence is IDENTICAL to target-only greedy decoding regardless of
-  draft quality (a correctness invariant the tests pin down).
+  draft quality (a correctness invariant tests/test_spec_batch.py pins
+  down).
 - Sampled (temperature > 0): standard rejection sampling (Leviathan et
   al. 2023; Chen et al. 2023) — the draft SAMPLES proposal x from its
-  temperature-scaled distribution q, the proposal is accepted with
-  probability min(1, p(x)/q(x)) against the target's distribution p,
-  and on the first rejection the correction token is sampled from the
-  residual normalize(max(p - q, 0)). The emitted tokens are then
-  distributed EXACTLY as target-only sampling (lossless in
-  distribution, not bitwise — tests/test_speculative.py pins both the
-  self-draft acceptance invariant and the output distribution).
+  filtered distribution q, the proposal is accepted with probability
+  min(1, p(x)/q(x)) against the target's distribution p, and on the
+  first rejection the correction token is sampled from the residual
+  normalize(max(p - q, 0)). The emitted tokens are then distributed
+  EXACTLY as target-only sampling (lossless in distribution, not
+  bitwise).
 
-The whole generation is one jitted program: an outer `lax.while_loop`
-over verify rounds, the draft's proposal loop as an inner `lax.scan`,
-KV caches as fixed-size carries with explicit per-row length
-accounting (rollback on rejection = set the length counter; stale KV
-beyond it is masked by the causal attention window).
+`spec_tick` is one fixed-shape draft/verify round over the continuous
+batcher's slot pool (serving/batching.py `_tick_spec*`): KV caches are
+carried with explicit per-row length accounting (rollback on rejection
+= set the length counter; stale KV beyond it is masked by the causal
+attention window).
 
 No reference analogue (the Go gateway executes no models); this is a
 serving-plane throughput component like ops/quant.py.
@@ -32,317 +32,8 @@ serving-plane throughput component like ops/quant.py.
 
 from __future__ import annotations
 
-from typing import NamedTuple
-
 import jax
 import jax.numpy as jnp
-
-
-class SpecResult(NamedTuple):
-    tokens: jnp.ndarray  # [B, max_new] — includes the eos when stopped
-    out_len: jnp.ndarray  # [B] — tokens up to and including first eos
-    rounds: jnp.ndarray  # scalar — verify rounds executed
-    drafted: jnp.ndarray  # scalar — draft tokens proposed
-    accepted: jnp.ndarray  # scalar — draft tokens accepted
-
-
-def speculative_generate(
-    target_fam,
-    target_params,
-    target_cfg,
-    draft_fam,
-    draft_params,
-    draft_cfg,
-    tokens: jnp.ndarray,  # [B, S] right-padded prompts
-    true_len: jnp.ndarray,  # [B]
-    max_new_budget: int,
-    gamma: int,
-    eos_id,
-    max_new=None,  # traced per-call cap ≤ max_new_budget (None → budget)
-    use_flash=None,  # threaded to forward (see engine flash policy)
-    flash_mesh=None,
-    kv_dtype: str = "",  # "" model dtype | "int8" quantized KV caches
-    temperature=None,  # [B] float; None → all-greedy program (no RNG ops)
-    seeds=None,  # [B] per-row PRNG seeds (required when temperature given)
-) -> SpecResult:
-    """Generate up to `max_new` tokens per row, speculative.
-
-    `max_new_budget` is static (sizes the output buffer — bucket it to
-    bound compilations); `max_new` is traced, so different request caps
-    reuse the same compiled program and decoding stops at the cap.
-    `temperature=None` compiles the pure-greedy program; a [B] array
-    enables per-row rejection sampling (rows with temperature 0 stay
-    exact-match greedy inside the same program — see module docstring).
-
-    The family modules supply the serving `forward(params, cfg, tokens,
-    cache) -> (logits, cache)` contract (models/llama.py). Dense
-    decoders only: MoE routing is batch-global, so per-round token
-    counts would change expert assignment and break the lossless
-    guarantee (the engine rejects MoE targets/drafts up front). The two
-    models must share a tokenizer/vocab.
-    """
-    b, s = tokens.shape
-    if max_new is None:
-        max_new = max_new_budget
-    max_new = jnp.minimum(jnp.int32(max_new), max_new_budget)
-    sampled_mode = temperature is not None
-    if sampled_mode:
-        temperature = jnp.asarray(temperature, jnp.float32)
-        is_sampled = temperature > 0.0  # [B] — 0 rows stay greedy
-        safe_t = jnp.maximum(temperature, 1e-6)[:, None]
-        row_keys = jax.vmap(jax.random.PRNGKey)(
-            jnp.asarray(seeds, jnp.uint32).astype(jnp.int32)
-        )
-
-        def _draw(logits, keys):
-            """Per-row: temperature sample (Gumbel trick) where
-            sampled, argmax where greedy."""
-            g = jax.vmap(
-                # graftlint: disable=sharded-sampling -- side micro-batcher (batching.speculative=off fallback): the [V]-shaped Gumbel draw is distributionally exact on any mesh; cross-mesh bit-identity is only claimed for the continuous-batcher path (ops/sampling CDF inversion), and converting this would invalidate every recorded seeded artifact for zero distributional gain
-                lambda k: jax.random.gumbel(k, (logits.shape[-1],))
-            )(keys)
-            samp = jnp.argmax(logits / safe_t + g, axis=-1)
-            return jnp.where(
-                is_sampled, samp, jnp.argmax(logits, axis=-1)
-            ).astype(jnp.int32)
-
-        def _fold(keys, tag):
-            return jax.vmap(jax.random.fold_in, in_axes=(0, None))(
-                keys, tag
-            )
-    budget = s + max_new_budget + gamma + 2  # verify may overshoot
-    # Per-position int8 quantization is write-order independent, so
-    # the verify re-reads see exactly the cache the draft rounds wrote
-    # and the lossless guarantee holds within the int8 config.
-    tcache = _kv_class(target_fam).create(target_cfg, b, budget, kv_dtype)
-    dcache = _kv_class(draft_fam).create(draft_cfg, b, budget, kv_dtype)
-
-    # Prefill both models on the prompt.
-    tlogits, tcache = target_fam.forward(
-        target_params, target_cfg, tokens, tcache, use_flash=use_flash, flash_mesh=flash_mesh
-    )
-    _, dcache = draft_fam.forward(
-        draft_params, draft_cfg, tokens, dcache, use_flash=use_flash, flash_mesh=flash_mesh
-    )
-    last_idx = jnp.maximum(true_len - 1, 0)
-    last_logits = jnp.take_along_axis(
-        tlogits, last_idx[:, None, None], axis=1
-    )[:, 0]  # [B, V]
-    if sampled_mode:
-        first = _draw(last_logits, _fold(row_keys, 0))
-    else:
-        first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-
-    # Roll both caches back to the true prompt length (prefill advanced
-    # them by the padded S). The draft additionally steps back one more:
-    # each round re-feeds [prev, cur] so `prev` rewrites its own slot.
-    tcache = tcache._replace(length=true_len)
-    dcache = dcache._replace(length=jnp.maximum(true_len - 1, 0))
-    prev = jnp.take_along_axis(tokens, last_idx[:, None], axis=1)[:, 0]
-
-    # Column max_new_budget is scratch: masked/overflow writes are
-    # routed there so in-range positions never see duplicate-index
-    # scatter collisions (with .set, duplicates pick an arbitrary
-    # winner).
-    out = jnp.zeros((b, max_new_budget + 1), jnp.int32)
-    out = out.at[:, 0].set(first)
-    out_len = jnp.ones((b,), jnp.int32)
-    has_eos = first == eos_id
-
-    def cond(carry):
-        (_, _, _, _, _, out_len, has_eos, _stats) = carry
-        return jnp.any(~has_eos & (out_len < max_new))
-
-    def round_body(carry):
-        tcache, dcache, prev, cur, out, out_len, has_eos, stats = carry
-        rounds, drafted, accepted = stats
-
-        # --- draft proposes gamma tokens -----------------------------
-        # First step feeds [prev, cur] (prev rewrites its own KV slot,
-        # cur extends), then gamma-1 single-token steps.
-        two = jnp.stack([prev, cur], axis=1)  # [B, 2]
-        dlogits, dcache2 = draft_fam.forward(
-            draft_params, draft_cfg, two, dcache, use_flash=use_flash,
-            flash_mesh=flash_mesh,
-        )
-        if sampled_mode:
-            # Per-round, per-row keys: row seed ⊕ round ⊕ position tag
-            # (tags 1..gamma draft draws, 700 uniforms, 900 residual).
-            rk = jax.vmap(jax.random.fold_in, in_axes=(0, None))(
-                row_keys, rounds + 1
-            )
-            d1 = _draw(dlogits[:, -1], _fold(rk, 1))
-            q0 = jax.nn.log_softmax(dlogits[:, -1] / safe_t, axis=-1)
-        else:
-            d1 = jnp.argmax(dlogits[:, -1], axis=-1).astype(jnp.int32)
-
-        def draft_step(c, pos):
-            tok, dc = c
-            lg, dc = draft_fam.forward(
-                draft_params, draft_cfg, tok[:, None], dc,
-                use_flash=use_flash, flash_mesh=flash_mesh,
-            )
-            lgl = lg[:, -1]
-            if sampled_mode:
-                nxt = _draw(lgl, _fold(rk, 1 + pos))
-                return (nxt, dc), (nxt, lgl)
-            nxt = jnp.argmax(lgl, axis=-1).astype(jnp.int32)
-            # Greedy program: don't carry [gamma-1, B, V] logits the
-            # acceptance rule never reads.
-            return (nxt, dc), nxt
-
-        if gamma > 1:
-            (_, dcache2), ys = jax.lax.scan(
-                draft_step, (d1, dcache2), jnp.arange(1, gamma)
-            )
-            rest, rest_lg = ys if sampled_mode else (ys, None)
-            proposals = jnp.concatenate([d1[:, None], rest.T], axis=1)
-            if sampled_mode:
-                qlogp = jnp.moveaxis(
-                    jnp.concatenate([
-                        q0[None],
-                        jax.nn.log_softmax(
-                            rest_lg / safe_t[None], axis=-1
-                        ),
-                    ], axis=0), 0, 1,
-                )  # [B, gamma, V]
-        else:
-            proposals = d1[:, None]  # [B, gamma]
-            if sampled_mode:
-                qlogp = q0[:, None]  # [B, 1, V]
-
-        # --- target verifies in ONE forward --------------------------
-        verify_in = jnp.concatenate([cur[:, None], proposals], axis=1)
-        vlogits, tcache2 = target_fam.forward(
-            target_params, target_cfg, verify_in, tcache,
-            use_flash=use_flash, flash_mesh=flash_mesh,
-        )
-        greedy = jnp.argmax(vlogits, axis=-1).astype(jnp.int32)  # [B, gamma+1]
-        # greedy[:, i] is the target's token AFTER verify_in[:, i]:
-        # greedy rows accept proposal i (= proposals[:, i]) iff it
-        # equals greedy[:, i] and all earlier proposals were accepted;
-        # sampled rows accept with probability min(1, p(x)/q(x)).
-        if sampled_mode:
-            vlogp = jax.nn.log_softmax(
-                vlogits / safe_t[:, :, None], axis=-1
-            )  # [B, gamma+1, V]
-            u = jax.vmap(
-                # graftlint: disable=sharded-sampling -- [gamma]-shaped accept uniforms: no sharding spec ever maps a mesh axis to the gamma dim, so the draw is replicated and bit-identical on any mesh (the hazard is vocab-shaped noise)
-                lambda k: jax.random.uniform(k, (gamma,))
-            )(_fold(rk, 700))
-            logp_x = jnp.take_along_axis(
-                vlogp[:, :gamma], proposals[:, :, None], axis=2
-            )[:, :, 0]
-            logq_x = jnp.take_along_axis(
-                qlogp, proposals[:, :, None], axis=2
-            )[:, :, 0]
-            match = jnp.where(
-                is_sampled[:, None],
-                jnp.log(u) < (logp_x - logq_x),
-                proposals == greedy[:, :gamma],
-            )
-        else:
-            match = proposals == greedy[:, :gamma]
-        acc_mask = jnp.cumprod(match.astype(jnp.int32), axis=1)
-        a = acc_mask.sum(axis=1)  # [B] in [0, gamma]
-        correction = jnp.take_along_axis(greedy, a[:, None], axis=1)[:, 0]
-        if sampled_mode:
-            # Correction: residual distribution max(p - q, 0)/Z at the
-            # first rejected position; the bonus token after gamma
-            # acceptances samples p directly.
-            p_a = jnp.take_along_axis(
-                vlogp, a[:, None, None], axis=1
-            )[:, 0]  # [B, V] log p at the correction position
-            q_a = jnp.take_along_axis(
-                qlogp, jnp.clip(a, 0, gamma - 1)[:, None, None], axis=1
-            )[:, 0]
-            resid = jnp.maximum(jnp.exp(p_a) - jnp.exp(q_a), 0.0)
-            resid = jnp.where(
-                (a == gamma)[:, None], jnp.exp(p_a), resid
-            )
-            g2 = jax.vmap(
-                # graftlint: disable=sharded-sampling -- [V]-shaped residual draw of a lossless rejection sampler: the emitted distribution is exact on any mesh; bit-level cross-mesh identity is only claimed for greedy rows, which never reach this draw
-                lambda k: jax.random.gumbel(k, (resid.shape[-1],))
-            )(_fold(rk, 900))
-            samp_corr = jnp.argmax(
-                jnp.log(resid + 1e-30) + g2, axis=-1
-            ).astype(jnp.int32)
-            correction = jnp.where(is_sampled, samp_corr, correction)
-
-        # --- emit [d_1..d_a, correction] -----------------------------
-        idx = jnp.arange(gamma + 1)[None, :]
-        cand = jnp.where(
-            idx < a[:, None],
-            jnp.pad(proposals, ((0, 0), (0, 1))),
-            jnp.where(idx == a[:, None], correction[:, None], 0),
-        )  # [B, gamma+1]
-        c = a + 1
-        # A row is live until EOS or its length cap — capped rows must
-        # stop advancing cache lengths and inflating draft statistics.
-        live = ~has_eos & (out_len < max_new)
-        pos = out_len[:, None] + idx  # [B, gamma+1]
-        write = live[:, None] & (idx < c[:, None]) & (pos < max_new)
-        batch_idx = jnp.arange(b)[:, None]
-        safe_pos = jnp.where(write, pos, max_new_budget)  # scratch column
-        out = out.at[batch_idx, safe_pos].set(cand)
-        emitted = jnp.where(live, jnp.minimum(c, max_new - out_len), 0)
-        out_len = out_len + emitted
-        new_eos = (jnp.where(write, cand, -1) == eos_id).any(axis=1)
-        has_eos = has_eos | new_eos
-
-        # --- cache/length accounting (rollback on rejection) ---------
-        # Target consumed [cur, d_1..d_gamma] at tlen..tlen+gamma; the
-        # valid prefix after acceptance ends at d_a → length = tlen+a+1.
-        # Draft's next [prev', cur'] = [last-accepted, correction], and
-        # prev' must rewrite its own slot → dlen' = dlen + 1 + a.
-        tlen = tcache.length
-        dlen = dcache.length
-        tcache2 = tcache2._replace(
-            length=jnp.where(live, tlen + a + 1, tlen)
-        )
-        dcache2 = dcache2._replace(
-            length=jnp.where(live, dlen + 1 + a, dlen)
-        )
-        prev2 = jnp.where(
-            a == 0, cur,
-            jnp.take_along_axis(
-                proposals, jnp.maximum(a - 1, 0)[:, None], axis=1
-            )[:, 0],
-        )
-        prev = jnp.where(live, prev2, prev)
-        cur = jnp.where(live, correction, cur)
-
-        stats = (
-            rounds + 1,
-            drafted + jnp.sum(jnp.where(live, gamma, 0)),
-            accepted + jnp.sum(jnp.where(live, a, 0)),
-        )
-        return (tcache2, dcache2, prev, cur, out, out_len, has_eos, stats)
-
-    stats0 = (jnp.int32(0), jnp.int32(0), jnp.int32(0))
-    carry = (tcache, dcache, prev, first, out, out_len, has_eos, stats0)
-    (_, _, _, _, out, out_len, _, stats) = jax.lax.while_loop(
-        cond, round_body, carry
-    )
-
-    out = out[:, :max_new_budget]  # drop the scratch column
-    # Same eos post-pass as the plain fused path (engine._generate_impl):
-    # out_len counts tokens up to and including the first eos.
-    is_eos = out == eos_id
-    any_eos = is_eos.any(axis=1)
-    first_eos = jnp.argmax(is_eos, axis=1)
-    final_len = jnp.where(
-        any_eos, jnp.minimum(first_eos + 1, out_len), out_len
-    )
-    return SpecResult(
-        tokens=out, out_len=final_len,
-        rounds=stats[0], drafted=stats[1], accepted=stats[2],
-    )
-
-
-def _kv_class(fam):
-    """The family's KV cache type (models expose it as `KVCache`)."""
-    return fam.KVCache
 
 
 def spec_tick(
@@ -365,11 +56,11 @@ def spec_tick(
     j_tokens=None,  # [S, J] int32 forced-run token ids
 ):
     """One FIXED-SHAPE draft/verify round over a continuous-batcher slot
-    pool (the batching.speculative=on tick body, serving/batching.py).
+    pool (the tick body when a draft is configured, serving/batching.py).
 
     Per round: the draft proposes `gamma` tokens (first feed is
-    [prev, cur] so `prev` rewrites its own KV slot — the one-behind
-    invariant from `speculative_generate`), then the target verifies
+    [prev, cur] so `prev` rewrites its own KV slot — the draft cache
+    stays one position behind the target's), then the target verifies
     [cur, d_1..d_gamma] in ONE (gamma+1)-position forward against the
     shared cache. Variable advance WITHOUT dynamic shapes: every row
     writes all gamma+1 target positions every round and only the length
@@ -384,8 +75,7 @@ def spec_tick(
       * temperature > 0 — rejection sampling over the per-row
         temp→top-k→top-p FILTERED p and q (filtered_logprobs applies
         the identical filter to both, which is what keeps the sampler
-        lossless for filtered distributions — the variant the sidecar
-        routing previously descoped);
+        lossless for filtered distributions);
       * constrained rows — the DFA allow-mask is applied to the draft's
         proposal distribution AND every verify position, with states
         advanced along the proposal path, so the emitted sequence obeys

@@ -337,23 +337,15 @@ class ContinuousBatcher:
         self._pipeline = mode == "on" or (
             mode == "auto" and platform == "tpu"
         )
-        # Speculative decoding inside this batcher (batching.speculative
-        # = "on" + a configured draft): every tick becomes one
-        # fixed-shape draft/verify round (ops/speculative.spec_tick) —
+        # Speculative decoding inside this batcher (a configured
+        # serving.speculative_draft is the switch): every tick becomes
+        # one fixed-shape draft/verify round (ops/speculative.spec_tick) —
         # gamma draft steps against a per-slot draft cache, one fused
         # (gamma+1)-position target verify over the shared pool, and
         # variable advance as per-slot length-pointer arithmetic. The
         # per-tick advance bound is gamma+1 (not steps_per_tick), so
         # the overshoot reserve re-derives from it.
-        spec_mode = getattr(self.cfg, "speculative", "off") == "on"
-        self._spec = (
-            spec_mode and getattr(engine, "draft_fam", None) is not None
-        )
-        if spec_mode and not self._spec:
-            logger.warning(
-                "batching.speculative=on but no serving.speculative_draft "
-                "is configured; falling back to the plain tick"
-            )
+        self._spec = getattr(engine, "draft_fam", None) is not None
         self._gamma = (
             max(1, int(getattr(engine.serving, "speculative_gamma", 4)))
             if self._spec else 0
@@ -430,7 +422,7 @@ class ContinuousBatcher:
             # config.validate mirrors this; batchers built directly in
             # tests must hit the same wall.
             raise ValueError(
-                "batching.speculative does not compose with kv_ring"
+                "speculative_draft does not compose with kv_ring"
             )
         if self._ring:
             engine_chunk = engine.serving.batching.prefill_chunk
@@ -452,22 +444,16 @@ class ContinuousBatcher:
         # Paged KV plane (batching.paged_kv=on, docs/paged_kv.md): the
         # shared cache becomes one page ARENA + per-slot block tables
         # (models/llama.py::PagedKVCache) and a host-side refcounted
-        # allocator (serving/pages.py) replaces the slot-granular
-        # prefix pool — token-level, page-aligned prefix sharing with
-        # copy-on-write at the divergent page. The contiguous path
-        # stays the off-mode so bit-identity is provable
-        # (tests/test_paged_kv.py).
+        # allocator (serving/pages.py) gives token-level, page-aligned
+        # prefix sharing with copy-on-write at the divergent page — the
+        # one prefix-reuse mechanism. The contiguous path stays the
+        # off-mode so bit-identity is provable (tests/test_paged_kv.py).
         self._paged = getattr(self.cfg, "paged_kv", "off") == "on"
         if self._paged:
             # config.validate mirrors these; batchers built directly in
             # tests must hit the same walls.
             if self._ring:
                 raise ValueError("paged_kv does not compose with kv_ring")
-            if self.cfg.prefix_cache_entries:
-                raise ValueError(
-                    "paged_kv supersedes the slot-granular prefix pool; "
-                    "set prefix_cache_entries to 0"
-                )
             page = max(1, int(getattr(self.cfg, "paged_kv_page_size", 16)))
             if s_max % page:
                 raise ValueError(
@@ -532,7 +518,7 @@ class ContinuousBatcher:
         # (host seed + device twin): the spec round's first draft feed
         # is [prev, cur] so prev rewrites its own draft-KV slot,
         # keeping the draft cache exactly one position behind the
-        # target (the speculative_generate invariant).
+        # target.
         if self._spec:
             self._fit_limit = min(
                 self._fit_limit, engine.draft_cfg.max_seq_len
@@ -632,31 +618,8 @@ class ContinuousBatcher:
         # they took from pages or a prefix entry instead.
         self.prefill_tokens = {"computed": 0, "reused": 0}
 
-        # Prefix (prompt-KV) cache: pool entries shaped like mini-cache
-        # rows so a hit is ONE dynamic_update_slice into the admission
-        # mini cache. Host maps (token tuples, lengths, LRU stamps) are
-        # touched only inside this batcher's serialized executor calls
-        # (docs/threading.md — batcher-owned state, no new contexts).
-        pe = self.cfg.prefix_cache_entries
-        self._pfx_max = min(self.cfg.prefix_cache_max_seq, s_max)
-        self._pfx_min = max(1, self.cfg.prefix_cache_min_seq)
-        # A storable prompt needs _pfx_min+1 tokens AND must be
-        # admissible: fit_request caps prompts at s_max minus the tick
-        # overshoot reserve, max_new (>= 1), and the next position.
-        poolable = (
-            self._pfx_min + 1 <= s_max - self._reserve - 2
-            and not self._ring  # pooled prefixes assume contiguous layout
-        )
-        if pe > 0 and poolable:
-            self._pfx_pool = engine.make_cache(pe, self._pfx_max)
-            self._pfx_keys: list[Optional[np.ndarray]] = [None] * pe
-            self._pfx_used = [0] * pe  # LRU stamps
-            self._pfx_clock = 0
-        else:
-            # Also lands here when this pool's cache is too short for
-            # any admissible poolable prefix (a small kv tier): no
-            # entries, no HBM.
-            self._pfx_pool = None
+        # Admissions that reused shared pages, and those that could not
+        # (the paged path stamps both).
         self.prefix_hits = 0
         self.prefix_misses = 0
 
@@ -765,25 +728,13 @@ class ContinuousBatcher:
             self._admit_single_impl, donate_argnums=(3,)
         )
         self._admit_full = jax.jit(self._admit_full_impl, donate_argnums=(3,))
-        # Chunked prefill for prompts longer than cfg.prefill_chunk:
-        # fixed [1, C] steps into a full-length mini cache — ONE
-        # compiled shape for any prompt length, and activations stay
-        # [1, C, ·] instead of [1, S, ·] (bounded memory at long S).
-        self._chunk_step = jax.jit(self._chunk_step_impl, donate_argnums=(2,))
-        self._insert_row = jax.jit(self._insert_row_impl, donate_argnums=(0,))
         # Fused chunked admission: the WHOLE multi-chunk prefill of an
         # admission group — mini-cache creation, lax.scan over [T, C]
         # chunk steps, per-row final-logit select, shared-cache merge,
         # first-token sample — in ONE device call instead of
-        # ~(4 + chunks)·rows of them. The _pfx variant additionally
-        # seeds every row from a prefix-pool entry before the scan
-        # (pool NOT donated — stores are rare and an undonated pool
-        # survives call failure).
+        # ~(4 + chunks)·rows of them.
         self._admit_chunked = jax.jit(
             self._admit_chunked_impl, donate_argnums=(3,)
-        )
-        self._admit_chunked_pfx = jax.jit(
-            self._admit_chunked_pfx_impl, donate_argnums=(3,)
         )
         # Paged prefix-reuse admission: gather the shared-page view
         # into a fresh mini through a host-built gather table, run the
@@ -795,16 +746,6 @@ class ContinuousBatcher:
             self._admit_paged_pfx = jax.jit(
                 self._admit_paged_pfx_impl, donate_argnums=(3,)
             )
-        self._first_token = jax.jit(self._first_token_impl)
-        # Prefix-pool store/load. The POOL is deliberately NOT donated:
-        # stores are rare (first sighting of a prefix), entries are
-        # small, and an undonated pool stays valid if a call fails. The
-        # load's fresh mini IS donated — its caller always reassigns,
-        # and without donation every hit would allocate + copy a dead
-        # full-size [1, S_max] KV row.
-        self._pfx_store = jax.jit(self._pfx_store_impl)
-        self._pfx_store_slot = jax.jit(self._pfx_store_slot_impl)
-        self._pfx_load = jax.jit(self._pfx_load_impl, donate_argnums=(0,))
         # Stall-free prefill/decode interleaving (prefill_interleave=
         # "on"): long prompts arriving mid-decode become per-tick chunk
         # work items instead of one serialized [T, C] grid call. Each
@@ -831,7 +772,7 @@ class ContinuousBatcher:
         self._ilv_finish = jax.jit(
             self._ilv_finish_impl, donate_argnums=(0,)
         )
-        # Speculative tick programs (batching.speculative=on): the
+        # Speculative tick programs (a draft is configured): the
         # draft/verify round (both slot-pool caches donated), its
         # tick+chunk fusion for interleaved admission (the carried mini
         # donated too), and the draft-side admission prefill (draft
@@ -884,9 +825,6 @@ class ContinuousBatcher:
         )
         engine.ledger.register(
             "draft_cache", lambda: self.dcache, scope=ledger_scope
-        )
-        engine.ledger.register(
-            "prefix_pool", lambda: self._pfx_pool, scope=ledger_scope
         )
         engine.ledger.register(
             "ilv_mini", lambda: self._ilv_mini, scope=ledger_scope
@@ -1434,7 +1372,7 @@ class ContinuousBatcher:
         self, params, tokens, true_len, cache, slots, seeds, temps, ks,
         ps, adapters, g0, g_allow, g_trans,
     ):
-        """Fused chunked admission (no prefix): the whole [R, T, C]
+        """Fused chunked admission (nothing reused): the whole [R, T, C]
         prefill grid + merge + first-token sample, ONE device call.
         R is the caller's bucketed group size — per-row work here is
         the heavy case (long prompts), so a trickle admission must not
@@ -1443,39 +1381,6 @@ class ContinuousBatcher:
         mini = self._make_mini(r, self.max_seq)
         fl, mini = self._chunked_scan(
             params, tokens, true_len, mini, adapters, jnp.int32(0)
-        )
-        return self._chunked_finish(
-            cache, mini, slots, true_len, fl, seeds, temps, ks, ps,
-            g0, g_allow, g_trans,
-        )
-
-    def _admit_chunked_pfx_impl(
-        self, params, tokens, true_len, cache, slots, seeds, temps, ks,
-        ps, adapters, pool, entry, start, g0, g_allow, g_trans,
-    ):
-        """Fused prefix-reuse admission: pool entry `entry` seeds the
-        first `start` positions of EVERY row, then the [R, 1, W] suffix
-        grid runs from `start`. One device call admits a whole wave of
-        same-preamble requests — the agentic arrival shape."""
-        b = tokens.shape[0]
-        mini = self._make_mini(b, self.max_seq)
-
-        def load(m, p):
-            row = jax.lax.dynamic_slice_in_dim(p, entry, 1, axis=1)
-            row = jnp.broadcast_to(
-                row, row.shape[:1] + (b,) + row.shape[2:]
-            )
-            return jax.lax.dynamic_update_slice(
-                m, row.astype(m.dtype), (0,) * m.ndim
-            )
-
-        mini = llama_mod.KVCache(
-            k=quant.kv_map(load, mini.k, pool.k),
-            v=quant.kv_map(load, mini.v, pool.v),
-            length=jnp.full((b,), start, jnp.int32),
-        )
-        fl, mini = self._chunked_scan(
-            params, tokens, true_len, mini, adapters, start
         )
         return self._chunked_finish(
             cache, mini, slots, true_len, fl, seeds, temps, ks, ps,
@@ -1799,9 +1704,9 @@ class ContinuousBatcher:
     ):
         """Final-chunk completion for one interleaved admission: copy
         mini row `row` into the shared cache at `slot` with true length
-        `n` (the _merge_row machinery — same as _insert_row) and sample
-        the first token from that row's final-position logits `sel`
-        (step 0, matching _chunked_finish/_first_token)."""
+        `n` (the _merge_row machinery) and sample the first token from
+        that row's final-position logits `sel` (step 0, matching
+        _chunked_finish)."""
 
         def pick(m):
             return jax.lax.dynamic_slice_in_dim(m, row, 1, axis=1)
@@ -1824,26 +1729,6 @@ class ContinuousBatcher:
         )
         return first, cache
 
-    def _chunk_step_impl(self, params, tokens, mini, true_len, adapter):
-        """One [1, C] prefill chunk appended to the row's mini cache at
-        its current length. Returns (last-position logits [1, V], mini)."""
-        if self._is_moe:
-            offset = mini.length[:, None]
-            valid = (offset + jnp.arange(tokens.shape[1])[None, :]) < true_len
-        else:
-            valid = None
-        # Cache-extending step (not a fresh prefill) → decode_forward.
-        logits, mini = self.engine.decode_forward(
-            params, tokens, mini, valid=valid, ring=self._ring,
-            lora_idx=adapter,
-        )
-        return logits, mini
-
-    def _insert_row_impl(self, cache, mini, slot, length):
-        """Copy a [1, ≤S_max] mini cache row into the shared cache at
-        `slot` with the row's true length (shared with fused admission)."""
-        return _merge_row(cache, mini, slot, length)
-
     def _first_token_impl(
         self, logits, idx, seeds, temps, ks, ps, g0, g_allow, g_trans
     ):
@@ -1852,308 +1737,6 @@ class ContinuousBatcher:
             last, seeds, jnp.int32(0), temps, ks, ps, g0, g_allow, g_trans
         )
         return first
-
-    def _pfx_store_impl(self, pool, mini, entry, plen):
-        """Copy the first `_pfx_max` cache positions of a fully
-        prefilled mini row into pool entry `entry` (the same row-merge
-        as slot insertion, with the mini clipped to the pool width)."""
-        m = self._pfx_max
-
-        def clip(a):
-            return a[:, :, :m]
-
-        clipped = llama_mod.KVCache(
-            k=quant.kv_map(clip, mini.k),
-            v=quant.kv_map(clip, mini.v),
-            length=mini.length,
-        )
-        return _merge_row(pool, clipped, entry, plen)
-
-    def _pfx_store_slot_impl(self, pool, cache, slot, entry, plen):
-        """_pfx_store from a SHARED-cache row instead of an admission
-        mini (burst learning): slice slot's row out of the pool-width
-        head of the cache and merge it into pool entry `entry`. Prefix
-        KV depends only on prefix tokens (causal), so any admitted row
-        holding the prefix is a valid source."""
-        m = self._pfx_max
-
-        def pick(c):
-            return jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1)[:, :, :m]
-
-        row = llama_mod.KVCache(
-            k=quant.kv_map(pick, cache.k),
-            v=quant.kv_map(pick, cache.v),
-            length=jnp.full((1,), plen, jnp.int32),
-        )
-        return _merge_row(pool, row, entry, plen)
-
-    def _pfx_load_impl(self, mini, pool, entry, plen):
-        """Write pool entry `entry` into a fresh mini cache's head and
-        set its length to the prefix length: the chunked prefill then
-        extends from position `plen` exactly as if the prefix had just
-        been prefilled. Stale pool positions past `plen` are overwritten
-        by the suffix chunks or masked by the final length."""
-
-        def load(m, p):
-            row = jax.lax.dynamic_slice_in_dim(p, entry, 1, axis=1)
-            return jax.lax.dynamic_update_slice(
-                m, row.astype(m.dtype), (0, 0, 0, 0, 0)
-            )
-
-        return llama_mod.KVCache(
-            k=quant.kv_map(load, mini.k, pool.k),
-            v=quant.kv_map(load, mini.v, pool.v),
-            length=jnp.full((1,), plen, jnp.int32),
-        )
-
-    # -- prefix-pool host side (executor-serialized, batcher-owned) ---------
-
-    @staticmethod
-    def _lcp(a: np.ndarray, b: np.ndarray, limit: int) -> int:
-        m = min(len(a), len(b), limit)
-        neq = np.nonzero(a[:m] != b[:m])[0]
-        return int(neq[0]) if neq.size else m
-
-    def _pfx_plan(
-        self, n: int, plen: int
-    ) -> tuple[int, list[tuple[int, int]]]:
-        """Prefill step geometry for an n-token prompt whose first
-        `plen` positions are pooled: the reuse point `start` (0 = the
-        pooled KV is unusable) and the (offset, width) prefill steps
-        covering [start, n). Every step writes its full [1, width]
-        block at the cache offset, so offset + width must stay inside
-        the mini cache (dynamic_update_slice would clamp the start and
-        silently overwrite the prefix), and every non-final step must
-        be completely filled with real tokens (intermediate cache
-        lengths count the whole block). Short suffixes run as ONE
-        bucketed step whose start is lowered until it fits; long
-        suffixes take one bucketed BRIDGE step from below the hit point
-        to the next chunk boundary, then re-enter the fixed chunk grid
-        — either way reuse is plen minus at most a bucket's rounding."""
-        c = min(self.cfg.prefill_chunk, self.max_seq)
-        if n - plen <= c:
-            width = bucket_len(n - plen, maximum=self.max_seq)
-            start = max(0, min(plen, self.max_seq - width))
-            return start, [(start, bucket_len(n - start, maximum=self.max_seq))]
-        boundary = (plen // c + 1) * c
-        width = bucket_len(boundary - plen, maximum=self.max_seq)
-        start = boundary - width
-        if start >= 0:
-            return start, [(start, width)] + [
-                (off, c) for off in range(boundary, n, c)
-            ]
-        # Tiny chunk sizes: no alignment possible.
-        return 0, [(off, c) for off in range(0, n, c)]
-
-    def _pfx_lookup(self, prompt: list[int]) -> Optional[tuple[int, int]]:
-        """Entry with the longest common prefix against `prompt` —
-        partial reuse: a hit at lcp < entry length loads the entry and
-        recomputes only from the divergence point. The match is capped
-        at len(prompt)-1 (at least one suffix token must run through
-        the model to produce sampling logits), and a match the step
-        geometry cannot reuse (plan start 0) is not a hit — it neither
-        refreshes the LRU stamp nor diverts the request from fused
-        admission. Returns (entry, prefix_len) or None."""
-        if self._pfx_pool is None or all(
-            key is None for key in self._pfx_keys
-        ):
-            return None
-        arr = np.asarray(prompt[: self._pfx_max], np.int32)
-        limit = len(prompt) - 1
-        best: Optional[tuple[int, int]] = None
-        for e, key in enumerate(self._pfx_keys):
-            if key is None:
-                continue
-            lcp = self._lcp(key, arr, limit)
-            if lcp >= self._pfx_min and (best is None or lcp > best[1]):
-                best = (e, lcp)
-        if best is None or self._pfx_plan(len(prompt), best[1])[0] == 0:
-            return None
-        self._pfx_clock += 1
-        self._pfx_used[best[0]] = self._pfx_clock
-        return best
-
-    def _pfx_covered(self, arr: np.ndarray, length: int) -> bool:
-        """True if some pooled key already covers the first `length`
-        tokens of `arr` — storing another entry for them could never
-        out-match it (shared by burst learning and the trickle store)."""
-        if self._pfx_pool is None:
-            return False
-        return any(
-            k is not None and len(k) >= length
-            and self._lcp(k, arr, length) == length
-            for k in self._pfx_keys
-        )
-
-    def _pfx_storable(self, prompt: list[int]) -> Optional[np.ndarray]:
-        """The key this prompt's prefix would pool under, or None if
-        too short. (Whether pooling adds anything over an existing hit
-        is the caller's check — it knows the hit length.)"""
-        if self._pfx_pool is None:
-            return None
-        plen = min(len(prompt) - 1, self._pfx_max)
-        if plen < self._pfx_min:
-            return None
-        return np.asarray(prompt[:plen], np.int32)
-
-    def _pfx_insert(self, mini, key: np.ndarray) -> None:
-        """Pool `key`'s KV out of a fully prefilled mini row."""
-        self._pfx_commit(key, lambda entry: self._pfx_store(
-            self._pfx_pool, mini, jnp.int32(entry), jnp.int32(len(key))
-        ))
-
-    def _pfx_commit(self, key: np.ndarray, pool_fn) -> None:
-        """Shared insert bookkeeping: pick the entry (free, else LRU),
-        run `pool_fn(entry)` to produce the updated pool, evict any
-        entry the new key subsumes. A device failure only skips the
-        caching (the pool is never donated)."""
-        free = [e for e, k in enumerate(self._pfx_keys) if k is None]
-        entry = free[0] if free else min(
-            range(len(self._pfx_keys)), key=lambda e: self._pfx_used[e]
-        )
-        try:
-            pool = pool_fn(entry)
-            jax.block_until_ready(pool.length)
-        except Exception:
-            logger.exception("prefix-pool store failed; entry not cached")
-            return
-        self._pfx_pool = pool
-        self._pfx_keys[entry] = key
-        self._pfx_clock += 1
-        self._pfx_used[entry] = self._pfx_clock
-        for e, other in enumerate(self._pfx_keys):
-            if (
-                e != entry and other is not None
-                and len(other) <= len(key)
-                and self._lcp(other, key, len(key)) == len(other)
-            ):
-                # `key` extends `other`: the shorter entry can never
-                # out-match the new one again.
-                self._pfx_keys[e] = None
-
-    def _pfx_learn_from_burst(
-        self, slots_idx: list[int], batch: list[_Request]
-    ) -> None:
-        """A cold burst sharing a NEW poolable prefix must not leave
-        the pool empty (the exact agentic arrival pattern the pool
-        exists for: N sessions landing together with the same system
-        prompt). After a fused admission, pool the prefix shared by
-        the most rows, copied from one admitted row's cache slice —
-        one extra device call, only when at least two rows share it."""
-        if self._pfx_pool is None or len(batch) < 2:
-            return
-        # Base-model rows only: a cache slice computed under an adapter
-        # must never seed the shared pool (_prefill_into_slots).
-        slots_idx = [
-            s for s, r in zip(slots_idx, batch) if r.adapter == 0
-        ]
-        batch = [r for r in batch if r.adapter == 0]
-        if len(batch) < 2:
-            return
-        prompts = [
-            np.asarray(r.prompt[: self._pfx_max + 1], np.int32)
-            for r in batch
-        ]
-        best: Optional[tuple[int, int, int]] = None  # (count, lcp, row)
-        for i in range(len(prompts)):
-            for j in range(i + 1, len(prompts)):
-                a, b = prompts[i], prompts[j]
-                # each sharer must keep ≥1 suffix token past the prefix
-                lcp = self._lcp(
-                    a, b, min(len(a) - 1, len(b) - 1, self._pfx_max)
-                )
-                if lcp < self._pfx_min:
-                    continue
-                key = a[:lcp]
-                count = sum(
-                    1 for p in prompts
-                    if len(p) > lcp and np.array_equal(p[:lcp], key)
-                )
-                cand = (count, lcp, i)
-                if best is None or cand[:2] > best[:2]:
-                    best = cand
-        if best is None:
-            return
-        _, lcp, row = best
-        key = prompts[row][:lcp]
-        if self._pfx_covered(key, lcp):
-            return  # an existing entry already covers this prefix
-        slot = slots_idx[row]
-        self._pfx_commit(key, lambda entry: self._pfx_store_slot(
-            self._pfx_pool, self.cache, jnp.int32(slot),
-            jnp.int32(entry), jnp.int32(lcp),
-        ))
-
-    def _prefill_chunked(
-        self,
-        slot_idx: int,
-        request: _Request,
-        pfx: Optional[tuple[int, int]] = None,
-    ) -> None:
-        """Admission for a long or prefix-pooled prompt: fixed-size
-        chunks into a full-length mini cache, then one insert + one
-        sample. With a prefix hit `pfx=(entry, plen)` the pooled KV
-        seeds the mini cache and only prompt[plen:] runs the model."""
-        prompt = request.prompt
-        n = len(prompt)
-        c = min(self.cfg.prefill_chunk, self.max_seq)
-        adapter1 = jnp.asarray([request.adapter], jnp.int32)
-        mini = self._make_mini(1, self.max_seq)
-        start = 0
-        if pfx is not None:
-            # Lookup already rejected geometrically unusable matches,
-            # so start > 0 here (see _pfx_plan for the step rules).
-            entry, plen = pfx
-            start, steps = self._pfx_plan(n, plen)
-            self.prefix_hits += 1
-            mini = self._pfx_load(
-                mini, self._pfx_pool, jnp.int32(entry), jnp.int32(start)
-            )
-        else:
-            steps = [(off, c) for off in range(0, n, c)]
-        logits = None
-        true_len = jnp.asarray([n], jnp.int32)
-        for off, width in steps:
-            chunk = np.zeros((1, width), np.int32)
-            piece = prompt[off : off + width]
-            chunk[0, : len(piece)] = piece
-            logits, mini = self._chunk_step(
-                self.engine.params, jnp.asarray(chunk), mini, true_len,
-                adapter1,
-            )
-        # Pool the prefix on first sighting — also when a SHORTER
-        # pooled prefix hit (the mini row holds the full prompt's KV
-        # either way, so the longer entry upgrades future matches).
-        # BASE rows only: adapter'd K/V must never enter the shared
-        # pool (_prefill_into_slots has the full rationale).
-        key = (
-            self._pfx_storable(prompt) if request.adapter == 0 else None
-        )
-        if key is not None and (pfx is None or pfx[1] < len(key)):
-            self._pfx_insert(mini, key)
-        mini = mini._replace(length=jnp.asarray([n], jnp.int32))
-        self._cache_at_risk = True
-        self.cache = self._insert_row(
-            self.cache, mini, jnp.int32(slot_idx), jnp.int32(n)
-        )
-        # Under JAX async dispatch a device failure inside the donating
-        # call surfaces only at materialization — force it BEFORE
-        # declaring the shared cache safe, or the failure handler would
-        # skip the rebuild of a poisoned cache.
-        jax.block_until_ready(self.cache.length)
-        self._cache_at_risk = False
-        # Last real token sits at n - last_step_offset - 1 of the final
-        # step (always < that step's width).
-        g_allow, g_trans = self._grammar_tables()
-        first = self._first_token(
-            logits, jnp.asarray([n - steps[-1][0] - 1], jnp.int32),
-            jnp.asarray([request.seed & 0xFFFFFFFF], jnp.uint32),
-            jnp.asarray([request.sampling.temperature], jnp.float32),
-            jnp.asarray([request.sampling.top_k], jnp.int32),
-            jnp.asarray([request.sampling.top_p], jnp.float32),
-            jnp.asarray([self._g0(request)], jnp.int32), g_allow, g_trans,
-        )
-        self._activate_slot(slot_idx, request, int(np.asarray(first)[0]))
 
     def _activate_slot(
         self, slot_idx: int, request: _Request, first_tok: int
@@ -2461,12 +2044,12 @@ class ContinuousBatcher:
         if self._paged:
             # Paged prefix-reuse admission ladder: every suffix-width
             # bucket a page hit can pick, trickle (R=1) and wave (R=B)
-            # row shapes — the same no-cold-compile-mid-request policy
-            # as the pool ladder below. All-sentinel gather tables and
-            # out-of-range slots keep it inert (reads clip to junk that
-            # is never merged; merges drop). Deeper [R, T>1, C] suffix
-            # grids compile on their first long shared prompt, exactly
-            # like the cold chunked grids.
+            # row shapes — no live request pays a cold compile.
+            # All-sentinel gather tables and out-of-range slots keep it
+            # inert (reads clip to junk that is never merged; merges
+            # drop). Deeper [R, T>1, C] suffix grids compile on their
+            # first long shared prompt, exactly like the cold chunked
+            # grids.
             width = 32
             while width <= bucket_len(c, maximum=self.max_seq):
                 for r_rows in (1, b_rows) if b_rows > 1 else (1,):
@@ -2488,80 +2071,6 @@ class ContinuousBatcher:
                         jnp.asarray(zib[:r_rows]), g_allow, g_trans,
                     )
                 width *= 2
-        if self._pfx_pool is not None:
-            # plen=0 and no host-side key: the warmup entry can never
-            # match a lookup. Store programs first (mini from a plain
-            # make — stores only copy rows, no forward needed).
-            mini = self._make_mini(1, self.max_seq)
-            self._pfx_pool = self._pfx_store(
-                self._pfx_pool, mini, jnp.int32(0), jnp.int32(0)
-            )
-            # Burst/trickle learning stores from a shared-cache row —
-            # warm that program too, or the first store pays its
-            # compile inline.
-            self._pfx_pool = self._pfx_store_slot(
-                self._pfx_pool, self.cache, jnp.int32(0),
-                jnp.int32(0), jnp.int32(0),
-            )
-            # Warm the fused prefix admission for every suffix-width
-            # bucket a hit can pick ([B, 1, 32] .. [B, 1, bucket(c)])
-            # — a hit wave's first use must not pay a cold compile
-            # mid-request.
-            width = 32
-            while width <= bucket_len(c, maximum=self.max_seq):
-                # Hit shapes: the wave (R=B, the agentic arrival the
-                # pool exists for) AND the trickle single (R=1) —
-                # every compile here is one a live request never pays.
-                for r_rows in (1, b_rows) if b_rows > 1 else (1,):
-                    _, self.cache = self._admit_chunked_pfx(
-                        self.engine.params,
-                        jnp.asarray(np.zeros((r_rows, 1, width), np.int32)),
-                        jnp.asarray(zlenb[:r_rows]), self.cache,
-                        jnp.asarray(zslotb[:r_rows]),
-                        jnp.asarray(zseedb[:r_rows]),
-                        jnp.asarray(zfb[:r_rows]),
-                        jnp.asarray(zib[:r_rows]),
-                        jnp.asarray(ofb[:r_rows]),
-                        jnp.asarray(zib[:r_rows]),
-                        self._pfx_pool, jnp.int32(0), jnp.int32(0),
-                        jnp.asarray(zib[:r_rows]), g_allow, g_trans,
-                    )
-                width *= 2
-            # The SERIAL fallback (_prefill_chunked) still serves
-            # prefix hits whose suffix needs a multi-step bridge plan
-            # (suffix > prefill_chunk) — REACHABLE only when an
-            # admissible prompt can outgrow the chunk beyond the
-            # shortest poolable prefix. Most tiers can't (e.g. a
-            # 512-cap tier with a 512 chunk): skip their serial warm
-            # ladder entirely — every skipped program is start-up
-            # time returned.
-            if self._fit_limit - self._pfx_min > c:
-                mini = self._pfx_load(
-                    self._make_mini(1, self.max_seq), self._pfx_pool,
-                    jnp.int32(0), jnp.int32(0),
-                )
-                logits, mini = self._chunk_step(
-                    self.engine.params,
-                    jnp.asarray(np.zeros((1, c), np.int32)),
-                    mini, jnp.asarray(zlen1), jnp.asarray(zi1),
-                )
-                width = 32
-                while width <= bucket_len(c, maximum=self.max_seq):
-                    if width != c:
-                        logits, mini = self._chunk_step(
-                            self.engine.params,
-                            jnp.asarray(np.zeros((1, width), np.int32)),
-                            mini, jnp.asarray(zlen1), jnp.asarray(zi1),
-                        )
-                    width *= 2
-                self.cache = self._insert_row(
-                    self.cache, mini, jnp.int32(0), jnp.int32(0)
-                )
-                _ = self._first_token(
-                    logits, jnp.asarray(zi1), jnp.asarray(zseed1),
-                    jnp.asarray(zf1), jnp.asarray(zi1), jnp.asarray(of1),
-                    jnp.asarray(zi1), g_allow, g_trans,
-                )
         jax.block_until_ready(self.cache.k)
 
     def start(self) -> None:
@@ -2872,13 +2381,11 @@ class ContinuousBatcher:
 
     def cache_bytes(self) -> int:
         """KV-cache HBM: the shared slot pool (or paged arena + block
-        tables), the prefix pool, and the interleave mini cache (K
-        admission rows) once allocated."""
+        tables) and the interleave mini cache (K admission rows) once
+        allocated."""
         total = self.cache.k.nbytes + self.cache.v.nbytes
         if self._paged:
             total += self.cache.table.nbytes
-        if self._pfx_pool is not None:
-            total += self._pfx_pool.k.nbytes + self._pfx_pool.v.nbytes
         if self._ilv_mini is not None:
             total += self._ilv_mini.k.nbytes + self._ilv_mini.v.nbytes
         if self.dcache is not None:
@@ -2888,8 +2395,8 @@ class ContinuousBatcher:
     def stall_snapshot(self) -> list[float]:
         """Snapshot of recent decode-stall samples (ms between
         consecutive emissions to a live slot) — the in-process view the
-        interleave tests and bench.py's stall columns read;
-        concatenated across tiers by the tiered facade."""
+        interleave tests read; concatenated across tiers by the tiered
+        facade."""
         return list(self._stall_records)
 
     def stats(self) -> dict:
@@ -2957,8 +2464,8 @@ class ContinuousBatcher:
     # gateway_backend_memory_bytes{target, component} family.
     _LEDGER_ENGINE_COMPONENTS = ("weights", "lora")
     _LEDGER_BATCHER_COMPONENTS = (
-        "kv_arena", "block_tables", "draft_cache", "prefix_pool",
-        "ilv_mini", "grammar_arena", "tick_state",
+        "kv_arena", "block_tables", "draft_cache", "ilv_mini",
+        "grammar_arena", "tick_state",
     )
 
     def _memory_stats(self) -> dict:
@@ -3069,11 +2576,10 @@ class ContinuousBatcher:
             # piggybacked onto decode ticks / requests admitted that way.
             "interleaved_chunks": self.interleaved_chunks,
             "interleaved_admissions": self.interleaved_admissions,
-            # Speculative tick activity (batching.speculative=on):
+            # Speculative tick activity (a draft is configured):
             # draft/verify rounds run, draft tokens proposed, and
             # proposals accepted — spec_accepted/spec_drafted is THIS
-            # batcher's realized acceptance rate (the side micro-
-            # batcher's speculative_drafted/accepted stay separate).
+            # batcher's realized acceptance rate.
             "spec_ticks": self.spec_ticks,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,
@@ -3745,11 +3251,10 @@ class ContinuousBatcher:
                 # activated (chunked path emits per-request) got their
                 # success chunk — don't queue a second terminal chunk.
                 # The shared cache is rebuilt ONLY if the failing call
-                # was one that donates it (_admit_single/_admit_full/
-                # _insert_row); an exception from _chunk_step only
-                # killed its private mini cache, and nuking every
-                # active slot for it would turn one poisoned prompt
-                # into a full-pool outage.
+                # was one that donates it (_cache_at_risk); a failure
+                # before that dispatch killed nothing shared, and
+                # nuking every active slot for it would turn one
+                # poisoned prompt into a full-pool outage.
                 logger.exception(
                     "batched prefill failed for slots %s", slots_idx
                 )
@@ -3914,17 +3419,14 @@ class ContinuousBatcher:
     def _route_admission(
         self, slots_idx: list[int], batch: list[_Request]
     ) -> tuple[int, int]:
-        """Route each admission. Short cold prompts fuse into one
-        prefill call (_prefill_fused); prefix-pool hits group by
-        identical step geometry and long prompts group wholesale, each
-        group admitted by ONE fused chunked device call
-        (_admit_chunked_group). Only a prefix hit whose suffix needs a
-        multi-step bridge plan (rare: pooled prefix + suffix longer
-        than prefill_chunk) falls back to the serial per-row path.
+        """Route each admission. Rows that reuse shared pages group by
+        suffix geometry (_admit_paged_group); short cold prompts fuse
+        into one prefill call (_prefill_fused); long cold prompts group
+        wholesale, each group admitted by ONE fused chunked device call
+        (_admit_chunked_group).
         Returns (rows queued for interleaved chunks, rows shed)."""
         fused_slots: list[int] = []
         fused_batch: list[_Request] = []
-        pfx_groups: dict[tuple, list[tuple[int, _Request]]] = {}
         long_rows: list[tuple[int, _Request]] = []
         queued = 0  # rows diverted to the interleave queue (no prefill)
         # Interleave long prompts only while decode (or earlier chunk
@@ -3934,7 +3436,6 @@ class ContinuousBatcher:
         ilv = self._ilv_k > 0 and (
             self._active_count() > 0 or self._ilv_busy()
         )
-        trickle = len(batch) == 1
         # Paged pre-pass (batching.paged_kv=on): every row gets its
         # block table built FIRST — the longest page-aligned indexed
         # prefix is refcount-shared, a divergent-page CoW source is
@@ -3995,9 +3496,8 @@ class ContinuousBatcher:
                 else:
                     self.prefix_misses += 1
                     cold.append((sl, req))
-                    # Eager registration (the burst shape the old pool
-                    # served with _pfx_learn_from_burst): index this
-                    # cold row's full pages NOW, so same-round rows
+                    # Eager registration: index this cold row's
+                    # full pages NOW, so same-round rows
                     # sharing its preamble land in a paged group
                     # instead of recomputing it. Sound because cold
                     # fused/chunked calls dispatch BEFORE the paged
@@ -4014,30 +3514,7 @@ class ContinuousBatcher:
                         )
             rows = cold
         for sl, req in rows:
-            # The prefix pool holds BASE-model KV only: a pooled prefix
-            # computed under one adapter would silently seed a
-            # different adapter's (or the base model's) request with
-            # contaminated K/V. Adapter'd requests neither consult nor
-            # feed the pool (and don't count as misses — they never
-            # look).
-            pfx = self._pfx_lookup(req.prompt) if req.adapter == 0 else None
-            if pfx is None and self._pfx_pool is not None and req.adapter == 0:
-                # Every pool-enabled lookup miss counts — fused-path
-                # admissions included — or the exported hit/miss ratio
-                # overstates the pool's effectiveness.
-                self.prefix_misses += 1
-            if pfx is not None:
-                entry, plen = pfx
-                start, steps = self._pfx_plan(len(req.prompt), plen)
-                if len(steps) == 1:
-                    # Bucketed widths make same-preamble waves share a
-                    # geometry key even when question lengths differ.
-                    key = (entry, start, steps[0][1])
-                    pfx_groups.setdefault(key, []).append((sl, req))
-                else:
-                    self._admission_ran("chunk_steps", start)
-                    self._prefill_chunked(sl, req, pfx)
-            elif len(req.prompt) > self.cfg.prefill_chunk:
+            if len(req.prompt) > self.cfg.prefill_chunk:
                 if ilv:
                     # Chunk work item: the slot is held (reserved) but
                     # the prefill rides the decode ticks one chunk at a
@@ -4073,8 +3550,6 @@ class ContinuousBatcher:
             for row, d in zip(long_rows, deep):
                 if d:
                     self._admit_chunked_group([row])
-        for (entry, start, width), group in pfx_groups.items():
-            self._admit_chunked_group(group, pfx=(entry, start, width))
         if fused_batch:
             self._prefill_fused(fused_slots, fused_batch)
         # Paged groups LAST: a group may gather pages a cold call above
@@ -4084,67 +3559,37 @@ class ContinuousBatcher:
             self._admit_paged_group(group, *key)
         if self._spec:
             # Draft-side admission for every slot this round activated
-            # (fused, chunked, and prefix paths alike; interleave-queued
+            # (fused, chunked, and paged paths alike; interleave-queued
             # rows are draft-admitted by _ilv_finish_row when their
             # final chunk lands). One bucketed device call per round.
             self._spec_admit_rows(list(zip(slots_idx, batch)))
-        if trickle and batch[0].adapter == 0 and self.slots[
-            slots_idx[0]
-        ].request is batch[0]:
-            # First sighting of a poolable prefix on a trickle
-            # admission: pool it from the admitted row's cache slice
-            # (one extra rare device call — the admission itself stayed
-            # fused). Bursts learn shared prefixes via
-            # _pfx_learn_from_burst instead; a longer-prefix upgrade
-            # over an existing hit rides the same store.
-            req = batch[0]
-            key = self._pfx_storable(req.prompt)
-            if key is not None and not self._pfx_covered(key, len(key)):
-                slot = slots_idx[0]
-                self._pfx_commit(key, lambda entry: self._pfx_store_slot(
-                    self._pfx_pool, self.cache, jnp.int32(slot),
-                    jnp.int32(entry), jnp.int32(len(key)),
-                ))
         return queued, shed_rows
 
     def _admit_chunked_group(
-        self,
-        rows: list[tuple[int, _Request]],
-        pfx: Optional[tuple[int, int, int]] = None,
+        self, rows: list[tuple[int, _Request]]
     ) -> None:
         """ONE fused device call admitting `rows` (slot, request)
-        pairs. pfx=(entry, start, width): every row reuses pool entry
-        KV up to `start` and prefills one [R, 1, width] suffix step;
-        otherwise full prompts run the [R, T, prefill_chunk] grid from
+        pairs: full prompts run the [R, T, prefill_chunk] grid from
         position 0 (rows shorter than the deepest prompt pad with
         no-op chunks).
 
         Row-count bucketing: long-prompt groups compile per power-of-2
         R (a trickle long admission must not pay the full slot pool's
-        prefill compute — group-of-1 at full B measured 4× the serial
-        cost on CPU). Prefix groups are cheap per row (one short suffix
-        step), so they use only R=1 (trickle) or R=B (wave) to keep the
-        warmup compile ladder small. Padding rows carry slot index B
-        (out of range → dropped by the insert scatter)."""
+        prefill compute). Padding rows carry slot index B (out of range
+        → dropped by the insert scatter)."""
         b = len(self.slots)
-        if pfx is None:
-            c = min(self.cfg.prefill_chunk, self.max_seq)
-            n_max = max(len(req.prompt) for _, req in rows)
-            t_steps = max(1, -(-n_max // c))
-            if self._deep_grid is not None and t_steps > self._deep_grid:
-                # Deep grids round up to a power of two: a long-context
-                # deployment compiles log2(S_max / chunk) programs, not
-                # one for every chunk count. The padding chunks run
-                # with no valid token, which the family's attention
-                # walk skips.
-                t_steps = min(
-                    bucket_len(t_steps, minimum=1), -(-self.max_seq // c))
-            start = 0
-            r = min(b, bucket_len(len(rows), minimum=1))
-        else:
-            entry, start, c = pfx
-            t_steps = 1
-            r = 1 if len(rows) == 1 else b
+        c = min(self.cfg.prefill_chunk, self.max_seq)
+        n_max = max(len(req.prompt) for _, req in rows)
+        t_steps = max(1, -(-n_max // c))
+        if self._deep_grid is not None and t_steps > self._deep_grid:
+            # Deep grids round up to a power of two: a long-context
+            # deployment compiles log2(S_max / chunk) programs, not
+            # one for every chunk count. The padding chunks run
+            # with no valid token, which the family's attention
+            # walk skips.
+            t_steps = min(
+                bucket_len(t_steps, minimum=1), -(-self.max_seq // c))
+        r = min(b, bucket_len(len(rows), minimum=1))
         tokens = np.zeros((r, t_steps, c), np.int32)
         true_len = np.zeros((r,), np.int32)
         slots_arr = np.full((r,), b, np.int32)  # pad = out of range
@@ -4155,7 +3600,7 @@ class ContinuousBatcher:
         adapters = np.zeros((r,), np.int32)
         g0s = np.zeros((r,), np.int32)
         for j, (sl, req) in enumerate(rows):
-            piece = np.asarray(req.prompt[start:], np.int32)
+            piece = np.asarray(req.prompt, np.int32)
             tokens[j].reshape(-1)[: len(piece)] = piece
             true_len[j] = len(req.prompt)
             slots_arr[j] = sl
@@ -4165,31 +3610,17 @@ class ContinuousBatcher:
             ps[j] = req.sampling.top_p
             adapters[j] = req.adapter
             g0s[j] = self._g0(req)
-        if pfx is not None:
-            self.prefix_hits += len(rows)
-            self._admission_ran("chunked_pfx", start * len(rows))
-        else:
-            self._admission_ran("chunked")
+        self._admission_ran("chunked")
         g_allow, g_trans = self._grammar_tables()
         self._sync_tables()
         self._cache_at_risk = True
-        if pfx is None:
-            first, self.cache = self._admit_chunked(
-                self.engine.params, jnp.asarray(tokens),
-                jnp.asarray(true_len), self.cache, jnp.asarray(slots_arr),
-                jnp.asarray(seeds), jnp.asarray(temps), jnp.asarray(ks),
-                jnp.asarray(ps), jnp.asarray(adapters),
-                jnp.asarray(g0s), g_allow, g_trans,
-            )
-        else:
-            first, self.cache = self._admit_chunked_pfx(
-                self.engine.params, jnp.asarray(tokens),
-                jnp.asarray(true_len), self.cache, jnp.asarray(slots_arr),
-                jnp.asarray(seeds), jnp.asarray(temps), jnp.asarray(ks),
-                jnp.asarray(ps), jnp.asarray(adapters),
-                self._pfx_pool, jnp.int32(entry), jnp.int32(start),
-                jnp.asarray(g0s), g_allow, g_trans,
-            )
+        first, self.cache = self._admit_chunked(
+            self.engine.params, jnp.asarray(tokens),
+            jnp.asarray(true_len), self.cache, jnp.asarray(slots_arr),
+            jnp.asarray(seeds), jnp.asarray(temps), jnp.asarray(ks),
+            jnp.asarray(ps), jnp.asarray(adapters),
+            jnp.asarray(g0s), g_allow, g_trans,
+        )
         # Materialize BEFORE clearing the at-risk flag (async-dispatch
         # failure surfacing — same contract as _prefill_fused).
         first = np.asarray(first)
@@ -4208,8 +3639,7 @@ class ContinuousBatcher:
         """ONE fused device call admitting a group of paged prefix
         reuses that share suffix geometry (same merge/scan starts and
         [T, C] suffix grid — a same-preamble wave lands in one group,
-        the agentic arrival shape the old pool served with
-        _admit_chunked_pfx). Row-count bucketing mirrors
+        the agentic arrival shape). Row-count bucketing mirrors
         _admit_chunked_group; padding rows carry slot index B and an
         all-sentinel gather table (reads clip, writes drop)."""
         b = len(self.slots)
@@ -4269,9 +3699,6 @@ class ContinuousBatcher:
             # prefill (compute scales with rows; round-trips are ~equal).
             for slot_idx, req in zip(slots_idx, batch):
                 self._prefill_fused([slot_idx], [req])
-            # Both rows are in the shared cache now — a pair arriving
-            # together with the same NEW preamble must learn it too.
-            self._pfx_learn_from_burst(slots_idx, batch)
             return
         row_of = (lambda j: 0) if single else (lambda j: slots_idx[j])
         tokens = np.zeros((rows, s), np.int32)
@@ -4322,8 +3749,6 @@ class ContinuousBatcher:
         self._cache_at_risk = False
         for j, (slot_idx, req) in enumerate(zip(slots_idx, batch)):
             self._activate_slot(slot_idx, req, int(first[row_of(j)]))
-        if not single:
-            self._pfx_learn_from_burst(slots_idx, batch)
 
     def _tick_step(self) -> None:
         """One loop turn of decode work: dispatch a tick (fused with at

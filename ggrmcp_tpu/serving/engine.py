@@ -821,8 +821,7 @@ class GenerationEngine:
         """Build the draft model when speculative decoding is enabled
         (serving.speculative_draft): greedy exact-match and rejection-
         sampled modes, lossless either way (ops/speculative.py). The
-        draft serves both the whole-generation micro-path and the
-        continuous batcher's spec tick (batching.speculative)."""
+        draft serves the continuous batcher's spec tick."""
         self.draft_fam = None
         if not self.serving.speculative_draft:
             return
@@ -865,48 +864,17 @@ class GenerationEngine:
                 self.draft_fam.param_specs(dcfg), self.mesh,
                 jax.random.PRNGKey(seed + 1),
             )
-        self._spec_fn = jax.jit(self._spec_impl, static_argnums=(4,))
 
     def draft_forward(self, draft_params, tokens, cache):
         """fam.forward for the speculative draft model (dense Llama —
         _init_speculative enforces it; PP/MoE/LoRA are rejected with a
         draft configured, so none of decode_forward's dispatch cases
-        apply). Used by both the fused whole-generation program
-        (ops/speculative.speculative_generate via _spec_impl) and the
-        continuous batcher's spec tick (serving/batching.py)."""
+        apply). Used by the continuous batcher's spec tick
+        (serving/batching.py)."""
         return self.draft_fam.forward(
             draft_params, self.draft_cfg, tokens, cache,
             use_flash=self.use_flash, flash_mesh=self.flash_mesh,
         )
-
-    def _spec_impl(
-        self, params, draft_params, tokens, true_len, max_new_budget: int,
-        max_new, eos_id, temperature=None, seeds=None,
-    ):
-        from ggrmcp_tpu.ops.speculative import speculative_generate
-
-        return speculative_generate(
-            self.fam, params, self.cfg,
-            self.draft_fam, draft_params, self.draft_cfg,
-            tokens, true_len, max_new_budget,
-            self.serving.speculative_gamma, eos_id, max_new=max_new,
-            use_flash=self.use_flash, flash_mesh=self.flash_mesh,
-            kv_dtype=self.kv_dtype, temperature=temperature, seeds=seeds,
-        )
-
-    def warmup_speculative(self, max_new_budget: int = 64) -> None:
-        """Compile the speculative program for the smallest prompt
-        bucket and the given decode budget before serving traffic."""
-        if self.draft_fam is None:
-            return
-        s = bucket_len(1, maximum=self.cfg.max_seq_len)
-        with self.mesh:
-            res = self._spec_fn(
-                self.params, self.draft_params,
-                jnp.zeros((1, s), jnp.int32), jnp.ones((1,), jnp.int32),
-                max_new_budget, jnp.int32(1), jnp.int32(2),
-            )
-        jax.block_until_ready(res.tokens)
 
     def _synthetic_int8_init(self, seed: int):
         """Initialize the int8-quantized weight STRUCTURE directly with
@@ -1278,67 +1246,6 @@ class GenerationEngine:
             for lease in leases:
                 self.adapter_arena.release(lease)
         return self._decode_outputs(out, out_len, eos_id)
-
-    def generate_speculative(
-        self,
-        prompts: list[list[int]],
-        max_new_tokens: int = 128,
-        eos_id: int = 2,
-        temperatures: Optional[list[float]] = None,
-        seeds: Optional[list[int]] = None,
-    ) -> tuple[list[list[int]], list[str], dict]:
-        """Speculative batch generation (requires a configured draft
-        model). With `temperatures=None` the output is identical to
-        greedy `generate`; a per-row temperature list enables rejection
-        sampling (output distributed exactly as plain sampling —
-        ops/speculative.py). Returns (token lists, finish reasons,
-        stats with acceptance rate). The decode budget is bucketed
-        (static buffer) while the requested cap rides as a traced arg,
-        so request-to-request max_new changes reuse the compiled
-        program."""
-        if self.draft_fam is None:
-            raise RuntimeError("speculative decoding not configured")
-        limit = min(self.cfg.max_seq_len, self.draft_cfg.max_seq_len)
-        tokens, true_len, max_new_tokens = self._pack_prompts(
-            prompts, max_new_tokens, limit
-        )
-        budget = bucket_len(max_new_tokens, minimum=8, maximum=limit)
-        temps = seed_arr = None
-        if temperatures is not None:
-            temps = jnp.asarray(
-                np.asarray(temperatures, np.float32)
-            )
-            if not seeds:
-                # Distinct per-row defaults: a shared seed-0 default
-                # would make every row of a sampled batch draw the SAME
-                # random stream — "independent" samples correlated
-                # across the batch. None entries inside an explicit
-                # list still mean seed 0 (caller's choice, row-local).
-                seeds = list(range(len(prompts)))
-            seed_arr = jnp.asarray(np.asarray(
-                [(s or 0) & 0xFFFFFFFF for s in seeds],
-                np.uint32,
-            ))
-        with self.mesh:
-            res = self._spec_fn(
-                self.params, self.draft_params,
-                jnp.asarray(tokens), jnp.asarray(true_len),
-                budget, jnp.int32(max_new_tokens), jnp.int32(eos_id),
-                temps, seed_arr,
-            )
-        results, reasons = self._decode_outputs(
-            np.asarray(res.tokens), np.asarray(res.out_len), eos_id
-        )
-        drafted = int(res.drafted)
-        stats = {
-            "rounds": int(res.rounds),
-            "drafted": drafted,
-            "accepted": int(res.accepted),
-            "acceptance_rate": (
-                round(int(res.accepted) / drafted, 4) if drafted else 0.0
-            ),
-        }
-        return results, reasons, stats
 
     def generate_stream(
         self,

@@ -110,7 +110,7 @@ class TickRecord:
     duration_ms: float = 0.0
     finished: int = 0
     source: str = ""
-    # Speculative tick (batching.speculative=on): draft tokens proposed
+    # Speculative tick (a draft is configured): draft tokens proposed
     # and accepted on THIS tick — the per-tick acceptance trace (0/0 on
     # plain ticks). Completed at collect, like finished/duration_ms.
     spec_drafted: int = 0
@@ -332,8 +332,8 @@ class LatencyHistogram:
 
 
 class FlightRecorder:
-    """Rings + histograms for ONE batcher (each KV tier and the
-    speculative micro-batcher own an instance; facades merge views)."""
+    """Rings + histograms for ONE batcher (each KV tier owns an
+    instance; the tiered facade merges views)."""
 
     def __init__(self, cfg: Optional[ObservabilityConfig] = None,
                  source: str = ""):

@@ -49,9 +49,9 @@ def resolve_colaunch_transport(cfg: Config) -> None:
     """Pick the gateway→sidecar hop for co-launch, in place.
 
     The co-launched hop never leaves the host, so ride a private UDS:
-    cheaper per call than TCP loopback on the shared core
-    (docs/BENCH.md) and no port to collide with. An explicitly
-    configured serving.port (or uds_path) wins over this default —
+    cheaper per call than TCP loopback on the shared core and no port
+    to collide with. An explicitly configured serving.port (or
+    uds_path) wins over this default —
     pinning a port means something external (grpcurl, another gateway)
     intends to dial the sidecar over TCP."""
     default_port = type(cfg.serving)().port

@@ -55,7 +55,7 @@ from typing import Any, Callable, Optional
 # append after these.
 CORE_COMPONENTS = (
     "weights", "lora", "kv_arena", "block_tables", "draft_cache",
-    "prefix_pool", "ilv_mini", "grammar_arena", "tick_state",
+    "ilv_mini", "grammar_arena", "tick_state",
 )
 
 

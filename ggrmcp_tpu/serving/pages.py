@@ -46,8 +46,7 @@ Invariants the device side relies on (serving/batching.py):
     scatter-dropped, never corruption.
 
 Threading: every method runs inside the owning batcher's serialized
-executor calls (docs/threading.md — batcher-owned host state, exactly
-like the old prefix-pool maps this module replaces).
+executor calls (docs/threading.md — batcher-owned host state).
 """
 
 from __future__ import annotations

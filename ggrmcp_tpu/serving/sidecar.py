@@ -100,7 +100,6 @@ class Sidecar:
             if self.serving.checkpoint_path:
                 params = self._restore_params(model_cfg, family, mesh)
         self.family = family
-        self.spec_batcher = None
         if family != "bert":  # every decoder family
             self.generation = GenerationEngine(
                 model_cfg, self.serving, mesh=mesh, params=params
@@ -114,22 +113,6 @@ class Sidecar:
                 )
             else:
                 self.batcher = ContinuousBatcher(
-                    self.generation, self.serving.batching,
-                    eos_id=self.tokenizer.eos_id,
-                )
-            if (
-                self.generation.draft_fam is not None
-                and self.serving.batching.speculative != "on"
-            ):
-                # The side micro-batcher is the NO-SLOT-POOL fallback:
-                # with batching.speculative=on the continuous batcher
-                # runs the draft/verify round inside its own tick
-                # (shared slot pool, top-k/top-p and grammar rows
-                # included — docs/speculative.md), so every request
-                # routes there and no second pool splits the HBM.
-                from ggrmcp_tpu.serving.spec_batcher import SpeculativeBatcher
-
-                self.spec_batcher = SpeculativeBatcher(
                     self.generation, self.serving.batching,
                     eos_id=self.tokenizer.eos_id,
                 )
@@ -477,87 +460,45 @@ class Sidecar:
         # on every failure path short of a successful submit.
         grammar = await self._resolve_grammar(request, context)
         adapter, lease = await self._resolve_adapter(request, context)
-        # Side micro-batcher path (the no-slot-pool fallback — absent
-        # when batching.speculative=on puts the draft/verify round
-        # inside the continuous batcher's tick, where top-k/top-p and
-        # grammar rows ARE handled): greedy requests (lossless,
-        # bitwise) and plain temperature sampling (rejection sampling —
-        # lossless in distribution, ops/speculative.py). The micro-
-        # batcher's own program still has no per-row top-k/top-p or
-        # grammar mask, so those requests take the continuous batcher —
-        # as do LONG prompts: speculative decoding wins on decode-bound
-        # traffic, but a long prompt is prefill-bound and the draft
-        # model would DOUBLE its prefill cost while bypassing the
-        # machinery built for it (chunked admission, length tiers, the
-        # prefix pool). Adapters can't reach this gate: lora +
-        # speculative_draft is rejected at engine init.
-        speculative = (
-            self.spec_batcher is not None
-            and sampling.top_k <= 0
-            and sampling.top_p >= 1.0
-            and len(prompt) <= self.serving.batching.prefill_chunk
-            and grammar is None
-        )
         with tracing.tracer.span(
             "sidecar.generate",
             trace_id=trace_id or None,
             model=self.generation.cfg.name, prompt_tokens=len(prompt),
         ) as span:
-            if speculative:
-                # Greedy + draft configured → lossless speculative path.
-                # Concurrent requests are micro-batched into ONE
-                # multi-row device program (serving/spec_batcher.py), so
-                # a configured draft no longer serializes greedy traffic
-                # one private program at a time.
-                try:
-                    token_ids, finish, stats = await self.spec_batcher.submit(
-                        prompt, max_new,
-                        temperature=max(0.0, sampling.temperature),
-                        seed=seed, trace_id=trace_id,
-                    )
-                    span.set(**stats)
-                except asyncio.CancelledError:
-                    raise  # client disconnect must cancel, not "error"
-                except Exception:
-                    logger.exception("speculative generation failed")
-                    finish = "error"
-            else:
-                # unary: one terminal chunk — skips per-tick
-                # cross-thread emission (batching.py _Request.unary).
-                try:
-                    tenant, qos_class = self._tenant_identity(
-                        request, context
-                    )
-                    it = self.batcher.submit(
-                        prompt, max_new, sampling, seed, unary=True,
-                        adapter=adapter, trace_id=trace_id, grammar=grammar,
-                        adapter_key=request.adapter, adapter_lease=lease,
-                        tenant=tenant, qos_class=qos_class,
-                    )
-                except OverloadedError as exc:
-                    # Load shedding, not failure: RESOURCE_EXHAUSTED is
-                    # the retryable-overload status (the gateway maps
-                    # it to HTTP 429 + Retry-After). The shed request
-                    # never reached the batcher — return its arena pin.
-                    self._release_adapter(lease)
-                    await context.abort(
-                        grpc.StatusCode.RESOURCE_EXHAUSTED,
-                        f"server overloaded ({exc.reason}): {exc}; "
-                        f"retry in {exc.retry_after_s:g}s",
-                    )
-                except GrammarCapacityError as exc:
-                    # Too many DISTINCT schemas decoding at once —
-                    # transient, retryable: same overload contract.
-                    self._release_adapter(lease)
-                    await context.abort(
-                        grpc.StatusCode.RESOURCE_EXHAUSTED, str(exc)
-                    )
-                async for chunk_ids, reason in it:
-                    token_ids.extend(chunk_ids)
-                    if reason:
-                        finish = reason
+            # unary: one terminal chunk — skips per-tick cross-thread
+            # emission (batching.py _Request.unary).
+            try:
+                tenant, qos_class = self._tenant_identity(request, context)
+                it = self.batcher.submit(
+                    prompt, max_new, sampling, seed, unary=True,
+                    adapter=adapter, trace_id=trace_id, grammar=grammar,
+                    adapter_key=request.adapter, adapter_lease=lease,
+                    tenant=tenant, qos_class=qos_class,
+                )
+            except OverloadedError as exc:
+                # Load shedding, not failure: RESOURCE_EXHAUSTED is
+                # the retryable-overload status (the gateway maps
+                # it to HTTP 429 + Retry-After). The shed request
+                # never reached the batcher — return its arena pin.
+                self._release_adapter(lease)
+                await context.abort(
+                    grpc.StatusCode.RESOURCE_EXHAUSTED,
+                    f"server overloaded ({exc.reason}): {exc}; "
+                    f"retry in {exc.retry_after_s:g}s",
+                )
+            except GrammarCapacityError as exc:
+                # Too many DISTINCT schemas decoding at once —
+                # transient, retryable: same overload contract.
+                self._release_adapter(lease)
+                await context.abort(
+                    grpc.StatusCode.RESOURCE_EXHAUSTED, str(exc)
+                )
+            async for chunk_ids, reason in it:
+                token_ids.extend(chunk_ids)
+                if reason:
+                    finish = reason
             span.set(completion_tokens=len(token_ids), finish=finish)
-            self._attribute_span(span, trace_id, speculative)
+            self._attribute_span(span, trace_id)
         if finish == "overloaded":
             # Paged-KV page-pool exhaustion discovered at admission
             # (after submit already queued the request): same typed
@@ -712,15 +653,14 @@ class Sidecar:
                 return
         yield serving_pb2.GenerateChunk(finish_reason="length", done=True)
 
-    def _attribute_span(self, span, trace_id: str, speculative: bool) -> None:
+    def _attribute_span(self, span, trace_id: str) -> None:
         """Stamp the flight-recorder lifecycle onto this call's span —
         ttft_ms plus the tick-seq range — so one trace id walks span →
         request record → tick records (/debug/traces → /debug/requests
         → /debug/ticks)."""
         if not trace_id:
             return
-        source = self.spec_batcher if speculative else self.batcher
-        rec = source.request_record(trace_id) if source is not None else None
+        rec = self.batcher.request_record(trace_id)
         if rec is None:
             return
         span.set(
@@ -1013,28 +953,6 @@ class Sidecar:
             # contribute grammar_masked_tokens / grammar_states_in_use).
             stats["grammar_compiles"] = self.grammar_cache.compiles
             stats["grammar_cache_hits"] = self.grammar_cache.hits
-        if self.spec_batcher is not None:
-            stats["speculative_calls"] = self.spec_batcher.calls
-            stats["speculative_requests"] = self.spec_batcher.requests
-            stats["speculative_drafted"] = self.spec_batcher.drafted
-            stats["speculative_accepted"] = self.spec_batcher.accepted
-            stats["queued_requests"] = (
-                stats.get("queued_requests", 0)
-                + self.spec_batcher.queue.qsize()
-            )
-            # Latency histograms are summable by construction: merge
-            # the speculative recorder's buckets into the batcher's so
-            # the exported ttft/e2e distributions cover BOTH serving
-            # paths.
-            from ggrmcp_tpu.serving.flight_recorder import FlightRecorder
-
-            spec_hist = self.spec_batcher.recorder.histogram_stats()
-            batch_hist = {
-                k: stats.pop(k) for k in list(spec_hist) if k in stats
-            }
-            stats.update(FlightRecorder.merge_histogram_stats(
-                [batch_hist, spec_hist]
-            ))
         return serving_pb2.ServingStatsResponse(**stats)
 
     async def get_model_info(self, request, context):
@@ -1122,21 +1040,6 @@ class Sidecar:
             admissions, handoffs = self.batcher.loop_snapshot(
                 max_ticks, request.trace_id
             )
-        if self.spec_batcher is not None:
-            enabled = enabled or self.spec_batcher.recorder.enabled
-            spec_requests = self.spec_batcher.recorder.request_snapshot()
-            if request.trace_id:
-                spec_requests = [
-                    r for r in spec_requests
-                    if r.trace_id == request.trace_id
-                ]
-            if request.tenant:
-                spec_requests = [
-                    r for r in spec_requests if r.tenant == request.tenant
-                ]
-            requests = sorted(
-                requests + spec_requests, key=lambda r: r.t_submit
-            )[-max_requests:]
         from ggrmcp_tpu.serving.compile_watcher import watcher
 
         return serving_pb2.FlightRecordResponse(
@@ -1417,17 +1320,7 @@ class Sidecar:
             await asyncio.get_running_loop().run_in_executor(
                 None, self.batcher.warmup
             )
-            if self.generation is not None and self.spec_batcher is not None:
-                # The whole-generation speculative program only serves
-                # the side micro-batcher; with batching.speculative=on
-                # the batcher's own warmup compiled the spec tick and
-                # this compile would be pure wasted window.
-                await asyncio.get_running_loop().run_in_executor(
-                    None, self.generation.warmup_speculative
-                )
             self.batcher.start()
-        if self.spec_batcher is not None:
-            self.spec_batcher.start()
         # Warmup is over: from here every XLA compile is a steady-state
         # recompile — counted, WARNING-logged, and a timeline instant
         # (serving/compile_watcher.py; compile_post_warmup == 0 is the
@@ -1458,8 +1351,6 @@ class Sidecar:
             except Exception:  # noqa: BLE001 — peer may already be gone
                 pass
         self._peer_channels.clear()
-        if self.spec_batcher is not None:
-            await self.spec_batcher.stop()
         if self.batcher is not None:
             await self.batcher.stop()
         if self.server is not None:

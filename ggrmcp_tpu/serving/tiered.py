@@ -8,11 +8,10 @@ long×few) keep every decode tick a fully tiled MXU program with zero
 gather overhead. Since then the paged KV plane (batching.paged_kv=on,
 docs/paged_kv.md) attacks the same waste at token granularity — pages
 are allocated to a request's actual length and shared prefixes are
-stored once — which covers most of what tiering bought, plus the
-prefix-thrash regime tiers never addressed. The two compose: each tier
-runs its own paged arena (a global paged_kv_pages budget is split
-across tiers by KV volume below), though a single paged pool is
-usually the simpler configuration now.
+stored once — which covers most of what tiering bought. The two
+compose: each tier runs its own paged arena (a global paged_kv_pages
+budget is split across tiers by KV volume below), though a single
+paged pool is usually the simpler configuration now.
 
 HBM = Σ slots_i × seq_i instead of B_total × S_global_max. Example for
 llama-1b bf16 KV (16 layers × 8 kv-heads × 64): a flat 32×4096 pool is
@@ -68,20 +67,10 @@ class TieredBatcher:
         volumes = [int(t[0]) * int(t[1]) for t in cfg.kv_tiers]
         total_volume = sum(volumes) or 1
         for tier, volume in zip(cfg.kv_tiers, volumes):
-            # [max_seq, slots] or [max_seq, slots, prefix_entries]:
-            # the optional third element overrides the global prefix
-            # pool size for THIS tier (0 = off). A tier whose workload
-            # can't produce poolable prompts (e.g. a short headline
-            # tier under the pool's min length) shouldn't pay the
-            # pool's HBM or its warmup compiles.
-            max_seq, slots = tier[0], tier[1]
+            max_seq, slots = tier
             tier_cfg = dataclasses.replace(
                 cfg, max_batch_size=int(slots),
                 kv_cache_max_seq=int(max_seq), kv_tiers=[],
-                prefix_cache_entries=(
-                    int(tier[2]) if len(tier) > 2
-                    else cfg.prefix_cache_entries
-                ),
                 paged_kv_pages=(
                     max(1, budget * volume // total_volume)
                     if paged and budget else 0

@@ -35,7 +35,7 @@ from dataclasses import dataclass
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 SCAN_DIRS = ["ggrmcp_tpu", "scripts", "examples", "tests"]
-SCAN_FILES = ["bench.py", "__graft_entry__.py"]
+SCAN_FILES = ["__graft_entry__.py"]
 
 # Names whose string-literal assignment looks like an embedded secret.
 SECRET_NAME = re.compile(

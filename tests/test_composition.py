@@ -1,6 +1,6 @@
 """Kitchen-sink composition: every serving feature enabled at once —
-int8 weights + int8 KV cache + length-tiered pools + prefix caching +
-speculative draft — on one sidecar, driven over real gRPC. Guards
+int8 weights + int8 KV cache + length-tiered pools + paged prefix
+sharing + speculative draft — on one sidecar, driven over real gRPC. Guards
 against feature-interaction regressions that per-feature suites miss.
 """
 
@@ -31,9 +31,8 @@ def maximal_serving() -> ServingConfig:
             kv_cache_max_seq=256,
             kv_tiers=[[64, 3], [256, 2]],
             prefill_chunk=32,
-            prefix_cache_entries=2,
-            prefix_cache_min_seq=8,
-            prefix_cache_max_seq=32,
+            paged_kv="on",
+            paged_kv_page_size=8,
         ),
     )
 
@@ -47,7 +46,7 @@ def test_maximal_config_validates():
 class TestMaximalSidecar:
     async def test_all_features_serve_together(self):
         side = Sidecar(maximal_serving())
-        assert side.spec_batcher is not None  # draft wired
+        assert side.generation.draft_fam is not None  # draft wired
         assert type(side.batcher).__name__ == "TieredBatcher"
         port = await side.start(0)
         channel = grpc.aio.insecure_channel(f"localhost:{port}")
@@ -59,7 +58,7 @@ class TestMaximalSidecar:
                 ),
                 response_deserializer=serving_pb2.GenerateResponse.FromString,
             )
-            long_prompt = "shared system preamble " * 4  # > prefix min
+            long_prompt = "shared system preamble " * 4  # 11 full pages
 
             async def call(prompt, temperature):
                 return await gen(serving_pb2.GenerateRequest(
@@ -69,30 +68,30 @@ class TestMaximalSidecar:
                     ),
                 ))
 
-            # Greedy → speculative micro-batcher; sampled → tiered
-            # batcher (short tier); long prompt → long tier via the
-            # chunked path, pooling its prefix; repeat → prefix hit.
+            # Every row rides the spec tick of its tier: greedy and
+            # sampled in the short tier; the long prompt in the long
+            # tier via the chunked path, registering its pages; its
+            # repeat reuses them.
             results = await asyncio.gather(
                 call("greedy one", 0.0),
                 call("greedy two", 0.0),
                 call("sampled", 0.9),
                 call(long_prompt + "q1", 0.9),
-                call(long_prompt + "q2", 0.9),
             )
+            results.append(await call(long_prompt + "q2", 0.9))
             for resp in results:
                 assert resp.finish_reason in ("length", "stop")
                 assert resp.completion_tokens <= 5
                 assert resp.model_id == "tiny-llama"
 
             # Determinism sanity within the quantized config: a repeat
-            # of the same greedy prompt reproduces its output. (Whether
-            # the first pair actually coalesced is timing-dependent
-            # here; multi-row-vs-solo losslessness is pinned
-            # deterministically in tests/test_speculative.py.)
+            # of the same greedy prompt reproduces its output
+            # (multi-row-vs-solo losslessness is pinned
+            # deterministically in tests/test_spec_batch.py).
             again = await call("greedy one", 0.0)
             assert again.text == results[0].text
 
-            # ServingStats reflects both planes' activity.
+            # ServingStats reflects the tiers' activity.
             stats_rpc = channel.unary_unary(
                 "/ggrmcp.tpu.ModelInfoService/GetServingStats",
                 request_serializer=(
@@ -105,9 +104,9 @@ class TestMaximalSidecar:
             stats = await stats_rpc(serving_pb2.ServingStatsRequest())
             assert stats.total_slots == 5  # 3 + 2 tier slots
             assert stats.kv_cache_bytes > 0
-            assert stats.speculative_requests >= 3
-            assert stats.decode_steps >= 1  # sampled traffic decoded
+            assert stats.spec_ticks >= 1 and stats.spec_drafted >= 1
             assert stats.prefix_cache_hits >= 1  # q2 reused q1's head
+            assert stats.paged_pages_reused >= 11
         finally:
             await channel.close()
             await side.stop()

@@ -300,8 +300,7 @@ class TestJumpBitIdentity:
         g = compile_schema(SCHEMAS["const_obj"], vocab_size=VOCAB)
         async with _batcher(engine, jump=False) as b:
             off, reason_off = await _drain(b, [3, 1, 4, 1], 256, grammar=g)
-        async with _batcher(spec_engine, jump=True,
-                            speculative="on") as b:
+        async with _batcher(spec_engine, jump=True) as b:
             on, reason_on = await _drain(b, [3, 1, 4, 1], 256, grammar=g)
             stats = b.counter_stats()
         assert on == off and reason_on == reason_off

@@ -158,32 +158,46 @@ class TestKVQuantServing:
             await batcher.stop()
         assert out1 == out2 and len(out1) <= 6
 
-    def test_speculative_composes_with_int8(self):
-        """Lossless speculative decoding on int8 caches: spec output
-        equals plain greedy WITHIN the int8 config (per-position
-        quantization is write-order independent, so draft-round cache
-        writes reproduce the plain path's values exactly)."""
+    async def test_speculative_composes_with_int8(self):
+        """Lossless speculative decoding on int8 caches: the spec
+        tick's output equals plain greedy WITHIN the int8 config
+        (per-position quantization is write-order independent, so
+        draft-round cache writes reproduce the plain path's values
+        exactly)."""
         eng = GenerationEngine(
             CFG,
             serving_cfg(speculative_draft="tiny-llama"),
         )
         prompts = [[3, 1, 4, 1, 5], [9, 2, 6]]
         plain, _ = eng.generate(prompts, max_new_tokens=10, seed=0)
-        spec, _, stats = eng.generate_speculative(prompts, max_new_tokens=10)
+        batcher = ContinuousBatcher(
+            eng, BatchingConfig(max_batch_size=4, kv_cache_max_seq=256)
+        )
+        batcher.start()
+        try:
+            spec = []
+            for prompt in prompts:
+                out = []
+                async for ids, _ in batcher.submit(
+                    prompt, 10, SamplingConfig(temperature=0.0)
+                ):
+                    out.extend(ids)
+                spec.append(out)
+        finally:
+            await batcher.stop()
         assert spec == plain
-        assert stats["rounds"] >= 1
+        assert batcher.spec_ticks >= 1
 
-    async def test_chunked_and_prefix_pool_on_int8(self, engine):
-        """Chunked prefill + prefix-pool store/load on the quantized
-        cache: repeat of a long prompt must hit and reproduce the
-        first run's greedy output (pool round-trips int8 KV)."""
+    async def test_chunked_admission_on_int8(self, engine):
+        """Chunked prefill on the quantized cache: a long prompt
+        admitted through the [T, C] grid reproduces the engine's own
+        greedy output, twice."""
         prompt = [(i * 13 + 5) % 500 + 1 for i in range(60)]
+        expected, _ = engine.generate([prompt], max_new_tokens=5, seed=0)
         batcher = ContinuousBatcher(
             engine,
             BatchingConfig(
                 max_batch_size=4, kv_cache_max_seq=256, prefill_chunk=16,
-                prefix_cache_entries=2, prefix_cache_min_seq=8,
-                prefix_cache_max_seq=32,
             ),
         )
         batcher.warmup()
@@ -197,7 +211,6 @@ class TestKVQuantServing:
                 ):
                     out.extend(ids)
                 outs.append(out)
-            assert batcher.prefix_hits == 1
         finally:
             await batcher.stop()
-        assert outs[0] == outs[1]
+        assert outs == [expected[0], expected[0]]

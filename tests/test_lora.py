@@ -187,8 +187,8 @@ class TestBatcherLora:
 
 
 class TestLoraSafety:
-    """Review-driven hazards: prefix-pool contamination, silent gather
-    clipping on out-of-range ids, broadcasting factor installs."""
+    """Review-driven hazards: silent gather clipping on out-of-range
+    ids, broadcasting factor installs."""
 
     def test_adapter_id_range_checked(self, lora_engine):
         with pytest.raises(ValueError, match="out of range"):
@@ -203,42 +203,6 @@ class TestLoraSafety:
         a, b = random_factors(cfg, 4)
         with pytest.raises(ValueError, match="factor shapes"):
             lora_engine.set_lora_weights("beta", a[0], b)  # missing L axis
-
-    async def test_prefix_pool_stays_base_only(self, lora_engine):
-        """A shared system prompt sent under an adapter must not seed
-        the pool: the base model re-sending it must get base KV (and a
-        base request's pooled entry must not serve adapter'd ones)."""
-        cfg = BatchingConfig(
-            max_batch_size=4, kv_cache_max_seq=256,
-            prefix_cache_entries=2, prefix_cache_min_seq=16,
-            prefix_cache_max_seq=64,
-        )
-        batcher = ContinuousBatcher(lora_engine, cfg)
-        batcher.start()
-        preamble = [7, 3, 9, 1] * 6  # 24 >= min_seq
-        acme_id = lora_engine.resolve_adapter("acme")
-
-        try:
-            # adapter'd request first: must NOT store its KV
-            await collect(batcher, preamble + [5], 6, adapter=acme_id)
-            assert batcher.prefix_hits == 0
-            # base request with the same preamble: a MISS (stores now)
-            base1, _ = await collect(batcher, preamble + [5], 6)
-            assert batcher.prefix_hits == 0
-            # base again: pool hit, identical tokens
-            base2, _ = await collect(batcher, preamble + [5], 6)
-            assert batcher.prefix_hits == 1
-            assert base2 == base1
-            # adapter'd request again: must not consult the base entry
-            hits_before = batcher.prefix_hits
-            acme, _ = await collect(batcher, preamble + [5], 6, adapter=acme_id)
-            assert batcher.prefix_hits == hits_before
-            solo_acme, _ = lora_engine.generate(
-                [preamble + [5]], max_new_tokens=6, adapters=["acme"]
-            )
-            assert acme == solo_acme[0]
-        finally:
-            await batcher.stop()
 
 
 class TestLoraCompositions:

@@ -216,7 +216,7 @@ class TestClosure:
                 speculative_draft="tiny-llama",
                 batching=BatchingConfig(
                     max_batch_size=2, kv_cache_max_seq=128,
-                    max_queue_delay_ms=2.0, speculative="on",
+                    max_queue_delay_ms=2.0,
                 ),
             ),
             [[5, 6, 7]],

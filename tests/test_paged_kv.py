@@ -11,8 +11,7 @@ The contract under test, in order of importance:
 2. SHARING — same-preamble admissions reference the SAME physical
    pages (refcounts, kv_pages_shared), divergent pages copy-on-write,
    and a working set that outgrows refcounts survives via LRU reuse of
-   refcount-0 pages (the thrash regime the slot-granular pool lost —
-   the slow-suite TestPrefixThrash pins the 3× working-set bound).
+   refcount-0 pages (test_paged_holds_hit_rate_at_3x_working_set).
 3. SAFETY — page-pool exhaustion sheds typed ("overloaded" →
    RESOURCE_EXHAUSTED → 429, the PR-2 ladder) and never corrupts
    resident block tables; compile counts stay stable for mixed
@@ -22,6 +21,7 @@ Marker `paged` (tier-1, `make test-paged`).
 """
 
 import asyncio
+import contextlib
 
 import numpy as np
 import pytest
@@ -296,9 +296,7 @@ class TestPagedBitIdentity:
         head = prompt_of(20)
         prompts = [head + prompt_of(4, salt=s) for s in range(4)]
         expected, _ = spec_engine.generate(prompts, max_new_tokens=5, seed=0)
-        outs_on, paged = await run_wave(
-            spec_engine, paged_cfg(speculative="on"), prompts
-        )
+        outs_on, paged = await run_wave(spec_engine, paged_cfg(), prompts)
         assert outs_on == expected
         assert paged.spec_ticks > 0
         # The one-round burst shares the first row's eagerly indexed
@@ -384,6 +382,104 @@ class TestPagedSharing:
         assert batcher.pages.hits >= 4
         assert batcher.pages.pages_reused >= 16
 
+    async def test_longer_prompt_reuses_shorter_then_its_own_chain(
+        self, engine
+    ):
+        """A longer prompt after a shorter one shares the shorter
+        one's pages and runs its multi-chunk suffix; repeated, it
+        shares its OWN longer chain. Outputs match the engine."""
+        short = prompt_of(16)
+        longer = short + prompt_of(44, salt=3)
+        expected, _ = engine.generate([longer], max_new_tokens=4, seed=0)
+        batcher = ContinuousBatcher(engine, paged_cfg(prefill_chunk=16))
+        batcher.start()
+        try:
+            await collect(batcher, short, 3)  # indexes 2 full pages
+            out1, _ = await collect(batcher, longer, 4)
+            assert batcher.prefix_hits == 1
+            assert batcher.pages.pages_reused == 2
+            out2, _ = await collect(batcher, longer, 4)
+            assert batcher.prefix_hits == 2
+            # 60 tokens at page 8: 7 full pages, every one shared.
+            assert batcher.pages.pages_reused == 2 + 7
+        finally:
+            await batcher.stop()
+        assert out1 == expected[0]
+        assert out2 == expected[0]
+
+    async def test_burst_of_distinct_prompts_shares_no_page(self, engine):
+        batcher = ContinuousBatcher(engine, paged_cfg(max_batch_size=8))
+        batcher.start()
+        try:
+            await asyncio.gather(*(
+                collect(batcher, prompt_of(20, salt=50 + i), 4, seed=i)
+                for i in range(6)
+            ))
+        finally:
+            await batcher.stop()
+        stats = batcher.counter_stats()
+        assert stats["paged_prefix_hits"] == 0
+        assert stats["paged_pages_reused"] == 0
+        assert (batcher.prefix_hits, batcher.prefix_misses) == (0, 6)
+
+    async def test_cold_burst_registers_and_next_burst_hits(self, engine):
+        """A cold burst carrying one NEW preamble registers its pages
+        in the same round (the later rows of the round already share
+        them), and every row of the next same-preamble burst hits."""
+        head = prompt_of(24, salt=9)
+        burst1 = [head + prompt_of(4, salt=100 + s) for s in range(16)]
+        burst2 = [head + prompt_of(4, salt=200 + s) for s in range(16)]
+        batcher = ContinuousBatcher(engine, paged_cfg(max_batch_size=16))
+        batcher.start()
+        try:
+            outs1 = await asyncio.gather(
+                *(collect(batcher, p, 4) for p in burst1)
+            )
+            assert all(r in ("length", "stop") for _, r in outs1)
+            hits1 = batcher.prefix_hits
+            assert hits1 >= 1 and batcher.prefix_misses >= 1
+            reused1 = batcher.pages.pages_reused
+            outs2 = await asyncio.gather(
+                *(collect(batcher, p, 4) for p in burst2)
+            )
+            assert batcher.prefix_hits - hits1 == 16
+            # 24-token head at page 8 = 3 full pages a row.
+            assert batcher.pages.pages_reused - reused1 == 16 * 3
+        finally:
+            await batcher.stop()
+        expected, _ = engine.generate(burst2[:2], max_new_tokens=4, seed=0)
+        assert [o for o, _ in outs2[:2]] == expected
+
+    async def test_paged_holds_hit_rate_at_3x_working_set(self, engine):
+        """12 distinct 32-token preambles revisited by 64 concurrent
+        sessions over a 16-slot arena: pages store each preamble once,
+        exactly sized, so the whole working set stays resident and at
+        least nine admissions in ten reuse it. A call may stop on the
+        end-of-sequence id before it emits a token."""
+        preambles = [prompt_of(32, salt=100 + p) for p in range(12)]
+        batcher = ContinuousBatcher(engine, paged_cfg(max_batch_size=16))
+        batcher.warmup()
+        batcher.start()
+        try:
+            for p, pre in enumerate(preambles):  # every preamble once
+                await collect(batcher, pre + [400 + p], 4, seed=p)
+            h0, m0 = batcher.prefix_hits, batcher.prefix_misses
+            results = await asyncio.gather(*(
+                collect(
+                    batcher,
+                    preambles[i % 12] + [300 + i, (i * 7) % 200 + 1],
+                    4, seed=i,
+                )
+                for i in range(64)
+            ))
+            hits = batcher.prefix_hits - h0
+            misses = batcher.prefix_misses - m0
+        finally:
+            await batcher.stop()
+        assert all(r in ("stop", "length") for _, r in results)
+        assert hits + misses == 64
+        assert hits / 64 >= 0.9, f"hit rate {hits / 64:.2f}"
+
     async def test_tick_records_carry_page_occupancy(self, engine):
         batcher = ContinuousBatcher(engine, paged_cfg())
         batcher.start()
@@ -454,6 +550,69 @@ class TestPagedSharing:
             await batcher.stop()
 
 
+@contextlib.contextmanager
+def recorded_shapes(batcher, program: str):
+    """The token-grid shape of every call of a batcher's jitted
+    admission `program` while the block runs."""
+    shapes: list[tuple] = []
+    real = getattr(batcher, program)
+
+    def recording(*args):
+        shapes.append(tuple(args[1].shape))
+        return real(*args)
+
+    setattr(batcher, program, recording)
+    try:
+        yield shapes
+    finally:
+        setattr(batcher, program, real)
+
+
+class TestFusedAdmissionShapes:
+    """One device call a group, at the bucketed row count."""
+
+    async def test_same_preamble_wave_is_one_fused_call(self, engine):
+        batcher = ContinuousBatcher(engine, paged_cfg())
+        batcher.warmup()
+        batcher.start()
+        head = prompt_of(24, salt=400)
+        try:
+            await collect(batcher, head + prompt_of(3, salt=401), 3)
+            with recorded_shapes(batcher, "_admit_paged_pfx") as shapes:
+                outs = await asyncio.gather(*(
+                    collect(batcher, head + prompt_of(3, salt=410 + i), 4,
+                            seed=i)
+                    for i in range(3)
+                ))
+        finally:
+            await batcher.stop()
+        assert all(r in ("length", "stop") for _, r in outs)
+        # The 3-request wave shares one geometry key -> ONE fused
+        # [R, 1, W] call; a straggler admitted on a later round may add
+        # one more.
+        assert 1 <= len(shapes) <= 2, shapes
+        assert all(s[1] == 1 for s in shapes)
+        assert sum(s[0] for s in shapes) >= 3
+
+    async def test_long_group_uses_bucketed_rows(self, engine):
+        """Long-prompt groups run at the bucketed row count, not the
+        full slot pool — a trickle long admission must not pay B x the
+        prefill compute."""
+        batcher = ContinuousBatcher(engine, paged_cfg(prefill_chunk=32))
+        batcher.warmup()
+        batcher.start()
+        try:
+            with recorded_shapes(batcher, "_admit_chunked") as shapes:
+                _, reason = await collect(
+                    batcher, prompt_of(100, salt=500), 4
+                )
+        finally:
+            await batcher.stop()
+        assert reason in ("length", "stop")
+        # One trickle admission: R=1 rows, T=ceil(100/32)=4 chunks.
+        assert shapes == [(1, 4, 32)], shapes
+
+
 # ---------------------------------------------------------------------------
 # Exhaustion: typed shed, no corruption
 # ---------------------------------------------------------------------------
@@ -521,10 +680,6 @@ class TestPagedConfig:
         with pytest.raises(ValueError, match="paged_kv"):
             self._cfg(paged_kv="maybe").validate()
 
-    def test_prefix_pool_superseded(self):
-        with pytest.raises(ValueError, match="supersedes"):
-            self._cfg(paged_kv="on", prefix_cache_entries=4).validate()
-
     def test_kv_ring_mutually_exclusive(self):
         cfg = self._cfg(paged_kv="on")
         cfg.serving.kv_ring = True
@@ -545,14 +700,12 @@ class TestPagedConfig:
                 kv_tiers=[[72, 4], [256, 2]], kv_cache_max_seq=256,
             ).validate()
 
-    def test_tier_prefix_entries_superseded(self):
-        with pytest.raises(ValueError, match="per-tier prefix"):
+    def test_tier_entry_is_max_seq_and_slots(self):
+        with pytest.raises(ValueError, match=r"\[max_seq, slots\]"):
             self._cfg(
                 paged_kv="on", kv_tiers=[[64, 4, 2], [256, 2]],
             ).validate()
 
     def test_batcher_mirrors_validation(self, engine):
-        with pytest.raises(ValueError, match="supersedes"):
-            ContinuousBatcher(
-                engine, paged_cfg(prefix_cache_entries=2)
-            )
+        with pytest.raises(ValueError, match="divide"):
+            ContinuousBatcher(engine, paged_cfg(paged_kv_page_size=24))

@@ -257,7 +257,3 @@ class TestSPWindowedPrefill:
                 mesh=seq_mesh,
             )
 
-
-# Heavy JAX-compile/serving integration module: excluded from the
-# fast `make test` signal; always in `make test-all` / CI.
-pytestmark = pytest.mark.slow

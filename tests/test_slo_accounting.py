@@ -74,18 +74,24 @@ def _slo_cfg(**kw):
     return SloConfig(**kw)
 
 
-@pytest.fixture(scope="module")
-def engine():
-    # speculative_draft makes the same engine serve the spec-on
-    # batcher config too (the test_spec_batch pattern).
+def _engine(**kw):
     return GenerationEngine(
         llama.CONFIGS["tiny-llama"],
         ServingConfig(
-            mesh=MeshConfig(tensor=2, data=0),
-            speculative_draft="tiny-llama",
-            slo=_slo_cfg(),
+            mesh=MeshConfig(tensor=2, data=0), slo=_slo_cfg(), **kw
         ),
     )
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return _engine()
+
+
+@pytest.fixture(scope="module")
+def spec_engine():
+    # A configured draft makes every batcher over it run the spec tick.
+    return _engine(speculative_draft="tiny-llama")
 
 
 @pytest.fixture(autouse=True)
@@ -624,10 +630,6 @@ def _make_batcher(engine, mode):
         return ContinuousBatcher(
             engine, BatchingConfig(**base, paged_kv="on")
         )
-    if mode == "spec":
-        return ContinuousBatcher(
-            engine, BatchingConfig(**base, speculative="on")
-        )
     if mode == "tiered":
         return TieredBatcher(
             engine, BatchingConfig(kv_tiers=[[64, 2], [128, 2]])
@@ -639,14 +641,17 @@ class TestClosureAcrossConfigs:
     @pytest.mark.parametrize(
         "mode", ["plain", "paged", "tiered", "spec", "grammar"]
     )
-    async def test_goodput_partition_closure(self, engine, mode):
+    async def test_goodput_partition_closure(
+        self, engine, spec_engine, mode
+    ):
         """The acceptance property, per serving config: every
         submitted request lands in exactly one partition; "fast"
         finishes violate (µs targets), "lax" finishes meet; tenant
         decode attribution reconciles against actually-emitted
         tokens."""
-        batcher = _make_batcher(engine, "plain" if mode == "grammar"
-                                else mode)
+        batcher = _make_batcher(
+            spec_engine if mode == "spec" else engine, mode
+        )
         grammar = (
             compile_schema({"enum": ["alpha", "beta"]}, vocab_size=VOCAB)
             if mode == "grammar" else None

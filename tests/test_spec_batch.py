@@ -1,19 +1,19 @@
 """Speculative decoding inside the continuous batcher (ISSUE 5,
-marker `spec_batch`): the fixed-shape draft/verify tick behind
-`batching.speculative=on`.
+marker `spec_batch`): the fixed-shape draft/verify tick a configured
+`serving.speculative_draft` turns on.
 
 The load-bearing guarantees:
 
-  * Greedy bitwise identity — with a draft configured, spec-on output
-    is BYTE-identical to spec-off across every admission path (fused
-    single/burst, chunked, prefix-pool, tick-interleaved) and under
+  * Greedy bitwise identity — the output of an engine with a draft
+    is BYTE-identical to that of the same engine without one across
+    every admission path (fused single/burst, chunked,
+    tick-interleaved; the paged path in tests/test_paged_kv.py) and under
     injected tick faults (chaos replay). Exact-match acceptance makes
     this hold REGARDLESS of draft quality.
   * Sampled losslessness — emitted tokens are distributed exactly as
     plain target sampling over the per-row temp→top-k→top-p FILTERED
-    distribution (the rejection-sampler extension this issue adds),
-    pinned by TV-distance against the exact conditional (carried over
-    from tests/test_speculative.py).
+    distribution, pinned by TV-distance against the exact
+    conditional.
   * Fixed shapes — mixed greedy/sampled/top-k/constrained batches
     share ONE compiled spec tick (compile-count stability).
 
@@ -22,6 +22,7 @@ Deliberately NOT slow-marked: tier-1 always runs the spec tick;
 """
 
 import asyncio
+import functools
 import json
 
 import jax
@@ -65,13 +66,23 @@ def clean_failpoints():
     failpoints.registry.disarm()
 
 
+@functools.cache
+def _plain_engine() -> GenerationEngine:
+    """The same target (same seed, same weights) with no draft: the
+    spec-off side of every comparison."""
+    return GenerationEngine(
+        llama.CONFIGS["tiny-llama"], spec_cfg(speculative_draft="")
+    )
+
+
 def _batcher(engine, spec: bool, **cfg_kw) -> ContinuousBatcher:
+    """A configured draft IS the switch: spec=False builds the batcher
+    over the draft-less twin of `engine`."""
     cfg_kw.setdefault("max_batch_size", 4)
     cfg_kw.setdefault("kv_cache_max_seq", 256)
-    cfg = BatchingConfig(
-        speculative=("on" if spec else "off"), **cfg_kw
+    return ContinuousBatcher(
+        engine if spec else _plain_engine(), BatchingConfig(**cfg_kw)
     )
-    return ContinuousBatcher(engine, cfg)
 
 
 async def _drain(batcher, prompt, max_new, sampling=GREEDY, seed=0,
@@ -126,37 +137,6 @@ class TestGreedyBitwiseIdentity:
             engine, [LONG], 8, spec=True, prefill_chunk=32
         )
         assert on == off
-
-    async def test_prefix_pool_admission(self, engine):
-        """Wave 1 seeds the pool, wave 2 reuses it — spec-on must match
-        spec-off through both the cold store and the fused prefix-hit
-        program (the draft side always prefills the FULL prompt; only
-        the target reuses pooled KV)."""
-        preamble = [(i * 5) % 150 + 3 for i in range(24)]
-        kw = dict(
-            prefix_cache_entries=2, prefix_cache_min_seq=8,
-            prefix_cache_max_seq=64,
-        )
-        outs = {}
-        for spec in (False, True):
-            batcher = _batcher(engine, spec, **kw)
-            batcher.start()
-            try:
-                seed_wave = await _drain(
-                    batcher, preamble + [7, 7], 8
-                )
-                hit_wave = await asyncio.gather(*(
-                    _drain(batcher, preamble + [9, i], 8, seed=i)
-                    for i in range(3)
-                ))
-                outs[spec] = (seed_wave, hit_wave)
-                if spec:
-                    assert batcher.prefix_hits > 0, (
-                        "prefix path not exercised"
-                    )
-            finally:
-                await batcher.stop()
-        assert outs[True] == outs[False]
 
     async def test_interleaved_admission(self, engine):
         """A long prompt landing while another slot decodes takes the
@@ -329,8 +309,7 @@ def _exact_conditional(engine, prompt, filt=None):
 
 
 class TestSampledLossless:
-    """The TV-distance net carried over from tests/test_speculative.py:
-    the spec TICK's rejection sampler (accept + residual against an
+    """The TV-distance net: the spec TICK's rejection sampler (accept + residual against an
     imperfect draft) must emit second tokens distributed exactly as
     plain target sampling — and, with top-k set, as the top-k FILTERED
     target distribution (the lossless extension this issue adds)."""
@@ -407,79 +386,52 @@ class TestStatsAndSidecar:
             0 <= t.spec_accepted <= t.spec_drafted for t in ticks
         )
 
-    async def test_sidecar_routes_everything_to_batcher(self):
-        """With batching.speculative=on the side micro-batcher is NOT
-        constructed — the continuous batcher serves draft-eligible
-        requests (spec_ticks move) and outputs stay well-formed."""
-        import grpc
-        import grpc.aio
-
-        from ggrmcp_tpu.rpc.pb import serving_pb2
-        from ggrmcp_tpu.serving.sidecar import Sidecar
-
-        side = Sidecar(spec_cfg(
-            batching=BatchingConfig(
-                max_batch_size=2, kv_cache_max_seq=256, speculative="on"
-            ),
-        ))
-        assert side.spec_batcher is None
-        port = await side.start(0)
-        channel = grpc.aio.insecure_channel(f"localhost:{port}")
-        try:
-            gen = channel.unary_unary(
-                "/ggrmcp.tpu.GenerateService/Generate",
-                request_serializer=(
-                    serving_pb2.GenerateRequest.SerializeToString
-                ),
-                response_deserializer=(
-                    serving_pb2.GenerateResponse.FromString
-                ),
-            )
-            resp = await gen(serving_pb2.GenerateRequest(
-                prompt="spec", max_new_tokens=6, return_tokens=True
-            ))
-            assert resp.completion_tokens == len(resp.token_ids) <= 6
-            assert resp.finish_reason in ("length", "stop")
-            stats_fn = channel.unary_unary(
-                "/ggrmcp.tpu.ModelInfoService/GetServingStats",
-                request_serializer=(
-                    serving_pb2.ServingStatsRequest.SerializeToString
-                ),
-                response_deserializer=(
-                    serving_pb2.ServingStatsResponse.FromString
-                ),
-            )
-            stats = await stats_fn(serving_pb2.ServingStatsRequest())
-            assert stats.spec_ticks > 0
-            assert stats.spec_drafted > 0
-            # The side micro-batcher's counters stay zero — nothing
-            # routed around the slot pool.
-            assert stats.speculative_calls == 0
-        finally:
-            await channel.close()
-            await side.stop()
-
-    def test_spec_without_draft_falls_back(self):
-        """speculative=on with NO draft configured must degrade to the
-        plain tick, loudly but functionally."""
-        eng = GenerationEngine(
-            llama.CONFIGS["tiny-llama"],
-            ServingConfig(
-                model="tiny-llama", mesh=MeshConfig(tensor=2, data=0)
-            ),
-        )
-        b = _batcher(eng, spec=True)
+    def test_no_draft_means_plain_tick(self, engine):
+        """No draft configured: the plain tick, no draft cache."""
+        b = _batcher(engine, spec=False)
         assert b._spec is False and b.dcache is None
 
     def test_config_rejects_bad_values(self):
         from ggrmcp_tpu.core import config as cfgmod
 
         cfg = cfgmod.default()
-        cfg.serving.batching.speculative = "maybe"
-        with pytest.raises(ValueError, match="speculative"):
+        cfg.serving.speculative_gamma = 0
+        with pytest.raises(ValueError, match="speculative_gamma"):
             cfg.validate()
-        cfg.serving.batching.speculative = "on"
+        cfg.serving.speculative_gamma = 4
+        cfg.serving.speculative_draft = "tiny-llama"
         cfg.serving.model = "tiny-mistral"
         cfg.serving.kv_ring = True
         with pytest.raises(ValueError, match="kv_ring"):
             cfg.validate()
+
+
+class TestValidation:
+    """What the engine refuses at construction when a draft is named."""
+
+    def test_embedding_draft_rejected(self):
+        with pytest.raises(ValueError, match="decoder"):
+            GenerationEngine(
+                llama.CONFIGS["tiny-llama"],
+                spec_cfg(speculative_draft="bert-tiny"),
+            )
+
+    def test_vocab_mismatch_rejected(self):
+        with pytest.raises(ValueError, match="vocab"):
+            GenerationEngine(
+                llama.CONFIGS["tiny-llama"],
+                spec_cfg(speculative_draft="llama-1b"),
+            )
+
+    def test_moe_target_rejected(self):
+        from ggrmcp_tpu.models import moe
+
+        with pytest.raises(
+            ValueError,
+            match=r"speculative decoding \(speculative_draft\) is not "
+                  r"supported for the moe family",
+        ):
+            GenerationEngine(
+                moe.CONFIGS["tiny-moe"],
+                spec_cfg(model="tiny-moe"),
+            )

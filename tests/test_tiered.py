@@ -31,30 +31,9 @@ def test_config_validation():
         cfg.validate()
     cfg.serving.batching.kv_tiers = [[512, 8], [4096, 2]]
     cfg.validate()
-    # Optional third element = per-tier prefix-pool size (0 = off).
-    cfg.serving.batching.kv_tiers = [[512, 8, 0], [4096, 2, 4]]
-    cfg.validate()
-    cfg.serving.batching.kv_tiers = [[512, 8, -1], [4096, 2]]
-    with pytest.raises(ValueError, match="prefix_entries"):
+    cfg.serving.batching.kv_tiers = [[512, 8, 0], [4096, 2]]
+    with pytest.raises(ValueError, match=r"\[max_seq, slots\]"):
         cfg.validate()
-
-
-def test_per_tier_prefix_pool_override(engine):
-    """[max_seq, slots, prefix_entries]: a tier whose workload can't
-    pool (short headline tier) opts out of the pool's HBM and warmup
-    compiles; other tiers keep the global setting."""
-    tiered = TieredBatcher(
-        engine,
-        BatchingConfig(
-            kv_tiers=[[64, 4, 0], [256, 4]],
-            prefix_cache_entries=2,
-            prefix_cache_min_seq=8,
-            prefix_cache_max_seq=32,
-            max_queue_delay_ms=1.0,
-        ),
-    )
-    assert tiered.tiers[0]._pfx_pool is None
-    assert tiered.tiers[1]._pfx_pool is not None
 
 
 def test_hbm_headroom_vs_flat_pool(engine):

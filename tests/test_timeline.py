@@ -188,9 +188,7 @@ class TestPhaseClosure:
         _assert_closure(batcher)
 
     async def test_spec_path(self, spec_engine):
-        batcher = await _drive(
-            spec_engine, [[5, 6, 7], [9, 10, 11]], speculative="on",
-        )
+        batcher = await _drive(spec_engine, [[5, 6, 7], [9, 10, 11]])
         ticks = _assert_closure(batcher)
         assert batcher.spec_ticks > 0
         assert any(t.spec_drafted > 0 for t in ticks)
@@ -295,8 +293,8 @@ class TestQueueSplit:
         _assert_queue_split(batcher)
 
     def test_a_record_without_a_pop_puts_all_of_queue_in_prefill(self):
-        """Unit: paths that stamp no pop (the speculative side batcher)
-        and clamped replays keep the sum."""
+        """Unit: a record that carries no pop stamp, and clamped
+        replays, keep the sum."""
         from ggrmcp_tpu.serving.flight_recorder import FlightRecorder
 
         rec = FlightRecorder()

@@ -310,9 +310,7 @@ class TestSpecTimesTP:
         """Draft/verify ticks on the tensor mesh: greedy exact-match
         keeps the stream identical to the plain TP tick (and the
         1-chip run, transitively)."""
-        outs, batcher = await _run_wave(
-            eng2_spec, _cfg(speculative="on")
-        )
+        outs, batcher = await _run_wave(eng2_spec, _cfg())
         assert outs == wave_tp
         assert batcher.spec_ticks >= 1
 

@@ -2,8 +2,7 @@
 
 The co-located deployment (`gateway --tpu`, serving/launcher.py) rides a
 private UDS by default: the hop never leaves the host, and a UDS round
-trip costs less shared-core CPU than TCP loopback (docs/BENCH.md
-proxy-phase table). These tests pin that the whole RPC stack — dial,
+trip costs less shared-core CPU than TCP loopback. These tests pin that the whole RPC stack — dial,
 reflection discovery, invocation, health — is transport-agnostic, and
 that the sidecar/launcher wiring produces working unix targets.
 """
