@@ -19,7 +19,10 @@ next starts (this parent never imports JAX or the package):
               flash_attention_sharded over tensor=4. Then
               paged_decode_attention against the gathered view
               (paged_view + attention_xla) at mistral's widths: 8 rows,
-              page 16, mixed lengths, a freed slot.
+              page 16, mixed lengths, a freed slot. Then
+              latent_prefill_attention against the XLA walk
+              (mla_moe.latent_attention) at kanana's attention widths:
+              2 rows of 512 queries on a 640-wide latent plane.
   serve       mistral-7b int8 synthetic weights, paged KV, one chip:
               tools/list, greedy generate (twice: same ids), SSE
               generatestream, a >= 1,024-token prompt, a second prompt
@@ -53,6 +56,7 @@ The last line of standard output on success is
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import signal
@@ -147,7 +151,9 @@ def kernel_leg_child(rehearsal: bool) -> None:
     interpreted rehearsal): 2e-3, as tests/test_models.py. The same
     tolerance holds paged_decode_attention to the gathered view: it
     rounds the weights to bf16 as attention_xla does, in another order
-    of blocks."""
+    of blocks; and latent_prefill_attention to the walk over the
+    latent plane, which rounds the same weights in blocks of another
+    size."""
     from ggrmcp_tpu.utils.jaxenv import init_runtime
 
     init_runtime("chip_smoke kernel leg")
@@ -156,11 +162,14 @@ def kernel_leg_child(rehearsal: bool) -> None:
     import numpy as np
 
     from ggrmcp_tpu.core.config import MeshConfig
+    from ggrmcp_tpu.models import mla_moe
     from ggrmcp_tpu.models.llama import paged_view
     from ggrmcp_tpu.ops.attention import (
         attention_xla,
         flash_attention,
         flash_attention_sharded,
+        latent_prefill_attention,
+        latent_prefill_attention_sharded,
         paged_decode_attention,
         paged_decode_attention_sharded,
     )
@@ -281,6 +290,77 @@ def kernel_leg_child(rehearsal: bool) -> None:
             )(qd, ka, va, table, kl, layer)
             compare(f"paged decode sharded tensor=4 window={window}",
                     np.asarray(out)[live], np.asarray(ref)[live])
+    # A prefill chunk of the latent family: every head of 512 queries
+    # on the one shared latent a position, read in place out of the
+    # contiguous plane, against the XLA walk in its absorbed form. A
+    # first chunk, a chunk deep in a document whose tail is padding,
+    # and (its output dropped by the batcher, zeros here) a row with no
+    # real query.
+    cfg = mla_moe.CONFIGS[
+        "tiny-mla-moe" if rehearsal else "kanana-2-30b-a3b-6l"]
+    if rehearsal:
+        layers, sq, s_max, block = 2, 32, 128, 32
+        q_offset, n_real = [0, 75, 40], [32, 20, 0]
+    else:
+        layers, sq, s_max, block = 2, 512, 4096, 512
+        q_offset, n_real = [0, 3072, 1024], [512, 300, 0]
+    rows, h = len(q_offset), cfg.num_heads
+    nope, rope, rank = (
+        cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank)
+    width = cfg.kv_planes[0][0]
+    plane = jax.random.normal(
+        jax.random.fold_in(key, 6), (layers, rows, s_max, width), dtype
+    ).at[..., cfg.latent_dim:].set(0)
+    q_nope = jax.random.normal(
+        jax.random.fold_in(key, 7), (rows, sq, h, nope), dtype)
+    q_rope = jax.random.normal(
+        jax.random.fold_in(key, 8), (rows, sq, h, rope), dtype)
+    wkv_b = 0.04 * jax.random.normal(
+        jax.random.fold_in(key, 9), (rank, h, nope + cfg.v_head_dim), dtype)
+    qo = jnp.asarray(q_offset, jnp.int32)
+    q_pos = qo[:, None] + jnp.arange(sq)[None, :]
+    real = np.arange(sq)[None, :] < np.asarray(n_real)[:, None]
+    last_q = jnp.max(jnp.where(real, q_pos, -1), axis=1)
+    layer = jnp.int32(layers - 1)
+
+    def walk(q_nope, q_rope, plane, wkv_b, q_pos, last_q):
+        def fetch(i):
+            return jax.lax.dynamic_slice_in_dim(
+                plane[layer], i * block, block, 1)
+
+        n_blocks = jnp.clip(
+            (jnp.max(last_q) + block) // block, 0, s_max // block)
+        return mla_moe.latent_attention(
+            q_nope, q_rope, fetch, n_blocks, block, wkv_b, q_pos, qo + sq,
+            cfg, absorbed=True)
+
+    def fused(attend, q_nope, q_rope, plane, wkv_b, last_q):
+        # The folding `mla_moe.attention_block` does around the kernel.
+        out = attend(
+            mla_moe.absorbed_queries(
+                q_nope, q_rope, wkv_b[..., :nope], width), plane, layer,
+            qo, qo + sq, last_q, value_width=rank,
+            scale=(nope + rope) ** -0.5, interpret=rehearsal)
+        return jnp.einsum("bshc,chd->bshd", out, wkv_b[..., nope:])
+
+    ref = jax.jit(walk)(q_nope, q_rope, plane, wkv_b, q_pos, last_q)
+    t0 = time.monotonic()
+    out = jax.jit(functools.partial(fused, latent_prefill_attention))(
+        q_nope, q_rope, plane, wkv_b, last_q)
+    jax.block_until_ready(out)
+    say(f"  latent_prefill_attention: compiled and ran in "
+        f"{time.monotonic() - t0:.1f} s (set-up, {dev.device_kind})")
+    name = (f"latent prefill q[{rows},{sq},{h},{width}] "
+            f"plane[{layers},{rows},{s_max},{width}]")
+    compare(name, np.asarray(out)[real], np.asarray(ref)[real])
+    check(not np.asarray(out, np.float32)[np.asarray(n_real) == 0].any(),
+          f"{name}: the output of a row with no real query is not zero")
+    if len(devices) >= 4:
+        out = jax.jit(functools.partial(fused, functools.partial(
+            latent_prefill_attention_sharded, mesh=mesh)))(
+            q_nope, q_rope, plane, wkv_b, last_q)
+        compare("latent prefill sharded tensor=4",
+                np.asarray(out)[real], np.asarray(ref)[real])
     print("LEG_RESULT " + json.dumps({
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices),
@@ -288,8 +368,8 @@ def kernel_leg_child(rehearsal: bool) -> None:
 
 
 def run_kernel_leg(rehearsal: bool) -> dict:
-    say("== leg kernel: flash_attention and paged_decode_attention vs "
-        "attention_xla on the device")
+    say("== leg kernel: flash_attention, paged_decode_attention and "
+        "latent_prefill_attention vs their XLA forms on the device")
     cmd = [sys.executable, os.path.abspath(__file__), "--child-kernel"]
     if rehearsal:
         cmd.append("--cpu-rehearsal")

@@ -23,7 +23,11 @@ of its own:
   step's query count (PERF.md has the measurement). Both walk the
   cache in blocks of keys with an online softmax and stop at the
   longest live row, so neither a [.., S_max] score tensor nor a
-  full-width gathered view is ever materialised.
+  full-width gathered view is ever materialised. A prefill chunk over
+  a contiguous plane takes the absorbed form as one Pallas kernel on a
+  TPU (`ops.attention.latent_prefill`), whose score block never
+  leaves VMEM; `latent_attention` stays what every other call runs
+  and what the tests hold the kernel to.
 - **The layer stack is not homogeneous** (leading dense layers, then
   expert layers), so the cache is loop-carried through BOTH layer scans
   and indexed [layer, ...] in place, for the paged arena (as PR 27 made
@@ -53,6 +57,7 @@ from ggrmcp_tpu.models.llama import (  # noqa: F401
     PagedKVCache,
     activation_spec,
 )
+from ggrmcp_tpu.ops import attention as attn_ops
 from ggrmcp_tpu.ops.quant import (
     QuantizedArray,
     dequantize,
@@ -64,14 +69,21 @@ from ggrmcp_tpu.ops.rope import apply_rope
 
 Params = common.Params
 
-# A step with at most this many queries a row attends in the absorbed
-# form, a longer one (a prefill chunk) in the expanded form. Measured
-# on a v5e (scripts/attn_form_bench.py; PERF.md section 4): the
-# absorbed form is as fast or faster at every size tried (512 queries
-# on a 12k past 8.46 against 8.70 ms, 16 decode rows 1.46 against
-# 16.45 ms), both being bound by the passes over the float32 scores. A
-# chunk stays expanded for its memory: absorbed, 16 rows x 512 queries
-# carry a 0.54 GB float32 accumulator beside a 2 GB mini cache.
+# A step with at most this many queries a row (a decode step, a short
+# re-admission suffix) attends in the absorbed form through the XLA
+# walk. A longer one is a prefill chunk: over a contiguous plane on a
+# TPU it attends in the absorbed form too, as one Pallas kernel
+# (ops/attention.py `latent_prefill`: score block and accumulator in
+# VMEM); where that kernel is not the call's kind (the CPU, a quantized
+# or float8 plane, the paged arena, no cache) the walk attends it in
+# the expanded form. Measured on a v5e (scripts/attn_form_bench.py;
+# PERF.md section 4): through the walk the absorbed form is as fast or
+# faster at every size tried (512 queries on a 12k past 8.42 against
+# 8.64 ms, 16 decode rows 1.47 against 16.6 ms), both being bound by
+# passes over float32 scores in HBM, and the kernel takes 3.76 ms. The
+# walk keeps a chunk expanded for its memory: absorbed, 16 rows x 512
+# queries carry a 0.54 GB float32 accumulator in HBM beside a 2 GB
+# mini cache; the kernel's accumulator is a tile's, 1 MiB of VMEM.
 ABSORBED_MAX_QUERIES = 128
 
 # What the batcher may ask of this family (serving/batching.py reads
@@ -85,7 +97,9 @@ ROUTING_STATS = True
 # 512: past 2,048 tokens). This family serves contexts of 6k-13k
 # tokens, where exact depths are a program each (13..24 chunks); the
 # padding chunks are cheap here because `attention_block` stops its
-# walk over the keys at the last `valid` one. llama's chunk attention
+# walk over the keys at the last `valid` one (the XLA walk for the
+# whole step; the prefill kernel a query tile, and a tile of padding
+# walks nothing). llama's chunk attention
 # has no such mask, so llama keeps exact depths and group admission, as
 # before this family came (no cell measures llama past 2,048 tokens).
 DEEP_GRID_CHUNKS = 4
@@ -281,12 +295,23 @@ def paged_cache_specs() -> PagedKVCache:
 
 
 def _key_block(b: int, s: int, s_keys: int, page: int) -> int:
-    """Keys a block: bounds the [B, H, S, block] float32 scores to a
-    few hundred MB at the published widths (16 rows x 512 queries)."""
+    """Keys a block of the XLA walk (the prefill kernel has its own,
+    ops/attention.py): bounds the walk's [B, H, S, block] float32
+    scores in HBM to a few hundred MB at the published widths (16 rows
+    x 512 queries)."""
     block = 2048 if b * s <= 512 else 512
     while block > page and (s_keys % block or block % page):
         block //= 2
     return block if block > page else page
+
+
+def absorbed_queries(q_nope, q_rope, w_uk, width: int):
+    """The step's queries in the latent plane's own space,
+    `[q_nope W_UK | q_rope | 0]`, `width` wide: a plane row is their
+    key as it is stored (what `ops.attention.latent_prefill` takes)."""
+    q = jnp.concatenate(
+        [jnp.einsum("bshd,chd->bshc", q_nope, w_uk), q_rope], axis=-1)
+    return jnp.pad(q, ((0, 0),) * 3 + ((0, width - q.shape[-1]),))
 
 
 def latent_attention(
@@ -367,7 +392,7 @@ def latent_attention(
 
 def attention_block(
     x, lp, cfg: MlaMoeConfig, positions, cache_k, cache_len,
-    page_table, layer, valid=None,
+    page_table, layer, valid=None, use_flash=None, flash_mesh=None,
 ):
     """Pre-norm latent attention with residual. `cache_k` is the WHOLE
     latent plane, loop-carried: `[L, B, S_max, latent]` (contiguous)
@@ -402,6 +427,7 @@ def attention_block(
         lat, ((0, 0), (0, 0), (0, cfg.kv_planes[0][0] - cfg.latent_dim)))
     wkv_b = lp["wkv_b"].reshape(rank, h, nope + cfg.v_head_dim)
 
+    out = None
     if cache_k is None:
         pad = -s % min(s, 512)
         lat_p = jnp.pad(lat, ((0, 0), (0, pad), (0, 0)))
@@ -447,6 +473,20 @@ def attention_block(
         n_blocks = jnp.clip(
             (jnp.max(last) + block) // block, 0, s_keys // block)
         arena = cache_k
+        if s > ABSORBED_MAX_QUERIES and page_table is None and not quantized:
+            # A prefill chunk over a contiguous plane: the absorbed
+            # form as one kernel where `latent_prefill` finds its kind
+            # (a TPU, the plane in the model's dtype), else None and
+            # the walk below.
+            out = attn_ops.latent_prefill(
+                absorbed_queries(
+                    q_nope, q_rope, wkv_b[..., :nope], lat.shape[-1]),
+                cache_k, layer, cache_len, kv_len, jnp.max(last, axis=1),
+                value_width=rank, scale=1.0 / math.sqrt(nope + rope),
+                use_flash=use_flash, flash_mesh=flash_mesh,
+            )
+            if out is not None:
+                out = jnp.einsum("bshc,chd->bshd", out, wkv_b[..., nope:])
 
         def fetch(i):
             if page_table is not None:
@@ -467,10 +507,11 @@ def attention_block(
             blk = kv_map(read, arena)
             return dequantize(blk) if quantized else blk.astype(lat.dtype)
 
-    out = latent_attention(
-        q_nope, q_rope, fetch, n_blocks, block, wkv_b, positions, kv_len,
-        cfg, absorbed=s <= ABSORBED_MAX_QUERIES,
-    )
+    if out is None:
+        out = latent_attention(
+            q_nope, q_rope, fetch, n_blocks, block, wkv_b, positions,
+            kv_len, cfg, absorbed=s <= ABSORBED_MAX_QUERIES,
+        )
     x = x + out.reshape(b, s, h * cfg.v_head_dim) @ lp["wo"]
     return x, cache_k
 
@@ -491,15 +532,39 @@ def _task_block(pairs: int, experts: int) -> int:
     return min(256, max(8, 1 << (mean - 1).bit_length()))
 
 
+def task_map(counts, block: int, max_tasks: int):
+    """The block tasks of `routed_experts`, known before its loop
+    starts: how many there are and, for task i of `max_tasks` (a static
+    bound on that number), its expert, the first sorted row it computes
+    and how many of its `block` rows are that expert's. An expert with `counts[e]` pairs
+    has ceil(counts[e] / block) tasks, in expert order; `task_end`
+    holds their running count, and a task's expert is the number of
+    entries of it the task's index has reached: one [max_tasks, E]
+    compare where a binary search inside the loop took a `while` of
+    scalar slices a task. Integers only. Tasks past the last one (the
+    loop never runs them) read as the last expert's."""
+    e = counts.shape[0]
+    starts = jnp.cumsum(counts) - counts
+    n_tasks = (counts + block - 1) // block
+    task_end = jnp.cumsum(n_tasks)
+    i = jnp.arange(max_tasks, dtype=jnp.int32)
+    ex = jnp.minimum(
+        (task_end[None, :] <= i[:, None]).sum(1, dtype=jnp.int32), e - 1)
+    j = i - (task_end[ex] - n_tasks[ex])
+    return task_end[-1], ex, starts[ex] + j * block, counts[ex] - j * block
+
+
 def routed_experts(xt, idx, weight, valid, banks, layer, cfg: MlaMoeConfig):
     """Every routed (token, expert) pair, no capacity and no drops.
     Pairs are sorted by expert; each block task multiplies up to
     `block` rows of ONE expert by that expert's three matrices, so the
     work is pairs/block + at most one task an expert, and an expert no
-    valid token chose is never read. `banks` are the STACKED expert
-    matrices `[layers, E, ..]`, indexed `[layer, expert]` inside the
-    task: sliced out a layer first, XLA would copy a layer's whole
-    bank (1.2 GB at the published widths) in front of the loop.
+    valid token chose is never read. Which expert, which rows and how
+    many of them a task keeps come from `task_map`, before the loop.
+    `banks` are the STACKED expert matrices `[layers, E, ..]`, indexed
+    `[layer, expert]` inside the task: sliced out a layer first, XLA
+    would copy a layer's whole bank (1.2 GB at the published widths)
+    in front of the loop.
     Returns (out [T, D], stats int32 [3]: experts hit, largest load,
     pairs)."""
     t, d = xt.shape
@@ -511,16 +576,13 @@ def routed_experts(xt, idx, weight, valid, banks, layer, cfg: MlaMoeConfig):
         flat = jnp.where(jnp.repeat(valid, k), flat, e)
     order = jnp.argsort(flat, stable=True)
     counts = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
-    starts = jnp.cumsum(counts) - counts
-    n_tasks = (counts + block - 1) // block
-    task_end = jnp.cumsum(n_tasks)
+    n_tasks, task_ex, task_row0, task_rows = task_map(
+        counts, block, pairs // block + e)
     xs = jnp.pad(xt[order // k], ((0, block), (0, 0)))  # [pairs+block, D]
     rows = jnp.arange(block)[:, None]
 
     def task(i, ys):
-        ex = jnp.searchsorted(task_end, i, side="right").astype(jnp.int32)
-        j = i - (task_end[ex] - n_tasks[ex])
-        row0 = starts[ex] + j * block
+        ex, row0 = task_ex[i], task_row0[i]
         xb = jax.lax.dynamic_slice(xs, (row0, 0), (block, d))
         yb = _swiglu(xb, *(
             jax.lax.dynamic_slice(
@@ -529,11 +591,11 @@ def routed_experts(xt, idx, weight, valid, banks, layer, cfg: MlaMoeConfig):
             for w in banks
         ))
         old = jax.lax.dynamic_slice(ys, (row0, 0), (block, d))
-        keep = rows < counts[ex] - j * block  # the next expert's rows stay
+        keep = rows < task_rows[i]  # the next expert's rows stay
         return jax.lax.dynamic_update_slice(
             ys, jnp.where(keep, yb, old), (row0, 0))
 
-    ys = jax.lax.fori_loop(0, task_end[-1], task, jnp.zeros_like(xs))
+    ys = jax.lax.fori_loop(0, n_tasks, task, jnp.zeros_like(xs))
     y = jnp.zeros((pairs, d), xt.dtype).at[order].set(ys[:pairs])
     out = (
         y.reshape(t, k, d).astype(jnp.float32) * weight[..., None]
@@ -573,13 +635,17 @@ def forward(
     valid: Optional[jnp.ndarray] = None,  # [B, S] bool
     logit_idx: Optional[jnp.ndarray] = None,  # [B]: one position a row
     with_stats: bool = False,
+    use_flash: Optional[bool] = None,
+    flash_mesh: Any = None,
 ):
     """Same contract as `llama.forward`. `valid` marks real tokens:
     the others route to no expert. `logit_idx` computes the head at one
     position a row only (logits [B, 1, V]): at this vocabulary a
     [16, 512, V] float32 block is 4 GB. `with_stats` also returns the
     routing counts summed over the expert layers (int32 [3]: experts
-    hit, largest load of an expert summed over layers, pairs)."""
+    hit, largest load of an expert summed over layers, pairs).
+    `use_flash` / `flash_mesh`: the engine's word on attention kernels
+    for its mesh, as for llama (ops/attention.py `latent_prefill`)."""
     b, s = tokens.shape
     x = embed_lookup(params["embed"], tokens, cfg.jnp_dtype)
     if cache is not None:
@@ -595,7 +661,8 @@ def forward(
         x, plane = carry
         lp, layer = scanned
         x, plane = attention_block(
-            x, lp, cfg, positions, plane, length, table, layer, valid)
+            x, lp, cfg, positions, plane, length, table, layer, valid,
+            use_flash, flash_mesh)
         n = common.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         x = x + _swiglu(n, lp["w_gate"], lp["w_up"], lp["w_down"])
         return (x, plane), None
@@ -609,7 +676,8 @@ def forward(
         x, plane = carry
         lp, layer = scanned
         x, plane = attention_block(
-            x, lp, cfg, positions, plane, length, table, layer, valid)
+            x, lp, cfg, positions, plane, length, table, layer, valid,
+            use_flash, flash_mesh)
         n = common.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         out, stats = moe_ffn(n, lp, banks, layer - kd, cfg, valid)
         return (x + out, plane), stats

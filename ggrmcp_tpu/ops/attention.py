@@ -1,7 +1,9 @@
 """Attention ops: XLA-fused reference path, a Pallas flash-attention
-TPU kernel for prefill and a Pallas paged-decode kernel.
+TPU kernel for prefill, a Pallas paged-decode kernel and a Pallas
+latent-prefill kernel.
 
-Two implementations with one contract, and a third for paged decode:
+Two implementations with one contract, a third for paged decode and a
+fourth for the latent family's prefill chunks:
 
 - `attention_xla` — einsum + masked softmax. XLA fuses this well and it
   is the correct choice for short sequences, decode steps (q_len == 1),
@@ -26,6 +28,17 @@ Two implementations with one contract, and a third for paged decode:
   full-width [B, W*P] view being gathered every layer. `paged_decode`
   picks it from platform, storage dtype and query count, or returns
   None and the caller gathers the view for `attention`.
+- `latent_prefill_attention` — a prefill chunk of the latent-attention
+  family (models/mla_moe.py) over its contiguous latent plane
+  [L, B, S_max, W]: one key a position shared by every head, its first
+  columns the value, so a tile's (query, head) pairs are the rows of
+  one score block against one key block DMA'd out of the plane in
+  place; scores, running maximum and sum and the accumulator stay in
+  VMEM. `latent_prefill` picks it from platform, storage dtype and
+  widths, or returns None and the caller walks the plane in XLA. It
+  shares no body with the two kernels above: K and V are one plane,
+  the value a column slice of the key, one KV head for every query
+  head.
 
 `attention` picks per call: flash for long prefill on TPU (crossover
 threshold FLASH_MIN_SEQ — an op-count estimate, not yet measured),
@@ -738,6 +751,269 @@ def paged_decode_attention_sharded(
 
 
 # ---------------------------------------------------------------------------
+# Latent prefill: a chunk's queries over one shared latent plane
+# ---------------------------------------------------------------------------
+
+# Score rows (queries x heads) a tile of the latent-prefill kernel
+# holds, and keys a block of its walk: a [512, 512] float32 score block
+# (1 MiB) and a [512, V] float32 accumulator stay in VMEM, and every
+# 128 x 128 tile of a key block meets 512 query rows on the MXU.
+_LATENT_PREFILL_ROWS = 512
+_LATENT_PREFILL_BLOCK_K = 512
+
+
+def _latent_prefill_blocks(s: int, h: int, s_keys: int) -> tuple[int, int]:
+    """(queries a tile, keys a block): the largest powers of two under
+    the targets that divide the chunk and the plane."""
+    block_q = max(1, _LATENT_PREFILL_ROWS // h)
+    while s % block_q:
+        block_q //= 2
+    block_k = _LATENT_PREFILL_BLOCK_K
+    while s_keys % block_k:
+        block_k //= 2
+    return block_q, block_k
+
+
+def _latent_prefill_kernel(
+    layer_ref,  # SMEM [1] int32
+    q_off_ref,  # SMEM [B] int32 — position of a row's first query
+    len_ref,  # SMEM [B] int32 — keys a row may see
+    last_ref,  # SMEM [B] int32 — position of a row's last real query
+    # (-1: the row has none)
+    q_ref,  # VMEM [block_q*H, W] — one tile's queries, (query, head) major
+    qi_ref,  # VMEM [block_q*H, 1] int32 — a score row's query in the tile
+    plane_hbm,  # HBM [L, B, S_max, W] — every layer's latents, never copied
+    o_ref,  # VMEM [block_q*H, V]
+    k_buf,  # VMEM [2, block_k, W]
+    sems,  # DMA [2 (slot)]
+    acc_ref,  # VMEM [block_q*H, V] f32
+    *,
+    block_q: int,
+    block_k: int,
+    scale: float,
+):
+    """One (row, query tile) a grid step: online softmax over the row's
+    latents in the plane, `block_k` keys at a time, the next block in
+    flight while this one is computed. Every head of every query of the
+    tile is a score row against the one shared key block; the block's
+    first V columns are its values. The walk ends at the last key a
+    REAL query of the tile may see: a tile past the row's last real
+    query walks nothing and emits zeros. Key blocks every query of the
+    tile sees whole take no mask."""
+    b, t = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
+    kv_len = len_ref[b]
+    v_width = o_ref.shape[-1]
+    first_pos = q_off_ref[b] + t * block_q  # the tile's first query
+    last_pos = jnp.minimum(first_pos + block_q - 1, last_ref[b])
+    # Keys [0, seen) are walked: none where the tile has no real query.
+    seen = jnp.where(
+        first_pos <= last_pos, jnp.minimum(last_pos + 1, kv_len), 0)
+    n_plane = plane_hbm.shape[2] // block_k
+    end = jnp.clip((seen + block_k - 1) // block_k, 0, n_plane)
+    whole = jnp.clip(jnp.minimum(first_pos + 1, kv_len) // block_k, 0, end)
+
+    def copy(i, slot):
+        return pltpu.make_async_copy(
+            plane_hbm.at[layer, b, pl.ds(i * block_k, block_k)],
+            k_buf.at[slot], sems.at[slot],
+        )
+
+    @pl.when(end > 0)
+    def _():
+        copy(0, 0).start()
+
+    q = q_ref[...]
+    q_pos = first_pos + qi_ref[...]  # [rows, 1]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(i, carry, masked):
+        m_prev, l_prev = carry
+        slot = i % 2
+
+        @pl.when(i + 1 < end)
+        def _():
+            copy(i + 1, 1 - slot).start()
+
+        copy(i, slot).wait()
+        k_blk = k_buf[slot]  # [block_k, W]
+        scores = jax.lax.dot_general(
+            q, k_blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [rows, block_k]
+        if masked:
+            k_pos = i * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_k), 1)
+            mask = (k_pos <= q_pos) & (k_pos < kv_len)
+            scores = jnp.where(mask, scores, NEG_INF)
+        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)
+        if masked:  # a query may see no key of this block at all
+            p = jnp.where(mask, p, 0.0)
+        l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(k_blk.dtype), k_blk[:, :v_width],
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l_new
+
+    rows = q.shape[0]
+    carry = (
+        jnp.full((rows, 1), NEG_INF, jnp.float32),
+        jnp.zeros((rows, 1), jnp.float32),
+    )
+    carry = jax.lax.fori_loop(
+        0, whole, functools.partial(block, masked=False), carry)
+    _, l = jax.lax.fori_loop(
+        whole, end, functools.partial(block, masked=True), carry)
+    o_ref[...] = jnp.where(
+        l > 0.0, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0
+    ).astype(o_ref.dtype)
+
+
+def _latent_prefill_vmem_bytes(
+    rows: int, w: int, v: int, block_k: int, itemsize: int
+) -> int:
+    """VMEM the latent-prefill kernel needs, told to the compiler: the
+    key block buffer, two slots; a tile's queries and its output
+    (double-buffered by the pipeline); the float32 accumulator; the
+    float32 scores, weights and mask of one block; 4 MiB of headroom
+    for Mosaic's own scratch. 8.5 MiB + headroom at the published
+    widths (512 rows, 640-wide keys, 512-wide values, bf16)."""
+    bufs = 2 * block_k * w * itemsize
+    qo = 2 * rows * (w + v) * itemsize
+    work = 4 * (rows * v + 4 * rows * block_k)
+    return bufs + qo + work + (4 << 20)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("value_width", "scale", "block_q", "block_k", "interpret"),
+)
+def latent_prefill_attention(
+    q: jnp.ndarray,  # [B, S, H, W] — queries folded into the latent space
+    plane: jnp.ndarray,  # [L, B, S_max, W] — every layer's latents
+    layer: jnp.ndarray,  # scalar layer index
+    q_offset: jnp.ndarray,  # [B] position of a row's first query
+    kv_len: jnp.ndarray,  # [B] keys a row may see
+    last_q: jnp.ndarray,  # [B] position of a row's last REAL query (-1:
+    # none): queries past it are padding, and their output is undefined
+    # (finite)
+    *,
+    value_width: int,  # a key's first `value_width` columns are its value
+    scale: float,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Causal attention of a prefill chunk over the latents its row
+    holds in a contiguous plane, read in place: one key per position
+    shared by all H heads (a plane row is the key, its first
+    `value_width` columns the value), the score block, the running
+    maximum and sum and the accumulator in VMEM (float32), the weights
+    cast to the plane's dtype before the value matmul. Query i of row b
+    sits at position q_offset[b] + i and sees keys [0, min(position + 1,
+    kv_len[b])). What `mla_moe.latent_attention` computes in its
+    absorbed form, for the real queries, without a score block in HBM.
+    Returns [B, S, H, value_width]. Compiled for the TPU unless
+    `interpret=True` (CPU tests) asks for the interpreter."""
+    b, s, h, w = q.shape
+    s_keys = plane.shape[2]
+    assert plane.shape[1] == b and plane.shape[3] == w, (q.shape, plane.shape)
+    auto_q, auto_k = _latent_prefill_blocks(s, h, s_keys)
+    block_q, block_k = block_q or auto_q, block_k or auto_k
+    assert s % block_q == 0 and s_keys % block_k == 0, (
+        f"chunk {s} / plane {s_keys} not multiples of blocks "
+        f"({block_q},{block_k})"
+    )
+    rows = block_q * h
+    kernel = functools.partial(
+        _latent_prefill_kernel, block_q=block_q, block_k=block_k, scale=scale,
+    )
+    # Score row r of a tile is (query r // H, head r % H): a constant
+    # of the program, as in the paged-decode kernel.
+    qi = (np.arange(rows) // h).astype(np.int32)[:, None]
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, s // block_q),
+            in_specs=[
+                pl.BlockSpec((None, rows, w), lambda bi, ti, *_: (bi, ti, 0)),
+                pl.BlockSpec((rows, 1), lambda *_: (0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, rows, value_width), lambda bi, ti, *_: (bi, ti, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, block_k, w), plane.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((rows, value_width), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, s * h, value_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_latent_prefill_vmem_bytes(
+                rows, w, value_width, block_k, plane.dtype.itemsize
+            ),
+        ),
+        name="latent_attention_prefill",
+        interpret=interpret,
+    )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        q_offset.astype(jnp.int32), kv_len.astype(jnp.int32),
+        last_q.astype(jnp.int32), q.reshape(b, s * h, w), qi, plane,
+    )
+    return out.reshape(b, s, h, value_width)
+
+
+def latent_prefill_attention_sharded(
+    q: jnp.ndarray,  # [B, S, H, W]
+    plane: jnp.ndarray,  # [L, B, S_max, W]
+    layer: jnp.ndarray,
+    q_offset: jnp.ndarray,
+    kv_len: jnp.ndarray,
+    last_q: jnp.ndarray,
+    mesh,
+    *,
+    value_width: int,
+    scale: float,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """`latent_prefill_attention` on a multi-device mesh, one kernel
+    per shard: rows over `data`/`fsdp` as `mla_moe.cache_specs` shards
+    the plane, heads over `tensor` (every shard reads the whole latent:
+    it is shared by all heads). Manual over EVERY mesh axis, as
+    `flash_attention_sharded` is; the data axes must divide the rows
+    and `tensor` the heads."""
+    from jax.sharding import PartitionSpec as P
+
+    ok, why = _flash_shardable(mesh, q.shape[0], q.shape[2])
+    if not ok:
+        raise ValueError(why)
+    qspec = P(("data", "fsdp"), None, "tensor", None)
+    rspec = P(("data", "fsdp"))
+
+    def local(q, pln, ly, qo, kl, lq):
+        return latent_prefill_attention(
+            q, pln, ly, qo, kl, lq, value_width=value_width, scale=scale,
+            interpret=interpret,
+        )
+
+    return jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(
+            qspec, P(None, ("data", "fsdp"), None, None), P(),
+            rspec, rspec, rspec,
+        ),
+        out_specs=qspec,
+        check_vma=False,
+    )(q, plane, layer, q_offset, kv_len, last_q)
+
+
+# ---------------------------------------------------------------------------
 # Dispatcher
 # ---------------------------------------------------------------------------
 
@@ -752,7 +1028,9 @@ FLASH_MIN_SEQ = 256
 # this counts programs, not executions): "flash" / "flash_sharded" =
 # the Pallas prefill kernel is in the program; "paged_decode" = the
 # paged-decode kernel is (a layer scan traces its body once, so one a
-# forward call a tick program holds); "xla_fallback" = the call wanted
+# forward call a tick program holds); "latent_prefill" = the latent
+# family's prefill kernel is (two a forward call: its dense and its
+# expert layers are a scan each); "xla_fallback" = the call wanted
 # a kernel and its shapes did not shard over the mesh, or the engine
 # runs none on its mesh. Process-wide, like the compile watcher: the sidecar
 # exports it beside mesh_spec_downgrades (attn_kernel_programs /
@@ -767,6 +1045,7 @@ def dispatch_stats() -> dict:
         "attn_kernel_programs": (
             dispatch_counts["flash"] + dispatch_counts["flash_sharded"]
             + dispatch_counts["paged_decode"]
+            + dispatch_counts["latent_prefill"]
         ),
         "attn_kernel_fallbacks": dispatch_counts["xla_fallback"],
     }
@@ -920,4 +1199,72 @@ def paged_decode(
         )
     return paged_decode_attention(
         q, k_arena, v_arena, table, kv_len, layer, window=window
+    )
+
+
+def latent_prefill(
+    q: jnp.ndarray,  # [B, S, H, W] — queries folded into the latent space
+    plane,  # [L, B, S_max, W] latents (an array in some dtype)
+    layer: jnp.ndarray,
+    q_offset: jnp.ndarray,  # [B]
+    kv_len: jnp.ndarray,  # [B]
+    last_q: jnp.ndarray,  # [B] position of a row's last real query
+    *,
+    value_width: int,
+    scale: float,
+    use_flash: Optional[bool] = None,
+    flash_mesh=None,
+) -> Optional[jnp.ndarray]:
+    """The latent-prefill kernel where the call is its kind, else None
+    and the caller walks the plane in XLA (`mla_moe.latent_attention`).
+    Chosen from what the call can see, no option: a TPU; a plane stored
+    in the queries' own dtype (a float8 plane is turned away here, a
+    quantized one never gets here); keys and values of whole 128-lane
+    rows; a chunk that tiles into whole sublane groups of score rows.
+    Which calls are chunks (by query count) is the caller's to say. On
+    a multi-device mesh the kernel runs per shard (`flash_mesh`, as for
+    the prefill kernel). A call of that kind on a mesh that cannot run
+    the kernel — the engine turned kernels off (`use_flash=False`), or
+    the mesh divides neither the rows nor the heads — walks in XLA too,
+    logged and counted as a fallback."""
+    b, s, h, w = q.shape
+    t_ax = 1 if flash_mesh is None else flash_mesh.shape.get("tensor", 1)
+    h_shard = max(1, h // t_ax)
+    block_q, _ = _latent_prefill_blocks(s, h_shard, plane.shape[2])
+    if (
+        not _on_tpu()
+        or plane.dtype != q.dtype
+        or w % 128 != 0
+        or value_width % 128 != 0
+        or (block_q * h_shard) % 16 != 0
+    ):
+        return None
+    why = ""
+    if use_flash is False:
+        why = "the engine runs no attention kernel on this mesh"
+    elif flash_mesh is not None:
+        _, why = _flash_shardable(flash_mesh, b, h)
+    if why:
+        dispatch_counts["xla_fallback"] += 1
+        logger.warning(
+            "attention: latent prefill q%s plane%s wanted the Pallas kernel "
+            "(%s) — this program walks the plane in XLA instead (watch "
+            "gauge attn_kernel_fallbacks)",
+            tuple(q.shape), tuple(plane.shape), why,
+        )
+        return None
+    dispatch_counts["latent_prefill"] += 1
+    logger.info(
+        "attention: latent-prefill Pallas kernel%s for q%s plane%s",
+        " per shard" if flash_mesh is not None else "",
+        tuple(q.shape), tuple(plane.shape),
+    )
+    if flash_mesh is not None:
+        return latent_prefill_attention_sharded(
+            q, plane, layer, q_offset, kv_len, last_q, flash_mesh,
+            value_width=value_width, scale=scale,
+        )
+    return latent_prefill_attention(
+        q, plane, layer, q_offset, kv_len, last_q,
+        value_width=value_width, scale=scale,
     )

@@ -796,7 +796,8 @@ class GenerationEngine:
         the mla_moe family's one-position head and routing counts
         (models/mla_moe.py::forward); callers pass them to that family
         only, which takes no `ring` and no `lora_idx` (_check_family
-        refuses both)."""
+        refuses both). Every family hears the engine's word on
+        attention kernels for its mesh (`use_flash`, `flash_mesh`)."""
         if self.pp_serving:
             return self._pp.pipeline_forward_cached(
                 params, self.cfg, tokens, cache, self.mesh, ring=ring
@@ -805,6 +806,7 @@ class GenerationEngine:
             return self.fam.forward(
                 params, self.cfg, tokens, cache, valid=valid,
                 logit_idx=logit_idx, with_stats=with_stats,
+                use_flash=self.use_flash, flash_mesh=self.flash_mesh,
             )
         if self.fam is moe_mod:
             return self.fam.forward(

@@ -1,20 +1,24 @@
 #!/usr/bin/env python3
 """Which form of latent attention for how many queries? Times
-`models/mla_moe.latent_attention` in both forms (absorbed, expanded) at
-the published widths, for a step of S queries a row against a latent
-past of P tokens in a contiguous cache, on the chip:
+`models/mla_moe.latent_attention` in both forms (absorbed, expanded)
+and the latent-prefill kernel (`ops/attention.latent_prefill_attention`,
+the absorbed form with its score block in VMEM) at the published
+widths, for a step of S queries a row against a latent past of P
+tokens in a contiguous cache, on the chip:
 
     python3 scripts/attn_form_bench.py            # on the TPU
     python3 scripts/attn_form_bench.py --cpu      # rehearsal, tiny sizes
 
 Prints one line a (rows, S, P, form): milliseconds a call, median of
-`--reps` after a warm-up. `ABSORBED_MAX_QUERIES` in models/mla_moe.py
+`--reps` after a warm-up, and for the kernel its largest difference
+from the absorbed walk. `ABSORBED_MAX_QUERIES` in models/mla_moe.py
 was set from this table (PERF.md, section 4).
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 import statistics
 import sys
@@ -35,6 +39,7 @@ def main() -> int:
     import jax.numpy as jnp
 
     from ggrmcp_tpu.models import mla_moe as M
+    from ggrmcp_tpu.ops import attention as A
 
     cfg = M.CONFIGS["tiny-mla-moe" if args.cpu else "kanana-2-30b-a3b-6l"]
     dtype = cfg.jnp_dtype
@@ -57,28 +62,50 @@ def main() -> int:
         q_pos = past + jnp.broadcast_to(jnp.arange(s), (rows, s))
         kv_len = jnp.full((rows,), past + s, jnp.int32)
         block = M._key_block(rows, s, s_max, 1)
-        for absorbed in (True, False):
-            def run(q_nope, q_rope, lat, wkv_b, q_pos, kv_len):
-                def fetch(i):
-                    return jax.lax.dynamic_slice_in_dim(lat, i * block, block, 1)
 
-                n_blocks = (jnp.max(kv_len) + block - 1) // block
-                return M.latent_attention(
-                    q_nope, q_rope, fetch, n_blocks, block, wkv_b, q_pos,
-                    kv_len, cfg, absorbed=absorbed)
+        def walk(absorbed, q_nope, q_rope, lat, wkv_b, q_pos, kv_len):
+            def fetch(i):
+                return jax.lax.dynamic_slice_in_dim(lat, i * block, block, 1)
 
+            n_blocks = (jnp.max(kv_len) + block - 1) // block
+            return M.latent_attention(
+                q_nope, q_rope, fetch, n_blocks, block, wkv_b, q_pos,
+                kv_len, cfg, absorbed=absorbed)
+
+        def kernel(q_nope, q_rope, lat, wkv_b, q_pos, kv_len):
+            # The folding `mla_moe.attention_block` does around it.
+            out = A.latent_prefill_attention(
+                M.absorbed_queries(q_nope, q_rope, wkv_b[..., :nope], width),
+                lat[None], jnp.int32(0), q_pos[:, 0], kv_len, q_pos[:, -1],
+                value_width=cfg.kv_lora_rank,
+                scale=(nope + rope) ** -0.5, interpret=args.cpu)
+            return jnp.einsum("bshc,chd->bshd", out, wkv_b[..., nope:])
+
+        forms = {
+            "absorbed": functools.partial(walk, True),
+            "expanded": functools.partial(walk, False),
+            "kernel": kernel,
+        }
+        operands = (q_nope, q_rope, lat, wkv_b, q_pos, kv_len)
+        want = None
+        for form, run in forms.items():
             fn = jax.jit(run)
-            out = fn(q_nope, q_rope, lat, wkv_b, q_pos, kv_len)
-            jax.block_until_ready(out)
+            out = jax.block_until_ready(fn(*operands))
+            if form == "absorbed":
+                want = out.astype(jnp.float32)
             times = []
             for _ in range(args.reps):
                 t = time.perf_counter()
-                jax.block_until_ready(
-                    fn(q_nope, q_rope, lat, wkv_b, q_pos, kv_len))
+                jax.block_until_ready(fn(*operands))
                 times.append((time.perf_counter() - t) * 1000.0)
+            diff = (
+                f"  max|kernel - absorbed| "
+                f"{float(jnp.abs(out.astype(jnp.float32) - want).max()):.4f}"
+                if form == "kernel" else ""
+            )
             print(f"rows {rows:2d} queries {s:4d} past {past:6d} block {block:4d} "
-                  f"{'absorbed' if absorbed else 'expanded'}: "
-                  f"{statistics.median(times):8.3f} ms", flush=True)
+                  f"{form}: {statistics.median(times):8.3f} ms{diff}",
+                  flush=True)
     return 0
 
 
