@@ -23,6 +23,15 @@ next starts (this parent never imports JAX or the package):
               latent_prefill_attention against the XLA walk
               (mla_moe.latent_attention) at kanana's attention widths:
               2 rows of 512 queries on a 640-wide latent plane.
+  sparse      one expert layer of deepseek-v3.2 at its published widths
+              (models/mla_moe.py: q-compressed latent attention, the
+              indexer, group-limited routing over this chip's 16 of 256
+              experts) against the benchmark's float32 reference layer
+              (benchmark/reference_dsv32.py): 4,608 tokens through a
+              contiguous cache 512 at a time, so the last chunks select
+              2,048 of up to 4,608 keys a query (the masked walk), then
+              one decode step over the same latents as pages (the
+              gather by token index).
   serve       mistral-7b int8 synthetic weights, paged KV, one chip:
               tools/list, greedy generate (twice: same ids), SSE
               generatestream, a >= 1,024-token prompt, a second prompt
@@ -367,10 +376,136 @@ def kernel_leg_child(rehearsal: bool) -> None:
     }), flush=True)
 
 
-def run_kernel_leg(rehearsal: bool) -> dict:
-    say("== leg kernel: flash_attention, paged_decode_attention and "
-        "latent_prefill_attention vs their XLA forms on the device")
-    cmd = [sys.executable, os.path.abspath(__file__), "--child-kernel"]
+def sparse_leg_child(rehearsal: bool) -> None:
+    """Runs in the child. One expert layer of the `deepseek_v32`
+    member (`attention_block` + `moe_ffn`, what a layer of `forward`
+    runs) against `reference_dsv32`'s float32 `expert_layer` on the
+    same drawn weights and the same input, random hidden states.
+
+    What is compared is the layer's own contribution `y - x`, by root
+    mean square over the compared positions, as a share of the
+    reference's: bf16 weights and activations on the chip against
+    float32 at highest precision give ~1e-2 (every matmul input is
+    rounded to 8 bits); the limit is 3e-2. A wrong selection is not a
+    rounding: the same comparison against the reference WITHOUT its
+    selection is printed beside it and must read at least three times
+    larger. float32 (the rehearsal): 1e-4."""
+    from ggrmcp_tpu.utils.jaxenv import init_runtime
+
+    init_runtime("chip_smoke sparse leg")
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import reference_dsv32 as ref_mod
+    from ggrmcp_tpu.models import common, mla_moe
+    from ggrmcp_tpu.models.llama import KVCache, PagedKVCache
+
+    dev = jax.devices()[0]
+    if rehearsal:
+        name, chunk, n_chunks, page, tol = "tiny-dsv32", 16, 5, 8, 1e-4
+        with open(os.path.join(
+                HERE, "tests", "benchmark", "rehearsal_dsv32", "benchmark",
+                "configs", "tiny-dsv32-cpu.json")) as f:
+            model = json.load(f)
+    else:
+        check(dev.platform == "tpu", f"sparse leg on {dev.platform}")
+        name, chunk, n_chunks, page, tol = (
+            "deepseek-v3.2-ep16-5l", 512, 9, 16, 3e-2)
+        with open(os.path.join(
+                HERE, "benchmark", "configs",
+                "deepseek-v3.2-bf16-ep16-1chip.json")) as f:
+            model = json.load(f)
+    # one expert layer, no dense one; every width as published
+    cfg = dataclasses.replace(
+        mla_moe.CONFIGS[name], num_layers=1, first_dense_layers=0)
+    model = dict(model, num_hidden_layers=1, first_k_dense_replace=0)
+    s_all = chunk * n_chunks
+    check(s_all > 2 * cfg.index_topk, "the selection would not bind")
+    weights = ref_mod.family_init_weights(jax, model)
+    lp = {k.split(".", 1)[1]: v[0] for k, v in weights.items()
+          if k.startswith("moe.")}
+    banks = tuple(weights["moe." + b] for b in ("w_gate", "w_up", "w_down"))
+    ones = {"attn_norm": cfg.hidden_dim, "mlp_norm": cfg.hidden_dim,
+            "kv_norm": cfg.kv_lora_rank, "q_norm": cfg.q_lora_rank,
+            "idx_k_norm": cfg.index_head_dim}
+    lp.update({k: jnp.ones((n,), cfg.jnp_dtype) for k, n in ones.items()})
+    lp["idx_k_bias"] = jnp.zeros((cfg.index_head_dim,), cfg.jnp_dtype)
+    x = jax.random.normal(
+        jax.random.PRNGKey(1), (s_all + 1, cfg.hidden_dim), jnp.float32)
+    x = x.astype(cfg.jnp_dtype)
+
+    def layer(lp, banks, x, planes, length, table):
+        # (the weights as arguments: closed over, 2 GB of them would be
+        # constants of the program and its compile would not fit the host)
+        positions = length[:, None] + jnp.arange(x.shape[1])[None, :]
+        y, planes, _ = mla_moe.attention_block(
+            x, lp, cfg, positions, planes, length, table, 0)
+        n = common.rms_norm(y, lp["mlp_norm"], cfg.norm_eps)
+        out, _ = mla_moe.moe_ffn(n, lp, banks, 0, cfg)
+        return y + out, planes
+
+    t0 = time.monotonic()
+    cache = KVCache.create(cfg, 1, 2 * s_all)
+    planes, got = (cache.k, cache.v), []
+    step = jax.jit(functools.partial(layer, table=None), donate_argnums=(3,))
+    for i in range(n_chunks):
+        y, planes = step(
+            lp, banks, x[None, i * chunk:(i + 1) * chunk], planes,
+            jnp.asarray([i * chunk], jnp.int32))
+        got.append(np.asarray(y[0], np.float32))
+    # the same latents and indexer keys as pages, and one decode step
+    n_pages = 2 * s_all // page
+    paged = PagedKVCache.create(cfg, 1, 2 * s_all, n_pages, page)
+    arena = tuple(
+        p.reshape(p.shape[0], n_pages, page, p.shape[-1]) for p in planes)
+    check(arena[0].shape == paged.k.shape and arena[1].shape == paged.v.shape,
+          f"page planes {arena[0].shape} {arena[1].shape}")
+    table = jnp.arange(n_pages, dtype=jnp.int32)[None, :]
+    y, _ = jax.jit(layer)(
+        lp, banks, x[None, s_all:], arena, jnp.asarray([s_all], jnp.int32),
+        table)
+    got.append(np.asarray(y[0], np.float32))
+    got = np.concatenate(got)
+    say(f"  sparse layer: {n_chunks} chunks of {chunk} and a decode step "
+        f"compiled and ran in {time.monotonic() - t0:.1f} s (set-up, "
+        f"{dev.device_kind})")
+
+    x32 = np.asarray(x, np.float32)
+    pad = ref_mod.padded_len(s_all + 1) - (s_all + 1)
+    x_ref = jnp.pad(x.astype(jnp.float32), ((0, pad), (0, 0)))
+    w1 = {k.split(".", 1)[1]: v[0] for k, v in weights.items()
+          if k.startswith("moe.")}
+
+    def rel(select, lo, hi):
+        want = np.asarray(
+            ref_mod.make_layers(jax, model, select=select)[1](x_ref, w1)
+        )[: s_all + 1]
+        d_ref, d_got = (want - x32)[lo:hi], (got - x32)[lo:hi]
+        check(bool(np.isfinite(d_got).all()), "non-finite layer output")
+        return float(np.sqrt(((d_got - d_ref) ** 2).mean())
+                     / np.sqrt((d_ref ** 2).mean()))
+
+    for label, lo, hi in (("sparse chunk", s_all - chunk, s_all),
+                          ("sparse decode step", s_all, s_all + 1)):
+        sound, dense = rel(True, lo, hi), rel(False, lo, hi)
+        say(f"  {label} ({cfg.index_topk} of {lo + 1}..{hi} keys): rms "
+            f"error {sound:.2e} of the layer's contribution (limit {tol:g}); "
+            f"against the reference without its selection {dense:.2e}")
+        check(sound < tol, f"{label}: {sound:.3e} beyond {tol:g}")
+        check(dense > 3 * sound, f"{label}: the selection does not show")
+    print("LEG_RESULT " + json.dumps({
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(jax.devices()),
+    }), flush=True)
+
+
+def run_child_leg(name: str, title: str, rehearsal: bool) -> dict:
+    """A leg that is one child process on the device: `--child-<name>`."""
+    say(f"== leg {name}: {title}")
+    cmd = [sys.executable, os.path.abspath(__file__), f"--child-{name}"]
     if rehearsal:
         cmd.append("--cpu-rehearsal")
     proc = subprocess.Popen(
@@ -380,7 +515,7 @@ def run_kernel_leg(rehearsal: bool) -> dict:
     try:
         out, _ = proc.communicate(timeout=remaining())
     except subprocess.TimeoutExpired:
-        raise SmokeFailure("kernel leg did not finish inside the budget")
+        raise SmokeFailure(f"{name} leg did not finish inside the budget")
     finally:
         stop_process(proc)
     lines = out.splitlines()
@@ -389,11 +524,18 @@ def run_kernel_leg(rehearsal: bool) -> dict:
         if not ln.startswith("LEG_RESULT "):
             say("  | " + ln)
     check(proc.returncode == 0 and len(result) == 1,
-          f"kernel leg failed (exit {proc.returncode}); its output is above")
+          f"{name} leg failed (exit {proc.returncode}); its output is above")
     info = json.loads(result[0][len("LEG_RESULT "):])
-    say(f"   kernel leg ok: platform={info['platform']} "
+    say(f"   {name} leg ok: platform={info['platform']} "
         f"device_kind={info['kind']!r} devices={info['count']}")
     return info
+
+
+def run_kernel_leg(rehearsal: bool) -> dict:
+    return run_child_leg(
+        "kernel", "flash_attention, paged_decode_attention and "
+        "latent_prefill_attention vs their XLA forms on the device",
+        rehearsal)
 
 
 # ---------------------------------------------------------------------------
@@ -729,7 +871,7 @@ def main() -> int:
     )
     ap.add_argument(
         "--legs", default="",
-        help="comma-separated subset of kernel,serve,default_kv,tp4 "
+        help="comma-separated subset of kernel,sparse,serve,default_kv,tp4 "
         "(debugging; the default is every leg the host can hold)",
     )
     ap.add_argument(
@@ -738,9 +880,14 @@ def main() -> int:
     )
     ap.add_argument("--child-kernel", action="store_true",
                     help=argparse.SUPPRESS)
+    ap.add_argument("--child-sparse", action="store_true",
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.child_kernel:
         kernel_leg_child(args.cpu_rehearsal)
+        return 0
+    if args.child_sparse:
+        sparse_leg_child(args.cpu_rehearsal)
         return 0
 
     rehearsal = args.cpu_rehearsal
@@ -755,8 +902,12 @@ def main() -> int:
     device = {"platform": "cpu" if rehearsal else "tpu", "kind": "", "count": 1}
     if not legs or "kernel" in legs:
         device = run_kernel_leg(rehearsal)
+    if not legs or "sparse" in legs:
+        run_child_leg(
+            "sparse", "one deepseek-v3.2 expert layer, a sparse chunk and "
+            "a sparse decode step, vs the float32 reference layer", rehearsal)
     if not legs:
-        legs = ["kernel", "serve", "default_kv"]
+        legs = ["kernel", "sparse", "serve", "default_kv"]
         if device["count"] >= 4 and not rehearsal:
             legs.append("tp4")
     else:

@@ -161,6 +161,17 @@ _SERVING_HELP = {
         "routed (token, expert) pairs the decode ticks computed",
     "moe_layer_steps":
         "(expert layer, decode step) instances the moe_* sums cover",
+    "moe_pairs_absent":
+        "routed pairs of the decode ticks whose expert this chip does "
+        "not hold (a model served as a share of its experts)",
+    "sparse_keys_selected":
+        "entries of the decode ticks' selections that name a key, "
+        "counted off the top-k's scores and summed over layers and "
+        "steps (a model with a sparse-attention indexer)",
+    "sparse_keys_visible":
+        "keys the indexer scored for those queries, summed likewise",
+    "sparse_layer_steps":
+        "(row, layer) instances in which a sparse selection ran",
     "prefill_tokens_computed":
         "prompt tokens the admission programs computed",
     "prefill_tokens_reused":

@@ -308,14 +308,27 @@ def paged_view(arena, table: jnp.ndarray, layer: jnp.ndarray):
     return kv_map(gather, arena)
 
 
-def paged_view_layers(arena, table: jnp.ndarray):
+def paged_view_layers(arena, table: jnp.ndarray, by_layer: bool = False):
     """`paged_view` for a full [L, N, P, KVH, Dh] arena (batcher-side
-    admission gathers): → [L, B, W·P, KVH, Dh]."""
+    admission gathers): → [L, B, W·P, KVH, Dh]. `by_layer`: a layer at
+    a time, indexed [layer, page] as the tick reads the arena. Where a
+    page row is one vector (the latent family's [L, N, P, width]), the
+    one gather makes the layer axis part of each slice, for which XLA
+    first re-lays the whole arena out (a copy of it) and then
+    transposes the result; the dense family's arena has no such copy
+    and its 32 layers are faster in one gather (PERF.md, PR 33)."""
     def gather(a):
-        v = a[:, jnp.minimum(table, a.shape[1] - 1)]  # [L, B, W, P, ...]
-        return v.reshape(
-            a.shape[0], table.shape[0], table.shape[1] * a.shape[2],
-            *a.shape[3:])
+        pages = jnp.minimum(table, a.shape[1] - 1)
+        shape = (table.shape[0], table.shape[1] * a.shape[2], *a.shape[3:])
+        if not by_layer:
+            return a[:, pages].reshape(a.shape[0], *shape)  # [L, B, W·P, ...]
+
+        def layer(i, out):
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, a[i, pages].reshape(1, *shape), i, 0)
+
+        return jax.lax.fori_loop(
+            0, a.shape[0], layer, jnp.zeros((a.shape[0], *shape), a.dtype))
 
     return kv_map(gather, arena)
 

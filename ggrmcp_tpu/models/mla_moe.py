@@ -65,7 +65,7 @@ from ggrmcp_tpu.ops.quant import (
     kv_map,
     quantize,
 )
-from ggrmcp_tpu.ops.rope import apply_rope
+from ggrmcp_tpu.ops.rope import apply_rope, yarn_softmax_gain
 
 Params = common.Params
 
@@ -91,7 +91,24 @@ ABSORBED_MAX_QUERIES = 128
 # behaviour). `forward` computes the head at one position a row
 # (`logit_idx`) and returns a step's routing counts (`with_stats`).
 HEAD_AT_INDEX = True
-ROUTING_STATS = True
+# The admission programs scatter into and gather from the whole arena
+# a layer at a time (batching._paged_put, llama.paged_view_layers): a
+# page row of this family is one vector, and one scatter or gather
+# over the layer axis makes XLA re-lay the whole arena out and back,
+# two copies alive beside it (found on the chip, PERF.md, PR 33; the
+# dense family's [.., KVH, Dh] rows have no such copy, and its 32
+# layers read 5% slower a call a layer at a time).
+ARENA_BY_LAYER = True
+# The counts a step returns (`with_stats`), by name, each summed over
+# its layers: distinct experts hit, the largest load of an expert,
+# routed pairs computed here, routed pairs whose expert another chip
+# holds; and what the sparse paths of `attention_block` saw themselves:
+# entries of the selections that name a key, keys the indexer scored
+# for those queries, and the queries that selected, a layer each.
+ROUTING_STATS = (
+    "experts_hit", "load_max", "pairs", "pairs_absent",
+    "sparse_selected", "sparse_visible", "sparse_layer_steps",
+)
 # A cold prompt of more chunks than this runs on a chunk grid rounded
 # up to a power of two and is admitted alone (at the default chunk of
 # 512: past 2,048 tokens). This family serves contexts of 6k-13k
@@ -103,6 +120,17 @@ ROUTING_STATS = True
 # has no such mask, so llama keeps exact depths and group admission, as
 # before this family came (no cell measures llama past 2,048 tokens).
 DEEP_GRID_CHUNKS = 4
+
+
+def admission_rows(cfg) -> Optional[int]:
+    """Rows one admission call over a full-width mini cache may take
+    (the batcher asks; None = the whole pool). A model with an indexer
+    takes one: its chunk attention runs a row at a time anyway (a
+    selection a query: index scores, a sort and the walk's score blocks
+    for 128 heads are a row's worth of memory each), so a group saves
+    no pass over the weights worth its rows x 0.25 GB of mini cache
+    beside 11.4 GB resident (PERF.md, PR 33)."""
+    return 1 if cfg.index_topk else None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,6 +151,26 @@ class MlaMoeConfig(LlamaConfig):
     routed_scaling: float = 2.448
     norm_eps: float = 1e-6
     rope_theta: float = 1e6
+    # What `model_type: deepseek_v32` adds; each default is "absent",
+    # which is kanana's value, and a member's keys alone pick its path.
+    # Queries through a low-rank bottleneck with its own norm.
+    q_lora_rank: int = 0
+    # The sparse-attention indexer of every layer: `index_heads` query
+    # heads of `index_head_dim` score each cached token's ONE indexer
+    # key, and a query attends its `index_topk` best keys only. 0: no
+    # indexer, every visible key is attended.
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # Group-limited routing: the experts in `n_group` equal groups, the
+    # best `topk_group` groups stay in the choice.
+    n_group: int = 1
+    topk_group: int = 1
+    # (first, count): the experts of `num_experts` whose weights this
+    # chip holds, its share of an expert-parallel deployment. The
+    # router keeps all `num_experts` outputs; a routed pair whose
+    # expert is absent adds nothing here. None: every expert.
+    experts_held: Optional[tuple] = None
 
     @property
     def latent_dim(self) -> int:
@@ -130,12 +178,27 @@ class MlaMoeConfig(LlamaConfig):
 
     @property
     def kv_planes(self) -> tuple:
-        """One latent plane; the V plane is empty. The plane is as wide
+        """One latent plane; the V plane is empty, or holds the
+        indexer's key of the token where the model has an indexer: a
+        second kind of state that rides every page beside its latent.
+        The latent plane is as wide
         as the next multiple of the TPU's 128 lanes (576 -> 640, zeros
         at the end): at 576 the compiler keeps the arena in a layout
         with the page axis minor-most, and every tick re-lays all of it
         out twice (PERF.md, PR 28)."""
-        return ((-(-self.latent_dim // 128) * 128,), (0,))
+        return (
+            (-(-self.latent_dim // 128) * 128,),
+            (self.index_head_dim if self.index_topk else 0,),
+        )
+
+    @property
+    def softmax_scale(self) -> float:
+        return yarn_softmax_gain(self.rope_scaling) / math.sqrt(
+            self.qk_nope_head_dim + self.qk_rope_head_dim)
+
+    @property
+    def num_experts_held(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.num_experts
 
     @property
     def num_expert_layers(self) -> int:
@@ -147,6 +210,20 @@ _KANANA = dict(
     head_dim=64, ffn_dim=6144, max_seq_len=32768,
 )
 
+# deepseek-ai/DeepSeek-V3.2 `config.json` (`model_type: deepseek_v32`).
+# Its multi-token-prediction module (`num_nextn_predict_layers` 1) is
+# not served: it does not enter the main model's logits. The indexer's
+# keys are cached in the cache's dtype, not FP8, without the Hadamard
+# rotation that precedes FP8 there (orthogonal: it cancels in q.k).
+_DSV32 = dict(
+    hidden_dim=7168, num_heads=128, num_kv_heads=128, head_dim=64,
+    ffn_dim=18432, max_seq_len=163840, rope_theta=10000.0,
+    rope_scaling=("yarn", 40.0, 4096, 32.0, 1.0), q_lora_rank=1536,
+    index_heads=64, index_head_dim=128, index_topk=2048, num_experts=256,
+    experts_per_token=8, expert_ffn_dim=2048, num_shared_experts=1,
+    routed_scaling=2.5, n_group=8, topk_group=4,
+)
+
 CONFIGS: dict[str, MlaMoeConfig] = {
     # kakaocorp/kanana-2-30b-a3b-instruct-2601, config.json as published.
     "kanana-2-30b-a3b": MlaMoeConfig(
@@ -155,6 +232,31 @@ CONFIGS: dict[str, MlaMoeConfig] = {
     # its cache: the leading dense layer and 5 expert layers.
     "kanana-2-30b-a3b-6l": MlaMoeConfig(
         name="kanana-2-30b-a3b-6l", num_layers=6, **_KANANA),
+    # As published: 3 dense + 58 expert layers, never loaded here.
+    "deepseek-v3.2": MlaMoeConfig(
+        name="deepseek-v3.2", vocab_size=129280, num_layers=61,
+        first_dense_layers=3, **_DSV32),
+    # One chip's share of a deployment in which 16 chips share each
+    # layer (attention and the shared expert on every chip, 16 routed
+    # experts a chip, the vocabulary in 8 slices), cut to a dense layer
+    # and 4 expert layers: 4,635M parameters, 9.27 GB in bf16.
+    "deepseek-v3.2-ep16-5l": MlaMoeConfig(
+        name="deepseek-v3.2-ep16-5l", vocab_size=16160, num_layers=5,
+        first_dense_layers=1, experts_held=(0, 16), **_DSV32),
+    # Every mechanism of that member live at a size for the CPU tests:
+    # q-compression, 4 groups of which 2 stay, experts held < experts,
+    # YaRN, and an `index_topk` below the tests' contexts.
+    "tiny-dsv32": MlaMoeConfig(
+        name="tiny-dsv32", vocab_size=512, hidden_dim=128, num_layers=3,
+        num_heads=4, num_kv_heads=4, head_dim=16, ffn_dim=256,
+        max_seq_len=1024, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=16, v_head_dim=16, num_experts=16,
+        experts_per_token=4, expert_ffn_dim=64, num_shared_experts=1,
+        routed_scaling=2.5, rope_theta=10000.0,
+        rope_scaling=("yarn", 8.0, 32, 32.0, 1.0), q_lora_rank=48,
+        index_heads=4, index_head_dim=32, index_topk=16, n_group=4,
+        topk_group=2, experts_held=(4, 8), dtype="float32",
+    ),
     "tiny-mla-moe": MlaMoeConfig(
         name="tiny-mla-moe", vocab_size=512, hidden_dim=128, num_layers=3,
         num_heads=4, num_kv_heads=4, head_dim=16, ffn_dim=256,
@@ -174,8 +276,16 @@ CONFIGS: dict[str, MlaMoeConfig] = {
 def _attn_shapes(cfg: MlaMoeConfig) -> dict:
     d, h = cfg.hidden_dim, cfg.num_heads
     qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    qr = cfg.q_lora_rank
+    queries = {"wq": ((d, h * qk), d**-0.5)} if not qr else {
+        "wq_a": ((d, qr), d**-0.5), "wq_b": ((qr, h * qk), qr**-0.5)}
+    indexer = {} if not cfg.index_topk else {
+        "idx_wq": ((qr, cfg.index_heads * cfg.index_head_dim), qr**-0.5),
+        "idx_wk": ((d, cfg.index_head_dim), d**-0.5),
+        "idx_ww": ((d, cfg.index_heads), d**-0.5),
+    }
     return {
-        "wq": ((d, h * qk), d**-0.5),
+        **queries, **indexer,
         "wkv_a": ((d, cfg.latent_dim), d**-0.5),
         "wkv_b": (
             (cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
@@ -190,9 +300,12 @@ def leaf_recipe(cfg: MlaMoeConfig) -> list:
     name). Leaf i is `truncated_normal(split(key, n)[i], -2, 2, shape,
     float32) * scale` cast to its dtype; the router bias is drawn
     non-zero so that a weight computed from `s + b` shows. Norm
-    weights are ones and not drawn. The benchmark's reference repeats
-    this recipe from its own copy of the list."""
-    d, e = cfg.hidden_dim, cfg.num_experts
+    weights are ones (the indexer's LayerNorm bias zeros) and not
+    drawn. The expert banks hold `experts_held` only, so a share's
+    weights are its own draw, not a slice of the whole model's. The
+    benchmark's references repeat this recipe from their own copies of
+    the list."""
+    d, e, eh = cfg.hidden_dim, cfg.num_experts, cfg.num_experts_held
     f, fs = cfg.expert_ffn_dim, cfg.num_shared_experts * cfg.expert_ffn_dim
     kd, km = cfg.first_dense_layers, cfg.num_expert_layers
     attn = _attn_shapes(cfg)
@@ -209,9 +322,9 @@ def leaf_recipe(cfg: MlaMoeConfig) -> list:
          cfg.dtype),
         (("layers", "router"), (km, d, e), d**-0.5, "float32"),
         (("layers", "router_bias"), (km, e), 0.1, "float32"),
-        (("layers", "w_gate"), (km, e, d, f), d**-0.5, cfg.dtype),
-        (("layers", "w_up"), (km, e, d, f), d**-0.5, cfg.dtype),
-        (("layers", "w_down"), (km, e, f, d), f**-0.5, cfg.dtype),
+        (("layers", "w_gate"), (km, eh, d, f), d**-0.5, cfg.dtype),
+        (("layers", "w_up"), (km, eh, d, f), d**-0.5, cfg.dtype),
+        (("layers", "w_down"), (km, eh, f, d), f**-0.5, cfg.dtype),
         (("layers", "ws_gate"), (km, d, fs), d**-0.5, cfg.dtype),
         (("layers", "ws_up"), (km, d, fs), d**-0.5, cfg.dtype),
         (("layers", "ws_down"), (km, fs, d), fs**-0.5, cfg.dtype),
@@ -242,19 +355,37 @@ def init_params(key: jax.Array, cfg: MlaMoeConfig) -> Params:
         params[stack]["attn_norm"] = jnp.ones((n, d), dtype)
         params[stack]["kv_norm"] = jnp.ones((n, cfg.kv_lora_rank), dtype)
         params[stack]["mlp_norm"] = jnp.ones((n, d), dtype)
+        if cfg.q_lora_rank:
+            params[stack]["q_norm"] = jnp.ones((n, cfg.q_lora_rank), dtype)
+        if cfg.index_topk:
+            params[stack]["idx_k_norm"] = jnp.ones(
+                (n, cfg.index_head_dim), dtype)
+            params[stack]["idx_k_bias"] = jnp.zeros(
+                (n, cfg.index_head_dim), dtype)
     params["final_norm"] = jnp.ones((d,), dtype)
     return params
 
 
 def param_specs(cfg: MlaMoeConfig) -> Params:
-    """Heads and FFN widths over `tensor`, as the dense family; experts
-    stay whole on every chip (sharding them is not built: ROADMAP)."""
+    """Heads and FFN widths over `tensor`, as the dense family; the
+    experts a chip holds (`cfg.experts_held`) stay whole on it: their
+    exchange over a mesh axis is not built (ROADMAP)."""
     attn = {
         "attn_norm": P(None, None), "kv_norm": P(None, None),
         "mlp_norm": P(None, None),
-        "wq": P(None, None, "tensor"), "wkv_a": P(None, None, None),
+        "wkv_a": P(None, None, None),
         "wkv_b": P(None, None, "tensor"), "wo": P(None, "tensor", None),
     }
+    if cfg.q_lora_rank:
+        attn.update(
+            wq_a=P(None, None, None), q_norm=P(None, None),
+            wq_b=P(None, None, "tensor"))
+    else:
+        attn["wq"] = P(None, None, "tensor")
+    if cfg.index_topk:  # small, and every row reads all of it
+        attn.update({
+            name: P(None, None, None) for name in ("idx_wq", "idx_wk", "idx_ww")
+        }, idx_k_norm=P(None, None), idx_k_bias=P(None, None))
     return {
         "embed": P("tensor", None),
         "dense": {
@@ -294,12 +425,12 @@ def paged_cache_specs() -> PagedKVCache:
 # ---------------------------------------------------------------------------
 
 
-def _key_block(b: int, s: int, s_keys: int, page: int) -> int:
+def _key_block(b: int, s: int, h: int, s_keys: int, page: int) -> int:
     """Keys a block of the XLA walk (the prefill kernel has its own,
     ops/attention.py): bounds the walk's [B, H, S, block] float32
     scores in HBM to a few hundred MB at the published widths (16 rows
-    x 512 queries)."""
-    block = 2048 if b * s <= 512 else 512
+    x 512 queries of 32 heads, or a row of 128 heads)."""
+    block = 2048 if b * s * h <= 512 * 32 else 512
     while block > page and (s_keys % block or block % page):
         block //= 2
     return block if block > page else page
@@ -324,15 +455,20 @@ def latent_attention(
     kv_len,  # [B] keys each row may see
     cfg: MlaMoeConfig,
     absorbed: bool,
+    key_pos=None,  # block index -> [B, block] positions of its keys
+    allowed=None,  # block index -> [B, S, block] bool: the selection
 ):
     """Causal softmax attention of the step's queries over the cached
     latents, block of keys by block of keys with a running maximum and
     sum (float32). `absorbed` picks the form; both give the same
-    attention up to rounding. Returns [B, S, H, v_head_dim]."""
+    attention up to rounding. A block's keys are the positions
+    `i * block ..` unless `key_pos` says which they are (a block
+    gathered by token index); `allowed` narrows what each query may
+    see to its selection. Returns [B, S, H, v_head_dim]."""
     b, s, h, nope = q_nope.shape
     rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
     f32 = jnp.float32
-    scale = 1.0 / math.sqrt(nope + cfg.qk_rope_head_dim)
+    scale = cfg.softmax_scale
     w_uk, w_uv = wkv_b[..., :nope], wkv_b[..., nope:]
     if absorbed:
         q_lat = jnp.einsum("bshd,chd->bshc", q_nope, w_uk)  # [B,S,H,rank]
@@ -358,10 +494,14 @@ def latent_attention(
                 "bshd,bkhd->bhsk", q_nope, kv[..., :nope],
                 preferred_element_type=f32,
             )
-        k_pos = i * block + jnp.arange(block)
-        seen = (k_pos[None, None, :] <= q_pos[:, :, None]) & (
-            k_pos[None, None, :] < kv_len[:, None, None]
-        )  # [B, S, block]
+        if key_pos is None:
+            k_pos = (i * block + jnp.arange(block))[None, None, :]
+        else:
+            k_pos = key_pos(i)[:, None, :]
+        seen = (k_pos <= q_pos[:, :, None]) & (
+            k_pos < kv_len[:, None, None])  # [B, S, block]
+        if allowed is not None:
+            seen &= allowed(i)
         scores = jnp.where(seen[:, None], scores * scale, -1e30)
         m_new = jnp.maximum(m, scores.max(-1))
         p = jnp.exp(scores - m_new[..., None])
@@ -390,26 +530,138 @@ def latent_attention(
     return out
 
 
+def indexer_inputs(c_q, normed, lp, cfg: MlaMoeConfig, positions):
+    """What the sparse-attention indexer of a layer makes of the step's
+    tokens: its queries `[B, S, heads, width]` (from the compressed
+    queries `c_q`), ONE key a token `[B, S, width]` (LayerNorm with
+    weight and bias; this is what the cache's second plane keeps) and
+    the heads' weights `[B, S, heads]` float32, already times
+    `heads^-0.5 width^-0.5`. RoPE, with the attention's frequencies,
+    turns the first `qk_rope_head_dim` values of queries and keys, as
+    half-split pairs."""
+    b, s, _ = normed.shape
+    hi, di, rope = cfg.index_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    f32 = jnp.float32
+
+    def rot(t):  # [B, S, N, width]
+        head = apply_rope(
+            t[..., :rope], positions, cfg.rope_theta, cfg.rope_scaling)
+        return jnp.concatenate([head, t[..., rope:]], axis=-1)
+
+    q_i = rot((c_q @ lp["idx_wq"]).reshape(b, s, hi, di))
+    k = (normed @ lp["idx_wk"]).astype(f32)
+    k = k - k.mean(-1, keepdims=True)
+    k = k * jax.lax.rsqrt((k * k).mean(-1, keepdims=True) + cfg.norm_eps)
+    k = k * lp["idx_k_norm"].astype(f32) + lp["idx_k_bias"].astype(f32)
+    k_i = rot(k.astype(normed.dtype)[:, :, None])[:, :, 0]
+    w_i = (normed @ lp["idx_ww"]).astype(f32) * (hi**-0.5 * di**-0.5)
+    return q_i, k_i, w_i
+
+
+def index_scores(q_i, w_i, fetch, n_blocks, block: int, s_keys: int,
+                 q_pos, kv_len):
+    """`I[t, s] = sum_j w[t, j] ReLU(q_I[t, j] . k_I[s])` for the
+    step's queries over the cached indexer keys, float32 `[B, S,
+    s_keys]`: reduced over the heads block of keys by block of keys
+    (`fetch(i)` -> `[B, block, width]`), so the `[.., heads, block]`
+    scores of one block are all that ever exists. -inf where a query
+    may not see (after its position `q_pos`, past the row's `kv_len`,
+    in blocks the walk does not reach)."""
+    b, s = q_i.shape[:2]
+
+    def body(i, buf):
+        per_head = jnp.einsum(
+            "bshd,bkd->bshk", q_i, fetch(i),
+            preferred_element_type=jnp.float32)
+        blk = (jax.nn.relu(per_head) * w_i[..., None]).sum(2)
+        return jax.lax.dynamic_update_slice(buf, blk, (0, 0, i * block))
+
+    scores = jax.lax.fori_loop(
+        0, n_blocks, body, jnp.full((b, s, s_keys), -jnp.inf, jnp.float32))
+    k_pos = jnp.arange(s_keys)[None, None, :]
+    return jnp.where(
+        (k_pos <= q_pos[:, :, None]) & (k_pos < kv_len[:, None, None]),
+        scores, -jnp.inf)
+
+
+def selection_mask(scores, topk: int, reach=None):
+    """`[.., s_keys]` bool: each query's `topk` largest scores, ties
+    to the lower position, exactly; every finite score where a query
+    sees fewer (-inf marks what it may not see). `reach` (a traced
+    scalar) promises that keys from it on are all -inf: the sort then
+    runs over the narrowest of a few halved widths that holds the rest
+    (a 512 x 32,768 sort costs eight times a 512 x 4,096 one), and not
+    at all at 0."""
+    def exact(scores):
+        thr = jax.lax.top_k(scores, topk)[0][..., -1:]
+        above = scores > thr
+        tied = (scores == thr) & (scores > -jnp.inf)
+        need = topk - above.sum(-1, keepdims=True)
+        return above | (tied & (jnp.cumsum(tied, axis=-1) <= need))
+
+    if reach is None:
+        return exact(scores)
+    s_keys = scores.shape[-1]
+    widths = [s_keys]
+    while widths[0] // 2 > topk:
+        widths.insert(0, widths[0] // 2)
+
+    def upto(width):
+        def branch(scores):
+            mask = exact(scores[..., :width])
+            return jnp.pad(
+                mask, ((0, 0),) * (mask.ndim - 1) + ((0, s_keys - width),))
+        return branch
+
+    index = jnp.where(
+        reach <= 0, 0,
+        1 + sum((reach > w).astype(jnp.int32) for w in widths[:-1]))
+    return jax.lax.switch(
+        index,
+        [lambda scores: jnp.zeros(scores.shape, bool)]
+        + [upto(w) for w in widths], scores)
+
+
 def attention_block(
-    x, lp, cfg: MlaMoeConfig, positions, cache_k, cache_len,
+    x, lp, cfg: MlaMoeConfig, positions, planes, cache_len,
     page_table, layer, valid=None, use_flash=None, flash_mesh=None,
 ):
-    """Pre-norm latent attention with residual. `cache_k` is the WHOLE
-    latent plane, loop-carried: `[L, B, S_max, latent]` (contiguous)
-    or `[L, N, P, latent]` with `page_table` (paged); this layer
-    writes and reads it at `[layer, ...]` in place. None = no cache
-    (the step's own latents are the keys). `valid` [B, S] marks the
+    """Pre-norm latent attention with residual. `planes` are the WHOLE
+    cache planes, loop-carried: the latent plane `[L, B, S_max,
+    latent]` (contiguous) or `[L, N, P, latent]` with `page_table`
+    (paged) and, beside it, the indexer's plane of the same leading
+    axes (width 0 where the model has no indexer); this layer writes
+    and reads them at `[layer, ...]` in place. None = no cache (the
+    step's own latents are the keys). `valid` [B, S] marks the
     real queries: the walk over the cache stops at the last key any of
     THEM may see, so a padding chunk of a chunk grid, or a tick in
     which the longest rows are parked, attends nothing it does not
     need (an all-padding step walks no block at all).
-    Returns (x + attn, plane)."""
+
+    With an indexer (`cfg.index_topk`) a query attends its selected
+    keys only. A decode step (one query a row) scores the row's
+    indexer keys to `kv_len`, takes the exact top-k and GATHERS those
+    latents by token index, 2,048 x 640 values a row where the walk
+    would read the whole context; the family's attention then runs
+    over that one block. A chunk or a re-admission suffix has a
+    selection a query: gathered, 512 queries x 2,048 latents would be
+    1.3 GB a layer, so it keeps the dense walk and masks each block
+    with the query's selection (the same set). Where the keys do not
+    outnumber `index_topk` nothing is selected.
+    Returns (x + attn, planes, counts): int32 [3], read off the index
+    scores and the selection this call made for its real queries (the
+    last three of ROUTING_STATS); zeros where none selected."""
     b, s, _ = x.shape
     h, nope, rope = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    rank = cfg.kv_lora_rank
+    rank, topk = cfg.kv_lora_rank, cfg.index_topk
 
     normed = common.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (normed @ lp["wq"]).reshape(b, s, h, nope + rope)
+    if cfg.q_lora_rank:
+        c_q = common.rms_norm(normed @ lp["wq_a"], lp["q_norm"], cfg.norm_eps)
+        q = c_q @ lp["wq_b"]
+    else:
+        q = normed @ lp["wq"]
+    q = q.reshape(b, s, h, nope + rope)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     a = normed @ lp["wkv_a"]  # [B, S, latent]
     c = common.rms_norm(a[..., :rank], lp["kv_norm"], cfg.norm_eps)
@@ -426,18 +678,44 @@ def attention_block(
     lat = jnp.pad(  # to the cache plane's width
         lat, ((0, 0), (0, 0), (0, cfg.kv_planes[0][0] - cfg.latent_dim)))
     wkv_b = lp["wkv_b"].reshape(rank, h, nope + cfg.v_head_dim)
+    if topk:
+        q_i, k_i, w_i = indexer_inputs(c_q, normed, lp, cfg, positions)
 
-    out = None
-    if cache_k is None:
+    out = read_at = None
+    counts = jnp.zeros((3,), jnp.int32)
+
+    def count(ran, real, chosen, scored):
+        """`ran` [B, S]: the queries that selected, `real` those of
+        them that count (`valid`'s rows, or None); `chosen` and `scored`
+        [B, S, n]: the selection's entries that name a key and the keys
+        with an index score."""
+        ran = ran if real is None else ran & real
+        return jnp.stack([
+            (chosen & ran[..., None]).sum(), (scored & ran[..., None]).sum(),
+            ran.sum()]).astype(jnp.int32)
+
+    if planes is None:
         pad = -s % min(s, 512)
-        lat_p = jnp.pad(lat, ((0, 0), (0, pad), (0, 0)))
         block = min(s, 512)
-        n_blocks = (s + pad) // block
+        s_keys = s + pad
+        n_blocks = s_keys // block
         kv_len = jnp.full((b,), s, jnp.int32)
 
-        def fetch(i):
-            return jax.lax.dynamic_slice_in_dim(lat_p, i * block, block, 1)
+        last = positions
+
+        def own(t):  # the step's own tokens as the keys
+            t = jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+
+            def fetch(i, row=None):
+                rows = t if row is None else (
+                    jax.lax.dynamic_slice_in_dim(t, row, 1, 0))
+                return jax.lax.dynamic_slice_in_dim(rows, i * block, block, 1)
+
+            return fetch
+
+        fetch, fetch_idx = own(lat), own(k_i) if topk else None
     else:
+        cache_k, cache_v = planes
         quantized = isinstance(cache_k, QuantizedArray)
         plane = cache_k.q if quantized else cache_k
         write_pos = cache_len[:, None] + jnp.arange(s)[None, :]  # [B, S]
@@ -463,57 +741,140 @@ def attention_block(
             return arena.at[layer, i0, i1].set(
                 val.astype(arena.dtype), mode="drop")
 
-        if quantized:
-            cache_k = kv_map(write, cache_k, quantize(lat, axis=-1))
-        else:
-            cache_k = write(cache_k, lat)
-        block = _key_block(b, s, s_keys, p_sz)
+        def put(arena, val):
+            if quantized:
+                return kv_map(write, arena, quantize(val, axis=-1))
+            return write(arena, val)
+
+        cache_k = put(cache_k, lat)
+        if topk:  # the token's indexer key, in the page of its latent
+            cache_v = put(cache_v, k_i)
+        planes = (cache_k, cache_v)
+        block = _key_block(b, s, h, s_keys, p_sz)
         kv_len = cache_len + s
         last = positions if valid is None else jnp.where(valid, positions, -1)
         n_blocks = jnp.clip(
             (jnp.max(last) + block) // block, 0, s_keys // block)
-        arena = cache_k
-        if s > ABSORBED_MAX_QUERIES and page_table is None and not quantized:
+        if (s > ABSORBED_MAX_QUERIES and page_table is None
+                and not quantized and not topk):
             # A prefill chunk over a contiguous plane: the absorbed
             # form as one kernel where `latent_prefill` finds its kind
             # (a TPU, the plane in the model's dtype), else None and
-            # the walk below.
+            # the walk below. (The kernel has no selection a query: a
+            # model with an indexer keeps the walk.)
             out = attn_ops.latent_prefill(
                 absorbed_queries(
                     q_nope, q_rope, wkv_b[..., :nope], lat.shape[-1]),
                 cache_k, layer, cache_len, kv_len, jnp.max(last, axis=1),
-                value_width=rank, scale=1.0 / math.sqrt(nope + rope),
+                value_width=rank, scale=cfg.softmax_scale,
                 use_flash=use_flash, flash_mesh=flash_mesh,
             )
             if out is not None:
                 out = jnp.einsum("bshc,chd->bshd", out, wkv_b[..., nope:])
 
-        def fetch(i):
-            if page_table is not None:
-                per = block // p_sz
-                pages = jax.lax.dynamic_slice_in_dim(
-                    page_table, i * per, per, 1)  # [B, per]
-
-                def read(a):
-                    v = a[layer, jnp.minimum(pages, a.shape[1] - 1)]
-                    return v.reshape(b, block, a.shape[-1])
-            else:
-                def read(a):
-                    v = jax.lax.dynamic_slice(
-                        a, (layer, 0, i * block, 0),
-                        (1, b, block, a.shape[-1]))
-                    return v.reshape(b, block, a.shape[-1])
-
+        def reader(arena, read):
             blk = kv_map(read, arena)
             return dequantize(blk) if quantized else blk.astype(lat.dtype)
 
-    if out is None:
+        def read_block(arena, i, row):
+            """Keys i * block .. of every row `[B, block, width]`, or
+            of row `row` alone `[1, block, width]`."""
+            r0, nb = (0, b) if row is None else (row, 1)
+            if page_table is not None:
+                per = block // p_sz
+                pages = jax.lax.dynamic_slice(
+                    page_table, (r0, i * per), (nb, per))
+
+                def read(a):
+                    v = a[layer, jnp.minimum(pages, a.shape[1] - 1)]
+                    return v.reshape(nb, block, a.shape[-1])
+            else:
+                def read(a):
+                    v = jax.lax.dynamic_slice(
+                        a, (layer, r0, i * block, 0),
+                        (1, nb, block, a.shape[-1]))
+                    return v.reshape(nb, block, a.shape[-1])
+
+            return reader(arena, read)
+
+        def read_at(arena, pos):  # the tokens at `pos` [B, K]
+            if page_table is not None:
+                pages = jnp.take_along_axis(page_table, pos // p_sz, axis=1)
+
+                def read(a):
+                    return a[
+                        layer, jnp.minimum(pages, a.shape[1] - 1), pos % p_sz]
+            else:
+                def read(a):
+                    return a[layer, jnp.arange(b)[:, None], pos]
+
+            return reader(arena, read)
+
+        def fetch(i, row=None):
+            return read_block(cache_k, i, row)
+
+        def fetch_idx(i, row=None):
+            return read_block(cache_v, i, row)
+
+    absorbed = s <= ABSORBED_MAX_QUERIES
+    if topk and s_keys > topk and s == 1 and read_at is not None:
+        # A decode step. Exact top-k, ties to the lower position
+        # (lax.top_k's order); a row no longer than `topk` reads its
+        # keys in order and owes nothing to the indexer.
+        attn_ops.dispatch_counts["sparse_decode"] += 1
+        scores = index_scores(
+            q_i, w_i, fetch_idx, n_blocks, block, s_keys, positions, kv_len)
+        ran = (kv_len > topk)[:, None]
+        best, picked = jax.lax.top_k(scores[:, 0], topk)
+        counts = count(
+            ran, valid, best[:, None] > -jnp.inf, scores > -jnp.inf)
+        picked = jnp.where(ran, picked, jnp.arange(topk)[None])
+        chosen = read_at(cache_k, picked)  # [B, topk, latent]
+        live = jnp.arange(topk)[None] < jnp.minimum(kv_len, topk)[:, None]
+        picked = jnp.where(live, picked, jnp.iinfo(jnp.int32).max)
+        out = latent_attention(
+            q_nope, q_rope, lambda i: chosen, 1, topk, wkv_b, positions,
+            kv_len, cfg, absorbed, key_pos=lambda i: picked)
+    elif topk and s_keys > topk:
+        # A chunk or a suffix: a selection a query, and the dense walk
+        # masked by it. A row at a time, each to its own last key: the
+        # index scores, the sort's temporaries and the walk's score
+        # blocks (128 heads x 512 queries) stay one row's.
+        attn_ops.dispatch_counts["sparse_chunk"] += 1
+
+        def one_row(row):
+            def cut(t):
+                return t if row is None else (
+                    jax.lax.dynamic_slice_in_dim(t, row, 1, 0))
+
+            pos, seen = cut(positions), cut(kv_len)
+            n_row = jnp.clip(
+                (jnp.max(cut(last)) + block) // block, 0, s_keys // block)
+            scores = index_scores(
+                cut(q_i), cut(w_i), lambda i: fetch_idx(i, row), n_row,
+                block, s_keys, pos, seen)
+            # sorted as far as the row's keys reach; a padding chunk
+            # of a deep grid (no block to walk) sorts nothing
+            mask = selection_mask(scores, topk, reach=n_row * block)
+            scored = scores > -jnp.inf
+            return latent_attention(
+                cut(q_nope), cut(q_rope), lambda i: fetch(i, row), n_row,
+                block, wkv_b, pos, seen, cfg, absorbed,
+                allowed=lambda i: jax.lax.dynamic_slice_in_dim(
+                    mask, i * block, block, 2),
+            ), count(
+                scored.sum(-1) > topk, None if valid is None else cut(valid),
+                mask, scored)
+
+        rows = [one_row(None if b == 1 else row) for row in range(b)]
+        out = jnp.concatenate([o for o, _ in rows])
+        counts = sum(c for _, c in rows)
+    elif out is None:
         out = latent_attention(
             q_nope, q_rope, fetch, n_blocks, block, wkv_b, positions,
-            kv_len, cfg, absorbed=s <= ABSORBED_MAX_QUERIES,
-        )
+            kv_len, cfg, absorbed)
     x = x + out.reshape(b, s, h * cfg.v_head_dim) @ lp["wo"]
-    return x, cache_k
+    return x, planes, counts
 
 
 # ---------------------------------------------------------------------------
@@ -565,13 +926,19 @@ def routed_experts(xt, idx, weight, valid, banks, layer, cfg: MlaMoeConfig):
     `[layer, expert]` inside the task: sliced out a layer first, XLA
     would copy a layer's whole bank (1.2 GB at the published widths)
     in front of the loop.
-    Returns (out [T, D], stats int32 [3]: experts hit, largest load,
-    pairs)."""
+    Where the chip holds a share (`cfg.experts_held`), `banks` are its
+    experts only and a pair routed to an absent expert goes nowhere,
+    like padding: its part of the sum is another chip's.
+    Returns (out [T, D], stats int32 [4]: experts hit, largest load,
+    pairs computed here, pairs whose expert is absent)."""
     t, d = xt.shape
-    k, e = idx.shape[1], cfg.num_experts
+    k, e = idx.shape[1], cfg.num_experts_held
     pairs = t * k
-    block = _task_block(pairs, e)
+    block = _task_block(pairs, cfg.num_experts)
     flat = idx.reshape(pairs)
+    if cfg.experts_held:
+        flat = flat - cfg.experts_held[0]
+        flat = jnp.where((flat >= 0) & (flat < e), flat, e)
     if valid is not None:  # padding and parked rows route nowhere
         flat = jnp.where(jnp.repeat(valid, k), flat, e)
     order = jnp.argsort(flat, stable=True)
@@ -600,17 +967,36 @@ def routed_experts(xt, idx, weight, valid, banks, layer, cfg: MlaMoeConfig):
     out = (
         y.reshape(t, k, d).astype(jnp.float32) * weight[..., None]
     ).sum(1).astype(xt.dtype)
-    stats = jnp.stack([(counts > 0).sum(), counts.max(), counts.sum()])
+    routed = pairs if valid is None else valid.sum() * k
+    stats = jnp.stack([
+        (counts > 0).sum(), counts.max(), counts.sum(),
+        routed - counts.sum()])
     return out, stats.astype(jnp.int32)
 
 
+def choose_experts(choice, cfg: MlaMoeConfig):
+    """[T, k] expert ids from the selection scores `s + b` [T, E]: the
+    top k, and with `n_group` > 1 the top k inside the best
+    `topk_group` groups only, a group of consecutive experts scoring
+    the sum of its two largest entries."""
+    if cfg.n_group > 1:
+        groups = choice.reshape(choice.shape[0], cfg.n_group, -1)
+        best = jax.lax.top_k(
+            jax.lax.top_k(groups, 2)[0].sum(-1), cfg.topk_group)[1]
+        kept = (best[..., None] == jnp.arange(cfg.n_group)).any(1)
+        choice = jnp.where(
+            kept[..., None], groups, -jnp.inf).reshape(choice.shape)
+    return jax.lax.top_k(choice, cfg.experts_per_token)[1]
+
+
 def moe_ffn(x, lp, banks, layer, cfg: MlaMoeConfig, valid=None):
-    """Sigmoid `noaux_tc` router (one group): choose by `s + b`, weigh
-    by `s`; routed experts plus the shared experts on every token."""
+    """Sigmoid `noaux_tc` router: choose by `s + b` (group-limited
+    where the model has groups), weigh by `s`; routed experts plus the
+    shared experts on every token."""
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
     scores = jax.nn.sigmoid(xt.astype(jnp.float32) @ lp["router"])
-    _, idx = jax.lax.top_k(scores + lp["router_bias"], cfg.experts_per_token)
+    idx = choose_experts(scores + lp["router_bias"], cfg)
     weight = jnp.take_along_axis(scores, idx, axis=-1)
     weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
     weight = weight * cfg.routed_scaling
@@ -650,22 +1036,26 @@ def forward(
     x = embed_lookup(params["embed"], tokens, cfg.jnp_dtype)
     if cache is not None:
         positions = cache.length[:, None] + jnp.arange(s)[None, :]
-        plane, length = cache.k, cache.length
+        plane, length = (cache.k, cache.v), cache.length
     else:
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
         plane, length = None, None
     table = cache.table if isinstance(cache, PagedKVCache) else None
     kd = cfg.first_dense_layers
 
+    # the selection's counts ride out of the scans only where asked
+    # for and where the model selects
+    sparse = with_stats and bool(cfg.index_topk)
+
     def dense_body(carry, scanned):
         x, plane = carry
         lp, layer = scanned
-        x, plane = attention_block(
+        x, plane, sel = attention_block(
             x, lp, cfg, positions, plane, length, table, layer, valid,
             use_flash, flash_mesh)
         n = common.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         x = x + _swiglu(n, lp["w_gate"], lp["w_up"], lp["w_down"])
-        return (x, plane), None
+        return (x, plane), sel if sparse else None
 
     bank_names = ("w_gate", "w_up", "w_down")
     banks = tuple(params["layers"][n] for n in bank_names)
@@ -675,33 +1065,39 @@ def forward(
     def expert_body(carry, scanned):
         x, plane = carry
         lp, layer = scanned
-        x, plane = attention_block(
+        x, plane, sel = attention_block(
             x, lp, cfg, positions, plane, length, table, layer, valid,
             use_flash, flash_mesh)
         n = common.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         out, stats = moe_ffn(n, lp, banks, layer - kd, cfg, valid)
-        return (x + out, plane), stats
+        return (x + out, plane), (
+            jnp.concatenate([stats, sel]) if sparse else stats)
 
-    (x, plane), _ = jax.lax.scan(
+    (x, plane), dense_sel = jax.lax.scan(
         dense_body, (x, plane), (params["dense"], jnp.arange(kd)))
     (x, plane), stats = jax.lax.scan(
         expert_body, (x, plane),
         (per_layer, jnp.arange(kd, cfg.num_layers)))
     new_cache = (
         None if cache is None
-        else cache._replace(k=plane, length=cache.length + s)
+        else cache._replace(k=plane[0], v=plane[1], length=cache.length + s)
     )
     if logit_idx is not None:
         x = jnp.take_along_axis(x, logit_idx[:, None, None], axis=1)
     x = common.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(cfg.jnp_dtype)).astype(jnp.float32)
     if with_stats:
-        return logits, new_cache, stats.sum(0)
+        stats = stats.sum(0)
+        if sparse:  # the dense layers select too
+            stats = stats.at[-3:].add(dense_sel.sum(0))
+        else:
+            stats = jnp.concatenate([stats, jnp.zeros((3,), jnp.int32)])
+        return logits, new_cache, stats
     return logits, new_cache
 
 
 def num_params(cfg: MlaMoeConfig) -> int:
+    norms = 2 * cfg.hidden_dim + cfg.kv_lora_rank + cfg.q_lora_rank + (
+        2 * cfg.index_head_dim if cfg.index_topk else 0)
     return sum(math.prod(shape) for _, shape, _, _ in leaf_recipe(cfg)) + (
-        cfg.num_layers * (2 * cfg.hidden_dim + cfg.kv_lora_rank)
-        + cfg.hidden_dim
-    )
+        cfg.num_layers * norms + cfg.hidden_dim)

@@ -1036,6 +1036,10 @@ FLASH_MIN_SEQ = 256
 # exports it beside mesh_spec_downgrades (attn_kernel_programs /
 # attn_kernel_fallbacks), and it is how chip_smoke.py knows a prefill
 # took the compiled kernel.
+# The latent family also counts here, once a traced program, which of
+# its two sparse-attention paths a layer took (models/mla_moe.py:
+# "sparse_decode" the gather by token index, "sparse_chunk" the walk
+# masked a query); they are XLA programs and in neither sum below.
 dispatch_counts: collections.Counter = collections.Counter()
 
 

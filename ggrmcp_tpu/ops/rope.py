@@ -29,9 +29,15 @@ def rope_freqs(
     wavelengths untouched, and a linear ramp blends between the two
     cutoffs — the published llama3 `rope_type` rule that Llama-3.1+
     checkpoints require for correct logits.
+
+    A tuple that starts with "yarn" is the YaRN rule instead (`yarn`
+    below): ("yarn", factor, original_max_position_embeddings,
+    beta_fast, beta_slow).
     """
     exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
     freqs = 1.0 / (theta**exponent)
+    if scaling and scaling[0] == "yarn":
+        return yarn(freqs, head_dim, theta, *scaling[1:])
     if scaling:
         factor, low, high, orig = (float(v) for v in scaling)
         wavelen = 2.0 * math.pi / freqs
@@ -39,6 +45,35 @@ def rope_freqs(
         smooth = jnp.clip(ramp, 0.0, 1.0)
         freqs = (1.0 - smooth) * freqs / factor + smooth * freqs
     return freqs
+
+
+def yarn(freqs, head_dim, theta, factor, orig, beta_fast, beta_slow):
+    """YaRN frequencies (the published `rope_scaling.type: "yarn"`):
+    pair i keeps `f_i` below the correction dimension that `beta_fast`
+    rotations over the original context give, takes `f_i / factor`
+    above the one `beta_slow` gives, and a linear ramp blends between
+    them. cos / sin stay unscaled (`mscale` equal to `mscale_all_dim`);
+    the softmax scale carries the rest (`yarn_softmax_gain`)."""
+
+    def correction(rotations):
+        return head_dim * math.log(orig / (rotations * 2.0 * math.pi)) / (
+            2.0 * math.log(theta))
+
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), head_dim - 1)
+    ramp = jnp.clip(
+        (jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+        / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
+
+
+def yarn_softmax_gain(scaling: Optional[tuple]) -> float:
+    """What a YaRN model multiplies its softmax scale by:
+    `(0.1 ln(factor) + 1) ** 2` with `mscale_all_dim` 1; 1.0 without
+    YaRN."""
+    if not scaling or scaling[0] != "yarn" or scaling[1] <= 1:
+        return 1.0
+    return (0.1 * math.log(scaling[1]) + 1.0) ** 2
 
 
 def apply_rope(

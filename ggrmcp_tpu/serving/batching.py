@@ -600,20 +600,30 @@ class ContinuousBatcher:
         # asks the module, never which family it is). HEAD_AT_INDEX:
         # the head at one position a row on request (`logit_idx`; a
         # [16, 512, V] logits block is 4 GB at a 128k vocabulary).
-        # ROUTING_STATS: a step's routing counts (`with_stats`), which
-        # ride the tick's token array as three extra rows
-        # (_decode_scan) and are summed at collect time: experts hit,
-        # the largest load of an expert, routed pairs, each summed over
-        # expert layers and steps. DEEP_GRID_CHUNKS: a cold prompt of
+        # ROUTING_STATS: the names of the counts a step returns
+        # (`with_stats`), which ride the tick's token array as one
+        # extra row each (_decode_scan) and are summed at collect time
+        # into `model_counts` under those names (the family's module
+        # says what each one counts). DEEP_GRID_CHUNKS: a cold prompt of
         # more chunks than this is a deep grid; its chunk count rounds
         # up to a power of two and it is admitted alone
         # (_route_admission, _admit_chunked_group). None: every grid
         # keeps its exact depth and its group.
         self._head_at_index = getattr(self.fam, "HEAD_AT_INDEX", False)
-        self._routing_stats = getattr(self.fam, "ROUTING_STATS", False)
+        self._routing_stats = tuple(getattr(self.fam, "ROUTING_STATS", ()))
         self._deep_grid = getattr(self.fam, "DEEP_GRID_CHUNKS", None)
-        self.moe_counts = {"experts_hit": 0, "load_max": 0, "pairs": 0,
-                           "layer_steps": 0}
+        # ARENA_BY_LAYER: the admission programs' scatter into and
+        # gather from the whole arena (_paged_put, paged_view_layers)
+        # go a layer at a time.
+        self._arena_by_layer = getattr(self.fam, "ARENA_BY_LAYER", False)
+        # admission_rows(cfg): the most rows one admission call over a
+        # full-width mini cache (chunked, paged prefix reuse) may take
+        # for this configuration; a family without it, or None, takes
+        # the whole pool. Larger groups go in consecutive calls.
+        rows_of = getattr(self.fam, "admission_rows", None)
+        self._mini_rows = min(b, (rows_of(engine.cfg) if rows_of else None) or b)
+        self.model_counts = dict.fromkeys(
+            self._routing_stats + ("layer_steps",), 0)
         # Prompt tokens admission programs computed, and prompt tokens
         # they took from pages or a prefix entry instead.
         self.prefill_tokens = {"computed": 0, "reused": 0}
@@ -943,7 +953,22 @@ class ContinuousBatcher:
         page = jnp.where(valid, page, self._n_pages)
 
         def put(a, m):
-            return a.at[:, page, off].set(m.astype(a.dtype), mode="drop")
+            if not self._arena_by_layer:
+                return a.at[:, page, off].set(m.astype(a.dtype), mode="drop")
+
+            # A layer at a time, the arena loop-carried and indexed
+            # [layer, page, offset] as the tick writes it: in place.
+            # Where a page row is one vector (the latent family), one
+            # scatter over `a.at[:, page, off]` makes the layer axis
+            # part of each update's window, and XLA then re-lays the
+            # whole arena out (layer axis next to the lanes) and back:
+            # two copies of it alive beside it, 2 x 2.7 GB at a 2 GB
+            # latent arena of 5 layers.
+            def layer(i, a):
+                return a.at[i, page, off].set(
+                    m[i].astype(a.dtype), mode="drop")
+
+            return jax.lax.fori_loop(0, a.shape[0], layer, a)
 
         k = quant.kv_map(put, cache.k, mini.k)
         v = quant.kv_map(put, cache.v, mini.v)
@@ -1405,8 +1430,10 @@ class ContinuousBatcher:
         device call admits a whole same-preamble wave."""
         r = tokens.shape[0]
         mini = llama_mod.KVCache(
-            k=llama_mod.paged_view_layers(cache.k, gtables),
-            v=llama_mod.paged_view_layers(cache.v, gtables),
+            k=llama_mod.paged_view_layers(
+                cache.k, gtables, self._arena_by_layer),
+            v=llama_mod.paged_view_layers(
+                cache.v, gtables, self._arena_by_layer),
             length=jnp.broadcast_to(scan_start, (r,)).astype(jnp.int32),
         )
         fl, mini = self._chunked_scan(
@@ -1428,9 +1455,9 @@ class ContinuousBatcher:
         advances the per-row DFA state via a table gather — the
         constrained step never leaves the device (rows at state 0, the
         accept-all state, are numerically untouched). Returns
-        (toks [B, steps], cache, gstate_out [B]); for the latent
-        family toks is [B + 3, steps], the last three rows each step's
-        routing counts (models/mla_moe.py::forward `with_stats`)."""
+        (toks [B, steps], cache, gstate_out [B]); where the family
+        names ROUTING_STATS, toks has one more row for each, a step's
+        counts (models/mla_moe.py::forward `with_stats`)."""
 
         def body(carry, i):
             cur, gs, cache = carry
@@ -1439,7 +1466,7 @@ class ContinuousBatcher:
                 valid=active[:, None] if self._is_moe else None,
                 ring=self._ring,
                 lora_idx=adapters,
-                with_stats=self._routing_stats,
+                with_stats=bool(self._routing_stats),
             )
             nxt, gs = masked_sample_dynamic(
                 logits[:, -1], seeds, step + i, temps, ks, ps,
@@ -1451,7 +1478,7 @@ class ContinuousBatcher:
         (_, gstate, cache), toks = jax.lax.scan(
             body, (tokens, gstate, cache), jnp.arange(self._steps_per_tick)
         )
-        return toks.T, cache, gstate  # [B (+3), steps_per_tick], ..., [B]
+        return toks.T, cache, gstate  # [B (+ counts), steps_per_tick], .., [B]
 
     def _tick_impl(
         self, params, tokens, cache, seeds, step, temps, ks, ps, active,
@@ -1941,12 +1968,13 @@ class ContinuousBatcher:
             # persistent compile cache keeps programs across runs.
             r_buckets = []
             r_bucket = 1
-            while r_bucket < len(self.slots):
+            while r_bucket < self._mini_rows:
                 r_buckets.append(r_bucket)
                 r_bucket *= 2
-            # Groups clamp to the pool size, so non-pow2 pools reach
-            # R = B itself (_admit_chunked_group's min(b, bucket)).
-            r_buckets.append(len(self.slots))
+            # Groups clamp to the pool size (or the family's rows a
+            # call), so non-pow2 pools reach R = B itself
+            # (_admit_chunked_group's min(b, bucket)).
+            r_buckets.append(self._mini_rows)
             for r_bucket in r_buckets:
                 _, self.cache = self._admit_chunked(
                     self.engine.params,
@@ -2052,7 +2080,7 @@ class ContinuousBatcher:
             # grids.
             width = 32
             while width <= bucket_len(c, maximum=self.max_seq):
-                for r_rows in (1, b_rows) if b_rows > 1 else (1,):
+                for r_rows in sorted({1, self._mini_rows}):
                     gtw = np.full(
                         (r_rows, self._table_width), self._n_pages,
                         np.int32,
@@ -2500,6 +2528,7 @@ class ContinuousBatcher:
         snapshots of host state the executor mutates — monotonic
         counters and slot flags, safe to read stale."""
         t = self.timing
+        counts = self.model_counts
         return {
             # Device-memory ledger components (serving/memory_ledger.py
             # — "phase attribution for bytes"): weights/lora are
@@ -2632,12 +2661,21 @@ class ContinuousBatcher:
             # Expert routing of the decode ticks (the latent-attention
             # family; 0 elsewhere), each summed over expert layers and
             # decode steps: distinct experts a valid token reached,
-            # the largest load of one expert, routed pairs computed,
-            # and the (layer, step) count that divides them.
-            "moe_experts_hit": self.moe_counts["experts_hit"],
-            "moe_load_max_sum": self.moe_counts["load_max"],
-            "moe_routed_pairs": self.moe_counts["pairs"],
-            "moe_layer_steps": self.moe_counts["layer_steps"],
+            # the largest load of one expert, routed pairs computed
+            # here and routed pairs whose expert this chip does not
+            # hold, and the (layer, step) count that divides them.
+            "moe_experts_hit": counts.get("experts_hit", 0),
+            "moe_load_max_sum": counts.get("load_max", 0),
+            "moe_routed_pairs": counts.get("pairs", 0),
+            "moe_pairs_absent": counts.get("pairs_absent", 0),
+            "moe_layer_steps": counts.get("layer_steps", 0),
+            # Sparse attention of the decode ticks (a model with an
+            # indexer; 0 elsewhere), summed over layers and steps: keys
+            # the selected queries attended, keys they could see, and
+            # the (row, layer) instances in which a selection ran.
+            "sparse_keys_selected": counts.get("sparse_selected", 0),
+            "sparse_keys_visible": counts.get("sparse_visible", 0),
+            "sparse_layer_steps": counts.get("sparse_layer_steps", 0),
             # Prompt tokens the admission programs computed, against
             # prompt tokens taken from shared pages or a prefix entry.
             "prefill_tokens_computed": self.prefill_tokens["computed"],
@@ -3545,8 +3583,8 @@ class ContinuousBatcher:
                 for _, req in long_rows
             ]
             shallow = [row for row, d in zip(long_rows, deep) if not d]
-            if shallow:
-                self._admit_chunked_group(shallow)
+            for at in range(0, len(shallow), self._mini_rows):
+                self._admit_chunked_group(shallow[at: at + self._mini_rows])
             for row, d in zip(long_rows, deep):
                 if d:
                     self._admit_chunked_group([row])
@@ -3556,7 +3594,8 @@ class ContinuousBatcher:
         # just wrote (eager same-round registration) — device execution
         # follows dispatch order, so the writes land first.
         for key, group in paged_groups.items():
-            self._admit_paged_group(group, *key)
+            for at in range(0, len(group), self._mini_rows):
+                self._admit_paged_group(group[at: at + self._mini_rows], *key)
         if self._spec:
             # Draft-side admission for every slot this round activated
             # (fused, chunked, and paged paths alike; interleave-queued
@@ -4153,10 +4192,10 @@ class ContinuousBatcher:
         # truncates to it.
         counts = None if counts_dev is None else np.asarray(counts_dev)
         if self._routing_stats and counts is None:
-            extra = toks[len(self.slots):]  # [3, steps]: hit, max, pairs
-            for name, row in zip(("experts_hit", "load_max", "pairs"), extra):
-                self.moe_counts[name] += int(row.sum())
-            self.moe_counts["layer_steps"] += (
+            extra = toks[len(self.slots):]  # [counts, steps]
+            for name, row in zip(self._routing_stats, extra):
+                self.model_counts[name] += int(row.sum())
+            self.model_counts["layer_steps"] += (
                 extra.shape[1] * self.engine.cfg.num_expert_layers)
             toks = toks[:len(self.slots)]
         if rec is not None:

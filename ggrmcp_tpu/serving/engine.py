@@ -133,6 +133,14 @@ _FP8_CACHE = (
     "a float8 plane is read by the latent family's block walk only; "
     "these families' attention reads int8 pages through their scales"
 )
+_MESH_WITH_INDEXER = (
+    "a mesh of more than one device, for a model with a "
+    "sparse-attention indexer"
+)
+_MESH_WITH_EXPERT_SHARE = (
+    "a mesh of more than one device, for a model that holds a "
+    "share of its experts"
+)
 _UNSUPPORTED = {
     "llama": {"kv_cache_dtype fp8": _FP8_CACHE},
     "moe": {
@@ -163,8 +171,28 @@ _UNSUPPORTED = {
             "the weights are served in bf16; int8 matmuls are wired "
             "into the dense family's projections only"
         ),
+        # Asked of the family's members with a sparse-attention indexer
+        # (`index_topk`) or a share of the experts (`experts_held`),
+        # once the mesh is built (_MESH_REFUSALS).
+        _MESH_WITH_INDEXER: (
+            "a row's top-k runs over its whole indexer plane and the "
+            "selected latents are gathered by token index; neither is "
+            "built for a sharded cache"
+        ),
+        _MESH_WITH_EXPERT_SHARE: (
+            "the chip computes its own experts' part of a layer and "
+            "the exchange of tokens and partial sums over a mesh axis "
+            "is not built; on one chip the layer runs without it"
+        ),
     },
 }
+
+# (config key, `_UNSUPPORTED` feature): refused where the key is set
+# and the mesh holds more than one device.
+_MESH_REFUSALS = (
+    ("index_topk", _MESH_WITH_INDEXER),
+    ("experts_held", _MESH_WITH_EXPERT_SHARE),
+)
 
 
 class GenerationEngine:
@@ -199,6 +227,10 @@ class GenerationEngine:
         self.mesh = mesh if mesh is not None else mesh_mod.build_mesh(
             self.serving.mesh
         )
+        if self.mesh.devices.size > 1:
+            for key, feature in _MESH_REFUSALS:
+                if getattr(cfg, key, None):
+                    self._refuse(feature)
         # Sharding-downgrade accounting (tensor-parallel serving,
         # docs/tensor_parallel_serving.md): every spec axis
         # compatible_spec replaces with replication is counted and

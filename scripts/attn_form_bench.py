@@ -61,7 +61,7 @@ def main() -> int:
             key, (cfg.kv_lora_rank, h, nope + cfg.v_head_dim), dtype) * 0.04
         q_pos = past + jnp.broadcast_to(jnp.arange(s), (rows, s))
         kv_len = jnp.full((rows,), past + s, jnp.int32)
-        block = M._key_block(rows, s, s_max, 1)
+        block = M._key_block(rows, s, h, s_max, 1)
 
         def walk(absorbed, q_nope, q_rope, lat, wkv_b, q_pos, kv_len):
             def fetch(i):
