@@ -29,7 +29,8 @@ next starts (this parent never imports JAX or the package):
               experts) against the benchmark's float32 reference layer
               (benchmark/reference_dsv32.py): 4,608 tokens through a
               contiguous cache 512 at a time, so the last chunks select
-              2,048 of up to 4,608 keys a query (the masked walk), then
+              2,048 of up to 4,608 keys a query (on the chip inside the
+              latent-prefill kernel; the rehearsal's masked walk), then
               one decode step over the same latents as pages (the
               gather by token index).
   serve       mistral-7b int8 synthetic weights, paged KV, one chip:
@@ -402,6 +403,7 @@ def sparse_leg_child(rehearsal: bool) -> None:
     from benchmark import reference_dsv32 as ref_mod
     from ggrmcp_tpu.models import common, mla_moe
     from ggrmcp_tpu.models.llama import KVCache, PagedKVCache
+    from ggrmcp_tpu.ops import attention as attn_ops
 
     dev = jax.devices()[0]
     if rehearsal:
@@ -469,9 +471,16 @@ def sparse_leg_child(rehearsal: bool) -> None:
         table)
     got.append(np.asarray(y[0], np.float32))
     got = np.concatenate(got)
+    took = attn_ops.dispatch_counts
     say(f"  sparse layer: {n_chunks} chunks of {chunk} and a decode step "
         f"compiled and ran in {time.monotonic() - t0:.1f} s (set-up, "
-        f"{dev.device_kind})")
+        f"{dev.device_kind}); programs: sparse_chunk {took['sparse_chunk']}, "
+        f"of them through the latent-prefill kernel {took['latent_prefill']}, "
+        f"sparse_decode {took['sparse_decode']}")
+    # On the chip the chunk's selection goes into the kernel; the CPU
+    # rehearsal walks.
+    check(took["latent_prefill"] == (0 if rehearsal else 1)
+          and took["xla_fallback"] == 0, f"the chunk's path: {dict(took)}")
 
     x32 = np.asarray(x, np.float32)
     pad = ref_mod.padded_len(s_all + 1) - (s_all + 1)

@@ -26,7 +26,8 @@ of its own:
   full-width gathered view is ever materialised. A prefill chunk over
   a contiguous plane takes the absorbed form as one Pallas kernel on a
   TPU (`ops.attention.latent_prefill`), whose score block never
-  leaves VMEM; `latent_attention` stays what every other call runs
+  leaves VMEM, for both members (an indexer's selection a query is an
+  operand of it); `latent_attention` stays what every other call runs
   and what the tests hold the kernel to.
 - **The layer stack is not homogeneous** (leading dense layers, then
   expert layers), so the cache is loop-carried through BOTH layer scans
@@ -74,9 +75,11 @@ Params = common.Params
 # walk. A longer one is a prefill chunk: over a contiguous plane on a
 # TPU it attends in the absorbed form too, as one Pallas kernel
 # (ops/attention.py `latent_prefill`: score block and accumulator in
-# VMEM); where that kernel is not the call's kind (the CPU, a quantized
-# or float8 plane, the paged arena, no cache) the walk attends it in
-# the expanded form. Measured on a v5e (scripts/attn_form_bench.py;
+# VMEM; with an indexer the queries' selection masks the score block
+# there); where that kernel is not the call's kind (the CPU, a
+# quantized or float8 plane, the paged arena, no cache) the walk
+# attends it in the expanded form, masked likewise. Measured on a v5e
+# (scripts/attn_form_bench.py;
 # PERF.md section 4): through the walk the absorbed form is as fast or
 # faster at every size tried (512 queries on a 12k past 8.42 against
 # 8.64 ms, 16 decode rows 1.47 against 16.6 ms), both being bound by
@@ -125,11 +128,11 @@ DEEP_GRID_CHUNKS = 4
 def admission_rows(cfg) -> Optional[int]:
     """Rows one admission call over a full-width mini cache may take
     (the batcher asks; None = the whole pool). A model with an indexer
-    takes one: its chunk attention runs a row at a time anyway (a
-    selection a query: index scores, a sort and the walk's score blocks
-    for 128 heads are a row's worth of memory each), so a group saves
-    no pass over the weights worth its rows x 0.25 GB of mini cache
-    beside 11.4 GB resident (PERF.md, PR 33)."""
+    takes one: its chunk attention selects a row at a time anyway
+    (index scores, a sort and a [queries, keys] selection are a row's
+    worth of memory each), so a group saves no pass over the weights
+    worth its rows x 0.25 GB of mini cache beside 11.4 GB resident
+    (PERF.md, PR 33)."""
     return 1 if cfg.index_topk else None
 
 
@@ -645,9 +648,14 @@ def attention_block(
     would read the whole context; the family's attention then runs
     over that one block. A chunk or a re-admission suffix has a
     selection a query: gathered, 512 queries x 2,048 latents would be
-    1.3 GB a layer, so it keeps the dense walk and masks each block
-    with the query's selection (the same set). Where the keys do not
-    outnumber `index_topk` nothing is selected.
+    1.3 GB a layer, so it reads the keys densely and masks each block
+    with the query's selection (the same set): inside the prefill
+    kernel, which takes the selection as an operand, where
+    `latent_prefill` finds the call its kind (as for a model without
+    an indexer: more than `ABSORBED_MAX_QUERIES` queries, a contiguous
+    plane in the model's dtype, a TPU), else in the XLA walk, a row at
+    a time. Where the keys do not outnumber `index_topk` nothing is
+    selected.
     Returns (x + attn, planes, counts): int32 [3], read off the index
     scores and the selection this call made for its real queries (the
     last three of ROUTING_STATS); zeros where none selected."""
@@ -681,7 +689,8 @@ def attention_block(
     if topk:
         q_i, k_i, w_i = indexer_inputs(c_q, normed, lp, cfg, positions)
 
-    out = read_at = None
+    read_at = None
+    chunk = False  # a prefill chunk over a contiguous plane (below)
     counts = jnp.zeros((3,), jnp.int32)
 
     def count(ran, real, chosen, scored):
@@ -755,22 +764,8 @@ def attention_block(
         last = positions if valid is None else jnp.where(valid, positions, -1)
         n_blocks = jnp.clip(
             (jnp.max(last) + block) // block, 0, s_keys // block)
-        if (s > ABSORBED_MAX_QUERIES and page_table is None
-                and not quantized and not topk):
-            # A prefill chunk over a contiguous plane: the absorbed
-            # form as one kernel where `latent_prefill` finds its kind
-            # (a TPU, the plane in the model's dtype), else None and
-            # the walk below. (The kernel has no selection a query: a
-            # model with an indexer keeps the walk.)
-            out = attn_ops.latent_prefill(
-                absorbed_queries(
-                    q_nope, q_rope, wkv_b[..., :nope], lat.shape[-1]),
-                cache_k, layer, cache_len, kv_len, jnp.max(last, axis=1),
-                value_width=rank, scale=cfg.softmax_scale,
-                use_flash=use_flash, flash_mesh=flash_mesh,
-            )
-            if out is not None:
-                out = jnp.einsum("bshc,chd->bshd", out, wkv_b[..., nope:])
+        chunk = (s > ABSORBED_MAX_QUERIES and page_table is None
+                 and not quantized)
 
         def reader(arena, read):
             blk = kv_map(read, arena)
@@ -816,6 +811,22 @@ def attention_block(
         def fetch_idx(i, row=None):
             return read_block(cache_v, i, row)
 
+    def chunk_kernel(allowed=None):
+        """A prefill chunk over a contiguous plane (`chunk`), with its
+        queries' selection if they have one: the absorbed form as one
+        kernel where `latent_prefill` finds its kind (a TPU, the plane
+        in the model's dtype), else None and the caller walks."""
+        if not chunk:
+            return None
+        out = attn_ops.latent_prefill(
+            absorbed_queries(q_nope, q_rope, wkv_b[..., :nope], lat.shape[-1]),
+            cache_k, layer, cache_len, kv_len, jnp.max(last, axis=1), allowed,
+            value_width=rank, scale=cfg.softmax_scale,
+            use_flash=use_flash, flash_mesh=flash_mesh,
+        )
+        return out if out is None else jnp.einsum(
+            "bshc,chd->bshd", out, wkv_b[..., nope:])
+
     absorbed = s <= ABSORBED_MAX_QUERIES
     if topk and s_keys > topk and s == 1 and read_at is not None:
         # A decode step. Exact top-k, ties to the lower position
@@ -836,43 +847,54 @@ def attention_block(
             q_nope, q_rope, lambda i: chosen, 1, topk, wkv_b, positions,
             kv_len, cfg, absorbed, key_pos=lambda i: picked)
     elif topk and s_keys > topk:
-        # A chunk or a suffix: a selection a query, and the dense walk
-        # masked by it. A row at a time, each to its own last key: the
-        # index scores, the sort's temporaries and the walk's score
-        # blocks (128 heads x 512 queries) stay one row's.
+        # A chunk or a suffix: a selection a query, [S, s_keys] bool,
+        # the same set the decode step gathers. Selected a row at a
+        # time, each to its own last key: the index scores and the
+        # sort's temporaries stay one row's.
         attn_ops.dispatch_counts["sparse_chunk"] += 1
 
-        def one_row(row):
-            def cut(t):
-                return t if row is None else (
-                    jax.lax.dynamic_slice_in_dim(t, row, 1, 0))
+        def cut(t, row):
+            return t if row is None else (
+                jax.lax.dynamic_slice_in_dim(t, row, 1, 0))
 
-            pos, seen = cut(positions), cut(kv_len)
+        def select(row):
             n_row = jnp.clip(
-                (jnp.max(cut(last)) + block) // block, 0, s_keys // block)
+                (jnp.max(cut(last, row)) + block) // block, 0,
+                s_keys // block)
             scores = index_scores(
-                cut(q_i), cut(w_i), lambda i: fetch_idx(i, row), n_row,
-                block, s_keys, pos, seen)
+                cut(q_i, row), cut(w_i, row), lambda i: fetch_idx(i, row),
+                n_row, block, s_keys, cut(positions, row), cut(kv_len, row))
             # sorted as far as the row's keys reach; a padding chunk
             # of a deep grid (no block to walk) sorts nothing
             mask = selection_mask(scores, topk, reach=n_row * block)
             scored = scores > -jnp.inf
-            return latent_attention(
-                cut(q_nope), cut(q_rope), lambda i: fetch(i, row), n_row,
-                block, wkv_b, pos, seen, cfg, absorbed,
-                allowed=lambda i: jax.lax.dynamic_slice_in_dim(
-                    mask, i * block, block, 2),
-            ), count(
-                scored.sum(-1) > topk, None if valid is None else cut(valid),
-                mask, scored)
+            return mask, n_row, count(
+                scored.sum(-1) > topk,
+                None if valid is None else cut(valid, row), mask, scored)
 
-        rows = [one_row(None if b == 1 else row) for row in range(b)]
-        out = jnp.concatenate([o for o, _ in rows])
-        counts = sum(c for _, c in rows)
-    elif out is None:
-        out = latent_attention(
-            q_nope, q_rope, fetch, n_blocks, block, wkv_b, positions,
-            kv_len, cfg, absorbed)
+        def walk(row, mask, n_row):
+            """The dense walk masked by the selection, its score blocks
+            (heads x queries x block, float32, in HBM) one row's."""
+            return latent_attention(
+                cut(q_nope, row), cut(q_rope, row), lambda i: fetch(i, row),
+                n_row, block, wkv_b, cut(positions, row), cut(kv_len, row),
+                cfg, absorbed,
+                allowed=lambda i: jax.lax.dynamic_slice_in_dim(
+                    mask, i * block, block, 2))
+
+        rows = [None] if b == 1 else range(b)
+        masks, reach, tallies = zip(*(select(row) for row in rows))
+        counts = sum(tallies)
+        out = chunk_kernel(jnp.concatenate(masks))  # every row's tiles
+        if out is None:
+            out = jnp.concatenate([
+                walk(*each) for each in zip(rows, masks, reach)])
+    else:
+        out = chunk_kernel()
+        if out is None:
+            out = latent_attention(
+                q_nope, q_rope, fetch, n_blocks, block, wkv_b, positions,
+                kv_len, cfg, absorbed)
     x = x + out.reshape(b, s, h * cfg.v_head_dim) @ lp["wo"]
     return x, planes, counts
 

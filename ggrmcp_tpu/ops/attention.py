@@ -783,14 +783,13 @@ def _latent_prefill_kernel(
     q_ref,  # VMEM [block_q*H, W] — one tile's queries, (query, head) major
     qi_ref,  # VMEM [block_q*H, 1] int32 — a score row's query in the tile
     plane_hbm,  # HBM [L, B, S_max, W] — every layer's latents, never copied
-    o_ref,  # VMEM [block_q*H, V]
-    k_buf,  # VMEM [2, block_k, W]
-    sems,  # DMA [2 (slot)]
-    acc_ref,  # VMEM [block_q*H, V] f32
-    *,
+    *rest,  # with a selection: sel_hbm, HBM [B, S/block_q, block_q, S_max]
+    # int32, then as without one: o_ref, k_buf, sems, acc_ref; and last
+    # sel_buf, VMEM [2, block_q, block_k] int32, and sel_sems, DMA [2]
     block_q: int,
     block_k: int,
     scale: float,
+    selected: bool,
 ):
     """One (row, query tile) a grid step: online softmax over the row's
     latents in the plane, `block_k` keys at a time, the next block in
@@ -799,7 +798,14 @@ def _latent_prefill_kernel(
     first V columns are its values. The walk ends at the last key a
     REAL query of the tile may see: a tile past the row's last real
     query walks nothing and emits zeros. Key blocks every query of the
-    tile sees whole take no mask."""
+    tile sees whole take no causal mask. Where the queries bring a
+    selection (`selected`, another program), a block's `[block_q,
+    block_k]` slab of it travels beside the block's keys and masks
+    every block, a query's slab row over its H score rows."""
+    if selected:
+        sel_hbm, o_ref, k_buf, sems, acc_ref, sel_buf, sel_sems = rest
+    else:
+        o_ref, k_buf, sems, acc_ref = rest
     b, t = pl.program_id(0), pl.program_id(1)
     layer = layer_ref[0]
     kv_len = len_ref[b]
@@ -813,43 +819,62 @@ def _latent_prefill_kernel(
     end = jnp.clip((seen + block_k - 1) // block_k, 0, n_plane)
     whole = jnp.clip(jnp.minimum(first_pos + 1, kv_len) // block_k, 0, end)
 
-    def copy(i, slot):
-        return pltpu.make_async_copy(
+    def copies(i, slot):
+        """Key block i into `slot`, and its slab of the selection."""
+        pair = [pltpu.make_async_copy(
             plane_hbm.at[layer, b, pl.ds(i * block_k, block_k)],
             k_buf.at[slot], sems.at[slot],
-        )
+        )]
+        if selected:
+            pair.append(pltpu.make_async_copy(
+                sel_hbm.at[b, t, :, pl.ds(i * block_k, block_k)],
+                sel_buf.at[slot], sel_sems.at[slot],
+            ))
+        return pair
 
     @pl.when(end > 0)
     def _():
-        copy(0, 0).start()
+        for copy in copies(0, 0):
+            copy.start()
 
     q = q_ref[...]
     q_pos = first_pos + qi_ref[...]  # [rows, 1]
     acc_ref[...] = jnp.zeros_like(acc_ref)
+    heads = q.shape[0] // block_q
 
-    def block(i, carry, masked):
+    def block(i, carry, causal):
         m_prev, l_prev = carry
         slot = i % 2
 
         @pl.when(i + 1 < end)
         def _():
-            copy(i + 1, 1 - slot).start()
+            for copy in copies(i + 1, 1 - slot):
+                copy.start()
 
-        copy(i, slot).wait()
+        for copy in copies(i, slot):
+            copy.wait()
         k_blk = k_buf[slot]  # [block_k, W]
         scores = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [rows, block_k]
-        if masked:
+        mask = None
+        if causal:
             k_pos = i * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_k), 1)
             mask = (k_pos <= q_pos) & (k_pos < kv_len)
+        if selected:
+            sel = sel_buf[slot] != 0  # [block_q, block_k]
+            sel = jnp.concatenate([
+                jnp.broadcast_to(sel[j:j + 1], (heads, block_k))
+                for j in range(block_q)])
+            mask = sel if mask is None else mask & sel
+        if mask is not None:
             scores = jnp.where(mask, scores, NEG_INF)
         m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(scores - m_new)
-        if masked:  # a query may see no key of this block at all
+        if mask is not None:  # a query may see no key of this block at all
             p = jnp.where(mask, p, 0.0)
         l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
@@ -864,26 +889,31 @@ def _latent_prefill_kernel(
         jnp.zeros((rows, 1), jnp.float32),
     )
     carry = jax.lax.fori_loop(
-        0, whole, functools.partial(block, masked=False), carry)
+        0, whole, functools.partial(block, causal=False), carry)
     _, l = jax.lax.fori_loop(
-        whole, end, functools.partial(block, masked=True), carry)
+        whole, end, functools.partial(block, causal=True), carry)
     o_ref[...] = jnp.where(
         l > 0.0, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0
     ).astype(o_ref.dtype)
 
 
 def _latent_prefill_vmem_bytes(
-    rows: int, w: int, v: int, block_k: int, itemsize: int
+    rows: int, w: int, v: int, block_k: int, itemsize: int,
+    selected_q: int = 0,
 ) -> int:
     """VMEM the latent-prefill kernel needs, told to the compiler: the
     key block buffer, two slots; a tile's queries and its output
     (double-buffered by the pipeline); the float32 accumulator; the
     float32 scores, weights and mask of one block; 4 MiB of headroom
     for Mosaic's own scratch. 8.5 MiB + headroom at the published
-    widths (512 rows, 640-wide keys, 512-wide values, bf16)."""
+    widths (512 rows, 640-wide keys, 512-wide values, bf16). With a
+    selection (`selected_q` queries a tile): its slab buffer, two
+    slots, and the slab spread over the score rows, 1 MiB more."""
     bufs = 2 * block_k * w * itemsize
     qo = 2 * rows * (w + v) * itemsize
     work = 4 * (rows * v + 4 * rows * block_k)
+    if selected_q:
+        work += 4 * (2 * max(selected_q, 8) * block_k + rows * block_k)
     return bufs + qo + work + (4 << 20)
 
 
@@ -900,6 +930,8 @@ def latent_prefill_attention(
     last_q: jnp.ndarray,  # [B] position of a row's last REAL query (-1:
     # none): queries past it are padding, and their output is undefined
     # (finite)
+    allowed: Optional[jnp.ndarray] = None,  # [B, S, S_max] bool — each
+    # query's selection (`mla_moe.selection_mask`); None: it sees all
     *,
     value_width: int,  # a key's first `value_width` columns are its value
     scale: float,
@@ -914,8 +946,11 @@ def latent_prefill_attention(
     maximum and sum and the accumulator in VMEM (float32), the weights
     cast to the plane's dtype before the value matmul. Query i of row b
     sits at position q_offset[b] + i and sees keys [0, min(position + 1,
-    kv_len[b])). What `mla_moe.latent_attention` computes in its
-    absorbed form, for the real queries, without a score block in HBM.
+    kv_len[b])), of them those `allowed` names where it is given. What
+    `mla_moe.latent_attention` computes in its absorbed form, for the
+    real queries, without a score block in HBM. Without a selection the
+    program is the one it was before there was one: the operand, its
+    buffer and its mask exist only in the program that is given it.
     Returns [B, S, H, value_width]. Compiled for the TPU unless
     `interpret=True` (CPU tests) asks for the interpreter."""
     b, s, h, w = q.shape
@@ -928,34 +963,52 @@ def latent_prefill_attention(
         f"({block_q},{block_k})"
     )
     rows = block_q * h
+    selected = allowed is not None
     kernel = functools.partial(
         _latent_prefill_kernel, block_q=block_q, block_k=block_k, scale=scale,
+        selected=selected,
     )
     # Score row r of a tile is (query r // H, head r % H): a constant
     # of the program, as in the paged-decode kernel.
     qi = (np.arange(rows) // h).astype(np.int32)[:, None]
+    operands = [q.reshape(b, s * h, w), qi, plane]
+    in_specs = [
+        pl.BlockSpec((None, rows, w), lambda bi, ti, *_: (bi, ti, 0)),
+        pl.BlockSpec((rows, 1), lambda *_: (0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    scratch_shapes = [
+        pltpu.VMEM((2, block_k, w), plane.dtype),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.VMEM((rows, value_width), jnp.float32),
+    ]
+    if selected:
+        # A tile's queries on an axis of their own, so that a slab is
+        # whole in the sublanes whatever `block_q` is (4 at 128 heads);
+        # 32-bit, which Mosaic tiles at any such count.
+        assert allowed.shape == (b, s, s_keys), (allowed.shape, q.shape)
+        operands.append(
+            allowed.astype(jnp.int32).reshape(b, s // block_q, block_q, s_keys))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        scratch_shapes += [
+            pltpu.VMEM((2, block_q, block_k), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(b, s // block_q),
-            in_specs=[
-                pl.BlockSpec((None, rows, w), lambda bi, ti, *_: (bi, ti, 0)),
-                pl.BlockSpec((rows, 1), lambda *_: (0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
+            in_specs=in_specs,
             out_specs=pl.BlockSpec(
                 (None, rows, value_width), lambda bi, ti, *_: (bi, ti, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, block_k, w), plane.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.VMEM((rows, value_width), jnp.float32),
-            ],
+            scratch_shapes=scratch_shapes,
         ),
         out_shape=jax.ShapeDtypeStruct((b, s * h, value_width), q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_latent_prefill_vmem_bytes(
-                rows, w, value_width, block_k, plane.dtype.itemsize
+                rows, w, value_width, block_k, plane.dtype.itemsize,
+                block_q if selected else 0,
             ),
         ),
         name="latent_attention_prefill",
@@ -963,7 +1016,7 @@ def latent_prefill_attention(
     )(
         jnp.reshape(layer, (1,)).astype(jnp.int32),
         q_offset.astype(jnp.int32), kv_len.astype(jnp.int32),
-        last_q.astype(jnp.int32), q.reshape(b, s * h, w), qi, plane,
+        last_q.astype(jnp.int32), *operands,
     )
     return out.reshape(b, s, h, value_width)
 
@@ -976,6 +1029,7 @@ def latent_prefill_attention_sharded(
     kv_len: jnp.ndarray,
     last_q: jnp.ndarray,
     mesh,
+    allowed: Optional[jnp.ndarray] = None,  # [B, S, S_max] bool
     *,
     value_width: int,
     scale: float,
@@ -984,7 +1038,8 @@ def latent_prefill_attention_sharded(
     """`latent_prefill_attention` on a multi-device mesh, one kernel
     per shard: rows over `data`/`fsdp` as `mla_moe.cache_specs` shards
     the plane, heads over `tensor` (every shard reads the whole latent:
-    it is shared by all heads). Manual over EVERY mesh axis, as
+    it is shared by all heads, and so is a query's selection, which
+    goes with the rows). Manual over EVERY mesh axis, as
     `flash_attention_sharded` is; the data axes must divide the rows
     and `tensor` the heads."""
     from jax.sharding import PartitionSpec as P
@@ -994,11 +1049,12 @@ def latent_prefill_attention_sharded(
         raise ValueError(why)
     qspec = P(("data", "fsdp"), None, "tensor", None)
     rspec = P(("data", "fsdp"))
+    selection = () if allowed is None else (allowed,)
 
-    def local(q, pln, ly, qo, kl, lq):
+    def local(q, pln, ly, qo, kl, lq, *sel):
         return latent_prefill_attention(
-            q, pln, ly, qo, kl, lq, value_width=value_width, scale=scale,
-            interpret=interpret,
+            q, pln, ly, qo, kl, lq, *sel, value_width=value_width,
+            scale=scale, interpret=interpret,
         )
 
     return jax.shard_map(
@@ -1006,11 +1062,11 @@ def latent_prefill_attention_sharded(
         mesh=mesh,
         in_specs=(
             qspec, P(None, ("data", "fsdp"), None, None), P(),
-            rspec, rspec, rspec,
+            rspec, rspec, rspec, *(rspec for _ in selection),
         ),
         out_specs=qspec,
         check_vma=False,
-    )(q, plane, layer, q_offset, kv_len, last_q)
+    )(q, plane, layer, q_offset, kv_len, last_q, *selection)
 
 
 # ---------------------------------------------------------------------------
@@ -1038,8 +1094,10 @@ FLASH_MIN_SEQ = 256
 # took the compiled kernel.
 # The latent family also counts here, once a traced program, which of
 # its two sparse-attention paths a layer took (models/mla_moe.py:
-# "sparse_decode" the gather by token index, "sparse_chunk" the walk
-# masked a query); they are XLA programs and in neither sum below.
+# "sparse_decode" the gather by token index, "sparse_chunk" a selection
+# a query); neither is in the sums below: a sparse chunk that runs the
+# kernel counts under "latent_prefill" too, one that wanted it and
+# walks under "xla_fallback", and the rest are XLA programs.
 dispatch_counts: collections.Counter = collections.Counter()
 
 
@@ -1213,6 +1271,8 @@ def latent_prefill(
     q_offset: jnp.ndarray,  # [B]
     kv_len: jnp.ndarray,  # [B]
     last_q: jnp.ndarray,  # [B] position of a row's last real query
+    allowed: Optional[jnp.ndarray] = None,  # [B, S, S_max] bool: each
+    # query's selection, where the model has an indexer
     *,
     value_width: int,
     scale: float,
@@ -1224,7 +1284,10 @@ def latent_prefill(
     Chosen from what the call can see, no option: a TPU; a plane stored
     in the queries' own dtype (a float8 plane is turned away here, a
     quantized one never gets here); keys and values of whole 128-lane
-    rows; a chunk that tiles into whole sublane groups of score rows.
+    rows; a chunk that tiles into whole sublane groups of score rows;
+    with a selection, key blocks of whole 128-lane slabs of it. A call
+    with a selection and one without are the same kind and two
+    programs (`latent_prefill_attention`).
     Which calls are chunks (by query count) is the caller's to say. On
     a multi-device mesh the kernel runs per shard (`flash_mesh`, as for
     the prefill kernel). A call of that kind on a mesh that cannot run
@@ -1234,13 +1297,14 @@ def latent_prefill(
     b, s, h, w = q.shape
     t_ax = 1 if flash_mesh is None else flash_mesh.shape.get("tensor", 1)
     h_shard = max(1, h // t_ax)
-    block_q, _ = _latent_prefill_blocks(s, h_shard, plane.shape[2])
+    block_q, block_k = _latent_prefill_blocks(s, h_shard, plane.shape[2])
     if (
         not _on_tpu()
         or plane.dtype != q.dtype
         or w % 128 != 0
         or value_width % 128 != 0
         or (block_q * h_shard) % 16 != 0
+        or (allowed is not None and block_k % 128 != 0)
     ):
         return None
     why = ""
@@ -1259,16 +1323,17 @@ def latent_prefill(
         return None
     dispatch_counts["latent_prefill"] += 1
     logger.info(
-        "attention: latent-prefill Pallas kernel%s for q%s plane%s",
+        "attention: latent-prefill Pallas kernel%s for q%s plane%s%s",
         " per shard" if flash_mesh is not None else "",
         tuple(q.shape), tuple(plane.shape),
+        "" if allowed is None else ", a selection a query",
     )
     if flash_mesh is not None:
         return latent_prefill_attention_sharded(
-            q, plane, layer, q_offset, kv_len, last_q, flash_mesh,
+            q, plane, layer, q_offset, kv_len, last_q, flash_mesh, allowed,
             value_width=value_width, scale=scale,
         )
     return latent_prefill_attention(
-        q, plane, layer, q_offset, kv_len, last_q,
+        q, plane, layer, q_offset, kv_len, last_q, allowed,
         value_width=value_width, scale=scale,
     )

@@ -13,6 +13,14 @@ Prints one line a (rows, S, P, form): milliseconds a call, median of
 `--reps` after a warm-up, and for the kernel its largest difference
 from the absorbed walk. `ABSORBED_MAX_QUERIES` in models/mla_moe.py
 was set from this table (PERF.md, section 4).
+
+Then the indexer member's chunk (`deepseek-v3.2-ep16-5l`: 128 heads,
+each query its 2,048 selected keys, a 32,768-key plane): the walk
+masked by the selection, as `attention_block` runs it where the kernel
+is not the call's kind (expanded, to the last real query's key), against
+the kernel given the selection, for a suffix of 250 real queries in
+the chunk of 512 and for a full chunk. The selection is an input here
+(random index scores through `selection_mask`): making it is not timed.
 """
 
 from __future__ import annotations
@@ -41,18 +49,37 @@ def main() -> int:
     from ggrmcp_tpu.models import mla_moe as M
     from ggrmcp_tpu.ops import attention as A
 
-    cfg = M.CONFIGS["tiny-mla-moe" if args.cpu else "kanana-2-30b-a3b-6l"]
+    # (model, plane, then rows, queries, past and real queries a case;
+    # 0 real queries: all of them, and no selection)
+    plain, sparse = (
+        [("tiny-mla-moe", 256, [(1, 64, 128, 0), (1, 16, 128, 0)]),
+         ("tiny-dsv32", 256, [(1, 64, 128, 30), (1, 64, 128, 64)])]
+        if args.cpu else
+        [("kanana-2-30b-a3b-6l", 16384, [
+            (1, 512, 12288, 0), (1, 512, 4096, 0), (1, 256, 12288, 0),
+            (1, 128, 12288, 0), (1, 64, 12288, 0), (4, 128, 12288, 0),
+            (16, 1, 12288, 0)]),
+         ("deepseek-v3.2-ep16-5l", 32768, [
+            (1, 512, 12288, 250), (1, 512, 12288, 512),
+            (1, 512, 18432, 250), (1, 512, 18432, 512)])]
+    )
+    for name, s_max, cases in (plain, sparse):
+        bench(args, M.CONFIGS[name], s_max, cases)
+    return 0
+
+
+def bench(args, cfg, s_max: int, cases) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from ggrmcp_tpu.models import mla_moe as M
+    from ggrmcp_tpu.ops import attention as A
+
     dtype = cfg.jnp_dtype
     h, nope, rope = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     width = cfg.kv_planes[0][0]
-    s_max = 256 if args.cpu else 16384
-    cases = (
-        [(1, 64, 128), (1, 16, 128)] if args.cpu else
-        [(1, 512, 12288), (1, 512, 4096), (1, 256, 12288), (1, 128, 12288),
-         (1, 64, 12288), (4, 128, 12288), (16, 1, 12288)]
-    )
     print(f"device {jax.devices()[0].device_kind}, {cfg.name}")
-    for rows, s, past in cases:
+    for rows, s, past, n_real in cases:
         key = jax.random.PRNGKey(0)
         lat = jax.random.normal(key, (rows, s_max, width), dtype)
         q_nope = jax.random.normal(key, (rows, s, h, nope), dtype)
@@ -61,24 +88,34 @@ def main() -> int:
             key, (cfg.kv_lora_rank, h, nope + cfg.v_head_dim), dtype) * 0.04
         q_pos = past + jnp.broadcast_to(jnp.arange(s), (rows, s))
         kv_len = jnp.full((rows,), past + s, jnp.int32)
+        last = q_pos[:, (n_real or s) - 1]  # a row's last real query
         block = M._key_block(rows, s, h, s_max, 1)
+        select = ()
+        if n_real:
+            k_pos = jnp.arange(s_max)[None, None, :]
+            select = (jax.jit(functools.partial(
+                M.selection_mask, topk=cfg.index_topk))(jnp.where(
+                    k_pos <= q_pos[:, :, None],
+                    jax.random.normal(key, (rows, s, s_max)), -jnp.inf)),)
 
-        def walk(absorbed, q_nope, q_rope, lat, wkv_b, q_pos, kv_len):
+        def walk(absorbed, q_nope, q_rope, lat, wkv_b, q_pos, kv_len, last,
+                 *select):
             def fetch(i):
                 return jax.lax.dynamic_slice_in_dim(lat, i * block, block, 1)
 
-            n_blocks = (jnp.max(kv_len) + block - 1) // block
             return M.latent_attention(
-                q_nope, q_rope, fetch, n_blocks, block, wkv_b, q_pos,
-                kv_len, cfg, absorbed=absorbed)
+                q_nope, q_rope, fetch, (jnp.max(last) + block) // block,
+                block, wkv_b, q_pos, kv_len, cfg, absorbed=absorbed,
+                allowed=(lambda i: jax.lax.dynamic_slice_in_dim(
+                    select[0], i * block, block, 2)) if select else None)
 
-        def kernel(q_nope, q_rope, lat, wkv_b, q_pos, kv_len):
+        def kernel(q_nope, q_rope, lat, wkv_b, q_pos, kv_len, last, *select):
             # The folding `mla_moe.attention_block` does around it.
             out = A.latent_prefill_attention(
                 M.absorbed_queries(q_nope, q_rope, wkv_b[..., :nope], width),
-                lat[None], jnp.int32(0), q_pos[:, 0], kv_len, q_pos[:, -1],
-                value_width=cfg.kv_lora_rank,
-                scale=(nope + rope) ** -0.5, interpret=args.cpu)
+                lat[None], jnp.int32(0), q_pos[:, 0], kv_len, last, *select,
+                value_width=cfg.kv_lora_rank, scale=cfg.softmax_scale,
+                interpret=args.cpu)
             return jnp.einsum("bshc,chd->bshd", out, wkv_b[..., nope:])
 
         forms = {
@@ -86,11 +123,11 @@ def main() -> int:
             "expanded": functools.partial(walk, False),
             "kernel": kernel,
         }
-        operands = (q_nope, q_rope, lat, wkv_b, q_pos, kv_len)
+        operands = (q_nope, q_rope, lat, wkv_b, q_pos, kv_len, last, *select)
         want = None
         for form, run in forms.items():
             fn = jax.jit(run)
-            out = jax.block_until_ready(fn(*operands))
+            out = jax.block_until_ready(fn(*operands))[:, :n_real or s]
             if form == "absorbed":
                 want = out.astype(jnp.float32)
             times = []
@@ -103,10 +140,10 @@ def main() -> int:
                 f"{float(jnp.abs(out.astype(jnp.float32) - want).max()):.4f}"
                 if form == "kernel" else ""
             )
-            print(f"rows {rows:2d} queries {s:4d} past {past:6d} block {block:4d} "
-                  f"{form}: {statistics.median(times):8.3f} ms{diff}",
-                  flush=True)
-    return 0
+            real = f" ({n_real} real, selected)" if n_real else ""
+            print(f"rows {rows:2d} queries {s:4d}{real} past {past:6d} "
+                  f"block {block:4d} {form}: "
+                  f"{statistics.median(times):8.3f} ms{diff}", flush=True)
 
 
 if __name__ == "__main__":

@@ -57,3 +57,18 @@ def pytest_pyfunc_call(pyfuncitem):
 @pytest.fixture(scope="session")
 def testdata_dir():
     return os.path.join(os.path.dirname(os.path.abspath(__file__)), "testdata")
+
+
+@pytest.fixture
+def latent_prefill_on_tpu(monkeypatch):
+    """What the latent-prefill dispatch (ops/attention.py
+    `latent_prefill`) sees on the chip, here: the platform answers TPU
+    and the kernel it then picks runs interpreted. The program has no
+    option for it."""
+    from ggrmcp_tpu.ops import attention
+
+    compiled = attention.latent_prefill_attention
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        attention, "latent_prefill_attention",
+        lambda *a, **kw: compiled(*a, **{**kw, "interpret": True}))

@@ -20,6 +20,7 @@ import pytest
 from ggrmcp_tpu.core.config import BatchingConfig, MeshConfig, ServingConfig
 from ggrmcp_tpu.models import common, family_module, get_model, llama
 from ggrmcp_tpu.models import mla_moe as M
+from ggrmcp_tpu.ops import attention as A
 from ggrmcp_tpu.ops import rope as rope_ops
 from ggrmcp_tpu.ops.sampling import SamplingConfig
 from ggrmcp_tpu.serving.batching import ContinuousBatcher
@@ -218,6 +219,67 @@ def test_a_chunks_counts_are_its_masks_and_its_scores(params):
         3 * 24 * 16, 3 * sum(range(33, 57)), 3 * 24]
 
 
+def test_a_sparse_chunk_asks_for_the_kernel_with_its_selection(
+        params, monkeypatch):
+    """`attention_block` over a contiguous plane, 512 queries on a past
+    of 512, the last 112 of them padding: it asks `latent_prefill` for
+    the kernel, once, with each query's selection and the row's last
+    real query. Here the kernel is a stub that attends densely what it
+    was handed (folded queries, the plane, the mask); the block's
+    output and its three counts are the masked walk's."""
+    lp = jax.tree.map(lambda a: a[1], params["layers"])
+    cache = llama.KVCache.create(CFG, 1, 1024)
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 1, 512, CFG.hidden_dim))
+    positions = jnp.arange(1024)[None]
+    valid = jnp.arange(512)[None] < 400
+    _, planes, _ = M.attention_block(
+        x[0], lp, CFG, positions[:, :512], (cache.k, cache.v),
+        jnp.asarray([0]), None, 2)
+
+    def chunk():
+        before = A.dispatch_counts["sparse_chunk"]
+        out, _, counts = M.attention_block(
+            x[1], lp, CFG, positions[:, 512:], planes, jnp.asarray([512]),
+            None, 2, valid=valid)
+        assert A.dispatch_counts["sparse_chunk"] == before + 1
+        return np.asarray(out)[valid], counts.tolist()
+
+    want, want_counts = chunk()  # on the CPU the real one answers None
+    calls = []
+
+    def stub(q, plane, layer, q_offset, kv_len, last_q, allowed=None, *,
+             value_width, scale, **kw):
+        calls.append((q, plane, layer, q_offset, kv_len, last_q, allowed, kw))
+        keys = plane[layer]  # [B, S_max, W]
+        k_pos = jnp.arange(keys.shape[1])[None, None]
+        q_pos = q_offset[:, None] + jnp.arange(q.shape[1])[None]
+        seen = allowed & (k_pos <= q_pos[..., None]) & (
+            k_pos < kv_len[:, None, None])
+        scores = jnp.einsum("bshw,bkw->bhsk", q, keys) * scale
+        weights = jax.nn.softmax(
+            jnp.where(seen[:, None], scores, -1e30), axis=-1)
+        return jnp.einsum("bhsk,bkc->bshc", weights, keys[..., :value_width])
+
+    monkeypatch.setattr(A, "latent_prefill", stub)
+    got, got_counts = chunk()
+    (q, plane, layer, q_offset, kv_len, last_q, allowed, kw), = calls
+    assert q.shape == (1, 512, CFG.num_heads, 128)
+    # the whole plane, this chunk's latents written: never a copy of a row
+    assert plane.shape == planes[0].shape == (3, 1, 1024, 128)
+    assert float(jnp.abs(plane[2, 0, 512:912]).min(0).max()) > 0
+    assert float(jnp.abs(planes[0][2, 0, 512:]).max()) == 0
+    assert (int(layer), q_offset.tolist(), kv_len.tolist(), last_q.tolist()
+            ) == (2, [512], [1024], [911])
+    assert allowed.shape == (1, 512, 1024) and allowed.dtype == jnp.bool_
+    assert set(kw) == {"use_flash", "flash_mesh"}
+    picked = np.asarray(allowed[0])
+    assert (picked.sum(-1) == CFG.index_topk).all()
+    assert not np.triu(picked[:, 512:], k=1).any()  # none past the query
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    assert got_counts == want_counts == [
+        400 * 16, sum(range(513, 913)), 400]
+
+
 @pytest.mark.parametrize("s", [1, 8])
 def test_the_counts_follow_the_path_and_not_the_lengths(
         params, monkeypatch, s):
@@ -378,6 +440,99 @@ def test_quantized_planes_carry_both_kinds_of_state(
     diff = np.abs(coarse - exact).max(-1)
     assert tight < diff[:16].max() < loose
     assert np.isfinite(coarse).all() and diff[16:].max() > tight
+
+
+# A latent of whole lanes (rank 128 in a 256-wide plane), as the
+# kernel's dispatch asks on the chip, and chunks of 256 queries.
+LANE = dataclasses.replace(CFG, name="tiny-dsv32-lane", kv_lora_rank=128)
+LANE_MODEL = dict(REF_MODEL, kv_lora_rank=128)
+
+
+@pytest.fixture(scope="module")
+def lane_params():
+    return jax.jit(lambda k: M.init_params(k, LANE))(jax.random.PRNGKey(0))
+
+
+def took(before):
+    return [A.dispatch_counts[key] - before.get(key, 0)
+            for key in ("sparse_chunk", "latent_prefill", "xla_fallback")]
+
+
+@pytest.mark.parametrize("path", ["walk", "kernel"])
+def test_chunks_a_suffix_and_a_step_agree_on_both_paths(
+        lane_params, path, request):
+    """A contiguous mini cache filled by two chunks of 256 queries (the
+    second's tail is padding), a suffix of 60 on the whole history and
+    a decode step, every real position's logits against the float32
+    reference: through the masked walk (the CPU's path), and with the
+    platform answering TPU through the kernel given each query's
+    selection (the suffix and the step keep their own paths)."""
+    if path == "kernel":
+        request.getfixturevalue("latent_prefill_on_tpu")
+    ids = ids_of(512, salt=12)
+    weights = R.family_init_weights(jax, LANE_MODEL)
+    want = ref_logits(
+        (weights, R.make_layers(jax, LANE_MODEL)), ids, LANE_MODEL)
+    cache = llama.KVCache.create(LANE, 1, 512)
+    step = jax.jit(lambda p, t, c, v: M.forward(p, LANE, t, c, valid=v))
+    before = dict(A.dispatch_counts)
+    got = []
+    # the first chunk's tail is padding too: the batcher then sets the
+    # row's length back, as here
+    for lo, hi, real in ((0, 256, 250), (250, 506, 201), (451, 511, 60),
+                         (511, 512, 1)):
+        tokens = np.zeros((1, hi - lo), np.int32)
+        tokens[0, :real] = ids[lo:lo + real]
+        logits, cache = step(
+            lane_params, jnp.asarray(tokens), cache,
+            jnp.arange(hi - lo)[None] < real)
+        cache = cache._replace(length=jnp.asarray([lo + real], jnp.int32))
+        got.append(np.asarray(logits[0, :real]))
+    # the chunk program's two layer scans trace their bodies once each
+    # (both chunks run it), then the suffix's; the step gathers
+    assert took(before) == [4, 2 if path == "kernel" else 0, 0]
+    np.testing.assert_allclose(np.concatenate(got), want, atol=ATOL)
+
+
+KEEPS_THE_WALK = {
+    "fp8_plane": dict(kv_dtype="fp8"),
+    "int8_plane": dict(kv_dtype="int8"),
+    "the_paged_arena": dict(paged=True),
+    "a_suffix_of_128": dict(s=128),
+}
+
+
+@pytest.mark.parametrize("case", KEEPS_THE_WALK, ids=list(KEEPS_THE_WALK))
+def test_a_sparse_chunk_keeps_the_walk(
+        lane_params, latent_prefill_on_tpu, case):
+    """On a TPU, with lane-wide latents: a float8 or quantized plane
+    (the benchmark's `fp8_kv` control reads the walk), the paged arena
+    and 128 queries or fewer still select a query, in the masked walk:
+    no kernel program, and no fallback counted (they never were the
+    kernel's kind)."""
+    c = dict(kv_dtype="", s=256, paged=False)
+    c.update(KEEPS_THE_WALK[case])
+    if c["paged"]:
+        cache = llama.PagedKVCache.create(LANE, 1, 512, 32, 16)
+        cache = cache._replace(table=jnp.arange(32, dtype=jnp.int32)[None])
+    else:
+        cache = llama.KVCache.create(LANE, 1, 512, c["kv_dtype"])
+    before = dict(A.dispatch_counts)
+    logits, _ = jax.jit(lambda p, t, c: M.forward(p, LANE, t, c))(
+        lane_params, jnp.asarray([ids_of(c["s"], salt=13)]), cache)
+    assert np.isfinite(np.asarray(logits)).all()
+    assert took(before) == [2, 0, 0]
+
+
+def test_a_sparse_chunk_that_wanted_the_kernel_is_a_counted_fallback(
+        lane_params, latent_prefill_on_tpu):
+    """The engine turned kernels off for its mesh: the chunk walks,
+    and says so, as a model without an indexer does."""
+    before = dict(A.dispatch_counts)
+    jax.jit(lambda p, t, c: M.forward(p, LANE, t, c, use_flash=False))(
+        lane_params, jnp.asarray([ids_of(256, salt=13)]),
+        llama.KVCache.create(LANE, 1, 512))
+    assert took(before) == [2, 0, 2]
 
 
 async def _collect(batcher, prompt, max_new, seed):

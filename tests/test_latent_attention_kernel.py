@@ -12,7 +12,8 @@ called from `mla_moe.attention_block`).
    and that both are counted; through `mla_moe.forward`, chunked
    prefill then decode gives the walk's logits.
 
-The platform rule is steered here, in the test (`_on_tpu` patched):
+The platform rule is steered by tests/conftest.py's
+`latent_prefill_on_tpu` (`_on_tpu` patched, the kernel interpreted):
 the program has no option for it. Marker `paged` (tier-1).
 """
 
@@ -59,31 +60,36 @@ def operands(cfg, q_off, s, dtype, seed=0):
     return plane, q_nope, q_rope, wkv_b
 
 
-def walked(cfg, plane, q_nope, q_rope, wkv_b, layer, q_off, kv_len, n_real):
+def walked(cfg, plane, q_nope, q_rope, wkv_b, layer, q_off, kv_len, n_real,
+           allowed=None, block_k=BLOCK_K):
     """`latent_attention` as `attention_block` calls it on a contiguous
-    plane: the walk stops at the last key a real query may see."""
+    plane: the walk stops at the last key a real query may see, masked
+    by the queries' selection `allowed` [B, S, S_max] if they have one."""
     s = q_nope.shape[1]
     positions = q_off[:, None] + jnp.arange(s)[None, :]
     last = jnp.where(jnp.arange(s)[None, :] < n_real[:, None], positions, -1)
     n_blocks = jnp.clip(
-        (jnp.max(last) + BLOCK_K) // BLOCK_K, 0, S_MAX // BLOCK_K)
+        (jnp.max(last) + block_k) // block_k, 0, plane.shape[2] // block_k)
 
     def fetch(i):
         return jax.lax.dynamic_slice_in_dim(
-            plane[layer], i * BLOCK_K, BLOCK_K, 1)
+            plane[layer], i * block_k, block_k, 1)
 
     return M.latent_attention(
-        q_nope, q_rope, fetch, n_blocks, BLOCK_K, wkv_b, positions, kv_len,
-        cfg, absorbed=True)
+        q_nope, q_rope, fetch, n_blocks, block_k, wkv_b, positions, kv_len,
+        cfg, absorbed=True,
+        allowed=None if allowed is None else (
+            lambda i: jax.lax.dynamic_slice_in_dim(
+                allowed, i * block_k, block_k, 2)))
 
 
 def kernel(cfg, plane, q_nope, q_rope, wkv_b, layer, q_off, kv_len, n_real,
-           **blocks):
+           allowed=None, **blocks):
     """The kernel inside the folding `attention_block` does around it."""
     nope = q_nope.shape[-1]
     out = A.latent_prefill_attention(
         M.absorbed_queries(q_nope, q_rope, wkv_b[..., :nope], plane.shape[-1]),
-        plane, jnp.int32(layer), q_off, kv_len, q_off + n_real - 1,
+        plane, jnp.int32(layer), q_off, kv_len, q_off + n_real - 1, allowed,
         value_width=cfg.kv_lora_rank,
         scale=(nope + cfg.qk_rope_head_dim) ** -0.5, interpret=True, **blocks)
     return jnp.einsum("bshc,chd->bshd", out, wkv_b[..., nope:])
@@ -150,9 +156,134 @@ def test_kernel_equals_the_walk(case):
         got[real], want[real], atol=c["tol"], rtol=c["tol"])
 
 
-def test_kernel_per_shard_equals_the_walk():
+# With a selection a query (`allowed`): what the indexer family's chunks
+# and suffixes bring. Index scores drawn on a few levels, so that the
+# threshold is tied; `selection_mask` makes the set as the model does.
+TOPK = 8
+
+SELECTED = {
+    # heads, queries, plane, then what differs from: the kernel's own
+    # blocks, every query real, the past a whole number of key blocks
+    "heads_128_block_q_4": dict(h=128, s=512, s_max=1024, q_off=(384,)),
+    "heads_32_block_q_16": dict(h=32, s=512, s_max=1024, q_off=(384,)),
+    "real_queries_end_mid_tile": dict(q_off=(40, 17), n_real=(21, 5)),
+    "a_row_with_no_real_query": dict(q_off=(40, 17), n_real=(0, S)),
+    "row_shorter_than_topk": dict(q_off=(0, 3), s=8, topk=16, block_q=8),
+    "ties_at_the_threshold": dict(q_off=(40, 17), levels=2),
+    "past_ends_mid_block": dict(q_off=(41, 23), s=24),
+    "kv_len_binds": dict(q_off=(40, 17), kv_short=7),
+    "unequal_rows_layer_2": dict(q_off=(40, 17, 3, 96), layer=2),
+    "bf16": dict(q_off=(40, 17), dtype=jnp.bfloat16, tol=3e-2),
+    "plane_640": dict(q_off=(40, 17), cfg=WIDE, s=16),
+}
+
+
+@pytest.mark.parametrize("case", SELECTED, ids=list(SELECTED))
+def test_kernel_with_a_selection_equals_the_masked_walk(case):
+    c = dict(
+        cfg=TINY, h=None, s=S, s_max=S_MAX, layer=1, n_real=None, kv_short=0,
+        topk=TOPK, levels=4, block_q=BLOCK_Q, block_k=BLOCK_K,
+        dtype=jnp.float32, tol=2e-5,
+    )
+    c.update(SELECTED[case])
+    cfg, s, s_max = c["cfg"], c["s"], c["s_max"]
+    if c["h"]:  # the served head counts: the kernel's own tile
+        cfg = dataclasses.replace(cfg, num_heads=c["h"])
+        c.update(block_q=None, block_k=None)
+    q_off = jnp.asarray(c["q_off"], jnp.int32)
+    n_real = jnp.asarray(c["n_real"] or (s,) * len(q_off), jnp.int32)
+    kv_len = q_off + s - c["kv_short"]
+    plane, q_nope, q_rope, wkv_b = operands(cfg, q_off, s, c["dtype"])
+    plane = jnp.tile(plane, (1, 1, s_max // S_MAX, 1))
+    positions = q_off[:, None] + jnp.arange(s)[None, :]
+    k_pos = jnp.arange(s_max)[None, None, :]
+    seen = (k_pos <= positions[:, :, None]) & (k_pos < kv_len[:, None, None])
+    scores = jnp.where(seen, jax.random.randint(
+        jax.random.PRNGKey(7), seen.shape, 0, c["levels"]).astype(jnp.float32),
+        -jnp.inf)
+    allowed = M.selection_mask(scores, c["topk"])
+    picked, sees = np.asarray(allowed), np.asarray(seen)
+    np.testing.assert_array_equal(
+        picked.sum(-1), np.minimum(sees.sum(-1), c["topk"]))
+    block_k = c["block_k"] or 512
+    by_block = picked.reshape(*picked.shape[:2], -1, block_k).any(-1)
+    if case != "row_shorter_than_topk":
+        # some query sees keys of a block and selected none of them
+        assert (sees.reshape(by_block.shape + (-1,)).any(-1) & ~by_block).any()
+    else:
+        np.testing.assert_array_equal(picked, sees)
+
+    args = (cfg, plane, q_nope, q_rope, wkv_b, c["layer"], q_off, kv_len,
+            n_real)
+    blocks = dict(block_q=c["block_q"], block_k=c["block_k"])
+    got = kernel(*args, allowed, **blocks)
+    # (in float32 on the same rounded operands, as above)
+    want = walked(
+        cfg, *(a.astype(jnp.float32) for a in args[1:5]), *args[5:], allowed,
+        block_k=block_k)
+    assert got.shape == want.shape and got.dtype == q_nope.dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    real = np.arange(s)[None, :] < np.asarray(n_real)[:, None]
+    np.testing.assert_array_equal(got[np.asarray(n_real) == 0], 0.0)
+    assert float(np.abs(want[real]).max()) > 0.1
+    np.testing.assert_allclose(
+        got[real], want[real], atol=c["tol"], rtol=c["tol"])
+    # and the selection binds: the unmasked kernel answers otherwise
+    if case != "row_shorter_than_topk":
+        dense = np.asarray(kernel(*args, **blocks), np.float32)
+        assert np.abs(dense[real] - want[real]).max() > 0.5
+
+
+def pallas_eqn(fn, *args):
+    """The `pallas_call` equation of a traced call."""
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                if (found := find(sub)) is not None:
+                    return found
+    return find(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("selected", [False, True], ids=["without", "with"])
+def test_the_program_without_a_selection_is_the_parents(selected):
+    """32 heads on a 16,384-key plane at the published widths (the
+    kanana cell's chunk): without the operand, the operands, scratch
+    and VMEM figure the kernel had before it took one (numbers from the
+    parent commit, 39237e8); with it, one operand, its slab buffer and
+    its semaphores more."""
+    q = jnp.zeros((1, 512, 32, 640), jnp.bfloat16)
+    plane = jnp.zeros((6, 1, 16384, 640), jnp.bfloat16)
+    one = jnp.zeros((1,), jnp.int32)
+    args = [q, plane, jnp.int32(0), one, one, one]
+    if selected:
+        args.append(jnp.ones((1, 512, 16384), bool))
+    eqn = pallas_eqn(
+        functools.partial(
+            A.latent_prefill_attention, value_width=512, scale=0.1,
+            interpret=True),
+        *args)
+    mapping = eqn.params["grid_mapping"]
+    vmem = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    assert mapping.grid == (1, 32) and mapping.num_index_operands == 4
+    if not selected:
+        assert (len(eqn.invars), mapping.num_scratch_operands, vmem) == (
+            7, 3, 13107200)
+        assert [tuple(v.aval.shape) for v in eqn.invars[4:]] == [
+            (1, 16384, 640), (512, 1), (6, 1, 16384, 640)]
+    else:
+        assert (len(eqn.invars), mapping.num_scratch_operands) == (8, 5)
+        assert eqn.invars[-1].aval.shape == (1, 32, 16, 16384)
+        assert 13107200 < vmem < 16 << 20
+
+
+@pytest.mark.parametrize("selected", [False, True], ids=["all", "selected"])
+def test_kernel_per_shard_equals_the_walk(selected):
     """Rows over `data`, heads over `tensor`, manual over every axis of
-    a data x tensor mesh: each shard walks its rows' whole latents."""
+    a data x tensor mesh: each shard walks its rows' whole latents, and
+    a selection goes with its rows, whole for every shard of heads."""
     mesh = mesh_mod.build_mesh(MeshConfig(data=2, tensor=4))
     q_off = jnp.asarray([40, 17, 3, 96], jnp.int32)
     n_real = jnp.asarray([S, 20, 0, S], jnp.int32)
@@ -163,6 +294,9 @@ def test_kernel_per_shard_equals_the_walk():
         value_width=TINY.kv_lora_rank,
         scale=(nope + TINY.qk_rope_head_dim) ** -0.5, interpret=True,
     )
+    if selected:
+        kw["allowed"] = jax.random.bernoulli(
+            jax.random.PRNGKey(9), 0.3, (4, S, S_MAX))
     got = jax.jit(functools.partial(
         A.latent_prefill_attention_sharded, mesh=mesh, **kw,
     ))(q, plane, jnp.int32(2), q_off, q_off + S, q_off + n_real - 1)
@@ -200,18 +334,6 @@ def test_the_vmem_count_at_the_published_widths():
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture
-def on_tpu(monkeypatch):
-    """What the dispatch sees on the chip, here: the platform answers
-    TPU and the kernel it then picks runs interpreted."""
-    compiled = A.latent_prefill_attention
-    monkeypatch.setattr(A, "_on_tpu", lambda: True)
-    monkeypatch.setattr(
-        A, "latent_prefill_attention",
-        lambda *a, **kw: compiled(*a, **{**kw, "interpret": True}),
-    )
-
-
 def counted(before):
     return {
         key: A.dispatch_counts[key] - before.get(key, 0)
@@ -219,16 +341,18 @@ def counted(before):
     }
 
 
-def dispatch(s=32, h=4, w=256, v=128, rows=2, plane_dtype=jnp.float32, **kw):
+def dispatch(s=32, h=4, w=256, v=128, rows=2, plane_dtype=jnp.float32,
+             s_max=S_MAX, selected=False, **kw):
     """`latent_prefill` on a small plane -> (output or None, the change
     in the kernel and fallback counters)."""
     plane = jax.random.normal(
-        jax.random.PRNGKey(0), (LAYERS, rows, S_MAX, w)).astype(plane_dtype)
+        jax.random.PRNGKey(0), (LAYERS, rows, s_max, w)).astype(plane_dtype)
     q = jax.random.normal(jax.random.PRNGKey(1), (rows, s, h, w))
     q_off = jnp.arange(rows, dtype=jnp.int32) * 9 + 20
     before = dict(A.dispatch_counts)
     out = A.latent_prefill(
         q, plane, jnp.int32(1), q_off, q_off + s, q_off + s - 1,
+        jnp.ones((rows, s, s_max), bool) if selected else None,
         value_width=v, scale=0.1, **kw)
     return out, counted(before)
 
@@ -247,11 +371,16 @@ DISPATCH = {
     "plane_not_lanes": (dict(w=192), NOT_ITS_KIND),
     "value_not_lanes": (dict(v=96), NOT_ITS_KIND),
     "tile_under_a_sublane_group": (dict(s=6, h=4), NOT_ITS_KIND),
+    "with_a_selection": (dict(selected=True), KERNEL),
+    "selection_kernels_off": (dict(selected=True, use_flash=False), FALLBACK),
+    "key_block_of_64": (dict(s_max=64, s=16), KERNEL),
+    "selection_slab_not_lanes": (
+        dict(s_max=64, s=16, selected=True), NOT_ITS_KIND),
 }
 
 
 @pytest.mark.parametrize("case", DISPATCH, ids=list(DISPATCH))
-def test_dispatch_by_platform_storage_and_widths(on_tpu, case):
+def test_dispatch_by_platform_storage_and_widths(latent_prefill_on_tpu, case):
     kw, want = DISPATCH[case]
     out, took = dispatch(**kw)
     assert took == want
@@ -279,7 +408,7 @@ def test_off_the_tpu_nothing_is_wanted_or_counted():
      (dict(tensor=2, data=4), 2, FALLBACK)],
     ids=["heads_over_tensor", "tensor_over_heads", "data_over_rows"],
 )
-def test_dispatch_on_a_mesh(on_tpu, mesh, rows, want):
+def test_dispatch_on_a_mesh(latent_prefill_on_tpu, mesh, rows, want):
     """With the engine's `flash_mesh` the kernel runs per shard; a
     mesh that divides neither the heads nor the rows is a counted
     fallback, as for the prefill kernel."""
@@ -328,7 +457,8 @@ def prefill_then_decode(params, cache, n_prompt=CHUNK + 150, **kw):
     return np.concatenate(out + [np.asarray(logits)[0]])
 
 
-def test_forward_takes_the_kernel_and_matches_the_walk(params, on_tpu):
+def test_forward_takes_the_kernel_and_matches_the_walk(
+        params, latent_prefill_on_tpu):
     cache = llama.KVCache.create(CFG, 1, 512)
     before = dict(A.dispatch_counts)
     got = prefill_then_decode(params, cache)
@@ -356,7 +486,7 @@ KEEPS_THE_WALK = {
 
 
 @pytest.mark.parametrize("case", KEEPS_THE_WALK, ids=list(KEEPS_THE_WALK))
-def test_forward_keeps_the_walk(params, on_tpu, case):
+def test_forward_keeps_the_walk(params, latent_prefill_on_tpu, case):
     """Quantized and float8 planes (the benchmark's `fp8_kv` control
     reads the walk), 128 queries or fewer and a cache-free forward are
     not the kernel's kind: nothing is counted."""
