@@ -2,17 +2,24 @@
 
 The serving sidecar resolves `ServingConfig.model` here. Families:
 "llama" (dense generation), "moe" (Mixtral-style sparse-MoE generation),
-"mla_moe" (latent attention + sigmoid-routed and shared experts), all
-three served by the same engine, and "bert" (embeddings).
+"mla_moe" (latent attention + sigmoid-routed and shared experts; its
+`deepseek_v32` members add q-compression and a sparse-attention indexer
+whose key rides the page's second plane), "keye" (GQA K and V per head +
+the same indexer with its key as a THIRD plane of every page +
+softmax-routed experts, every layer an expert layer), all four served
+by the same engine, and "bert" (embeddings).
 """
 
 from __future__ import annotations
 
 from typing import Any
 
-from ggrmcp_tpu.models import bert, llama, mla_moe, moe
+from ggrmcp_tpu.models import bert, keye, llama, mla_moe, moe
 
-_FAMILIES = {"llama": llama, "moe": moe, "mla_moe": mla_moe, "bert": bert}
+_FAMILIES = {
+    "llama": llama, "moe": moe, "mla_moe": mla_moe, "keye": keye,
+    "bert": bert,
+}
 
 
 def get_model(name: str) -> tuple[str, Any]:
@@ -29,13 +36,15 @@ def available_models() -> list[str]:
 
 
 def family_module(cfg):
-    """The decoder family module (llama, moe or mla_moe) implementing
+    """The decoder family module (llama, moe, mla_moe or keye) implementing
     the shared init_params / param_specs / forward / cache_specs
     contract for `cfg`, told from the config's type. Single dispatch
     point — engines, trainers and the pipeline all resolve the family
     here."""
     if isinstance(cfg, mla_moe.MlaMoeConfig):
         return mla_moe
+    if isinstance(cfg, keye.KeyeConfig):
+        return keye
     return moe if isinstance(cfg, moe.MoEConfig) else llama
 
 

@@ -67,10 +67,13 @@ class LlamaConfig(common.ModelConfig):
 
     @property
     def kv_planes(self) -> tuple:
-        """(heads, width) of what one token keeps in the cache's K
-        plane and in its V plane. A family that caches something else
+        """What one token keeps in each plane of the cache, the shape
+        that follows `[layer, row or page, position]`: here K and V,
+        (heads, width) each. A family that caches something else
         overrides this (models/mla_moe.py: one latent plane, an empty
-        V plane); every cache constructor sizes the planes from it."""
+        or an indexer-key V plane; models/keye.py: K, V and a third
+        plane of indexer keys); every cache constructor sizes the
+        planes from it, two at least."""
         return ((self.num_kv_heads, self.head_dim),) * 2
 
 
@@ -198,8 +201,8 @@ def activation_spec() -> P:
 
 
 def _zero_planes(cfg: LlamaConfig, lead: tuple, kv_dtype: str) -> tuple:
-    """The K and the V plane of an empty cache, `lead + (heads, width)`
-    each as `cfg.kv_planes` sizes them; "int8" = values int8 with
+    """The planes of an empty cache, `lead + plane` each as
+    `cfg.kv_planes` sizes them; "int8" = values int8 with
     per-position/head scales in the model dtype; "fp8" = plain
     float8_e4m3fn planes (4 significant bits, no scales)."""
     if kv_dtype not in ("", "int8", "fp8"):
@@ -215,14 +218,17 @@ def _zero_planes(cfg: LlamaConfig, lead: tuple, kv_dtype: str) -> tuple:
             )
         return jnp.zeros(shape, dtype)
 
-    k_plane, v_plane = cfg.kv_planes
-    return plane(k_plane), plane(v_plane)
+    return tuple(plane(p) for p in cfg.kv_planes)
 
 
 class KVCache(NamedTuple):
     k: jnp.ndarray  # [L, B, S_max, KVH, Dh]
     v: jnp.ndarray  # [L, B, S_max, KVH, Dh]
     length: jnp.ndarray  # [B] int32 — valid prefix length
+    # The planes past the second that `cfg.kv_planes` names (keye: the
+    # indexer keys), same leading axes. Empty for a two-plane family,
+    # whose pytree then has the leaves it always had.
+    extra: tuple = ()
 
     @classmethod
     def create(
@@ -231,9 +237,11 @@ class KVCache(NamedTuple):
         """kv_dtype "" = model dtype; "int8" = quantized KV (values
         int8, per-position/head scales in the model dtype — halves KV
         HBM and decode KV bandwidth; serving.kv_cache_dtype)."""
-        k, v = _zero_planes(
+        k, v, *extra = _zero_planes(
             cfg, (cfg.num_layers, batch, max_len), kv_dtype)
-        return cls(k=k, v=v, length=jnp.zeros((batch,), jnp.int32))
+        return cls(
+            k=k, v=v, length=jnp.zeros((batch,), jnp.int32),
+            extra=tuple(extra))
 
 
 def cache_specs() -> KVCache:
@@ -265,6 +273,7 @@ class PagedKVCache(NamedTuple):
     v: jnp.ndarray
     table: jnp.ndarray  # [B, S_max // page] int32 page ids
     length: jnp.ndarray  # [B] int32 — valid prefix length
+    extra: tuple = ()  # further planes of every page (KVCache.extra)
 
     @classmethod
     def create(
@@ -273,12 +282,13 @@ class PagedKVCache(NamedTuple):
     ) -> "PagedKVCache":
         assert max_len % page_size == 0, "page_size must divide max_len"
         width = max_len // page_size
-        k, v = _zero_planes(
+        k, v, *extra = _zero_planes(
             cfg, (cfg.num_layers, n_pages, page_size), kv_dtype)
         return cls(
             k=k, v=v,
             table=jnp.full((batch, width), n_pages, jnp.int32),
             length=jnp.zeros((batch,), jnp.int32),
+            extra=tuple(extra),
         )
 
 
@@ -287,6 +297,31 @@ def paged_cache_specs() -> PagedKVCache:
     across slots, so the page axis cannot shard over a batch axis."""
     spec = P(None, None, None, "tensor", None)
     return PagedKVCache(k=spec, v=spec, table=P(), length=P())
+
+
+def cache_planes(cache) -> tuple:
+    """Every plane of a KVCache or PagedKVCache, in `cfg.kv_planes`
+    order."""
+    return (cache.k, cache.v, *cache.extra)
+
+
+def with_planes(cache, planes, **fields):
+    """`cache` holding `planes` (as `cache_planes` lists them) and any
+    other field given."""
+    k, v, *extra = planes
+    return cache._replace(k=k, v=v, extra=tuple(extra), **fields)
+
+
+def map_planes(fn, cache, *others, **fields):
+    """`fn(plane, *the others' same plane)` over every plane of
+    `cache`, a quantized plane's values and scales alike (`kv_map`):
+    the one form of every bookkeeping op that indexes the leading
+    [layer, row or page, position] axes only (row merges, page puts,
+    gathers), whatever the planes are and however many."""
+    return with_planes(cache, tuple(
+        kv_map(fn, *each) for each in zip(
+            cache_planes(cache), *(cache_planes(o) for o in others))
+    ), **fields)
 
 
 def paged_view(arena, table: jnp.ndarray, layer: jnp.ndarray):
@@ -331,6 +366,99 @@ def paged_view_layers(arena, table: jnp.ndarray, by_layer: bool = False):
             0, a.shape[0], layer, jnp.zeros((a.shape[0], *shape), a.dtype))
 
     return kv_map(gather, arena)
+
+
+class PlaneIO(NamedTuple):
+    """One layer's writes and reads of the whole, loop-carried cache
+    planes, for the families that walk the cache block of keys by
+    block (`plane_io`)."""
+
+    s_keys: int  # key positions a row of the cache can hold
+    p_sz: int  # tokens a page (1: contiguous)
+    quantized: bool
+    put: Any  # (arena, values [B, S, ...]) -> arena
+    read_block: Any  # (arena, i, row, block) -> [B or 1, block, ...]
+    read_at: Any  # (arena, positions [B, K]) -> [B, K, ...]
+
+
+def plane_io(planes, page_table, layer, cache_len, s: int, dtype) -> PlaneIO:
+    """What an attention block needs of cache planes of any width and
+    number: `[L, B, S_max, ...]` (contiguous) or `[L, N, P, ...]` with
+    `page_table` [B, W] (paged), values or QuantizedArray, written and
+    read at `[layer, ...]` in place. `put` writes this step's `s`
+    tokens of every row at `cache_len`.. (a position past the table's
+    width goes to the sentinel page and is dropped); `read_block`
+    reads keys `i * block`.. of every row, or of row `row` alone;
+    `read_at` reads the tokens at given positions. Reads come back
+    dequantized, in `dtype`."""
+    quantized = isinstance(planes[0], QuantizedArray)
+    ref = planes[0].q if quantized else planes[0]
+    b = cache_len.shape[0]
+    write_pos = cache_len[:, None] + jnp.arange(s)[None, :]  # [B, S]
+    if page_table is not None:
+        n_pg, p_sz = ref.shape[1:3]
+        width = page_table.shape[1]
+        s_keys = width * p_sz
+        w_idx = write_pos // p_sz
+        # Past the table's width is the sentinel, as in attention_block.
+        i0 = jnp.where(
+            w_idx < width,
+            jnp.take_along_axis(
+                page_table, jnp.minimum(w_idx, width - 1), axis=1),
+            n_pg,
+        )
+        i1 = write_pos % p_sz
+    else:
+        p_sz, s_keys = 1, ref.shape[2]
+        i0 = jnp.broadcast_to(jnp.arange(b)[:, None], (b, s))
+        i1 = write_pos
+
+    def write(arena, val):
+        return arena.at[layer, i0, i1].set(
+            val.astype(arena.dtype), mode="drop")
+
+    def put(arena, val):
+        if quantized:
+            return kv_map(write, arena, quantize(val, axis=-1))
+        return write(arena, val)
+
+    def reader(arena, read):
+        blk = kv_map(read, arena)
+        return dequantize(blk) if quantized else blk.astype(dtype)
+
+    def read_block(arena, i, row, block):
+        r0, nb = (0, b) if row is None else (row, 1)
+        if page_table is not None:
+            per = block // p_sz
+            pages = jax.lax.dynamic_slice(
+                page_table, (r0, i * per), (nb, per))
+
+            def read(a):
+                got = a[layer, jnp.minimum(pages, a.shape[1] - 1)]
+                return got.reshape(nb, block, *a.shape[3:])
+        else:
+            def read(a):
+                got = jax.lax.dynamic_slice(
+                    a, (layer, r0, i * block) + (0,) * (a.ndim - 3),
+                    (1, nb, block, *a.shape[3:]))
+                return got.reshape(nb, block, *a.shape[3:])
+
+        return reader(arena, read)
+
+    def read_at(arena, pos):
+        if page_table is not None:
+            pages = jnp.take_along_axis(page_table, pos // p_sz, axis=1)
+
+            def read(a):
+                return a[
+                    layer, jnp.minimum(pages, a.shape[1] - 1), pos % p_sz]
+        else:
+            def read(a):
+                return a[layer, jnp.arange(b)[:, None], pos]
+
+        return reader(arena, read)
+
+    return PlaneIO(s_keys, p_sz, quantized, put, read_block, read_at)
 
 
 # ---------------------------------------------------------------------------

@@ -57,15 +57,12 @@ from ggrmcp_tpu.models.llama import (  # noqa: F401
     LlamaConfig,
     PagedKVCache,
     activation_spec,
+    plane_io,
 )
 from ggrmcp_tpu.ops import attention as attn_ops
-from ggrmcp_tpu.ops.quant import (
-    QuantizedArray,
-    dequantize,
-    embed_lookup,
-    kv_map,
-    quantize,
-)
+from ggrmcp_tpu.ops import indexer
+from ggrmcp_tpu.ops.indexer import index_scores, selection_mask  # noqa: F401
+from ggrmcp_tpu.ops.quant import embed_lookup
 from ggrmcp_tpu.ops.rope import apply_rope, yarn_softmax_gain
 
 Params = common.Params
@@ -152,6 +149,9 @@ class MlaMoeConfig(LlamaConfig):
     expert_ffn_dim: int = 768
     num_shared_experts: int = 2
     routed_scaling: float = 2.448
+    # How the router scores: "sigmoid" (`noaux_tc`, with a selection
+    # bias) or "softmax" (models/keye.py); `route`.
+    router_scoring: str = "sigmoid"
     norm_eps: float = 1e-6
     rope_theta: float = 1e6
     # What `model_type: deepseek_v32` adds; each default is "absent",
@@ -534,95 +534,13 @@ def latent_attention(
 
 
 def indexer_inputs(c_q, normed, lp, cfg: MlaMoeConfig, positions):
-    """What the sparse-attention indexer of a layer makes of the step's
-    tokens: its queries `[B, S, heads, width]` (from the compressed
-    queries `c_q`), ONE key a token `[B, S, width]` (LayerNorm with
-    weight and bias; this is what the cache's second plane keeps) and
-    the heads' weights `[B, S, heads]` float32, already times
-    `heads^-0.5 width^-0.5`. RoPE, with the attention's frequencies,
-    turns the first `qk_rope_head_dim` values of queries and keys, as
-    half-split pairs."""
-    b, s, _ = normed.shape
-    hi, di, rope = cfg.index_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
-    f32 = jnp.float32
-
-    def rot(t):  # [B, S, N, width]
-        head = apply_rope(
-            t[..., :rope], positions, cfg.rope_theta, cfg.rope_scaling)
-        return jnp.concatenate([head, t[..., rope:]], axis=-1)
-
-    q_i = rot((c_q @ lp["idx_wq"]).reshape(b, s, hi, di))
-    k = (normed @ lp["idx_wk"]).astype(f32)
-    k = k - k.mean(-1, keepdims=True)
-    k = k * jax.lax.rsqrt((k * k).mean(-1, keepdims=True) + cfg.norm_eps)
-    k = k * lp["idx_k_norm"].astype(f32) + lp["idx_k_bias"].astype(f32)
-    k_i = rot(k.astype(normed.dtype)[:, :, None])[:, :, 0]
-    w_i = (normed @ lp["idx_ww"]).astype(f32) * (hi**-0.5 * di**-0.5)
-    return q_i, k_i, w_i
-
-
-def index_scores(q_i, w_i, fetch, n_blocks, block: int, s_keys: int,
-                 q_pos, kv_len):
-    """`I[t, s] = sum_j w[t, j] ReLU(q_I[t, j] . k_I[s])` for the
-    step's queries over the cached indexer keys, float32 `[B, S,
-    s_keys]`: reduced over the heads block of keys by block of keys
-    (`fetch(i)` -> `[B, block, width]`), so the `[.., heads, block]`
-    scores of one block are all that ever exists. -inf where a query
-    may not see (after its position `q_pos`, past the row's `kv_len`,
-    in blocks the walk does not reach)."""
-    b, s = q_i.shape[:2]
-
-    def body(i, buf):
-        per_head = jnp.einsum(
-            "bshd,bkd->bshk", q_i, fetch(i),
-            preferred_element_type=jnp.float32)
-        blk = (jax.nn.relu(per_head) * w_i[..., None]).sum(2)
-        return jax.lax.dynamic_update_slice(buf, blk, (0, 0, i * block))
-
-    scores = jax.lax.fori_loop(
-        0, n_blocks, body, jnp.full((b, s, s_keys), -jnp.inf, jnp.float32))
-    k_pos = jnp.arange(s_keys)[None, None, :]
-    return jnp.where(
-        (k_pos <= q_pos[:, :, None]) & (k_pos < kv_len[:, None, None]),
-        scores, -jnp.inf)
-
-
-def selection_mask(scores, topk: int, reach=None):
-    """`[.., s_keys]` bool: each query's `topk` largest scores, ties
-    to the lower position, exactly; every finite score where a query
-    sees fewer (-inf marks what it may not see). `reach` (a traced
-    scalar) promises that keys from it on are all -inf: the sort then
-    runs over the narrowest of a few halved widths that holds the rest
-    (a 512 x 32,768 sort costs eight times a 512 x 4,096 one), and not
-    at all at 0."""
-    def exact(scores):
-        thr = jax.lax.top_k(scores, topk)[0][..., -1:]
-        above = scores > thr
-        tied = (scores == thr) & (scores > -jnp.inf)
-        need = topk - above.sum(-1, keepdims=True)
-        return above | (tied & (jnp.cumsum(tied, axis=-1) <= need))
-
-    if reach is None:
-        return exact(scores)
-    s_keys = scores.shape[-1]
-    widths = [s_keys]
-    while widths[0] // 2 > topk:
-        widths.insert(0, widths[0] // 2)
-
-    def upto(width):
-        def branch(scores):
-            mask = exact(scores[..., :width])
-            return jnp.pad(
-                mask, ((0, 0),) * (mask.ndim - 1) + ((0, s_keys - width),))
-        return branch
-
-    index = jnp.where(
-        reach <= 0, 0,
-        1 + sum((reach > w).astype(jnp.int32) for w in widths[:-1]))
-    return jax.lax.switch(
-        index,
-        [lambda scores: jnp.zeros(scores.shape, bool)]
-        + [upto(w) for w in widths], scores)
+    """`ops.indexer.indexer_inputs` as this family's members call it:
+    queries from the compressed queries `c_q`, RoPE on the first
+    `qk_rope_head_dim` values with the attention's frequencies."""
+    return indexer.indexer_inputs(
+        c_q, normed, lp, positions, heads=cfg.index_heads,
+        width=cfg.index_head_dim, rope_dim=cfg.qk_rope_head_dim,
+        theta=cfg.rope_theta, scaling=cfg.rope_scaling, eps=cfg.norm_eps)
 
 
 def attention_block(
@@ -693,15 +611,7 @@ def attention_block(
     chunk = False  # a prefill chunk over a contiguous plane (below)
     counts = jnp.zeros((3,), jnp.int32)
 
-    def count(ran, real, chosen, scored):
-        """`ran` [B, S]: the queries that selected, `real` those of
-        them that count (`valid`'s rows, or None); `chosen` and `scored`
-        [B, S, n]: the selection's entries that name a key and the keys
-        with an index score."""
-        ran = ran if real is None else ran & real
-        return jnp.stack([
-            (chosen & ran[..., None]).sum(), (scored & ran[..., None]).sum(),
-            ran.sum()]).astype(jnp.int32)
+    count = indexer.selection_counts
 
     if planes is None:
         pad = -s % min(s, 512)
@@ -724,40 +634,12 @@ def attention_block(
 
         fetch, fetch_idx = own(lat), own(k_i) if topk else None
     else:
+        io = plane_io(planes, page_table, layer, cache_len, s, lat.dtype)
+        s_keys, p_sz, read_at = io.s_keys, io.p_sz, io.read_at
         cache_k, cache_v = planes
-        quantized = isinstance(cache_k, QuantizedArray)
-        plane = cache_k.q if quantized else cache_k
-        write_pos = cache_len[:, None] + jnp.arange(s)[None, :]  # [B, S]
-        if page_table is not None:
-            n_pg, p_sz = plane.shape[1:3]
-            width = page_table.shape[1]
-            s_keys = width * p_sz
-            w_idx = write_pos // p_sz
-            # Past the table's width is the sentinel, as in llama.
-            i0 = jnp.where(
-                w_idx < width,
-                jnp.take_along_axis(
-                    page_table, jnp.minimum(w_idx, width - 1), axis=1),
-                n_pg,
-            )
-            i1 = write_pos % p_sz
-        else:
-            p_sz, s_keys = 1, plane.shape[2]
-            i0 = jnp.broadcast_to(jnp.arange(b)[:, None], (b, s))
-            i1 = write_pos
-
-        def write(arena, val):
-            return arena.at[layer, i0, i1].set(
-                val.astype(arena.dtype), mode="drop")
-
-        def put(arena, val):
-            if quantized:
-                return kv_map(write, arena, quantize(val, axis=-1))
-            return write(arena, val)
-
-        cache_k = put(cache_k, lat)
+        cache_k = io.put(cache_k, lat)
         if topk:  # the token's indexer key, in the page of its latent
-            cache_v = put(cache_v, k_i)
+            cache_v = io.put(cache_v, k_i)
         planes = (cache_k, cache_v)
         block = _key_block(b, s, h, s_keys, p_sz)
         kv_len = cache_len + s
@@ -765,45 +647,10 @@ def attention_block(
         n_blocks = jnp.clip(
             (jnp.max(last) + block) // block, 0, s_keys // block)
         chunk = (s > ABSORBED_MAX_QUERIES and page_table is None
-                 and not quantized)
-
-        def reader(arena, read):
-            blk = kv_map(read, arena)
-            return dequantize(blk) if quantized else blk.astype(lat.dtype)
+                 and not io.quantized)
 
         def read_block(arena, i, row):
-            """Keys i * block .. of every row `[B, block, width]`, or
-            of row `row` alone `[1, block, width]`."""
-            r0, nb = (0, b) if row is None else (row, 1)
-            if page_table is not None:
-                per = block // p_sz
-                pages = jax.lax.dynamic_slice(
-                    page_table, (r0, i * per), (nb, per))
-
-                def read(a):
-                    v = a[layer, jnp.minimum(pages, a.shape[1] - 1)]
-                    return v.reshape(nb, block, a.shape[-1])
-            else:
-                def read(a):
-                    v = jax.lax.dynamic_slice(
-                        a, (layer, r0, i * block, 0),
-                        (1, nb, block, a.shape[-1]))
-                    return v.reshape(nb, block, a.shape[-1])
-
-            return reader(arena, read)
-
-        def read_at(arena, pos):  # the tokens at `pos` [B, K]
-            if page_table is not None:
-                pages = jnp.take_along_axis(page_table, pos // p_sz, axis=1)
-
-                def read(a):
-                    return a[
-                        layer, jnp.minimum(pages, a.shape[1] - 1), pos % p_sz]
-            else:
-                def read(a):
-                    return a[layer, jnp.arange(b)[:, None], pos]
-
-            return reader(arena, read)
+            return io.read_block(arena, i, row, block)
 
         def fetch(i, row=None):
             return read_block(cache_k, i, row)
@@ -1011,23 +858,39 @@ def choose_experts(choice, cfg: MlaMoeConfig):
     return jax.lax.top_k(choice, cfg.experts_per_token)[1]
 
 
-def moe_ffn(x, lp, banks, layer, cfg: MlaMoeConfig, valid=None):
-    """Sigmoid `noaux_tc` router: choose by `s + b` (group-limited
-    where the model has groups), weigh by `s`; routed experts plus the
-    shared experts on every token."""
-    b, s, d = x.shape
-    xt = x.reshape(b * s, d)
-    scores = jax.nn.sigmoid(xt.astype(jnp.float32) @ lp["router"])
-    idx = choose_experts(scores + lp["router_bias"], cfg)
+def route(xt, lp, cfg):
+    """Each token's experts `[T, k]` and their float32 weights, by the
+    config's `router_scoring`. "sigmoid" (`noaux_tc`): `s =
+    sigmoid(x W_r)`, chosen by `s + b` (group-limited where the model
+    has groups), weighed by `s`. "softmax": `p = softmax(x W_r)` over
+    every expert, the top k of `p`, no bias. Either way the chosen
+    weights are normalised to sum to 1 and scaled by
+    `routed_scaling`."""
+    logits = xt.astype(jnp.float32) @ lp["router"]
+    if cfg.router_scoring == "softmax":
+        scores = choice = jax.nn.softmax(logits, axis=-1)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        choice = scores + lp["router_bias"]
+    idx = choose_experts(choice, cfg)
     weight = jnp.take_along_axis(scores, idx, axis=-1)
     weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
-    weight = weight * cfg.routed_scaling
-    routed, stats = routed_experts(
+    return idx, weight * cfg.routed_scaling
+
+
+def moe_ffn(x, lp, banks, layer, cfg, valid=None):
+    """The routed experts of every token (`route`, `routed_experts`),
+    plus the shared experts where the model has any."""
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    idx, weight = route(xt, lp, cfg)
+    out, stats = routed_experts(
         xt, idx, weight, None if valid is None else valid.reshape(b * s),
         banks, layer, cfg,
     )
-    shared = _swiglu(xt, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
-    return (routed + shared).reshape(b, s, d), stats
+    if cfg.num_shared_experts:
+        out = out + _swiglu(xt, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+    return out.reshape(b, s, d), stats
 
 
 # ---------------------------------------------------------------------------

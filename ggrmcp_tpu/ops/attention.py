@@ -931,7 +931,7 @@ def latent_prefill_attention(
     # none): queries past it are padding, and their output is undefined
     # (finite)
     allowed: Optional[jnp.ndarray] = None,  # [B, S, S_max] bool — each
-    # query's selection (`mla_moe.selection_mask`); None: it sees all
+    # query's selection (`ops.indexer.selection_mask`); None: it sees all
     *,
     value_width: int,  # a key's first `value_width` columns are its value
     scale: float,

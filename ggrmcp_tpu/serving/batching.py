@@ -152,11 +152,8 @@ def _merge_row(cache, mini, slot, length):
             c, m.astype(c.dtype), (0, slot, 0, 0, 0)
         )
 
-    return llama_mod.KVCache(
-        k=quant.kv_map(merge, cache.k, mini.k),
-        v=quant.kv_map(merge, cache.v, mini.v),
-        length=cache.length.at[slot].set(length),
-    )
+    return llama_mod.map_planes(
+        merge, cache, mini, length=cache.length.at[slot].set(length))
 
 
 @dataclasses.dataclass
@@ -825,7 +822,8 @@ class ContinuousBatcher:
         self._ledger_scope = ledger_scope
         engine.ledger.register(
             "kv_arena",
-            lambda: (self.cache.k, self.cache.v, self.cache.length),
+            lambda: (
+                *llama_mod.cache_planes(self.cache), self.cache.length),
             scope=ledger_scope,
         )
         engine.ledger.register(
@@ -970,12 +968,8 @@ class ContinuousBatcher:
 
             return jax.lax.fori_loop(0, a.shape[0], layer, a)
 
-        k = quant.kv_map(put, cache.k, mini.k)
-        v = quant.kv_map(put, cache.v, mini.v)
         length = cache.length.at[slots].set(true_len, mode="drop")
-        return llama_mod.PagedKVCache(
-            k=k, v=v, table=cache.table, length=length
-        )
+        return llama_mod.map_planes(put, cache, mini, length=length)
 
     # -- KV page export/import (sidecar→sidecar TransferKV plane) -----------
 
@@ -1320,10 +1314,9 @@ class ContinuousBatcher:
                 jnp.where(sel, m.astype(c.dtype), c[:, :, :s])
             )
 
-        k = quant.kv_map(select, cache.k, mini.k)
-        v = quant.kv_map(select, cache.v, mini.v)
         lengths = jnp.where(valid, true_len, cache.length)
-        return first, llama_mod.KVCache(k=k, v=v, length=lengths)
+        return first, llama_mod.map_planes(
+            select, cache, mini, length=lengths)
 
     def _chunked_scan(self, params, tokens, true_len, mini, adapters, start):
         """lax.scan over a [B, T, C] chunk grid: each step extends
@@ -1388,10 +1381,8 @@ class ContinuousBatcher:
         def put(c_, m):
             return c_.at[:, slots].set(m.astype(c_.dtype), mode="drop")
 
-        k = quant.kv_map(put, cache.k, mini.k)
-        v = quant.kv_map(put, cache.v, mini.v)
         lengths = cache.length.at[slots].set(true_len, mode="drop")
-        return first, llama_mod.KVCache(k=k, v=v, length=lengths)
+        return first, llama_mod.map_planes(put, cache, mini, length=lengths)
 
     def _admit_chunked_impl(
         self, params, tokens, true_len, cache, slots, seeds, temps, ks,
@@ -1429,13 +1420,11 @@ class ContinuousBatcher:
         slot's fresh divergent page instead of recomputing it. One
         device call admits a whole same-preamble wave."""
         r = tokens.shape[0]
-        mini = llama_mod.KVCache(
-            k=llama_mod.paged_view_layers(
-                cache.k, gtables, self._arena_by_layer),
-            v=llama_mod.paged_view_layers(
-                cache.v, gtables, self._arena_by_layer),
-            length=jnp.broadcast_to(scan_start, (r,)).astype(jnp.int32),
-        )
+        mini = llama_mod.with_planes(
+            llama_mod.KVCache(
+                None, None, jnp.broadcast_to(scan_start, (r,)).astype(jnp.int32)),
+            [llama_mod.paged_view_layers(plane, gtables, self._arena_by_layer)
+             for plane in llama_mod.cache_planes(cache)])
         fl, mini = self._chunked_scan(
             params, tokens, true_len, mini, adapters, scan_start
         )
@@ -1738,11 +1727,8 @@ class ContinuousBatcher:
         def pick(m):
             return jax.lax.dynamic_slice_in_dim(m, row, 1, axis=1)
 
-        picked = llama_mod.KVCache(
-            k=quant.kv_map(pick, mini.k),
-            v=quant.kv_map(pick, mini.v),
-            length=jnp.full((1,), n, jnp.int32),
-        )
+        picked = llama_mod.map_planes(
+            pick, mini, length=jnp.full((1,), n, jnp.int32))
         if self._paged:
             cache = self._paged_put(
                 cache, picked, jnp.reshape(slot, (1,)),
@@ -2411,13 +2397,16 @@ class ContinuousBatcher:
         """KV-cache HBM: the shared slot pool (or paged arena + block
         tables) and the interleave mini cache (K admission rows) once
         allocated."""
-        total = self.cache.k.nbytes + self.cache.v.nbytes
+        def planes(cache):
+            return sum(p.nbytes for p in llama_mod.cache_planes(cache))
+
+        total = planes(self.cache)
         if self._paged:
             total += self.cache.table.nbytes
         if self._ilv_mini is not None:
-            total += self._ilv_mini.k.nbytes + self._ilv_mini.v.nbytes
+            total += planes(self._ilv_mini)
         if self.dcache is not None:
-            total += self.dcache.k.nbytes + self.dcache.v.nbytes
+            total += planes(self.dcache)
         return total
 
     def stall_snapshot(self) -> list[float]:

@@ -33,7 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ggrmcp_tpu.core.config import ServingConfig
 from ggrmcp_tpu.models import bert as bert_mod
 from ggrmcp_tpu.models import llama as llama_mod
-from ggrmcp_tpu.models import mla_moe as mla_moe_mod
 from ggrmcp_tpu.models import moe as moe_mod
 from ggrmcp_tpu.models.common import count_params
 from ggrmcp_tpu.ops import quant
@@ -129,9 +128,14 @@ _LATENT_CACHE = (
     "these paths move K/V pairs of [kv_heads, head_dim]; this family "
     "caches one latent plane"
 )
+_THREE_PLANES = (
+    "the page payload and the tier snapshot hold a K and a V plane; "
+    "this family's page has a third, the indexer's keys"
+)
 _FP8_CACHE = (
-    "a float8 plane is read by the latent family's block walk only; "
-    "these families' attention reads int8 pages through their scales"
+    "a float8 plane is read by the latent and keye families' block "
+    "walks only; these families' attention reads int8 pages through "
+    "their scales"
 )
 _MESH_WITH_INDEXER = (
     "a mesh of more than one device, for a model with a "
@@ -183,6 +187,30 @@ _UNSUPPORTED = {
             "the chip computes its own experts' part of a layer and "
             "the exchange of tokens and partial sums over a mesh axis "
             "is not built; on one chip the layer runs without it"
+        ),
+    },
+    "keye": {
+        "lora": "the adapter delta sits on the dense family's fused qkv",
+        "pipeline-parallel serving (mesh.stage > 1)": (
+            "the staged forward threads two cache planes through the "
+            "dense layer; this family's layer has three and experts"
+        ),
+        "speculative decoding (speculative_draft)": (
+            "the verify window would have to rewind three-plane pages "
+            "and a window of queries has a selection each"
+        ),
+        "kv_ring": "the model has no sliding window",
+        "batching.kv_tiers": _THREE_PLANES,
+        "batching.paged_kv_host_bytes (the host tier)": _THREE_PLANES,
+        "a non-mixed serving.role (KV export/import)": _THREE_PLANES,
+        "quantize / synthetic_weights": (
+            "the weights are served in bf16; int8 matmuls are wired "
+            "into the dense family's projections only"
+        ),
+        _MESH_WITH_INDEXER: (
+            "a row's top-k runs over its whole indexer plane and the "
+            "selected K and V are gathered by token index; neither is "
+            "built for a sharded cache"
         ),
     },
 }
@@ -834,7 +862,7 @@ class GenerationEngine:
             return self._pp.pipeline_forward_cached(
                 params, self.cfg, tokens, cache, self.mesh, ring=ring
             )
-        if self.fam is mla_moe_mod:
+        if getattr(self.fam, "HEAD_AT_INDEX", False):  # mla_moe, keye
             return self.fam.forward(
                 params, self.cfg, tokens, cache, valid=valid,
                 logit_idx=logit_idx, with_stats=with_stats,
@@ -1126,10 +1154,10 @@ class GenerationEngine:
                 scale=mesh_mod.compatible_spec(spec, scale_shape, self.mesh),
             )
 
-        k_plane, v_plane = cfg.kv_planes
-        specs = llama_mod.KVCache(
-            k=kv_spec(specs.k, k_plane),
-            v=kv_spec(specs.v, v_plane),
+        specs = llama_mod.with_planes(
+            specs,
+            [kv_spec(spec, plane) for spec, plane in zip(
+                llama_mod.cache_planes(specs), cfg.kv_planes)],
             length=mesh_mod.compatible_spec(specs.length, (batch,), self.mesh),
         )
         with self.mesh:
@@ -1177,11 +1205,9 @@ class GenerationEngine:
                 ),
             )
 
-        k_plane, v_plane = self.cfg.kv_planes
-        specs = llama_mod.PagedKVCache(
-            k=kv_spec(raw.k, k_plane), v=kv_spec(raw.v, v_plane),
-            table=raw.table, length=raw.length,
-        )
+        specs = llama_mod.with_planes(raw, [
+            kv_spec(spec, plane) for spec, plane in zip(
+                llama_mod.cache_planes(raw), self.cfg.kv_planes)])
         with self.mesh:
             return jax.jit(
                 partial(

@@ -104,12 +104,17 @@ class TestChipSmoke:
         result = json.loads(lines[-1])
         assert result["ok"] is True and result["rehearsal"] is True
         assert result["device"]["platform"] == "cpu"
-        assert "all legs passed: kernel,sparse,serve,default_kv" in proc.stdout
+        assert "all legs passed: kernel,sparse,keye,serve,default_kv" in proc.stdout
         # The sparse leg held one expert layer of the tiny deepseek_v32
         # member, a sparse chunk and a sparse decode step, to the
         # float32 reference layer, and saw the selection bind.
         assert "sparse chunk (16 of 65..80 keys)" in proc.stdout
         assert "sparse decode step (16 of 81..81 keys)" in proc.stdout
+        # The keye leg the same for one layer of the tiny keye member,
+        # and both of its sparse paths ran.
+        assert "keye leg ok" in proc.stdout
+        assert "sparse_gqa_chunk 1, sparse_gqa_decode 1" in proc.stdout
+        assert proc.stdout.count("sparse chunk (16 of 65..80 keys)") == 3
         assert "paged vs contiguous: first 8 of 8 ids agree" in proc.stdout
         # The kernel leg walked both kernels, the paged-decode one with
         # and without a window that binds.
