@@ -445,8 +445,9 @@ def attention_block(
     elif topk and s_keys > topk:
         # A chunk or a suffix: a selection a query, `[S, s_keys]` bool,
         # the set the decode step gathers; made and walked a row at a
-        # time, each to its own last key, so the index scores, the
-        # sort's temporaries and the score blocks stay one row's.
+        # time, each to its own last key, so the index scores, their
+        # image under the selection's passes and the score blocks stay
+        # one row's.
         attn_ops.dispatch_counts["sparse_gqa_chunk"] += 1
 
         def row_attention(row):

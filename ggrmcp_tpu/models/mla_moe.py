@@ -126,8 +126,9 @@ def admission_rows(cfg) -> Optional[int]:
     """Rows one admission call over a full-width mini cache may take
     (the batcher asks; None = the whole pool). A model with an indexer
     takes one: its chunk attention selects a row at a time anyway
-    (index scores, a sort and a [queries, keys] selection are a row's
-    worth of memory each), so a group saves no pass over the weights
+    (index scores, their image under the selection's passes and a
+    [queries, keys] selection are a row's worth of memory each), so a
+    group saves no pass over the weights
     worth its rows x 0.25 GB of mini cache beside 11.4 GB resident
     (PERF.md, PR 33)."""
     return 1 if cfg.index_topk else None
@@ -696,8 +697,8 @@ def attention_block(
     elif topk and s_keys > topk:
         # A chunk or a suffix: a selection a query, [S, s_keys] bool,
         # the same set the decode step gathers. Selected a row at a
-        # time, each to its own last key: the index scores and the
-        # sort's temporaries stay one row's.
+        # time, each to its own last key: the index scores and their
+        # image under the selection's passes stay one row's.
         attn_ops.dispatch_counts["sparse_chunk"] += 1
 
         def cut(t, row):
@@ -711,8 +712,8 @@ def attention_block(
             scores = index_scores(
                 cut(q_i, row), cut(w_i, row), lambda i: fetch_idx(i, row),
                 n_row, block, s_keys, cut(positions, row), cut(kv_len, row))
-            # sorted as far as the row's keys reach; a padding chunk
-            # of a deep grid (no block to walk) sorts nothing
+            # counted as far as the row's keys reach; a padding chunk
+            # of a deep grid (no block to walk) selects nothing
             mask = selection_mask(scores, topk, reach=n_row * block)
             scored = scores > -jnp.inf
             return mask, n_row, count(

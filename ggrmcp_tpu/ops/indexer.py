@@ -68,16 +68,44 @@ def index_scores(q_i, w_i, fetch, n_blocks, block: int, s_keys: int,
         scores, -jnp.inf)
 
 
+def kth_largest(scores, k: int):
+    """The `k`-th largest value of the last axis, float32 `[.., 1]`,
+    exactly and without a sort: bisection over the unsigned image of
+    the scores that keeps their order (every bit of a negative flipped,
+    the sign bit of the others set; -inf lies below every finite one).
+    From the top bit down, a bit stays set where `k` keys still reach
+    the value so far with it: 32 compare-and-count reads of the row in
+    place of a sort of it (on the chip 1.2 ms against 18.2 for 512 x
+    32,768: PERF.md, PR 38; two or four bits a read were no faster).
+    Of a -0.0 and a +0.0 that tie either may come back; a row with
+    fewer than `k` finite scores gives -inf."""
+    u32 = jnp.uint32
+    bits = jax.lax.bitcast_convert_type(scores, u32)
+    sign = u32(1 << 31)
+    key = jnp.where(bits >= sign, ~bits, bits | sign)
+
+    def settle(i, cut):  # cut [.., 1]: the bits above 31 - i are final
+        higher = cut | (sign >> i.astype(u32))
+        reached = (key >= higher).sum(-1, keepdims=True) >= k
+        return jnp.where(reached, higher, cut)
+
+    cut = jax.lax.fori_loop(
+        0, 32, settle, jnp.zeros(scores.shape[:-1] + (1,), u32))
+    return jax.lax.bitcast_convert_type(
+        jnp.where(cut >= sign, cut ^ sign, ~cut), jnp.float32)
+
+
 def selection_mask(scores, topk: int, reach=None):
     """`[.., s_keys]` bool: each query's `topk` largest scores, ties
     to the lower position, exactly; every finite score where a query
-    sees fewer (-inf marks what it may not see). `reach` (a traced
-    scalar) promises that keys from it on are all -inf: the sort then
-    runs over the narrowest of a few halved widths that holds the rest
-    (a 512 x 32,768 sort costs eight times a 512 x 4,096 one), and not
-    at all at 0."""
+    sees fewer (-inf marks what it may not see). The cut is the
+    `topk`-th largest score (`kth_largest`: counted, not sorted).
+    `reach` (a traced scalar) promises that keys from it on are all
+    -inf: the passes then read the narrowest of a few halved widths
+    that holds the rest (their cost is the width's), and nothing runs
+    at 0."""
     def exact(scores):
-        thr = jax.lax.top_k(scores, topk)[0][..., -1:]
+        thr = kth_largest(scores, topk)
         above = scores > thr
         tied = (scores == thr) & (scores > -jnp.inf)
         need = topk - above.sum(-1, keepdims=True)

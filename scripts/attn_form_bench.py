@@ -19,8 +19,14 @@ each query its 2,048 selected keys, a 32,768-key plane): the walk
 masked by the selection, as `attention_block` runs it where the kernel
 is not the call's kind (expanded, to the last real query's key), against
 the kernel given the selection, for a suffix of 250 real queries in
-the chunk of 512 and for a full chunk. The selection is an input here
-(random index scores through `selection_mask`): making it is not timed.
+the chunk of 512 and for a full chunk. The selection is an input
+there (random index scores through `selection_mask`).
+
+Last, making that selection (`ops/indexer.selection_mask`, the cut
+found by counting) beside the same four lines with the cut read off
+`lax.top_k`, which the chip sorts the whole row for: the cut alone and
+the whole mask, float32 index scores of 512 or 256 queries that see
+every key of the width.
 """
 
 from __future__ import annotations
@@ -65,7 +71,61 @@ def main() -> int:
     )
     for name, s_max, cases in (plain, sparse):
         bench(args, M.CONFIGS[name], s_max, cases)
+    bench_selection(args, *(
+        (16, [(64, 128), (64, 256)]) if args.cpu else
+        (2048, [(512, 4096), (512, 16384), (512, 32768), (256, 32768)])))
     return 0
+
+
+def median_ms(fn, operands, reps: int) -> float:
+    """Of a program its caller has already run once (compiled)."""
+    import jax
+
+    times = []
+    for _ in range(reps):
+        t = time.perf_counter()
+        jax.block_until_ready(fn(*operands))
+        times.append((time.perf_counter() - t) * 1000.0)
+    return statistics.median(times)
+
+
+def bench_selection(args, topk: int, cases) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from ggrmcp_tpu.ops import indexer
+
+    def sorted_cut(scores, k):
+        return jax.lax.top_k(scores, k)[0][..., -1:]
+
+    def sorted_mask(scores, k):  # selection_mask as it was with the sort
+        thr = sorted_cut(scores, k)
+        above = scores > thr
+        tied = (scores == thr) & (scores > -jnp.inf)
+        need = k - above.sum(-1, keepdims=True)
+        return above | (tied & (jnp.cumsum(tied, axis=-1) <= need))
+
+    forms = {
+        "cut, lax.top_k": sorted_cut,
+        "cut, counted": indexer.kth_largest,
+        "mask, lax.top_k": sorted_mask,
+        "mask, counted": indexer.selection_mask,
+    }
+    print(f"device {jax.devices()[0].device_kind}, the selection of "
+          f"{topk} keys a query")
+    for s, width in cases:
+        scores = jnp.round(jax.random.normal(
+            jax.random.PRNGKey(width + s), (1, s, width), jnp.float32), 3)
+        got = {}
+        for form, run in forms.items():
+            fn = jax.jit(lambda scores, run=run: run(scores, topk))
+            got[form] = jax.block_until_ready(fn(scores))
+            print(f"queries {s:4d} keys {width:6d} {form}: "
+                  f"{median_ms(fn, (scores,), args.reps):8.3f} ms",
+                  flush=True)
+        for kind in ("cut", "mask"):
+            assert bool((got[f"{kind}, lax.top_k"]
+                         == got[f"{kind}, counted"]).all()), (kind, s, width)
 
 
 def bench(args, cfg, s_max: int, cases) -> None:
@@ -130,11 +190,7 @@ def bench(args, cfg, s_max: int, cases) -> None:
             out = jax.block_until_ready(fn(*operands))[:, :n_real or s]
             if form == "absorbed":
                 want = out.astype(jnp.float32)
-            times = []
-            for _ in range(args.reps):
-                t = time.perf_counter()
-                jax.block_until_ready(fn(*operands))
-                times.append((time.perf_counter() - t) * 1000.0)
+            ms = median_ms(fn, operands, args.reps)
             diff = (
                 f"  max|kernel - absorbed| "
                 f"{float(jnp.abs(out.astype(jnp.float32) - want).max()):.4f}"
@@ -143,7 +199,7 @@ def bench(args, cfg, s_max: int, cases) -> None:
             real = f" ({n_real} real, selected)" if n_real else ""
             print(f"rows {rows:2d} queries {s:4d}{real} past {past:6d} "
                   f"block {block:4d} {form}: "
-                  f"{statistics.median(times):8.3f} ms{diff}", flush=True)
+                  f"{ms:8.3f} ms{diff}", flush=True)
 
 
 if __name__ == "__main__":
