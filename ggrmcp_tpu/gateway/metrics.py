@@ -56,12 +56,6 @@ _SERVING_HELP = {
     "prefix_cache_hits": "prefix cache hits",
     "prefix_cache_misses": "prefix cache misses",
     "decode_steps": "fused decode steps issued",
-    # No writer: the proto keeps the four field numbers, the gauges
-    # read 0 (the spec tick reports through spec_* below).
-    "speculative_calls": "reserved, reads 0",
-    "speculative_requests": "reserved, reads 0",
-    "speculative_drafted": "reserved, reads 0",
-    "speculative_accepted": "reserved, reads 0",
     "ticks": "decode ticks dispatched",
     "tick_collects": "decode tick token collects",
     "admit_rounds": "admission rounds run",
@@ -206,7 +200,6 @@ _SERVING_HELP = {
         "ledger: paged per-slot device block-table bytes",
     "memory_draft_cache_bytes":
         "ledger: speculative draft slot-pool KV bytes",
-    "memory_prefix_pool_bytes": "reserved, reads 0 (no such component)",
     "memory_ilv_mini_bytes":
         "ledger: interleaved-admission mini-cache bytes",
     "memory_grammar_arena_bytes":
@@ -321,6 +314,15 @@ _SERVING_HIST_HELP = {
     "prefill_ms":
         "queue wait, second half: that pop to slot activation — the "
         "executor hand-off plus the admission program (ms)",
+    "admit_device_ms":
+        "one admission program alone on the device: from the tick in "
+        "flight leaving it to the program's first tokens on the host "
+        "(ms), one observation per program call",
+    "admit_host_ms":
+        "host work of one admission round: building the call, "
+        "enqueueing it and activating the slots (ms); the admit phase "
+        "less this and admit_device_ms is the wait for the tick in "
+        "flight",
     "tick_duration_ms": "decode tick dispatch-to-collect latency (ms)",
     "tick_phase_admit_ms": "per-tick admit-phase time (ms)",
     "tick_phase_sync_ms": "per-tick host-state-sync time (ms)",

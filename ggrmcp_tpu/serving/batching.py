@@ -720,6 +720,11 @@ class ContinuousBatcher:
         # by the admission paths, read into its AdmissionRecord.
         self._adm_families: list[str] = []
         self._adm_reused = 0
+        # The round's own clock and what its profiler spans carry
+        # (_prefill_into_slots opens both; _admission_program and the
+        # activation loops mark and annotate where the work happens).
+        self._adm_timer = PhaseTimer()
+        self._adm_span: dict = {}
 
         # jitted: one decode tick for the whole slot pool (params ride
         # as an argument — a closed-over weight tree would be lowered
@@ -3388,12 +3393,20 @@ class ContinuousBatcher:
         round's PhaseTimer gives its duration to the next tick's admit
         phase, the per-row cost EMA and the round's AdmissionRecord
         (family that ran, rows, tokens, trace ids, the tick it
-        precedes). While a profile capture runs the round is also a
-        `ggrmcp.admit` span in the profiler's own trace."""
-        timer = PhaseTimer()
+        precedes). The timer is marked from inside, by every admission
+        program call (_admission_program: build / launch / tick_wait /
+        device) and activation loop (activate), so the record also says
+        how much of the round was host work, how much the wait for the
+        tick in flight and how much the admission programs alone on
+        the device. While a profile capture runs the round is also a
+        `ggrmcp.admit` span in the profiler's own trace, with a
+        `ggrmcp.admit.program` / `.device` / `.activate` child for each
+        of those."""
+        timer = self._adm_timer = PhaseTimer()
         seq = self.timing["admit_rounds"] + 1
         tick_seq = self.timing["ticks"] + 1
-        with tracing.annotation("ggrmcp.admit", seq=seq, tick=tick_seq):
+        self._adm_span = {"seq": seq, "tick": tick_seq}
+        with tracing.annotation("ggrmcp.admit", **self._adm_span):
             # Chaos hooks: admission latency (admit_slow, arm with ms=)
             # and admission failure (admit_fail) — the latter exercises
             # _admit's blast-radius-scaled batch-failure handling.
@@ -3408,7 +3421,12 @@ class ContinuousBatcher:
                     return
             self._adm_families, self._adm_reused = [], 0
             queued, shed_rows = self._route_admission(slots_idx, batch)
-        dt = timer.mark("admit")
+        # What is left after the last activation loop is the draft-side
+        # admission (spec mode) and the way out; a round that launched
+        # nothing (every row queued for tick-fused chunks, or shed) was
+        # building all along.
+        timer.mark("activate" if "device" in timer.acc else "build")
+        dt = (timer.last - timer.t0) * 1000.0
         self.timing["admit_rounds"] = seq
         # Phase attribution: this round's executor time seeds the NEXT
         # tick record's admit phase (queue drain + admission prefill
@@ -3442,6 +3460,74 @@ class ContinuousBatcher:
         if not self._adm_families or self._adm_families[-1] != family:
             self._adm_families.append(family)
         self._adm_reused += reused_tokens
+
+    def _admission_program(
+        self, launch, family: str, rows: int, chunks: int, tokens: int
+    ) -> np.ndarray:
+        """Run ONE admission program and bring each row's first token
+        to the host. `launch()` makes the jitted call, which donates
+        the shared cache, and returns (first, cache). The round's timer
+        is marked where each thing happens (flight_recorder.
+        ADMIT_HOST_MARKS): `build` closes here (the caller's numpy
+        grids and grammar tables, the table sync), `launch` when the
+        jitted call returns (argument transfer + enqueue), `tick_wait`
+        when the tick dispatched before this round has left the device
+        (_await_tick_in_flight), `device` when `first` is on the host —
+        the admission program alone on the device, plus that copy.
+        While a capture runs the call is a `ggrmcp.admit.program` span
+        (launch → first on the host) around a `ggrmcp.admit.device`
+        one, both carrying the round's seq and the tick it precedes."""
+        timer = self._adm_timer
+        self._sync_tables()
+        self._cache_at_risk = True
+        timer.mark("build")
+        with tracing.annotation(
+            "ggrmcp.admit.program", family=family, rows=rows,
+            chunks=chunks, tokens=tokens, **self._adm_span,
+        ):
+            first, self.cache = launch()
+            timer.mark("launch")
+            self._await_tick_in_flight()
+            with tracing.annotation("ggrmcp.admit.device", **self._adm_span):
+                # Materialize BEFORE clearing the at-risk flag: under
+                # async dispatch a device failure in the donating call
+                # surfaces here, and the handler must still see the
+                # cache as possibly dead.
+                first = np.asarray(first)
+            timer.mark("device")
+        self._cache_at_risk = False
+        return first
+
+    def _await_tick_in_flight(self) -> None:
+        """On a pipelined loop the tick dispatched one turn earlier is
+        still on the device when an admission program is launched, and
+        the program queues behind it: wait here until that tick has
+        left the device and mark the time `tick_wait`, so that what
+        follows is the admission program alone. Nothing is consumed,
+        collected or reordered — the thread would block as long in
+        np.asarray(first), and the tick's collect then finds its array
+        ready. Once a round: a second program call finds the device
+        already its own. The newest tick in flight is the one waited
+        for (the loop keeps at most one; the device runs them in
+        order)."""
+        if "tick_wait" in self._adm_timer.acc or not self._inflight:
+            return
+        try:
+            jax.block_until_ready(self._inflight[-1][0])
+        except Exception:  # noqa: BLE001 — the tick's own failure is
+            # raised by its collect, to the handler that owns it
+            # (_loop's replay), not to this round's.
+            pass
+        self._adm_timer.mark("tick_wait")
+
+    def _activate_rows(self, rows: list) -> None:
+        """Activate (slot, request, first token) rows: the round's
+        `activate` segment, a `ggrmcp.admit.activate` span while a
+        capture runs."""
+        with tracing.annotation("ggrmcp.admit.activate", **self._adm_span):
+            for sl, req, tok in rows:
+                self._activate_slot(sl, req, tok)
+        self._adm_timer.mark("activate")
 
     def _route_admission(
         self, slots_idx: list[int], batch: list[_Request]
@@ -3640,21 +3726,20 @@ class ContinuousBatcher:
             g0s[j] = self._g0(req)
         self._admission_ran("chunked")
         g_allow, g_trans = self._grammar_tables()
-        self._sync_tables()
-        self._cache_at_risk = True
-        first, self.cache = self._admit_chunked(
-            self.engine.params, jnp.asarray(tokens),
-            jnp.asarray(true_len), self.cache, jnp.asarray(slots_arr),
-            jnp.asarray(seeds), jnp.asarray(temps), jnp.asarray(ks),
-            jnp.asarray(ps), jnp.asarray(adapters),
-            jnp.asarray(g0s), g_allow, g_trans,
+        first = self._admission_program(
+            lambda: self._admit_chunked(
+                self.engine.params, jnp.asarray(tokens),
+                jnp.asarray(true_len), self.cache, jnp.asarray(slots_arr),
+                jnp.asarray(seeds), jnp.asarray(temps), jnp.asarray(ks),
+                jnp.asarray(ps), jnp.asarray(adapters),
+                jnp.asarray(g0s), g_allow, g_trans,
+            ),
+            "chunked", rows=len(rows), chunks=t_steps,
+            tokens=int(true_len.sum()),
         )
-        # Materialize BEFORE clearing the at-risk flag (async-dispatch
-        # failure surfacing — same contract as _prefill_fused).
-        first = np.asarray(first)
-        self._cache_at_risk = False
-        for j, (sl, req) in enumerate(rows):
-            self._activate_slot(sl, req, int(first[j]))
+        self._activate_rows(
+            [(sl, req, int(first[j])) for j, (sl, req) in enumerate(rows)]
+        )
 
     def _admit_paged_group(
         self,
@@ -3696,20 +3781,21 @@ class ContinuousBatcher:
             g0s[j] = self._g0(req)
         self._admission_ran("paged_pfx", scan_start * len(rows))
         g_allow, g_trans = self._grammar_tables()
-        self._sync_tables()
-        self._cache_at_risk = True
-        first, self.cache = self._admit_paged_pfx(
-            self.engine.params, jnp.asarray(tokens),
-            jnp.asarray(true_len), self.cache, jnp.asarray(slots_arr),
-            jnp.asarray(gtables), jnp.int32(scan_start),
-            jnp.int32(merge_start), jnp.asarray(seeds),
-            jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(ps),
-            jnp.asarray(adapters), jnp.asarray(g0s), g_allow, g_trans,
+        first = self._admission_program(
+            lambda: self._admit_paged_pfx(
+                self.engine.params, jnp.asarray(tokens),
+                jnp.asarray(true_len), self.cache, jnp.asarray(slots_arr),
+                jnp.asarray(gtables), jnp.int32(scan_start),
+                jnp.int32(merge_start), jnp.asarray(seeds),
+                jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(ps),
+                jnp.asarray(adapters), jnp.asarray(g0s), g_allow, g_trans,
+            ),
+            "paged_pfx", rows=len(rows), chunks=t_steps,
+            tokens=int(true_len.sum()) - scan_start * len(rows),
         )
-        first = np.asarray(first)
-        self._cache_at_risk = False
-        for j, (sl, req, adm) in enumerate(rows):
-            self._activate_slot(sl, req, int(first[j]))
+        self._activate_rows(
+            [(sl, req, int(first[j])) for j, (sl, req, _) in enumerate(rows)]
+        )
 
     def _prefill_fused(
         self, slots_idx: list[int], batch: list[_Request]
@@ -3749,34 +3835,27 @@ class ContinuousBatcher:
             valid[row] = True
             adapters[row] = req.adapter
             g0s[row] = self._g0(req)
-        self._admission_ran("single" if single else "full")
+        family = "single" if single else "full"
+        self._admission_ran(family)
         g_allow, g_trans = self._grammar_tables()
-        self._sync_tables()
-        self._cache_at_risk = True
-        if single:
-            first, self.cache = self._admit_single(
+        program = self._admit_single if single else self._admit_full
+        first = self._admission_program(
+            lambda: program(
                 self.engine.params, jnp.asarray(tokens),
                 jnp.asarray(true_len), self.cache,
-                jnp.int32(slots_idx[0]), jnp.asarray(seeds),
-                jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(ps),
-                jnp.asarray(adapters),
-                jnp.asarray(g0s), g_allow, g_trans,
-            )
-        else:
-            first, self.cache = self._admit_full(
-                self.engine.params, jnp.asarray(tokens),
-                jnp.asarray(true_len), self.cache, jnp.asarray(valid),
+                # The single-row program takes the slot index, the
+                # full-pool one a mask of the rows that hold a request.
+                jnp.int32(slots_idx[0]) if single else jnp.asarray(valid),
                 jnp.asarray(seeds), jnp.asarray(temps), jnp.asarray(ks),
                 jnp.asarray(ps), jnp.asarray(adapters),
                 jnp.asarray(g0s), g_allow, g_trans,
-            )
-        # Materialize BEFORE clearing the at-risk flag: under async
-        # dispatch a device failure in the donating call surfaces here,
-        # and the handler must still see the cache as possibly dead.
-        first = np.asarray(first)
-        self._cache_at_risk = False
-        for j, (slot_idx, req) in enumerate(zip(slots_idx, batch)):
-            self._activate_slot(slot_idx, req, int(first[row_of(j)]))
+            ),
+            family, rows=len(batch), chunks=1, tokens=int(true_len.sum()),
+        )
+        self._activate_rows([
+            (slot_idx, req, int(first[row_of(j)]))
+            for j, (slot_idx, req) in enumerate(zip(slots_idx, batch))
+        ])
 
     def _tick_step(self) -> None:
         """One loop turn of decode work: dispatch a tick (fused with at

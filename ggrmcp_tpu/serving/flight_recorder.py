@@ -56,11 +56,27 @@ PHASE_NAMES = ("admit", "sync", "dispatch", "wait", "host")
 # derived from the existing first/last lifecycle stamps — and the two
 # halves of queue_ms (144-149): pending (submit → the pop that put the
 # request into an admission batch) and prefill (that pop → activation).
+# Then the admission round's two (169-174): admit_device_ms, one
+# observation per admission program call (the program alone on the
+# device), and admit_host_ms, one per round (its host segments).
 # Keys double as the stats() field prefixes:
 # <name>_bucket / <name>_sum / <name>_count.
 HISTOGRAM_NAMES = ("ttft_ms", "e2e_ms", "queue_ms", "tick_duration_ms") + tuple(
     f"tick_phase_{p}_ms" for p in PHASE_NAMES
-) + ("tpot_ms", "pending_ms", "prefill_ms")
+) + ("tpot_ms", "pending_ms", "prefill_ms", "admit_device_ms", "admit_host_ms")
+
+# The segments an admission round's PhaseTimer marks, in the order one
+# admission program call makes them (serving/batching.py
+# _admission_program): build (numpy grids, block tables, grammar
+# tables, table sync — everything since the previous mark), launch (the
+# jitted call until it returns: argument transfer + enqueue), tick_wait
+# (only while a pipelined tick dispatched before the round is still on
+# the device: the admission program queues behind it), device (from the
+# device being free to the program's first tokens on the host: the
+# admission program alone, plus the copy back) and activate (the
+# _activate_slot loop, and the draft-side admission where it runs). The
+# first, second and last are the round's host work.
+ADMIT_HOST_MARKS = ("build", "launch", "activate")
 
 
 class PhaseTimer:
@@ -90,6 +106,11 @@ class PhaseTimer:
         self.marks.append((phase, self.last))
         self.last = now
         return ms
+
+    def segments(self) -> list:
+        """(phase, start, end) per mark, perf_counter seconds."""
+        ends = [start for _, start in self.marks[1:]] + [self.last]
+        return [(p, s, e) for (p, s), e in zip(self.marks, ends)]
 
 
 @dataclasses.dataclass
@@ -202,6 +223,15 @@ class AdmissionRecord:
     trace_ids: list
     tick_seq: int
     source: str = ""
+    # duration_ms split where the round's timer marked it: host =
+    # build + launch + activate (ADMIT_HOST_MARKS), tick_wait = waiting
+    # for the tick in flight to leave the device, device = the
+    # admission programs alone on it; the three sum to duration_ms.
+    # programs = admission program calls in the round.
+    host_ms: float = 0.0
+    tick_wait_ms: float = 0.0
+    device_ms: float = 0.0
+    programs: int = 0
 
     def to_dict(self) -> dict:
         return {
@@ -215,6 +245,10 @@ class AdmissionRecord:
             "traceIds": self.trace_ids,
             "tickSeq": self.tick_seq,
             "source": self.source,
+            "hostMs": round(self.host_ms, 3),
+            "tickWaitMs": round(self.tick_wait_ms, 3),
+            "deviceMs": round(self.device_ms, 3),
+            "programs": self.programs,
         }
 
 
@@ -544,9 +578,20 @@ class FlightRecorder:
     ) -> None:
         """Record one admission round from its PhaseTimer (t0 = the
         round's start, last = its end; the wall stamp is paired here,
-        at the end)."""
+        at the end), and observe its split: admit_host_ms once, the
+        round's host segments, and admit_device_ms once per `device`
+        segment, which is once per admission program call."""
         if not self.enabled:
             return
+        device = [
+            (end - start) * 1000.0
+            for phase, start, end in timer.segments() if phase == "device"
+        ]
+        host_ms = sum(timer.acc.get(p, 0.0) for p in ADMIT_HOST_MARKS)
+        with self._lock:
+            self._hists["admit_host_ms"].observe(host_ms)
+            for ms in device:
+                self._hists["admit_device_ms"].observe(ms)
         self._admissions.append(AdmissionRecord(
             seq=seq,
             t_wall=time.time() - (time.perf_counter() - timer.t0),
@@ -559,6 +604,10 @@ class FlightRecorder:
             trace_ids=batch_trace_ids,
             tick_seq=tick_seq,
             source=self.source,
+            host_ms=host_ms,
+            tick_wait_ms=timer.acc.get("tick_wait", 0.0),
+            device_ms=timer.acc.get("device", 0.0),
+            programs=len(device),
         ))
 
     def note_handoff(
@@ -615,8 +664,9 @@ class FlightRecorder:
         return None
 
     def histogram_stats(self) -> dict:
-        """The ServingStats histogram fields (proto 33-45 and the
-        per-phase triplets 67-81), keyed by exact proto field name so
+        """The ServingStats histogram fields (proto 33-45, the
+        per-phase triplets 67-81 and the later ones HISTOGRAM_NAMES
+        lists), keyed by exact proto field name so
         ServingStatsResponse(**stats) drift fails loudly."""
         out = {"latency_bucket_bounds_ms": list(self._bounds)}
         with self._lock:

@@ -1102,7 +1102,9 @@ class Sidecar:
                     rows=a.rows, prompt_tokens=a.prompt_tokens,
                     reused_tokens=a.reused_tokens,
                     trace_ids=a.trace_ids, tick_seq=a.tick_seq,
-                    source=a.source,
+                    source=a.source, host_ms=a.host_ms,
+                    tick_wait_ms=a.tick_wait_ms, device_ms=a.device_ms,
+                    programs=a.programs,
                 )
                 for a in admissions
             ],

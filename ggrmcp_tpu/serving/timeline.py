@@ -10,7 +10,8 @@ trace id, so concurrent calls never overlap on a track; each backend is
 its own process row with "ticks" threads per source batcher (flat pool
 / KV tier; two lanes, odd and even seq, because a pipelined tick is
 still in flight while the next one is dispatched), an "admissions"
-thread (one slice per admission round, where it was), a "loop" thread
+thread (one slice per admission round, where it was, its host /
+tick-wait / device split among the slice's args), a "loop" thread
 (every executor call of the batcher loop as four contiguous slices:
 host / exec_wait / work / lag — the hand-offs between event loop and
 executor), one row per request lifecycle, and instant markers for
@@ -230,6 +231,7 @@ def _admission_events(admissions: list, pid: int, events: list) -> None:
                 k: adm.get(k) for k in (
                     "seq", "family", "rows", "promptTokens",
                     "reusedTokens", "traceIds", "tickSeq", "source",
+                    "hostMs", "tickWaitMs", "deviceMs", "programs",
                 ) if k in adm
             },
         })

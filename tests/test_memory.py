@@ -454,7 +454,7 @@ class TestMemoryDebugSurface:
         assert by_comp["kv_arena"] > 0
         assert set(by_comp) >= {
             "weights", "lora", "kv_arena", "block_tables", "draft_cache",
-            "prefix_pool", "ilv_mini", "grammar_arena", "tick_state",
+            "ilv_mini", "grammar_arena", "tick_state",
         }
         assert families["gateway_backend_compile_count"].samples[0].value > 0
         assert "gateway_backend_compile_post_warmup" in families

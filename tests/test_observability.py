@@ -114,6 +114,8 @@ class TestProtoDrift:
             "tpot_ms",
             # queue_ms split at the admission pop (fields 144-149).
             "pending_ms", "prefill_ms",
+            # The admission round timed from inside (fields 169-174).
+            "admit_device_ms", "admit_host_ms",
             # Tick-phase attribution: one histogram per phase, rendered
             # as ONE gateway_backend_tick_phase_ms{phase} family.
             *(f"tick_phase_{p}_ms"
@@ -127,8 +129,7 @@ class TestProtoDrift:
         # gateway_backend_memory_bytes family (never per-field gauges).
         assert memory == {
             "weights", "lora", "kv_arena", "block_tables",
-            "draft_cache", "prefix_pool", "ilv_mini", "grammar_arena",
-            "tick_state",
+            "draft_cache", "ilv_mini", "grammar_arena", "tick_state",
         }
         assert not (gauges & infos)
         # Repeated MESSAGE fields carry structured per-class/per-tenant
@@ -220,11 +221,16 @@ class TestProtoDrift:
         "queue_ms_p50", "queue_ms_p99", "service_ms_p50",
         "service_ms_p99", "decode_stall_ms_p50", "decode_stall_ms_p99",
         "decode_stall_ms_max",
+        # PR 39: without a writer since PR 30.
+        "speculative_calls", "speculative_requests",
+        "speculative_drafted", "speculative_accepted",
+        "memory_prefix_pool_bytes",
     ])
     def test_retired_fields_are_gone_and_their_numbers_reserved(self, name):
         """PR 26's inventory: the second tick clocks and the lifetime
-        percentile gauges had no reader. Their numbers stay reserved,
-        so no later field can take one and be misread by an old peer."""
+        percentile gauges had no reader; PR 39's: five fields had no
+        writer. Their numbers stay reserved, so no later field can take
+        one and be misread by an old peer."""
         from ggrmcp_tpu.gateway.metrics import serving_gauge_names
         from ggrmcp_tpu.rpc.pb import serving_pb2
 
@@ -238,7 +244,9 @@ class TestProtoDrift:
         reserved = {
             n for r in proto.reserved_range for n in range(r.start, r.end)
         }
-        assert reserved == {14, 15, 16, 17, 18, 19, 20, 23, 26, 27, 28}
+        assert reserved == {
+            8, 9, 14, 15, 16, 17, 18, 19, 20, 21, 22, 23, 26, 27, 28, 97,
+        }
         assert not reserved & {f.number for f in desc.fields}
 
     def test_flight_recorder_stats_match_proto_fields(self):

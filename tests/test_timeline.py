@@ -554,6 +554,10 @@ class TestTraceAnnotation:
         entered = await self._names_entered(mistral_engine, monkeypatch)
         assert {name for name, _ in entered} == {
             "ggrmcp.admit", "ggrmcp.tick.dispatch", "ggrmcp.tick.collect",
+            # The round's children (tests/test_admission_marks.py pins
+            # their attributes and nesting).
+            "ggrmcp.admit.program", "ggrmcp.admit.device",
+            "ggrmcp.admit.activate",
         }
         # Dispatch and collect of one tick carry the same seq, the key
         # into the tick ring.
