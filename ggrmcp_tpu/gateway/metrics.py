@@ -79,6 +79,12 @@ _SERVING_HELP = {
         "tokens emitted under an active grammar mask",
     "grammar_states_in_use":
         "DFA states resident in the grammar table arena",
+    "sampler_order_ticks":
+        "ticks with a live row sampling under top_k/top_p (the "
+        "sampler sorted the vocabulary)",
+    "sampler_mask_ticks":
+        "ticks with a live constrained row (the sampler read the "
+        "grammar tables)",
     "grammar_jump_tokens":
         "forced tokens emitted by jump-ahead runs (no forward pass)",
     "grammar_jump_runs": "forced multi-token jump-ahead runs collapsed",

@@ -566,6 +566,12 @@ class ContinuousBatcher:
         # Tokens emitted under an active grammar mask (the
         # grammar_masked_tokens ServingStats field).
         self.grammar_tokens = 0
+        # Ticks the sampler's gates (ops/sampling.py) could not shorten,
+        # counted at dispatch from what the tick is given: a live row
+        # sampling under top-k/top-p (the sort runs), a live constrained
+        # row (the grammar tables are read). Read against `ticks`.
+        self.sampler_order_ticks = 0
+        self.sampler_mask_ticks = 0
         # Jump-ahead accounting (grammar_jump_* ServingStats fields):
         # forced tokens emitted by multi-token advances, jump ticks
         # that advanced at least one run, and runs the collect-side
@@ -1464,7 +1470,7 @@ class ContinuousBatcher:
             )
             nxt, gs = masked_sample_dynamic(
                 logits[:, -1], seeds, step + i, temps, ks, ps,
-                gs, g_allow, g_trans,
+                gs, g_allow, g_trans, live=active,
             )
             out = jnp.concatenate([nxt, *counts]) if counts else nxt
             return (nxt, gs, cache), out
@@ -1518,7 +1524,7 @@ class ContinuousBatcher:
         return toks, cache, mini, sel, gstate
 
     def _jump_core(
-        self, params, tokens, cache, seeds, step, temps, ks, ps,
+        self, params, tokens, cache, seeds, step, temps, ks, ps, active,
         adapters, gstate, g_allow, g_trans, j_len, j_tok, j_state,
         jump_ok,
     ):
@@ -1561,6 +1567,7 @@ class ContinuousBatcher:
         )[:, 0]
         nxt, gstate2 = masked_sample_dynamic(
             sel, seeds, step, temps, ks, ps, landing, g_allow, g_trans,
+            live=active,
         )
         idx = jnp.arange(window.shape[1])[None, :]
         emit = jnp.where(
@@ -1583,9 +1590,8 @@ class ContinuousBatcher:
         """One jump-ahead device call for the whole slot pool — the
         multi-token twin of _tick_impl, dispatched instead of it while
         any live slot can jump (_tick_step)."""
-        del active  # dense-only path; kept for dispatch symmetry
         return self._jump_core(
-            params, tokens, cache, seeds, step, temps, ks, ps,
+            params, tokens, cache, seeds, step, temps, ks, ps, active,
             adapters, gstate, g_allow, g_trans, j_len, j_tok, j_state,
             jump_ok,
         )
@@ -1600,9 +1606,8 @@ class ContinuousBatcher:
         prefill machinery the same way _tick_chunk_impl does, so a
         forced run never serializes against a long prompt's
         admission."""
-        del active
         emit, count, cache, cur2, gstate2 = self._jump_core(
-            params, tokens, cache, seeds, step, temps, ks, ps,
+            params, tokens, cache, seeds, step, temps, ks, ps, active,
             adapters, gstate, g_allow, g_trans, j_len, j_tok, j_state,
             jump_ok,
         )
@@ -2612,6 +2617,9 @@ class ContinuousBatcher:
             # adds the compile/cache-hit counters from its GrammarCache.
             "grammar_masked_tokens": self.grammar_tokens,
             "grammar_states_in_use": self.arena.states_in_use(),
+            # Of `ticks`, those the sampler's gates could not shorten.
+            "sampler_order_ticks": self.sampler_order_ticks,
+            "sampler_mask_ticks": self.sampler_mask_ticks,
             # Jump-ahead constrained decoding (grammar.jump_max > 0):
             # forced tokens emitted by multi-token advances, runs
             # advanced, and runs the collect-side validator refused
@@ -3902,6 +3910,16 @@ class ContinuousBatcher:
         "host" — and is seeded with the executor admission time
         accumulated since the previous dispatch (the admit phase)."""
         admit_ms, self._admit_phase_ms = self._admit_phase_ms, 0.0
+        # The sampler's two counters, here because every dispatch path
+        # opens its record with the tick's `active` mask in hand.
+        self.sampler_order_ticks += bool(np.any(
+            active & (self.temps > 0.0)
+            & ((self.top_ks > 0) | (self.top_ps < 1.0))
+        ))
+        self.sampler_mask_ticks += any(
+            s.active and s.request is not None
+            and s.request.grammar is not None for s in self.slots
+        )
         if not self.recorder.enabled:
             return None
         trace_ids = list(dict.fromkeys(

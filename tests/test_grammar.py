@@ -598,6 +598,69 @@ async def _sidecar():
         await side.stop()
 
 
+class TestSamplerGateCounters:
+    """sampler_order_ticks / sampler_mask_ticks (PR 40): how many of
+    `ticks` carried a live row that makes the sampler sort, or read the
+    grammar tables. Stamped at dispatch from what the tick is given."""
+
+    async def test_zero_of_ticks_after_greedy_unconstrained_traffic(
+        self, engine
+    ):
+        async with _batcher(engine) as batcher:
+            await asyncio.gather(
+                _drain(batcher, [3, 1, 4, 1], 8),
+                _drain(batcher, [5, 5], 8),
+                # temperature alone asks for no order: CDF inversion
+                # over everything, no sort.
+                _drain(batcher, [9, 2], 8,
+                       sampling=SamplingConfig(temperature=0.9), seed=4),
+            )
+            stats = batcher.stats()
+            assert stats["ticks"] > 0
+            assert stats["sampler_order_ticks"] == 0
+            assert stats["sampler_mask_ticks"] == 0
+
+    @pytest.mark.parametrize("sampling", [
+        SamplingConfig(temperature=0.8, top_p=0.9),
+        SamplingConfig(temperature=0.8, top_k=5),
+    ], ids=["top_p", "top_k"])
+    async def test_order_ticks_count_a_sampling_row_under_top_k_or_top_p(
+        self, engine, sampling
+    ):
+        async with _batcher(engine) as batcher:
+            await _drain(batcher, [3, 1, 4, 1], 8, sampling=sampling, seed=7)
+            stats = batcher.stats()
+            assert 0 < stats["sampler_order_ticks"] <= stats["ticks"]
+            assert stats["sampler_mask_ticks"] == 0
+            # A greedy row that sets top_p is an argmax all the same.
+            await _drain(batcher, [3, 1, 4, 1], 8,
+                         sampling=SamplingConfig(temperature=0.0, top_p=0.5))
+            after = batcher.stats()
+            assert after["ticks"] > stats["ticks"]
+            assert after["sampler_order_ticks"] == stats["sampler_order_ticks"]
+
+    async def test_mask_ticks_count_a_live_grammar_and_stop_once_it_parks(
+        self, engine
+    ):
+        g = compile_schema(SUITE["object_required"], vocab_size=VOCAB)
+        async with _batcher(engine) as batcher:
+            await _drain(batcher, [3], 256, grammar=g)
+            stats = batcher.stats()
+            assert 0 < stats["sampler_mask_ticks"] <= stats["ticks"]
+            assert stats["sampler_order_ticks"] == 0
+            # The request is over and its slot parked (the device twin of
+            # its state is stale, not live): unconstrained traffic on the
+            # same batcher adds ticks and no mask tick.
+            plain, _ = await _drain(batcher, [3, 1, 4, 1], 8)
+            after = batcher.stats()
+            assert after["ticks"] > stats["ticks"]
+            assert after["sampler_mask_ticks"] == stats["sampler_mask_ticks"]
+            # ... and reads what a batcher that never saw a grammar reads.
+            async with _batcher(engine) as fresh:
+                solo, _ = await _drain(fresh, [3, 1, 4, 1], 8)
+            assert plain == solo
+
+
 class TestSidecarConstraint:
     async def test_generate_with_constraint_returns_valid_json(self):
         schema = SUITE["object_required"]
@@ -623,6 +686,8 @@ class TestSidecarConstraint:
             assert stats.grammar_compiles == 1
             assert stats.grammar_masked_tokens > 0
             assert stats.grammar_states_in_use > 1
+            assert 0 < stats.sampler_mask_ticks <= stats.ticks
+            assert stats.sampler_order_ticks == 0
             # second call with the SAME schema hits the compile cache
             await gen(serving_pb2.GenerateRequest(
                 prompt="yo", max_new_tokens=256,

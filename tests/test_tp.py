@@ -247,10 +247,21 @@ class TestGreedyBitIdentity:
         assert short1 == short2
         assert long1 == long2
 
-    async def test_sampled_rows_identical_across_meshes(self, eng1, eng2):
+    @pytest.mark.parametrize("sampling", [
+        SamplingConfig(temperature=0.7, top_k=8),
+        SamplingConfig(temperature=0.7, top_p=0.8),
+        SamplingConfig(temperature=0.7),
+    ], ids=["top_k", "top_p", "temperature_alone"])
+    async def test_sampled_rows_identical_across_meshes(
+        self, eng1, eng2, sampling
+    ):
         """Seeded sampling (temperature + top-k) also reproduces across
         meshes: the RNG stream is device-count independent and the
-        filtered distributions round the same way on tiny logits."""
+        filtered distributions round the same way on tiny logits. So
+        does each branch of the sampler's gates (ops/sampling.py): the
+        sort under top-k or top-p, and the draw without it when a
+        sampling row sets neither; the predicates are replicated
+        per-row scalars beside vocabulary-sharded logits."""
 
         async def run(engine):
             batcher = ContinuousBatcher(engine, _cfg())
@@ -258,8 +269,7 @@ class TestGreedyBitIdentity:
             try:
                 out = []
                 async for ids, reason in batcher.submit(
-                    SHORT_B, 8,
-                    SamplingConfig(temperature=0.7, top_k=8), seed=123,
+                    SHORT_B, 8, sampling, seed=123,
                 ):
                     out.extend(ids)
             finally:
