@@ -42,6 +42,14 @@ next starts (this parent never imports JAX or the package):
               block walk), then one decode step over the same state as
               pages (K and V gathered by token index); fails unless
               both sparse paths ran.
+  experts     the routed experts of one keye layer at its published
+              widths (2,048 x 768, 128 experts, top 8): the grouped
+              SwiGLU Pallas kernel (ops/experts.py) against the XLA
+              task loop and both against float32, a decode step, a
+              suffix with padding rows and a 512-token chunk; fails
+              unless the chooser took the kernel. Only with `--legs
+              experts`: the sparse and keye legs run the same kernel
+              inside their layers on the chip.
   serve       mistral-7b int8 synthetic weights, paged KV, one chip:
               tools/list, greedy generate (twice: same ids), SSE
               generatestream, a >= 1,024-token prompt, a second prompt
@@ -656,6 +664,121 @@ def keye_leg_child(rehearsal: bool) -> None:
     }), flush=True)
 
 
+def experts_leg_child(rehearsal: bool) -> None:
+    """Runs in the child. The routed experts of one layer at keye's
+    published widths (2,048 x 768, 128 experts, top 8; two layers of
+    banks stacked, the second read) as `mla_moe.routed_experts` runs
+    them on the device: the grouped SwiGLU kernel (`ops/experts.py`;
+    the leg fails unless the chooser took it on the chip) against the
+    XLA task loop on the same operands, both against every expert on
+    every token in float32 at the highest precision. A decode step (8
+    tokens), a suffix with padding rows (96 tokens, 60% real) and a
+    chunk (512 tokens): `stats` equal, rows routed nowhere exactly zero, and the
+    kernel no further from float32 than the loop is (it rounds fewer
+    intermediates) up to a rounding of the result; both within 2e-2 of
+    its largest value. The rehearsal runs the kernel in the
+    interpreter at tiny widths in float32: 1e-5."""
+    from ggrmcp_tpu.utils.jaxenv import init_runtime
+
+    init_runtime("chip_smoke experts leg")
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ggrmcp_tpu.models import keye, mla_moe
+    from ggrmcp_tpu.ops import attention as attn_ops
+    from ggrmcp_tpu.ops import experts as experts_ops
+
+    dev = jax.devices()[0]
+    chooser = experts_ops.grouped_experts
+    if rehearsal:
+        cfg, tol = keye.CONFIGS["tiny-keye"], 1e-5
+        cases = ((8, None), (24, 0.6), (64, None))
+        experts_ops.grouped_swiglu = functools.partial(
+            experts_ops.grouped_swiglu, interpret=True)
+        take = lambda *_: True  # noqa: E731
+    else:
+        check(dev.platform == "tpu", f"experts leg on {dev.platform}")
+        cfg, tol = keye.CONFIGS["keye-vl-2.0-30b-a3b-6l"], 2e-2
+        cases = ((8, None), (96, 0.6), (512, None))
+        take = chooser
+    cfg = dataclasses.replace(cfg, num_layers=2)
+    dtype, d, f = cfg.jnp_dtype, cfg.hidden_dim, cfg.expert_ffn_dim
+    e, k = cfg.num_experts, cfg.experts_per_token
+    key = jax.random.PRNGKey(43)
+    banks = tuple(
+        jax.jit(lambda kk, dims=dims: jax.random.normal(
+            kk, (2, e, *dims), dtype) * dims[0] ** -0.5
+        )(jax.random.fold_in(key, i))
+        for i, dims in enumerate(((d, f), (d, f), (f, d))))
+    banks32 = tuple(w.astype(jnp.float32) for w in banks)
+
+    @jax.jit
+    def dense(xt, idx, weight, valid, banks32, layer):
+        """Every expert on every token in float32 at the highest
+        precision, then each token's own: no sort, no task, no block."""
+        hi = jax.lax.Precision.HIGHEST
+        x = xt.astype(jnp.float32)
+        wg, wu, wd = (w[layer] for w in banks32)
+        g = jnp.einsum("td,edf->etf", x, wg, precision=hi)
+        u = jnp.einsum("td,edf->etf", x, wu, precision=hi)
+        y = jnp.einsum(
+            "etf,efd->etd", jax.nn.silu(g) * u, wd, precision=hi)
+        picked = y[idx, jnp.arange(x.shape[0])[:, None]]  # [T, k, D]
+        out = (picked * weight[..., None]).sum(1)
+        return out if valid is None else jnp.where(valid[:, None], out, 0.0)
+
+    def run(choose, *operands):
+        experts_ops.grouped_experts = choose
+        try:  # a new function a call: a new trace under this chooser
+            return jax.jit(lambda *a: mla_moe.routed_experts(*a, cfg))(
+                *operands)
+        finally:
+            experts_ops.grouped_experts = chooser
+
+    for tokens, real_share in cases:
+        kk = jax.random.fold_in(key, tokens)
+        xt = jax.random.normal(kk, (tokens, d), dtype)
+        weight = jax.random.uniform(jax.random.fold_in(kk, 1), (tokens, k))
+        idx = jax.lax.top_k(jax.random.normal(
+            jax.random.fold_in(kk, 2), (tokens, e)), k)[1].astype(jnp.int32)
+        valid = None if real_share is None else jax.random.bernoulli(
+            jax.random.fold_in(kk, 3), real_share, (tokens,))
+        layer = jnp.int32(1)
+        before = attn_ops.dispatch_counts["grouped_experts"]
+        got, got_stats = run(take, xt, idx, weight, valid, banks, layer)
+        if not rehearsal:
+            check(attn_ops.dispatch_counts["grouped_experts"] == before + 1,
+                  "the chooser did not take the grouped-experts kernel")
+        loop, loop_stats = run(
+            lambda *a: False, xt, idx, weight, valid, banks, layer)
+        want = dense(xt, idx, weight, valid, banks32, layer)
+        check(np.array_equal(np.asarray(got_stats), np.asarray(loop_stats)),
+              f"stats differ: {got_stats} vs {loop_stats}")
+        got, loop, want = (np.asarray(a, np.float32) for a in (got, loop, want))
+        check(bool(np.isfinite(got).all()), "non-finite output")
+        scale = float(np.abs(want).max())
+        mine = float(np.abs(got - want).max()) / scale
+        theirs = float(np.abs(loop - want).max()) / scale
+        hit, load_max, pairs, _ = (int(v) for v in got_stats)
+        say(f"  {tokens} tokens ({pairs} pairs, {hit} of {e} experts hit, "
+            f"largest load {load_max}): max|. - float32| / max|float32|: "
+            f"kernel {mine:.2e}, loop {theirs:.2e} (limit {tol:g})")
+        check(mine < tol and theirs < tol, f"{tokens} tokens: beyond {tol:g}")
+        check(mine <= theirs + float(jnp.finfo(dtype).eps),
+              f"{tokens} tokens: the kernel is further from float32 "
+              f"({mine:.3e}) than the loop ({theirs:.3e})")
+        if valid is not None:
+            check(not got[~np.asarray(valid)].any(),
+                  "a padding row's result is not zero")
+    print("LEG_RESULT " + json.dumps({
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(jax.devices()),
+    }), flush=True)
+
+
 def run_child_leg(name: str, title: str, rehearsal: bool) -> dict:
     """A leg that is one child process on the device: `--child-<name>`."""
     say(f"== leg {name}: {title}")
@@ -1025,7 +1148,8 @@ def main() -> int:
     )
     ap.add_argument(
         "--legs", default="",
-        help="comma-separated subset of kernel,sparse,keye,serve,default_kv,tp4 "
+        help="comma-separated subset of kernel,sparse,keye,experts,serve,"
+        "default_kv,tp4 "
         "(debugging; the default is every leg the host can hold)",
     )
     ap.add_argument(
@@ -1038,6 +1162,8 @@ def main() -> int:
                     help=argparse.SUPPRESS)
     ap.add_argument("--child-keye", action="store_true",
                     help=argparse.SUPPRESS)
+    ap.add_argument("--child-experts", action="store_true",
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.child_kernel:
         kernel_leg_child(args.cpu_rehearsal)
@@ -1047,6 +1173,9 @@ def main() -> int:
         return 0
     if args.child_keye:
         keye_leg_child(args.cpu_rehearsal)
+        return 0
+    if args.child_experts:
+        experts_leg_child(args.cpu_rehearsal)
         return 0
 
     rehearsal = args.cpu_rehearsal
@@ -1070,6 +1199,11 @@ def main() -> int:
             "keye", "one keye layer (GQA under the indexer's selection "
             "over three planes, softmax-routed experts), a sparse chunk and "
             "a sparse decode step, vs the float32 reference layer", rehearsal)
+    if "experts" in legs:  # on request: the keye and sparse legs run the
+        # same kernel inside their layers, against the float32 layer
+        run_child_leg(
+            "experts", "the routed experts of one keye layer, the grouped "
+            "SwiGLU kernel vs the XLA task loop vs float32", rehearsal)
     if not legs:
         legs = ["kernel", "sparse", "keye", "serve", "default_kv"]
         if device["count"] >= 4 and not rehearsal:

@@ -27,6 +27,9 @@ What it takes from the others, and what is its own:
 - **The FFN is mla_moe's dropless routed experts** (`routed_experts`,
   `task_map`) behind its router in the softmax form (`route`): every
   layer an expert layer, no shared expert, no bias, groups or scaling.
+  On a TPU they run as the grouped SwiGLU kernel (`ops/experts.py`),
+  the one Pallas kernel of this family; `forward` hands `moe_ffn` the
+  engine's `use_flash` / `flash_mesh` for it.
 - Per-head RMSNorm on q and k before RoPE (`qk_norm`): the config has no
   key for it; it is the convention of the Qwen3-MoE-shaped key set the
   config uses. `mrope_section` gives each rotary frequency one of three
@@ -499,9 +502,9 @@ def forward(
 ):
     """`mla_moe.forward`'s contract (`valid`, `logit_idx`,
     `with_stats`: the same seven counts summed over the layers).
-    `use_flash` / `flash_mesh` are heard and unused: this family's
-    attention is XLA on every platform."""
-    del use_flash, flash_mesh
+    `use_flash` / `flash_mesh` reach the experts alone
+    (`mla_moe.moe_ffn`): this family's attention is XLA on every
+    platform."""
     b, s = tokens.shape
     x = embed_lookup(params["embed"], tokens, cfg.jnp_dtype)
     if cache is not None:
@@ -523,7 +526,8 @@ def forward(
         x, planes, sel = attention_block(
             x, lp, cfg, positions, planes, length, table, layer, valid)
         n = common.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        out, stats = mla_moe.moe_ffn(n, lp, banks, layer, cfg, valid)
+        out, stats = mla_moe.moe_ffn(
+            n, lp, banks, layer, cfg, valid, use_flash, flash_mesh)
         return (x + out, planes), jnp.concatenate([stats, sel])
 
     (x, planes), stats = jax.lax.scan(

@@ -38,7 +38,11 @@ of its own:
   normalised and scaled. Every routed (token, expert) pair is computed:
   pairs are sorted by expert and walked in fixed-size blocks, one
   expert's weights per block, so a token's output cannot depend on who
-  shares its batch, and only experts that were hit are read.
+  shares its batch, and only experts that were hit are read. On a TPU
+  the blocks are one Pallas kernel a layer (`ops/experts.py`: each
+  expert hit streamed out of the stacked banks once, the next in
+  flight while this one multiplies); elsewhere a `fori_loop` of three
+  sliced matmuls a block, which the tests hold the kernel to.
 """
 
 from __future__ import annotations
@@ -60,6 +64,7 @@ from ggrmcp_tpu.models.llama import (  # noqa: F401
     plane_io,
 )
 from ggrmcp_tpu.ops import attention as attn_ops
+from ggrmcp_tpu.ops import experts as experts_ops
 from ggrmcp_tpu.ops import indexer
 from ggrmcp_tpu.ops.indexer import index_scores, selection_mask  # noqa: F401
 from ggrmcp_tpu.ops.quant import embed_lookup
@@ -785,34 +790,12 @@ def task_map(counts, block: int, max_tasks: int):
     return task_end[-1], ex, starts[ex] + j * block, counts[ex] - j * block
 
 
-def routed_experts(xt, idx, weight, valid, banks, layer, cfg: MlaMoeConfig):
-    """Every routed (token, expert) pair, no capacity and no drops.
-    Pairs are sorted by expert; each block task multiplies up to
-    `block` rows of ONE expert by that expert's three matrices, so the
-    work is pairs/block + at most one task an expert, and an expert no
-    valid token chose is never read. Which expert, which rows and how
-    many of them a task keeps come from `task_map`, before the loop.
-    `banks` are the STACKED expert matrices `[layers, E, ..]`, indexed
-    `[layer, expert]` inside the task: sliced out a layer first, XLA
-    would copy a layer's whole bank (1.2 GB at the published widths)
-    in front of the loop.
-    Where the chip holds a share (`cfg.experts_held`), `banks` are its
-    experts only and a pair routed to an absent expert goes nowhere,
-    like padding: its part of the sum is another chip's.
-    Returns (out [T, D], stats int32 [4]: experts hit, largest load,
-    pairs computed here, pairs whose expert is absent)."""
-    t, d = xt.shape
-    k, e = idx.shape[1], cfg.num_experts_held
-    pairs = t * k
-    block = _task_block(pairs, cfg.num_experts)
-    flat = idx.reshape(pairs)
-    if cfg.experts_held:
-        flat = flat - cfg.experts_held[0]
-        flat = jnp.where((flat >= 0) & (flat < e), flat, e)
-    if valid is not None:  # padding and parked rows route nowhere
-        flat = jnp.where(jnp.repeat(valid, k), flat, e)
-    order = jnp.argsort(flat, stable=True)
-    counts = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
+def _looped_tasks(xt, order, counts, banks, layer, k: int, block: int):
+    """The block tasks as a `fori_loop` in XLA, three sliced matmuls a
+    task over the sorted rows; a task's tail rows are the next
+    expert's and stay (`keep`). Returns every pair's result in the
+    pairs' own order `[pairs, D]`, zeros for a pair routed nowhere."""
+    pairs, d, e = order.shape[0], xt.shape[1], counts.shape[0]
     n_tasks, task_ex, task_row0, task_rows = task_map(
         counts, block, pairs // block + e)
     xs = jnp.pad(xt[order // k], ((0, block), (0, 0)))  # [pairs+block, D]
@@ -833,7 +816,86 @@ def routed_experts(xt, idx, weight, valid, banks, layer, cfg: MlaMoeConfig):
             ys, jnp.where(keep, yb, old), (row0, 0))
 
     ys = jax.lax.fori_loop(0, n_tasks, task, jnp.zeros_like(xs))
-    y = jnp.zeros((pairs, d), xt.dtype).at[order].set(ys[:pairs])
+    return jnp.zeros((pairs, d), xt.dtype).at[order].set(ys[:pairs])
+
+
+def _task_tiles(xt, order, counts, k: int, tile: int):
+    """What `ops.experts.grouped_swiglu` takes of a step: its rows laid
+    out a task a tile, gathered straight from the tokens (row r of task
+    i is sorted row `task_row0[i] + r`; a task's tail and the tasks
+    there are not are padding), and the map's count, experts and rows."""
+    pairs, e = order.shape[0], counts.shape[0]
+    max_tasks = min(pairs // tile + e, pairs)  # a task has a pair
+    n_tasks, task_ex, task_row0, task_rows = task_map(counts, tile, max_tasks)
+    at = jnp.minimum(task_row0[:, None] + jnp.arange(tile), pairs - 1)
+    return xt[order[at.reshape(-1)] // k], n_tasks, task_ex, task_rows
+
+
+def _grouped_tasks(xt, flat, order, counts, banks, layer, k: int, tile: int):
+    """The block tasks as one kernel (`ops.experts.grouped_swiglu`),
+    `tile` rows a task (`_task_tiles`; padding rows are computed or
+    skipped, never read back). A pair's result is read back from where
+    its sorted row landed: its expert's first task, then in order.
+    Same contract as `_looped_tasks`."""
+    pairs, e = order.shape[0], counts.shape[0]
+    rows, n_tasks, task_ex, task_rows = _task_tiles(xt, order, counts, k, tile)
+    ys = experts_ops.grouped_swiglu(
+        rows, *banks, layer, n_tasks, task_ex, task_rows, block=tile)
+    rank = jnp.zeros((pairs,), jnp.int32).at[order].set(
+        jnp.arange(pairs, dtype=jnp.int32))
+    starts = jnp.cumsum(counts) - counts
+    tasks = (counts + tile - 1) // tile
+    ex = jnp.minimum(flat, e - 1)
+    landed = (jnp.cumsum(tasks) - tasks)[ex] * tile + rank - starts[ex]
+    return jnp.where(
+        (flat < e)[:, None], ys[jnp.clip(landed, 0, ys.shape[0] - 1)], 0)
+
+
+def routed_experts(
+    xt, idx, weight, valid, banks, layer, cfg: MlaMoeConfig,
+    use_flash: Optional[bool] = None, flash_mesh: Any = None,
+):
+    """Every routed (token, expert) pair, no capacity and no drops.
+    Pairs are sorted by expert; each block task multiplies up to
+    `block` rows of ONE expert by that expert's three matrices, so the
+    work is pairs/block + at most one task an expert, and an expert no
+    valid token chose is never read. Which expert, which rows and how
+    many of them a task keeps come from `task_map`, before the walk.
+    `banks` are the STACKED expert matrices `[layers, E, ..]`, indexed
+    `[layer, expert]` inside the task: sliced out a layer first, XLA
+    would copy a layer's whole bank (1.2 GB at the published widths)
+    in front of the walk.
+    The tasks run as ONE Pallas kernel where the call is its kind
+    (`ops.experts.grouped_experts`: a TPU, banks in the tokens'
+    dtype, no mesh; `use_flash` / `flash_mesh` are the engine's word,
+    as for the attention kernels): every expert hit is streamed once,
+    the next in flight while this one multiplies, its tasks a tile of
+    rows each. Everywhere else (the CPU) they are a `fori_loop` of
+    three sliced matmuls a task, which is also what the tests hold the
+    kernel to.
+    Where the chip holds a share (`cfg.experts_held`), `banks` are its
+    experts only and a pair routed to an absent expert goes nowhere,
+    like padding: its part of the sum is another chip's.
+    Returns (out [T, D], stats int32 [4]: experts hit, largest load,
+    pairs computed here, pairs whose expert is absent)."""
+    t, d = xt.shape
+    k, e = idx.shape[1], cfg.num_experts_held
+    pairs = t * k
+    block = _task_block(pairs, cfg.num_experts)
+    flat = idx.reshape(pairs)
+    if cfg.experts_held:
+        flat = flat - cfg.experts_held[0]
+        flat = jnp.where((flat >= 0) & (flat < e), flat, e)
+    if valid is not None:  # padding and parked rows route nowhere
+        flat = jnp.where(jnp.repeat(valid, k), flat, e)
+    order = jnp.argsort(flat, stable=True)
+    counts = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
+    if experts_ops.grouped_experts(xt, banks, use_flash, flash_mesh):
+        y = _grouped_tasks(
+            xt, flat, order, counts, banks, layer, k,
+            max(block, experts_ops.MIN_ROWS))
+    else:
+        y = _looped_tasks(xt, order, counts, banks, layer, k, block)
     out = (
         y.reshape(t, k, d).astype(jnp.float32) * weight[..., None]
     ).sum(1).astype(xt.dtype)
@@ -879,15 +941,18 @@ def route(xt, lp, cfg):
     return idx, weight * cfg.routed_scaling
 
 
-def moe_ffn(x, lp, banks, layer, cfg, valid=None):
+def moe_ffn(
+    x, lp, banks, layer, cfg, valid=None, use_flash=None, flash_mesh=None,
+):
     """The routed experts of every token (`route`, `routed_experts`),
-    plus the shared experts where the model has any."""
+    plus the shared experts where the model has any. `use_flash` /
+    `flash_mesh`: the engine's word on kernels for its mesh."""
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
     idx, weight = route(xt, lp, cfg)
     out, stats = routed_experts(
         xt, idx, weight, None if valid is None else valid.reshape(b * s),
-        banks, layer, cfg,
+        banks, layer, cfg, use_flash, flash_mesh,
     )
     if cfg.num_shared_experts:
         out = out + _swiglu(xt, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
@@ -955,7 +1020,8 @@ def forward(
             x, lp, cfg, positions, plane, length, table, layer, valid,
             use_flash, flash_mesh)
         n = common.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        out, stats = moe_ffn(n, lp, banks, layer - kd, cfg, valid)
+        out, stats = moe_ffn(
+            n, lp, banks, layer - kd, cfg, valid, use_flash, flash_mesh)
         return (x + out, plane), (
             jnp.concatenate([stats, sel]) if sparse else stats)
 

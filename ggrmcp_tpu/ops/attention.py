@@ -1080,24 +1080,23 @@ def latent_prefill_attention_sharded(
 FLASH_MIN_SEQ = 256
 
 # Which implementation each kernel-eligible call took, counted at TRACE
-# time (the dispatcher is Python that runs once per traced program, so
-# this counts programs, not executions): "flash" / "flash_sharded" =
-# the Pallas prefill kernel is in the program; "paged_decode" = the
-# paged-decode kernel is (a layer scan traces its body once, so one a
-# forward call a tick program holds); "latent_prefill" = the latent
-# family's prefill kernel is (two a forward call: its dense and its
-# expert layers are a scan each); "xla_fallback" = the call wanted
-# a kernel and its shapes did not shard over the mesh, or the engine
-# runs none on its mesh. Process-wide, like the compile watcher: the sidecar
-# exports it beside mesh_spec_downgrades (attn_kernel_programs /
-# attn_kernel_fallbacks), and it is how chip_smoke.py knows a prefill
-# took the compiled kernel.
-# The latent family also counts here, once a traced program, which of
-# its two sparse-attention paths a layer took (models/mla_moe.py:
-# "sparse_decode" the gather by token index, "sparse_chunk" a selection
-# a query); neither is in the sums below: a sparse chunk that runs the
-# kernel counts under "latent_prefill" too, one that wanted it and
-# walks under "xla_fallback", and the rest are XLA programs.
+# time (the dispatcher is Python that runs once a traced program, so
+# this counts programs, not executions). "flash" / "flash_sharded": the
+# Pallas prefill kernel is in the program; "paged_decode": the
+# paged-decode kernel is (one a forward call a tick program holds: a
+# layer scan traces its body once); "latent_prefill": the latent
+# family's prefill kernel is (two a forward call, its dense and its
+# expert layers being a scan each); "grouped_experts": the routed
+# experts' grouped SwiGLU kernel is (ops/experts.py, one a forward
+# call); "xla_fallback": the call wanted a kernel and its shapes did
+# not shard over the mesh, or the engine runs none on its mesh. The
+# sidecar exports the sums below beside mesh_spec_downgrades, and by
+# them chip_smoke.py knows that a prefill took the compiled kernel.
+# The latent family also counts, once a traced program, which
+# sparse-attention path a layer took (models/mla_moe.py: "sparse_decode"
+# the gather by token index, "sparse_chunk" a selection a query);
+# neither is in the sums: a sparse chunk that runs the kernel counts
+# under "latent_prefill" too, one that walks under "xla_fallback".
 dispatch_counts: collections.Counter = collections.Counter()
 
 
@@ -1108,6 +1107,7 @@ def dispatch_stats() -> dict:
             dispatch_counts["flash"] + dispatch_counts["flash_sharded"]
             + dispatch_counts["paged_decode"]
             + dispatch_counts["latent_prefill"]
+            + dispatch_counts["grouped_experts"]
         ),
         "attn_kernel_fallbacks": dispatch_counts["xla_fallback"],
     }

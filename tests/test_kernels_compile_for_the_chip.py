@@ -104,3 +104,53 @@ def test_per_shard_with_a_selection_compiles_for_a_2x2(topo, uncached):
         selected=True, rows=2,
     )).compile()
     assert "latent_attention_prefill" in compiled.as_text()
+
+
+# The grouped-experts kernel (ops/experts.py) at the served widths:
+# (hidden, intermediate, experts held, layers stacked) x the pairs of a
+# decode step, a short suffix and a 512-token chunk; `loop_block` is
+# what `mla_moe._task_block` gives the XLA loop there, the kernel's own
+# row tile that or `experts.MIN_ROWS`.
+EXPERT_WIDTHS = {
+    "kanana_keye": dict(d=2048, f=768, held=128, experts=128, layers=6),
+    "dsv32": dict(d=7168, f=2048, held=16, experts=256, layers=4),
+}
+EXPERT_CALLS = {
+    "kanana_keye-64_pairs": ("kanana_keye", 64, 8),
+    "kanana_keye-96_pairs": ("kanana_keye", 96, 8),
+    "kanana_keye-3072_pairs": ("kanana_keye", 3072, 32),
+    "kanana_keye-4096_pairs": ("kanana_keye", 4096, 32),
+    "dsv32-64_pairs": ("dsv32", 64, 8),
+    "dsv32-4096_pairs": ("dsv32", 4096, 16),
+    # float32 tokens and banks are the chooser's kind too: half an
+    # expert a slot (two tiles of F)
+    "kanana_keye-64_pairs-float32": ("kanana_keye", 64, 8, jnp.float32),
+    "kanana_keye-3072_pairs-float32": ("kanana_keye", 3072, 32, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", EXPERT_CALLS, ids=list(EXPERT_CALLS))
+def test_the_grouped_experts_kernel_compiles_for_a_v5e(topo, uncached, case):
+    from ggrmcp_tpu.models import mla_moe
+    from ggrmcp_tpu.ops import experts
+
+    widths, pairs, loop_block, *dtype = EXPERT_CALLS[case]
+    dtype = dtype[0] if dtype else jnp.bfloat16
+    d, f, held, n_experts, layers = EXPERT_WIDTHS[widths].values()
+    assert mla_moe._task_block(pairs, n_experts) == loop_block
+    tile = max(loop_block, experts.MIN_ROWS)
+    max_tasks = min(pairs // tile + held, pairs)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype=dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        functools.partial(experts.grouped_swiglu, block=tile)
+    ).lower(
+        shape((max_tasks * tile, d)), shape((layers, held, d, f)),
+        shape((layers, held, d, f)), shape((layers, held, f, d)),
+        shape((), jnp.int32), shape((), jnp.int32),
+        shape((max_tasks,), jnp.int32), shape((max_tasks,), jnp.int32),
+    ).compile()
+    assert "grouped_experts_swiglu" in compiled.as_text()
