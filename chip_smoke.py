@@ -169,12 +169,12 @@ def kernel_leg_child(rehearsal: bool) -> None:
     device, at the shapes the default-KV prefill hands the dispatcher.
 
     Tolerance, bf16 (the chip): 2e-2 absolute and relative. Both paths
-    take bf16 q/k/v and accumulate in float32, but attention_xla rounds
-    the softmax weights to bf16 before the PV matmul and the kernel
-    keeps them in float32, and both round the output to bf16 (half an
-    ulp at |x| <= 1 is 2e-3). The sum over up to 4,096 keys of weight
-    roundings of relative size 2^-9 stays an order below 2e-2; a wrong
-    mask, offset or block skip moves outputs by O(0.1-1). float32 (the
+    take bf16 q/k/v, accumulate in float32 and round the softmax
+    weights to bf16 before the PV matmul (the kernel block by block,
+    against its running maximum), and both round the output to bf16
+    (half an ulp at |x| <= 1 is 2e-3). The sum over up to 4,096 keys of
+    weight roundings of relative size 2^-9 stays an order below 2e-2; a
+    wrong mask, offset or block skip moves outputs by O(0.1-1). float32 (the
     interpreted rehearsal): 2e-3, as tests/test_models.py. The same
     tolerance holds paged_decode_attention to the gathered view: it
     rounds the weights to bf16 as attention_xla does, in another order

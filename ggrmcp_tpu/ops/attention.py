@@ -11,15 +11,18 @@ fourth for the latent family's prefill chunks:
 - `flash_attention` — blockwise online-softmax Pallas kernel (the
   standard FlashAttention recurrence) that never materializes the
   [S, S] score matrix, keeping HBM traffic linear in sequence length.
-  Grid: (batch, q_heads, q_blocks); the kernel loops over k blocks with
-  running max/denominator carried in registers. Per-batch `q_offset`
+  Grid: (batch, kv_heads, q_blocks); the kernel loops over k blocks with
+  running max/denominator carried in float32. Per-batch `q_offset`
   (absolute position of q[0], for cached prefill) and `kv_len` (valid
   cache prefix) ride in SMEM, so the SERVING prefill path — where the
   KV cache supplies both — can use the kernel, not just the cache-free
   training/scoring forward. GQA is native: K/V keep their (fewer) KV
-  heads and the grid's head index maps onto the shared KV head, so
-  repeated K/V never hit HBM. Causal masking skips fully masked
-  k blocks; `kv_len` bounds the k loop per batch. Compiled by default;
+  heads and a grid cell takes the whole query group of one KV head,
+  stacked on the row axis, so repeated K/V never hit HBM and a key
+  block is read once for the group. The products take the operands in
+  the dtype they arrive in. Causal masking skips fully masked k blocks
+  and builds a mask only for the blocks it can cut; `kv_len` bounds
+  the k loop per batch. Compiled by default;
   `interpret=True` (CPU tests) runs the same kernel body in the Pallas
   interpreter and must be asked for.
 - `paged_decode_attention` — the decode tick's read of a paged K/V
@@ -174,13 +177,40 @@ def attention_xla(
 # ---------------------------------------------------------------------------
 
 
+# What `flash_attention` aims a block at where the caller names none:
+# score rows a query block (a KV head's whole query group stacked, `reps
+# x block_q`) and keys a key block; their product bounds the float32
+# score block. Read off the chip at the served shapes
+# (scripts/flash_form_bench.py; docs/perf_attention.md has the table).
+_FLASH_ROWS = 512
+_FLASH_BLOCK_K = 512
+
+
+def _flash_blocks(sq: int, sk: int, reps: int) -> tuple[int, int]:
+    """(queries a block, keys a block) from the shapes: the targets
+    halved until they divide the sequence and the keys and the score
+    block holds no more than `_FLASH_ROWS x _FLASH_BLOCK_K` elements,
+    never under 128 (a shorter sequence is one block)."""
+    block_q = max(128, _FLASH_ROWS // reps)
+    while block_q > 128 and sq % block_q:
+        block_q //= 2
+    block_q = min(block_q, sq)
+    block_k = _FLASH_BLOCK_K
+    while block_k > 128 and (
+        sk % block_k or reps * block_q * block_k > _FLASH_ROWS * _FLASH_BLOCK_K
+    ):
+        block_k //= 2
+    return block_q, min(block_k, sk)
+
+
 def _flash_kernel(
     q_off_ref,  # SMEM [B] int32 — absolute position of q[0] per batch
     kv_len_ref,  # SMEM [B] int32 — valid kv prefix per batch
-    q_ref,  # [block_q, D]
+    q_ref,  # [block_q, reps * D] — the query heads of one KV head
     k_ref,  # [Sk, D]
     v_ref,  # [Sk, D]
-    o_ref,  # [block_q, D]
+    o_ref,  # [block_q, reps * D]
+    acc_ref,  # VMEM scratch [reps * block_q, D] float32
     *,
     block_k: int,
     sk: int,
@@ -188,90 +218,114 @@ def _flash_kernel(
     block_q: int,
     window: Optional[int] = None,
 ):
-    """One (batch, head, q_block) cell: online-softmax over k blocks."""
+    """One (batch, kv head, q_block) cell: online-softmax over k blocks.
+    The `reps` query heads that share the KV head are stacked on the row
+    axis (score row r is head r // block_q at query r % block_q), so a
+    K / V block is read once for the group. Both products take their
+    operands in the dtype they arrive in and accumulate in float32;
+    scores, running maximum, sum and accumulator are float32. Key blocks
+    every query of the block sees whole take no mask."""
     b_idx = pl.program_id(0)
-    q_start = pl.program_id(2) * block_q
-    q_off = q_off_ref[b_idx]
     limit = kv_len_ref[b_idx]  # keys at position >= limit are invalid
-
-    q = q_ref[:].astype(jnp.float32)  # [bq, D]
-    scale = q.shape[-1] ** -0.5
-    q = q * scale
-
-    m0 = jnp.full((block_q, 1), NEG_INF, dtype=jnp.float32)
-    l0 = jnp.zeros((block_q, 1), dtype=jnp.float32)
-    acc0 = jnp.zeros_like(q)
+    first_pos = q_off_ref[b_idx] + pl.program_id(2) * block_q
+    last_pos = first_pos + block_q - 1
+    rows, d = acc_ref.shape
+    reps = rows // block_q
+    q = jnp.concatenate(
+        [q_ref[:, r * d:(r + 1) * d] for r in range(reps)], axis=0)
+    scale = d ** -0.5
+    q_pos = first_pos + jnp.concatenate(
+        [jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)] * reps, axis=0)
 
     # Number of k blocks that can contain a valid key for this q block:
     # bounded by the batch's kv_len, and under causality by the last
-    # query's absolute position.
-    kv_limit = limit
+    # query's absolute position. Of them the first `whole` hold only
+    # keys every query sees: under kv_len, at or below the FIRST query.
+    kv_limit = jnp.minimum(limit, sk)
+    whole = kv_limit
     if causal:
-        kv_limit = jnp.minimum(kv_limit, q_off + q_start + block_q)
-    kv_limit = jnp.minimum(kv_limit, sk)
-    num_iters = (kv_limit + block_k - 1) // block_k
+        kv_limit = jnp.minimum(kv_limit, last_pos + 1)
+        whole = jnp.minimum(whole, first_pos + 1)
     # Sliding window: k blocks entirely below the FIRST query's window
     # hold no visible key for any row of this q block — skip them (the
     # work saved is what makes windowed prefill O(S·W) not O(S²)).
-    start_iter = 0
+    # Blocks below `edge` may still be cut by the LAST query's window.
+    start_iter = edge = 0
     if window is not None:
-        win_lo = jnp.maximum(q_off + q_start - window + 1, 0)
-        start_iter = win_lo // block_k
+        start_iter = jnp.maximum(first_pos - window + 1, 0) // block_k
+        edge = pl.cdiv(jnp.maximum(last_pos - window + 1, 0), block_k)
+    num_iters = jnp.maximum(pl.cdiv(kv_limit, block_k), start_iter)
+    edge = jnp.clip(edge, start_iter, num_iters)
+    whole = jnp.clip(whole // block_k, edge, num_iters)
 
-    def body(kb, carry):
-        m_prev, l_prev, acc_prev = carry
+    def block(kb, carry, masked):
+        m_prev, l_prev = carry
         k_start = pl.multiple_of(kb * block_k, block_k)
-        k_blk = k_ref[pl.ds(k_start, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(k_start, block_k), :].astype(jnp.float32)
-        scores = jnp.dot(
-            q, k_blk.T, preferred_element_type=jnp.float32
-        )  # [bq, bk]
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = k_pos < limit
-        if causal:
-            q_pos = q_off + q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            mask &= q_pos >= k_pos
-            if window is not None:
-                mask &= k_pos > q_pos - window
-        scores = jnp.where(mask, scores, NEG_INF)
+        k_blk = k_ref[pl.ds(k_start, block_k), :]
+        v_blk = v_ref[pl.ds(k_start, block_k), :]
+        scores = jax.lax.dot_general(
+            q, k_blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [rows, bk]
+        if masked:
+            k_pos = k_start + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_k), 1)
+            mask = k_pos < limit
+            if causal:
+                mask &= q_pos >= k_pos
+                if window is not None:
+                    mask &= k_pos > q_pos - window
+            scores = jnp.where(mask, scores, NEG_INF)
         m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(scores - m_new)
         l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-        acc_new = acc_prev * alpha + jnp.dot(
-            p, v_blk, preferred_element_type=jnp.float32
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32
         )
-        return m_new, l_new, acc_new
+        return m_new, l_new
 
-    m, l, acc = jax.lax.fori_loop(start_iter, num_iters, body, (m0, l0, acc0))
+    # The accumulator in VMEM and not in the loop's carry: a sixth off
+    # the kernel at the served chunk (docs/perf_attention.md).
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    carry = (
+        jnp.full((rows, 1), NEG_INF, dtype=jnp.float32),
+        jnp.zeros((rows, 1), dtype=jnp.float32),
+    )
+    cut = functools.partial(block, masked=True)
+    if window is not None:
+        carry = jax.lax.fori_loop(start_iter, edge, cut, carry)
+    carry = jax.lax.fori_loop(
+        edge, whole, functools.partial(block, masked=False), carry)
+    m, l = jax.lax.fori_loop(whole, num_iters, cut, carry)
     # Fully masked rows have l == 0 when the loop never ran; emit
     # zeros. A row whose PROCESSED blocks are all masked (possible only
     # for out-of-window pad queries — serving rows always see their own
     # key) keeps m == NEG_INF with p == exp(0) == 1 accumulating
     # garbage; zero those rows explicitly rather than emit it.
     live = m > NEG_INF / 2
-    o_ref[:] = jnp.where(
-        live, acc / jnp.maximum(l, 1e-30), 0.0
+    out = jnp.where(
+        live, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0
     ).astype(o_ref.dtype)
+    for r in range(reps):
+        o_ref[:, r * d:(r + 1) * d] = out[r * block_q:(r + 1) * block_q]
 
 
 def _flash_vmem_bytes(
-    sk: int, d: int, block_q: int, block_k: int, itemsize: int
+    sk: int, d: int, rows: int, block_k: int, itemsize: int
 ) -> int:
     """VMEM the kernel needs, told to the compiler instead of leaving
-    it to the 16 MiB scoped default: each grid cell holds one head's
+    it to the 16 MiB scoped default: each grid cell holds one KV head's
     whole [Sk, D] K and V, double-buffered by the pipeline, so the need
     grows with the cache length (8 MiB at Sk 8192, D 128, bf16 — the
-    default refuses Sk 16384). Plus the q/o blocks (double-buffered)
-    and the loop's float32 working set; 4 MiB of headroom for Mosaic's
-    own scratch."""
+    default refuses Sk 16384). Plus the q/o blocks of `rows` (a query
+    group's heads x block_q; double-buffered) and the loop's float32
+    working set (accumulator, a key and a value block, four score
+    blocks: scores, weights, mask and the weights cast); 4 MiB of
+    headroom for Mosaic's own scratch."""
     kv = 2 * 2 * sk * d * itemsize
-    qo = 2 * 2 * block_q * d * itemsize
-    work = 4 * (3 * block_q * d + 2 * block_k * d + 4 * block_q * block_k)
+    qo = 2 * 2 * rows * d * itemsize
+    work = 4 * (3 * rows * d + 2 * block_k * d + 4 * rows * block_k)
     return kv + qo + work + (4 << 20)
 
 
@@ -286,22 +340,27 @@ def flash_attention(
     causal: bool = True,
     q_offset: Optional[jnp.ndarray] = None,  # [B] absolute pos of q[0]
     kv_len: Optional[jnp.ndarray] = None,  # [B] valid kv prefix
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,  # None: from the shapes
+    block_k: Optional[int] = None,
     interpret: bool = False,
     window: Optional[int] = None,  # sliding window (causal only)
 ) -> jnp.ndarray:
     """FlashAttention over [B, S, H, D]; S must be a multiple of the
     block sizes (pad upstream; padded keys are masked out via kv_len).
-    K/V keep their KV heads — the grid maps query head h onto KV head
-    h // (H // KVH), so GQA costs no HBM repeat. Compiled for the TPU
-    unless `interpret=True` (CPU tests) asks for the interpreter."""
+    K/V keep their KV heads — a grid cell takes the H // KVH query
+    heads of one KV head together, so GQA costs no HBM repeat and a key
+    block is read once for the group. The products run in the operands'
+    dtype (bf16 in serving, float32 in the CPU tests) and accumulate in
+    float32. Block sizes come from the shapes (`_flash_blocks`) unless
+    the caller names them. Compiled for the TPU unless `interpret=True`
+    (CPU tests) asks for the interpreter."""
     b, sq, h, d = q.shape
     sk, kvh = k.shape[1], k.shape[2]
     assert h % kvh == 0, f"q heads {h} not a multiple of kv heads {kvh}"
     reps = h // kvh
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    auto_q, auto_k = _flash_blocks(sq, sk, reps)
+    block_q = min(block_q or auto_q, sq)
+    block_k = min(block_k or auto_k, sk)
     assert sq % block_q == 0 and sk % block_k == 0, (
         f"seq lens ({sq},{sk}) must be multiples of blocks ({block_q},{block_k})"
     )
@@ -311,48 +370,44 @@ def flash_attention(
     if kv_len is None:
         kv_len = jnp.full((b,), sk, jnp.int32)
 
-    # [B, S, H, D] → [B, H, S, D]: Mosaic wants the squeezed (blocked-
-    # to-1) dims major; the minor two block dims (block_q, d) then meet
-    # the (8, 128)-or-full tiling rule.
-    qh = q.transpose(0, 2, 1, 3)
-    kh = k.transpose(0, 2, 1, 3)  # [B, KVH, Sk, D]
-    vh = v.transpose(0, 2, 1, 3)
-
     assert window is None or causal, "sliding window requires causal"
     kernel = functools.partial(
         _flash_kernel, block_k=block_k, sk=sk, causal=causal,
         block_q=block_q, window=window,
     )
+    # KV heads major, a head's query group `reps * d` columns of a query
+    # row: Mosaic wants the squeezed (blocked-to-1) dims major, and a
+    # block's minor two dims (block_q, reps * d) and (sk, d) then meet
+    # the (8, 128)-or-full tiling rule at any head width. Inside a
+    # program XLA folds the transposes into the producers and the
+    # consumer (PERF.md section 5, PR 46).
+    operands = (
+        q.reshape(b, sq, kvh, reps * d).transpose(0, 2, 1, 3),
+        k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+    )
+    q_spec = pl.BlockSpec(
+        (None, None, block_q, reps * d), lambda bi, gi, qb: (bi, gi, qb, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, sk, d), lambda bi, gi, qb: (bi, gi, 0, 0))
     out = pl.pallas_call(
         kernel,
-        grid=(b, h, sq // block_q),
+        grid=(b, kvh, sq // block_q),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # q_offset [B]
             pl.BlockSpec(memory_space=pltpu.SMEM),  # kv_len [B]
-            pl.BlockSpec(
-                (None, None, block_q, d), lambda bi, hi, qb: (bi, hi, qb, 0)
-            ),
-            pl.BlockSpec(
-                (None, None, sk, d), lambda bi, hi, qb: (bi, hi // reps, 0, 0)
-            ),
-            pl.BlockSpec(
-                (None, None, sk, d), lambda bi, hi, qb: (bi, hi // reps, 0, 0)
-            ),
+            q_spec, kv_spec, kv_spec,
         ],
-        out_specs=pl.BlockSpec(
-            (None, None, block_q, d), lambda bi, hi, qb: (bi, hi, qb, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((reps * block_q, d), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct(operands[0].shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_flash_vmem_bytes(
-                sk, d, block_q, block_k, k.dtype.itemsize
+                sk, d, reps * block_q, block_k, k.dtype.itemsize
             ),
         ),
         interpret=interpret,
-    )(
-        q_offset.astype(jnp.int32), kv_len.astype(jnp.int32), qh, kh, vh
-    )
-    return out.transpose(0, 2, 1, 3)
+    )(q_offset.astype(jnp.int32), kv_len.astype(jnp.int32), *operands)
+    return out.transpose(0, 2, 1, 3).reshape(b, sq, h, d)
 
 
 # ---------------------------------------------------------------------------
@@ -381,8 +436,8 @@ def flash_attention_sharded(
     causal: bool = True,
     q_offset: Optional[jnp.ndarray] = None,
     kv_len: Optional[jnp.ndarray] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: bool = False,
     window: Optional[int] = None,
 ) -> jnp.ndarray:

@@ -1,5 +1,6 @@
-"""The latent-prefill kernel compiled at the served widths for a v5e
-that is described, not attached (the TPU's compiler is installed here):
+"""The latent-prefill kernel, the dense family's prefill kernel and the
+grouped-experts kernel compiled at the served widths for a v5e that is
+described, not attached (the TPU's compiler is installed here):
 what the interpreter cannot refuse, Mosaic can (a slab that does not
 tile, a broadcast it has no lowering for, more VMEM than a kernel may
 use). Nothing runs, and no time or result is read off it.
@@ -104,6 +105,38 @@ def test_per_shard_with_a_selection_compiles_for_a_2x2(topo, uncached):
         selected=True, rows=2,
     )).compile()
     assert "latent_attention_prefill" in compiled.as_text()
+
+
+# The dense family's prefill kernel at the two shapes the benchmark's
+# cells reach: a chunk of the `[8, 4, 512]` admission grid over its
+# 2,048-position mini cache (mistral's window rides along and cuts
+# nothing there), and one row of 256 tokens, fresh or the suffix of a
+# reused prefix in a mini cache of the full width.
+FLASH_CALLS = {
+    "8_rows_512_on_2048_window_4096": dict(
+        rows=8, sq=512, sk=2048, window=4096),
+    "1_row_256_on_2048_window_4096": dict(
+        rows=1, sq=256, sk=2048, window=4096),
+    "1_row_256_fresh": dict(rows=1, sq=256, sk=256, window=None),
+}
+
+
+@pytest.mark.parametrize("case", FLASH_CALLS, ids=list(FLASH_CALLS))
+def test_the_flash_kernel_compiles_for_a_v5e(topo, uncached, case):
+    rows, sq, sk, window = FLASH_CALLS[case].values()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        functools.partial(A.flash_attention, causal=True, window=window)
+    ).lower(
+        shape((rows, sq, 32, 128)), shape((rows, sk, 8, 128)),
+        shape((rows, sk, 8, 128)),
+        q_offset=shape((rows,), jnp.int32), kv_len=shape((rows,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 # The grouped-experts kernel (ops/experts.py) at the served widths:

@@ -12,7 +12,7 @@ import pytest
 
 from ggrmcp_tpu.core.config import BatchingConfig, MeshConfig, ServingConfig
 from ggrmcp_tpu.models import llama
-from ggrmcp_tpu.ops.attention import attention_xla
+from ggrmcp_tpu.ops.attention import attention_xla, flash_attention
 from ggrmcp_tpu.serving.engine import GenerationEngine
 
 CFG = llama.CONFIGS["tiny-mistral"]
@@ -127,6 +127,91 @@ class TestFlashWindow:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=2e-5
         )
+
+
+class TestFlashOperands:
+    """The kernel on the operands serving hands it (bf16), a KV head's
+    whole query group a block, and the block sizes it picks. Here and
+    not in tests/test_models.py, which is `slow` as a module: these
+    run in tier-1."""
+
+    @pytest.mark.parametrize("case", [
+        # 32 query heads on 8 KV heads, each row its own offset, kv_len
+        # ending inside a key block
+        dict(id="gqa_32_on_8_row_offsets", h=32, kvh=8, q_offset=[0, 70],
+             kv_len=[128, 198]),
+        dict(id="kv_len_inside_a_block", h=8, kvh=2, q_offset=[0, 0],
+             kv_len=[100, 37]),
+        dict(id="a_row_with_no_keys", h=8, kvh=2, q_offset=[0, 64],
+             kv_len=[0, 192]),
+        dict(id="reps_1", h=4, kvh=4, q_offset=[0, 70], kv_len=[128, 198]),
+        dict(id="blocks_from_the_shapes", h=8, kvh=2, q_offset=[0, 70],
+             kv_len=[128, 198], blocks={}),
+        dict(id="not_causal", h=8, kvh=2, q_offset=None, kv_len=[384, 150],
+             causal=False),
+        # the served head width
+        dict(id="heads_of_128", h=4, kvh=2, q_offset=[0, 70],
+             kv_len=[128, 198], d=128),
+        # the window's lower edge inside a 64-key block, between it and
+        # the diagonal blocks that run unmasked
+        dict(id="window_edge_inside_a_block", h=8, kvh=2, q_offset=[256, 70],
+             kv_len=[384, 198], window=100),
+        dict(id="window_edge_reps_1", h=4, kvh=4, q_offset=[256, 70],
+             kv_len=[384, 198], window=100),
+        # narrower than a block: edge and diagonal cut the same block
+        dict(id="window_inside_one_block", h=8, kvh=2, q_offset=[256, 70],
+             kv_len=[384, 198], window=40),
+    ], ids=lambda case: case["id"])
+    def test_flash_bf16_operands_match_xla(self, case):
+        """bf16 in, as the serving cache hands them over: the kernel's
+        products take them as they are (float32 only where it
+        accumulates), a KV head's whole query group a block. Held to
+        attention_xla on the same bf16 values, which rounds its weights
+        to bf16 the same way; both round the output (half an ulp at
+        |x| <= 1 is 2e-3). A row that may see no key emits zeros."""
+        key = jax.random.PRNGKey(21)
+        b, sq, sk, d = 2, 128, 384, case.get("d", 16)
+        q = jax.random.normal(key, (b, sq, case["h"], d), jnp.bfloat16)
+        k, v = (
+            jax.random.normal(jax.random.fold_in(key, i),
+                              (b, sk, case["kvh"], d), jnp.bfloat16)
+            for i in (1, 2)
+        )
+        rows = dict(
+            causal=case.get("causal", True), window=case.get("window"),
+            q_offset=(None if case["q_offset"] is None
+                      else jnp.asarray(case["q_offset"], jnp.int32)),
+            kv_len=jnp.asarray(case["kv_len"], jnp.int32),
+        )
+        reps = case["h"] // case["kvh"]
+        ref = attention_xla(
+            q, jnp.repeat(k, reps, axis=2), jnp.repeat(v, reps, axis=2),
+            **rows)
+        out = flash_attention(
+            q, k, v, interpret=True,
+            **case.get("blocks", dict(block_q=64, block_k=64)), **rows)
+        assert out.dtype == jnp.bfloat16
+        out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+        for row, n in enumerate(case["kv_len"]):
+            if n == 0:
+                assert not out[row].any()
+            else:
+                np.testing.assert_allclose(
+                    out[row], ref[row], atol=2e-2, rtol=2e-2)
+
+    @pytest.mark.parametrize("sq,sk,reps,blocks", [
+        (512, 2048, 4, (128, 512)),  # a chunk of the admission grid
+        (256, 2048, 4, (128, 512)),  # a suffix on a reused prefix
+        (256, 256, 4, (128, 256)),  # a fresh short prompt
+        (512, 2048, 1, (512, 512)),  # no group: the rows are queries
+        (512, 2048, 16, (128, 128)),  # a wide group narrows the key block
+        (384, 640, 4, (128, 128)),  # what divides
+        (64, 64, 2, (64, 64)),  # shorter than a block: one block
+    ])
+    def test_flash_blocks_from_the_shapes(self, sq, sk, reps, blocks):
+        from ggrmcp_tpu.ops.attention import _flash_blocks
+
+        assert _flash_blocks(sq, sk, reps) == blocks
 
 
 class TestMistralModel:
