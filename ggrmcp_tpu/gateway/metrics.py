@@ -177,6 +177,9 @@ _SERVING_HELP = {
     "prefill_tokens_reused":
         "prompt tokens admissions took from shared pages or a prefix "
         "entry instead of computing them",
+    "prefill_chunk_tokens_run":
+        "token positions of the chunk rows the admission programs ran "
+        "(prefill_tokens_computed over this is the chunks' fill)",
     # Disaggregated prefill/decode serving (serving.role): the
     # sidecar→sidecar KV page-shipping plane. The role itself is a
     # string field and exports info-style beside mesh_shape.

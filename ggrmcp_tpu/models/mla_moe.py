@@ -114,16 +114,13 @@ ROUTING_STATS = (
     "experts_hit", "load_max", "pairs", "pairs_absent",
     "sparse_selected", "sparse_visible", "sparse_layer_steps",
 )
-# A cold prompt of more chunks than this runs on a chunk grid rounded
-# up to a power of two and is admitted alone (at the default chunk of
-# 512: past 2,048 tokens). This family serves contexts of 6k-13k
-# tokens, where exact depths are a program each (13..24 chunks); the
-# padding chunks are cheap here because `attention_block` stops its
-# walk over the keys at the last `valid` one (the XLA walk for the
-# whole step; the prefill kernel a query tile, and a tile of padding
-# walks nothing). llama's chunk attention
-# has no such mask, so llama keeps exact depths and group admission, as
-# before this family came (no cell measures llama past 2,048 tokens).
+# A cold prompt of more chunks than this is admitted alone, in arrival
+# order (at the default chunk of 512: past 2,048 tokens). This family
+# serves contexts of 6k-13k tokens, a whole prefill each: a group's
+# rows all finish with its deepest, so whether two such prompts that
+# arrived a millisecond apart were popped together would move the first
+# one's first token by the other's prefill. llama names no such depth
+# and keeps group admission (no cell measures llama past 2,048 tokens).
 DEEP_GRID_CHUNKS = 4
 
 

@@ -608,10 +608,9 @@ class ContinuousBatcher:
         # extra row each (_decode_scan) and are summed at collect time
         # into `model_counts` under those names (the family's module
         # says what each one counts). DEEP_GRID_CHUNKS: a cold prompt of
-        # more chunks than this is a deep grid; its chunk count rounds
-        # up to a power of two and it is admitted alone
-        # (_route_admission, _admit_chunked_group). None: every grid
-        # keeps its exact depth and its group.
+        # more chunks than this is admitted alone, in arrival order
+        # (_route_admission). None: every cold long prompt of a round
+        # shares its group's call.
         self._head_at_index = getattr(self.fam, "HEAD_AT_INDEX", False)
         self._routing_stats = tuple(getattr(self.fam, "ROUTING_STATS", ()))
         self._deep_grid = getattr(self.fam, "DEEP_GRID_CHUNKS", None)
@@ -625,11 +624,19 @@ class ContinuousBatcher:
         # the whole pool. Larger groups go in consecutive calls.
         rows_of = getattr(self.fam, "admission_rows", None)
         self._mini_rows = min(b, (rows_of(engine.cfg) if rows_of else None) or b)
+        # Depth of every cold chunked admission's token grid: the
+        # chunks of the longest prompt a request may bring. A constant,
+        # so a prompt's depth shapes no program (_admit_chunked_group).
+        self._grid_chunks = -(-self._fit_limit // min(
+            self.cfg.prefill_chunk, self.max_seq))
         self.model_counts = dict.fromkeys(
             self._routing_stats + ("layer_steps",), 0)
         # Prompt tokens admission programs computed, and prompt tokens
-        # they took from pages or a prefix entry instead.
-        self.prefill_tokens = {"computed": 0, "reused": 0}
+        # they took from pages or a prefix entry instead; chunk_run:
+        # the token positions of the chunk rows those programs ran
+        # (_admission_program), computed / chunk_run their fill. All
+        # three are stamped at a round's end (_prefill_into_slots).
+        self.prefill_tokens = {"computed": 0, "reused": 0, "chunk_run": 0}
 
         # Admissions that reused shared pages, and those that could not
         # (the paged path stamps both).
@@ -726,6 +733,7 @@ class ContinuousBatcher:
         # by the admission paths, read into its AdmissionRecord.
         self._adm_families: list[str] = []
         self._adm_reused = 0
+        self._adm_chunk_run = 0
         # The round's own clock and what its profiler spans carry
         # (_prefill_into_slots opens both; _admission_program and the
         # activation loops mark and annotate where the work happens).
@@ -1329,13 +1337,42 @@ class ContinuousBatcher:
         return first, llama_mod.map_planes(
             select, cache, mini, length=lengths)
 
+    def _chunk_step(
+        self, params, chunk, off, true_len, last, mini, fl, adapters
+    ):
+        """Extend `mini` by one [B, C] chunk whose first token sits at
+        absolute position `off`, and keep in `fl` [B, V] the logits at
+        each row's final prompt position `last` (true_len - 1, taken
+        once by the caller) if this chunk holds it. The one chunk body
+        of every chunked admission (_chunked_scan's grid,
+        _chunked_rows' per-row walk)."""
+        c = chunk.shape[1]
+        if self._is_moe:
+            valid = (off + jnp.arange(c))[None, :] < true_len[:, None]
+        else:
+            valid = None
+        idx = jnp.clip(last - off, 0, c - 1)
+        logits, mini = self.engine.decode_forward(
+            params, chunk, mini, valid=valid, ring=self._ring,
+            lora_idx=adapters,
+            logit_idx=idx if self._head_at_index else None,
+        )
+        if self._head_at_index:  # logits [B, 1, V], at idx already
+            idx = jnp.zeros_like(idx)
+        sel = jnp.take_along_axis(
+            logits, idx[:, None, None], axis=1
+        )[:, 0]
+        take = (last >= off) & (last < off + c)
+        fl = jnp.where(take[:, None], sel.astype(fl.dtype), fl)
+        return mini, fl
+
     def _chunked_scan(self, params, tokens, true_len, mini, adapters, start):
         """lax.scan over a [B, T, C] chunk grid: each step extends
         `mini` (which must already hold `start` positions per row) by
         one [B, C] chunk and captures the logits at each row's final
-        prompt position as it passes. Rows shorter than the grid
-        process padding chunks whose K/V land past their final length
-        (masked on merge, exactly like the serial chunked path).
+        prompt position as it passes (_chunk_step). Every row of a
+        paged prefix-reuse group has the grid's depth (the group's key
+        holds it), so no step is a row's padding.
         Returns (final_logits [B, V] f32, mini)."""
         b, t_steps, c = tokens.shape
         carry0 = jnp.zeros((b, self.engine.cfg.vocab_size), jnp.float32)
@@ -1344,29 +1381,60 @@ class ContinuousBatcher:
         def body(carry, xs):
             mini, fl = carry
             chunk, off = xs
-            if self._is_moe:
-                valid = (off + jnp.arange(c))[None, :] < true_len[:, None]
-            else:
-                valid = None
-            idx = jnp.clip(last - off, 0, c - 1)
-            logits, mini = self.engine.decode_forward(
-                params, chunk, mini, valid=valid, ring=self._ring,
-                lora_idx=adapters,
-                logit_idx=idx if self._head_at_index else None,
-            )
-            if self._head_at_index:  # logits [B, 1, V], at idx already
-                idx = jnp.zeros_like(idx)
-            sel = jnp.take_along_axis(
-                logits, idx[:, None, None], axis=1
-            )[:, 0]
-            take = (last >= off) & (last < off + c)
-            fl = jnp.where(take[:, None], sel.astype(fl.dtype), fl)
-            return (mini, fl), None
+            return self._chunk_step(
+                params, chunk, off, true_len, last, mini, fl, adapters
+            ), None
 
         offs = start + jnp.arange(t_steps, dtype=jnp.int32) * c
         (mini, fl), _ = jax.lax.scan(
             body, (mini, carry0), (jnp.moveaxis(tokens, 1, 0), offs)
         )
+        return fl, mini
+
+    def _chunked_rows(self, params, tokens, true_len, adapters):
+        """A cold [R, T_max, C] admission, a row at a time in the order
+        given: row r runs its own ceil(true_len[r] / C) chunks, one
+        [1, C] step each against a one-row mini (_chunk_step), and
+        nothing past them — the grid's depth T_max is the batcher's
+        constant and shapes no work, a padding row of the bucket
+        (true_len 0) runs no chunk. A chunk of one row is already
+        compute-bound, so the weights' extra passes hide under its
+        products. Returns (final_logits [R, V] f32, mini [R, max_seq])."""
+        r, _, c = tokens.shape
+        vocab = self.engine.cfg.vocab_size
+
+        def row(j, mini1):
+            """Row j's chunks into the fresh one-row `mini1`."""
+            n = jax.lax.dynamic_slice_in_dim(true_len, j, 1)
+            lora = jax.lax.dynamic_slice_in_dim(adapters, j, 1)
+            chunks = jax.lax.dynamic_index_in_dim(
+                tokens, j, keepdims=False)  # [T_max, C]
+
+            def step(t, carry):
+                chunk = jax.lax.dynamic_slice_in_dim(chunks, t, 1)
+                return self._chunk_step(
+                    params, chunk, t * c, n, n - 1, *carry, lora)
+
+            return jax.lax.fori_loop(
+                0, (n[0] + c - 1) // c, step,
+                (mini1, jnp.zeros((1, vocab), jnp.float32)))
+
+        if r == 1:  # the row's mini is the group's
+            mini, fl = row(jnp.int32(0), self._make_mini(1, self.max_seq))
+            return fl, mini
+
+        def body(j, carry):
+            mini, fl = carry
+            mini1, fl1 = row(j, self._make_mini(1, self.max_seq))
+            mini = llama_mod.map_planes(
+                lambda m, m1: jax.lax.dynamic_update_slice_in_dim(
+                    m, m1, j, axis=1), mini, mini1)
+            return mini, jax.lax.dynamic_update_slice_in_dim(
+                fl, fl1, j, axis=0)
+
+        mini, fl = jax.lax.fori_loop(
+            0, r, body, (self._make_mini(r, self.max_seq),
+                         jnp.zeros((r, vocab), jnp.float32)))
         return fl, mini
 
     def _chunked_finish(
@@ -1399,16 +1467,14 @@ class ContinuousBatcher:
         self, params, tokens, true_len, cache, slots, seeds, temps, ks,
         ps, adapters, g0, g_allow, g_trans,
     ):
-        """Fused chunked admission (nothing reused): the whole [R, T, C]
-        prefill grid + merge + first-token sample, ONE device call.
-        R is the caller's bucketed group size — per-row work here is
-        the heavy case (long prompts), so a trickle admission must not
-        pay the full slot pool's compute."""
-        r = tokens.shape[0]
-        mini = self._make_mini(r, self.max_seq)
-        fl, mini = self._chunked_scan(
-            params, tokens, true_len, mini, adapters, jnp.int32(0)
-        )
+        """Fused chunked admission (nothing reused): every row's own
+        chunks of the [R, T_max, C] grid (_chunked_rows) + merge +
+        first-token sample, ONE device call, and one program a row
+        bucket whatever the prompts' depths. R is the caller's bucketed
+        group size — per-row work here is the heavy case (long
+        prompts), so a trickle admission must not pay the full slot
+        pool's compute."""
+        fl, mini = self._chunked_rows(params, tokens, true_len, adapters)
         return self._chunked_finish(
             cache, mini, slots, true_len, fl, seeds, temps, ks, ps,
             g0, g_allow, g_trans,
@@ -1940,11 +2006,10 @@ class ContinuousBatcher:
                     self._g_jstate_dev,
                     jnp.asarray(np.zeros((b,), bool)),
                 )
-        # Fused chunked-admission programs. The long-prompt grid
-        # ([B, T, C]) compiles per distinct T — warm the single-chunk
-        # grid when the chunked path is reachable (deeper grids compile
-        # on their first long prompt; callers that care, like the
-        # bench, send one long warmup request off the clock).
+        # Fused chunked-admission programs: one a row bucket, whatever
+        # the prompts' depths (the grid's depth is `_grid_chunks`, and
+        # each row's chunk count is read on the device), so these calls
+        # compile every program a cold long prompt can run.
         b_rows = len(self.slots)
         zlenb = np.zeros((b_rows,), np.int32)
         # Out-of-range slot indices: the insert scatter drops every
@@ -1956,12 +2021,8 @@ class ContinuousBatcher:
         ofb = np.ones((b_rows,), np.float32)
         c = min(self.cfg.prefill_chunk, self.max_seq)
         if self.cfg.prefill_chunk < self._fit_limit or self._ring:
-            # Warm every reachable row bucket (R = 1, 2, 4 .. B) at
-            # T=1. Deeper T grids still compile on their first long
-            # prompt (warming the full R×T product would be quadratic
-            # in compile time) — callers that care send off-clock
-            # long warmup requests (the bench does), and the
-            # persistent compile cache keeps programs across runs.
+            # Every reachable row bucket (R = 1, 2, 4 .. B), all rows
+            # empty: no chunk runs.
             r_buckets = []
             r_bucket = 1
             while r_bucket < self._mini_rows:
@@ -1974,7 +2035,8 @@ class ContinuousBatcher:
             for r_bucket in r_buckets:
                 _, self.cache = self._admit_chunked(
                     self.engine.params,
-                    jnp.asarray(np.zeros((r_bucket, 1, c), np.int32)),
+                    jnp.asarray(np.zeros(
+                        (r_bucket, self._grid_chunks, c), np.int32)),
                     jnp.asarray(zlenb[:r_bucket]), self.cache,
                     jnp.asarray(zslotb[:r_bucket]),
                     jnp.asarray(zseedb[:r_bucket]),
@@ -2682,6 +2744,9 @@ class ContinuousBatcher:
             # prompt tokens taken from shared pages or a prefix entry.
             "prefill_tokens_computed": self.prefill_tokens["computed"],
             "prefill_tokens_reused": self.prefill_tokens["reused"],
+            # Token positions of the chunk rows those programs ran:
+            # what prefill_tokens_computed fills.
+            "prefill_chunk_tokens_run": self.prefill_tokens["chunk_run"],
         }
 
     # -- the loop -----------------------------------------------------------
@@ -3428,6 +3493,7 @@ class ContinuousBatcher:
                 if not batch:
                     return
             self._adm_families, self._adm_reused = [], 0
+            self._adm_chunk_run = 0
             queued, shed_rows = self._route_admission(slots_idx, batch)
         # What is left after the last activation loop is the draft-side
         # admission (spec mode) and the way out; a round that launched
@@ -3443,6 +3509,7 @@ class ContinuousBatcher:
         prompt_tokens = sum(len(r.prompt) for r in batch)
         self.prefill_tokens["reused"] += self._adm_reused
         self.prefill_tokens["computed"] += prompt_tokens - self._adm_reused
+        self.prefill_tokens["chunk_run"] += self._adm_chunk_run
         self.recorder.note_admission(
             timer, "+".join(self._adm_families),
             [r.trace_id for r in batch if r.trace_id],
@@ -3470,11 +3537,18 @@ class ContinuousBatcher:
         self._adm_reused += reused_tokens
 
     def _admission_program(
-        self, launch, family: str, rows: int, chunks: int, tokens: int
+        self, launch, family: str, rows: int, chunks: int, tokens: int,
+        width: int,
     ) -> np.ndarray:
         """Run ONE admission program and bring each row's first token
         to the host. `launch()` makes the jitted call, which donates
-        the shared cache, and returns (first, cache). The round's timer
+        the shared cache, and returns (first, cache). `chunks` is the
+        number of [1, width] chunk rows the program runs, a bucket's
+        padding rows and a grid's no-op chunks among them where the
+        program computes those: chunks x width is what the `tokens`
+        prompt tokens it computes fill (prefill_chunk_tokens_run beside
+        prefill_tokens_computed, both stamped at the round's end, so a
+        delta of the two covers the same rounds). The round's timer
         is marked where each thing happens (flight_recorder.
         ADMIT_HOST_MARKS): `build` closes here (the caller's numpy
         grids and grammar tables, the table sync), `launch` when the
@@ -3488,10 +3562,13 @@ class ContinuousBatcher:
         timer = self._adm_timer
         self._sync_tables()
         self._cache_at_risk = True
+        run = chunks * width
+        self._adm_chunk_run += run
         timer.mark("build")
         with tracing.annotation(
             "ggrmcp.admit.program", family=family, rows=rows,
-            chunks=chunks, tokens=tokens, **self._adm_span,
+            chunks=chunks, tokens=tokens, chunk_tokens_run=run,
+            **self._adm_span,
         ):
             first, self.cache = launch()
             timer.mark("launch")
@@ -3691,28 +3768,20 @@ class ContinuousBatcher:
         self, rows: list[tuple[int, _Request]]
     ) -> None:
         """ONE fused device call admitting `rows` (slot, request)
-        pairs: full prompts run the [R, T, prefill_chunk] grid from
-        position 0 (rows shorter than the deepest prompt pad with
-        no-op chunks).
+        pairs: each prompt lies from position 0 in its row of an
+        [R, T_max, prefill_chunk] grid whose depth is the batcher's
+        constant (`_grid_chunks`), and the program runs each row's own
+        ceil(len / chunk) chunks (_chunked_rows): the prompts' depths
+        shape no program.
 
         Row-count bucketing: long-prompt groups compile per power-of-2
         R (a trickle long admission must not pay the full slot pool's
         prefill compute). Padding rows carry slot index B (out of range
-        → dropped by the insert scatter)."""
+        → dropped by the insert scatter) and length 0 (no chunk)."""
         b = len(self.slots)
         c = min(self.cfg.prefill_chunk, self.max_seq)
-        n_max = max(len(req.prompt) for _, req in rows)
-        t_steps = max(1, -(-n_max // c))
-        if self._deep_grid is not None and t_steps > self._deep_grid:
-            # Deep grids round up to a power of two: a long-context
-            # deployment compiles log2(S_max / chunk) programs, not
-            # one for every chunk count. The padding chunks run
-            # with no valid token, which the family's attention
-            # walk skips.
-            t_steps = min(
-                bucket_len(t_steps, minimum=1), -(-self.max_seq // c))
         r = min(b, bucket_len(len(rows), minimum=1))
-        tokens = np.zeros((r, t_steps, c), np.int32)
+        tokens = np.zeros((r, self._grid_chunks, c), np.int32)
         true_len = np.zeros((r,), np.int32)
         slots_arr = np.full((r,), b, np.int32)  # pad = out of range
         seeds = np.zeros((r,), np.uint32)
@@ -3742,8 +3811,9 @@ class ContinuousBatcher:
                 jnp.asarray(ps), jnp.asarray(adapters),
                 jnp.asarray(g0s), g_allow, g_trans,
             ),
-            "chunked", rows=len(rows), chunks=t_steps,
-            tokens=int(true_len.sum()),
+            "chunked", rows=len(rows),
+            chunks=int((-(-true_len // c)).sum()),
+            tokens=int(true_len.sum()), width=c,
         )
         self._activate_rows(
             [(sl, req, int(first[j])) for j, (sl, req) in enumerate(rows)]
@@ -3798,8 +3868,9 @@ class ContinuousBatcher:
                 jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(ps),
                 jnp.asarray(adapters), jnp.asarray(g0s), g_allow, g_trans,
             ),
-            "paged_pfx", rows=len(rows), chunks=t_steps,
+            "paged_pfx", rows=len(rows), chunks=r * t_steps,
             tokens=int(true_len.sum()) - scan_start * len(rows),
+            width=width,
         )
         self._activate_rows(
             [(sl, req, int(first[j])) for j, (sl, req, _) in enumerate(rows)]
@@ -3858,7 +3929,8 @@ class ContinuousBatcher:
                 jnp.asarray(ps), jnp.asarray(adapters),
                 jnp.asarray(g0s), g_allow, g_trans,
             ),
-            family, rows=len(batch), chunks=1, tokens=int(true_len.sum()),
+            family, rows=len(batch), chunks=rows,
+            tokens=int(true_len.sum()), width=s,
         )
         self._activate_rows([
             (slot_idx, req, int(first[row_of(j)]))

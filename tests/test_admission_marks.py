@@ -62,6 +62,7 @@ def _round(inflight=()) -> ContinuousBatcher:
     b._inflight = [(t, None, [], None, "plain") for t in inflight]
     b._adm_timer = PhaseTimer()
     b._adm_span = {"seq": 1, "tick": 1}
+    b._adm_chunk_run = 0
     return b
 
 
@@ -71,7 +72,8 @@ def _program(b, seen: list, first=(7,)):
         seen.append(b._cache_at_risk)
         return np.asarray(first, np.int32), "cache"
 
-    return b._admission_program(launch, "single", rows=1, chunks=1, tokens=3)
+    return b._admission_program(
+        launch, "single", rows=1, chunks=1, tokens=3, width=4)
 
 
 class TestTheRoundsMarks:
@@ -335,11 +337,102 @@ class TestSpans:
         by_name = {name: stats for kind, name, stats in admit}
         assert by_name["ggrmcp.admit.program"] == {
             "seq": 1, "tick": 1, "family": "chunked",
-            "rows": 1, "chunks": 3, "tokens": 80,
+            "rows": 1, "chunks": 3, "tokens": 80, "chunk_tokens_run": 96,
         }
         for name in ("ggrmcp.admit.device", "ggrmcp.admit.activate"):
             assert by_name[name] == by_name["ggrmcp.admit"] == {
                 "seq": 1, "tick": 1}
+
+
+class TestTheChunksThatRan:
+    """`prefill_chunk_tokens_run` (PR 47): the token positions of the
+    chunk rows the admission programs ran, beside the prompt tokens
+    they computed; the program span's `chunks` and `chunk_tokens_run`
+    say the same of each call."""
+
+    @pytest.mark.parametrize("case, want", [
+        # A cold chunked round of 80, 33 and 64 tokens at chunk 32:
+        # each row's own 3, 2 and 2 chunks, not 3 x the bucket of four.
+        ("chunked", {"family": "chunked", "rows": 3, "chunks": 7,
+                     "tokens": 177, "chunk_tokens_run": 32 * sum(
+                         -(-n // 32) for n in (80, 33, 64))}),
+        # One short prompt: the single-row program at its 32 bucket.
+        ("single", {"family": "single", "rows": 1, "chunks": 1,
+                    "tokens": 9, "chunk_tokens_run": 32}),
+        # A burst of three: the full-pool program runs all four rows.
+        ("full", {"family": "full", "rows": 3, "chunks": 4,
+                  "tokens": 27, "chunk_tokens_run": 4 * 32}),
+    ])
+    async def test_the_counter_and_the_span_agree(
+        self, mistral_engine, monkeypatch, case, want
+    ):
+        import jax
+
+        spans: list = []
+
+        class Spy:
+            def __init__(self, name, **stats):
+                if name == "ggrmcp.admit.program":
+                    spans.append(stats)
+
+            def __enter__(self):
+                pass
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+        monkeypatch.setattr(tracing, "capture_running", True)
+        lens = {"chunked": (80, 33, 64), "single": (9,),
+                "full": (9, 9, 9)}[case]
+        batcher = _batcher(mistral_engine, prefill_chunk=32)
+        # Queued before the loop starts: one admission round.
+        tasks = [
+            asyncio.ensure_future(
+                _consume(batcher, [3 + i] * n, 2, seed=i))
+            for i, n in enumerate(lens)
+        ]
+        await asyncio.sleep(0)
+        batcher.start()
+        try:
+            await asyncio.gather(*tasks)
+        finally:
+            await batcher.stop()
+        [span] = spans
+        assert {k: span[k] for k in want} == want
+        stats = batcher.counter_stats()
+        assert stats["prefill_chunk_tokens_run"] == want["chunk_tokens_run"]
+        assert stats["prefill_tokens_computed"] == want["tokens"]
+
+    async def test_a_paged_suffix_group_counts_its_buckets_rows(
+        self, mistral_engine
+    ):
+        """A same-preamble wave of three re-admissions: the suffix grid
+        runs the bucket of four rows x one chunk of the suffix's
+        16-token bucket, padding row included, as that program does."""
+        batcher = await _run(
+            mistral_engine,
+            [[PREAMBLE + [70]], [PREAMBLE + [71 + i] for i in range(3)]],
+            paged_kv="on", paged_kv_page_size=16)
+        fams = [a.family for a in batcher.recorder.admission_snapshot()]
+        assert fams[0] == "single" and "paged_pfx" in fams[-1]
+        stats = batcher.counter_stats()
+        assert stats["prefill_tokens_reused"] > 0
+        # 65 tokens cold at their 128 bucket, then the wave's grids.
+        assert stats["prefill_chunk_tokens_run"] > 128
+        assert stats["prefill_chunk_tokens_run"] % 16 == 0
+        assert stats["prefill_chunk_tokens_run"] >= (
+            stats["prefill_tokens_computed"])
+
+    def test_the_field_reaches_the_proto_and_the_metrics(self):
+        from ggrmcp_tpu.gateway import metrics
+        from ggrmcp_tpu.rpc.pb import serving_pb2
+
+        field = serving_pb2.ServingStatsResponse.DESCRIPTOR.fields_by_name[
+            "prefill_chunk_tokens_run"]
+        assert field.number == 177
+        assert "chunk rows" in metrics._SERVING_HELP[
+            "prefill_chunk_tokens_run"]
 
 
 def test_the_clock_check_pairs_a_device_span_with_its_module():

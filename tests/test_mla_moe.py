@@ -267,39 +267,59 @@ async def test_page_reuse_and_cow_over_latent_pages(engine):
     assert stats["moe_load_max_sum"] >= steps  # some expert was hit a step
 
 
+def _spy_on_chunked(batcher) -> list:
+    """Every `_admit_chunked` call's (tokens' shape, rows' lengths)."""
+    calls = []
+    inner = batcher._admit_chunked
+
+    def spy(params, tokens, true_len, *rest):
+        calls.append((tuple(tokens.shape), np.asarray(true_len).tolist()))
+        return inner(params, tokens, true_len, *rest)
+
+    batcher._admit_chunked = spy
+    return calls
+
+
+async def _one_round(batcher, prompts, max_new):
+    """Every prompt queued before the loop starts: one admission round."""
+    tasks = [asyncio.ensure_future(_collect(batcher, p, max_new, i))
+             for i, p in enumerate(prompts)]
+    await asyncio.sleep(0)
+    batcher.start()
+    try:
+        return await asyncio.gather(*tasks)
+    finally:
+        await batcher.stop()
+
+
 async def test_deep_grids_round_up_and_go_one_row_a_call(engine):
-    """Cold prompts past the family's DEEP_GRID_CHUNKS: each is admitted
-    alone (so two that arrive together do not finish together) on a grid
-    rounded up to a power of two (7 and 6 chunks -> 8); a shallow one
-    beside them keeps its exact depth. Outputs are the engine's own."""
-    prompts = [ids_of(100, salt=30), ids_of(90, salt=31), ids_of(40, salt=32)]
+    """Cold prompts past the family's DEEP_GRID_CHUNKS (7 and 6 chunks
+    against 4): each is admitted alone, in arrival order (so two that
+    arrive together do not finish together); the two shallow ones
+    beside them share one call, which goes first. Nothing rounds up any
+    more: every call's `tokens` is (R, T_max, 16) whatever the depths,
+    T_max the batcher's ceil(256 / 16), and the program runs each row's
+    own chunks. Outputs are the engine's own."""
+    prompts = [ids_of(100, salt=30), ids_of(40, salt=32),
+               ids_of(90, salt=31), ids_of(50, salt=33)]
     expected, _ = engine.generate(prompts, max_new_tokens=5, seed=0)
     batcher = ContinuousBatcher(engine, BatchingConfig(
         max_batch_size=4, kv_cache_max_seq=256, paged_kv="on",
         paged_kv_page_size=8, prefill_chunk=16))
-    shapes = []
-    inner = batcher._admit_chunked
-
-    def spy(params, tokens, *rest):
-        shapes.append(tuple(tokens.shape))
-        return inner(params, tokens, *rest)
-
-    batcher._admit_chunked = spy
-    batcher.start()
-    try:
-        outs = await asyncio.gather(*(
-            _collect(batcher, p, 5, i) for i, p in enumerate(prompts)))
-    finally:
-        await batcher.stop()
+    assert batcher._deep_grid == 4 and batcher._grid_chunks == 16
+    calls = _spy_on_chunked(batcher)
+    outs = await _one_round(batcher, prompts, 5)
     assert outs == expected
-    assert sorted(shapes) == [(1, 3, 16), (1, 8, 16), (1, 8, 16)]
+    assert calls == [
+        ((2, 16, 16), [40, 50]), ((1, 16, 16), [100]), ((1, 16, 16), [90])]
 
 
 async def test_a_family_without_deep_grids_keeps_depth_and_group():
-    """llama's module names no DEEP_GRID_CHUNKS: the same three cold
-    prompts go in one call at the deepest prompt's exact depth (7), as
-    before the latent family came; the batcher asks the module, it
-    never tells families apart."""
+    """llama's module names no DEEP_GRID_CHUNKS: the same cold prompts
+    go in one call, as before the latent family came (the batcher asks
+    the module, it never tells families apart), on the same
+    (R, T_max, 16) grid: the bucket's fourth row is padding, of length
+    0, and runs no chunk."""
     cfg = llama.CONFIGS["tiny-llama"]
     eng = GenerationEngine(cfg, _serving())
     rng = np.random.RandomState(5)
@@ -311,25 +331,150 @@ async def test_a_family_without_deep_grids_keeps_depth_and_group():
         paged_kv_page_size=8, prefill_chunk=16))
     assert batcher._deep_grid is None and not batcher._head_at_index
     assert not batcher._routing_stats
-    shapes = []
-    inner = batcher._admit_chunked
+    calls = _spy_on_chunked(batcher)
+    outs = await _one_round(batcher, prompts, 3)
+    assert outs == expected
+    assert calls == [((4, 16, 16), [100, 90, 40, 0])]
 
-    def spy(params, tokens, *rest):
-        shapes.append(tuple(tokens.shape))
-        return inner(params, tokens, *rest)
 
-    batcher._admit_chunked = spy
-    # all three queued before the loop starts: one admission round
-    tasks = [asyncio.ensure_future(_collect(batcher, p, 3, i))
-             for i, p in enumerate(prompts)]
-    await asyncio.sleep(0)
+@pytest.fixture(scope="module", params=["llama", "mla_moe", "keye"])
+def family_engine(request):
+    from ggrmcp_tpu.models import keye
+
+    cfg = {"llama": llama.CONFIGS["tiny-llama"], "mla_moe": CFG,
+           "keye": keye.CONFIGS["tiny-keye"]}[request.param]
+    return GenerationEngine(cfg, _serving())
+
+
+def _paged_batcher(eng):
+    return ContinuousBatcher(eng, BatchingConfig(
+        max_batch_size=4, kv_cache_max_seq=128, paged_kv="on",
+        paged_kv_page_size=8, prefill_chunk=16))
+
+
+def _exact_depth_grid(batcher):
+    """The admission program as it was before a row's chunk count was
+    read on the device: the [R, T, C] grid scanned to the deepest
+    prompt's exact depth, rows and no-op chunks alike."""
+    def impl(params, tokens, true_len, cache, slots, seeds, temps, ks, ps,
+             adapters, g0, g_allow, g_trans):
+        mini = batcher._make_mini(tokens.shape[0], batcher.max_seq)
+        fl, mini = batcher._chunked_scan(
+            params, tokens, true_len, mini, adapters, jnp.int32(0))
+        return batcher._chunked_finish(
+            cache, mini, slots, true_len, fl, seeds, temps, ks, ps,
+            g0, g_allow, g_trans)
+
+    return jax.jit(impl)
+
+
+@pytest.mark.parametrize("lens", [
+    pytest.param((100, 48, 21), id="mixed_depths_and_a_padding_row"),
+    pytest.param((128, 33), id="max_seq_beside_a_short_one"),
+    pytest.param((64,), id="exactly_k_chunks_alone"),
+])
+def test_each_rows_own_chunks_write_what_the_exact_grid_wrote(
+    family_engine, lens
+):
+    """A cold group as one `_admit_chunked` call on the (R, T_max, C)
+    grid against the exact-depth grid of before, a row a call: the same
+    first tokens and the same pages, bit for bit, in all three
+    families: mixed
+    depths (7, 3 and 2 chunks, the 48-token prompt exactly 3 x 16) in a
+    bucket of four whose last row is padding; a prompt of max_seq (8
+    chunks, the grid's whole depth); a prompt of exactly 4 chunks in a
+    bucket of one, whose mini is the row's own."""
+    eng, batcher = family_engine, _paged_batcher(family_engine)
+    c, b = 16, 4
+    r = 1 if len(lens) == 1 else b
+    rng = np.random.RandomState(sum(lens))
+    true_len = np.zeros((r,), np.int32)
+    slots = np.full((r,), b, np.int32)
+    grid = np.zeros((r, batcher._grid_chunks, c), np.int32)
+    for j, n in enumerate(lens):
+        prompt = rng.randint(3, eng.cfg.vocab_size, n)
+        batcher.pages.admit(j, prompt.tolist(), n, share=False)
+        grid[j].reshape(-1)[:n] = prompt
+        true_len[j], slots[j] = n, j
+    batcher._tables_dirty = True
+    batcher._sync_tables()
+    g_tables = batcher._grammar_tables()
+
+    def args(rows):
+        """The rows `rows` of the call, greedy and unconstrained."""
+        n, zi = len(rows), jnp.zeros((len(rows),), jnp.int32)
+        return (jnp.asarray(true_len[rows]),), (
+            jnp.asarray(slots[rows]), jnp.zeros((n,), jnp.uint32),
+            jnp.zeros((n,), jnp.float32), zi, jnp.ones((n,), jnp.float32),
+            zi, zi, *g_tables)
+
+    # What stood before, a row a call so that its products have this
+    # program's shapes (a [4, 16] chunk and a [1, 16] one round their
+    # sums differently): each row on the grid of its own exact depth.
+    exact = _exact_depth_grid(batcher)
+    want, want_first = jax.tree.map(jnp.copy, batcher.cache), []
+    for j, n in enumerate(lens):
+        lens_j, rest = args([j])
+        first, want = exact(
+            eng.params, jnp.asarray(grid[j:j + 1, :-(-n // c)]), *lens_j,
+            want, *rest)
+        want_first.append(int(first[0]))
+    lens_all, rest = args(list(range(r)))
+    got_first, got = batcher._admit_chunked(
+        eng.params, jnp.asarray(grid), *lens_all,
+        jax.tree.map(jnp.copy, batcher.cache), *rest)
+    assert np.asarray(got_first)[:len(lens)].tolist() == want_first
+    leaves, want_leaves = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(leaves) == len(want_leaves)
+    written = 0
+    for leaf, want_leaf in zip(leaves, want_leaves):
+        np.testing.assert_array_equal(
+            np.asarray(leaf, np.float32), np.asarray(want_leaf, np.float32))
+        written += int(np.count_nonzero(np.asarray(leaf, np.float32)))
+    assert written > 0  # the pages hold the prompts' state
+
+
+async def test_one_chunked_program_a_row_bucket_whatever_the_depths(
+    family_engine
+):
+    """Rounds of three different depths, a row each (3, 5 and 7 chunks;
+    48 tokens are exactly 3 x 16), then a round of three prompts of 3
+    and 4 chunks in a bucket of four (a row a call where the family's
+    `admission_rows` says so): outputs are `engine.generate`'s,
+    and `_admit_chunked` holds one compiled program a row bucket, where
+    the exact-depth grid compiled one a (rows, depth) pair. The counter
+    says which chunks ran: each row's own."""
+    eng, batcher = family_engine, _paged_batcher(family_engine)
+    rng = np.random.RandomState(11)
+    rounds = [[48], [75], [100], [37, 61, 50]]
+    calls = _spy_on_chunked(batcher)
+    traced, walk = [], batcher._chunked_rows
+
+    def tracing(params, tokens, *rest):  # runs once a program traced
+        traced.append(tuple(tokens.shape))
+        return walk(params, tokens, *rest)
+
+    batcher._chunked_rows = tracing
+    # A family with an indexer admits a row a call (`admission_rows`).
+    group = batcher._mini_rows > 1
+    buckets = [(1, 8, 16)] + [(4, 8, 16)] * group
     batcher.start()
     try:
-        outs = await asyncio.gather(*tasks)
+        for lens in rounds:
+            prompts = [[int(t) for t in rng.randint(3, eng.cfg.vocab_size, n)]
+                       for n in lens]
+            expected, _ = eng.generate(prompts, max_new_tokens=4, seed=0)
+            tasks = [asyncio.ensure_future(_collect(batcher, p, 4, i))
+                     for i, p in enumerate(prompts)]
+            assert await asyncio.gather(*tasks) == expected
+            assert traced == buckets[:1 if len(lens) == 1 else 2]
     finally:
         await batcher.stop()
-    assert outs == expected
-    assert shapes == [(4, 7, 16)]
+    assert [shape for shape, _ in calls] == 3 * [(1, 8, 16)] + (
+        [(4, 8, 16)] if group else 3 * [(1, 8, 16)])
+    stats = batcher.counter_stats()
+    assert stats["prefill_chunk_tokens_run"] == 16 * (3 + 5 + 7 + 3 + 4 + 4)
+    assert stats["prefill_tokens_computed"] == 48 + 75 + 100 + 37 + 61 + 50
 
 
 @pytest.mark.parametrize("serving, feature", [

@@ -609,8 +609,9 @@ class TestFusedAdmissionShapes:
         finally:
             await batcher.stop()
         assert reason in ("length", "stop")
-        # One trickle admission: R=1 rows, T=ceil(100/32)=4 chunks.
-        assert shapes == [(1, 4, 32)], shapes
+        # One trickle admission: R=1 rows, on the batcher's one grid of
+        # T_max = 256/32 = 8 chunks (the program runs the prompt's 4).
+        assert shapes == [(1, 8, 32)], shapes
 
 
 # ---------------------------------------------------------------------------
