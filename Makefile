@@ -6,7 +6,7 @@ PY ?= python
 CPU_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
 .PHONY: proto proto-check descriptors test test-all test-fast test-chaos \
-  test-obs test-grammar test-grammar-jump test-spec-batch test-paged \
+  test-obs test-grammar test-grammar-jump test-paged \
   test-tp test-analysis \
   test-disagg test-fleet test-mem test-kvtier test-lora-arena test-slo \
   test-sched \
@@ -72,17 +72,9 @@ test-grammar:
 test-grammar-jump:
 	$(CPU_ENV) $(PY) -m pytest tests/ -q -m grammar_jump
 
-# Speculative continuous batching alone (CPU mesh): greedy bitwise
-# identity spec-on vs spec-off across every admission path, filtered
-# (top-k/top-p) rejection-sampling losslessness, compile-count
-# stability for mixed batches, chaos replay with spec on. Tier-1 runs
-# these too; this target is the fast inner loop for spec-tick work.
-test-spec-batch:
-	$(CPU_ENV) $(PY) -m pytest tests/ -q -m spec_batch
-
 # Paged KV cache alone (CPU mesh): allocator bookkeeping, greedy
 # bitwise identity paged-on vs paged-off across every admission path
-# (chaos/speculative/grammar/int8 included), refcounted prefix sharing
+# (chaos/grammar/int8 included), refcounted prefix sharing
 # + copy-on-write, typed page-exhaustion shed, composition validation.
 # Tier-1 runs these too; this target is the fast inner loop for
 # serving/pages.py + paged-batcher work.
@@ -92,7 +84,7 @@ test-paged:
 # Tensor-parallel serving net alone, on a FORCED 2-DEVICE CPU mesh —
 # the stand-in recipe for a real >=2-chip TPU window
 # (docs/tensor_parallel_serving.md): 1-chip vs 2-chip greedy
-# bit-identity across admission paths, paged x TP, spec x TP,
+# bit-identity across admission paths, paged x TP,
 # chaos x TP, compile-count stability, and the sidecar TP e2e with a
 # real HF tokenizer. Tier-1 runs the same tests on the 8-device mesh;
 # this target pins the exact 2-device topology the issue names.
@@ -170,7 +162,7 @@ test-lora-arena:
 	$(CPU_ENV) $(PY) -m pytest tests/ -q -m lora_arena
 
 # Tenant & SLO accounting plane alone (CPU mesh): goodput-partition
-# closure across plain/paged/tiered/spec/grammar configs and under
+# closure across plain/paged/tiered/grammar configs and under
 # chaos, burn-rate windows, the bounded tenant table under churn,
 # obs-off zero-work, /debug/slo + ?tenant= parity on both http impls,
 # and the class-labeled /metrics families. Tier-1 runs these too; this

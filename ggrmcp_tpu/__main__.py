@@ -62,10 +62,6 @@ def build_parser() -> argparse.ArgumentParser:
         help="sidecar HuggingFace tokenizer.json path (with --tpu)",
     )
     gw.add_argument(
-        "--speculative-draft", default=None,
-        help="sidecar draft model for speculative decoding (with --tpu)",
-    )
-    gw.add_argument(
         "--workers", type=int, default=None,
         help="gateway worker processes sharing the port (SO_REUSEPORT)",
     )
@@ -103,10 +99,6 @@ def build_parser() -> argparse.ArgumentParser:
     sc.add_argument(
         "--tokenizer", default=None, help="HuggingFace tokenizer.json path"
     )
-    sc.add_argument(
-        "--speculative-draft", default=None,
-        help="draft model registry key for speculative decoding",
-    )
     sc.add_argument("--config", default=None, help="YAML/JSON config file")
     sc.add_argument("--log-level", default=None)
 
@@ -140,8 +132,6 @@ def load_config(args: argparse.Namespace) -> cfgmod.Config:
         cfg.serving.hf_checkpoint_path = args.hf_checkpoint
     if getattr(args, "tokenizer", None):
         cfg.serving.tokenizer_path = args.tokenizer
-    if getattr(args, "speculative_draft", None):
-        cfg.serving.speculative_draft = args.speculative_draft
     if getattr(args, "workers", None):
         cfg.server.workers = args.workers
     cfg.validate(colaunch=getattr(args, "tpu", False))

@@ -205,9 +205,9 @@ class UnshardedTransferRule(Rule):
 
 
 class AllocInJitRule(Rule):
-    """Jitted tick bodies (`_tick_*_impl`, `spec_tick`) and everything
-    they call within their module must not create fresh device arrays
-    or touch PageAllocator host state: pages are allocated for a
+    """Jitted tick bodies (`_tick_*_impl`) and everything they call
+    within their module must not create fresh device arrays or touch
+    PageAllocator host state: pages are allocated for a
     request's WHOLE LIFETIME at admission, block tables are host state
     snapshotted between ticks, and the tick's shapes/donation contract
     depend on it."""
@@ -226,7 +226,7 @@ class AllocInJitRule(Rule):
         "shape this rule bans from tick bodies."
     )
 
-    _ROOT_RE = re.compile(r"^_tick\w*_impl$|^spec_tick$")
+    _ROOT_RE = re.compile(r"^_tick\w*_impl$")
     _ALLOC = {
         "zeros", "ones", "empty", "full",
         "zeros_like", "ones_like", "empty_like", "full_like",
@@ -245,9 +245,7 @@ class AllocInJitRule(Rule):
 
         # Reachability over the intra-module call graph: edges are
         # bare-name calls and self./cls. method calls that resolve to a
-        # function defined in this module. Cross-module callees are
-        # covered by scanning their own module (spec_tick is a root in
-        # ops/speculative.py for exactly this reason).
+        # function defined in this module.
         def callees(fn: ast.AST):
             for node in ast.walk(fn):
                 if not isinstance(node, ast.Call):
@@ -333,8 +331,7 @@ class LedgerUnregisteredRule(Rule):
     # unsharded-transfer rule's territory (usually transient jit
     # inputs, its documented carve-out).
     _ALLOC_TAILS = {
-        "make_cache", "make_paged_cache", "make_draft_cache",
-        "_make_mini", "_make_shared_cache", "_snap_dev", "device_put",
+        "make_cache", "make_paged_cache", "_make_mini", "_make_shared_cache", "_snap_dev", "device_put",
         "_sharded_init", "_shard_params", "_synthetic_int8_init",
         "HostPagePool",
     }

@@ -869,20 +869,6 @@ class ServingConfig:
     # with kv_tiers; composes with int8 KV and pipeline serving
     # (validate() below, tests/test_pp_serving.py).
     kv_ring: bool = False
-    # Speculative decoding: registry key of a small dense draft model
-    # sharing the target's vocab ("" → off). A configured draft IS the
-    # switch: the draft rides INSIDE the continuous batcher, where
-    # every decode tick is one fixed-shape draft/verify round —
-    # `speculative_gamma` draft steps against a per-slot draft KV
-    # cache, then ONE (gamma+1)-position target verify over the shared
-    # slot pool, with variable advance expressed as per-slot
-    # length-pointer arithmetic (never dynamic shapes). Greedy rows
-    # stay bitwise identical to the plain tick; sampled rows (incl.
-    # top-k/top-p) are rejection-sampled losslessly over the filtered
-    # distributions; grammar-constrained rows verify against the DFA
-    # mask (docs/speculative.md).
-    speculative_draft: str = ""
-    speculative_gamma: int = 4
     # Sequence-parallel prefill over the mesh `sequence` axis: "ring"
     # (ppermute K/V rotation) or "ulysses" (all_to_all head re-shard);
     # "" disables. Engages for fresh prefills of at least
@@ -890,8 +876,6 @@ class ServingConfig:
     # (serving/engine.py::prefill_forward, SURVEY §5.7).
     sp_prefill: str = "ring"
     sp_prefill_min_seq: int = 1024
-    # Orbax checkpoint for the draft's params (empty → random init).
-    speculative_draft_checkpoint: str = ""
     # Multi-LoRA serving (ops/lora.py): named adapters served from the
     # SAME continuous batch via per-row low-rank deltas on the fused
     # qkv projection. Dense Llama, single-stage meshes only (the
@@ -1412,14 +1396,6 @@ class Config:
                     "compose with batching.kv_tiers: page import needs "
                     "ONE arena per replica to land transferred pages in"
                 )
-        if self.serving.speculative_gamma < 1:
-            raise ValueError("speculative_gamma must be >= 1")
-        if self.serving.speculative_draft and self.serving.kv_ring:
-            raise ValueError(
-                "speculative_draft does not compose with kv_ring: the "
-                "draft slot-pool cache is contiguous and the (gamma+1)-"
-                "position verify assumes the contiguous length mask"
-            )
         if self.logging.format not in ("", "json"):
             raise ValueError(
                 f"unknown logging.format {self.logging.format!r}; "

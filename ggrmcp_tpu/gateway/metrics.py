@@ -59,9 +59,6 @@ _SERVING_HELP = {
     "ticks": "decode ticks dispatched",
     "tick_collects": "decode tick token collects",
     "admit_rounds": "admission rounds run",
-    "spec_ticks": "continuous-batcher speculative draft/verify ticks",
-    "spec_drafted": "draft tokens proposed by the spec tick",
-    "spec_accepted": "draft tokens accepted by the spec tick",
     "interleaved_chunks": "prefill chunks fused into decode ticks",
     "interleaved_admissions":
         "requests admitted via tick-interleaved prefill",
@@ -201,14 +198,12 @@ _SERVING_HELP = {
     # the memory collector, not as per-field gauges; the help entries
     # here keep the proto-drift contract (every scalar field named).
     "memory_weights_bytes":
-        "ledger: target + draft model parameter bytes (LoRA excluded)",
+        "ledger: model parameter bytes (LoRA excluded)",
     "memory_lora_bytes": "ledger: stacked LoRA adapter factor bytes",
     "memory_kv_arena_bytes":
         "ledger: shared KV slot pool / paged page arena bytes",
     "memory_block_tables_bytes":
         "ledger: paged per-slot device block-table bytes",
-    "memory_draft_cache_bytes":
-        "ledger: speculative draft slot-pool KV bytes",
     "memory_ilv_mini_bytes":
         "ledger: interleaved-admission mini-cache bytes",
     "memory_grammar_arena_bytes":
@@ -428,8 +423,6 @@ _TICK_HELP = {
     "replayed_total": "cumulative replay counter snapshotted at dispatch",
     "timed_out_total":
         "cumulative queue-timeout counter snapshotted at dispatch",
-    "spec_drafted": "draft tokens proposed on this tick (spec mode)",
-    "spec_accepted": "draft tokens accepted on this tick (spec mode)",
     "kv_pages_in_use": "paged KV arena pages resident at dispatch",
     "phase_admit_ms": "queue drain + admission prefill preceding the tick",
     "phase_sync_ms":

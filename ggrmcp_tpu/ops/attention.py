@@ -73,8 +73,8 @@ NEG_INF = -1e30
 
 # Decode-shaped GQA calls (sq at or below this) take the grouped
 # einsum in attention_xla; longer queries repeat K/V (see its
-# docstring). 8 covers fused decode ticks, speculative gamma-step
-# verification windows, and small prefill chunks (configs with
+# docstring). 8 covers fused decode ticks, the jump tick's forced-run
+# windows, and small prefill chunks (configs with
 # prefill_chunk <= 8 run their chunk steps grouped too — numerically
 # identical either way).
 GQA_GROUPED_MAX_SQ = 8
@@ -490,7 +490,7 @@ def flash_attention_sharded(
 # ---------------------------------------------------------------------------
 
 # Query positions a step the paged-decode kernel takes: the decode tick
-# (1) and the small static windows of the jump and speculative ticks.
+# (1) and the small static window of the jump tick.
 # A prefill chunk's queries keep the gathered view.
 PAGED_DECODE_MAX_SQ = GQA_GROUPED_MAX_SQ
 

@@ -138,27 +138,6 @@ def dynamic_support_mask(
     )
 
 
-def filtered_logprobs(
-    logits: jnp.ndarray,  # [B, V] (grammar-masked rows arrive as -inf)
-    temperature: jnp.ndarray,  # [B]
-    top_k: jnp.ndarray,  # [B]
-    top_p: jnp.ndarray,  # [B]
-) -> jnp.ndarray:  # [B, V] float32 log-probs
-    """Log-probs of the temperature→top-k→top-p filtered distribution —
-    the distribution `sample_dynamic` actually draws from. This is what
-    makes the speculative rejection sampler lossless under top-k/top-p
-    (ops/speculative.py): applying the SAME per-row filter to both the
-    target's p and the draft's q keeps the accept test min(1, p(x)/q(x))
-    and the residual normalize(max(p−q, 0)) exact for the filtered
-    target distribution. Tokens outside the support are -inf."""
-    support = dynamic_support_mask(logits, temperature, top_k, top_p)
-    safe_temp = jnp.maximum(temperature, 1e-6)[:, None]
-    return jax.nn.log_softmax(
-        jnp.where(support, logits.astype(jnp.float32) / safe_temp, -jnp.inf),
-        axis=-1,
-    )
-
-
 def sample_dynamic(
     logits: jnp.ndarray,  # [B, V]
     seeds: jnp.ndarray,  # [B] uint32/int — per-request seeds

@@ -334,19 +334,6 @@ class ContinuousBatcher:
         self._pipeline = mode == "on" or (
             mode == "auto" and platform == "tpu"
         )
-        # Speculative decoding inside this batcher (a configured
-        # serving.speculative_draft is the switch): every tick becomes
-        # one fixed-shape draft/verify round (ops/speculative.spec_tick) —
-        # gamma draft steps against a per-slot draft cache, one fused
-        # (gamma+1)-position target verify over the shared pool, and
-        # variable advance as per-slot length-pointer arithmetic. The
-        # per-tick advance bound is gamma+1 (not steps_per_tick), so
-        # the overshoot reserve re-derives from it.
-        self._spec = getattr(engine, "draft_fam", None) is not None
-        self._gamma = (
-            max(1, int(getattr(engine.serving, "speculative_gamma", 4)))
-            if self._spec else 0
-        )
         # Jump-ahead constrained decoding (serving.grammar.jump_max,
         # docs/structured_output.md "Jump-ahead"): when a slot's DFA
         # state forces a token run, the tick emits up to jump_max
@@ -361,10 +348,8 @@ class ContinuousBatcher:
         # window only constrained rows use (their surplus positions in
         # a jump tick are junk that the write path's sentinel/OOB drop
         # semantics discard — see models/llama.py paged scatter).
-        # Spec mode keeps its own gamma+1 window (forced runs ride the
-        # draft proposal there, not a wider verify). Ring mode is out:
-        # its clobber bound was sized for the prefill chunk, not a
-        # decode-side window.
+        # Ring mode is out: its clobber bound was sized for the prefill
+        # chunk, not a decode-side window.
         gcfg = getattr(engine.serving, "grammar", None) or GrammarConfig()
         jump_max = (
             max(0, int(getattr(gcfg, "jump_max", 0))) if gcfg.enabled else 0
@@ -378,24 +363,21 @@ class ContinuousBatcher:
         if jump_max and getattr(engine, "fam", llama_mod) is not llama_mod:
             # MoE routing is batch-global: junk window positions past a
             # row's run would compete for expert capacity and perturb
-            # live rows — the same reason spec_tick is dense-only.
+            # live rows.
             logger.warning(
                 "grammar.jump_max > 0 is dense-family only; falling "
                 "back to one-token constrained decoding"
             )
             jump_max = 0
         self._jump_max = jump_max
-        if self._spec:
-            advance = jump_advance = self._gamma + 1
-        else:
-            advance = self._steps_per_tick
-            jump_advance = max(advance, 1 + self._jump_max)
+        advance = self._steps_per_tick
+        jump_advance = max(advance, 1 + self._jump_max)
         self._reserve = (
             2 * advance - 1 if self._pipeline else advance - 1
         )
         # Per-request widened twin of _reserve (== _reserve when jump
-        # is off or under spec): _reserve_for picks between them by
-        # grammar presence at every fit/clamp/admission site.
+        # is off): _reserve_for picks between them by grammar presence
+        # at every fit/clamp/admission site.
         self._jump_reserve = (
             2 * jump_advance - 1 if self._pipeline else jump_advance - 1
         )
@@ -415,12 +397,6 @@ class ContinuousBatcher:
         # never wraps, so its contiguous layout IS the ring layout);
         # prompts past prefill_chunk take the chunked path as usual.
         self._ring = engine.ring_capacity is not None
-        if self._spec and self._ring:
-            # config.validate mirrors this; batchers built directly in
-            # tests must hit the same wall.
-            raise ValueError(
-                "speculative_draft does not compose with kv_ring"
-            )
         if self._ring:
             engine_chunk = engine.serving.batching.prefill_chunk
             if self.cfg.prefill_chunk > engine_chunk:
@@ -506,32 +482,6 @@ class ContinuousBatcher:
             self.pages = None
             self.host_pool = None
             self.cache = engine.make_cache(b, s_max)
-        # Spec mode: the draft's KV slot pool rides beside the shared
-        # target cache (the cache-level merge docs/speculative.md's
-        # revisit trigger asked for — one slot pool, draft cache
-        # alongside). Request length additionally clamps to the draft's
-        # RoPE range: a prompt the draft can't position-encode would
-        # silently wreck acceptance. prev_tokens mirrors cur_tokens
-        # (host seed + device twin): the spec round's first draft feed
-        # is [prev, cur] so prev rewrites its own draft-KV slot,
-        # keeping the draft cache exactly one position behind the
-        # target.
-        if self._spec:
-            self._fit_limit = min(
-                self._fit_limit, engine.draft_cfg.max_seq_len
-            )
-            self.dcache = engine.make_draft_cache(b, s_max)
-        else:
-            self.dcache = None
-        self.prev_tokens = np.zeros((b,), np.int32)
-        self._prev_dev = None
-        self._dcache_at_risk = False
-        # Spec-tick accounting: ticks run in draft/verify mode, draft
-        # tokens proposed, and proposals accepted — accepted/drafted is
-        # the realized acceptance rate (ServingStats spec_* fields).
-        self.spec_ticks = 0
-        self.spec_drafted = 0
-        self.spec_accepted = 0
         # Host-mirrored per-slot state, pushed to device each tick.
         # cur_tokens additionally keeps a DEVICE-resident twin
         # (_cur_dev): the tick feeds on the previous tick's last-step
@@ -798,24 +748,6 @@ class ContinuousBatcher:
         self._ilv_finish = jax.jit(
             self._ilv_finish_impl, donate_argnums=(0,)
         )
-        # Speculative tick programs (a draft is configured): the
-        # draft/verify round (both slot-pool caches donated), its
-        # tick+chunk fusion for interleaved admission (the carried mini
-        # donated too), and the draft-side admission prefill (draft
-        # pool donated — a failed call leaves a rebuilt-zeros pool,
-        # which degrades ACCEPTANCE for live rows but can never break
-        # correctness: exact-match/rejection only ever emits what the
-        # target distribution allows).
-        if self._spec:
-            self._tick_spec = jax.jit(
-                self._tick_spec_impl, donate_argnums=(4, 5)
-            )
-            self._tick_spec_chunk = jax.jit(
-                self._tick_spec_chunk_impl, donate_argnums=(4, 5, 15)
-            )
-            self._spec_admit = jax.jit(
-                self._spec_admit_impl, donate_argnums=(3,)
-            )
         # Jump-ahead tick programs (grammar.jump_max > 0,
         # docs/structured_output.md "Jump-ahead"): one decode forward
         # over a static [B, 1 + jump_max] window emits each row's
@@ -851,9 +783,6 @@ class ContinuousBatcher:
             scope=ledger_scope,
         )
         engine.ledger.register(
-            "draft_cache", lambda: self.dcache, scope=ledger_scope
-        )
-        engine.ledger.register(
             "ilv_mini", lambda: self._ilv_mini, scope=ledger_scope
         )
         engine.ledger.register(
@@ -866,7 +795,7 @@ class ContinuousBatcher:
         )
         engine.ledger.register(
             "tick_state",
-            lambda: (self._cur_dev, self._prev_dev, self._gstate_dev),
+            lambda: (self._cur_dev, self._gstate_dev),
             scope=ledger_scope,
         )
         # Host-tier bytes are HOST memory — outside jax.live_arrays(),
@@ -997,7 +926,7 @@ class ContinuousBatcher:
         cover: grammar-carrying requests reserve the jump window
         (1 + jump_max positions may be written in one jump tick),
         unconstrained requests only the plain per-tick advance. Both
-        values are identical when jump is off or under spec mode."""
+        values are identical when jump is off."""
         return self._jump_reserve if constrained else self._reserve
 
     def clamp_prompt(
@@ -1603,9 +1532,8 @@ class ContinuousBatcher:
         data-dependent run length; rows without a forced run (state 0,
         jump_ok False, parked slots) read run_len 0 and collapse to the
         plain one-token constrained step, their surplus window
-        positions junk that dies under the causal length mask exactly
-        like spec_tick's rejected verify positions (only the length
-        POINTER advances by 1 + run_len; the forward wrote all
+        positions junk that dies under the causal length mask (only the
+        length POINTER advances by 1 + run_len; the forward wrote all
         1 + jump_max). Forced tokens get real KV writes from the same
         forward that samples the landing token — "emit without a
         forward pass" means no per-token forward, not no KV.
@@ -1621,7 +1549,7 @@ class ContinuousBatcher:
         window = jnp.concatenate([tokens[:, None], run_tokens], axis=1)
         # Dense families only (the constructor gates jump off for MoE:
         # batch-global expert routing would see the junk window
-        # positions) — no validity mask needed, like spec_tick.
+        # positions) — no validity mask needed.
         logits, cache = self.engine.decode_forward(
             params, window, cache, ring=self._ring, lora_idx=adapters,
         )
@@ -1686,7 +1614,7 @@ class ContinuousBatcher:
         self, params, chunk, mini, offs, c_true_len, c_valid, c_adapters
     ):
         """The chunk half of a fused tick+chunk call (shared by the
-        plain and speculative variants): extend the carried [K, S_max]
+        plain and jump variants): extend the carried [K, S_max]
         mini cache by one [K, C] chunk at the host-stamped offsets and
         gather each row's final-prompt-position logits."""
         mini = mini._replace(length=offs)
@@ -1706,89 +1634,6 @@ class ContinuousBatcher:
         idx = jnp.clip(last - offs, 0, c - 1)
         sel = jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0]
         return mini, sel.astype(jnp.float32)
-
-    def _spec_round(
-        self, params, draft_params, prev, tokens, cache, dcache, seeds,
-        step, temps, ks, ps, gstate, g_allow, g_trans, j_len, j_tok,
-    ):
-        """One fixed-shape draft/verify round over the slot pool
-        (ops/speculative.spec_tick wired to this engine's forwards).
-        j_len/j_tok are the arena's forced-run tables (None when
-        grammar.jump_max is 0): a forced run seeds the draft's proposal
-        prefix as a free 100%-acceptance draft — see spec_tick's "Jump
-        seeding" note."""
-        from ggrmcp_tpu.ops.speculative import spec_tick
-
-        return spec_tick(
-            lambda t, c: self.engine.decode_forward(
-                params, t, c, ring=self._ring
-            ),
-            lambda t, c: self.engine.draft_forward(draft_params, t, c),
-            prev, tokens, cache, dcache, self._gamma, seeds, step,
-            temps, ks, ps, gstate, g_allow, g_trans,
-            j_len=j_len, j_tokens=j_tok,
-        )
-
-    def _tick_spec_impl(
-        self, params, draft_params, prev, tokens, cache, dcache, seeds,
-        step, temps, ks, ps, gstate, g_allow, g_trans, j_len, j_tok,
-    ):
-        """The speculative tick: ONE device call = gamma draft steps +
-        one (gamma+1)-position target verify for every slot. Returns
-        (emit [B, gamma+1], count [B], cache, dcache, prev', cur',
-        gstate'); the host emits emit[i, :count[i]] per live row —
-        variable advance, fixed shapes (docs/speculative.md)."""
-        return self._spec_round(
-            params, draft_params, prev, tokens, cache, dcache, seeds,
-            step, temps, ks, ps, gstate, g_allow, g_trans, j_len, j_tok,
-        )
-
-    def _tick_spec_chunk_impl(
-        self, params, draft_params, prev, tokens, cache, dcache, seeds,
-        step, temps, ks, ps, gstate, g_allow, g_trans,
-        chunk, mini, offs, c_true_len, c_valid, c_adapters,
-        j_len, j_tok,
-    ):
-        """_tick_spec_impl fused with one [K, C] interleaved-admission
-        prefill chunk — spec mode composes with prefill_interleave the
-        same way the plain tick does (_tick_chunk_impl)."""
-        emit, count, cache, dcache, prev2, cur2, gstate2 = (
-            self._spec_round(
-                params, draft_params, prev, tokens, cache, dcache,
-                seeds, step, temps, ks, ps, gstate, g_allow, g_trans,
-                j_len, j_tok,
-            )
-        )
-        mini, sel = self._chunk_extend(
-            params, chunk, mini, offs, c_true_len, c_valid, c_adapters
-        )
-        return emit, count, cache, dcache, prev2, cur2, gstate2, mini, sel
-
-    def _spec_admit_impl(self, draft_params, tokens, true_len, dcache, slots):
-        """Draft-side admission: fresh draft prefill of the [R, S]
-        right-padded prompts, each row's first S cache positions
-        scattered into the draft slot pool at `slots` (out-of-range
-        padding rows dropped) with length true_len - 1 — one position
-        BEHIND the target, so the first spec round's [prev, cur] feed
-        rewrites the last prompt token's slot (idempotent: same token,
-        same position) and extends from there. One extra small device
-        call per admission round; the target-side admission programs
-        are untouched."""
-        r, s = tokens.shape
-        mini = llama_mod.KVCache.create(
-            self.engine.draft_cfg, r, s, self.engine.kv_dtype
-        )
-        _, mini = self.engine.draft_forward(draft_params, tokens, mini)
-
-        def put(c_, m):
-            return c_.at[:, slots, :s].set(m.astype(c_.dtype), mode="drop")
-
-        k = quant.kv_map(put, dcache.k, mini.k)
-        v = quant.kv_map(put, dcache.v, mini.v)
-        lengths = dcache.length.at[slots].set(
-            jnp.maximum(true_len - 1, 0), mode="drop"
-        )
-        return llama_mod.KVCache(k=k, v=v, length=lengths)
 
     def _ilv_finish_impl(
         self, cache, mini, row, slot, n, sel, seeds, temps, ks, ps,
@@ -1917,13 +1762,6 @@ class ContinuousBatcher:
         # Grammar tables ride every sampling program as fixed-shape
         # args; state 0 (accept-all) keeps warmup numerics inert.
         g_allow, g_trans = self._grammar_tables()
-        # Forced-run twins for the jump/spec programs (uploaded by the
-        # _grammar_tables call above; None when jump-ahead is off keeps
-        # the no-jump spec trace).
-        spec_jargs = (
-            (self._g_jlen_dev, self._g_jtok_dev)
-            if self._jump_max else (None, None)
-        )
         zgb = np.zeros((b,), np.int32)
         _, self.cache = self._admit_single(
             self.engine.params, jnp.asarray(zeros1), jnp.asarray(zlen1),
@@ -1948,34 +1786,25 @@ class ContinuousBatcher:
         # program is a variant serving never calls and the FIRST live
         # request pays the real compile (the compile watcher caught
         # exactly this: a post-warmup jit(_tick_impl) on call one).
-        if self._spec:
-            # Spec mode never runs the plain tick — warm the draft/
-            # verify round and the draft-admission prefill (trickle and
-            # full-pool row buckets) instead. Same pre-serving-only
-            # contract: these overwrite rows and advance both length
-            # pointers, harmless while no slot is active.
-            (
-                _, _, self.cache, self.dcache, _, _, _
-            ) = self._tick_spec(
-                self.engine.params, self.engine.draft_params,
-                self._snap_dev(self.prev_tokens),
-                self._snap_dev(self.cur_tokens), self.cache, self.dcache,
-                jnp.asarray(self.seeds), jnp.int32(0),
-                jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-                jnp.asarray(self.top_ps),
-                self._snap_dev(self.gstates), g_allow, g_trans,
-                *spec_jargs,
-            )
-            for r_rows in (1, b) if b > 1 else (1,):
-                self.dcache = self._spec_admit(
-                    self.engine.draft_params,
-                    jnp.asarray(np.zeros((r_rows, s), np.int32)),
-                    jnp.asarray(np.ones((r_rows,), np.int32)),
-                    self.dcache,
-                    jnp.asarray(np.full((r_rows,), b, np.int32)),
-                )
-        else:
-            _, self.cache, _ = self._tick(
+        _, self.cache, _ = self._tick(
+            self.engine.params, self._snap_dev(self.cur_tokens),
+            self.cache,
+            jnp.asarray(self.seeds), jnp.int32(0),
+            jnp.asarray(self.temps), jnp.asarray(self.top_ks),
+            jnp.asarray(self.top_ps),
+            jnp.asarray(np.zeros((b,), bool)),
+            jnp.asarray(np.zeros((b,), np.int32)),
+            self._snap_dev(self.gstates), g_allow, g_trans,
+        )
+        if self._jump_max:
+            # The jump tick alternates with the plain tick at
+            # dispatch time (jump only while some slot can jump) —
+            # BOTH must be warm or the first constrained request
+            # pays a post-warmup compile (compile-watcher contract).
+            # All-False jump_ok: every row runs a zero-length run,
+            # advancing length pointers by 1 like the plain tick —
+            # harmless pre-serving.
+            _, _, self.cache, _, _ = self._tick_jump(
                 self.engine.params, self._snap_dev(self.cur_tokens),
                 self.cache,
                 jnp.asarray(self.seeds), jnp.int32(0),
@@ -1984,28 +1813,10 @@ class ContinuousBatcher:
                 jnp.asarray(np.zeros((b,), bool)),
                 jnp.asarray(np.zeros((b,), np.int32)),
                 self._snap_dev(self.gstates), g_allow, g_trans,
+                self._g_jlen_dev, self._g_jtok_dev,
+                self._g_jstate_dev,
+                jnp.asarray(np.zeros((b,), bool)),
             )
-            if self._jump_max:
-                # The jump tick alternates with the plain tick at
-                # dispatch time (jump only while some slot can jump) —
-                # BOTH must be warm or the first constrained request
-                # pays a post-warmup compile (compile-watcher contract).
-                # All-False jump_ok: every row runs a zero-length run,
-                # advancing length pointers by 1 like the plain tick —
-                # harmless pre-serving.
-                _, _, self.cache, _, _ = self._tick_jump(
-                    self.engine.params, self._snap_dev(self.cur_tokens),
-                    self.cache,
-                    jnp.asarray(self.seeds), jnp.int32(0),
-                    jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-                    jnp.asarray(self.top_ps),
-                    jnp.asarray(np.zeros((b,), bool)),
-                    jnp.asarray(np.zeros((b,), np.int32)),
-                    self._snap_dev(self.gstates), g_allow, g_trans,
-                    self._g_jlen_dev, self._g_jtok_dev,
-                    self._g_jstate_dev,
-                    jnp.asarray(np.zeros((b,), bool)),
-                )
         # Fused chunked-admission programs: one a row bucket, whatever
         # the prompts' depths (the grid's depth is `_grid_chunks`, and
         # each row's chunk count is read on the device), so these calls
@@ -2058,32 +1869,33 @@ class ContinuousBatcher:
             if self._ilv_mini is None:
                 self._ilv_mini = self._make_mini(self._ilv_k, self.max_seq)
             k_rows = self._ilv_k
-            if self._spec:
+            _, self.cache, self._ilv_mini, sel, _ = self._tick_chunk(
+                self.engine.params, self._snap_dev(self.cur_tokens),
+                self.cache, jnp.asarray(self.seeds), jnp.int32(0),
+                jnp.asarray(self.temps), jnp.asarray(self.top_ks),
+                jnp.asarray(self.top_ps),
+                jnp.asarray(np.zeros((b,), bool)),
+                jnp.asarray(np.zeros((b,), np.int32)),
+                jnp.asarray(np.zeros((k_rows, c), np.int32)),
+                self._ilv_mini,
+                jnp.asarray(np.zeros((k_rows,), np.int32)),
+                jnp.asarray(np.ones((k_rows,), np.int32)),
+                jnp.asarray(np.zeros((k_rows,), bool)),
+                jnp.asarray(np.zeros((k_rows,), np.int32)),
+                self._snap_dev(self.gstates), g_allow, g_trans,
+            )
+            if self._jump_max:
+                # Jump + interleave composes (same alternating-
+                # dispatch reasoning as the plain/jump pair above).
                 (
-                    _, _, self.cache, self.dcache, _, _, _,
-                    self._ilv_mini, sel,
-                ) = self._tick_spec_chunk(
-                    self.engine.params, self.engine.draft_params,
-                    self._snap_dev(self.prev_tokens),
+                    _, _, self.cache, _, _, self._ilv_mini, sel
+                ) = self._tick_jump_chunk(
+                    self.engine.params,
                     self._snap_dev(self.cur_tokens),
-                    self.cache, self.dcache,
-                    jnp.asarray(self.seeds), jnp.int32(0),
-                    jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-                    jnp.asarray(self.top_ps),
-                    self._snap_dev(self.gstates), g_allow, g_trans,
-                    jnp.asarray(np.zeros((k_rows, c), np.int32)),
-                    self._ilv_mini,
-                    jnp.asarray(np.zeros((k_rows,), np.int32)),
-                    jnp.asarray(np.ones((k_rows,), np.int32)),
-                    jnp.asarray(np.zeros((k_rows,), bool)),
-                    jnp.asarray(np.zeros((k_rows,), np.int32)),
-                    *spec_jargs,
-                )
-            else:
-                _, self.cache, self._ilv_mini, sel, _ = self._tick_chunk(
-                    self.engine.params, self._snap_dev(self.cur_tokens),
-                    self.cache, jnp.asarray(self.seeds), jnp.int32(0),
-                    jnp.asarray(self.temps), jnp.asarray(self.top_ks),
+                    self.cache, jnp.asarray(self.seeds),
+                    jnp.int32(0),
+                    jnp.asarray(self.temps),
+                    jnp.asarray(self.top_ks),
                     jnp.asarray(self.top_ps),
                     jnp.asarray(np.zeros((b,), bool)),
                     jnp.asarray(np.zeros((b,), np.int32)),
@@ -2094,33 +1906,10 @@ class ContinuousBatcher:
                     jnp.asarray(np.zeros((k_rows,), bool)),
                     jnp.asarray(np.zeros((k_rows,), np.int32)),
                     self._snap_dev(self.gstates), g_allow, g_trans,
+                    self._g_jlen_dev, self._g_jtok_dev,
+                    self._g_jstate_dev,
+                    jnp.asarray(np.zeros((b,), bool)),
                 )
-                if self._jump_max:
-                    # Jump + interleave composes (same alternating-
-                    # dispatch reasoning as the plain/jump pair above).
-                    (
-                        _, _, self.cache, _, _, self._ilv_mini, sel
-                    ) = self._tick_jump_chunk(
-                        self.engine.params,
-                        self._snap_dev(self.cur_tokens),
-                        self.cache, jnp.asarray(self.seeds),
-                        jnp.int32(0),
-                        jnp.asarray(self.temps),
-                        jnp.asarray(self.top_ks),
-                        jnp.asarray(self.top_ps),
-                        jnp.asarray(np.zeros((b,), bool)),
-                        jnp.asarray(np.zeros((b,), np.int32)),
-                        jnp.asarray(np.zeros((k_rows, c), np.int32)),
-                        self._ilv_mini,
-                        jnp.asarray(np.zeros((k_rows,), np.int32)),
-                        jnp.asarray(np.ones((k_rows,), np.int32)),
-                        jnp.asarray(np.zeros((k_rows,), bool)),
-                        jnp.asarray(np.zeros((k_rows,), np.int32)),
-                        self._snap_dev(self.gstates), g_allow, g_trans,
-                        self._g_jlen_dev, self._g_jtok_dev,
-                        self._g_jstate_dev,
-                        jnp.asarray(np.zeros((b,), bool)),
-                    )
             _, self.cache = self._ilv_finish(
                 self.cache, self._ilv_mini, jnp.int32(0), jnp.int32(0),
                 jnp.int32(0), sel, jnp.asarray(zseed1),
@@ -2477,8 +2266,6 @@ class ContinuousBatcher:
             total += self.cache.table.nbytes
         if self._ilv_mini is not None:
             total += planes(self._ilv_mini)
-        if self.dcache is not None:
-            total += planes(self.dcache)
         return total
 
     def stall_snapshot(self) -> list[float]:
@@ -2553,8 +2340,8 @@ class ContinuousBatcher:
     # gateway_backend_memory_bytes{target, component} family.
     _LEDGER_ENGINE_COMPONENTS = ("weights", "lora")
     _LEDGER_BATCHER_COMPONENTS = (
-        "kv_arena", "block_tables", "draft_cache", "ilv_mini",
-        "grammar_arena", "tick_state",
+        "kv_arena", "block_tables", "ilv_mini", "grammar_arena",
+        "tick_state",
     )
 
     def _memory_stats(self) -> dict:
@@ -2666,13 +2453,6 @@ class ContinuousBatcher:
             # piggybacked onto decode ticks / requests admitted that way.
             "interleaved_chunks": self.interleaved_chunks,
             "interleaved_admissions": self.interleaved_admissions,
-            # Speculative tick activity (a draft is configured):
-            # draft/verify rounds run, draft tokens proposed, and
-            # proposals accepted — spec_accepted/spec_drafted is THIS
-            # batcher's realized acceptance rate.
-            "spec_ticks": self.spec_ticks,
-            "spec_drafted": self.spec_drafted,
-            "spec_accepted": self.spec_accepted,
             # Grammar-constrained decoding: tokens emitted under an
             # active DFA mask, and arena table rows currently resident
             # (state 0 + every cached grammar's states). The sidecar
@@ -3210,17 +2990,6 @@ class ContinuousBatcher:
             self.pages.reset()
             self._tables_dirty = True
         self.cache = self._make_shared_cache()
-        if self._spec:
-            # The spec tick donated the draft pool alongside the shared
-            # cache; every victim replays through admission, which
-            # re-prefills its draft row, so a fresh pool is complete
-            # recovery (prev mirrors re-stamp there too).
-            self.prev_tokens[:] = 0
-            self._prev_dev = None
-            self._dcache_at_risk = False
-            self.dcache = self.engine.make_draft_cache(
-                len(self.slots), self.max_seq
-            )
 
     def _sweep_expired_pending(self) -> None:
         """Deadline-aware sweep: drop already-expired (and abandoned)
@@ -3364,16 +3133,6 @@ class ContinuousBatcher:
                     "batched prefill failed for slots %s", slots_idx
                 )
                 cache_dead = self._cache_at_risk
-                if self._dcache_at_risk:
-                    # The draft-admission call died mid-donation: its
-                    # pool is gone. A zeroed rebuild degrades live
-                    # rows' ACCEPTANCE only — exact-match/rejection can
-                    # never emit a token the target distribution
-                    # wouldn't, whatever the draft proposes.
-                    self._dcache_at_risk = False
-                    self.dcache = self.engine.make_draft_cache(
-                        len(self.slots), self.max_seq
-                    )
                 activated = {
                     id(s.request) for s in self.slots
                     if s.active and s.request is not None
@@ -3418,46 +3177,6 @@ class ContinuousBatcher:
             admitted += len(batch)
         return admitted
 
-    def _spec_admit_rows(self, rows: list[tuple[int, _Request]]) -> None:
-        """Draft-side admission for newly activated slots (spec mode):
-        ONE bucketed [R, S] draft prefill + scatter into the draft slot
-        pool, then the prev-token mirrors. Runs AFTER the target-side
-        activation inside the same serialized executor call, so the
-        next tick (which cannot overlap admission) always sees a draft
-        cache one position behind the target. A failure here only
-        costs acceptance (the rebuilt-zeros pool degrades proposals,
-        never correctness) — the caller's handler rebuilds via
-        _dcache_at_risk."""
-        rows = [
-            (sl, req) for sl, req in rows
-            if self.slots[sl].request is req  # still live (not finished)
-        ]
-        if not self._spec or not rows:
-            return
-        r_b = min(len(self.slots), bucket_len(len(rows), minimum=1))
-        s = bucket_len(
-            max(len(req.prompt) for _, req in rows), maximum=self.max_seq
-        )
-        tokens = np.zeros((r_b, s), np.int32)
-        true_len = np.ones((r_b,), np.int32)
-        slots_arr = np.full((r_b,), len(self.slots), np.int32)  # pad=drop
-        for j, (sl, req) in enumerate(rows):
-            tokens[j, : len(req.prompt)] = req.prompt
-            true_len[j] = len(req.prompt)
-            slots_arr[j] = sl
-        self._dcache_at_risk = True
-        self.dcache = self._spec_admit(
-            self.engine.draft_params, jnp.asarray(tokens),
-            jnp.asarray(true_len), self.dcache, jnp.asarray(slots_arr),
-        )
-        jax.block_until_ready(self.dcache.length)
-        self._dcache_at_risk = False
-        for sl, req in rows:
-            prev = int(req.prompt[-1])
-            self.prev_tokens[sl] = prev
-            if self._prev_dev is not None:
-                self._prev_dev = self._prev_dev.at[sl].set(prev)
-
     def _prefill_into_slots(
         self, slots_idx: list[int], batch: list[_Request]
     ) -> None:
@@ -3495,10 +3214,9 @@ class ContinuousBatcher:
             self._adm_families, self._adm_reused = [], 0
             self._adm_chunk_run = 0
             queued, shed_rows = self._route_admission(slots_idx, batch)
-        # What is left after the last activation loop is the draft-side
-        # admission (spec mode) and the way out; a round that launched
-        # nothing (every row queued for tick-fused chunks, or shed) was
-        # building all along.
+        # What is left after the last activation loop is the way out; a
+        # round that launched nothing (every row queued for tick-fused
+        # chunks, or shed) was building all along.
         timer.mark("activate" if "device" in timer.acc else "build")
         dt = (timer.last - timer.t0) * 1000.0
         self.timing["admit_rounds"] = seq
@@ -3756,12 +3474,6 @@ class ContinuousBatcher:
         for key, group in paged_groups.items():
             for at in range(0, len(group), self._mini_rows):
                 self._admit_paged_group(group[at: at + self._mini_rows], *key)
-        if self._spec:
-            # Draft-side admission for every slot this round activated
-            # (fused, chunked, and paged paths alike; interleave-queued
-            # rows are draft-admitted by _ilv_finish_row when their
-            # final chunk lands). One bucketed device call per round.
-            self._spec_admit_rows(list(zip(slots_idx, batch)))
         return queued, shed_rows
 
     def _admit_chunked_group(
@@ -3955,9 +3667,7 @@ class ContinuousBatcher:
         with tracing.annotation(
             "ggrmcp.tick.dispatch", seq=self.timing["ticks"] + 1
         ):
-            if self._spec:
-                self._tick_spec_dispatch(chunk=self._ilv_busy())
-            elif self._jump_max and bool(self.jump_ok.any()):
+            if self._jump_max and bool(self.jump_ok.any()):
                 # Jump-ahead tick only while some live slot can
                 # actually jump (a constrained, non-degraded request):
                 # unconstrained workloads keep the plain tick's
@@ -4049,96 +3759,9 @@ class ContinuousBatcher:
         # finish (tick N's emission) and be re-admitted before tick
         # N+1's junk row for the old request is collected.
         owners = [s.request if s.active else None for s in self.slots]
-        self._inflight.append((toks, None, owners, rec, "plain"))
+        self._inflight.append((toks, None, owners, rec))
         self.timing["ticks"] += 1
         if rec is not None:
-            rec.phases.mark("dispatch")
-
-    def _tick_spec_dispatch(self, chunk: bool = False) -> None:
-        """The speculative twin of _tick_dispatch / _tick_dispatch_chunk:
-        one device call = gamma draft steps + one fused (gamma+1)-
-        position verify for the whole pool (plus at most one [K, C]
-        interleaved prefill chunk when `chunk`). Token feedback (cur,
-        prev, grammar state) and both cache length pointers stay
-        device-resident, so spec ticks pipeline exactly like plain
-        ones; the host pulls (emit, count) at collect and advances each
-        slot by its accepted count."""
-        step0 = self.step_counter
-        # gamma+1 target positions per round — decode_steps counts
-        # positions processed, and the per-round RNG tag (step0+1)
-        # stays unique across ticks.
-        self.step_counter += self._gamma + 1
-        active = np.array([s.active for s in self.slots], bool)
-        # Record first: the PhaseTimer must cover the host-state sync
-        # below (same contract as _tick_dispatch).
-        rec = self._tick_record(active)
-        if chunk:
-            self._ilv_fill_rows()
-        self._sync_tables()
-        if self._cur_dev is None:
-            self._cur_dev = self._snap_dev(self.cur_tokens)
-        if self._prev_dev is None:
-            self._prev_dev = self._snap_dev(self.prev_tokens)
-        if self._gstate_dev is None:
-            self._gstate_dev = self._snap_dev(self.gstates)
-        g_allow, g_trans = self._grammar_tables()
-        args = (
-            self.engine.params, self.engine.draft_params,
-            self._prev_dev, self._cur_dev, self.cache, self.dcache,
-            jnp.asarray(self.seeds), jnp.int32(step0 + 1),
-            jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-            jnp.asarray(self.top_ps),
-            self._gstate_dev, g_allow, g_trans,
-        )
-        # Forced-run tables for the draft's jump seeding (None keeps
-        # the no-jump trace when grammar.jump_max is 0). Refreshed by
-        # _grammar_tables above, so they always match g_allow/g_trans.
-        jargs = (
-            (self._g_jlen_dev, self._g_jtok_dev)
-            if self._jump_max else (None, None)
-        )
-        if chunk:
-            (chunk_arr, offs, c_tl, c_valid, c_adapt) = (
-                self._ilv_chunk_inputs()
-            )
-            if rec is not None:
-                rec.interleaved_rows = int(c_valid.sum())
-            if self._ilv_mini is None:
-                self._ilv_mini = self._make_mini(self._ilv_k, self.max_seq)
-            if rec is not None:
-                rec.phases.mark("sync")
-            (
-                toks, counts, self.cache, self.dcache,
-                prev_out, cur_out, gstate_out, self._ilv_mini, sel,
-            ) = self._tick_spec_chunk(
-                *args, jnp.asarray(chunk_arr), self._ilv_mini,
-                jnp.asarray(offs), jnp.asarray(c_tl),
-                jnp.asarray(c_valid), jnp.asarray(c_adapt), *jargs,
-            )
-        else:
-            if rec is not None:
-                rec.phases.mark("sync")
-            (
-                toks, counts, self.cache, self.dcache,
-                prev_out, cur_out, gstate_out,
-            ) = self._tick_spec(*args, *jargs)
-        self._cur_dev = cur_out
-        self._prev_dev = prev_out
-        self._gstate_dev = gstate_out
-        try:
-            toks.copy_to_host_async()
-            counts.copy_to_host_async()
-        except (AttributeError, RuntimeError):
-            pass
-        owners = [s.request if s.active else None for s in self.slots]
-        self._inflight.append((toks, counts, owners, rec, "spec"))
-        self.timing["ticks"] += 1
-        self.spec_ticks += 1
-        if chunk:
-            self._ilv_advance(sel)
-        if rec is not None:
-            # After _ilv_advance: a final chunk's row finish (one small
-            # device call + activation) is dispatch-side host work.
             rec.phases.mark("dispatch")
 
     def _ilv_fill_rows(self) -> None:
@@ -4149,7 +3772,7 @@ class ContinuousBatcher:
 
     def _ilv_chunk_inputs(self):
         """Host-stamped inputs for the chunk half of a fused tick+chunk
-        call (shared by the plain and speculative dispatches)."""
+        call (shared by the plain and jump dispatches)."""
         k = self._ilv_k
         c = min(self.cfg.prefill_chunk, self.max_seq)
         chunk = np.zeros((k, c), np.int32)
@@ -4227,7 +3850,7 @@ class ContinuousBatcher:
         except (AttributeError, RuntimeError):
             pass
         owners = [s.request if s.active else None for s in self.slots]
-        self._inflight.append((toks, None, owners, rec, "plain"))
+        self._inflight.append((toks, None, owners, rec))
         self.timing["ticks"] += 1
         self._ilv_advance(sel)
         if rec is not None:
@@ -4306,7 +3929,7 @@ class ContinuousBatcher:
         except (AttributeError, RuntimeError):
             pass
         owners = [s.request if s.active else None for s in self.slots]
-        self._inflight.append((toks, counts, owners, rec, "jump"))
+        self._inflight.append((toks, counts, owners, rec))
         self.timing["ticks"] += 1
         if chunk:
             self._ilv_advance(sel)
@@ -4335,19 +3958,16 @@ class ContinuousBatcher:
         first_tok = int(np.asarray(first)[0])
         self._ilv_rows[r] = None
         self._activate_slot(st.slot, req, first_tok)
-        if self._spec:
-            self._spec_admit_rows([(st.slot, req)])
 
     def _collect_tick(self) -> None:
         """Pull the oldest in-flight tick's tokens to the host and emit
         them. Rows whose owner no longer holds the slot (finished — and
         possibly re-admitted — since dispatch) are dropped: their
         tokens are the junk a parked slot keeps sampling."""
-        toks_dev, counts_dev, owners, rec, kind = self._inflight.popleft()
-        toks = np.asarray(toks_dev)  # [B, steps_per_tick | gamma+1 | J+1]
-        # counts is the spec tick's per-row accepted+1 (or the jump
-        # tick's forced-run length + 1; None on plain ticks): emission
-        # truncates to it.
+        toks_dev, counts_dev, owners, rec = self._inflight.popleft()
+        toks = np.asarray(toks_dev)  # [B, steps_per_tick | J+1]
+        # counts is the jump tick's per-row forced-run length + 1 (None
+        # on plain ticks): emission truncates to it.
         counts = None if counts_dev is None else np.asarray(counts_dev)
         if self._routing_stats and counts is None:
             extra = toks[len(self.slots):]  # [counts, steps]
@@ -4363,21 +3983,17 @@ class ContinuousBatcher:
             rec.phases.mark("wait")
         self.timing["collects"] += 1
         finished = 0
-        drafted = accepted = 0
         jump_tokens = jump_runs = 0
         for i, request in enumerate(owners):
             if request is None:
                 continue
-            if kind == "spec":
-                drafted += self._gamma
-                accepted += int(counts[i]) - 1
             slot = self.slots[i]
             if slot.request is not request:
                 continue
             if counts is None:
                 self.cur_tokens[i] = toks[i, -1]
                 self._emit_chunk(i, toks[i])
-            elif kind == "jump":
+            else:
                 c = int(counts[i])
                 if c > 1 and not self._jump_validate(i, request, toks, c):
                     # Refused run (grammar_jump_fail chaos or corrupted
@@ -4391,26 +4007,12 @@ class ContinuousBatcher:
                     jump_runs += 1
                 self.cur_tokens[i] = toks[i, c - 1]
                 self._emit_chunk(i, toks[i, :c])
-            else:
-                c = int(counts[i])
-                # Host mirrors trail the device twins (rebuild seeds
-                # only): cur = the correction token, prev = the token
-                # committed just before it.
-                self.prev_tokens[i] = (
-                    toks[i, c - 2] if c >= 2 else self.cur_tokens[i]
-                )
-                self.cur_tokens[i] = toks[i, c - 1]
-                self._emit_chunk(i, toks[i, :c])
             if self.slots[i].request is not request:
                 finished += 1
-        if kind == "spec":
-            self.spec_drafted += drafted
-            self.spec_accepted += accepted
         self.grammar_jump_tokens += jump_tokens
         self.grammar_jump_runs += jump_runs
         self.recorder.tick_done(
-            rec, finished, spec_drafted=drafted, spec_accepted=accepted,
-            jump_tokens=jump_tokens, jump_runs=jump_runs,
+            rec, finished, jump_tokens=jump_tokens, jump_runs=jump_runs,
         )
         if rec is not None:
             # Cumulative per-phase attribution (ServingStats

@@ -151,9 +151,6 @@ _UNSUPPORTED = {
         "kv_cache_dtype fp8": _FP8_CACHE,
         "lora": "the adapter delta sits on the dense family's fused qkv",
         "pipeline-parallel serving (mesh.stage > 1)": _MOE_ROUTING,
-        "speculative decoding (speculative_draft)": (
-            _MOE_ROUTING + ", which breaks lossless verification"
-        ),
         "batching.paged_kv": (
             "its forward scans the cache in and out a layer and has no "
             "block-table path; the dropless mla_moe family has"
@@ -163,9 +160,6 @@ _UNSUPPORTED = {
         "lora": "the adapter delta sits on the dense family's fused qkv",
         "pipeline-parallel serving (mesh.stage > 1)": (
             "the staged forward runs one homogeneous layer stack"
-        ),
-        "speculative decoding (speculative_draft)": (
-            "the verify window would have to rewind latent pages"
         ),
         "kv_ring": "the model has no sliding window",
         "batching.kv_tiers": _LATENT_CACHE,
@@ -194,10 +188,6 @@ _UNSUPPORTED = {
         "pipeline-parallel serving (mesh.stage > 1)": (
             "the staged forward threads two cache planes through the "
             "dense layer; this family's layer has three and experts"
-        ),
-        "speculative decoding (speculative_draft)": (
-            "the verify window would have to rewind three-plane pages "
-            "and a window of queries has a selection each"
         ),
         "kv_ring": "the model has no sliding window",
         "batching.kv_tiers": _THREE_PLANES,
@@ -383,7 +373,6 @@ class GenerationEngine:
         self._generate_fn = jax.jit(
             self._generate_impl, static_argnums=(3, 4)
         )
-        self._init_speculative(seed)
         self._init_ledger()
 
     def _init_ledger(self) -> None:
@@ -392,7 +381,7 @@ class GenerationEngine:
         built over it register their components into the same instance
         (per-tier scopes) so one reconcile() closes over the whole
         serving stack. Suppliers read live attributes, so quantize/
-        LoRA/draft rebuilds are accounted automatically. Obs-off:
+        LoRA rebuilds are accounted automatically. Obs-off:
         the ledger registers nothing and the watcher never installs —
         zero work, like the flight recorder's disabled hooks."""
         from ggrmcp_tpu.serving import compile_watcher
@@ -418,8 +407,8 @@ class GenerationEngine:
             compile_watcher.watcher.mark_cold()
 
     def _ledger_weights(self):
-        """Target + draft model parameters (LoRA factors excluded —
-        they are their own component)."""
+        """The model's parameters (LoRA factors excluded — they are
+        their own component)."""
         params = self.params
         if self.lora_enabled and isinstance(params, dict):
             params = {
@@ -429,10 +418,7 @@ class GenerationEngine:
                     if not k.startswith("lora_")
                 },
             }
-        out = [params]
-        if self.draft_fam is not None:
-            out.append(self.draft_params)
-        return out
+        return params
 
     def _ledger_lora(self):
         """The stacked per-adapter factor arrays inside params (the
@@ -536,12 +522,6 @@ class GenerationEngine:
                 "lora does not compose with pipeline-parallel serving "
                 "yet (the staged layer loop would need per-stage idx "
                 "threading)"
-            )
-        if self.serving.speculative_draft:
-            raise ValueError(
-                "lora does not compose with speculative decoding (the "
-                "draft/verify loop runs the base model; an adapter'd "
-                "request would silently lose its adapter)"
             )
         if self.serving.lora.rank < 1:
             raise ValueError("lora.rank must be >= 1")
@@ -763,8 +743,6 @@ class GenerationEngine:
             "lora": bool(sv.lora.adapters)
             or bool(getattr(sv.lora, "registry", "")),
             "pipeline-parallel serving (mesh.stage > 1)": stages > 1,
-            "speculative decoding (speculative_draft)": bool(
-                sv.speculative_draft),
             "kv_ring": bool(getattr(sv, "kv_ring", False)),
             "batching.kv_tiers": bool(bt.kv_tiers),
             "batching.paged_kv_host_bytes (the host tier)": bool(
@@ -877,65 +855,6 @@ class GenerationEngine:
         return self.fam.forward(
             params, self.cfg, tokens, cache, use_flash=self.use_flash,
             flash_mesh=self.flash_mesh, ring=ring, lora_idx=lora_idx,
-        )
-
-    def _init_speculative(self, seed: int) -> None:
-        """Build the draft model when speculative decoding is enabled
-        (serving.speculative_draft): greedy exact-match and rejection-
-        sampled modes, lossless either way (ops/speculative.py). The
-        draft serves the continuous batcher's spec tick."""
-        self.draft_fam = None
-        if not self.serving.speculative_draft:
-            return
-        from ggrmcp_tpu import models as models_mod
-
-        if self.pp_serving:
-            raise ValueError(
-                "speculative decoding is not supported under "
-                "pipeline-parallel serving (the draft/verify loop would "
-                "run the layer scan against stage-sharded weights)"
-            )
-        family, dcfg = models_mod.get_model(self.serving.speculative_draft)
-        if family != "llama":
-            raise ValueError(
-                "speculative draft must be a dense decoder model"
-            )
-        if dcfg.vocab_size != self.cfg.vocab_size:
-            raise ValueError(
-                f"draft vocab {dcfg.vocab_size} != target vocab "
-                f"{self.cfg.vocab_size}"
-            )
-        self.draft_cfg = dcfg
-        self.draft_fam = models_mod.family_module(dcfg)
-        if self.serving.speculative_draft_checkpoint:
-            from ggrmcp_tpu.serving.checkpoint import restore
-
-            like = jax.eval_shape(
-                partial(self.draft_fam.init_params, cfg=dcfg),
-                jax.random.PRNGKey(0),
-            )
-            params = restore(
-                self.serving.speculative_draft_checkpoint, like=like
-            )
-            self.draft_params = _shard_params(
-                params, self.draft_fam.param_specs(dcfg), self.mesh
-            )
-        else:
-            self.draft_params = _sharded_init(
-                partial(self.draft_fam.init_params, cfg=dcfg),
-                self.draft_fam.param_specs(dcfg), self.mesh,
-                jax.random.PRNGKey(seed + 1),
-            )
-
-    def draft_forward(self, draft_params, tokens, cache):
-        """fam.forward for the speculative draft model (dense Llama —
-        _init_speculative enforces it; PP/MoE/LoRA are rejected with a
-        draft configured, so none of decode_forward's dispatch cases
-        apply). Used by the continuous batcher's spec tick
-        (serving/batching.py)."""
-        return self.draft_fam.forward(
-            draft_params, self.draft_cfg, tokens, cache,
-            use_flash=self.use_flash, flash_mesh=self.flash_mesh,
         )
 
     def _synthetic_int8_init(self, seed: int):
@@ -1112,29 +1031,13 @@ class GenerationEngine:
 
     # -- public API ---------------------------------------------------------
 
-    def make_draft_cache(self, batch: int, max_len: int) -> llama_mod.KVCache:
-        """Slot-pool KV cache for the speculative DRAFT model (the
-        continuous batcher's spec mode carries one beside the shared
-        target cache). Draft serving is never pipeline-parallel
-        (_init_speculative rejects the combination), so the plain
-        family cache specs apply."""
-        assert self.draft_fam is not None
-        return self.make_cache(batch, max_len, cfg=self.draft_cfg,
-                               fam=self.draft_fam)
-
-    def make_cache(
-        self, batch: int, max_len: int, cfg=None, fam=None
-    ) -> llama_mod.KVCache:
-        """Mesh-sharded KV cache. Default: the target model's geometry
-        (PP-aware); pass cfg/fam to build one for another model sharing
-        the mesh (the speculative draft)."""
-        other = cfg is not None
-        cfg = cfg or self.cfg
-        fam = fam or self.fam
+    def make_cache(self, batch: int, max_len: int) -> llama_mod.KVCache:
+        """Mesh-sharded KV cache in the model's geometry (PP-aware)."""
+        cfg = self.cfg
         lead = (cfg.num_layers, batch, max_len)
         specs = (
-            self._pp.cache_specs_pp() if self.pp_serving and not other
-            else fam.cache_specs()
+            self._pp.cache_specs_pp() if self.pp_serving
+            else self.fam.cache_specs()
         )
         observe = partial(self._observe_cache_spec, "kv_cache")
 

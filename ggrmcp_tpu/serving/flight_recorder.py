@@ -74,8 +74,8 @@ HISTOGRAM_NAMES = ("ttft_ms", "e2e_ms", "queue_ms", "tick_duration_ms") + tuple(
 # the device: the admission program queues behind it), device (from the
 # device being free to the program's first tokens on the host: the
 # admission program alone, plus the copy back) and activate (the
-# _activate_slot loop, and the draft-side admission where it runs). The
-# first, second and last are the round's host work.
+# _activate_slot loop). The first, second and last are the round's host
+# work.
 ADMIT_HOST_MARKS = ("build", "launch", "activate")
 
 
@@ -131,15 +131,10 @@ class TickRecord:
     duration_ms: float = 0.0
     finished: int = 0
     source: str = ""
-    # Speculative tick (a draft is configured): draft tokens proposed
-    # and accepted on THIS tick — the per-tick acceptance trace (0/0 on
-    # plain ticks). Completed at collect, like finished/duration_ms.
-    spec_drafted: int = 0
-    spec_accepted: int = 0
     # Jump-ahead tick (grammar.jump_max > 0): forced tokens emitted by
     # multi-token advances on THIS tick and runs advanced (0/0 on
-    # plain/spec ticks) — the per-tick jump trace beside the spec
-    # acceptance one. Completed at collect, like finished/duration_ms.
+    # plain ticks) — the per-tick jump trace. Completed at collect,
+    # like finished/duration_ms.
     jump_tokens: int = 0
     jump_runs: int = 0
     # Paged KV arena occupancy at dispatch (batching.paged_kv=on; 0
@@ -186,8 +181,6 @@ class TickRecord:
             "timedOutTotal": self.timed_out_total,
             "traceIds": self.trace_ids,
             "source": self.source,
-            "specDrafted": self.spec_drafted,
-            "specAccepted": self.spec_accepted,
             "jumpTokens": self.jump_tokens,
             "jumpRuns": self.jump_runs,
             "kvPagesInUse": self.kv_pages_in_use,
@@ -442,8 +435,6 @@ class FlightRecorder:
         self,
         rec: Optional[TickRecord],
         finished: int,
-        spec_drafted: int = 0,
-        spec_accepted: int = 0,
         jump_tokens: int = 0,
         jump_runs: int = 0,
     ) -> None:
@@ -452,9 +443,8 @@ class FlightRecorder:
         includes the deliberate one-tick lag under pipelining), settle
         the phase attribution (the final `host` mark covers emission
         and finish bookkeeping — the caller marked sync/dispatch/wait),
-        how many requests finished on it, and — on speculative/jump
-        ticks — the round's draft/accept or forced-run counts (the
-        per-tick acceptance and jump traces)."""
+        how many requests finished on it, and — on jump ticks — the
+        round's forced-run counts (the per-tick jump trace)."""
         if rec is None:
             return
         if rec.phases is not None:
@@ -476,8 +466,6 @@ class FlightRecorder:
         else:
             rec.duration_ms = (time.perf_counter() - rec.t_mono) * 1000.0
         rec.finished = finished
-        rec.spec_drafted = spec_drafted
-        rec.spec_accepted = spec_accepted
         rec.jump_tokens = jump_tokens
         rec.jump_runs = jump_runs
         with self._lock:

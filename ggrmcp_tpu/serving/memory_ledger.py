@@ -4,9 +4,9 @@ for bytes" (the PR 9 design discipline applied to HBM instead of time).
 
 The two scarce resources a TPU window spends are bytes and compiles,
 and until this module the tree exported exactly one memory number
-(`kv_cache_bytes`) while weights, the paged arena, block tables, the
-draft cache, grammar tables, LoRA factors, and the interleave mini all
-went unaccounted. vLLM's startup memory profiler is the prior art: it
+(`kv_cache_bytes`) while weights, the paged arena, block tables,
+grammar tables, LoRA factors, and the interleave mini all went
+unaccounted. vLLM's startup memory profiler is the prior art: it
 walks what is actually resident and attributes it, instead of trusting
 a config-derived estimate.
 
@@ -54,8 +54,8 @@ from typing import Any, Callable, Optional
 # (scope, component) ordering for stable output; unknown components
 # append after these.
 CORE_COMPONENTS = (
-    "weights", "lora", "kv_arena", "block_tables", "draft_cache",
-    "ilv_mini", "grammar_arena", "tick_state",
+    "weights", "lora", "kv_arena", "block_tables", "ilv_mini",
+    "grammar_arena", "tick_state",
 )
 
 

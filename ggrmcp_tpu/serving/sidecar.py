@@ -1062,8 +1062,6 @@ class Sidecar:
                     shed_total=t.shed_total, replayed_total=t.replayed_total,
                     timed_out_total=t.timed_out_total,
                     trace_ids=t.trace_ids, source=t.source,
-                    spec_drafted=t.spec_drafted,
-                    spec_accepted=t.spec_accepted,
                     kv_pages_in_use=t.kv_pages_in_use,
                     phase_admit_ms=t.phase_admit_ms,
                     phase_sync_ms=t.phase_sync_ms,

@@ -59,7 +59,7 @@ def _round(inflight=()) -> ContinuousBatcher:
     b._paged = False
     b._cache_at_risk = False
     b.cache = None
-    b._inflight = [(t, None, [], None, "plain") for t in inflight]
+    b._inflight = [(t, None, [], None) for t in inflight]
     b._adm_timer = PhaseTimer()
     b._adm_span = {"seq": 1, "tick": 1}
     b._adm_chunk_run = 0

@@ -91,24 +91,22 @@ class TestLoadConfig:
         cfg = cli.load_config(args)
         assert cfg.server.port == 3333
 
-    def test_sidecar_serving_overrides(self):
-        args = cli.build_parser().parse_args([
+    def test_sidecar_serving_overrides(self, tmp_path):
+        argv = [
             "sidecar", "--port", "7001", "--model", "tiny-llama",
-            "--quantize", "int8", "--speculative-draft", "tiny-llama",
-        ])
-        cfg = cli.load_config(args)
+            "--quantize", "int8",
+        ]
+        cfg = cli.load_config(cli.build_parser().parse_args(argv))
         assert cfg.serving.port == 7001
         assert cfg.serving.model == "tiny-llama"
         assert cfg.serving.quantize == "int8"
-        assert cfg.serving.speculative_draft == "tiny-llama"
-
-    def test_gateway_tpu_speculative_draft_flag(self):
-        args = cli.build_parser().parse_args([
-            "gateway", "--tpu", "--model", "tiny-llama",
-            "--speculative-draft", "tiny-llama",
-        ])
-        cfg = cli.load_config(args)
-        assert cfg.serving.speculative_draft == "tiny-llama"
+        # A file that still names a retired option is refused like any
+        # other unknown key (config._merge); there is no alias.
+        f = tmp_path / "retired.json"
+        f.write_text('{"serving": {"speculative_draft": "tiny-llama"}}')
+        args = cli.build_parser().parse_args([*argv, "--config", str(f)])
+        with pytest.raises(ValueError, match="unknown config key"):
+            cli.load_config(args)
 
     def test_invalid_flag_value_fails_validation(self):
         args = cli.build_parser().parse_args(

@@ -246,12 +246,13 @@ class TestAllocInJit:
         assert "_grow_row" in report.findings[0].message
         assert "PR 6" in report.findings[0].precedent
 
-    def test_fires_on_allocator_mutation_in_spec_tick(self, tmp_path):
+    def test_fires_on_allocator_mutation_in_tick_body(self, tmp_path):
         report = lint(
-            tmp_path, "ggrmcp_tpu/ops/speculative.py", """
-            def spec_tick(batcher, tokens):
-                batcher.pages.admit(2)
-                return tokens
+            tmp_path, "ggrmcp_tpu/serving/batching.py", """
+            class Batcher:
+                def _tick_impl(self, params, tokens, cache):
+                    self.pages.admit(2)
+                    return tokens
             """,
         )
         assert rule_ids(report) == ["alloc-in-jit"]
@@ -368,11 +369,11 @@ class TestLedgerUnregistered:
             tmp_path, "ggrmcp_tpu/serving/engine2.py", """
             class Engine:
                 def __init__(self):
-                    self.draft_params = _sharded_init(init, None, None)
+                    self.params = _sharded_init(init, None, None)
                     self.ledger.register("weights", self._weights)
 
                 def _weights(self):
-                    return [self.draft_params]
+                    return [self.params]
             """,
         )
         assert report.clean

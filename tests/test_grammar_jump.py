@@ -9,7 +9,7 @@ Covers, bottom-up:
   over jump_tokens)
 - batcher: greedy constrained output BIT-identical jump-on vs jump-off
   on every admission path — fused, chunked prefill, tick-interleaved
-  admission, paged KV, and speculative ticks — with jump_runs > 0 on
+  admission and paged KV — with jump_runs > 0 on
   the on side (the fast path demonstrably engaged)
 - compile stability: a mixed batch over distinct schemas adds zero
   compiles to the plain AND jump tick programs post-warmup (the
@@ -174,17 +174,6 @@ def engine():
     )
 
 
-@pytest.fixture(scope="module")
-def spec_engine():
-    return GenerationEngine(
-        llama.CONFIGS["tiny-llama"],
-        ServingConfig(
-            mesh=MeshConfig(tensor=2, data=0),
-            speculative_draft="tiny-llama",
-        ),
-    )
-
-
 @pytest.fixture(autouse=True)
 def clean_failpoints():
     failpoints.registry.disarm()
@@ -292,20 +281,6 @@ class TestJumpBitIdentity:
             assert _jump_stats(b)["grammar_jump_runs"] > 0
         assert on == off
         json.loads(TOK.decode(on))
-
-    async def test_speculative(self, engine, spec_engine):
-        """Spec mode seeds its draft proposal with the forced prefix (a
-        free 100%-acceptance draft): spec-on constrained greedy output
-        equals the plain jump-off run."""
-        g = compile_schema(SCHEMAS["const_obj"], vocab_size=VOCAB)
-        async with _batcher(engine, jump=False) as b:
-            off, reason_off = await _drain(b, [3, 1, 4, 1], 256, grammar=g)
-        async with _batcher(spec_engine, jump=True) as b:
-            on, reason_on = await _drain(b, [3, 1, 4, 1], 256, grammar=g)
-            stats = b.counter_stats()
-        assert on == off and reason_on == reason_off
-        assert stats["spec_drafted"] > 0
-        assert stats["spec_accepted"] > 0
 
 
 class TestJumpCompileStability:

@@ -158,36 +158,6 @@ class TestKVQuantServing:
             await batcher.stop()
         assert out1 == out2 and len(out1) <= 6
 
-    async def test_speculative_composes_with_int8(self):
-        """Lossless speculative decoding on int8 caches: the spec
-        tick's output equals plain greedy WITHIN the int8 config
-        (per-position quantization is write-order independent, so
-        draft-round cache writes reproduce the plain path's values
-        exactly)."""
-        eng = GenerationEngine(
-            CFG,
-            serving_cfg(speculative_draft="tiny-llama"),
-        )
-        prompts = [[3, 1, 4, 1, 5], [9, 2, 6]]
-        plain, _ = eng.generate(prompts, max_new_tokens=10, seed=0)
-        batcher = ContinuousBatcher(
-            eng, BatchingConfig(max_batch_size=4, kv_cache_max_seq=256)
-        )
-        batcher.start()
-        try:
-            spec = []
-            for prompt in prompts:
-                out = []
-                async for ids, _ in batcher.submit(
-                    prompt, 10, SamplingConfig(temperature=0.0)
-                ):
-                    out.extend(ids)
-                spec.append(out)
-        finally:
-            await batcher.stop()
-        assert spec == plain
-        assert batcher.spec_ticks >= 1
-
     async def test_chunked_admission_on_int8(self, engine):
         """Chunked prefill on the quantized cache: a long prompt
         admitted through the [T, C] grid reproduces the engine's own

@@ -125,11 +125,6 @@ class TestEngineLora:
                 moe.CONFIGS["tiny-moe"],
                 lora_serving(),
             )
-        with pytest.raises(ValueError, match="speculative"):
-            GenerationEngine(
-                llama.CONFIGS["tiny-llama"],
-                lora_serving(speculative_draft="tiny-llama"),
-            )
 
 
 class TestBatcherLora:

@@ -6,7 +6,7 @@ Covers, bottom-up:
 - THE closure contract: the component sum reconciles against JAX
   live-buffer totals BY ARRAY IDENTITY — attributed + unattributed ==
   live exactly, and unattributed == 0 for a quiescent serving stack —
-  across plain/paged/tiered/speculative/grammar configs, all on the
+  across plain/paged/tiered/grammar configs, all on the
   2-device CPU tensor mesh (the TP stand-in, like tests/test_tp.py)
 - compile watcher: a genuine recompile (new shape after the warmup
   mark) increments the counter, emits the WARNING log line, and lands
@@ -169,16 +169,16 @@ class TestMemoryLedger:
         import numpy as np
 
         led = MemoryLedger(enabled=True)
-        led.register("draft_cache", lambda: None)
+        led.register("ilv_mini", lambda: None)
         led.register("tick_state", lambda: np.zeros((8,)))  # host RAM
         assert led.component_bytes() == {
-            ("", "draft_cache"): 0, ("", "tick_state"): 0,
+            ("", "ilv_mini"): 0, ("", "tick_state"): 0,
         }
 
 
 class TestClosure:
     """Component sum == JAX live-buffer totals, by identity, across
-    the serving configs (acceptance: paged/tiered/spec/grammar/TP —
+    the serving configs (acceptance: paged/tiered/grammar/TP —
     every config here runs on the 2-device tensor mesh)."""
 
     async def test_plain_tp(self):
@@ -209,22 +209,6 @@ class TestClosure:
         _assert_closed(rec)
         assert rec["components"]["block_tables"] > 0
         assert batcher.stats()["memory_block_tables_bytes"] > 0
-
-    async def test_speculative(self):
-        _eng, batcher, rec = await _closed_stack(
-            _serving(
-                speculative_draft="tiny-llama",
-                batching=BatchingConfig(
-                    max_batch_size=2, kv_cache_max_seq=128,
-                    max_queue_delay_ms=2.0,
-                ),
-            ),
-            [[5, 6, 7]],
-        )
-        _assert_closed(rec)
-        assert rec["components"]["draft_cache"] > 0
-        # Draft-model parameters fold into the weights component.
-        assert batcher.stats()["memory_draft_cache_bytes"] > 0
 
     async def test_grammar_constrained(self):
         from ggrmcp_tpu.grammar import compile_schema
@@ -453,8 +437,8 @@ class TestMemoryDebugSurface:
         assert by_comp["weights"] > 0
         assert by_comp["kv_arena"] > 0
         assert set(by_comp) >= {
-            "weights", "lora", "kv_arena", "block_tables", "draft_cache",
-            "ilv_mini", "grammar_arena", "tick_state",
+            "weights", "lora", "kv_arena", "block_tables", "ilv_mini",
+            "grammar_arena", "tick_state",
         }
         assert families["gateway_backend_compile_count"].samples[0].value > 0
         assert "gateway_backend_compile_post_warmup" in families

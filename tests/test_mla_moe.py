@@ -479,7 +479,6 @@ async def test_one_chunked_program_a_row_bucket_whatever_the_depths(
 
 @pytest.mark.parametrize("serving, feature", [
     (_serving(lora=LoraConfig(adapters=["a"])), "lora"),
-    (_serving(speculative_draft="tiny-llama"), "speculative decoding"),
     (_serving(kv_ring=True), "kv_ring"),
     (_serving(batching=BatchingConfig(kv_tiers=[[64, 2], [128, 2]])),
      "batching.kv_tiers"),

@@ -129,7 +129,7 @@ class TestProtoDrift:
         # gateway_backend_memory_bytes family (never per-field gauges).
         assert memory == {
             "weights", "lora", "kv_arena", "block_tables",
-            "draft_cache", "ilv_mini", "grammar_arena", "tick_state",
+            "ilv_mini", "grammar_arena", "tick_state",
         }
         assert not (gauges & infos)
         # Repeated MESSAGE fields carry structured per-class/per-tenant
@@ -225,11 +225,15 @@ class TestProtoDrift:
         "speculative_calls", "speculative_requests",
         "speculative_drafted", "speculative_accepted",
         "memory_prefix_pool_bytes",
+        # PR 48: went with the in-batcher speculative tick.
+        "spec_ticks", "spec_drafted", "spec_accepted",
+        "memory_draft_cache_bytes",
     ])
     def test_retired_fields_are_gone_and_their_numbers_reserved(self, name):
         """PR 26's inventory: the second tick clocks and the lifetime
         percentile gauges had no reader; PR 39's: five fields had no
-        writer. Their numbers stay reserved, so no later field can take
+        writer; PR 48's: four fields went with the path that wrote
+        them. Their numbers stay reserved, so no later field can take
         one and be misread by an old peer."""
         from ggrmcp_tpu.gateway.metrics import serving_gauge_names
         from ggrmcp_tpu.rpc.pb import serving_pb2
@@ -245,9 +249,15 @@ class TestProtoDrift:
             n for r in proto.reserved_range for n in range(r.start, r.end)
         }
         assert reserved == {
-            8, 9, 14, 15, 16, 17, 18, 19, 20, 21, 22, 23, 26, 27, 28, 97,
+            8, 9, 14, 15, 16, 17, 18, 19, 20, 21, 22, 23, 26, 27, 28,
+            50, 51, 52, 96, 97,
         }
         assert not reserved & {f.number for f in desc.fields}
+        tick = descriptor_pb2.DescriptorProto()
+        serving_pb2.TickRecord.DESCRIPTOR.CopyToProto(tick)
+        assert {
+            n for r in tick.reserved_range for n in range(r.start, r.end)
+        } == {14, 15}
 
     def test_flight_recorder_stats_match_proto_fields(self):
         """histogram_stats() keys must be exact proto field names —

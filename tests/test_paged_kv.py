@@ -5,8 +5,8 @@ The contract under test, in order of importance:
 1. BIT-IDENTITY — greedy outputs with paged_kv=on are byte-equal to
    the contiguous path (and to the engine's uncached generate) across
    every admission path (fused / chunked / paged-prefix / interleaved),
-   under injected tick faults (chaos replay), and with speculative and
-   grammar rows in the batch. The contiguous path stays the off-mode
+   under injected tick faults (chaos replay), and with grammar rows
+   in the batch. The contiguous path stays the off-mode
    precisely so this is provable.
 2. SHARING — same-preamble admissions reference the SAME physical
    pages (refcounts, kv_pages_shared), divergent pages copy-on-write,
@@ -53,19 +53,6 @@ def engine():
     return GenerationEngine(
         llama.CONFIGS["tiny-llama"],
         ServingConfig(mesh=MeshConfig(tensor=2, data=0)),
-    )
-
-
-@pytest.fixture(scope="module")
-def spec_engine():
-    """Draft-configured engine (draft = same arch, independent random
-    weights → realistic imperfect acceptance) for spec×paged tests."""
-    return GenerationEngine(
-        llama.CONFIGS["tiny-llama"],
-        ServingConfig(
-            mesh=MeshConfig(tensor=2, data=0),
-            speculative_draft="tiny-llama",
-        ),
     )
 
 
@@ -288,20 +275,6 @@ class TestPagedBitIdentity:
             failpoints.registry.disarm()
         assert outs_chaos == outs_off
         assert chaos.replayed >= 1
-
-    async def test_speculative_rows_match(self, spec_engine):
-        """Spec draft/verify ticks over the paged pool: greedy rows
-        bitwise what the plain path emits, and a same-preamble burst
-        shares pages even though the verify tick owns the cache."""
-        head = prompt_of(20)
-        prompts = [head + prompt_of(4, salt=s) for s in range(4)]
-        expected, _ = spec_engine.generate(prompts, max_new_tokens=5, seed=0)
-        outs_on, paged = await run_wave(spec_engine, paged_cfg(), prompts)
-        assert outs_on == expected
-        assert paged.spec_ticks > 0
-        # The one-round burst shares the first row's eagerly indexed
-        # preamble pages (2 full pages of the 20-token head at page 8).
-        assert paged.prefix_hits >= 3
 
     async def test_grammar_row_in_paged_batch(self, engine):
         """A DFA-constrained row and plain greedy rows share one paged

@@ -212,18 +212,6 @@ class TestPPRing:
 
 
 class TestPPValidation:
-    def test_speculative_rejected_under_pp(self, pp_mesh):
-        with pytest.raises(ValueError, match="pipeline"):
-            GenerationEngine(
-                CFG,
-                ServingConfig(
-                    model="tiny-llama",
-                    mesh=MeshConfig(stage=2, tensor=2, data=0),
-                    speculative_draft="tiny-llama",
-                ),
-                mesh=pp_mesh,
-            )
-
     def test_indivisible_layers_rejected(self):
         mesh = mesh_mod.build_mesh(MeshConfig(stage=8, data=0))
         with pytest.raises(ValueError, match="divisible"):

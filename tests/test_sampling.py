@@ -331,8 +331,7 @@ class TestGatedSamplerIsTheUngatedOne:
                 np.testing.assert_array_equal(nxt[~live], state[~live])
 
     def test_support_of_sampling_rows_is_the_ungated_support(self):
-        """dynamic_support_mask, the gate's other caller's view
-        (ops/speculative.py reads it through filtered_logprobs): every
+        """dynamic_support_mask as its callers see it: every
         SAMPLING row's support is the sorted threshold's, gate open or
         shut. A greedy row's is not compared: nothing reads it."""
         logits = jax.random.normal(jax.random.PRNGKey(9), (GATE_B, GATE_V))

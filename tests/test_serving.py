@@ -767,8 +767,3 @@ class TestGatewayToSidecar:
         finally:
             await gw.stop()
             await side.stop()
-
-
-# Heavy JAX-compile/serving integration module: excluded from the
-# fast `make test` signal; always in `make test-all` / CI.
-pytestmark = pytest.mark.slow

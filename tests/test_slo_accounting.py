@@ -3,7 +3,7 @@ docs/observability.md "SLO accounting").
 
 What this file proves:
 - goodput-partition CLOSURE: met + violated + unevaluated ==
-  total_requests EXACTLY, per class, across plain/paged/tiered/spec/
+  total_requests EXACTLY, per class, across plain/paged/tiered/
   grammar batcher configs and under chaos (submit-storm shed, queue
   timeout, tick-failure replay) — a shed or a timeout lands TYPED in
   the partition, never silently dropped from the total
@@ -86,12 +86,6 @@ def _engine(**kw):
 @pytest.fixture(scope="module")
 def engine():
     return _engine()
-
-
-@pytest.fixture(scope="module")
-def spec_engine():
-    # A configured draft makes every batcher over it run the spec tick.
-    return _engine(speculative_draft="tiny-llama")
 
 
 @pytest.fixture(autouse=True)
@@ -639,19 +633,15 @@ def _make_batcher(engine, mode):
 
 class TestClosureAcrossConfigs:
     @pytest.mark.parametrize(
-        "mode", ["plain", "paged", "tiered", "spec", "grammar"]
+        "mode", ["plain", "paged", "tiered", "grammar"]
     )
-    async def test_goodput_partition_closure(
-        self, engine, spec_engine, mode
-    ):
+    async def test_goodput_partition_closure(self, engine, mode):
         """The acceptance property, per serving config: every
         submitted request lands in exactly one partition; "fast"
         finishes violate (µs targets), "lax" finishes meet; tenant
         decode attribution reconciles against actually-emitted
         tokens."""
-        batcher = _make_batcher(
-            spec_engine if mode == "spec" else engine, mode
-        )
+        batcher = _make_batcher(engine, mode)
         grammar = (
             compile_schema({"enum": ["alpha", "beta"]}, vocab_size=VOCAB)
             if mode == "grammar" else None

@@ -5,7 +5,7 @@ The load-bearing guarantees:
 
   * Phase accounting CLOSES — for every collected tick, admit + sync +
     dispatch + wait + host equals the record's duration_ms within a
-    small epsilon, across fused/chunked/interleaved/paged/spec
+    small epsilon, across fused/chunked/interleaved/paged
     dispatch paths (no unattributed time). This is what makes "this
     tick lost 3.1 ms to host-side table sync" a trustworthy statement
     before the TPU window spends minutes capturing it.
@@ -55,14 +55,6 @@ def engine():
     return GenerationEngine(
         llama.CONFIGS["tiny-llama"],
         ServingConfig(mesh=_mesh()),
-    )
-
-
-@pytest.fixture(scope="module")
-def spec_engine():
-    return GenerationEngine(
-        llama.CONFIGS["tiny-llama"],
-        ServingConfig(mesh=_mesh(), speculative_draft="tiny-llama"),
     )
 
 
@@ -186,12 +178,6 @@ class TestPhaseClosure:
             paged_kv="on", paged_kv_page_size=16,
         )
         _assert_closure(batcher)
-
-    async def test_spec_path(self, spec_engine):
-        batcher = await _drive(spec_engine, [[5, 6, 7], [9, 10, 11]])
-        ticks = _assert_closure(batcher)
-        assert batcher.spec_ticks > 0
-        assert any(t.spec_drafted > 0 for t in ticks)
 
     async def test_disabled_recorder_attributes_nothing(self, engine):
         from ggrmcp_tpu.core.config import ObservabilityConfig

@@ -5,8 +5,8 @@ The contract under test, in order of importance:
 1. BIT-IDENTITY — greedy outputs on an N-chip tensor mesh are
    byte-equal to the 1-chip run with the SAME weights, across every
    admission path (fused trickle/burst, chunked, interleaved), with
-   the paged KV arena on, with speculative draft/verify ticks on, and
-   under injected tick faults (chaos replay). Token ids, not logits:
+   the paged KV arena on, and under injected tick faults (chaos
+   replay). Token ids, not logits:
    multichip reduction order may perturb the last float ulp, but the
    served stream must be the same stream.
 2. NO MASQUERADE — a sharding spec silently downgraded to replication
@@ -302,27 +302,6 @@ class TestPagedTimesTP:
             eng1, _cfg(paged_kv="on", paged_kv_page_size=8)
         )
         assert outs == wave_tp
-
-
-class TestSpecTimesTP:
-    @pytest.fixture(scope="class")
-    def eng2_spec(self, params_host):
-        return GenerationEngine(
-            llama.CONFIGS["tiny-llama"],
-            ServingConfig(
-                mesh=MeshConfig(tensor=2, data=0),
-                speculative_draft="tiny-llama",
-            ),
-            params=params_host,
-        )
-
-    async def test_spec_ticks_tp_bit_identical(self, eng2_spec, wave_tp):
-        """Draft/verify ticks on the tensor mesh: greedy exact-match
-        keeps the stream identical to the plain TP tick (and the
-        1-chip run, transitively)."""
-        outs, batcher = await _run_wave(eng2_spec, _cfg())
-        assert outs == wave_tp
-        assert batcher.spec_ticks >= 1
 
 
 class TestChaosTimesTP:
