@@ -42,6 +42,14 @@ next starts (this parent never imports JAX or the package):
               block walk), then one decode step over the same state as
               pages (K and V gathered by token index); fails unless
               both sparse paths ran.
+  jamba       AI21-Jamba2-3B whole at its published widths (models/
+              jamba.py: 26 Mamba layers, attention without rotary at
+              layers 7 and 21) against benchmark/reference_jamba.py's
+              float32 logits: one 512-token chunk into a contiguous
+              mini cache (the chunk scan, the prefill kernel), then 8
+              decode steps over the same K/V as pages and the same
+              state in a pool (the state update, the paged-decode
+              kernel); prints a chunk's and a 32-row decode step's time.
   experts     the routed experts of one keye layer at its published
               widths (2,048 x 768, 128 experts, top 8): the grouped
               SwiGLU Pallas kernel (ops/experts.py) against the XLA
@@ -664,6 +672,124 @@ def keye_leg_child(rehearsal: bool) -> None:
     }), flush=True)
 
 
+def jamba_leg_child(rehearsal: bool) -> None:
+    """Runs in the child. The whole hybrid model at its published widths
+    (`jamba.forward`: 26 Mamba layers' chunk scan and decode step, the 2
+    attention layers through the prefill and the paged-decode kernels),
+    one chunk of 512 positions into a contiguous mini cache and then 8
+    decode steps over the same K/V as pages and the same state in a
+    pool, against `reference_jamba`'s float32 logits of the same 520
+    tokens on the same drawn weights (the program's and the
+    reference's are drawn one after the other from one recipe: both do
+    not fit the chip together). The statistic is the rms of the logits'
+    difference over the rms of the reference's logits about their mean.
+    It also times the chunk program and a 32-row decode step."""
+    from ggrmcp_tpu.utils.jaxenv import init_runtime
+
+    init_runtime("chip_smoke jamba leg")
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import reference_jamba as ref_mod
+    from ggrmcp_tpu.models import jamba
+    from ggrmcp_tpu.models.llama import KVCache, PagedKVCache
+    from ggrmcp_tpu.ops import attention as attn_ops
+
+    dev = jax.devices()[0]
+    if rehearsal:
+        name, chunk, page, rows, tol = "tiny-jamba", 64, 16, 2, 1e-4
+        path = os.path.join(
+            HERE, "tests", "benchmark", "rehearsal_jamba", "benchmark",
+            "configs", "tiny-jamba-cpu.json")
+    else:
+        check(dev.platform == "tpu", f"jamba leg on {dev.platform}")
+        name, chunk, page, rows, tol = "jamba2-3b", 512, 16, 32, 0.1
+        path = os.path.join(
+            HERE, "benchmark", "configs", "jamba2-3b-bf16-1chip.json")
+    with open(path) as f:
+        model = json.load(f)
+    cfg = jamba.CONFIGS[name]
+    steps, s_max = 8, 2 * chunk
+    ids = np.random.RandomState(3).randint(3, cfg.vocab_size, chunk + steps)
+    params = jax.jit(lambda k: jamba.init_params(k, cfg))(jax.random.PRNGKey(0))
+
+    def run(params, tokens, cache):
+        return jamba.forward(params, cfg, tokens, cache)
+
+    t0 = time.monotonic()
+    step = jax.jit(run, donate_argnums=(2,))
+    logits, mini = step(
+        params, jnp.asarray(ids[None, :chunk]), KVCache.create(cfg, 1, s_max))
+    got = [np.asarray(logits[0])]
+    # the same K and V as pages, the same state as entry 0 of a pool
+    n_pages = s_max // page
+    paged = PagedKVCache.create(cfg, 1, s_max, n_pages, page)
+    paged = paged._replace(
+        k=mini.k.reshape(paged.k.shape), v=mini.v.reshape(paged.v.shape),
+        table=jnp.arange(n_pages, dtype=jnp.int32)[None, :],
+        length=mini.length,
+        state=tuple(pool.at[:, :1].set(leaf)
+                    for pool, leaf in zip(paged.state, mini.state)))
+    for i in range(chunk, chunk + steps):
+        logits, paged = step(params, jnp.asarray(ids[None, i:i + 1]), paged)
+        got.append(np.asarray(logits[0]))
+    got = np.concatenate(got)
+    took = attn_ops.dispatch_counts
+    say(f"  jamba: a chunk of {chunk} and {steps} decode steps compiled and "
+        f"ran in {time.monotonic() - t0:.1f} s (set-up, {dev.device_kind}); "
+        f"programs: ssm_scan {took['ssm_scan']}, ssm_step {took['ssm_step']}, "
+        f"flash {took['flash']}, paged_decode {took['paged_decode']}")
+    check(took["ssm_scan"] >= 1 and took["ssm_step"] >= 1,
+          f"the intended paths did not run: {dict(took)}")
+    if not rehearsal:
+        check(took["flash"] >= 1 and took["paged_decode"] >= 1,
+              f"the attention kernels did not run: {dict(took)}")
+
+    def timed(fn, *args, n=5):
+        out = fn(*args)
+        jax.block_until_ready(out)
+        t = time.monotonic()
+        for _ in range(n):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return (time.monotonic() - t) / n * 1e3
+
+    # a chunk of one row, as a trickle admission runs it, and a decode
+    # step of the whole pool (host launch included in both)
+    chunk_fn = jax.jit(lambda p, t, c: jamba.forward(
+        p, cfg, t, c, logit_idx=jnp.zeros((1,), jnp.int32))[0])
+    ms_chunk = timed(chunk_fn, params, jnp.asarray(ids[None, :chunk]),
+                     KVCache.create(cfg, 1, s_max))
+    pool = PagedKVCache.create(cfg, rows, s_max, rows * n_pages, page)
+    pool = pool._replace(
+        table=jnp.arange(rows * n_pages, dtype=jnp.int32).reshape(rows, -1),
+        length=jnp.full((rows,), chunk, jnp.int32))
+    tick_fn = jax.jit(lambda p, t, c: jamba.forward(p, cfg, t, c)[0])
+    ms_step = timed(tick_fn, params, jnp.zeros((rows, 1), jnp.int32), pool)
+    say(f"  jamba: a [1, {chunk}] chunk {ms_chunk:.2f} ms, a decode step of "
+        f"{rows} rows {ms_step:.2f} ms (host clock, launch included, "
+        f"{dev.device_kind})")
+    del params, mini, paged, pool, logits
+
+    weights = ref_mod.to_host(jax, model, ref_mod.family_init_weights(jax, model))
+    want = np.asarray(ref_mod.logits_of(jax, model, weights, ids.tolist()))
+    check(bool(np.isfinite(got).all()), "non-finite logits")
+    for label, lo, hi in (("chunk", 0, chunk),
+                          ("decode steps", chunk, chunk + steps)):
+        ref = want[lo:hi]
+        err = float(np.sqrt(((got[lo:hi] - ref) ** 2).mean())
+                    / np.sqrt(((ref - ref.mean(-1, keepdims=True)) ** 2).mean()))
+        agree = float((got[lo:hi].argmax(-1) == ref.argmax(-1)).mean())
+        say(f"  {label}: rms error {err:.2e} of the reference logits' spread "
+            f"(limit {tol:g}); the argmax agrees at {agree:.3f} of positions")
+        check(err < tol, f"{label}: {err:.3e} beyond {tol:g}")
+    print("LEG_RESULT " + json.dumps({
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(jax.devices()),
+    }), flush=True)
+
+
 def experts_leg_child(rehearsal: bool) -> None:
     """Runs in the child. The routed experts of one layer at keye's
     published widths (2,048 x 768, 128 experts, top 8; two layers of
@@ -1164,6 +1290,8 @@ def main() -> int:
                     help=argparse.SUPPRESS)
     ap.add_argument("--child-experts", action="store_true",
                     help=argparse.SUPPRESS)
+    ap.add_argument("--child-jamba", action="store_true",
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.child_kernel:
         kernel_leg_child(args.cpu_rehearsal)
@@ -1176,6 +1304,9 @@ def main() -> int:
         return 0
     if args.child_experts:
         experts_leg_child(args.cpu_rehearsal)
+        return 0
+    if args.child_jamba:
+        jamba_leg_child(args.cpu_rehearsal)
         return 0
 
     rehearsal = args.cpu_rehearsal
@@ -1199,13 +1330,18 @@ def main() -> int:
             "keye", "one keye layer (GQA under the indexer's selection "
             "over three planes, softmax-routed experts), a sparse chunk and "
             "a sparse decode step, vs the float32 reference layer", rehearsal)
+    if not legs or "jamba" in legs:
+        run_child_leg(
+            "jamba", "the whole hybrid model (26 Mamba layers, 2 attention "
+            "layers), a chunk and 8 decode steps, vs the float32 reference",
+            rehearsal)
     if "experts" in legs:  # on request: the keye and sparse legs run the
         # same kernel inside their layers, against the float32 layer
         run_child_leg(
             "experts", "the routed experts of one keye layer, the grouped "
             "SwiGLU kernel vs the XLA task loop vs float32", rehearsal)
     if not legs:
-        legs = ["kernel", "sparse", "keye", "serve", "default_kv"]
+        legs = ["kernel", "sparse", "keye", "jamba", "serve", "default_kv"]
         if device["count"] >= 4 and not rehearsal:
             legs.append("tp4")
     else:

@@ -177,6 +177,23 @@ _SERVING_HELP = {
     "prefill_chunk_tokens_run":
         "token positions of the chunk rows the admission programs ran "
         "(prefill_tokens_computed over this is the chunks' fill)",
+    # State beside pages (a family whose rows keep a recurrent state).
+    "state_snapshots_taken":
+        "row-state snapshots indexed on page chain keys",
+    "state_snapshot_lookups":
+        "admissions that matched indexed pages and asked for a state",
+    "state_snapshot_hits":
+        "snapshot lookups that found a state to restore",
+    "state_snapshot_evictions":
+        "row-state snapshots dropped, least recently used first",
+    "state_tokens_matched":
+        "tokens of the pages snapshot lookups matched",
+    "state_tokens_recomputed":
+        "tokens of matched pages run again for want of a state",
+    "state_pool_in_use":
+        "row-state snapshot entries held (the slots' own not counted)",
+    "state_pool_total":
+        "row-state snapshot entries the pool holds at most",
     # Disaggregated prefill/decode serving (serving.role): the
     # sidecar→sidecar KV page-shipping plane. The role itself is a
     # string field and exports info-style beside mesh_shape.

@@ -6,20 +6,27 @@ The serving sidecar resolves `ServingConfig.model` here. Families:
 `deepseek_v32` members add q-compression and a sparse-attention indexer
 whose key rides the page's second plane), "keye" (GQA K and V per head +
 the same indexer with its key as a THIRD plane of every page +
-softmax-routed experts, every layer an expert layer), all four served
-by the same engine, and "bert" (embeddings).
+softmax-routed experts, every layer an expert layer), "jamba" (runs of
+Mamba-1 state-space layers around a few attention layers without
+rotary: only those cache K/V, and every row keeps a recurrent state in
+a pool beside the pages), all five served by the same engine, and
+"bert" (embeddings).
 """
 
 from __future__ import annotations
 
 from typing import Any
 
-from ggrmcp_tpu.models import bert, keye, llama, mla_moe, moe
+from ggrmcp_tpu.models import bert, jamba, keye, llama, mla_moe, moe
 
 _FAMILIES = {
     "llama": llama, "moe": moe, "mla_moe": mla_moe, "keye": keye,
-    "bert": bert,
+    "jamba": jamba, "bert": bert,
 }
+
+
+_BY_CONFIG_MODULE = {
+    m.__name__: m for n, m in _FAMILIES.items() if n != "bert"}
 
 
 def get_model(name: str) -> tuple[str, Any]:
@@ -36,16 +43,17 @@ def available_models() -> list[str]:
 
 
 def family_module(cfg):
-    """The decoder family module (llama, moe, mla_moe or keye) implementing
-    the shared init_params / param_specs / forward / cache_specs
-    contract for `cfg`, told from the config's type. Single dispatch
-    point — engines, trainers and the pipeline all resolve the family
-    here."""
-    if isinstance(cfg, mla_moe.MlaMoeConfig):
-        return mla_moe
-    if isinstance(cfg, keye.KeyeConfig):
-        return keye
-    return moe if isinstance(cfg, moe.MoEConfig) else llama
+    """The decoder family module implementing the shared init_params /
+    param_specs / forward / cache_specs contract for `cfg`: the module
+    that defines the config's class, or the nearest class above it
+    that a family defines (a LlamaConfig subclass of a test is the
+    dense family's). Single dispatch point — engines, trainers and the
+    pipeline all resolve the family here."""
+    for cls in type(cfg).__mro__:
+        module = _BY_CONFIG_MODULE.get(cls.__module__)
+        if module is not None:
+            return module
+    return llama
 
 
 def family_name(cfg) -> str:

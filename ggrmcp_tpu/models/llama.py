@@ -76,6 +76,21 @@ class LlamaConfig(common.ModelConfig):
         planes from it, two at least."""
         return ((self.num_kv_heads, self.head_dim),) * 2
 
+    @property
+    def cache_layers(self) -> int:
+        """Layers that keep K/V: the layer axis of every cache. A stack
+        of which only some layers attend overrides this
+        (models/jamba.py)."""
+        return self.num_layers
+
+    @property
+    def row_state(self) -> tuple:
+        """What a ROW keeps that no position addresses, a leaf each:
+        (shape after `[layer, entry]`, dtype name). Nothing here; a
+        state-space family names its recurrent state (models/jamba.py)
+        and `zero_state` sizes the pool from it."""
+        return ()
+
 
 # Known configurations. llama3-8b mirrors the published Llama-3-8B
 # architecture (the BASELINE.md target model on v5e-8).
@@ -221,6 +236,23 @@ def _zero_planes(cfg: LlamaConfig, lead: tuple, kv_dtype: str) -> tuple:
     return tuple(plane(p) for p in cfg.kv_planes)
 
 
+def zero_state(cfg: LlamaConfig, entries: int) -> tuple:
+    """An empty pool of `entries` row states, a leaf `[layers, entries,
+    ...]` for each that `cfg.row_state` names: () for a family whose
+    rows keep none, so its caches have the leaves they always had."""
+    if not cfg.row_state:
+        return ()
+    layers = cfg.num_layers - cfg.cache_layers
+    return tuple(
+        jnp.zeros((layers, entries, *shape), dtype)
+        for shape, dtype in cfg.row_state)
+
+
+# Pool entries a slot: its own and four snapshots' (docs/paged_kv.md
+# "State beside pages").
+STATE_ENTRIES_PER_SLOT = 5
+
+
 class KVCache(NamedTuple):
     k: jnp.ndarray  # [L, B, S_max, KVH, Dh]
     v: jnp.ndarray  # [L, B, S_max, KVH, Dh]
@@ -229,6 +261,11 @@ class KVCache(NamedTuple):
     # indexer keys), same leading axes. Empty for a two-plane family,
     # whose pytree then has the leaves it always had.
     extra: tuple = ()
+    # The rows' state pool (`cfg.row_state`: a leaf `[layers, entries,
+    # ...]` each) and the entry each batch row reads and writes, [B]
+    # int32; None: row b owns entry b. Empty for a family without one.
+    state: tuple = ()
+    state_rows: Any = None
 
     @classmethod
     def create(
@@ -238,10 +275,10 @@ class KVCache(NamedTuple):
         int8, per-position/head scales in the model dtype — halves KV
         HBM and decode KV bandwidth; serving.kv_cache_dtype)."""
         k, v, *extra = _zero_planes(
-            cfg, (cfg.num_layers, batch, max_len), kv_dtype)
+            cfg, (cfg.cache_layers, batch, max_len), kv_dtype)
         return cls(
             k=k, v=v, length=jnp.zeros((batch,), jnp.int32),
-            extra=tuple(extra))
+            extra=tuple(extra), state=zero_state(cfg, batch))
 
 
 def cache_specs() -> KVCache:
@@ -274,6 +311,10 @@ class PagedKVCache(NamedTuple):
     table: jnp.ndarray  # [B, S_max // page] int32 page ids
     length: jnp.ndarray  # [B] int32 — valid prefix length
     extra: tuple = ()  # further planes of every page (KVCache.extra)
+    # The rows' state pool and who reads which entry (KVCache.state):
+    # the slots' entries first, then the snapshots'.
+    state: tuple = ()
+    state_rows: Any = None
 
     @classmethod
     def create(
@@ -283,12 +324,13 @@ class PagedKVCache(NamedTuple):
         assert max_len % page_size == 0, "page_size must divide max_len"
         width = max_len // page_size
         k, v, *extra = _zero_planes(
-            cfg, (cfg.num_layers, n_pages, page_size), kv_dtype)
+            cfg, (cfg.cache_layers, n_pages, page_size), kv_dtype)
         return cls(
             k=k, v=v,
             table=jnp.full((batch, width), n_pages, jnp.int32),
             length=jnp.zeros((batch,), jnp.int32),
             extra=tuple(extra),
+            state=zero_state(cfg, STATE_ENTRIES_PER_SLOT * batch),
         )
 
 
@@ -545,8 +587,9 @@ def attention_block(
     q = q.reshape(b, s, h, hd)
     k = k.reshape(b, s, kvh, hd)
     v = v.reshape(b, s, kvh, hd)
-    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+    if cfg.rope_theta:  # 0: a model without rotary (models/jamba.py)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
 
     paged_out = k_all = v_all = None
     if cache_k is not None and page_table is not None:

@@ -422,6 +422,17 @@ class ContinuousBatcher:
         # one prefix-reuse mechanism. The contiguous path stays the
         # off-mode so bit-identity is provable (tests/test_paged_kv.py).
         self._paged = getattr(self.cfg, "paged_kv", "off") == "on"
+        # ROW_STATE (the family's module says so, as for the flags
+        # below): every row keeps a state that no position addresses,
+        # in a pool that rides the cache (`cache.state`: the slots'
+        # entries, then the snapshots'). Admission programs restore,
+        # carry and capture it (`_state_io`); serving/pages.py owns
+        # which snapshot hangs where (docs/paged_kv.md "State beside
+        # pages").
+        self._row_state = bool(
+            getattr(getattr(engine, "fam", None), "ROW_STATE", False))
+        # A pool entry no pool has: a read of it clips, a write drops.
+        self._no_entry = llama_mod.STATE_ENTRIES_PER_SLOT * b
         if self._paged:
             # config.validate mirrors these; batchers built directly in
             # tests must hit the same walls.
@@ -442,6 +453,9 @@ class ContinuousBatcher:
             self.pages = PageAllocator(
                 self._n_pages, page, slots=b,
                 table_width=self._table_width,
+                state_entries=(
+                    (llama_mod.STATE_ENTRIES_PER_SLOT - 1) * b
+                    if self._row_state else 0),
             )
             self._tables_dirty = False
             self.cache = engine.make_paged_cache(
@@ -482,6 +496,8 @@ class ContinuousBatcher:
             self.pages = None
             self.host_pool = None
             self.cache = engine.make_cache(b, s_max)
+        if self._row_state:
+            self._say_row_states()
         # Host-mirrored per-slot state, pushed to device each tick.
         # cur_tokens additionally keeps a DEVICE-resident twin
         # (_cur_dev): the tick feeds on the previous tick's last-step
@@ -689,6 +705,9 @@ class ContinuousBatcher:
         # activation loops mark and annotate where the work happens).
         self._adm_timer = PhaseTimer()
         self._adm_span: dict = {}
+        # slot -> (restore source entry, captures) of the rows of the
+        # admission round in progress (_state_plan).
+        self._state_rows: dict = {}
 
         # jitted: one decode tick for the whole slot pool (params ride
         # as an argument — a closed-over weight tree would be lowered
@@ -774,7 +793,8 @@ class ContinuousBatcher:
         engine.ledger.register(
             "kv_arena",
             lambda: (
-                *llama_mod.cache_planes(self.cache), self.cache.length),
+                *llama_mod.cache_planes(self.cache), self.cache.length,
+                *self.cache.state),
             scope=ledger_scope,
         )
         engine.ledger.register(
@@ -918,6 +938,72 @@ class ContinuousBatcher:
 
         length = cache.length.at[slots].set(true_len, mode="drop")
         return llama_mod.map_planes(put, cache, mini, length=length)
+
+    # -- row state beside pages (a ROW_STATE family) -------------------------
+
+    def _state_io(self, placed: list, r: int):
+        """What an admission program of `r` rows needs to carry its
+        rows' state, as device arrays (`sio`), None for a family
+        without one (its programs then have the arguments they always
+        had). `placed`: (row index, slot) of the real rows, whose
+        restore source and captures `_state_plan` noted (a row it did
+        not see starts from zeros and captures nothing). (src [r]: the
+        entry each row's state is restored from, -1 = zeros; every
+        [r, G]: entry for the state at absolute position (g + 1) x
+        prefill_chunk; at [r] + at_dst [r]: one more position, a page
+        boundary.) An entry out of range captures nothing."""
+        if not self._row_state:
+            return None
+        every = self.cfg.prefill_chunk
+        src = np.full((r,), -1, np.int32)
+        grid = np.full((r, self._grid_chunks), self._no_entry, np.int32)
+        at = np.full((r,), -1, np.int32)
+        at_dst = np.full((r,), self._no_entry, np.int32)
+        for j, sl in placed:
+            src[j], captures = self._state_rows.get(sl, (-1, ()))
+            for pos, entry in captures:
+                if pos % every == 0:
+                    grid[j, pos // every - 1] = entry
+                else:
+                    at[j], at_dst[j] = pos, entry
+        return tuple(jnp.asarray(x) for x in (src, grid, at, at_dst))
+
+    def _state_enter(self, cache, mini, rows, sio):
+        """`mini` with the shared cache's state pool grafted in: its
+        batch rows read and write the pool entries `rows` (slot
+        indices; out of range: a padding row, dropped), each restored
+        first from the snapshot `sio` names, or zeroed."""
+        if sio is None:
+            return mini
+        return mini._replace(
+            state=self.fam.restore_rows(cache.state, rows, sio[0]),
+            state_rows=rows)
+
+    def _capture_of(self, sio, off, width: int, j=None):
+        """forward's `capture` for one [rows, width] step that starts
+        at absolute position `off`: the multiple of prefill_chunk the
+        step reaches, if it passes one, and the row's page-boundary
+        position (forward takes neither where the step does not pass
+        it). `j`: one row of `sio` alone."""
+        if sio is None:
+            return None
+        _, grid, at, at_dst = sio
+        if j is not None:
+            grid, at, at_dst = (
+                jax.lax.dynamic_slice_in_dim(x, j, 1) for x in (grid, at, at_dst))
+        every = self.cfg.prefill_chunk
+        off = jnp.broadcast_to(off, at.shape).astype(jnp.int32)
+        reach = (off + width) // every * every
+        k = jnp.clip(reach // every - 1, 0, grid.shape[1] - 1)
+        dst = jnp.take_along_axis(grid, k[:, None], axis=1)[:, 0]
+        pos = jnp.where(reach > off, reach, -1)
+        return jnp.stack([pos, at], 1), jnp.stack([dst, at_dst], 1)
+
+    @staticmethod
+    def _state_leave(cache, mini, sio):
+        """The shared cache holding the pool the admission's forward
+        passes left in `mini`."""
+        return cache if sio is None else cache._replace(state=mini.state)
 
     # -- KV page export/import (sidecar→sidecar TransferKV plane) -----------
 
@@ -1196,14 +1282,16 @@ class ContinuousBatcher:
 
     def _prefill_sample(
         self, params, tokens, true_len, seeds, temps, ks, ps, adapters,
-        g0, g_allow, g_trans,
+        g0, g_allow, g_trans, cache=None, rows=None, sio=None,
     ):
         """Shared admission core: prefill the right-padded prompts
         [R, S] against a fresh mini cache, sample each row's first
         token (grammar-masked under each row's admission state `g0`;
-        0 = unconstrained). Returns (first [R], mini cache)."""
+        0 = unconstrained). Returns (first [R], mini cache). `sio`
+        (a ROW_STATE family): the rows' state lives in `cache`'s pool
+        at entries `rows` and leaves in the mini (_state_leave)."""
         r, s = tokens.shape
-        mini = self._make_mini(r, s)
+        mini = self._state_enter(cache, self._make_mini(r, s), rows, sio)
         # Fresh prefill → engine.prefill_forward (handles MoE validity
         # and the sequence-parallel long-chunk path).
         valid = jnp.arange(s)[None, :] < true_len[:, None]
@@ -1211,6 +1299,7 @@ class ContinuousBatcher:
         logits, mini = self.engine.prefill_forward(
             params, tokens, mini, valid=valid, lora_idx=adapters,
             logit_idx=last if self._head_at_index else None,
+            capture=self._capture_of(sio, 0, s),
         )
         if self._head_at_index:  # logits [R, 1, V]: each row's last
             last = jnp.zeros_like(last)
@@ -1221,40 +1310,46 @@ class ContinuousBatcher:
 
     def _admit_single_impl(
         self, params, tokens, true_len, cache, slot, seeds, temps, ks, ps,
-        adapters, g0, g_allow, g_trans,
+        adapters, g0, g_allow, g_trans, sio=None,
     ):
         """Admit ONE request (row shapes [1, S]) into slot `slot`."""
         first, mini = self._prefill_sample(
             params, tokens, true_len, seeds, temps, ks, ps, adapters,
-            g0, g_allow, g_trans,
+            g0, g_allow, g_trans, cache, jnp.reshape(slot, (1,)), sio,
         )
         if self._paged:
-            return first, self._paged_put(
+            cache = self._paged_put(
                 cache, mini, jnp.reshape(slot, (1,)), true_len,
                 jnp.int32(0),
             )
-        return first, _merge_row(cache, mini, slot, true_len[0])
+        else:
+            cache = _merge_row(cache, mini, slot, true_len[0])
+        return first, self._state_leave(cache, mini, sio)
 
     def _admit_full_impl(
         self, params, tokens, true_len, cache, valid, seeds, temps, ks, ps,
-        adapters, g0, g_allow, g_trans,
+        adapters, g0, g_allow, g_trans, sio=None,
     ):
         """Admit a burst in one call: `tokens` is a full [B, S] batch
         with admitted prompts placed at their slots' rows and
         `valid[B]` marking them; other rows keep their cache state (a
         row-select, not a scatter, so no duplicate-index hazards)."""
         s = tokens.shape[1]
+        # A row that admits nothing keeps its state too: its entry is
+        # out of range (reads clip, writes drop).
+        rows = jnp.where(
+            valid, jnp.arange(len(self.slots)), self._no_entry)
         first, mini = self._prefill_sample(
             params, tokens, true_len, seeds, temps, ks, ps, adapters,
-            g0, g_allow, g_trans,
+            g0, g_allow, g_trans, cache, rows, sio,
         )
         if self._paged:
             slots = jnp.where(
                 valid, jnp.arange(len(self.slots)), len(self.slots)
             )
-            return first, self._paged_put(
+            return first, self._state_leave(self._paged_put(
                 cache, mini, slots, true_len, jnp.int32(0)
-            )
+            ), mini, sio)
         sel = valid[None, :, None, None, None]
 
         def select(c, m):
@@ -1263,11 +1358,12 @@ class ContinuousBatcher:
             )
 
         lengths = jnp.where(valid, true_len, cache.length)
-        return first, llama_mod.map_planes(
-            select, cache, mini, length=lengths)
+        return first, self._state_leave(llama_mod.map_planes(
+            select, cache, mini, length=lengths), mini, sio)
 
     def _chunk_step(
-        self, params, chunk, off, true_len, last, mini, fl, adapters
+        self, params, chunk, off, true_len, last, mini, fl, adapters,
+        capture=None,
     ):
         """Extend `mini` by one [B, C] chunk whose first token sits at
         absolute position `off`, and keep in `fl` [B, V] the logits at
@@ -1285,6 +1381,7 @@ class ContinuousBatcher:
             params, chunk, mini, valid=valid, ring=self._ring,
             lora_idx=adapters,
             logit_idx=idx if self._head_at_index else None,
+            capture=capture,
         )
         if self._head_at_index:  # logits [B, 1, V], at idx already
             idx = jnp.zeros_like(idx)
@@ -1295,7 +1392,9 @@ class ContinuousBatcher:
         fl = jnp.where(take[:, None], sel.astype(fl.dtype), fl)
         return mini, fl
 
-    def _chunked_scan(self, params, tokens, true_len, mini, adapters, start):
+    def _chunked_scan(
+        self, params, tokens, true_len, mini, adapters, start, sio=None
+    ):
         """lax.scan over a [B, T, C] chunk grid: each step extends
         `mini` (which must already hold `start` positions per row) by
         one [B, C] chunk and captures the logits at each row's final
@@ -1311,7 +1410,8 @@ class ContinuousBatcher:
             mini, fl = carry
             chunk, off = xs
             return self._chunk_step(
-                params, chunk, off, true_len, last, mini, fl, adapters
+                params, chunk, off, true_len, last, mini, fl, adapters,
+                self._capture_of(sio, off, c),
             ), None
 
         offs = start + jnp.arange(t_steps, dtype=jnp.int32) * c
@@ -1320,7 +1420,10 @@ class ContinuousBatcher:
         )
         return fl, mini
 
-    def _chunked_rows(self, params, tokens, true_len, adapters):
+    def _chunked_rows(
+        self, params, tokens, true_len, adapters, cache=None, slots=None,
+        sio=None,
+    ):
         """A cold [R, T_max, C] admission, a row at a time in the order
         given: row r runs its own ceil(true_len[r] / C) chunks, one
         [1, C] step each against a one-row mini (_chunk_step), and
@@ -1328,47 +1431,58 @@ class ContinuousBatcher:
         constant and shapes no work, a padding row of the bucket
         (true_len 0) runs no chunk. A chunk of one row is already
         compute-bound, so the weights' extra passes hide under its
-        products. Returns (final_logits [R, V] f32, mini [R, max_seq])."""
+        products. Returns (final_logits [R, V] f32, mini [R, max_seq]).
+        `sio` (a ROW_STATE family): the rows' state is carried from
+        chunk to chunk in `cache`'s pool at entries `slots`, which the
+        group's mini holds on return (_state_leave)."""
         r, _, c = tokens.shape
         vocab = self.engine.cfg.vocab_size
 
-        def row(j, mini1):
+        def row(j, mini1, state):
             """Row j's chunks into the fresh one-row `mini1`."""
             n = jax.lax.dynamic_slice_in_dim(true_len, j, 1)
             lora = jax.lax.dynamic_slice_in_dim(adapters, j, 1)
             chunks = jax.lax.dynamic_index_in_dim(
                 tokens, j, keepdims=False)  # [T_max, C]
+            if sio is not None:
+                mini1 = mini1._replace(
+                    state=state,
+                    state_rows=jax.lax.dynamic_slice_in_dim(slots, j, 1))
 
             def step(t, carry):
                 chunk = jax.lax.dynamic_slice_in_dim(chunks, t, 1)
                 return self._chunk_step(
-                    params, chunk, t * c, n, n - 1, *carry, lora)
+                    params, chunk, t * c, n, n - 1, *carry, lora,
+                    self._capture_of(sio, t * c, c, j))
 
             return jax.lax.fori_loop(
                 0, (n[0] + c - 1) // c, step,
                 (mini1, jnp.zeros((1, vocab), jnp.float32)))
 
         if r == 1:  # the row's mini is the group's
-            mini, fl = row(jnp.int32(0), self._make_mini(1, self.max_seq))
+            mini1 = self._state_enter(
+                cache, self._make_mini(1, self.max_seq), slots, sio)
+            mini, fl = row(jnp.int32(0), mini1, mini1.state)
             return fl, mini
 
         def body(j, carry):
             mini, fl = carry
-            mini1, fl1 = row(j, self._make_mini(1, self.max_seq))
+            mini1, fl1 = row(j, self._make_mini(1, self.max_seq), mini.state)
             mini = llama_mod.map_planes(
                 lambda m, m1: jax.lax.dynamic_update_slice_in_dim(
-                    m, m1, j, axis=1), mini, mini1)
+                    m, m1, j, axis=1), mini, mini1, state=mini1.state)
             return mini, jax.lax.dynamic_update_slice_in_dim(
                 fl, fl1, j, axis=0)
 
+        group = self._state_enter(
+            cache, self._make_mini(r, self.max_seq), self._pool_rows(slots), sio)
         mini, fl = jax.lax.fori_loop(
-            0, r, body, (self._make_mini(r, self.max_seq),
-                         jnp.zeros((r, vocab), jnp.float32)))
+            0, r, body, (group, jnp.zeros((r, vocab), jnp.float32)))
         return fl, mini
 
     def _chunked_finish(
         self, cache, mini, slots, true_len, fl, seeds, temps, ks, ps,
-        g0, g_allow, g_trans, start=None,
+        g0, g_allow, g_trans, start=None, sio=None,
     ):
         """Scatter the [R, S_max] admission mini into the shared cache
         at `slots` (padding rows carry an out-of-range slot index and
@@ -1381,20 +1495,21 @@ class ContinuousBatcher:
             fl, seeds, jnp.int32(0), temps, ks, ps, g0, g_allow, g_trans
         )
         if self._paged:
-            return first, self._paged_put(
+            return first, self._state_leave(self._paged_put(
                 cache, mini, slots, true_len,
                 jnp.int32(0) if start is None else start,
-            )
+            ), mini, sio)
 
         def put(c_, m):
             return c_.at[:, slots].set(m.astype(c_.dtype), mode="drop")
 
         lengths = cache.length.at[slots].set(true_len, mode="drop")
-        return first, llama_mod.map_planes(put, cache, mini, length=lengths)
+        return first, self._state_leave(
+            llama_mod.map_planes(put, cache, mini, length=lengths), mini, sio)
 
     def _admit_chunked_impl(
         self, params, tokens, true_len, cache, slots, seeds, temps, ks,
-        ps, adapters, g0, g_allow, g_trans,
+        ps, adapters, g0, g_allow, g_trans, sio=None,
     ):
         """Fused chunked admission (nothing reused): every row's own
         chunks of the [R, T_max, C] grid (_chunked_rows) + merge +
@@ -1403,16 +1518,17 @@ class ContinuousBatcher:
         group size — per-row work here is the heavy case (long
         prompts), so a trickle admission must not pay the full slot
         pool's compute."""
-        fl, mini = self._chunked_rows(params, tokens, true_len, adapters)
+        fl, mini = self._chunked_rows(
+            params, tokens, true_len, adapters, cache, slots, sio)
         return self._chunked_finish(
             cache, mini, slots, true_len, fl, seeds, temps, ks, ps,
-            g0, g_allow, g_trans,
+            g0, g_allow, g_trans, sio=sio,
         )
 
     def _admit_paged_pfx_impl(
         self, params, tokens, true_len, cache, slots, gtables,
         scan_start, merge_start, seeds, temps, ks, ps, adapters,
-        g0, g_allow, g_trans,
+        g0, g_allow, g_trans, sio=None,
     ):
         """Fused paged prefix-reuse admission: gather each row's shared
         prefix into a full-width mini VIEW through the host-built
@@ -1431,12 +1547,13 @@ class ContinuousBatcher:
                 None, None, jnp.broadcast_to(scan_start, (r,)).astype(jnp.int32)),
             [llama_mod.paged_view_layers(plane, gtables, self._arena_by_layer)
              for plane in llama_mod.cache_planes(cache)])
+        mini = self._state_enter(cache, mini, self._pool_rows(slots), sio)
         fl, mini = self._chunked_scan(
-            params, tokens, true_len, mini, adapters, scan_start
+            params, tokens, true_len, mini, adapters, scan_start, sio
         )
         return self._chunked_finish(
             cache, mini, slots, true_len, fl, seeds, temps, ks, ps,
-            g0, g_allow, g_trans, start=merge_start,
+            g0, g_allow, g_trans, start=merge_start, sio=sio,
         )
 
     def _decode_scan(
@@ -1768,6 +1885,7 @@ class ContinuousBatcher:
             self.cache, jnp.int32(0), jnp.asarray(zseed1),
             jnp.asarray(zf1), jnp.asarray(zi1), jnp.asarray(of1),
             jnp.asarray(zi1), jnp.asarray(zi1), g_allow, g_trans,
+            self._state_io([], 1),
         )
         _, self.cache = self._admit_full(
             self.engine.params, jnp.asarray(np.zeros((b, s), np.int32)),
@@ -1778,7 +1896,7 @@ class ContinuousBatcher:
             jnp.asarray(np.zeros((b,), np.int32)),
             jnp.asarray(np.ones((b,), np.float32)),
             jnp.asarray(np.zeros((b,), np.int32)),
-            jnp.asarray(zgb), g_allow, g_trans,
+            jnp.asarray(zgb), g_allow, g_trans, self._state_io([], b),
         )
         # Token/grammar-state feedback rides the tick as the COMMITTED
         # device twin (_snap_dev) at real dispatch — warmup must
@@ -1856,6 +1974,7 @@ class ContinuousBatcher:
                     jnp.asarray(ofb[:r_bucket]),
                     jnp.asarray(zib[:r_bucket]),
                     jnp.asarray(zib[:r_bucket]), g_allow, g_trans,
+                    self._state_io([], r_bucket),
                 )
         if self._ilv_k and (
             self.cfg.prefill_chunk < self._fit_limit or self._ring
@@ -1944,6 +2063,7 @@ class ContinuousBatcher:
                         jnp.asarray(ofb[:r_rows]),
                         jnp.asarray(zib[:r_rows]),
                         jnp.asarray(zib[:r_rows]), g_allow, g_trans,
+                        self._state_io([], r_rows),
                     )
                 width *= 2
         jax.block_until_ready(self.cache.k)
@@ -2254,14 +2374,36 @@ class ContinuousBatcher:
         finally:
             request.cancelled = True
 
+    def _say_row_states(self) -> None:
+        """What the pool really holds, leaf by leaf off the device
+        arrays, once at start-up: the benchmark's check holds the bytes
+        an entry to the precisions its configuration states."""
+        state = tuple(self.cache.state)
+        entries = state[0].shape[1]
+        logger.info(
+            "row states: %d entries x %d B an entry (%s)", entries,
+            sum(leaf.nbytes for leaf in state) // entries,
+            ", ".join(f"{leaf.dtype} {list(leaf.shape)}" for leaf in state))
+
+    def _pool_rows(self, slots):
+        """The state pool's entries of an admission group's rows (traced
+        inside the program): a real row's is its slot's; a bucket's
+        padding row carries slot index B, out of range for the slots
+        but the FIRST SNAPSHOT'S entry of the pool, which its zeroed
+        restore would overwrite: such a row gets an entry no pool has
+        (reads clip, writes drop). A lone row is never padding."""
+        if slots.shape[0] == 1:
+            return slots
+        return jnp.where(slots < len(self.slots), slots, self._no_entry)
+
     def cache_bytes(self) -> int:
         """KV-cache HBM: the shared slot pool (or paged arena + block
-        tables) and the interleave mini cache (K admission rows) once
-        allocated."""
+        tables), the rows' state pool where the family has one, and the
+        interleave mini cache (K admission rows) once allocated."""
         def planes(cache):
             return sum(p.nbytes for p in llama_mod.cache_planes(cache))
 
-        total = planes(self.cache)
+        total = planes(self.cache) + sum(p.nbytes for p in self.cache.state)
         if self._paged:
             total += self.cache.table.nbytes
         if self._ilv_mini is not None:
@@ -3213,7 +3355,12 @@ class ContinuousBatcher:
                     return
             self._adm_families, self._adm_reused = [], 0
             self._adm_chunk_run = 0
-            queued, shed_rows = self._route_admission(slots_idx, batch)
+            try:
+                queued, shed_rows = self._route_admission(slots_idx, batch)
+            finally:
+                if self._row_state and self._paged:
+                    self.pages.release_snapshot_pins()
+                self._state_rows.clear()
         # What is left after the last activation loop is the way out; a
         # round that launched nothing (every row queued for tick-fused
         # chunks, or shed) was building all along.
@@ -3245,6 +3392,23 @@ class ContinuousBatcher:
             self._admit_ema_ms = (
                 0.7 * self._admit_ema_ms + 0.3 * dt / prefilled
             )
+
+    def _state_plan(self, sl: int, req: _Request, adm) -> None:
+        """A ROW_STATE family's row, its block table just built: note
+        which snapshot its admission program restores from and reserve
+        the pool entries for the states it will capture; `_state_io`
+        reads both when the row's program is built. The `no snapshot
+        state` fault (failpoint state_restore_zero, the benchmark's
+        control) leaves the slot's state zero where one was due."""
+        src = adm.state_src
+        if src >= 0:
+            try:
+                failpoints.evaluate("state_restore_zero")
+            except failpoints.FailpointError:
+                src = -1
+        self._state_rows[sl] = (src, self.pages.plan_snapshots(
+            sl, req.prompt, adm.scan_start, self.cfg.prefill_chunk,
+            adapter=req.adapter_key))
 
     def _admission_ran(self, family: str, reused_tokens: int = 0) -> None:
         """An admission path notes the program family it dispatched
@@ -3399,6 +3563,8 @@ class ContinuousBatcher:
                     )
                     continue
                 self._tables_dirty = True
+                if self._row_state:
+                    self._state_plan(sl, req, adm)
                 if adm.scan_start > 0:
                     self.prefix_hits += 1
                     suffix = len(req.prompt) - adm.scan_start
@@ -3515,13 +3681,14 @@ class ContinuousBatcher:
             g0s[j] = self._g0(req)
         self._admission_ran("chunked")
         g_allow, g_trans = self._grammar_tables()
+        sio = self._state_io([(j, sl) for j, (sl, _) in enumerate(rows)], r)
         first = self._admission_program(
             lambda: self._admit_chunked(
                 self.engine.params, jnp.asarray(tokens),
                 jnp.asarray(true_len), self.cache, jnp.asarray(slots_arr),
                 jnp.asarray(seeds), jnp.asarray(temps), jnp.asarray(ks),
                 jnp.asarray(ps), jnp.asarray(adapters),
-                jnp.asarray(g0s), g_allow, g_trans,
+                jnp.asarray(g0s), g_allow, g_trans, sio,
             ),
             "chunked", rows=len(rows),
             chunks=int((-(-true_len // c)).sum()),
@@ -3571,6 +3738,8 @@ class ContinuousBatcher:
             g0s[j] = self._g0(req)
         self._admission_ran("paged_pfx", scan_start * len(rows))
         g_allow, g_trans = self._grammar_tables()
+        sio = self._state_io(
+            [(j, sl) for j, (sl, _, _) in enumerate(rows)], r)
         first = self._admission_program(
             lambda: self._admit_paged_pfx(
                 self.engine.params, jnp.asarray(tokens),
@@ -3579,6 +3748,7 @@ class ContinuousBatcher:
                 jnp.int32(merge_start), jnp.asarray(seeds),
                 jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(ps),
                 jnp.asarray(adapters), jnp.asarray(g0s), g_allow, g_trans,
+                sio,
             ),
             "paged_pfx", rows=len(rows), chunks=r * t_steps,
             tokens=int(true_len.sum()) - scan_start * len(rows),
@@ -3629,6 +3799,8 @@ class ContinuousBatcher:
         family = "single" if single else "full"
         self._admission_ran(family)
         g_allow, g_trans = self._grammar_tables()
+        sio = self._state_io(
+            [(row_of(j), sl) for j, sl in enumerate(slots_idx)], rows)
         program = self._admit_single if single else self._admit_full
         first = self._admission_program(
             lambda: program(
@@ -3639,7 +3811,7 @@ class ContinuousBatcher:
                 jnp.int32(slots_idx[0]) if single else jnp.asarray(valid),
                 jnp.asarray(seeds), jnp.asarray(temps), jnp.asarray(ks),
                 jnp.asarray(ps), jnp.asarray(adapters),
-                jnp.asarray(g0s), g_allow, g_trans,
+                jnp.asarray(g0s), g_allow, g_trans, sio,
             ),
             family, rows=len(batch), chunks=rows,
             tokens=int(true_len.sum()), width=s,

@@ -145,6 +145,14 @@ _MESH_WITH_EXPERT_SHARE = (
     "a mesh of more than one device, for a model that holds a "
     "share of its experts"
 )
+_MESH_WITH_ROW_STATE = (
+    "a mesh of more than one device, for a model with state-space layers"
+)
+_STATE_NOT_IN_PAGES = (
+    "a row's recurrent state lives in the device's state pool beside "
+    "the pages and only pages travel: a state through the host tier "
+    "or over TransferKV is not built"
+)
 _UNSUPPORTED = {
     "llama": {"kv_cache_dtype fp8": _FP8_CACHE},
     "moe": {
@@ -203,6 +211,39 @@ _UNSUPPORTED = {
             "built for a sharded cache"
         ),
     },
+    "jamba": {
+        "lora": "the adapter delta sits on the dense family's fused qkv",
+        "pipeline-parallel serving (mesh.stage > 1)": (
+            "the staged forward runs one homogeneous layer stack; this "
+            "family's is runs of state-space layers around attention "
+            "layers"
+        ),
+        "kv_ring": "the model has no sliding window",
+        "batching.kv_tiers": (
+            "a tier's batcher holds a pool of row states of its own and "
+            "a request does not move between tiers with its state"
+        ),
+        "batching.paged_kv_host_bytes (the host tier)": _STATE_NOT_IN_PAGES,
+        "a non-mixed serving.role (KV export/import)": _STATE_NOT_IN_PAGES,
+        "quantize / synthetic_weights": (
+            "the weights are served in bf16; int8 matmuls are wired "
+            "into the dense family's projections only"
+        ),
+        "kv_cache_dtype (other than the model's)": (
+            "the two attention layers' pages are served in bf16 and the "
+            "recurrence's state in float32; no lower precision of either "
+            "has been held to the reference"
+        ),
+        "batching.prefill_interleave": (
+            "a chunk that rides a tick would have to carry the "
+            "admitting row's state between ticks; only the admission "
+            "programs do"
+        ),
+        _MESH_WITH_ROW_STATE: (
+            "the state pool and the selective scan are whole on one "
+            "chip; a state-space layer on a mesh is not built"
+        ),
+    },
 }
 
 # (config key, `_UNSUPPORTED` feature): refused where the key is set
@@ -210,13 +251,15 @@ _UNSUPPORTED = {
 _MESH_REFUSALS = (
     ("index_topk", _MESH_WITH_INDEXER),
     ("experts_held", _MESH_WITH_EXPERT_SHARE),
+    ("row_state", _MESH_WITH_ROW_STATE),
 )
 
 
 class GenerationEngine:
-    """Decoder-family generation (dense Llama or sparse MoE): prefill +
-    decode + fused generate. The family module supplies init_params /
-    param_specs / forward / cache_specs with a shared contract."""
+    """Decoder-family generation (any family of `models/__init__.py`
+    but the embedding one): prefill + decode + fused generate. The
+    family module supplies init_params / param_specs / forward /
+    cache_specs with a shared contract."""
 
     def __init__(
         self,
@@ -752,6 +795,10 @@ class GenerationEngine:
             "quantize / synthetic_weights": bool(sv.quantize)
             or bool(sv.synthetic_weights),
             "kv_cache_dtype fp8": sv.kv_cache_dtype == "fp8",
+            "kv_cache_dtype (other than the model's)": bool(
+                sv.kv_cache_dtype),
+            "batching.prefill_interleave": getattr(
+                bt, "prefill_interleave", "off") == "on",
         }
         for feature in refused:
             if asked.get(feature):
@@ -770,7 +817,7 @@ class GenerationEngine:
             )
 
     def prefill_forward(self, params, tokens, cache, valid=None,
-                        lora_idx=None, logit_idx=None):
+                        lora_idx=None, logit_idx=None, capture=None):
         """fam.forward for FRESH prefill (cache written from offset 0 —
         the attn_impl contract, models/llama.py::attention_block).
         Dispatches to the sequence-parallel path when configured and
@@ -794,7 +841,7 @@ class GenerationEngine:
             )
         return self.decode_forward(
             params, tokens, cache, valid=valid, lora_idx=lora_idx,
-            logit_idx=logit_idx,
+            logit_idx=logit_idx, capture=capture,
         )
 
     def _init_pp_serving(self) -> None:
@@ -822,7 +869,7 @@ class GenerationEngine:
 
     def decode_forward(
         self, params, tokens, cache, valid=None, ring=False, lora_idx=None,
-        logit_idx=None, with_stats=False,
+        logit_idx=None, with_stats=False, capture=None,
     ):
         """fam.forward for decode/extension steps (cache already has
         history). Dispatches to the staged path under PP. `ring` is
@@ -835,16 +882,20 @@ class GenerationEngine:
         (models/mla_moe.py::forward); callers pass them to that family
         only, which takes no `ring` and no `lora_idx` (_check_family
         refuses both). Every family hears the engine's word on
-        attention kernels for its mesh (`use_flash`, `flash_mesh`)."""
+        attention kernels for its mesh (`use_flash`, `flash_mesh`).
+        `capture`: which row states a ROW_STATE family's step copies
+        into the pool as it passes them (models/jamba.py::forward)."""
         if self.pp_serving:
             return self._pp.pipeline_forward_cached(
                 params, self.cfg, tokens, cache, self.mesh, ring=ring
             )
-        if getattr(self.fam, "HEAD_AT_INDEX", False):  # mla_moe, keye
+        if getattr(self.fam, "HEAD_AT_INDEX", False):  # mla_moe, keye, jamba
+            more = {} if capture is None else {"capture": capture}
             return self.fam.forward(
                 params, self.cfg, tokens, cache, valid=valid,
                 logit_idx=logit_idx, with_stats=with_stats,
                 use_flash=self.use_flash, flash_mesh=self.flash_mesh,
+                **more,
             )
         if self.fam is moe_mod:
             return self.fam.forward(
@@ -1034,7 +1085,7 @@ class GenerationEngine:
     def make_cache(self, batch: int, max_len: int) -> llama_mod.KVCache:
         """Mesh-sharded KV cache in the model's geometry (PP-aware)."""
         cfg = self.cfg
-        lead = (cfg.num_layers, batch, max_len)
+        lead = (cfg.cache_layers, batch, max_len)
         specs = (
             self._pp.cache_specs_pp() if self.pp_serving
             else self.fam.cache_specs()
@@ -1089,7 +1140,7 @@ class GenerationEngine:
                 "serving (the staged forward has no block-table path)"
             )
         self._refuse("batching.paged_kv")  # asked for by the batcher
-        lead = (self.cfg.num_layers, n_pages, page_size)
+        lead = (self.cfg.cache_layers, n_pages, page_size)
         raw = self.fam.paged_cache_specs()
         observe = partial(self._observe_cache_spec, "paged_kv_arena")
 

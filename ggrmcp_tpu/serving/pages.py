@@ -120,6 +120,11 @@ class PageAdmission:
     # are re-indexed at refcount > 0, so from here on they are
     # ordinary shared device pages — the proven sharing path.
     pages_restored: int = 0
+    # State beside pages (a family whose rows keep a recurrent state):
+    # the pool entry holding the state at `scan_start`, which the
+    # admission program copies into the slot's entry; -1: none, the row
+    # starts from zeros at position 0.
+    state_src: int = -1
 
 
 class PageAllocator:
@@ -127,7 +132,7 @@ class PageAllocator:
     batcher's paged KV arena."""
 
     def __init__(self, n_pages: int, page_size: int, slots: int,
-                 table_width: int):
+                 table_width: int, state_entries: int = 0):
         if n_pages < 1 or page_size < 1:
             raise ValueError("n_pages and page_size must be >= 1")
         self.n_pages = n_pages
@@ -174,6 +179,36 @@ class PageAllocator:
         self.host_bytes_demoted = 0
         self.host_bytes_restored = 0
         self.host_restore_failures = 0
+        # State beside pages (docs/paged_kv.md): where a row also keeps
+        # a state that no position addresses, a prefix hit is worth
+        # only as far as a SNAPSHOT of that state exists. Snapshots are
+        # entries slots .. slots + state_entries - 1 of the device's
+        # state pool (entry s < slots is slot s's own), each hung on
+        # the chain key of the page it follows: `_snap_of[key]` is the
+        # state after that page's last token. 0 entries: no such
+        # state, and nothing below ever runs.
+        self.state_entries = state_entries
+        self._snap_free: list[int] = list(
+            range(slots, slots + state_entries))
+        self._snap_of: dict[int, int] = {}  # chain key -> entry
+        self._snap_key: dict[int, int] = {}  # entry -> chain key
+        self._snap_stamp: dict[int, int] = {}  # entry -> LRU stamp
+        # Entries an admission of the round in flight restores from:
+        # not evictable until the round ends (release_snapshot_pins).
+        self._snap_pinned: set[int] = set()
+        # slot -> [(chain key, entry)] captured by the slot's admission
+        # program, hung once its pages are indexed (register).
+        self._snap_plan: dict[int, list[tuple[int, int]]] = {}
+        # slot -> (prompt array, root, chain keys of its first pages):
+        # admit, plan_snapshots and register walk the same prompt, and
+        # hash it once between them (_walk_keys).
+        self._walk: dict[int, tuple[np.ndarray, int, list[int]]] = {}
+        self.snap_taken = 0
+        self.snap_lookups = 0
+        self.snap_hits = 0
+        self.snap_evictions = 0
+        self.state_tokens_matched = 0
+        self.state_tokens_recomputed = 0
 
     # -- stats ---------------------------------------------------------------
 
@@ -209,6 +244,16 @@ class PageAllocator:
             "kv_host_bytes_demoted": self.host_bytes_demoted,
             "kv_host_bytes_restored": self.host_bytes_restored,
             "kv_host_restore_failures": self.host_restore_failures,
+            # State beside pages: all 0 for a family without one.
+            "state_snapshots_taken": self.snap_taken,
+            "state_snapshot_lookups": self.snap_lookups,
+            "state_snapshot_hits": self.snap_hits,
+            "state_snapshot_evictions": self.snap_evictions,
+            "state_tokens_matched": self.state_tokens_matched,
+            "state_tokens_recomputed": self.state_tokens_recomputed,
+            "state_pool_in_use": len(self._snap_key) + sum(
+                len(plan) for plan in self._snap_plan.values()),
+            "state_pool_total": self.state_entries,
             **(
                 self.host.stats() if self.host is not None else {
                     "kv_host_entries": 0, "kv_host_bytes_used": 0,
@@ -264,18 +309,20 @@ class PageAllocator:
         return cow_page, cow_t
 
     def _lookup(
-        self, arr: np.ndarray, limit: int, root: int = _ROOT
+        self, arr: np.ndarray, limit: int, root: int = _ROOT,
+        keys: Optional[list] = None,
     ) -> tuple[list, int, int, int]:
         """Longest page-aligned indexed prefix of arr[:limit] plus the
         best partially matching divergent page, walking from `root`
-        (the adapter's key domain). Returns (shared pages, chain key at
+        (the adapter's key domain; `keys`: the pages' chain keys where
+        the caller has hashed them). Returns (shared pages, chain key at
         the divergence, cow_page or -1, cow_overlap)."""
         p = self.page_size
         key = root
         pages: list[int] = []
         for j in range(limit // p):
             toks = arr[j * p:(j + 1) * p]
-            nxt = self._chain(key, toks)
+            nxt = self._chain(key, toks) if keys is None else keys[j]
             page = self._index.get(nxt)
             if page is None or not np.array_equal(self._tokens_of[page], toks):
                 break  # hash collision verifies as a miss
@@ -290,6 +337,8 @@ class PageAllocator:
     def _unindex(self, page: int) -> None:
         key = self._key_of.pop(page)
         self._index.pop(key, None)
+        if key in self._snap_of:  # a snapshot goes with its page
+            self._snap_drop(self._snap_of[key])
         self._children.pop(key, None)  # orphan subtree: verification
         # against _tokens_of keeps any dangling child unreachable, and
         # those children are themselves evictable entries.
@@ -411,12 +460,22 @@ class PageAllocator:
         # At least one suffix token must run through the model to
         # produce sampling logits — cap reuse at len(prompt) - 1.
         limit = len(prompt) - 1
+        keys = self._walk_keys(slot, arr, root, limit // p) if (
+            self.state_entries and share) else None
         if share:
             shared, break_key, cow_page, cow_t = self._lookup(
-                arr, limit, root
+                arr, limit, root, keys
             )
         else:
             shared, break_key, cow_page, cow_t = [], root, -1, 0
+        state_src = -1
+        if keys is not None:
+            # The deepest matched page that HAS a snapshot: the pages
+            # past it are not reused (their tokens run again, into
+            # pages of the slot's own), and no divergent page is
+            # copied: a state exists at page boundaries only.
+            shared, state_src = self._clip_to_snapshot(keys, shared)
+            cow_page, cow_t = -1, 0
         m = len(shared)
         # Host-tier extension (attach_host): continue the chain walk
         # past the device break — orphaned device pages re-link free,
@@ -518,7 +577,127 @@ class PageAllocator:
             gather_row=gather,
             pages_shared=t,
             pages_restored=n_host,
+            state_src=state_src,
         )
+
+    # -- state beside pages ---------------------------------------------------
+
+    def _walk_keys(
+        self, slot: int, arr: np.ndarray, root: int, n_pages: int
+    ) -> list[int]:
+        """Chain keys of the first `n_pages` full pages of `arr`, one
+        hash a page a slot's admission: admit, plan_snapshots and
+        register ask for the same prompt's in turn, and what the slot's
+        last walk hashed of these very tokens is kept (a preempted row
+        registers a longer prompt: the walk goes on from where it
+        stood)."""
+        p = self.page_size
+        keys: list[int] = []
+        memo = self._walk.get(slot)
+        if memo is not None and memo[1] == root:
+            n = min(len(memo[2]), n_pages)
+            if np.array_equal(memo[0][:n * p], arr[:n * p]):
+                if n == n_pages:
+                    return memo[2][:n]
+                keys = memo[2]
+        key = keys[-1] if keys else root
+        for j in range(len(keys), n_pages):
+            key = self._chain(key, arr[j * p:(j + 1) * p])
+            keys.append(key)
+        self._walk[slot] = (arr, root, keys)
+        return keys
+
+    def _clip_to_snapshot(
+        self, keys: list, shared: list
+    ) -> tuple[list, int]:
+        """`shared` (pages on the chain `keys`) cut back to the deepest
+        matched page whose chain key holds a snapshot, and that
+        snapshot's entry (pinned for the round, stamped as used);
+        ([], -1) where none has one."""
+        self.snap_lookups += 1
+        self.state_tokens_matched += len(shared) * self.page_size
+        for j in range(len(shared), 0, -1):
+            entry = self._snap_of.get(keys[j - 1])
+            if entry is not None:
+                self.snap_hits += 1
+                self.state_tokens_recomputed += (
+                    (len(shared) - j) * self.page_size)
+                self._snap_pinned.add(entry)
+                self._clock += 1
+                self._snap_stamp[entry] = self._clock
+                return shared[:j], entry
+        self.state_tokens_recomputed += len(shared) * self.page_size
+        return [], -1
+
+    def _snap_drop(self, entry: int) -> None:
+        key = self._snap_key.pop(entry)
+        del self._snap_of[key]
+        self._snap_stamp.pop(entry, None)
+        self._snap_free.append(entry)
+
+    def plan_snapshots(
+        self, slot: int, prompt: list, start: int, every: int,
+        adapter: str = "",
+    ) -> list[tuple[int, int]]:
+        """Which states the admission program of `slot` captures as it
+        passes them, computing `prompt` from position `start`: (a)
+        every absolute multiple of `every` (where prompts that share a
+        prefix branch) and (b) the prompt's deepest page boundary under
+        admit()'s reuse cap (where the session's next turn will match
+        to), each past `start`, at most `len(prompt) - 1`, and not
+        already held. Returns [(position, entry)]; an entry is taken
+        from the free ones, else from the least recently used snapshot
+        that no admission of this round reads, else the position is
+        left out. Nothing is indexed until `register` has run for the
+        slot (its rule: nothing of a failed admission is indexed)."""
+        if not self.state_entries:
+            return []
+        p = self.page_size
+        limit = len(prompt) - 1
+        wanted = set(range(
+            (start // every + 1) * every, limit + 1, every))
+        wanted.add(limit // p * p)
+        wanted = sorted(pos for pos in wanted if pos > start)
+        if not wanted:
+            return []
+        keys = self._walk_keys(
+            slot, np.asarray(prompt, np.int32), adapter_root(adapter),
+            wanted[-1] // p)
+        plan, out = [], []
+        for pos in wanted:
+            key = keys[pos // p - 1]
+            if key in self._snap_of:
+                continue
+            if not self._snap_free:
+                idle = [e for e in self._snap_stamp
+                        if e not in self._snap_pinned]
+                if not idle:
+                    continue
+                self._snap_drop(min(idle, key=self._snap_stamp.__getitem__))
+                self.snap_evictions += 1
+            entry = self._snap_free.pop()
+            plan.append((key, entry))
+            out.append((pos, entry))
+        self._snap_plan[slot] = plan
+        return out
+
+    def _hang_snapshots(self, slot: int) -> None:
+        """Hang the slot's captured states on their chain keys, each
+        where the key's page is indexed and holds no snapshot yet."""
+        for key, entry in self._snap_plan.pop(slot, ()):
+            if key in self._index and key not in self._snap_of:
+                self._snap_of[key] = entry
+                self._snap_key[entry] = key
+                self._clock += 1
+                self._snap_stamp[entry] = self._clock
+                self.snap_taken += 1
+            else:
+                self._snap_free.append(entry)
+
+    def release_snapshot_pins(self) -> None:
+        """The admission round has been dispatched: what it restores
+        from is read in device order before any later capture."""
+        self._snap_pinned.clear()
 
     def _extend_lookup(
         self, arr: np.ndarray, limit: int, m: int, key: int
@@ -697,9 +876,11 @@ class PageAllocator:
         p = self.page_size
         arr = np.asarray(prompt, np.int32)
         key = adapter_root(adapter)
+        keys = self._walk_keys(slot, arr, key, len(prompt) // p) if (
+            self.state_entries) else None
         for j in range(len(prompt) // p):
             toks = arr[j * p:(j + 1) * p]
-            nxt = self._chain(key, toks)
+            nxt = self._chain(key, toks) if keys is None else keys[j]
             page = self._index.get(nxt)
             if page is None:
                 page = int(self.tables[slot, j])
@@ -711,6 +892,8 @@ class PageAllocator:
                 self._parent_of[page] = key
                 self._children.setdefault(key, set()).add(page)
             key = nxt
+        if slot in self._snap_plan:
+            self._hang_snapshots(slot)
 
     def free_slot(self, slot: int, discard_index: bool = False) -> None:
         """Release a slot's page references. Exclusive un-indexed pages
@@ -722,6 +905,9 @@ class PageAllocator:
         index and frees instead of caching garbage (a still-referenced
         indexed page is kept: any surviving sharer was admitted by a
         call that already materialized its content)."""
+        for _key, entry in self._snap_plan.pop(slot, ()):
+            self._snap_free.append(entry)  # captured, never indexed
+        self._walk.pop(slot, None)
         row = self.tables[slot]
         for mapped in row[row != self.sentinel]:
             page = int(mapped)
@@ -784,6 +970,11 @@ class PageAllocator:
         self._parent_of.clear()
         self._children.clear()
         self._stamp.clear()
+        slots = self.tables.shape[0]
+        self._snap_free = list(range(slots, slots + self.state_entries))
+        for book in (self._snap_of, self._snap_key, self._snap_stamp,
+                     self._snap_pinned, self._snap_plan, self._walk):
+            book.clear()
         # The host pool (if attached) deliberately SURVIVES a reset:
         # its entries are host-RAM/file copies of pages that were valid
         # when demoted — replays restore from it instead of recomputing
@@ -828,3 +1019,20 @@ class PageAllocator:
             f"pages lost: {len(free)} free + {referenced} live + "
             f"{cached} cached != {self.n_pages}"
         )
+        # Snapshots: every entry is free, hung on an indexed key, or
+        # planned by one slot's admission in flight; none twice.
+        planned = [e for plan in self._snap_plan.values() for _, e in plan]
+        entries = self._snap_free + list(self._snap_key) + planned
+        assert len(set(entries)) == len(entries), "snapshot entry held twice"
+        slots = self.tables.shape[0]
+        assert sorted(entries) == list(
+            range(slots, slots + self.state_entries)), "snapshot entries lost"
+        for key, entry in self._snap_of.items():
+            assert self._snap_key.get(entry) == key, (
+                f"snapshot maps disagree for entry {entry}")
+            assert key in self._index, f"snapshot {entry} on an unindexed key"
+            assert entry in self._snap_stamp, f"snapshot {entry} unstamped"
+        assert set(self._snap_stamp) == set(self._snap_key), (
+            "snapshot stamps disagree with the hung entries")
+        assert self._snap_pinned <= set(self._snap_key) | set(
+            self._snap_free) | set(planned), "pinned entry unknown"

@@ -104,7 +104,7 @@ class TestChipSmoke:
         result = json.loads(lines[-1])
         assert result["ok"] is True and result["rehearsal"] is True
         assert result["device"]["platform"] == "cpu"
-        assert "all legs passed: kernel,sparse,keye,serve,default_kv" in proc.stdout
+        assert "all legs passed: kernel,sparse,keye,jamba,serve,default_kv" in proc.stdout
         # The sparse leg held one expert layer of the tiny deepseek_v32
         # member, a sparse chunk and a sparse decode step, to the
         # float32 reference layer, and saw the selection bind.
@@ -113,6 +113,10 @@ class TestChipSmoke:
         # The keye leg the same for one layer of the tiny keye member,
         # and both of its sparse paths ran.
         assert "keye leg ok" in proc.stdout
+        # The jamba leg: the whole tiny hybrid model, a chunk through the
+        # scan and decode steps through the pool, to float32 logits.
+        assert "jamba leg ok" in proc.stdout
+        assert "ssm_scan 1, ssm_step 1" in proc.stdout
         assert "sparse_gqa_chunk 1, sparse_gqa_decode 1" in proc.stdout
         assert proc.stdout.count("sparse chunk (16 of 65..80 keys)") == 3
         assert "paged vs contiguous: first 8 of 8 ids agree" in proc.stdout

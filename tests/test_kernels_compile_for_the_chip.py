@@ -118,12 +118,21 @@ FLASH_CALLS = {
     "1_row_256_on_2048_window_4096": dict(
         rows=1, sq=256, sk=2048, window=4096),
     "1_row_256_fresh": dict(rows=1, sq=256, sk=256, window=None),
+    # the hybrid family's two attention layers: 20 query heads on ONE
+    # KV head (a query group of 20), a 512-token chunk and a 128-token
+    # suffix over the 8,192-position mini cache
+    "1_row_512_on_8192_group_20": dict(
+        rows=1, sq=512, sk=8192, window=None, heads=20, kv_heads=1),
+    "4_rows_128_on_8192_group_20": dict(
+        rows=4, sq=128, sk=8192, window=None, heads=20, kv_heads=1),
 }
 
 
 @pytest.mark.parametrize("case", FLASH_CALLS, ids=list(FLASH_CALLS))
 def test_the_flash_kernel_compiles_for_a_v5e(topo, uncached, case):
-    rows, sq, sk, window = FLASH_CALLS[case].values()
+    call = {"heads": 32, "kv_heads": 8, **FLASH_CALLS[case]}
+    rows, sq, sk, window = (call[k] for k in ("rows", "sq", "sk", "window"))
+    heads, kv_heads = call["heads"], call["kv_heads"]
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def shape(dims, dtype=jnp.bfloat16):
@@ -132,8 +141,8 @@ def test_the_flash_kernel_compiles_for_a_v5e(topo, uncached, case):
     compiled = jax.jit(
         functools.partial(A.flash_attention, causal=True, window=window)
     ).lower(
-        shape((rows, sq, 32, 128)), shape((rows, sk, 8, 128)),
-        shape((rows, sk, 8, 128)),
+        shape((rows, sq, heads, 128)), shape((rows, sk, kv_heads, 128)),
+        shape((rows, sk, kv_heads, 128)),
         q_offset=shape((rows,), jnp.int32), kv_len=shape((rows,), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -192,6 +192,174 @@ class TestPageAllocator:
         assert alloc.shared() == 0
 
 
+class TestStateSnapshots:
+    """State beside pages (docs/paged_kv.md): snapshots of a row's
+    recurrent state hung on page chain keys, host side only. 2 slots,
+    so entries 2..5 of the pool are the snapshots'."""
+
+    def _alloc(self, **kw):
+        kw.setdefault("state_entries", 4)
+        return PageAllocator(32, 4, slots=2, table_width=16, **kw)
+
+    def test_no_state_entries_means_no_snapshot_work(self):
+        alloc = PageAllocator(16, 4, slots=2, table_width=8)
+        p = list(range(11))
+        adm = alloc.admit(0, p, need_len=12)
+        assert adm.state_src == -1
+        assert alloc.plan_snapshots(0, p, 0, every=8) == []
+        alloc.register(0, p)
+        assert alloc.stats()["state_pool_total"] == 0
+        assert alloc.stats()["state_snapshot_lookups"] == 0
+        alloc.check_invariants()
+
+    @pytest.mark.parametrize("state_entries, hashed", [(4, 6), (0, 1 + 6)])
+    def test_an_admission_hashes_each_page_of_its_prompt_once(
+            self, monkeypatch, state_entries, hashed):
+        """admit, plan_snapshots and register walk the same prompt:
+        where rows keep a state the slot's walk is kept between them
+        (24 tokens: 6 pages, 5 under the reuse cap); a family without
+        one walks as it always did (admit to the first miss, register
+        every page), and a later prompt on the slot starts anew."""
+        alloc = self._alloc(state_entries=state_entries)
+        calls = []
+        chain = PageAllocator._chain
+        monkeypatch.setattr(
+            PageAllocator, "_chain",
+            staticmethod(lambda key, toks: calls.append(1) or chain(key, toks)))
+        p = list(range(24))
+        alloc.admit(0, p, need_len=28)
+        alloc.plan_snapshots(0, p, 0, every=8)
+        alloc.register(0, p)
+        assert len(calls) == hashed
+        if not state_entries:
+            return
+        alloc.check_invariants()
+        # the same slot, another prompt that shares 9 tokens: nothing
+        # of the first walk is taken for it
+        alloc.free_slot(0)
+        del calls[:]
+        q = p[:9] + [700 + i for i in range(15)]
+        adm = alloc.admit(0, q, need_len=28)
+        assert adm.scan_start == 8 and len(calls) == 5
+        alloc.register(0, q)
+        assert len(calls) == 6
+        alloc.check_invariants()
+
+    def test_plan_names_the_multiples_and_the_deepest_boundary(self):
+        alloc = self._alloc()
+        p = list(range(23))  # reuse cap 22: boundaries 4 .. 20
+        alloc.admit(0, p, need_len=24)
+        plan = alloc.plan_snapshots(0, p, 0, every=8)
+        # (a) 8 and 16, (b) 22 // 4 x 4 = 20; distinct pool entries
+        assert [pos for pos, _ in plan] == [8, 16, 20]
+        assert sorted(e for _, e in plan) == sorted(set(e for _, e in plan))
+        assert all(2 <= e < 6 for _, e in plan)
+        # nothing is indexed until register has run for the slot
+        assert alloc.stats()["state_snapshots_taken"] == 0
+        assert alloc.stats()["state_pool_in_use"] == 3
+        alloc.check_invariants()
+        alloc.register(0, p)
+        assert alloc.stats()["state_snapshots_taken"] == 3
+        alloc.check_invariants()
+        # from a start past 8, and where 16 and 20 are held: nothing new
+        alloc.admit(1, p, need_len=24)
+        assert alloc.plan_snapshots(1, p, 8, every=8) == []
+
+    def test_lookup_is_clipped_to_the_deepest_page_with_a_snapshot(self):
+        alloc = self._alloc()
+        p = list(range(24))  # 6 full pages; cap 23: snapshots at 8, 16, 20
+        alloc.admit(0, p, need_len=24)
+        alloc.plan_snapshots(0, p, 0, every=8)
+        alloc.register(0, p)
+        # the same prompt again: 5 pages match under the cap (20 tokens)
+        adm = alloc.admit(1, p, need_len=28)
+        assert adm.scan_start == adm.merge_start == 20 and adm.state_src >= 2
+        assert alloc.stats()["state_tokens_recomputed"] == 0
+        alloc.free_slot(1)
+        # a prompt that leaves after 14 tokens: 3 pages match (12), the
+        # deepest state under them is at 8: one page runs again, into
+        # a page of the slot's own, and no divergent page is copied
+        q = p[:14] + [900 + i for i in range(10)]
+        adm = alloc.admit(1, q, need_len=28)
+        assert adm.scan_start == adm.merge_start == 8 and adm.pages_shared == 2
+        assert alloc.tables[1][2] != alloc.tables[0][2]
+        assert alloc.cow_copies == 0
+        st = alloc.stats()
+        assert st["state_snapshot_lookups"] == 3  # the cold one too
+        assert st["state_snapshot_hits"] == 2
+        assert st["state_tokens_matched"] == 20 + 12
+        assert st["state_tokens_recomputed"] == 4
+        assert st["paged_pages_reused"] == 5 + 2
+        alloc.check_invariants()
+
+    def test_pages_without_a_snapshot_are_not_reused_at_all(self):
+        alloc = self._alloc()
+        p = list(range(24))
+        alloc.admit(0, p, need_len=24)
+        alloc.register(0, p)  # pages indexed, no state captured
+        adm = alloc.admit(1, p, need_len=24)
+        assert (adm.scan_start, adm.pages_shared, adm.state_src) == (0, 0, -1)
+        assert alloc.stats()["state_tokens_recomputed"] == 20
+        alloc.check_invariants()
+
+    def test_lru_eviction_spares_what_the_round_reads(self):
+        alloc = self._alloc()
+        a, b = list(range(24)), list(range(100, 124))
+        alloc.admit(0, a, need_len=24)
+        alloc.plan_snapshots(0, a, 0, every=8)  # 8, 16, 20
+        alloc.register(0, a)
+        alloc.free_slot(0)
+        # this round: slot 0 restores a's deepest snapshot (pinned) ...
+        adm = alloc.admit(0, a, need_len=28)
+        pinned = adm.state_src
+        # ... and slot 1's cold prompt wants three entries of four: one
+        # is free, two come from a's least recently used, never the pin
+        alloc.admit(1, b, need_len=24)
+        plan = alloc.plan_snapshots(1, b, 0, every=8)
+        assert len(plan) == 3 and pinned not in [e for _, e in plan]
+        assert alloc.stats()["state_snapshot_evictions"] == 2
+        alloc.check_invariants()
+        alloc.release_snapshot_pins()
+        alloc.register(1, b)
+        alloc.check_invariants()
+        # a's snapshots at 8 and 16 went: a prefix of 16 tokens of it
+        # now matches pages and finds no state
+        alloc.free_slot(1)
+        adm = alloc.admit(1, a[:17], need_len=20)
+        assert (adm.scan_start, adm.state_src) == (0, -1)
+        alloc.check_invariants()
+
+    def test_a_snapshot_goes_with_its_page_and_a_failed_admission_leaves_none(
+            self):
+        alloc = PageAllocator(8, 4, slots=2, table_width=8, state_entries=4)
+        a = list(range(13))
+        alloc.admit(0, a, need_len=16)
+        alloc.plan_snapshots(0, a, 0, every=8)  # 8, 12
+        alloc.register(0, a)
+        alloc.free_slot(0)
+        assert alloc.stats()["state_pool_in_use"] == 2
+        # pressure evicts a's pages, and the snapshots hung on them
+        alloc.admit(1, list(range(200, 230)), need_len=32)
+        assert alloc.stats()["state_pool_in_use"] == 0
+        alloc.check_invariants()
+        alloc.free_slot(1)
+        # a failed admission: planned, eagerly indexed, then discarded
+        alloc.admit(0, a, need_len=16)
+        alloc.plan_snapshots(0, a, 0, every=8)
+        alloc.register(0, a)
+        assert alloc.stats()["state_pool_in_use"] == 2
+        alloc.free_slot(0, discard_index=True)
+        assert alloc.stats()["state_pool_in_use"] == 0
+        alloc.check_invariants()
+        # planned and never registered
+        alloc.admit(0, a, need_len=16)
+        alloc.plan_snapshots(0, a, 0, every=8)
+        alloc.free_slot(0, discard_index=True)
+        assert alloc.stats()["state_pool_in_use"] == 0
+        alloc.reset()
+        alloc.check_invariants()
+
+
 # ---------------------------------------------------------------------------
 # Bit-identity: paged on == paged off == engine.generate
 # ---------------------------------------------------------------------------
