@@ -284,14 +284,21 @@ class BatchingConfig:
     max_decode_steps: int = 512
     prefill_chunk: int = 512
     kv_cache_max_seq: int = 4096
-    # Decode steps fused into one device call (lax.scan): k× fewer
-    # host↔device round-trips per generated token. Streaming chunks and
-    # new-request admission are quantized to this many tokens, and up
-    # to k-1 sampled tokens per request are discarded at EOS/max_new,
-    # so keep it small; 1 = the classic one-call-per-token loop (best
-    # for CPU test meshes, where compute dominates the round-trip).
-    # "auto" = DECODE_STEPS_TPU on TPU devices, 1 elsewhere (resolved
-    # by the batcher against the engine's mesh).
+    # Decode steps fused into one device call (lax.scan): the FULL
+    # length of a tick, the upper bound the cache reserves derive from.
+    # Streaming chunks are quantized to a tick and up to k-1 sampled
+    # tokens per request are discarded at EOS/max_new. Under
+    # pipeline_ticks the host's round trip hides behind the tick in
+    # flight whatever k is; what k costs is admission latency: a new
+    # request waits out the tick in flight and is launched behind the
+    # next one. So the pipelined batcher dispatches a short tick
+    # (short_tick_steps) while a request waits or a slot is free, and
+    # this length with every slot live and nobody waiting, and after
+    # admissions of more than k x prefill_chunk chunk tokens, which
+    # held the decoding rows for longer than k steps (_tick_steps). 1 =
+    # the classic one-call-per-token loop (CPU test meshes; short
+    # equals full there). "auto" = DECODE_STEPS_TPU on TPU devices, 1
+    # elsewhere (resolved by the batcher against the engine's mesh).
     decode_steps_per_tick: "int | str" = "auto"  # "auto" | int >= 1
     # Pipelined decode ticks: dispatch tick N+1 (with device-resident
     # token feedback) BEFORE blocking on tick N's host copy, so the
@@ -399,21 +406,42 @@ class BatchingConfig:
     tick_retry_limit: int = 1
 
 
-# decode_steps_per_tick="auto" resolves to this on TPU meshes: with
-# max_new=16-class agentic calls one tick covers a whole generation,
-# so a call costs ~2 host round-trips (admit + tick) instead of 17.
+# decode_steps_per_tick="auto" resolves to this on TPU meshes: the full
+# tick, dispatched while every slot is live and nobody waits. What the
+# chip showed (PERF.md sections 5 and 6, PRs 39, 49 and 50): on the
+# pipelined loop the host's ~7.5 ms a tick is hidden behind the tick in
+# flight (tick_handoff_share 0.9%, loop_lag_ms_mean 0.47 ms), so a long
+# tick buys no throughput from the round trip; its length is admission
+# latency, two ticks of it between a client's answer and its first
+# token.
 DECODE_STEPS_TPU = 8
 
 
 def resolve_decode_steps(batching: "BatchingConfig", platform: str) -> int:
-    """Resolve decode_steps_per_tick for a device platform ("tpu",
-    "cpu", ...). The "auto" default favors fused multi-step ticks on
-    TPU (host round-trips dominate) and the classic one-step loop on
+    """Resolve decode_steps_per_tick (the full tick) for a device
+    platform ("tpu", "cpu", ...). The "auto" default is the fused
+    multi-step tick on TPU, where the pipelined loop hides the host's
+    turn behind the tick in flight, and the classic one-step loop on
     CPU test meshes (compute dominates; overshoot is pure waste)."""
     steps = batching.decode_steps_per_tick
     if steps == "auto":
         return DECODE_STEPS_TPU if platform == "tpu" else 1
     return max(1, int(steps))
+
+
+def short_tick_steps(full: int) -> int:
+    """The pipelined batcher's short tick, from the full one
+    (batching.py _tick_steps: dispatched while a request waits or a
+    slot is free). A half, never under 1. On the chip (PERF.md section
+    6, PR 50) a quarter, 2 of 8 steps, read better where 8 rows decode
+    (agent-shared call_ms_p50 -9.6% against the half's -7.7%,
+    decode-steady -3.1% against -2.4%) and worse where 32 do and a row
+    is admitted a call: chat-sessions -0.2% against -2.4%, with
+    device_idle_share up 4.5 points against 1: two steps no longer
+    cover an admission round's host turn there. The half held every
+    cell's idle share within 1.3 points of the fixed loop's, and a
+    step's device time within 0.3% where every tick is short."""
+    return max(1, full // 2)
 
 
 @dataclass

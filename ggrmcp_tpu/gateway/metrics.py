@@ -57,6 +57,9 @@ _SERVING_HELP = {
     "prefix_cache_misses": "prefix cache misses",
     "decode_steps": "fused decode steps issued",
     "ticks": "decode ticks dispatched",
+    "short_ticks":
+        "decode ticks dispatched at the short length (a request "
+        "waited or a slot was free)",
     "tick_collects": "decode tick token collects",
     "admit_rounds": "admission rounds run",
     "interleaved_chunks": "prefill chunks fused into decode ticks",
@@ -451,6 +454,7 @@ _TICK_HELP = {
     "jump_tokens":
         "forced tokens emitted by jump-ahead runs on this tick",
     "jump_runs": "jump-ahead forced runs collapsed on this tick",
+    "steps": "decode steps this tick advanced a row by",
 }
 
 

@@ -31,6 +31,7 @@ from ggrmcp_tpu.core.config import (
     BatchingConfig,
     GrammarConfig,
     resolve_decode_steps,
+    short_tick_steps,
 )
 from ggrmcp_tpu.grammar.compiler import CompiledGrammar
 from ggrmcp_tpu.grammar.runtime import GrammarArena, GrammarHandle
@@ -324,16 +325,15 @@ class ContinuousBatcher:
         platform = engine.mesh.devices.flat[0].platform
         self._steps_per_tick = resolve_decode_steps(self.cfg, platform)
         # Pipelined ticks: tick N+1 is dispatched (device-resident token
-        # feedback) before tick N's tokens are pulled to the host, so
-        # the host round-trip overlaps the next tick's compute. A slot
-        # can then overshoot its budget by up to one EXTRA tick before
-        # the host notices EOS/max_new — the cache reserve doubles.
-        # "auto" enables it only when there is a real accelerator to
-        # overlap with: on CPU the lagged tick is pure extra compute.
+        # feedback) before tick N's tokens are pulled to the host, so the
+        # host round-trip overlaps its compute. A slot can then overshoot
+        # its budget by up to one EXTRA tick before the host notices
+        # EOS/max_new: the reserve doubles. "auto": only on an accelerator.
         mode = getattr(self.cfg, "pipeline_ticks", "off")
-        self._pipeline = mode == "on" or (
-            mode == "auto" and platform == "tpu"
-        )
+        self._pipeline = mode == "on" or (mode == "auto" and platform == "tpu")
+        # The short tick (_tick_steps) is the pipelined loop's alone.
+        short = short_tick_steps(self._steps_per_tick)
+        self._short_steps = short if self._pipeline else self._steps_per_tick
         # Jump-ahead constrained decoding (serving.grammar.jump_max,
         # docs/structured_output.md "Jump-ahead"): when a slot's DFA
         # state forces a token run, the tick emits up to jump_max
@@ -612,7 +612,7 @@ class ContinuousBatcher:
         # Tick / collect / admission-round counts. All TIMES come from
         # one clock per tick, the flight recorder's PhaseTimer
         # (phase_ms below; an admission round carries its own timer).
-        self.timing = {"ticks": 0, "collects": 0, "admit_rounds": 0}
+        self.timing = {"ticks": 0, "short_ticks": 0, "collects": 0, "admit_rounds": 0}
         # Decode-stall histogram: wall-clock gaps (ms) between
         # consecutive token emissions to a slot while its request is
         # live — the per-slot observable the prefill-interleave mode
@@ -674,12 +674,12 @@ class ContinuousBatcher:
             )
         # Tick-phase attribution (flight_recorder.PhaseTimer):
         # cumulative per-phase ms over collected ticks (the ServingStats
-        # tick_phase_*_ms scalars; summable across tiers), and the
-        # executor admission time accumulated since the last dispatch —
-        # seeded into the NEXT tick's record as its admit phase, so a
-        # tick window shows the admission work that preceded it.
+        # tick_phase_*_ms scalars; summable across tiers), and two things
+        # kept since the last dispatch: the executor admission time
+        # (seeded into the NEXT tick's record as its admit phase) and the
+        # chunk tokens the admission programs ran (_tick_steps reads it).
         self.phase_ms = dict.fromkeys(PHASE_NAMES, 0.0)
-        self._admit_phase_ms = 0.0
+        self._admit_phase_ms, self._admit_run = 0.0, 0
         # The loop's turn, partitioned with nothing left over
         # (_in_executor): cumulative ms of the four contiguous parts of
         # every executor call — exec_wait (submitted → started on the
@@ -713,7 +713,7 @@ class ContinuousBatcher:
         # as an argument — a closed-over weight tree would be lowered
         # into the module as constants, bloating compiles and defeating
         # the persistent compile cache; see DecoderEngine.__init__)
-        self._tick = jax.jit(self._tick_impl, donate_argnums=(2,))
+        self._tick = jax.jit(self._tick_impl, donate_argnums=(2,), static_argnames="steps")
         # jitted admission — fused prefill + first-token sample + cache
         # merge, ONE device call per admission round. Exactly two row
         # shapes compile per sequence bucket (predictable cold-start):
@@ -1558,9 +1558,9 @@ class ContinuousBatcher:
 
     def _decode_scan(
         self, params, tokens, cache, seeds, step, temps, ks, ps, active,
-        adapters, gstate, g_allow, g_trans,
+        adapters, gstate, g_allow, g_trans, steps=None,
     ):
-        """`decode_steps_per_tick` fused decode steps (lax.scan) — the
+        """`steps` (the full tick's when None) fused decode steps (scan) — the
         shared core of the plain tick and the fused tick+chunk program,
         so interleaved admission cannot perturb decode numerics by
         construction. Each step samples through the grammar mask and
@@ -1588,22 +1588,22 @@ class ContinuousBatcher:
             return (nxt, gs, cache), out
 
         (_, gstate, cache), toks = jax.lax.scan(
-            body, (tokens, gstate, cache), jnp.arange(self._steps_per_tick)
+            body, (tokens, gstate, cache), jnp.arange(steps or self._steps_per_tick)
         )
-        return toks.T, cache, gstate  # [B (+ counts), steps_per_tick], .., [B]
+        return toks.T, cache, gstate  # [B (+ counts), steps], .., [B]
 
     def _tick_impl(
         self, params, tokens, cache, seeds, step, temps, ks, ps, active,
-        adapters, gstate, g_allow, g_trans,
+        adapters, gstate, g_allow, g_trans, steps=None,
     ):
-        """One device call = `decode_steps_per_tick` fused decode steps
-        (lax.scan). Fewer host round-trips per token: tokens sampled
-        after a slot's EOS/max_new are dropped host-side in
-        `_emit_chunk` (the cache rows they touched are masked by
-        `length` on slot reuse)."""
+        """One device call = `steps` fused decode steps (lax.scan; a
+        static argument: the full and the short tick are two instances
+        of this one program). Tokens sampled after a slot's EOS/max_new
+        are dropped host-side in `_emit_chunk` (the cache rows they
+        touched are masked by `length` on slot reuse)."""
         return self._decode_scan(
             params, tokens, cache, seeds, step, temps, ks, ps, active,
-            adapters, gstate, g_allow, g_trans,
+            adapters, gstate, g_allow, g_trans, steps,
         )
 
     def _tick_chunk_impl(
@@ -1899,21 +1899,21 @@ class ContinuousBatcher:
             jnp.asarray(zgb), g_allow, g_trans, self._state_io([], b),
         )
         # Token/grammar-state feedback rides the tick as the COMMITTED
-        # device twin (_snap_dev) at real dispatch — warmup must
-        # compile against the same placement, or the warmed tick
-        # program is a variant serving never calls and the FIRST live
-        # request pays the real compile (the compile watcher caught
-        # exactly this: a post-warmup jit(_tick_impl) on call one).
-        _, self.cache, _ = self._tick(
-            self.engine.params, self._snap_dev(self.cur_tokens),
-            self.cache,
-            jnp.asarray(self.seeds), jnp.int32(0),
-            jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-            jnp.asarray(self.top_ps),
-            jnp.asarray(np.zeros((b,), bool)),
-            jnp.asarray(np.zeros((b,), np.int32)),
-            self._snap_dev(self.gstates), g_allow, g_trans,
-        )
+        # device twin (_snap_dev) at real dispatch: warmup compiles
+        # against the same placement, or the FIRST live request pays a
+        # post-warmup jit(_tick_impl) (the compile watcher caught it).
+        # Both lengths _tick_steps can pick, the full one first.
+        for steps in sorted({self._steps_per_tick, self._short_steps}, reverse=True):
+            _, self.cache, _ = self._tick(
+                self.engine.params, self._snap_dev(self.cur_tokens),
+                self.cache,
+                jnp.asarray(self.seeds), jnp.int32(0),
+                jnp.asarray(self.temps), jnp.asarray(self.top_ks),
+                jnp.asarray(self.top_ps),
+                jnp.asarray(np.zeros((b,), bool)),
+                jnp.asarray(np.zeros((b,), np.int32)),
+                self._snap_dev(self.gstates), g_allow, g_trans, steps=steps,
+            )
         if self._jump_max:
             # The jump tick alternates with the plain tick at
             # dispatch time (jump only while some slot can jump) —
@@ -2410,6 +2410,43 @@ class ContinuousBatcher:
             total += planes(self._ilv_mini)
         return total
 
+    def _tick_steps(self) -> int:
+        """The length of the plain tick about to be dispatched, from
+        what the batcher sees at that moment. Under the pipeline a tick
+        is dispatched before the one in flight is collected, so an
+        admission waits out the tick in flight and is launched behind
+        the next: two tick lengths between a client's answer and its
+        first token (PERF.md section 5). While a request waits for a
+        slot, or a slot is free for the next one to arrive, the tick is
+        short (the same program at a smaller static step count, warmed
+        beside the full one); with every slot live and nobody waiting
+        there is nothing to admit at the next collect, and the full
+        length costs the fewest host turns a token. Without the
+        pipeline the collect follows its own dispatch and an admission
+        waits behind no tick: `_short_steps` is the full length there.
+        The tick after a LONG admission is full as well. An admission
+        round waits the tick in flight off the device and runs its
+        programs to their end, so between two rounds the decoding rows
+        advance by one tick, the one dispatched here. A pass over a
+        chunk of `prefill_chunk` tokens takes a decode step's time at
+        least (the same weights, far more arithmetic), so once the
+        programs since the last dispatch ran more chunk tokens than a
+        full tick's steps times `prefill_chunk`, the rows have stood
+        still for longer than a full tick: they get one. A count the
+        batcher keeps, not a clock: the same arrivals give the same
+        ticks. (Where cold documents of 6-12k tokens follow one another,
+        the kanana cell, short ticks there halved what a decoding row
+        advanced a round and moved the median call up: PERF.md section
+        6, PR 50.) The reserves stay derived from the full length, the
+        upper bound. `pending` is the loop thread's; this reads its
+        emptiness alone (one deque truth test) from the executor thread
+        that owns the slots."""
+        if self._admit_run > self._steps_per_tick * self.cfg.prefill_chunk:
+            return self._steps_per_tick
+        if not self.pending.empty() or self._free_slots():
+            return self._short_steps
+        return self._steps_per_tick
+
     def stall_snapshot(self) -> list[float]:
         """Snapshot of recent decode-stall samples (ms between
         consecutive emissions to a live slot) — the in-process view the
@@ -2616,6 +2653,9 @@ class ContinuousBatcher:
             # Ticks dispatched, ticks collected, admission rounds: the
             # divisors of the phase sums below.
             "ticks": t["ticks"],
+            # Of `ticks`, those dispatched at the short length because
+            # a request waited or a slot was free (_tick_steps).
+            "short_ticks": t["short_ticks"],
             "tick_collects": t["collects"],
             "admit_rounds": t["admit_rounds"],
             # Tick-phase attribution (flight recorder PhaseTimer;
@@ -3446,6 +3486,7 @@ class ContinuousBatcher:
         self._cache_at_risk = True
         run = chunks * width
         self._adm_chunk_run += run
+        self._admit_run += run
         timer.mark("build")
         with tracing.annotation(
             "ggrmcp.admit.program", family=family, rows=rows,
@@ -3855,15 +3896,17 @@ class ContinuousBatcher:
         while len(self._inflight) > depth:
             self._tick_collect_one()
 
-    def _tick_record(self, active):
+    def _tick_record(self, active, steps: int):
         """Open this tick's flight record at dispatch (None when the
         recorder is disabled). seq is 1-based on timing["ticks"], the
-        same counter _activate_slot stamps first_tick from. The record
+        same counter _activate_slot stamps first_tick from; `steps` is
+        the decode steps this dispatch advances a row by. The record
         carries the tick's PhaseTimer — the dispatch paths mark "sync"
         and "dispatch", the collect marks "wait", tick_done settles
         "host" — and is seeded with the executor admission time
         accumulated since the previous dispatch (the admit phase)."""
         admit_ms, self._admit_phase_ms = self._admit_phase_ms, 0.0
+        self._admit_run = 0
         # The sampler's two counters, here because every dispatch path
         # opens its record with the tick's `active` mask in hand.
         self.sampler_order_ticks += bool(np.any(
@@ -3883,6 +3926,7 @@ class ContinuousBatcher:
         return self.recorder.tick_start(
             seq=self.timing["ticks"] + 1,
             active=int(active.sum()),
+            steps=steps,
             interleaved_rows=0,  # chunk dispatchers stamp theirs post-create
             trace_ids=trace_ids,
             shed=self.shed,
@@ -3894,13 +3938,17 @@ class ContinuousBatcher:
         )
 
     def _tick_dispatch(self) -> None:
+        steps = self._tick_steps()
         step0 = self.step_counter
-        self.step_counter += self._steps_per_tick
+        # By the steps dispatched: the counter tags the sampler's draws
+        # (step + i) and is ServingStats decode_steps.
+        self.step_counter += steps
+        self.timing["short_ticks"] += steps < self._steps_per_tick
         active = np.array([s.active for s in self.slots], bool)
         # Record FIRST so the PhaseTimer's contiguous marks cover the
         # host-state sync below ("sync") and the jitted launch
         # ("dispatch") — the phase sum must close on duration_ms.
-        rec = self._tick_record(active)
+        rec = self._tick_record(active, steps)
         self._sync_tables()
         if self._cur_dev is None:
             self._cur_dev = self._snap_dev(self.cur_tokens)
@@ -3915,7 +3963,7 @@ class ContinuousBatcher:
             jnp.asarray(self.temps), jnp.asarray(self.top_ks),
             jnp.asarray(self.top_ps), jnp.asarray(active),
             jnp.asarray(self.adapter_ids),
-            self._gstate_dev, g_allow, g_trans,
+            self._gstate_dev, g_allow, g_trans, steps=steps,
         )
         # Device-side feedback for the next tick; no host sync. Grammar
         # state rides the same way: the scan's final per-row states
@@ -3990,7 +4038,7 @@ class ContinuousBatcher:
         active = np.array([s.active for s in self.slots], bool)
         # Record first: the PhaseTimer must cover the host-state sync
         # below (same contract as _tick_dispatch).
-        rec = self._tick_record(active)
+        rec = self._tick_record(active, self._steps_per_tick)
         self._ilv_fill_rows()
         self._sync_tables()
         if self._cur_dev is None:
@@ -4046,7 +4094,7 @@ class ContinuousBatcher:
         active = np.array([s.active for s in self.slots], bool)
         # Record first: the PhaseTimer must cover the host-state sync
         # below (same contract as _tick_dispatch).
-        rec = self._tick_record(active)
+        rec = self._tick_record(active, 1 + self._jump_max)
         if chunk:
             self._ilv_fill_rows()
         self._sync_tables()

@@ -137,6 +137,9 @@ class TickRecord:
     # like finished/duration_ms.
     jump_tokens: int = 0
     jump_runs: int = 0
+    # Decode steps this tick advanced a row by (the full or the short
+    # length of the plain tick; a jump tick's 1 + jump_max).
+    steps: int = 0
     # Paged KV arena occupancy at dispatch (batching.paged_kv=on; 0
     # off): resident pages — live + reuse-cached — so a tick window
     # shows page pressure next to its admissions/finishes.
@@ -183,6 +186,7 @@ class TickRecord:
             "source": self.source,
             "jumpTokens": self.jump_tokens,
             "jumpRuns": self.jump_runs,
+            "steps": self.steps,
             "kvPagesInUse": self.kv_pages_in_use,
             "phaseAdmitMs": round(self.phase_admit_ms, 3),
             "phaseSyncMs": round(self.phase_sync_ms, 3),
@@ -400,6 +404,7 @@ class FlightRecorder:
         kv_pages_in_use: int = 0,
         admit_ms: float = 0.0,
         memory: Optional[dict] = None,
+        steps: int = 0,
     ) -> Optional[TickRecord]:
         """Record a tick at dispatch; returns the record so the caller
         can carry it alongside the in-flight device call and complete
@@ -426,6 +431,7 @@ class FlightRecorder:
             source=self.source,
             kv_pages_in_use=kv_pages_in_use,
             memory=memory or {},
+            steps=steps,
         )
         self._admitted_since_tick = 0
         self._ticks.append(rec)

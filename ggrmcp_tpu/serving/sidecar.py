@@ -1071,6 +1071,7 @@ class Sidecar:
                     phase_marks=[p for p, _ in t.marks],
                     phase_mark_start_ms=[ms for _, ms in t.marks],
                     jump_tokens=t.jump_tokens, jump_runs=t.jump_runs,
+                    steps=t.steps,
                     memory_components=list(t.memory),
                     memory_component_bytes=[
                         int(b) for b in t.memory.values()

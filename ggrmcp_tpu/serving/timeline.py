@@ -146,7 +146,7 @@ def _tick_events(ticks: list, pid: int, events: list) -> None:
             for k in (
                 "seq", "activeSlots", "admitted", "finished",
                 "interleavedRows", "traceIds", "specDrafted",
-                "specAccepted", "kvPagesInUse", "phaseAdmitMs",
+                "specAccepted", "kvPagesInUse", "phaseAdmitMs", "steps",
             )
             if k in tick
         }

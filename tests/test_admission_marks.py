@@ -62,7 +62,7 @@ def _round(inflight=()) -> ContinuousBatcher:
     b._inflight = [(t, None, [], None) for t in inflight]
     b._adm_timer = PhaseTimer()
     b._adm_span = {"seq": 1, "tick": 1}
-    b._adm_chunk_run = 0
+    b._adm_chunk_run = b._admit_run = 0
     return b
 
 
