@@ -62,6 +62,9 @@ _SERVING_HELP = {
         "waited or a slot was free)",
     "tick_collects": "decode tick token collects",
     "admit_rounds": "admission rounds run",
+    "admit_rounds_deferred":
+        "admission rounds whose first tokens were read after the "
+        "next decode tick was dispatched",
     "interleaved_chunks": "prefill chunks fused into decode ticks",
     "interleaved_admissions":
         "requests admitted via tick-interleaved prefill",

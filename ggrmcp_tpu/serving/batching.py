@@ -609,10 +609,10 @@ class ContinuousBatcher:
         self.prefix_hits = 0
         self.prefix_misses = 0
 
-        # Tick / collect / admission-round counts. All TIMES come from
-        # one clock per tick, the flight recorder's PhaseTimer
-        # (phase_ms below; an admission round carries its own timer).
-        self.timing = {"ticks": 0, "short_ticks": 0, "collects": 0, "admit_rounds": 0}
+        # Tick / collect / admission-round counts, the rounds settled
+        # after a dispatch among them. All TIMES come from PhaseTimers.
+        self.timing = dict.fromkeys((
+            "ticks", "short_ticks", "collects", "admit_rounds", "admit_rounds_deferred"), 0)
         # Decode-stall histogram: wall-clock gaps (ms) between
         # consecutive token emissions to a slot while its request is
         # live — the per-slot observable the prefill-interleave mode
@@ -700,11 +700,11 @@ class ContinuousBatcher:
         self._adm_families: list[str] = []
         self._adm_reused = 0
         self._adm_chunk_run = 0
-        # The round's own clock and what its profiler spans carry
-        # (_prefill_into_slots opens both; _admission_program and the
-        # activation loops mark and annotate where the work happens).
-        self._adm_timer = PhaseTimer()
-        self._adm_span: dict = {}
+        # The admission round in progress (`_round`: its clock, its
+        # spans' tags, the programs it queued; _prefill_into_slots opens
+        # it) and the round whose rows are seated, first tokens still on
+        # the device (`_seated`; _settle_round). Each a _SeatedRound.
+        self._round = self._seated = None
         # slot -> (restore source entry, captures) of the rows of the
         # admission round in progress (_state_plan).
         self._state_rows: dict = {}
@@ -1789,9 +1789,15 @@ class ContinuousBatcher:
         )
         return first
 
-    def _activate_slot(
-        self, slot_idx: int, request: _Request, first_tok: int
-    ) -> None:
+    def _seat_slot(self, slot_idx: int, request: _Request) -> None:
+        """Seat `request` in its slot while its admission program is
+        queued: everything the next tick's dispatch reads of the row
+        but its first token, which stays on the device
+        (`_patch_seated` scatters it into the tick's token feedback
+        from the program's own output). No device result is read
+        here; `_settle_slot` does the rest once the token is on the
+        host (the two halves of what was one activation: the round
+        seats, the tick step settles after its dispatch)."""
         slot = self.slots[slot_idx]
         slot.active = True
         slot.request = request
@@ -1799,11 +1805,10 @@ class ContinuousBatcher:
         slot.max_new = request.max_new
         slot.done = False
         slot.reserved = False
-        request.t_admit = time.perf_counter()
         if request.parked:
             # Resume completes a preempt cycle (serving/scheduler.py):
             # the parked request is decoding again, its demoted pages
-            # restored (or recomputed) by the prefill that just ran.
+            # restored (or recomputed) by the prefill just queued.
             request.parked = False
             if self.sched is not None:
                 self.sched.resumes += 1
@@ -1812,22 +1817,15 @@ class ContinuousBatcher:
         # 1-based on the same counter).
         request.first_tick = self.timing["ticks"] + 1
         self.recorder.note_admit()
-        self.cur_tokens[slot_idx] = first_tok
-        if self._cur_dev is not None:
-            self._cur_dev = self._cur_dev.at[slot_idx].set(first_tok)
         # Grammar state: the row's emit tracker starts at the admission
-        # state (the _emit below advances it through first_tok); the
-        # slot's NEXT-tick state is the post-first-token state, patched
-        # into the mirror + device twin like cur_tokens.
-        g0 = self._g0(request)
-        request.gcur = g0
-        g_next = (
-            self.arena.step(g0, first_tok)
-            if request.grammar is not None else 0
-        )
-        self.gstates[slot_idx] = g_next
-        if self._gstate_dev is not None:
-            self._gstate_dev = self._gstate_dev.at[slot_idx].set(g_next)
+        # state (the settle's _emit advances it through the first
+        # token). The slot's NEXT-tick state is the post-first-token
+        # state, which only the host can step to: a round with a
+        # grammar row settles before the tick is dispatched
+        # (_prefill_into_slots), and `_settle_slot` patches it into the
+        # mirror and the device twin. An unconstrained row's is 0.
+        request.gcur = self._g0(request)
+        self.gstates[slot_idx] = 0
         self.temps[slot_idx] = request.sampling.temperature
         self.top_ks[slot_idx] = request.sampling.top_k
         self.top_ps[slot_idx] = request.sampling.top_p
@@ -1840,20 +1838,22 @@ class ContinuousBatcher:
             and request.grammar is not None
             and not request.jump_degraded
         )
-        # Paged KV: the prompt's full pages now hold valid prefix KV
-        # (activation implies the prefill materialized) — index them so
-        # later admissions share instead of recomputing. Adapter'd rows
-        # index under their own key domain (the chain root folds the
-        # stable adapter key — serving/pages.py), so same-adapter
-        # sessions share while cross-adapter aliasing stays impossible.
-        # Before _emit: a one-token request finishes inside it, and the
-        # cache window should survive the request (refcount-0 indexed
-        # pages stay resident, LRU-evicted).
-        if self._paged:
-            self.pages.register(
-                slot_idx, request.prompt, adapter=request.adapter_key
-            )
-        self._emit(slot_idx, first_tok)
+        # What is NOT here, and waits for the token (`_settle_slot`,
+        # below `cache_bytes`): the host mirror `cur_tokens`, the
+        # request's `t_admit` stamp, `pages.register` (the prompt's
+        # full pages enter the index once the first token proves the
+        # prefill ran) and the first token's `_emit`. A seated row is
+        # active and has emitted nothing: a failure before its settle
+        # replays it from its prompt (_recover_after_tick_failure).
+        #
+        # (This function keeps the place and the length `_activate_slot`
+        # had. The line numbers of everything above `warmup()`'s last
+        # program call are part of the compile cache's key of every
+        # program with a kernel in it, so a host-only change that
+        # moves none of them starts warm on the chip beside its
+        # parent; the new helpers live below `cache_bytes`. See the
+        # verify skill's note on keeping a chip call warm: PR 49's,
+        # in `.claude/skills/verify/SKILL.md`.)
 
     # -- public API ---------------------------------------------------------
 
@@ -1879,15 +1879,15 @@ class ContinuousBatcher:
         # Grammar tables ride every sampling program as fixed-shape
         # args; state 0 (accept-all) keeps warmup numerics inert.
         g_allow, g_trans = self._grammar_tables()
-        zgb = np.zeros((b,), np.int32)
-        _, self.cache = self._admit_single(
+        zgb, warm = np.zeros((b,), np.int32), {}  # warm: rows -> first
+        warm[1], self.cache = self._admit_single(
             self.engine.params, jnp.asarray(zeros1), jnp.asarray(zlen1),
             self.cache, jnp.int32(0), jnp.asarray(zseed1),
             jnp.asarray(zf1), jnp.asarray(zi1), jnp.asarray(of1),
             jnp.asarray(zi1), jnp.asarray(zi1), g_allow, g_trans,
             self._state_io([], 1),
         )
-        _, self.cache = self._admit_full(
+        warm[b], self.cache = self._admit_full(
             self.engine.params, jnp.asarray(np.zeros((b, s), np.int32)),
             jnp.asarray(np.zeros((b,), np.int32)), self.cache,
             jnp.asarray(np.zeros((b,), bool)),
@@ -1962,7 +1962,7 @@ class ContinuousBatcher:
             # (_admit_chunked_group's min(b, bucket)).
             r_buckets.append(self._mini_rows)
             for r_bucket in r_buckets:
-                _, self.cache = self._admit_chunked(
+                warm[r_bucket], self.cache = self._admit_chunked(
                     self.engine.params,
                     jnp.asarray(np.zeros(
                         (r_bucket, self._grid_chunks, c), np.int32)),
@@ -2051,7 +2051,7 @@ class ContinuousBatcher:
                         (r_rows, self._table_width), self._n_pages,
                         np.int32,
                     )
-                    _, self.cache = self._admit_paged_pfx(
+                    warm[r_rows], self.cache = self._admit_paged_pfx(
                         self.engine.params,
                         jnp.asarray(np.zeros((r_rows, 1, width), np.int32)),
                         jnp.asarray(zlenb[:r_rows]), self.cache,
@@ -2066,6 +2066,14 @@ class ContinuousBatcher:
                         self._state_io([], r_rows),
                     )
                 width *= 2
+        # The seat's scatter of a program's first tokens into the
+        # tick's token feedback (_patch_seated), one a row count, from
+        # the programs' own outputs (their placement is part of the
+        # scatter's signature). Every slot index out of range: nothing
+        # is written, and the twins read what the first dispatch would
+        # have snapped.
+        for r_rows, first in warm.items():
+            self._patch_seated(first, np.full((r_rows,), b, np.int32))
         jax.block_until_ready(self.cache.k)
 
     def start(self) -> None:
@@ -2079,6 +2087,7 @@ class ContinuousBatcher:
     _HANDOFF_KINDS = {
         "_tick_step": "tick", "_prefill_into_slots": "admit",
         "_drain_inflight": "drain", "_preempt_slots": "preempt",
+        "_settle_round": "settle",
     }
 
     async def _in_executor(self, loop, fn, *args):
@@ -2425,9 +2434,10 @@ class ContinuousBatcher:
         pipeline the collect follows its own dispatch and an admission
         waits behind no tick: `_short_steps` is the full length there.
         The tick after a LONG admission is full as well. An admission
-        round waits the tick in flight off the device and runs its
-        programs to their end, so between two rounds the decoding rows
-        advance by one tick, the one dispatched here. A pass over a
+        round's programs run behind the tick in flight, to their end,
+        before the tick dispatched here runs (it is queued behind
+        them), so between two rounds the decoding rows advance by one
+        tick, this one. A pass over a
         chunk of `prefill_chunk` tokens takes a decode step's time at
         least (the same weights, far more arithmetic), so once the
         programs since the last dispatch ran more chunk tokens than a
@@ -2658,6 +2668,10 @@ class ContinuousBatcher:
             "short_ticks": t["short_ticks"],
             "tick_collects": t["collects"],
             "admit_rounds": t["admit_rounds"],
+            # Of `admit_rounds`, those whose following tick was
+            # dispatched before their first tokens were read
+            # (_tick_step settles them after its dispatch).
+            "admit_rounds_deferred": t["admit_rounds_deferred"],
             # Tick-phase attribution (flight recorder PhaseTimer;
             # cumulative ms over collected ticks, divide by
             # tick_collects for per-tick means): admit = queue drain +
@@ -3162,6 +3176,11 @@ class ContinuousBatcher:
         self.gstates[:] = 0
         self.jump_ok[:] = False
         self._gstate_dev = None
+        # A round seated and not settled dies with the cache its
+        # programs donated: its rows were active, with nothing emitted,
+        # and were replayed from their prompts above.
+        self._seated = None
+        self._cache_at_risk = False
         if self._paged:
             # The donated arena died with the tick: every page and
             # every index entry is device-dead. Reset the HOST
@@ -3295,6 +3314,24 @@ class ContinuousBatcher:
             if not batch:
                 break
             slots_idx = self._free_slots()[: len(batch)]
+            if self._seated is not None:
+                # A second round of this call: the round before it is
+                # settled first (its first tokens read, its pages
+                # indexed, its first tokens emitted), as it was before
+                # the settle moved behind the tick's dispatch, so this
+                # round's pages.admit sees the index the synchronous
+                # order shows it. A call of its own: a device failure
+                # of those programs is theirs, not this batch's.
+                try:
+                    await self._in_executor(loop, self._settle_round)
+                except asyncio.CancelledError:
+                    raise  # batcher shutdown cancels the loop task
+                except Exception:
+                    logger.exception(
+                        "admission programs failed; replaying active slots"
+                    )
+                    self._recover_after_tick_failure()
+                    slots_idx = self._free_slots()[: len(batch)]
             try:
                 await self._in_executor(
                     loop, self._prefill_into_slots, slots_idx, batch
@@ -3304,8 +3341,9 @@ class ContinuousBatcher:
             except Exception:
                 # Fail the batch, but scale the blast radius to what
                 # actually broke. Requests from this batch that already
-                # activated (chunked path emits per-request) got their
-                # success chunk — don't queue a second terminal chunk.
+                # activated (a program of the round seated them: its
+                # settle emits their first tokens, or a dead cache
+                # replays them below) get no terminal chunk here.
                 # The shared cache is rebuilt ONLY if the failing call
                 # was one that donates it (_cache_at_risk); a failure
                 # before that dispatch killed nothing shared, and
@@ -3355,6 +3393,11 @@ class ContinuousBatcher:
                         self._tables_dirty = True
                     self.cache = self._make_shared_cache()
                     self._cache_at_risk = False
+                    # The seated rows were among the replayed, and the
+                    # token feedback their seat patched is as dead as
+                    # the cache (a failed program's output poisons it).
+                    self._seated = None
+                    self._cur_dev = self._gstate_dev = None
                 continue
             admitted += len(batch)
         return admitted
@@ -3363,24 +3406,49 @@ class ContinuousBatcher:
         self, slots_idx: list[int], batch: list[_Request]
     ) -> None:
         """One admission round (an executor work item): route the
-        batch (_route_admission), and account for it on ONE clock — the
-        round's PhaseTimer gives its duration to the next tick's admit
-        phase, the per-row cost EMA and the round's AdmissionRecord
-        (family that ran, rows, tokens, trace ids, the tick it
-        precedes). The timer is marked from inside, by every admission
-        program call (_admission_program: build / launch / tick_wait /
-        device) and activation loop (activate), so the record also says
-        how much of the round was host work, how much the wait for the
-        tick in flight and how much the admission programs alone on
-        the device. While a profile capture runs the round is also a
-        `ggrmcp.admit` span in the profiler's own trace, with a
-        `ggrmcp.admit.program` / `.device` / `.activate` child for each
-        of those."""
-        timer = self._adm_timer = PhaseTimer()
+        batch (_route_admission), which QUEUES the round's programs and
+        seats their rows without reading a device result, and account
+        for the round on ONE clock, its PhaseTimer, marked from inside
+        by every admission program call (_admission_program: build /
+        launch, and tick_wait / device where a second program of the
+        round waits for the one before it) and seat (activate).
+
+        On a pipelined loop the round ends there, its rows seated
+        (`_seated`): the loop dispatches the next tick behind the
+        round's programs and `_tick_step` settles the round after that
+        dispatch (_settle_round: first tokens read, pages indexed,
+        first tokens emitted), so the device never waits for a host
+        turn at a round's end. Two rounds keep the order they always
+        had and settle here, before the round returns, because the code
+        can see from their input that the next dispatch needs the
+        host's view of the first token: a loop that is not pipelined
+        (`_pipeline` false: the collect follows its own dispatch, the
+        tests' synchronous reference), and a round that holds a row
+        with a grammar (the next tick's `gstates[slot]` is
+        `arena.step(g0, first)`, stepped on the host). Observed, not
+        configured.
+
+        The round's time in THIS call goes to the next tick's admit
+        phase; a deferred settle runs inside that tick's wait phase.
+        The round's AdmissionRecord (family that ran, rows, tokens,
+        trace ids, the tick it precedes; host / tick_wait / device /
+        dispatch, which sum to its duration) and the per-row cost EMA
+        are written when the round is settled (_round_done). While a
+        profile capture runs the round is a `ggrmcp.admit` span in the
+        profiler's own trace, with a `ggrmcp.admit.program` /
+        `.activate` child for each launch and seat; its settle is a
+        `ggrmcp.admit.settle` span with `.device` / `.activate`
+        children."""
+        timer = PhaseTimer()
         seq = self.timing["admit_rounds"] + 1
         tick_seq = self.timing["ticks"] + 1
-        self._adm_span = {"seq": seq, "tick": tick_seq}
-        with tracing.annotation("ggrmcp.admit", **self._adm_span):
+        rnd = self._round = _SeatedRound(
+            timer, {"seq": seq, "tick": tick_seq},
+            # The tick dispatched before this round, still on the
+            # device (the loop keeps at most one; the newest is last).
+            self._inflight[-1][0] if self._inflight else None,
+        )
+        with tracing.annotation("ggrmcp.admit", **rnd.span):
             # Chaos hooks: admission latency (admit_slow, arm with ms=)
             # and admission failure (admit_fail) — the latter exercises
             # _admit's blast-radius-scaled batch-failure handling.
@@ -3398,13 +3466,23 @@ class ContinuousBatcher:
             try:
                 queued, shed_rows = self._route_admission(slots_idx, batch)
             finally:
+                # Pins keep a LATER pages.admit of this round from
+                # evicting a pool entry a queued program restores from;
+                # past the round, device order does: a program that
+                # captures into the entry is queued behind the one
+                # that reads it.
                 if self._row_state and self._paged:
                     self.pages.release_snapshot_pins()
                 self._state_rows.clear()
-        # What is left after the last activation loop is the way out; a
-        # round that launched nothing (every row queued for tick-fused
-        # chunks, or shed) was building all along.
-        timer.mark("activate" if "device" in timer.acc else "build")
+            # What is left after the last seat is the way out; a round
+            # that launched nothing (every row queued for tick-fused
+            # chunks, or shed) was building all along.
+            timer.mark("activate" if rnd.programs else "build")
+            if rnd.programs and (
+                not self._pipeline
+                or any(r.grammar is not None for r in batch)
+            ):
+                self._settle_round()
         dt = (timer.last - timer.t0) * 1000.0
         self.timing["admit_rounds"] = seq
         # Phase attribution: this round's executor time seeds the NEXT
@@ -3415,9 +3493,9 @@ class ContinuousBatcher:
         self.prefill_tokens["reused"] += self._adm_reused
         self.prefill_tokens["computed"] += prompt_tokens - self._adm_reused
         self.prefill_tokens["chunk_run"] += self._adm_chunk_run
-        self.recorder.note_admission(
-            timer, "+".join(self._adm_families),
-            [r.trace_id for r in batch if r.trace_id],
+        rnd.note = dict(
+            family="+".join(self._adm_families),
+            batch_trace_ids=[r.trace_id for r in batch if r.trace_id],
             rows=len(batch),
             prompt_tokens=prompt_tokens,
             reused_tokens=self._adm_reused,
@@ -3427,10 +3505,63 @@ class ContinuousBatcher:
         # ~zero cost into the EMA would let the p50_budget_ms cap admit
         # unbounded short-prompt bursts on the strength of cheap
         # enqueues.
-        prefilled = len(batch) - queued - shed_rows
-        if prefilled:
+        rnd.prefilled = len(batch) - queued - shed_rows
+        if self._seated is None:  # settled above, or nothing launched
+            self._round_done(rnd)
+
+    def _settle_round(self) -> None:
+        """Settle the seated round: wait the tick that was in flight
+        before it off the device (`tick_wait`: what follows is the
+        round's programs alone), bring each program's first tokens to
+        the host (`device`, once a program: the wait for it, not the
+        copy of one already waited for) and settle its rows
+        (`activate`: _settle_slot). Called by `_tick_step` after its
+        dispatch (the deferred order: the gap since the round returned,
+        the hop to the loop and that dispatch, is marked `dispatch`),
+        by `_admit` before a second round of one call, and by the round
+        itself where the order is synchronous. A device failure of a
+        donating program surfaces here, with `_cache_at_risk` still
+        set: the caller's handler replays every active slot, the seated
+        rows among them."""
+        rnd, self._seated = self._seated, None
+        timer = rnd.timer
+        returned = rnd.note is not None
+        if returned:
+            # What lies between the round's return and here is the
+            # tick's dispatch where one was made, else the loop's hop.
+            timer.mark("dispatch" if rnd.deferred else "activate")
+        settle_from = timer.last
+        with tracing.annotation("ggrmcp.admit.settle", **rnd.span):
+            for program in rnd.programs:
+                self._await_program(rnd, program)
+            # Cleared only now: under async dispatch a device failure
+            # in a donating call surfaces in the waits above, and the
+            # handler must still see the cache as possibly dead.
+            self._cache_at_risk = False
+            with tracing.annotation("ggrmcp.admit.activate", **rnd.span):
+                for program in rnd.programs:
+                    first = np.asarray(program.first)
+                    # A settled round holds nothing on the device.
+                    program.first = None
+                    for sl, req, j in program.rows:
+                        self._settle_slot(sl, req, int(first[j]))
+            rnd.tick_ahead = None
+            timer.mark("activate")
+        if returned:
+            if not rnd.deferred:
+                self._admit_phase_ms += (timer.last - settle_from) * 1000.0
+            self._round_done(rnd)
+
+    def _round_done(self, rnd: "_SeatedRound") -> None:
+        """The round's record and the per-row cost EMA, once its marks
+        are all made."""
+        timer = rnd.timer
+        self.recorder.note_admission(timer, deferred=rnd.deferred, **rnd.note)
+        if rnd.prefilled:
+            dt = (timer.last - timer.t0) * 1000.0 - timer.acc.get(
+                "dispatch", 0.0)
             self._admit_ema_ms = (
-                0.7 * self._admit_ema_ms + 0.3 * dt / prefilled
+                0.7 * self._admit_ema_ms + 0.3 * dt / rnd.prefilled
             )
 
     def _state_plan(self, sl: int, req: _Request, adm) -> None:
@@ -3461,81 +3592,166 @@ class ContinuousBatcher:
     def _admission_program(
         self, launch, family: str, rows: int, chunks: int, tokens: int,
         width: int,
-    ) -> np.ndarray:
-        """Run ONE admission program and bring each row's first token
-        to the host. `launch()` makes the jitted call, which donates
-        the shared cache, and returns (first, cache). `chunks` is the
-        number of [1, width] chunk rows the program runs, a bucket's
-        padding rows and a grid's no-op chunks among them where the
-        program computes those: chunks x width is what the `tokens`
-        prompt tokens it computes fill (prefill_chunk_tokens_run beside
-        prefill_tokens_computed, both stamped at the round's end, so a
-        delta of the two covers the same rounds). The round's timer
-        is marked where each thing happens (flight_recorder.
-        ADMIT_HOST_MARKS): `build` closes here (the caller's numpy
-        grids and grammar tables, the table sync), `launch` when the
-        jitted call returns (argument transfer + enqueue), `tick_wait`
-        when the tick dispatched before this round has left the device
-        (_await_tick_in_flight), `device` when `first` is on the host —
-        the admission program alone on the device, plus that copy.
-        While a capture runs the call is a `ggrmcp.admit.program` span
-        (launch → first on the host) around a `ggrmcp.admit.device`
-        one, both carrying the round's seq and the tick it precedes."""
-        timer = self._adm_timer
+    ):
+        """Queue ONE admission program and return its rows' first
+        tokens as the DEVICE array the program produced: nothing is
+        read here (_settle_round reads, once everything the loop has
+        for the device is queued). `launch()` makes the jitted call,
+        which donates the shared cache, and returns (first, cache).
+        `chunks` is the number of [1, width] chunk rows the program
+        runs, a bucket's padding rows and a grid's no-op chunks among
+        them where the program computes those: chunks x width is what
+        the `tokens` prompt tokens it computes fill
+        (prefill_chunk_tokens_run beside prefill_tokens_computed, both
+        stamped at the round's end, so a delta of the two covers the
+        same rounds). The round's timer is marked where each thing
+        happens (flight_recorder.ADMIT_HOST_MARKS): `build` closes here
+        (the caller's numpy grids, its argument transfers, the grammar
+        tables, the table sync), `launch` when the jitted call returns
+        (the enqueue).
+
+        One admission program's temporaries at a time: the runtime
+        reserves a program's temporaries when it is QUEUED, so a second
+        program of a round queued behind the first would hold two mini
+        caches at once where the families that admit a row a program do
+        so because one is all that fits (keye: 0.45 GB a row beside
+        ~13.5 of 16 GB). A second program is therefore BUILT while the
+        one before it runs and launched when that one has left the
+        device (_await_program: no copy, no activation; the device
+        waits for the enqueue alone). One rule for every family: the
+        tick queued behind a round's last program adds its own few MB
+        and is safe everywhere. While a capture runs the launch is a
+        `ggrmcp.admit.program` span carrying the round's seq and the
+        tick it precedes."""
+        rnd = self._round
         self._sync_tables()
-        self._cache_at_risk = True
         run = chunks * width
         self._adm_chunk_run += run
         self._admit_run += run
-        timer.mark("build")
+        rnd.timer.mark("build")
+        if rnd.programs:
+            self._await_program(rnd, rnd.programs[-1])
+        self._cache_at_risk = True
         with tracing.annotation(
             "ggrmcp.admit.program", family=family, rows=rows,
             chunks=chunks, tokens=tokens, chunk_tokens_run=run,
-            **self._adm_span,
+            **rnd.span,
         ):
             first, self.cache = launch()
-            timer.mark("launch")
-            self._await_tick_in_flight()
-            with tracing.annotation("ggrmcp.admit.device", **self._adm_span):
-                # Materialize BEFORE clearing the at-risk flag: under
-                # async dispatch a device failure in the donating call
-                # surfaces here, and the handler must still see the
-                # cache as possibly dead.
-                first = np.asarray(first)
-            timer.mark("device")
-        self._cache_at_risk = False
+        rnd.timer.mark("launch")
         return first
 
-    def _await_tick_in_flight(self) -> None:
-        """On a pipelined loop the tick dispatched one turn earlier is
-        still on the device when an admission program is launched, and
-        the program queues behind it: wait here until that tick has
+    def _await_tick_ahead(self, rnd: "_SeatedRound") -> None:
+        """On a pipelined loop the tick dispatched before the round is
+        still on the device when the round's first program is queued,
+        and the program runs behind it: wait here until that tick has
         left the device and mark the time `tick_wait`, so that what
-        follows is the admission program alone. Nothing is consumed,
-        collected or reordered — the thread would block as long in
-        np.asarray(first), and the tick's collect then finds its array
-        ready. Once a round: a second program call finds the device
-        already its own. The newest tick in flight is the one waited
-        for (the loop keeps at most one; the device runs them in
-        order)."""
-        if "tick_wait" in self._adm_timer.acc or not self._inflight:
+        follows is the admission programs alone. Nothing is consumed,
+        collected or reordered — the thread would block as long in the
+        wait for the program, and the tick's collect then finds its
+        array ready. Once a round; the tick's own failure is raised by
+        its collect, to the handler that owns it (_loop's replay), not
+        here."""
+        if rnd.tick_ahead is None or "tick_wait" in rnd.timer.acc:
             return
         try:
-            jax.block_until_ready(self._inflight[-1][0])
-        except Exception:  # noqa: BLE001 — the tick's own failure is
-            # raised by its collect, to the handler that owns it
-            # (_loop's replay), not to this round's.
+            jax.block_until_ready(rnd.tick_ahead)
+        except Exception:  # noqa: BLE001 — see above
             pass
-        self._adm_timer.mark("tick_wait")
+        rnd.timer.mark("tick_wait")
 
-    def _activate_rows(self, rows: list) -> None:
-        """Activate (slot, request, first token) rows: the round's
+    def _await_program(self, rnd: "_SeatedRound", program) -> None:
+        """Wait until `program` has left the device (its first tokens
+        are ready; nothing is copied) and mark the wait `device`, once
+        a program: the round's `device` marks are the host's waits for
+        its programs, each ending when its program does (a
+        `ggrmcp.admit.device` span while a capture runs). The
+        program's failure is raised here."""
+        self._await_tick_ahead(rnd)
+        if program.waited:
+            return
+        program.waited = True
+        with tracing.annotation("ggrmcp.admit.device", **rnd.span):
+            jax.block_until_ready(program.first)
+        rnd.timer.mark("device")
+
+    def _activate_rows(self, rows: list, first, slots) -> None:
+        """Seat the rows of the program just queued: `rows` are (slot,
+        request, index into `first`), `first` the program's first
+        tokens on the device, `slots` [len(first)] the slot each of
+        them belongs to (out of range: a padding row). The round's
         `activate` segment, a `ggrmcp.admit.activate` span while a
-        capture runs."""
-        with tracing.annotation("ggrmcp.admit.activate", **self._adm_span):
-            for sl, req, tok in rows:
-                self._activate_slot(sl, req, tok)
-        self._adm_timer.mark("activate")
+        capture runs. The round is `_seated` from its first program
+        on."""
+        rnd = self._round
+        with tracing.annotation("ggrmcp.admit.activate", **rnd.span):
+            for sl, req, _ in rows:
+                self._seat_slot(sl, req)
+            self._patch_seated(first, slots)
+        try:
+            first.copy_to_host_async()
+        except (AttributeError, RuntimeError):
+            pass  # the settle's np.asarray transfers instead
+        rnd.programs.append(_QueuedProgram(first, rows))
+        self._seated = rnd
+        rnd.timer.mark("activate")
+
+    def _patch_seated(self, first, slots) -> None:
+        """The device half of a seat: scatter a program's first tokens
+        (`first`, still on the device) into the tick's token feedback
+        at `slots`, one scatter a program, and zero the grammar-state
+        twin there (an unconstrained row's state; a constrained row's
+        is patched by its settle, which comes before the next
+        dispatch). An index out of range is dropped. The twins are
+        snapped here where no tick has made them yet."""
+        if self._cur_dev is None:
+            self._cur_dev = self._snap_dev(self.cur_tokens)
+        if self._gstate_dev is None:
+            self._gstate_dev = self._snap_dev(self.gstates)
+        self._cur_dev = self._cur_dev.at[slots].set(first, mode="drop")
+        self._gstate_dev = self._gstate_dev.at[slots].set(0, mode="drop")
+
+    def _settle_slot(
+        self, slot_idx: int, request: _Request, first_tok: int
+    ) -> None:
+        """What a seated row's activation still owes once its first
+        token is on the host (_seat_slot did the rest)."""
+        request.t_admit = time.perf_counter()
+        self.cur_tokens[slot_idx] = first_tok
+        if request.grammar is not None:
+            # The slot's NEXT-tick state is the post-first-token state,
+            # patched into the mirror + device twin like cur_tokens.
+            g_next = self.arena.step(request.gcur, first_tok)
+            self.gstates[slot_idx] = g_next
+            if self._gstate_dev is not None:
+                self._gstate_dev = self._gstate_dev.at[slot_idx].set(g_next)
+        # Paged KV: the prompt's full pages now hold valid prefix KV
+        # (the first token implies the prefill materialized) — index
+        # them so later admissions share instead of recomputing.
+        # Adapter'd rows index under their own key domain (the chain
+        # root folds the stable adapter key — serving/pages.py), so
+        # same-adapter sessions share while cross-adapter aliasing
+        # stays impossible. Before _emit: a one-token request finishes
+        # inside it, and the cache window should survive the request
+        # (refcount-0 indexed pages stay resident, LRU-evicted).
+        if self._paged:
+            self.pages.register(
+                slot_idx, request.prompt, adapter=request.adapter_key
+            )
+        self._emit(slot_idx, first_tok)
+
+    def _activate_slot(
+        self, slot_idx: int, request: _Request, first_tok: int
+    ) -> None:
+        """Seat and settle one row whose first token is on the host
+        already (an interleaved admission's row finish, inside the
+        tick's dispatch)."""
+        self._seat_slot(slot_idx, request)
+        if self._cur_dev is not None:
+            self._cur_dev = self._cur_dev.at[slot_idx].set(first_tok)
+        if self._gstate_dev is not None:
+            self._gstate_dev = self._gstate_dev.at[slot_idx].set(0)
+        self._settle_slot(slot_idx, request, first_tok)
 
     def _route_admission(
         self, slots_idx: list[int], batch: list[_Request]
@@ -3723,20 +3939,21 @@ class ContinuousBatcher:
         self._admission_ran("chunked")
         g_allow, g_trans = self._grammar_tables()
         sio = self._state_io([(j, sl) for j, (sl, _) in enumerate(rows)], r)
+        # Transferred here, while the program before this one (if the
+        # round has one) still runs: the launch is the enqueue alone.
+        toks_dev, len_dev, *rest = map(jnp.asarray, (
+            tokens, true_len, slots_arr, seeds, temps, ks, ps, adapters, g0s))
         first = self._admission_program(
             lambda: self._admit_chunked(
-                self.engine.params, jnp.asarray(tokens),
-                jnp.asarray(true_len), self.cache, jnp.asarray(slots_arr),
-                jnp.asarray(seeds), jnp.asarray(temps), jnp.asarray(ks),
-                jnp.asarray(ps), jnp.asarray(adapters),
-                jnp.asarray(g0s), g_allow, g_trans, sio,
+                self.engine.params, toks_dev, len_dev, self.cache, *rest,
+                g_allow, g_trans, sio,
             ),
             "chunked", rows=len(rows),
             chunks=int((-(-true_len // c)).sum()),
             tokens=int(true_len.sum()), width=c,
         )
         self._activate_rows(
-            [(sl, req, int(first[j])) for j, (sl, req) in enumerate(rows)]
+            [(sl, req, j) for j, (sl, req) in enumerate(rows)], first, slots_arr
         )
 
     def _admit_paged_group(
@@ -3781,22 +3998,22 @@ class ContinuousBatcher:
         g_allow, g_trans = self._grammar_tables()
         sio = self._state_io(
             [(j, sl) for j, (sl, _, _) in enumerate(rows)], r)
+        # Transferred here (see _admit_chunked_group).
+        toks_dev, len_dev, *rest = map(jnp.asarray, (
+            tokens, true_len, slots_arr, gtables, np.int32(scan_start),
+            np.int32(merge_start), seeds, temps, ks, ps, adapters, g0s))
         first = self._admission_program(
             lambda: self._admit_paged_pfx(
-                self.engine.params, jnp.asarray(tokens),
-                jnp.asarray(true_len), self.cache, jnp.asarray(slots_arr),
-                jnp.asarray(gtables), jnp.int32(scan_start),
-                jnp.int32(merge_start), jnp.asarray(seeds),
-                jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(ps),
-                jnp.asarray(adapters), jnp.asarray(g0s), g_allow, g_trans,
-                sio,
+                self.engine.params, toks_dev, len_dev, self.cache, *rest,
+                g_allow, g_trans, sio,
             ),
             "paged_pfx", rows=len(rows), chunks=r * t_steps,
             tokens=int(true_len.sum()) - scan_start * len(rows),
             width=width,
         )
         self._activate_rows(
-            [(sl, req, int(first[j])) for j, (sl, req, _) in enumerate(rows)]
+            [(sl, req, j) for j, (sl, req, _) in enumerate(rows)],
+            first, slots_arr,
         )
 
     def _prefill_fused(
@@ -3843,29 +4060,35 @@ class ContinuousBatcher:
         sio = self._state_io(
             [(row_of(j), sl) for j, sl in enumerate(slots_idx)], rows)
         program = self._admit_single if single else self._admit_full
+        # Transferred here (see _admit_chunked_group). The single-row
+        # program takes the slot index, the full-pool one a mask of the
+        # rows that hold a request.
+        toks_dev, len_dev, *rest = map(jnp.asarray, (
+            tokens, true_len, np.int32(slots_idx[0]) if single else valid,
+            seeds, temps, ks, ps, adapters, g0s))
         first = self._admission_program(
             lambda: program(
-                self.engine.params, jnp.asarray(tokens),
-                jnp.asarray(true_len), self.cache,
-                # The single-row program takes the slot index, the
-                # full-pool one a mask of the rows that hold a request.
-                jnp.int32(slots_idx[0]) if single else jnp.asarray(valid),
-                jnp.asarray(seeds), jnp.asarray(temps), jnp.asarray(ks),
-                jnp.asarray(ps), jnp.asarray(adapters),
-                jnp.asarray(g0s), g_allow, g_trans, sio,
+                self.engine.params, toks_dev, len_dev, self.cache, *rest,
+                g_allow, g_trans, sio,
             ),
             family, rows=len(batch), chunks=rows,
             tokens=int(true_len.sum()), width=s,
         )
+        # The slot of each of the program's rows: the one row's, or the
+        # row's own index where the row holds a request.
+        slots_arr = np.asarray(slots_idx[:1], np.int32) if single else (
+            np.where(valid, np.arange(rows, dtype=np.int32), rows))
         self._activate_rows([
-            (slot_idx, req, int(first[row_of(j)]))
+            (slot_idx, req, row_of(j))
             for j, (slot_idx, req) in enumerate(zip(slots_idx, batch))
-        ])
+        ], first, slots_arr)
 
     def _tick_step(self) -> None:
         """One loop turn of decode work: dispatch a tick (fused with at
         most one prefill chunk when interleaved admissions are in
-        flight), then collect down to the pipeline depth. Synchronous
+        flight), settle the admission round seated before it, if any
+        (_settle_round: the deferred order), then collect down to the
+        pipeline depth. Synchronous
         mode (pipeline_ticks off) collects the tick it just dispatched
         — the classic loop; pipelined mode leaves it in flight and
         collects the PREVIOUS one, so the host pull of tick N overlaps
@@ -3892,6 +4115,20 @@ class ContinuousBatcher:
                 self._tick_dispatch_chunk()
             else:
                 self._tick_dispatch()
+        rnd = self._seated
+        if rnd is not None:
+            # The round before this tick queued its programs and seated
+            # its rows; the tick is queued behind them now, and only
+            # here are their first tokens read: the programs and this
+            # tick run while the host settles, and the device has not
+            # waited for the round's way out, the loop's hop or this
+            # dispatch. (A round that failed on its way keeps no record
+            # and is not counted: its seated rows are settled all the
+            # same.)
+            if rnd.note is not None:
+                rnd.deferred = True
+                self.timing["admit_rounds_deferred"] += 1
+            self._settle_round()
         depth = 1 if self._pipeline else 0
         while len(self._inflight) > depth:
             self._tick_collect_one()
@@ -4386,3 +4623,34 @@ class ContinuousBatcher:
 
     def _emit(self, slot_idx: int, token: int) -> None:
         self._emit_chunk(slot_idx, [token])
+
+
+# Below the class, not beside _IlvRow: see _seat_slot on the lines
+# above the admission programs.
+@dataclasses.dataclass
+class _QueuedProgram:
+    """One admission program of a round, queued: its first tokens on
+    the device, its rows as (slot, request, index into `first`), and
+    whether the host has waited for it already (_await_program)."""
+
+    first: object
+    rows: list
+    waited: bool = False
+
+
+@dataclasses.dataclass
+class _SeatedRound:
+    """An admission round from its start to its settle: its clock, what
+    its profiler spans carry (seq, tick), the token array of the tick
+    that was in flight when it began (None: none was), the programs it
+    queued, whether a tick was dispatched before its first tokens were
+    read, and, once the round has returned, what its AdmissionRecord
+    says (`note`) and how many of its rows ran a prefill."""
+
+    timer: PhaseTimer
+    span: dict
+    tick_ahead: object
+    programs: list = dataclasses.field(default_factory=list)
+    deferred: bool = False
+    note: Optional[dict] = None
+    prefilled: int = 0

@@ -57,25 +57,30 @@ PHASE_NAMES = ("admit", "sync", "dispatch", "wait", "host")
 # halves of queue_ms (144-149): pending (submit → the pop that put the
 # request into an admission batch) and prefill (that pop → activation).
 # Then the admission round's two (169-174): admit_device_ms, one
-# observation per admission program call (the program alone on the
-# device), and admit_host_ms, one per round (its host segments).
+# observation per admission program call (the program's own time on
+# the device: note_admission), and admit_host_ms, one per round (its
+# host segments).
 # Keys double as the stats() field prefixes:
 # <name>_bucket / <name>_sum / <name>_count.
 HISTOGRAM_NAMES = ("ttft_ms", "e2e_ms", "queue_ms", "tick_duration_ms") + tuple(
     f"tick_phase_{p}_ms" for p in PHASE_NAMES
 ) + ("tpot_ms", "pending_ms", "prefill_ms", "admit_device_ms", "admit_host_ms")
 
-# The segments an admission round's PhaseTimer marks, in the order one
-# admission program call makes them (serving/batching.py
-# _admission_program): build (numpy grids, block tables, grammar
-# tables, table sync — everything since the previous mark), launch (the
-# jitted call until it returns: argument transfer + enqueue), tick_wait
-# (only while a pipelined tick dispatched before the round is still on
-# the device: the admission program queues behind it), device (from the
-# device being free to the program's first tokens on the host: the
-# admission program alone, plus the copy back) and activate (the
-# _activate_slot loop). The first, second and last are the round's host
-# work.
+# The segments an admission round's PhaseTimer marks (serving/
+# batching.py): by every program call (_admission_program), build
+# (numpy grids, block tables, argument transfers, grammar tables, table
+# sync — everything since the previous mark) and launch (the jitted
+# call until it returns: the enqueue); by every seat and by the settle
+# (_activate_rows, _settle_round), activate; and by the waits, wherever
+# the round makes them (before a second program's launch, else in the
+# settle): tick_wait (once, only while a pipelined tick dispatched
+# before the round is still on the device: the round's programs queue
+# behind it) and device (the host's wait for one program, ending when
+# that program's first tokens are ready). A round settled after the
+# next tick's dispatch marks the stretch between its return and its
+# settle dispatch: the loop's hop and that tick's dispatch, which the
+# tick's own record counts as its sync and dispatch phases. build,
+# launch and activate are the round's host work.
 ADMIT_HOST_MARKS = ("build", "launch", "activate")
 
 
@@ -222,13 +227,17 @@ class AdmissionRecord:
     source: str = ""
     # duration_ms split where the round's timer marked it: host =
     # build + launch + activate (ADMIT_HOST_MARKS), tick_wait = waiting
-    # for the tick in flight to leave the device, device = the
-    # admission programs alone on it; the three sum to duration_ms.
-    # programs = admission program calls in the round.
+    # for the tick in flight to leave the device, device = waiting for
+    # the admission programs alone on it, dispatch = between the
+    # round's return and its settle, where the next tick was
+    # dispatched first (`deferred`; 0 otherwise); the four sum to
+    # duration_ms. programs = admission program calls in the round.
     host_ms: float = 0.0
     tick_wait_ms: float = 0.0
     device_ms: float = 0.0
     programs: int = 0
+    dispatch_ms: float = 0.0
+    deferred: bool = False
 
     def to_dict(self) -> dict:
         return {
@@ -246,6 +255,8 @@ class AdmissionRecord:
             "tickWaitMs": round(self.tick_wait_ms, 3),
             "deviceMs": round(self.device_ms, 3),
             "programs": self.programs,
+            "dispatchMs": round(self.dispatch_ms, 3),
+            "deferred": self.deferred,
         }
 
 
@@ -569,18 +580,33 @@ class FlightRecorder:
         reused_tokens: int,
         tick_seq: int,
         seq: int,
+        deferred: bool = False,
     ) -> None:
         """Record one admission round from its PhaseTimer (t0 = the
-        round's start, last = its end; the wall stamp is paired here,
-        at the end), and observe its split: admit_host_ms once, the
-        round's host segments, and admit_device_ms once per `device`
-        segment, which is once per admission program call."""
+        round's start, last = its settle's end; the wall stamp is
+        paired here, at the end), and observe its split: admit_host_ms
+        once, the round's host segments, and admit_device_ms once per
+        `device` segment, which is once per admission program call:
+        the program's OWN time on the device, from the later of its
+        launch's return and the wait before it ending (the tick in
+        flight, or the program before it, leaving the device) to its
+        first tokens being ready. Where the host only waited that is
+        the `device` segment itself; where it worked on while the
+        program ran (a seat, the next tick's dispatch) the segment is
+        what was left of the program's time when the host came to wait
+        for it."""
         if not self.enabled:
             return
-        device = [
-            (end - start) * 1000.0
-            for phase, start, end in timer.segments() if phase == "device"
-        ]
+        device, launched, free_at = [], [], timer.t0
+        for phase, start, end in timer.segments():
+            if phase == "launch":
+                launched.append(end)
+            elif phase == "device":
+                began = launched[len(device)] if (
+                    len(device) < len(launched)) else start
+                device.append((end - max(began, free_at)) * 1000.0)
+            if phase in ("tick_wait", "device"):
+                free_at = end
         host_ms = sum(timer.acc.get(p, 0.0) for p in ADMIT_HOST_MARKS)
         with self._lock:
             self._hists["admit_host_ms"].observe(host_ms)
@@ -602,6 +628,8 @@ class FlightRecorder:
             tick_wait_ms=timer.acc.get("tick_wait", 0.0),
             device_ms=timer.acc.get("device", 0.0),
             programs=len(device),
+            dispatch_ms=timer.acc.get("dispatch", 0.0),
+            deferred=deferred,
         ))
 
     def note_handoff(

@@ -1103,7 +1103,7 @@ class Sidecar:
                     trace_ids=a.trace_ids, tick_seq=a.tick_seq,
                     source=a.source, host_ms=a.host_ms,
                     tick_wait_ms=a.tick_wait_ms, device_ms=a.device_ms,
-                    programs=a.programs,
+                    programs=a.programs, dispatch_ms=a.dispatch_ms, deferred=a.deferred,
                 )
                 for a in admissions
             ],

@@ -232,6 +232,7 @@ def _admission_events(admissions: list, pid: int, events: list) -> None:
                     "seq", "family", "rows", "promptTokens",
                     "reusedTokens", "traceIds", "tickSeq", "source",
                     "hostMs", "tickWaitMs", "deviceMs", "programs",
+                    "dispatchMs", "deferred",
                 ) if k in adm
             },
         })

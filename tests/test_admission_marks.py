@@ -1,15 +1,20 @@
-"""The admission round, timed from inside (PR 39, marker `obs`).
+"""The admission round, timed from inside (PR 39, marker `obs`; the
+order of the marks since PR 52: tests/test_deferred_first.py has the
+deferred order through a whole batcher).
 
   * The round's PhaseTimer is marked by every admission program call
-    (build / launch / tick_wait / device) and activation loop
-    (activate): contiguous, so the marks sum to the round's duration.
-  * `tick_wait` ends when the tick in flight has left the device, so
-    `device` is the admission program alone; it is taken once a round,
-    and the tick's own failure is not raised in the round.
-  * `admit_device_ms` is observed once per program call and
-    `admit_host_ms` once per round, through stats(), the proto, the
-    /debug ring and /metrics; the profiler's spans carry the round's
-    seq and the program's family, rows, chunks and tokens.
+    (build / launch), seat and settle (activate) and wait (tick_wait /
+    device): contiguous, so the marks sum to the round's duration.
+  * Nothing is read at the launch: the first tokens come back as the
+    device's array, and the settle waits. `tick_wait` ends when the
+    tick in flight has left the device, so `device` is the admission
+    program alone; it is taken once a round, and the tick's own
+    failure is not raised in the round. A second program of a round
+    is launched when the first has left the device.
+  * `admit_device_ms` is observed once per program call (the program's
+    own time) and `admit_host_ms` once per round, through stats(), the
+    proto, the /debug ring and /metrics; the profiler's spans carry the
+    round's seq and the program's family, rows, chunks and tokens.
 """
 
 import asyncio
@@ -20,7 +25,7 @@ import pytest
 
 from ggrmcp_tpu.core.config import MeshConfig, ServingConfig
 from ggrmcp_tpu.models import llama
-from ggrmcp_tpu.serving.batching import ContinuousBatcher
+from ggrmcp_tpu.serving.batching import ContinuousBatcher, _SeatedRound
 from ggrmcp_tpu.serving.engine import GenerationEngine
 from ggrmcp_tpu.serving.flight_recorder import (
     ADMIT_HOST_MARKS,
@@ -54,101 +59,164 @@ class _Tick:
 
 def _round(inflight=()) -> ContinuousBatcher:
     """Just enough of a batcher for one admission round's program
-    calls: the round's state, no tables to sync, `inflight` ticks."""
+    calls, seats and settle: the round's state, no tables to sync,
+    `inflight` ticks, a four-slot token feedback."""
+    import jax.numpy as jnp
+
     b = ContinuousBatcher.__new__(ContinuousBatcher)
     b._paged = False
     b._cache_at_risk = False
     b.cache = None
     b._inflight = [(t, None, [], None) for t in inflight]
-    b._adm_timer = PhaseTimer()
-    b._adm_span = {"seq": 1, "tick": 1}
+    b._seated = None
+    b._round = _SeatedRound(
+        PhaseTimer(), {"seq": 1, "tick": 1},
+        inflight[-1] if inflight else None)
+    b._cur_dev = b._gstate_dev = jnp.zeros((4,), jnp.int32)
     b._adm_chunk_run = b._admit_run = 0
     return b
 
 
 def _program(b, seen: list, first=(7,)):
-    """One program call whose launch notes the at-risk flag."""
+    """One program call whose launch notes the at-risk flag, and the
+    seat of its (no) rows."""
+    import jax.numpy as jnp
+
     def launch():
         seen.append(b._cache_at_risk)
-        return np.asarray(first, np.int32), "cache"
+        return jnp.asarray(first, jnp.int32), "cache"
 
-    return b._admission_program(
+    got = b._admission_program(
         launch, "single", rows=1, chunks=1, tokens=3, width=4)
+    b._activate_rows([], got, np.full((len(first),), 4, np.int32))
+    return got
+
+
+def _marks(b) -> list:
+    return [p for p, _ in b._round.timer.marks]
 
 
 class TestTheRoundsMarks:
-    def test_no_tick_in_flight_device_starts_at_the_launchs_return(self):
+    def test_nothing_is_read_at_the_launch(self):
         b, seen = _round(), []
         first = _program(b, seen)
-        assert first.tolist() == [7] and b.cache == "cache"
-        # The donating call ran with the cache flagged, and the flag
-        # is cleared once `first` is on the host.
-        assert seen == [True] and b._cache_at_risk is False
-        assert [p for p, _ in b._adm_timer.marks] == [
-            "build", "launch", "device"]
+        # The device's array, not a host copy; the round is seated and
+        # the cache stays flagged until the settle has waited.
+        assert not isinstance(first, np.ndarray) and b.cache == "cache"
+        assert seen == [True] and b._cache_at_risk is True
+        assert b._seated is b._round
+        assert _marks(b) == ["build", "launch", "activate"]
+        b._settle_round()
+        assert b._seated is None and b._cache_at_risk is False
+        # A settled round holds nothing on the device.
+        assert b._round.programs[0].first is None
+        # No tick in flight: device starts where the seat ended.
+        assert _marks(b) == ["build", "launch", "activate", "device", "activate"]
 
     def test_device_starts_when_the_tick_in_flight_is_ready(self):
         tick = _Tick(after=0.05)
         b = _round([tick])
         _program(b, [])
-        segs = {p: (s, e) for p, s, e in b._adm_timer.segments()}
-        assert list(segs) == ["build", "launch", "tick_wait", "device"]
+        assert tick.waits == 0  # the round itself waited for nothing
+        b._settle_round()
+        segs = {p: (s, e) for p, s, e in b._round.timer.segments()}
+        assert list(segs) == [
+            "build", "launch", "activate", "tick_wait", "device"]
         # tick_wait ends, and device starts, at the tick's readiness.
         assert segs["tick_wait"][1] == segs["device"][0]
         assert segs["device"][0] >= tick.ready_at
         assert segs["device"][0] - tick.ready_at < 0.02
-        assert b._adm_timer.acc["tick_wait"] >= 40.0
+        assert b._round.timer.acc["tick_wait"] >= 40.0
         # Nothing was consumed: the tick is still in flight.
         assert len(b._inflight) == 1 and tick.waits == 1
 
-    def test_a_second_program_call_does_not_wait_for_the_tick_again(self):
+    def test_a_second_program_waits_for_the_first_and_not_for_the_tick_again(self):
         tick = _Tick(after=0.0)
         b = _round([tick])
         _program(b, [])
-        b._activate_rows([])
         _program(b, [])
         assert tick.waits == 1
-        assert [p for p, _ in b._adm_timer.marks] == [
-            "build", "launch", "tick_wait", "device", "activate",
-            "build", "launch", "device"]
+        # The second launch came after the wait for the first program
+        # (one admission program's temporaries at a time).
+        assert _marks(b) == [
+            "build", "launch", "activate",
+            "build", "tick_wait", "device", "launch", "activate"]
+        assert [p.waited for p in b._round.programs] == [True, False]
+        b._settle_round()
+        assert tick.waits == 1
+        assert _marks(b)[8:] == ["device", "activate"]
 
     def test_the_newest_tick_in_flight_is_the_one_waited_for(self):
         old, new = _Tick(after=0.0), _Tick(after=0.0)
         b = _round([old, new])
         _program(b, [])
+        b._settle_round()
         assert (old.waits, new.waits) == (0, 1)
 
     def test_the_ticks_failure_is_not_raised_in_the_round(self):
         tick = _Tick(after=0.0, fail=True)
         b = _round([tick])
-        assert _program(b, []).tolist() == [7]
-        assert tick.waits == 1 and "tick_wait" in b._adm_timer.acc
+        _program(b, [])
+        b._settle_round()
+        assert b._seated is None and b._round.programs[0].waited
+        assert tick.waits == 1 and "tick_wait" in b._round.timer.acc
         # The collect still finds the tick, and raises its failure.
         assert b._inflight[0][0] is tick
 
     def test_the_marks_sum_to_the_rounds_duration(self):
         b = _round([_Tick(after=0.01)])
         _program(b, [])
-        b._activate_rows([])
         _program(b, [])
-        b._activate_rows([])
-        timer = b._adm_timer
+        b._settle_round()
+        timer = b._round.timer
         assert set(timer.acc) == MARKS
         assert sum(timer.acc.values()) == pytest.approx(
             (timer.last - timer.t0) * 1000.0, abs=1e-9)
         rec = FlightRecorder()
         rec.note_admission(timer, "single", [], 2, 6, 0, tick_seq=1, seq=1)
         [adm] = rec.admission_snapshot()
-        assert adm.programs == 2
+        assert adm.programs == 2 and not adm.deferred
         assert adm.host_ms == pytest.approx(
             sum(timer.acc[p] for p in ADMIT_HOST_MARKS))
         assert adm.host_ms + adm.tick_wait_ms + adm.device_ms == (
             pytest.approx(adm.duration_ms, abs=1e-9))
+        assert adm.dispatch_ms == 0.0
         stats = rec.histogram_stats()
         assert stats["admit_device_ms_count"] == 2
-        assert stats["admit_device_ms_sum"] == pytest.approx(adm.device_ms)
+        # The first program's own time is the wait for it (the host
+        # had nothing else to do); the second's runs from its launch's
+        # return, through its seat, to its first tokens.
+        segs = timer.segments()
+        [seat2] = [e - s for p, s, e in segs[-3:] if p == "activate"][:1]
+        assert stats["admit_device_ms_sum"] == pytest.approx(
+            adm.device_ms + seat2 * 1000.0)
         assert stats["admit_host_ms_count"] == 1
         assert stats["admit_host_ms_sum"] == pytest.approx(adm.host_ms)
+
+    def test_a_deferred_round_names_the_gap_and_keeps_it_out_of_device(self):
+        b = _round([_Tick(after=0.0)])
+        _program(b, [])
+        b._round.timer.mark("activate")  # the way out
+        time.sleep(0.01)  # the loop's hop and the tick's dispatch
+        b._round.timer.mark("dispatch")
+        b._settle_round()
+        timer = b._round.timer
+        assert _marks(b) == [
+            "build", "launch", "activate", "activate", "dispatch",
+            "tick_wait", "device", "activate"]
+        rec = FlightRecorder()
+        rec.note_admission(
+            timer, "single", [], 1, 3, 0, tick_seq=1, seq=1, deferred=True)
+        [adm] = rec.admission_snapshot()
+        assert adm.deferred and adm.dispatch_ms >= 10.0
+        assert (adm.host_ms + adm.tick_wait_ms + adm.device_ms
+                + adm.dispatch_ms) == pytest.approx(adm.duration_ms, abs=1e-9)
+        # The program's own time starts where the wait before it ended,
+        # after the gap: none of the dispatch is in it.
+        stats = rec.histogram_stats()
+        assert stats["admit_device_ms_sum"] == pytest.approx(adm.device_ms)
+        assert adm.to_dict()["deferred"] is True
+        assert adm.to_dict()["dispatchMs"] == round(adm.dispatch_ms, 3)
 
 
 @pytest.fixture(scope="module")
@@ -213,6 +281,8 @@ class TestThroughABatcher:
             await batcher.stop()
         first, second = batcher.recorder.admission_snapshot()
         assert first.tick_wait_ms == 0.0 and second.tick_wait_ms > 0.0
+        # The second round was settled behind the tick's dispatch.
+        assert second.deferred and second.dispatch_ms > 0.0
         self._assert_split(batcher)
 
     @staticmethod
@@ -225,17 +295,25 @@ class TestThroughABatcher:
             r.programs for r in records) >= len(records)
         assert stats["admit_host_ms_count"] == len(records) == (
             stats["admit_rounds"])
-        # admit phase >= host + device: what is left is the wait for
-        # the tick in flight (and the rounds no tick has carried yet).
+        assert stats["admit_rounds_deferred"] == sum(
+            r.deferred for r in records)
+        # The admit phase holds a round's time up to its return, and
+        # its settle where no tick was dispatched in between; a deferred
+        # round's settle (its waits among it) lies in that tick's wait
+        # phase.
         admit = stats["tick_phase_admit_ms"] + batcher._admit_phase_ms
-        split = stats["admit_host_ms_sum"] + stats["admit_device_ms_sum"]
-        assert admit >= split - 0.05 > 0
-        assert admit - split == pytest.approx(
-            sum(r.tick_wait_ms for r in records), abs=0.05)
+        whole = sum(r.duration_ms for r in records if not r.deferred)
+        assert admit >= whole - 0.05 and admit > 0
+        if not any(r.deferred for r in records):
+            assert admit == pytest.approx(whole, abs=0.05)
+        # A program's own time is never less than the host's wait for
+        # it.
+        assert stats["admit_device_ms_sum"] >= sum(
+            r.device_ms for r in records) - 1e-6
         for r in records:
             assert r.programs >= 1 and r.device_ms > 0 and r.host_ms > 0
-            assert r.host_ms + r.tick_wait_ms + r.device_ms == (
-                pytest.approx(r.duration_ms, abs=1e-6))
+            assert (r.host_ms + r.tick_wait_ms + r.device_ms
+                    + r.dispatch_ms) == pytest.approx(r.duration_ms, abs=1e-6)
 
     async def test_the_record_carries_the_split_to_dict_and_proto(
         self, mistral_engine
@@ -248,6 +326,7 @@ class TestThroughABatcher:
         assert d["programs"] == 1
         assert d["hostMs"] + d["tickWaitMs"] + d["deviceMs"] == (
             pytest.approx(d["durationMs"], abs=0.005))
+        assert d["deferred"] is False and d["dispatchMs"] == 0.0
         wire = serving_pb2.AdmissionRecord(
             seq=adm.seq, duration_ms=adm.duration_ms, family=adm.family,
             host_ms=adm.host_ms, tick_wait_ms=adm.tick_wait_ms,
@@ -322,16 +401,21 @@ class TestSpans:
         monkeypatch.setattr(tracing, "capture_running", True)
         await _run(mistral_engine, [[list(range(3, 83))]], prefill_chunk=32)
         admit = [e for e in events if e[1].startswith("ggrmcp.admit")]
-        # One round, one program call: the program and the activation
-        # are children of the round, the device span of the program.
+        # One round, one program call: the launch and the seat are
+        # children of the round; the settle (here inside the round: the
+        # loop is not pipelined) holds the wait and the activation.
         assert [(kind, name) for kind, name, _ in admit] == [
             ("enter", "ggrmcp.admit"),
             ("enter", "ggrmcp.admit.program"),
-            ("enter", "ggrmcp.admit.device"),
-            ("exit", "ggrmcp.admit.device"),
             ("exit", "ggrmcp.admit.program"),
             ("enter", "ggrmcp.admit.activate"),
             ("exit", "ggrmcp.admit.activate"),
+            ("enter", "ggrmcp.admit.settle"),
+            ("enter", "ggrmcp.admit.device"),
+            ("exit", "ggrmcp.admit.device"),
+            ("enter", "ggrmcp.admit.activate"),
+            ("exit", "ggrmcp.admit.activate"),
+            ("exit", "ggrmcp.admit.settle"),
             ("exit", "ggrmcp.admit"),
         ]
         by_name = {name: stats for kind, name, stats in admit}
@@ -339,7 +423,8 @@ class TestSpans:
             "seq": 1, "tick": 1, "family": "chunked",
             "rows": 1, "chunks": 3, "tokens": 80, "chunk_tokens_run": 96,
         }
-        for name in ("ggrmcp.admit.device", "ggrmcp.admit.activate"):
+        for name in ("ggrmcp.admit.device", "ggrmcp.admit.activate",
+                     "ggrmcp.admit.settle"):
             assert by_name[name] == by_name["ggrmcp.admit"] == {
                 "seq": 1, "tick": 1}
 
@@ -436,9 +521,10 @@ class TestTheChunksThatRan:
 
 
 def test_the_clock_check_pairs_a_device_span_with_its_module():
-    """scripts/admit_clock_check.py on a hand-made trace: a program
-    call whose device span starts when the tick's module ends, and one
-    the capture cut (its module is not in the trace)."""
+    """scripts/admit_clock_check.py on a hand-made trace: a settle whose
+    device span starts when the tick's module ends and ends with the
+    program's module, a second program the host came to 30 ms into its
+    run, and a span the capture cut (no module ends in it)."""
     from benchmark import xplane
     from scripts.admit_clock_check import pair
 
@@ -449,19 +535,25 @@ def test_the_clock_check_pairs_a_device_span_with_its_module():
 
     planes = xplane.parse(xplane.dump([
         xplane.Plane("/host:CPU", [xplane.Line("batcher", [
-            ev("ggrmcp.admit", 0, 105), ev("ggrmcp.admit.program", 5, 95),
+            ev("ggrmcp.admit", 0, 12), ev("ggrmcp.admit.program", 5, 3),
+            ev("ggrmcp.admit.activate", 8, 1),
+            ev("ggrmcp.tick.dispatch", 13, 5),
+            ev("ggrmcp.admit.settle", 18, 86),
             ev("ggrmcp.admit.device", 20, 80),
             ev("ggrmcp.admit.activate", 100, 4),
-            ev("ggrmcp.admit.program", 200, 50),
-            ev("ggrmcp.admit.device", 210, 40),
+            ev("ggrmcp.admit.device", 230, 20),
+            ev("ggrmcp.admit.device", 400, 40),
         ])]),
         xplane.Plane("/device:TPU:0", [xplane.Line("XLA Modules", [
             ev("jit__tick_impl(1)", 0, 20),
             ev("jit__admit_chunked_impl(2)", 20.25, 79.5),
+            ev("jit__admit_paged_pfx_impl(3)", 200, 49.5),
         ])]),
     ]))
     pairs, cut = pair(planes)
     assert cut == 1
-    [(device_ms, module_ms, start_ms, name)] = pairs
+    (device_ms, module_ms, start_ms, name), second = pairs
     assert (device_ms, module_ms) == (80.0, 79.5)
     assert start_ms == pytest.approx(0.25) and "admit_chunked" in name
+    # The host waited for the last 20 ms of a 49.5 ms program.
+    assert second[:2] == (20.0, 49.5) and second[2] == pytest.approx(-30.0)

@@ -543,7 +543,7 @@ class TestTraceAnnotation:
             # The round's children (tests/test_admission_marks.py pins
             # their attributes and nesting).
             "ggrmcp.admit.program", "ggrmcp.admit.device",
-            "ggrmcp.admit.activate",
+            "ggrmcp.admit.activate", "ggrmcp.admit.settle",
         }
         # Dispatch and collect of one tick carry the same seq, the key
         # into the tick ring.
