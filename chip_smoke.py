@@ -50,6 +50,15 @@ next starts (this parent never imports JAX or the package):
               decode steps over the same K/V as pages and the same
               state in a pool (the state update, the paged-decode
               kernel); prints a chunk's and a 32-row decode step's time.
+  window      one period of SmallThinker-21BA3B-Instruct at its published
+              widths (models/smallthinker.py: a full layer without
+              positional encoding, three window-4,096 layers, 64 ReGLU
+              experts) against benchmark/reference_smallthinker.py's
+              float32 logits: ten 512-token chunks (past the window),
+              8 decode steps over pages of two kinds with the window
+              layers' pages behind the window unmapped and poisoned,
+              and a re-admission on both kinds' views. Only with
+              `--legs window`.
   experts     the routed experts of one keye layer at its published
               widths (2,048 x 768, 128 experts, top 8): the grouped
               SwiGLU Pallas kernel (ops/experts.py) against the XLA
@@ -790,6 +799,130 @@ def jamba_leg_child(rehearsal: bool) -> None:
     }), flush=True)
 
 
+def window_leg_child(rehearsal: bool) -> None:
+    """Runs in the child. One period of SmallThinker-21BA3B-Instruct at
+    its published widths (`smallthinker.forward`: a full layer without
+    positional encoding, three window-4,096 layers with RoPE, 64 ReGLU
+    experts routed from the attention block's input), against
+    `reference_smallthinker`'s float32 logits of the same tokens on the
+    same drawn weights: ten 512-token chunks into a contiguous mini
+    cache (the prefill kernel, with the window and without), then 8
+    decode steps over the same K/V as pages of TWO kinds, the window
+    layers' pages behind the window unmapped and poisoned (the
+    paged-decode kernel's window walk), then a re-admission: both
+    kinds' views gathered back into a mini and a 128-token suffix run
+    on it. The statistic is the rms of the logits' difference over the
+    rms of the reference's logits about their mean."""
+    import dataclasses
+
+    from ggrmcp_tpu.utils.jaxenv import init_runtime
+
+    init_runtime("chip_smoke window leg")
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import reference_smallthinker as ref_mod
+    from ggrmcp_tpu.models import llama
+    from ggrmcp_tpu.models import smallthinker as st
+    from ggrmcp_tpu.ops import attention as attn_ops
+
+    dev = jax.devices()[0]
+    if rehearsal:
+        name, chunk, chunks, suffix, tol = "tiny-smallthinker", 64, 3, 32, 1e-4
+        path = os.path.join(
+            HERE, "tests", "benchmark", "rehearsal_smallthinker", "benchmark",
+            "configs", "tiny-smallthinker-cpu.json")
+    else:
+        check(dev.platform == "tpu", f"window leg on {dev.platform}")
+        name, chunk, chunks, suffix, tol = (
+            "smallthinker-21b-a3b-8l", 512, 10, 128, 0.1)
+        path = os.path.join(
+            HERE, "benchmark", "configs",
+            "smallthinker-21b-a3b-bf16-1chip.json")
+    with open(path) as f:
+        model = dict(json.load(f), num_hidden_layers=4)
+    cfg = dataclasses.replace(st.CONFIGS[name], num_layers=4)
+    window, page, steps = cfg.sliding_window, 16, 8
+    filled = chunk * chunks
+    check(filled > window + chunk, "the prefill does not pass the window")
+    s_max = -(-(filled + steps + suffix) // chunk) * chunk
+    width = s_max // page
+    ids = np.random.RandomState(5).randint(
+        3, cfg.vocab_size, filled + steps + suffix)
+    params = jax.jit(lambda k: st.init_params(k, cfg))(jax.random.PRNGKey(0))
+
+    t0 = time.monotonic()
+    step = jax.jit(
+        lambda p, t, c: st.forward(p, cfg, t, c), donate_argnums=(2,))
+    mini, got = llama.KVCache.create(cfg, 1, s_max), []
+    for at in range(0, filled, chunk):
+        logits, mini = step(params, jnp.asarray(ids[None, at:at + chunk]), mini)
+        got.append(np.asarray(logits[0]))
+    # the same K and V as pages of two kinds: every block of the full
+    # layer mapped, of the window layers those a query at `filled` can
+    # read; their other pages poisoned
+    first = max(0, filled - window + 1) // page
+    gone = (jnp.arange(width) < first)[None, :, None, None, None]
+    table = jnp.arange(width, dtype=jnp.int32)[None]
+    arenas = {}
+    for plane in ("k", "v"):
+        full, tail = llama.split_kinds(cfg, getattr(mini, plane))
+        arenas[plane] = full.reshape(full.shape[0], width, page, *full.shape[3:])
+        tail = tail.reshape(tail.shape[0], width, page, *tail.shape[3:])
+        arenas["w" + plane] = jnp.where(gone, 1e4, tail).astype(tail.dtype)
+    paged = llama.PagedKVCache.create(
+        cfg, 1, s_max, width, page, window_pages=width)._replace(
+        k=arenas["k"], v=arenas["v"], table=table, length=mini.length,
+        window=llama.WindowArena(
+            arenas["wk"], arenas["wv"],
+            jnp.where(jnp.arange(width) >= first, table, width)))
+    for i in range(filled, filled + steps):
+        logits, paged = step(params, jnp.asarray(ids[None, i:i + 1]), paged)
+        got.append(np.asarray(logits[0]))
+    views = [llama.join_kinds(cfg, [
+        llama.paged_view_layers(a, t) for a, t in (
+            (full, paged.table), (tail, paged.window.table))])
+        for full, tail in ((paged.k, paged.window.k),
+                           (paged.v, paged.window.v))]
+    again = llama.KVCache(
+        views[0], views[1], jnp.asarray([filled + steps], jnp.int32))
+    logits, _ = step(
+        params, jnp.asarray(ids[None, filled + steps:]), again)
+    got = np.concatenate(got + [np.asarray(logits[0])])
+    took = attn_ops.dispatch_counts
+    say(f"  window: {chunks} chunks of {chunk}, {steps} decode steps and a "
+        f"suffix of {suffix} compiled and ran in {time.monotonic() - t0:.1f} s "
+        f"(set-up, {dev.device_kind}); programs: flash {took['flash']}, "
+        f"paged_decode {took['paged_decode']}, grouped_experts "
+        f"{took['grouped_experts']}")
+    if not rehearsal:
+        check(took["flash"] >= 2 and took["paged_decode"] >= 2
+              and took["grouped_experts"] >= 1,
+              f"the kernels did not run: {dict(took)}")
+    del params, mini, paged, again, views, arenas, logits
+
+    weights = ref_mod.to_host(jax, model, ref_mod.family_init_weights(jax, model))
+    want = np.asarray(ref_mod.logits_of(jax, model, weights, ids.tolist()))
+    check(bool(np.isfinite(got).all()), "non-finite logits")
+    for label, lo, hi in (
+            ("chunks inside the window", 0, window),
+            ("chunks past the window", window, filled),
+            ("decode steps on two kinds of page", filled, filled + steps),
+            ("the re-admitted suffix", filled + steps, len(ids))):
+        ref = want[lo:hi]
+        err = float(np.sqrt(((got[lo:hi] - ref) ** 2).mean())
+                    / np.sqrt(((ref - ref.mean(-1, keepdims=True)) ** 2).mean()))
+        agree = float((got[lo:hi].argmax(-1) == ref.argmax(-1)).mean())
+        say(f"  {label}: rms error {err:.2e} of the reference logits' spread "
+            f"(limit {tol:g}); the argmax agrees at {agree:.3f} of positions")
+        check(err < tol, f"{label}: {err:.3e} beyond {tol:g}")
+    print("LEG_RESULT " + json.dumps({
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(jax.devices()),
+    }), flush=True)
+
+
 def experts_leg_child(rehearsal: bool) -> None:
     """Runs in the child. The routed experts of one layer at keye's
     published widths (2,048 x 768, 128 experts, top 8; two layers of
@@ -1292,6 +1425,8 @@ def main() -> int:
                     help=argparse.SUPPRESS)
     ap.add_argument("--child-jamba", action="store_true",
                     help=argparse.SUPPRESS)
+    ap.add_argument("--child-window", action="store_true",
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.child_kernel:
         kernel_leg_child(args.cpu_rehearsal)
@@ -1307,6 +1442,9 @@ def main() -> int:
         return 0
     if args.child_jamba:
         jamba_leg_child(args.cpu_rehearsal)
+        return 0
+    if args.child_window:
+        window_leg_child(args.cpu_rehearsal)
         return 0
 
     rehearsal = args.cpu_rehearsal
@@ -1335,6 +1473,12 @@ def main() -> int:
             "jamba", "the whole hybrid model (26 Mamba layers, 2 attention "
             "layers), a chunk and 8 decode steps, vs the float32 reference",
             rehearsal)
+    if "window" in legs:  # on request (~4 min on the chip)
+        run_child_leg(
+            "window", "one SmallThinker period (a full layer, three "
+            "window layers, ReGLU experts), chunks past the window, decode "
+            "on pages of two kinds and a re-admission, vs the float32 "
+            "reference", rehearsal)
     if "experts" in legs:  # on request: the keye and sparse legs run the
         # same kernel inside their layers, against the float32 layer
         run_child_leg(

@@ -200,6 +200,21 @@ _SERVING_HELP = {
         "row-state snapshot entries held (the slots' own not counted)",
     "state_pool_total":
         "row-state snapshot entries the pool holds at most",
+    # Two kinds of page (a model whose window layers keep their pages
+    # in a second arena under a free rule by position).
+    "kv_window_pages_total": "window-layer KV arena size in pages",
+    "kv_window_pages_in_use":
+        "window-layer KV pages resident (live + cached for reuse)",
+    "paged_window_pages_freed":
+        "window-layer pages rows let go of behind their window",
+    "paged_window_hits_refused":
+        "prefix hits cut short or dropped for want of a window page",
+    "paged_window_pages_mapped":
+        "window-layer pages mapped for rows to write",
+    "window_keys_read":
+        "keys the window layers' decode steps read (min(context, window))",
+    "window_keys_context":
+        "keys the contexts of those decode steps' rows hold",
     # Disaggregated prefill/decode serving (serving.role): the
     # sidecar→sidecar KV page-shipping plane. The role itself is a
     # string field and exports info-style beside mesh_shape.

@@ -9,19 +9,30 @@ the same indexer with its key as a THIRD plane of every page +
 softmax-routed experts, every layer an expert layer), "jamba" (runs of
 Mamba-1 state-space layers around a few attention layers without
 rotary: only those cache K/V, and every row keeps a recurrent state in
-a pool beside the pages), all five served by the same engine, and
-"bert" (embeddings).
+a pool beside the pages), "smallthinker" (one full-attention layer
+without positional encoding to three sliding-window layers with RoPE,
+each kind with an arena and a block table of its own, and ReGLU experts
+routed from the attention block's input), all six served by the same
+engine, and "bert" (embeddings).
 """
 
 from __future__ import annotations
 
 from typing import Any
 
-from ggrmcp_tpu.models import bert, jamba, keye, llama, mla_moe, moe
+from ggrmcp_tpu.models import (
+    bert,
+    jamba,
+    keye,
+    llama,
+    mla_moe,
+    moe,
+    smallthinker,
+)
 
 _FAMILIES = {
     "llama": llama, "moe": moe, "mla_moe": mla_moe, "keye": keye,
-    "jamba": jamba, "bert": bert,
+    "jamba": jamba, "smallthinker": smallthinker, "bert": bert,
 }
 
 

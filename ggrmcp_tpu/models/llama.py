@@ -26,6 +26,7 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ggrmcp_tpu.models import common
@@ -82,6 +83,18 @@ class LlamaConfig(common.ModelConfig):
         of which only some layers attend overrides this
         (models/jamba.py)."""
         return self.num_layers
+
+    @property
+    def cache_kinds(self) -> tuple:
+        """The kinds of caching layer, (layers, window) each, in the
+        order the paged cache holds them: an arena and a block table a
+        kind (`PagedKVCache`), and a free rule a kind in
+        serving/pages.py. `None` keeps every position; a window W keeps
+        what a query can still read and lets go of the pages behind it.
+        One kind that keeps everything, here and in every family but
+        models/smallthinker.py (mistral's window masks, and its cache
+        still keeps every page: ROADMAP B1)."""
+        return ((self.cache_layers, None),)
 
     @property
     def row_state(self) -> tuple:
@@ -287,6 +300,18 @@ def cache_specs() -> KVCache:
     return KVCache(k=spec, v=spec, length=P(("data", "fsdp")))
 
 
+class WindowArena(NamedTuple):
+    """The pages of the caching layers of a second kind
+    (`cfg.cache_kinds[1]`: layers that attend a window), beside the
+    arena that keeps everything: their own K and V `[Lw, n_pages_w, page,
+    KVH, Dh]` and their own block table, whose entries behind a row's
+    window are unmapped (docs/paged_kv.md "Two kinds of page")."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    table: jnp.ndarray  # [B, S_max // page] int32; n_pages_w = unmapped
+
+
 class PagedKVCache(NamedTuple):
     """Paged KV plane (batching.paged_kv, docs/paged_kv.md): one arena
     of fixed-size pages per layer plus per-slot block tables. Positions
@@ -315,22 +340,35 @@ class PagedKVCache(NamedTuple):
     # the slots' entries first, then the snapshots'.
     state: tuple = ()
     state_rows: Any = None
+    # The second kind's arena and table (`cfg.cache_kinds`); None for a
+    # config of one kind, whose pytree has the leaves it always had.
+    # `k`, `v` and `table` above are then the first kind's alone.
+    window: Any = None
 
     @classmethod
     def create(
         cls, cfg: LlamaConfig, batch: int, max_len: int, n_pages: int,
-        page_size: int, kv_dtype: str = "",
+        page_size: int, kv_dtype: str = "", window_pages: int = 0,
     ) -> "PagedKVCache":
         assert max_len % page_size == 0, "page_size must divide max_len"
         width = max_len // page_size
+        kinds = cfg.cache_kinds
         k, v, *extra = _zero_planes(
-            cfg, (cfg.cache_layers, n_pages, page_size), kv_dtype)
+            cfg, (kinds[0][0], n_pages, page_size), kv_dtype)
+        window = None
+        if len(kinds) > 1:
+            assert len(kinds) == 2 and not extra and window_pages > 0, kinds
+            window = WindowArena(
+                *_zero_planes(
+                    cfg, (kinds[1][0], window_pages, page_size), kv_dtype),
+                jnp.full((batch, width), window_pages, jnp.int32))
         return cls(
             k=k, v=v,
             table=jnp.full((batch, width), n_pages, jnp.int32),
             length=jnp.zeros((batch,), jnp.int32),
             extra=tuple(extra),
             state=zero_state(cfg, STATE_ENTRIES_PER_SLOT * batch),
+            window=window,
         )
 
 
@@ -364,6 +402,29 @@ def map_planes(fn, cache, *others, **fields):
         kv_map(fn, *each) for each in zip(
             cache_planes(cache), *(cache_planes(o) for o in others))
     ), **fields)
+
+
+def _kind_layers(cfg) -> list:
+    """The model's layer indices of each kind, in `cfg.cache_kinds`
+    order (`cfg.layer_kinds` names every layer's: "full" | "window")."""
+    return [
+        [i for i, kind in enumerate(cfg.layer_kinds) if kind == name]
+        for name in ("full", "window")]
+
+
+def split_kinds(cfg, plane):
+    """A `[layers, ...]` plane in the model's layer order (a contiguous
+    cache's) as its kinds' `[layers of the kind, ...]` planes."""
+    return tuple(
+        kv_map(lambda a, at=np.asarray(at): a[at], plane)
+        for at in _kind_layers(cfg))
+
+
+def join_kinds(cfg, planes):
+    """`split_kinds` undone: the kinds' planes back in the model's
+    layer order."""
+    order = np.argsort(np.concatenate(_kind_layers(cfg)))
+    return kv_map(lambda *parts: jnp.concatenate(parts)[order], *planes)
 
 
 def paged_view(arena, table: jnp.ndarray, layer: jnp.ndarray):
@@ -641,9 +702,9 @@ def attention_block(
                     window=cfg.sliding_window, use_flash=use_flash,
                     flash_mesh=flash_mesh,
                 )
-            if paged_out is None:
-                k_all = paged_view(cache_k, page_table, layer)
-                v_all = paged_view(cache_v, page_table, layer)
+            if paged_out is None:  # (a float8 arena: read in q's dtype)
+                k_all = paged_view(cache_k, page_table, layer).astype(q.dtype)
+                v_all = paged_view(cache_v, page_table, layer).astype(q.dtype)
         kv_len = cache_len + s
         q_offset = cache_len
         k_positions = None
@@ -706,9 +767,12 @@ def attention_block(
             use_flash = False  # materializing bf16 KV for the Pallas
             # kernel would forfeit the int8 bandwidth win
         else:
-            cache_k = cache_k.at[batch_idx, write_pos].set(k)
-            cache_v = cache_v.at[batch_idx, write_pos].set(v)
-            k_all, v_all = cache_k, cache_v
+            cache_k = cache_k.at[batch_idx, write_pos].set(
+                k.astype(cache_k.dtype))
+            cache_v = cache_v.at[batch_idx, write_pos].set(
+                v.astype(cache_v.dtype))
+            # (a float8 cache, models/smallthinker.py: read in q's dtype)
+            k_all, v_all = cache_k.astype(q.dtype), cache_v.astype(q.dtype)
             k_step, v_step = k, v
         kv_len = cache_len + s
         q_offset = cache_len

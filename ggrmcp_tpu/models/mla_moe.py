@@ -754,8 +754,8 @@ def attention_block(
 # ---------------------------------------------------------------------------
 
 
-def _swiglu(x, w_gate, w_up, w_down):
-    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+def _swiglu(x, w_gate, w_up, w_down, act: str = "silu"):
+    return (experts_ops.ACTIVATIONS[act](x @ w_gate) * (x @ w_up)) @ w_down
 
 
 def _task_block(pairs: int, experts: int) -> int:
@@ -787,7 +787,8 @@ def task_map(counts, block: int, max_tasks: int):
     return task_end[-1], ex, starts[ex] + j * block, counts[ex] - j * block
 
 
-def _looped_tasks(xt, order, counts, banks, layer, k: int, block: int):
+def _looped_tasks(xt, order, counts, banks, layer, k: int, block: int,
+                  act: str = "silu"):
     """The block tasks as a `fori_loop` in XLA, three sliced matmuls a
     task over the sorted rows; a task's tail rows are the next
     expert's and stay (`keep`). Returns every pair's result in the
@@ -806,7 +807,7 @@ def _looped_tasks(xt, order, counts, banks, layer, k: int, block: int):
                 w, (layer, ex, 0, 0), (1, 1, *w.shape[2:])
             ).reshape(w.shape[2:])
             for w in banks
-        ))
+        ), act)
         old = jax.lax.dynamic_slice(ys, (row0, 0), (block, d))
         keep = rows < task_rows[i]  # the next expert's rows stay
         return jax.lax.dynamic_update_slice(
@@ -828,7 +829,8 @@ def _task_tiles(xt, order, counts, k: int, tile: int):
     return xt[order[at.reshape(-1)] // k], n_tasks, task_ex, task_rows
 
 
-def _grouped_tasks(xt, flat, order, counts, banks, layer, k: int, tile: int):
+def _grouped_tasks(xt, flat, order, counts, banks, layer, k: int, tile: int,
+                   act: str = "silu"):
     """The block tasks as one kernel (`ops.experts.grouped_swiglu`),
     `tile` rows a task (`_task_tiles`; padding rows are computed or
     skipped, never read back). A pair's result is read back from where
@@ -837,7 +839,7 @@ def _grouped_tasks(xt, flat, order, counts, banks, layer, k: int, tile: int):
     pairs, e = order.shape[0], counts.shape[0]
     rows, n_tasks, task_ex, task_rows = _task_tiles(xt, order, counts, k, tile)
     ys = experts_ops.grouped_swiglu(
-        rows, *banks, layer, n_tasks, task_ex, task_rows, block=tile)
+        rows, *banks, layer, n_tasks, task_ex, task_rows, block=tile, act=act)
     rank = jnp.zeros((pairs,), jnp.int32).at[order].set(
         jnp.arange(pairs, dtype=jnp.int32))
     starts = jnp.cumsum(counts) - counts
@@ -887,12 +889,13 @@ def routed_experts(
         flat = jnp.where(jnp.repeat(valid, k), flat, e)
     order = jnp.argsort(flat, stable=True)
     counts = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
+    act = getattr(cfg, "expert_act", "silu")  # the gate's activation
     if experts_ops.grouped_experts(xt, banks, use_flash, flash_mesh):
         y = _grouped_tasks(
             xt, flat, order, counts, banks, layer, k,
-            max(block, experts_ops.MIN_ROWS))
+            max(block, experts_ops.MIN_ROWS), act)
     else:
-        y = _looped_tasks(xt, order, counts, banks, layer, k, block)
+        y = _looped_tasks(xt, order, counts, banks, layer, k, block, act)
     out = (
         y.reshape(t, k, d).astype(jnp.float32) * weight[..., None]
     ).sum(1).astype(xt.dtype)
@@ -940,13 +943,17 @@ def route(xt, lp, cfg):
 
 def moe_ffn(
     x, lp, banks, layer, cfg, valid=None, use_flash=None, flash_mesh=None,
+    routing=None,
 ):
     """The routed experts of every token (`route`, `routed_experts`),
     plus the shared experts where the model has any. `use_flash` /
-    `flash_mesh`: the engine's word on kernels for its mesh."""
+    `flash_mesh`: the engine's word on kernels for its mesh. `routing`:
+    the tokens' (experts, weights) where the caller's router read
+    something else than `x` (models/smallthinker.py: the attention
+    block's input) and has routed them already."""
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
-    idx, weight = route(xt, lp, cfg)
+    idx, weight = route(xt, lp, cfg) if routing is None else routing
     out, stats = routed_experts(
         xt, idx, weight, None if valid is None else valid.reshape(b * s),
         banks, layer, cfg, use_flash, flash_mesh,

@@ -555,8 +555,15 @@ def _paged_decode_kernel(
     def walk(row):  # -> (first block, end block, pages)
         kv_len = len_ref[row]
         # A freed slot keeps its length and has its table unmapped.
-        live = (kv_len > 0) & (table_ref[row, 0] < n_pages)
+        if window is None:
+            live = (kv_len > 0) & (table_ref[row, 0] < n_pages)
         pages = jnp.minimum((kv_len + page - 1) // page, width)
+        if window is not None:
+            # A live row's table may be unmapped behind its window
+            # (serving/pages.py lets those pages go): the page of its
+            # newest key says whether it is mapped at all.
+            live = (kv_len > 0) & (
+                table_ref[row, jnp.maximum(pages - 1, 0)] < n_pages)
         end = (pages + block_pages - 1) // block_pages
         first = 0
         if window is not None:

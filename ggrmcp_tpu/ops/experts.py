@@ -52,6 +52,12 @@ logger = logging.getLogger(__name__)
 _SLOT_BYTES = 12 << 20
 
 
+# The gate's activation, by the name a config gives it
+# (`cfg.expert_act`): the kernel's body and the XLA loop
+# (`mla_moe._swiglu`) read the same table.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 # Rows of a task at the least: bf16 packs 16 rows a tile, and a row DMA
 # moves whole tiles.
 MIN_ROWS = 16
@@ -100,6 +106,7 @@ def _grouped_swiglu_kernel(
     *acc,  # with tiles of F: VMEM [block, D] float32
     block: int,
     ft: int,
+    act: str,
 ):
     """The whole walk in one grid step: units (task, tile of F) in
     order, the next unit's weights in flight while this one multiplies.
@@ -176,7 +183,7 @@ def _grouped_swiglu_kernel(
         when(fetch)(gate.wait)
         g = jnp.dot(x, wg_buf[w_slot], preferred_element_type=jnp.float32)
         when(fetch)(up.wait)
-        h = jax.nn.silu(g) * jnp.dot(
+        h = ACTIVATIONS[act](g) * jnp.dot(
             x, wu_buf[w_slot], preferred_element_type=jnp.float32)
         when(fetch)(down.wait)
         part = jnp.dot(
@@ -210,7 +217,7 @@ def _grouped_swiglu_kernel(
         pl.when(n >= back)(rows_out(n - back, (n - back) % 2).wait)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "interpret", "act"))
 def grouped_swiglu(
     x: jnp.ndarray,  # [max_tasks * block, D] — task i's rows at i * block
     w_gate: jnp.ndarray,  # [L, E, D, F]
@@ -223,16 +230,18 @@ def grouped_swiglu(
     *,
     block: int,
     interpret: bool = False,
+    act: str = "silu",
 ) -> jnp.ndarray:
-    """`(silu(x W_gate) * (x W_up)) W_down` of every block task
+    """`(act(x W_gate) * (x W_up)) W_down` of every block task
     (`mla_moe.task_map`) with its own expert's matrices `[layer,
     task_ex[i]]`, read in place out of the stacked banks. Row r of task
     i is row `i * block + r` of `x` and of the result; rows of a task
     past its expert's (`task_rows`) are computed like the others and
     the caller's to drop; tasks from `n_tasks` on are not computed and
     their rows of the result are undefined. Operands in the banks'
-    dtype, every product accumulated in float32, `silu(g) * u` in
-    float32. Compiled for the TPU unless `interpret=True` (CPU tests)
+    dtype, every product accumulated in float32, `act(g) * u` in
+    float32, `act` one of ACTIVATIONS (the caller's: SwiGLU's silu, or
+    relu for a ReGLU expert). Compiled for the TPU unless `interpret=True` (CPU tests)
     asks for the interpreter."""
     d, f = w_gate.shape[2:]
     max_tasks = task_ex.shape[0]
@@ -252,7 +261,8 @@ def grouped_swiglu(
     if ft != f:
         scratch.append(pltpu.VMEM((block, d), jnp.float32))
     return pl.pallas_call(
-        functools.partial(_grouped_swiglu_kernel, block=block, ft=ft),
+        functools.partial(
+            _grouped_swiglu_kernel, block=block, ft=ft, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(1,),

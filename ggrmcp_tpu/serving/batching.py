@@ -51,7 +51,12 @@ from ggrmcp_tpu.serving.flight_recorder import (
     FlightRecorder,
     PhaseTimer,
 )
-from ggrmcp_tpu.serving.pages import PageAllocator, PageExhaustedError
+from ggrmcp_tpu.serving.pages import (
+    PageAllocator,
+    PageExhaustedError,
+    WindowPages,
+    window_pages_per_slot,
+)
 from ggrmcp_tpu.serving.scheduler import (
     Scheduler,
     SchedulerQueue,
@@ -422,6 +427,9 @@ class ContinuousBatcher:
         # one prefix-reuse mechanism. The contiguous path stays the
         # off-mode so bit-identity is provable (tests/test_paged_kv.py).
         self._paged = getattr(self.cfg, "paged_kv", "off") == "on"
+        # Keys the window layers' decode steps read, and keys their
+        # rows hold in context (_window_release; 0 without such layers).
+        self.window_keys = {"read": 0, "context": 0}
         # ROW_STATE (the family's module says so, as for the flags
         # below): every row keeps a state that no position addresses,
         # in a pool that rides the cache (`cache.state`: the slots'
@@ -450,17 +458,40 @@ class ContinuousBatcher:
                 int(getattr(self.cfg, "paged_kv_pages", 0) or 0)
                 or b * self._table_width
             )
+            # A second kind of caching layer (`cfg.cache_kinds`: layers
+            # that attend a window) keeps its pages in an arena of its
+            # own, sized here from slots, window, chunk and page (no
+            # option: `paged_kv_pages` stays the arena that keeps
+            # everything), and serving/pages.py lets go of a row's
+            # pages behind its window as it decodes (docs/paged_kv.md
+            # "Two kinds of page"). The host maps a row's next pages
+            # `lookahead` positions ahead of what it has seen the row
+            # emit: the ticks in flight and the one being dispatched.
+            kinds = getattr(engine.cfg, "cache_kinds", ((0, None),))
+            self._window = kinds[1][1] if len(kinds) > 1 else None
+            self._window_layers = kinds[1][0] if self._window else 0
+            self._n_pages_w, window_pages = 0, None
+            if self._window:
+                lookahead = self._reserve + 1 + self._steps_per_tick
+                per_slot = window_pages_per_slot(
+                    self._window, self.cfg.prefill_chunk, page, lookahead)
+                self._n_pages_w = b * per_slot
+                window_pages = WindowPages(
+                    self._n_pages_w, page, slots=b,
+                    table_width=self._table_width, window=self._window,
+                    per_slot=per_slot, lookahead=lookahead,
+                    retain=self.cfg.prefill_chunk)
+            self._window_dirty = False
             self.pages = PageAllocator(
                 self._n_pages, page, slots=b,
                 table_width=self._table_width,
                 state_entries=(
                     (llama_mod.STATE_ENTRIES_PER_SLOT - 1) * b
                     if self._row_state else 0),
+                window=window_pages,
             )
             self._tables_dirty = False
-            self.cache = engine.make_paged_cache(
-                b, s_max, self._n_pages, page
-            )
+            self.cache = self._make_shared_cache()
             # Host-tier page pool (batching.paged_kv_host_bytes > 0,
             # docs/paged_kv.md "Host tier"): arena eviction demotes
             # page contents D2H into this byte-budgeted host pool and
@@ -495,6 +526,7 @@ class ContinuousBatcher:
         else:
             self.pages = None
             self.host_pool = None
+            self._window = None
             self.cache = engine.make_cache(b, s_max)
         if self._row_state:
             self._say_row_states()
@@ -794,7 +826,8 @@ class ContinuousBatcher:
             "kv_arena",
             lambda: (
                 *llama_mod.cache_planes(self.cache), self.cache.length,
-                *self.cache.state),
+                *self.cache.state,
+                *(getattr(self.cache, "window", None) or ())),
             scope=ledger_scope,
         )
         engine.ledger.register(
@@ -844,7 +877,7 @@ class ContinuousBatcher:
         if self._paged:
             return self.engine.make_paged_cache(
                 len(self.slots), self.max_seq, self._n_pages,
-                self._page_size,
+                self._page_size, window_pages=self._n_pages_w,
             )
         return self.engine.make_cache(len(self.slots), self.max_seq)
 
@@ -866,15 +899,29 @@ class ContinuousBatcher:
         resharding transfer inside every tick and breaking cache-leaf
         donation under tensor-parallel serving
         (docs/tensor_parallel_serving.md)."""
-        if self._paged and self._tables_dirty:
-            from jax.sharding import NamedSharding, PartitionSpec
+        if not self._paged:
+            return
+        from jax.sharding import NamedSharding, PartitionSpec
 
+        everywhere = NamedSharding(self.engine.mesh, PartitionSpec())
+        if self._window:
+            # The window pages each live row's next steps write, mapped
+            # before the device call that may write them. This table
+            # moves most ticks (a row passes a page every 16 tokens);
+            # the other one only when a row comes or goes.
+            for i, slot in enumerate(self.slots):
+                if slot.active and slot.request is not None and (
+                    self.pages.window.extend(i, self._next_query(slot))
+                ):
+                    self._window_dirty = True
+            if self._window_dirty or self._tables_dirty:
+                self.cache = self.cache._replace(
+                    window=self.cache.window._replace(table=jax.device_put(
+                        self.pages.window.tables, everywhere)))
+                self._window_dirty = False
+        if self._tables_dirty:
             self.cache = self.cache._replace(
-                table=jax.device_put(
-                    self.pages.tables,
-                    NamedSharding(self.engine.mesh, PartitionSpec()),
-                )
-            )
+                table=jax.device_put(self.pages.tables, everywhere))
             self._tables_dirty = False
 
     def _snap_dev(self, x):
@@ -907,8 +954,6 @@ class ContinuousBatcher:
         s = mk.shape[2]
         pos = jnp.arange(s)
         rows = jnp.clip(slots, 0, b - 1)
-        rtab = cache.table[rows]  # [R, W]
-        page = rtab[:, jnp.minimum(pos // p, self._table_width - 1)]
         off = jnp.broadcast_to(pos % p, (r, s))
         start = jnp.broadcast_to(start, (r,))
         valid = (
@@ -916,6 +961,29 @@ class ContinuousBatcher:
             & (pos[None, :] < true_len[:, None])
             & (slots[:, None] >= 0) & (slots[:, None] < b)
         )
+        length = cache.length.at[slots].set(true_len, mode="drop")
+        if cache.window is not None:
+            # Two kinds of page: each kind's layers of the mini go
+            # through its own table into its own arena. A window
+            # layer's table maps the live tail only, so the positions
+            # behind it drop like any unmapped entry's.
+            def through(table, n_pages):
+                pg = table[rows][:, jnp.minimum(
+                    pos // p, self._table_width - 1)]
+                pg = jnp.where(valid, pg, n_pages)
+                return lambda a, m: a.at[:, pg, off].set(
+                    m.astype(a.dtype), mode="drop")
+
+            cfg, win = self.engine.cfg, cache.window
+            (fk, wk), (fv, wv) = (
+                llama_mod.split_kinds(cfg, m) for m in (mini.k, mini.v))
+            full = through(cache.table, self._n_pages)
+            tail = through(win.table, self._n_pages_w)
+            return cache._replace(
+                k=full(cache.k, fk), v=full(cache.v, fv), length=length,
+                window=win._replace(k=tail(win.k, wk), v=tail(win.v, wv)))
+        rtab = cache.table[rows]  # [R, W]
+        page = rtab[:, jnp.minimum(pos // p, self._table_width - 1)]
         page = jnp.where(valid, page, self._n_pages)
 
         def put(a, m):
@@ -936,7 +1004,6 @@ class ContinuousBatcher:
 
             return jax.lax.fori_loop(0, a.shape[0], layer, a)
 
-        length = cache.length.at[slots].set(true_len, mode="drop")
         return llama_mod.map_planes(put, cache, mini, length=length)
 
     # -- row state beside pages (a ROW_STATE family) -------------------------
@@ -1542,11 +1609,34 @@ class ContinuousBatcher:
         slot's fresh divergent page instead of recomputing it. One
         device call admits a whole same-preamble wave."""
         r = tokens.shape[0]
+        if cache.window is not None:
+            # `gtables` [R, 2, W]: a gather row a kind (_gather_tables);
+            # the kinds' views, joined in the model's layer order. A
+            # page is gathered as its `[page x KVH, Dh]` rows (the
+            # shape the paged-decode kernel reads it in): gathered as
+            # `[page, KVH, Dh]` with 4 KV heads, the compiler wanted the
+            # view KVH-major and first copied each of the four arenas
+            # whole into that order, 3.3 ms each, a third of a
+            # re-admission program on the chip (PERF.md section 6, PR
+            # 53; the compile for a described v5e shows the copies).
+            def view(a, table):
+                flat = llama_mod.paged_view_layers(
+                    a.reshape(*a.shape[:2], -1, a.shape[-1]), table)
+                return flat.reshape(*flat.shape[:2], -1, *a.shape[3:])
+
+            views = [llama_mod.join_kinds(self.engine.cfg, [
+                view(a, gtables[:, kind]) for kind, a in enumerate(pair)])
+                for pair in ((cache.k, cache.window.k),
+                             (cache.v, cache.window.v))]
+        else:
+            views = [
+                llama_mod.paged_view_layers(
+                    plane, gtables, self._arena_by_layer)
+                for plane in llama_mod.cache_planes(cache)]
         mini = llama_mod.with_planes(
             llama_mod.KVCache(
                 None, None, jnp.broadcast_to(scan_start, (r,)).astype(jnp.int32)),
-            [llama_mod.paged_view_layers(plane, gtables, self._arena_by_layer)
-             for plane in llama_mod.cache_planes(cache)])
+            views)
         mini = self._state_enter(cache, mini, self._pool_rows(slots), sio)
         fl, mini = self._chunked_scan(
             params, tokens, true_len, mini, adapters, scan_start, sio
@@ -2047,10 +2137,7 @@ class ContinuousBatcher:
             width = 32
             while width <= bucket_len(c, maximum=self.max_seq):
                 for r_rows in sorted({1, self._mini_rows}):
-                    gtw = np.full(
-                        (r_rows, self._table_width), self._n_pages,
-                        np.int32,
-                    )
+                    gtw = self._gather_tables(r_rows)
                     warm[r_rows], self.cache = self._admit_paged_pfx(
                         self.engine.params,
                         jnp.asarray(np.zeros((r_rows, 1, width), np.int32)),
@@ -2414,10 +2501,47 @@ class ContinuousBatcher:
 
         total = planes(self.cache) + sum(p.nbytes for p in self.cache.state)
         if self._paged:
-            total += self.cache.table.nbytes
+            total += self.cache.table.nbytes + sum(
+                a.nbytes for a in self.cache.window or ())
         if self._ilv_mini is not None:
             total += planes(self._ilv_mini)
         return total
+
+    def _gather_tables(self, r: int) -> np.ndarray:
+        """An admission group's gather tables, every entry unmapped:
+        [r, W], or [r, 2, W] where the cache has two kinds of page (a
+        row of each kind's table; reads of an unmapped entry clip)."""
+        if self._window:
+            return np.full(
+                (r, 2, self._table_width),
+                max(self._n_pages, self._n_pages_w), np.int32)
+        return np.full((r, self._table_width), self._n_pages, np.int32)
+
+    @staticmethod
+    def _next_query(slot) -> int:
+        """The position of a live row's next query as far as the host
+        has seen it emit: its newest token's (whose K/V that step
+        writes). The device may be ahead by the ticks in flight, never
+        behind."""
+        return len(slot.request.prompt) + max(slot.generated, 1) - 1
+
+    def _window_release(self, steps: int) -> None:
+        """A tick's collect, for a cache with window pages: every live
+        row lets go of the pages now wholly behind the window of its
+        next query (serving/pages.py WindowPages.release; a
+        `ggrmcp.pages.window_release` span while a capture runs), and
+        the keys its window layers read in the tick's `steps` steps
+        are counted beside the keys its context holds."""
+        with tracing.annotation("ggrmcp.pages.window_release"):
+            for i, slot in enumerate(self.slots):
+                if not slot.active or slot.request is None:
+                    continue
+                pos = self._next_query(slot)
+                if self.pages.window.release(i, pos):
+                    self._window_dirty = True
+                per = self._window_layers * steps
+                self.window_keys["read"] += per * min(pos + 1, self._window)
+                self.window_keys["context"] += per * (pos + 1)
 
     def _tick_steps(self) -> int:
         """The length of the plain tick about to be dispatched, from
@@ -2623,6 +2747,8 @@ class ContinuousBatcher:
             # resident (live + reuse cache), pages referenced by 2+
             # slots right now, admissions that reused shared pages or a
             # CoW source, and divergent-page copy-on-writes.
+            "window_keys_read": self.window_keys["read"],
+            "window_keys_context": self.window_keys["context"],
             **(self.pages.stats() if self._paged else {
                 "kv_pages_total": 0, "kv_pages_in_use": 0,
                 "kv_pages_shared": 0, "paged_prefix_hits": 0,
@@ -3975,7 +4101,7 @@ class ContinuousBatcher:
         tokens = np.zeros((r, t_steps, width), np.int32)
         true_len = np.zeros((r,), np.int32)
         slots_arr = np.full((r,), b, np.int32)
-        gtables = np.full((r, self._table_width), self._n_pages, np.int32)
+        gtables = self._gather_tables(r)
         seeds = np.zeros((r,), np.uint32)
         temps = np.zeros((r,), np.float32)
         ks = np.zeros((r,), np.int32)
@@ -3987,7 +4113,10 @@ class ContinuousBatcher:
             tokens[j].reshape(-1)[: len(piece)] = piece
             true_len[j] = len(req.prompt)
             slots_arr[j] = sl
-            gtables[j] = adm.gather_row
+            if self._window:
+                gtables[j] = adm.gather_row, self.pages.window.tables[sl]
+            else:
+                gtables[j] = adm.gather_row
             seeds[j] = req.seed & 0xFFFFFFFF
             temps[j] = req.sampling.temperature
             ks[j] = req.sampling.top_k
@@ -4466,6 +4595,8 @@ class ContinuousBatcher:
                 self._emit_chunk(i, toks[i, :c])
             if self.slots[i].request is not request:
                 finished += 1
+        if self._window:
+            self._window_release(toks.shape[1])
         self.grammar_jump_tokens += jump_tokens
         self.grammar_jump_runs += jump_runs
         self.recorder.tick_done(

@@ -145,6 +145,10 @@ _MESH_WITH_EXPERT_SHARE = (
     "a mesh of more than one device, for a model that holds a "
     "share of its experts"
 )
+_TWO_KINDS_OF_PAGE = (
+    "these paths move pages of one arena; this family's window layers "
+    "keep theirs in a second arena under a free rule by position"
+)
 _MESH_WITH_ROW_STATE = (
     "a mesh of more than one device, for a model with state-space layers"
 )
@@ -242,6 +246,35 @@ _UNSUPPORTED = {
         _MESH_WITH_ROW_STATE: (
             "the state pool and the selective scan are whole on one "
             "chip; a state-space layer on a mesh is not built"
+        ),
+    },
+    "smallthinker": {
+        "lora": "the adapter delta sits on the dense family's fused qkv",
+        "pipeline-parallel serving (mesh.stage > 1)": (
+            "the staged forward runs one homogeneous layer stack; this "
+            "family's is periods of a full layer and window layers, "
+            "each kind with an arena of its own"
+        ),
+        "kv_ring": (
+            "the full layers attend every key; the window layers' pages "
+            "are let go by position under batching.paged_kv"
+        ),
+        "batching.kv_tiers": _TWO_KINDS_OF_PAGE,
+        "batching.paged_kv_host_bytes (the host tier)": _TWO_KINDS_OF_PAGE,
+        "a non-mixed serving.role (KV export/import)": _TWO_KINDS_OF_PAGE,
+        "quantize / synthetic_weights": (
+            "the weights are served in bf16; int8 matmuls are wired "
+            "into the dense family's projections only"
+        ),
+        "kv_cache_dtype int8": (
+            "the layer stack scans plain planes a period; pages of int8 "
+            "values and scales have not been held to the reference "
+            "(float8 pages are the benchmark's control)"
+        ),
+        "batching.prefill_interleave": (
+            "a chunk that rides a tick is merged a row at a time into "
+            "one arena; the window layers' live tail goes through "
+            "_paged_put alone"
         ),
     },
 }
@@ -795,6 +828,7 @@ class GenerationEngine:
             "quantize / synthetic_weights": bool(sv.quantize)
             or bool(sv.synthetic_weights),
             "kv_cache_dtype fp8": sv.kv_cache_dtype == "fp8",
+            "kv_cache_dtype int8": sv.kv_cache_dtype == "int8",
             "kv_cache_dtype (other than the model's)": bool(
                 sv.kv_cache_dtype),
             "batching.prefill_interleave": getattr(
@@ -1126,7 +1160,8 @@ class GenerationEngine:
             )()
 
     def make_paged_cache(
-        self, batch: int, max_len: int, n_pages: int, page_size: int
+        self, batch: int, max_len: int, n_pages: int, page_size: int,
+        window_pages: int = 0,
     ) -> llama_mod.PagedKVCache:
         """Mesh-sharded paged KV arena + block tables (batching.paged_kv,
         docs/paged_kv.md). Pages shard heads over `tensor` only — a page
@@ -1140,7 +1175,7 @@ class GenerationEngine:
                 "serving (the staged forward has no block-table path)"
             )
         self._refuse("batching.paged_kv")  # asked for by the batcher
-        lead = (self.cfg.cache_layers, n_pages, page_size)
+        lead = (self.cfg.cache_kinds[0][0], n_pages, page_size)
         raw = self.fam.paged_cache_specs()
         observe = partial(self._observe_cache_spec, "paged_kv_arena")
 
@@ -1162,11 +1197,17 @@ class GenerationEngine:
         specs = llama_mod.with_planes(raw, [
             kv_spec(spec, plane) for spec, plane in zip(
                 llama_mod.cache_planes(raw), self.cfg.kv_planes)])
+        if raw.window is not None:
+            # The second kind's arena (`window_pages` of them): the
+            # same planes under the same specs, a table of its own.
+            specs = specs._replace(window=llama_mod.WindowArena(
+                specs.k, specs.v, raw.window.table))
         with self.mesh:
             return jax.jit(
                 partial(
                     llama_mod.PagedKVCache.create, self.cfg, batch,
                     max_len, n_pages, page_size, self.kv_dtype,
+                    window_pages,
                 ),
                 out_shardings=jax.tree_util.tree_map(
                     lambda s: NamedSharding(self.mesh, s), specs,

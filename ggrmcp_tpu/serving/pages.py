@@ -127,14 +127,307 @@ class PageAdmission:
     state_src: int = -1
 
 
+def window_pages_per_slot(
+    window: int, chunk: int, page_size: int, lookahead: int
+) -> int:
+    """Pages of the window arena a slot: what one row holds at the most.
+    The window in front of a re-admission's first query (or, decoding,
+    in front of the end of its own prompt), the chunk it admits (or the
+    `retain` = one chunk of positions it keeps of that tail), the steps
+    the host maps ahead of the device's writes, and a page for each
+    ragged end. The arena is `slots` times this, so a row's next page
+    is always free or evictable."""
+    return -(-(window + chunk + lookahead) // page_size) + 2
+
+
+class WindowPages:
+    """The pages of the caching layers that attend a window (the second
+    of `cfg.cache_kinds`; docs/paged_kv.md "Two kinds of page"): an
+    arena, block tables and a prefix index of their own beside
+    PageAllocator's, which owns this object and drives it.
+
+    The index is keyed by the SAME chain keys: block j of a prompt is
+    one key, under which the allocator holds the page that keeps every
+    layer's K/V of the block and this holds the page of the window
+    layers' (content was verified against the tokens there). What
+    differs is the FREE RULE BY POSITION: a row lets go of a page once
+    its last position is more than `window - 1` behind the row's next
+    query (`release`), and maps the pages its next steps write as it
+    goes (`extend`), so a row holds about a window of pages whatever
+    its context. "Lets go" is the reference a row holds: a page the
+    index still names stays resident at refcount 0, evictable least
+    recently used first, exactly as the allocator's own.
+
+    One exception, for what a finished session's follow-up turn
+    re-admits on: the pages a query at the END OF THE ROW'S OWN PROMPT
+    reads (the deepest hit its indexed pages allow) stay referenced
+    while the row decodes, as far as `retain` positions behind its
+    next query. Let go by position a few steps in, they would be the
+    oldest of the session's tail when the next turn looks for them,
+    and at a window a slot the arena's cached pages turn over within a
+    turn (a simulation of this allocator under the `mixed-ctx`
+    schedule, no chip: one follow-up in thirteen found its first pages
+    gone and re-ran 8-13k tokens; with the exception none). A row's
+    share of the arena is sized for it (`window_pages_per_slot`: the
+    window, `retain`, the steps mapped ahead).
+    """
+
+    def __init__(self, n_pages: int, page_size: int, slots: int,
+                 table_width: int, window: int, per_slot: int,
+                 lookahead: int, retain: int):
+        self.n_pages = n_pages
+        self.page_size = page_size
+        self.window = window
+        self.per_slot = per_slot
+        self.lookahead = lookahead
+        self.retain = retain
+        self.sentinel = n_pages
+        self.tables = np.full((slots, table_width), self.sentinel, np.int32)
+        # A row's mapped blocks lie in [lo, hi) (with a hole where a
+        # suffix longer than the window was admitted); cap: the blocks
+        # its request may ever write.
+        self._lo = np.zeros(slots, np.int64)
+        self._hi = np.zeros(slots, np.int64)
+        self._cap = np.zeros(slots, np.int64)
+        # The end of the row's prompt's full pages: where its follow-up
+        # turn's hit would end (the exception above).
+        self._tail = np.zeros(slots, np.int64)
+        self._ref = np.zeros(n_pages, np.int64)
+        self._free: list[int] = list(range(n_pages))
+        self._index: dict[int, int] = {}
+        self._key_of: dict[int, int] = {}
+        self._stamp: dict[int, int] = {}
+        self._clock = 0
+        # Pages let go by the position rule; prefix hits cut short or
+        # dropped because a window page was gone (or the row's share of
+        # the arena would not hold the hit and the suffix); pages
+        # mapped for rows to write (admissions and decode steps).
+        self.freed = 0
+        self.hits_refused = 0
+        self.mapped = 0
+
+    def in_use(self) -> int:
+        return self.n_pages - len(self._free)
+
+    def first_block(self, pos: int) -> int:
+        """The first block a query at `pos` can read: keys s > pos -
+        window."""
+        return max(0, pos - self.window + 1) // self.page_size
+
+    def _blocks_for(self, t: int, prompt_len: int, need_len: int):
+        """What a row admitted on `t` shared blocks holds: the shared
+        blocks [lo_s, t) its suffix's queries read, and fresh blocks
+        [lo_f, hi) for what the put writes of the live tail and the
+        first decode steps."""
+        p = self.page_size
+        hi = min(-(-need_len // p), -(-(prompt_len + self.lookahead) // p))
+        return (self.first_block(t * p), max(t, self.first_block(prompt_len)),
+                hi)
+
+    def plan(self, keys: list, m: int, prompt_len: int, need_len: int) -> int:
+        """How many of the `m` matched blocks (chain keys `keys`) a row
+        can be admitted on: the longest j <= m for which the window
+        pages a query at j x page can read, blocks [first_block, j),
+        are all resident, and whose hit and suffix fit the row's share
+        of the arena. 0: cold."""
+        runs, run = [], 0  # resident blocks in a row, ending at block j
+        for j in range(m):
+            run = run + 1 if keys[j] in self._index else 0
+            runs.append(run)
+        t = 0
+        for j in range(m, 0, -1):
+            lo_s, lo_f, hi = self._blocks_for(j, prompt_len, need_len)
+            if runs[j - 1] >= j - lo_s and (
+                    j - lo_s) + (hi - lo_f) <= self.per_slot:
+                t = j
+                break
+        self.hits_refused += t < m
+        return t
+
+    def _reclaim(self, need: int, keep: frozenset = frozenset()) -> None:
+        shortfall = need - len(self._free)
+        if shortfall <= 0:
+            return
+        candidates = [pg for pg in self._stamp if pg not in keep]
+        if shortfall > len(candidates):
+            raise PageExhaustedError(
+                f"window page pool exhausted: need {need} pages, "
+                f"{len(self._free)} free + {len(candidates)} evictable "
+                f"of {self.n_pages}")
+        for page in heapq.nsmallest(
+                shortfall, candidates, key=self._stamp.__getitem__):
+            del self._stamp[page]
+            del self._index[self._key_of.pop(page)]
+            self._free.append(page)
+
+    def reserve(self, keys: list, t: int, prompt_len: int,
+                need_len: int) -> None:
+        """Make room for `admit` with these arguments, or raise
+        PageExhaustedError with nothing but evictions done."""
+        lo_s, lo_f, hi = self._blocks_for(t, prompt_len, need_len)
+        self._reclaim(hi - lo_f, keep=frozenset(
+            self._index[keys[j]] for j in range(lo_s, t)))
+
+    def admit(self, slot: int, keys: list, t: int, prompt_len: int,
+              need_len: int) -> None:
+        """Build slot's row (after `reserve`): references on the shared
+        blocks a query at t x page reads, and fresh pages from the
+        first block the row's first decode query reads to the steps
+        mapped ahead. Blocks between the two (a suffix longer than the
+        window) are never stored."""
+        lo_s, lo_f, hi = self._blocks_for(t, prompt_len, need_len)
+        row = self.tables[slot]
+        row[:] = self.sentinel
+        for j in range(lo_s, t):
+            page = self._index[keys[j]]
+            if self._ref[page] == 0:
+                self._stamp.pop(page, None)
+            self._ref[page] += 1
+            row[j] = page
+        for j in range(lo_f, hi):
+            page = self._free.pop()
+            self._ref[page] = 1
+            row[j] = page
+        self.mapped += max(0, hi - lo_f)
+        self._lo[slot] = lo_s if t > lo_s else lo_f
+        self._hi[slot] = max(hi, t)
+        self._cap[slot] = -(-need_len // self.page_size)
+        self._tail[slot] = prompt_len // self.page_size * self.page_size
+
+    def extend(self, slot: int, pos: int) -> bool:
+        """Map the pages the row's next steps write: to `lookahead`
+        positions past its next query at `pos`, within its request's
+        extent. Returns whether the row changed."""
+        hi = min(int(self._cap[slot]),
+                 -(-(pos + self.lookahead) // self.page_size))
+        at = int(self._hi[slot])
+        if hi <= at:
+            return False
+        self._reclaim(hi - at)
+        row = self.tables[slot]
+        for j in range(at, hi):
+            page = self._free.pop()
+            self._ref[page] = 1
+            row[j] = page
+        self.mapped += hi - at
+        self._hi[slot] = hi
+        return True
+
+    def _unref(self, page: int) -> None:
+        self._ref[page] -= 1
+        if self._ref[page] == 0:
+            if page in self._key_of:
+                self._clock += 1
+                self._stamp[page] = self._clock
+            else:
+                self._free.append(page)
+
+    def release(self, slot: int, pos: int) -> int:
+        """The free rule by position: let go of the row's pages whose
+        last position is more than window - 1 behind its next query at
+        `pos`, but for what a query at the end of its own prompt reads,
+        kept as far as `retain` positions behind `pos` (the class's
+        docstring). Returns how many."""
+        behind = min(pos, max(int(self._tail[slot]), pos - self.retain))
+        lo, new_lo = int(self._lo[slot]), min(
+            self.first_block(behind), int(self._hi[slot]))
+        if new_lo <= lo:
+            return 0
+        row, n = self.tables[slot], 0
+        for j in range(lo, new_lo):
+            if row[j] != self.sentinel:
+                self._unref(int(row[j]))
+                row[j] = self.sentinel
+                n += 1
+        self._lo[slot] = new_lo
+        self.freed += n
+        return n
+
+    def register(self, slot: int, keys: list) -> None:
+        """Index the row's mapped pages of the blocks `keys` names
+        (full pages of a prefilled prompt); a key already held keeps
+        its page."""
+        row = self.tables[slot]
+        for j, key in enumerate(keys):
+            page = int(row[j])
+            if (page != self.sentinel and key not in self._index
+                    and page not in self._key_of):
+                self._index[key] = page
+                self._key_of[page] = key
+
+    def free_slot(self, slot: int, discard_index: bool = False) -> None:
+        row = self.tables[slot]
+        for mapped in row[row != self.sentinel]:
+            page = int(mapped)
+            if discard_index and self._ref[page] == 1 and page in self._key_of:
+                del self._index[self._key_of.pop(page)]
+            self._unref(page)
+        row[:] = self.sentinel
+        self._lo[slot] = self._hi[slot] = self._cap[slot] = 0
+        self._tail[slot] = 0
+
+    def reset(self) -> None:
+        self.tables[:] = self.sentinel
+        self._ref[:] = 0
+        self._free = list(range(self.n_pages))
+        for book in (self._index, self._key_of, self._stamp):
+            book.clear()
+        self._lo[:] = self._hi[:] = self._cap[:] = self._tail[:] = 0
+
+    def stats(self) -> dict:
+        return {
+            "kv_window_pages_total": self.n_pages,
+            "kv_window_pages_in_use": self.in_use(),
+            "paged_window_pages_freed": self.freed,
+            "paged_window_hits_refused": self.hits_refused,
+            "paged_window_pages_mapped": self.mapped,
+        }
+
+    def check_invariants(self) -> None:
+        free = set(self._free)
+        assert len(free) == len(self._free), "duplicate window page in free list"
+        live = self.tables[self.tables != self.sentinel]
+        counts = np.bincount(live, minlength=self.n_pages)
+        assert (counts == self._ref).all(), (
+            "window refcounts disagree with block-table occurrences")
+        for key, page in self._index.items():
+            assert self._key_of.get(page) == key, "window index disagrees"
+            assert page not in free, f"indexed window page {page} is free"
+        assert set(self._stamp) == {
+            pg for pg in self._key_of if self._ref[pg] == 0}, (
+            "window stamps are not the refcount-0 indexed pages")
+        cached = len(self._stamp)
+        referenced = int((self._ref > 0).sum())
+        assert len(free) + referenced + cached == self.n_pages, "window pages lost"
+        for slot, row in enumerate(self.tables):
+            mapped = np.nonzero(row != self.sentinel)[0]
+            if mapped.size:
+                assert self._lo[slot] <= mapped[0] and mapped[-1] < self._hi[
+                    slot], f"slot {slot}'s window pages outside its bounds"
+                assert mapped.size <= self.per_slot, (
+                    f"slot {slot} holds {mapped.size} window pages")
+
+
+WINDOW_STATS_OFF = {
+    "kv_window_pages_total": 0, "kv_window_pages_in_use": 0,
+    "paged_window_pages_freed": 0, "paged_window_hits_refused": 0,
+    "paged_window_pages_mapped": 0,
+}
+
+
 class PageAllocator:
     """Refcounted page allocator + token-level prefix index for ONE
     batcher's paged KV arena."""
 
     def __init__(self, n_pages: int, page_size: int, slots: int,
-                 table_width: int, state_entries: int = 0):
+                 table_width: int, state_entries: int = 0,
+                 window: Optional[WindowPages] = None):
         if n_pages < 1 or page_size < 1:
             raise ValueError("n_pages and page_size must be >= 1")
+        # The second kind's pages (WindowPages), where the model has
+        # layers that attend a window: admit, register, free_slot and
+        # reset carry it along; None: nothing below ever runs.
+        self.window = window
         self.n_pages = n_pages
         self.page_size = page_size
         self.width = table_width
@@ -254,6 +547,8 @@ class PageAllocator:
             "state_pool_in_use": len(self._snap_key) + sum(
                 len(plan) for plan in self._snap_plan.values()),
             "state_pool_total": self.state_entries,
+            **(self.window.stats() if self.window is not None
+               else WINDOW_STATS_OFF),
             **(
                 self.host.stats() if self.host is not None else {
                     "kv_host_entries": 0, "kv_host_bytes_used": 0,
@@ -275,6 +570,10 @@ class PageAllocator:
         stream (demote inside _reclaim, restore inside admit), so
         neither can interleave with a tick, an admission, or a
         TransferKV host op."""
+        if self.window is not None:
+            raise ValueError(
+                "the host tier moves pages of one kind; a cache with "
+                "window pages beside it is not built")
         self.host = pool
         self._fetch_pages = fetch
         self._restore_pages = restore
@@ -461,7 +760,8 @@ class PageAllocator:
         # produce sampling logits — cap reuse at len(prompt) - 1.
         limit = len(prompt) - 1
         keys = self._walk_keys(slot, arr, root, limit // p) if (
-            self.state_entries and share) else None
+            (self.state_entries or self.window is not None) and share
+        ) else None
         if share:
             shared, break_key, cow_page, cow_t = self._lookup(
                 arr, limit, root, keys
@@ -469,7 +769,14 @@ class PageAllocator:
         else:
             shared, break_key, cow_page, cow_t = [], root, -1, 0
         state_src = -1
-        if keys is not None:
+        if self.window is not None:
+            # A hit only as far as the window layers' pages that a
+            # query there can read are resident too; a divergent page
+            # is not copied (it would take one of each kind).
+            shared = shared[:self.window.plan(
+                keys or [], len(shared), len(prompt), need_len)]
+            cow_page, cow_t = -1, 0
+        if self.state_entries and keys is not None:
             # The deepest matched page that HAS a snapshot: the pages
             # past it are not reused (their tokens run again, into
             # pages of the slot's own), and no divergent page is
@@ -494,6 +801,8 @@ class PageAllocator:
         # may raise; nothing mutated yet (demotion only fills the host
         # pool — additive, safe even if the admission then sheds)
         self._reclaim(w_need - m - n_dev, keep=keep)
+        if self.window is not None:  # may raise too; evictions only
+            self.window.reserve(keys or [], m, len(prompt), need_len)
         fresh = [self._free.pop() for _ in range(w_need - m - n_dev)]
         restored: list[tuple[int, int]] = []  # (ext index, blob bytes)
         host_items = [
@@ -565,6 +874,8 @@ class PageAllocator:
         if cow_page >= 0 and cow_t > 0:
             gather[t] = cow_page
             self.cow_copies += 1
+        if self.window is not None:
+            self.window.admit(slot, keys or [], m, len(prompt), need_len)
         self.pages_admitted += w_need
         self.pages_reused += m + len(relinked)
         if t or cow_t:
@@ -877,7 +1188,7 @@ class PageAllocator:
         arr = np.asarray(prompt, np.int32)
         key = adapter_root(adapter)
         keys = self._walk_keys(slot, arr, key, len(prompt) // p) if (
-            self.state_entries) else None
+            self.state_entries or self.window is not None) else None
         for j in range(len(prompt) // p):
             toks = arr[j * p:(j + 1) * p]
             nxt = self._chain(key, toks) if keys is None else keys[j]
@@ -894,6 +1205,8 @@ class PageAllocator:
             key = nxt
         if slot in self._snap_plan:
             self._hang_snapshots(slot)
+        if self.window is not None:
+            self.window.register(slot, keys)
 
     def free_slot(self, slot: int, discard_index: bool = False) -> None:
         """Release a slot's page references. Exclusive un-indexed pages
@@ -908,6 +1221,8 @@ class PageAllocator:
         for _key, entry in self._snap_plan.pop(slot, ()):
             self._snap_free.append(entry)  # captured, never indexed
         self._walk.pop(slot, None)
+        if self.window is not None:
+            self.window.free_slot(slot, discard_index)
         row = self.tables[slot]
         for mapped in row[row != self.sentinel]:
             page = int(mapped)
@@ -961,6 +1276,8 @@ class PageAllocator:
         and every index entry is device-dead — forget it all. Victims
         replay through admission, which re-prefills and re-registers;
         shared prefixes re-share from the first replayed sighting."""
+        if self.window is not None:
+            self.window.reset()
         self.tables[:] = self.sentinel
         self._ref[:] = 0
         self._free = list(range(self.n_pages))
@@ -986,6 +1303,8 @@ class PageAllocator:
         interleaved step to prove zero pages are lost or double-mapped
         through the serialized host-op stream). Raises AssertionError
         naming the violated invariant."""
+        if self.window is not None:
+            self.window.check_invariants()
         free = set(self._free)
         assert len(free) == len(self._free), "duplicate page in free list"
         for page in free:

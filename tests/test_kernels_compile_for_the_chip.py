@@ -125,6 +125,16 @@ FLASH_CALLS = {
         rows=1, sq=512, sk=8192, window=None, heads=20, kv_heads=1),
     "4_rows_128_on_8192_group_20": dict(
         rows=4, sq=128, sk=8192, window=None, heads=20, kv_heads=1),
+    # the two kinds of layer of models/smallthinker.py: 28 query heads
+    # on 4 KV heads (a group of 7), a 512-token chunk and a 256-token
+    # suffix over the 16,384-position mini cache, with the window that
+    # binds there and without one
+    "1_row_512_on_16384_window_4096_group_7": dict(
+        rows=1, sq=512, sk=16384, window=4096, heads=28, kv_heads=4),
+    "1_row_512_on_16384_group_7": dict(
+        rows=1, sq=512, sk=16384, window=None, heads=28, kv_heads=4),
+    "1_row_256_on_16384_window_4096_group_7": dict(
+        rows=1, sq=256, sk=16384, window=4096, heads=28, kv_heads=4),
 }
 
 
@@ -156,6 +166,10 @@ def test_the_flash_kernel_compiles_for_a_v5e(topo, uncached, case):
 EXPERT_WIDTHS = {
     "kanana_keye": dict(d=2048, f=768, held=128, experts=128, layers=6),
     "dsv32": dict(d=7168, f=2048, held=16, experts=256, layers=4),
+    # ReGLU (the gate's activation is the caller's): 11.8 MB an expert,
+    # the largest the whole-expert slot has held
+    "smallthinker": dict(
+        d=2560, f=768, held=64, experts=64, layers=8, act="relu"),
 }
 EXPERT_CALLS = {
     "kanana_keye-64_pairs": ("kanana_keye", 64, 8),
@@ -168,6 +182,10 @@ EXPERT_CALLS = {
     # expert a slot (two tiles of F)
     "kanana_keye-64_pairs-float32": ("kanana_keye", 64, 8, jnp.float32),
     "kanana_keye-3072_pairs-float32": ("kanana_keye", 3072, 32, jnp.float32),
+    # 32 decoding rows x 6, a 128-token suffix, a 512-token chunk
+    "smallthinker-192_pairs": ("smallthinker", 192, 8),
+    "smallthinker-768_pairs": ("smallthinker", 768, 16),
+    "smallthinker-3072_pairs": ("smallthinker", 3072, 64),
 }
 
 
@@ -178,7 +196,12 @@ def test_the_grouped_experts_kernel_compiles_for_a_v5e(topo, uncached, case):
 
     widths, pairs, loop_block, *dtype = EXPERT_CALLS[case]
     dtype = dtype[0] if dtype else jnp.bfloat16
-    d, f, held, n_experts, layers = EXPERT_WIDTHS[widths].values()
+    sizes = dict(EXPERT_WIDTHS[widths])
+    act = sizes.pop("act", "silu")
+    d, f, held, n_experts, layers = sizes.values()
+    if widths == "smallthinker":  # an expert whole, just inside a slot
+        assert experts._f_tile(d, f, 2) == f
+        assert 3 * d * f * 2 <= experts._SLOT_BYTES < 3 * d * (f + 128) * 2
     assert mla_moe._task_block(pairs, n_experts) == loop_block
     tile = max(loop_block, experts.MIN_ROWS)
     max_tasks = min(pairs // tile + held, pairs)
@@ -188,7 +211,7 @@ def test_the_grouped_experts_kernel_compiles_for_a_v5e(topo, uncached, case):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     compiled = jax.jit(
-        functools.partial(experts.grouped_swiglu, block=tile)
+        functools.partial(experts.grouped_swiglu, block=tile, act=act)
     ).lower(
         shape((max_tasks * tile, d)), shape((layers, held, d, f)),
         shape((layers, held, d, f)), shape((layers, held, f, d)),
@@ -196,3 +219,35 @@ def test_the_grouped_experts_kernel_compiles_for_a_v5e(topo, uncached, case):
         shape((max_tasks,), jnp.int32), shape((max_tasks,), jnp.int32),
     ).compile()
     assert "grouped_experts_swiglu" in compiled.as_text()
+
+
+# The paged-decode kernel at the two arenas of models/smallthinker.py, a
+# tick of 32 rows: the full layers' walk over every page of a row, and
+# the window layers' over a table whose entries behind the window are
+# unmapped (the walk starts at the window's first block and the row's
+# liveness is read off the page of its newest key).
+PAGED_DECODE_CALLS = {
+    "full_2_layers_1024_pages_a_row": dict(
+        layers=2, pages=32 * 1024, window=None),
+    "window_6_layers_292_pages_a_row": dict(
+        layers=6, pages=32 * 292, window=4096),
+}
+
+
+@pytest.mark.parametrize(
+    "case", PAGED_DECODE_CALLS, ids=list(PAGED_DECODE_CALLS))
+def test_the_paged_decode_kernel_compiles_for_a_v5e(topo, uncached, case):
+    layers, pages, window = PAGED_DECODE_CALLS[case].values()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    arena = shape((layers, pages, 16, 4, 128))
+    compiled = jax.jit(
+        functools.partial(A.paged_decode_attention, window=window)
+    ).lower(
+        shape((32, 1, 28, 128)), arena, arena, shape((32, 1024), jnp.int32),
+        shape((32,), jnp.int32), shape((), jnp.int32),
+    ).compile()
+    assert "paged_decode_attention" in compiled.as_text()

@@ -41,6 +41,8 @@ from ggrmcp_tpu.serving.pages import (
     PageAdmission,
     PageAllocator,
     PageExhaustedError,
+    WindowPages,
+    window_pages_per_slot,
 )
 from ggrmcp_tpu.serving.tiered import TieredBatcher
 from ggrmcp_tpu.utils import failpoints
@@ -363,6 +365,222 @@ class TestStateSnapshots:
 # ---------------------------------------------------------------------------
 # Bit-identity: paged on == paged off == engine.generate
 # ---------------------------------------------------------------------------
+
+
+class TestWindowPages:
+    """Two kinds of page (docs/paged_kv.md): the window layers' pages
+    beside the allocator's own, host side only. Pages of 4 tokens, a
+    window of 16 (4 pages), a chunk of 8, 3 slots; a row may hold 9
+    window pages."""
+
+    P, W, CHUNK = 4, 16, 8
+
+    def _alloc(self, n_pages_w=27, per_slot=9, lookahead=3, slots=3):
+        win = WindowPages(
+            n_pages_w, self.P, slots=slots, table_width=32, window=self.W,
+            per_slot=per_slot, lookahead=lookahead, retain=self.CHUNK)
+        return PageAllocator(
+            128, self.P, slots=slots, table_width=32, window=win)
+
+    @staticmethod
+    def _mapped(alloc, slot):
+        row = alloc.window.tables[slot]
+        return [int(j) for j in np.nonzero(row != alloc.window.sentinel)[0]]
+
+    def test_the_arena_a_slot_is_sized_from_window_chunk_and_page(self):
+        # ISSUE 53's cell: (4,096 + 512 + 24 mapped ahead) / 16 -> 290, + 2
+        assert window_pages_per_slot(4096, 512, 16, 24) == 292
+        assert window_pages_per_slot(32, 32, 16, 2) == 7
+        assert window_pages_per_slot(16, 8, 4, 3) == 9
+
+    def test_a_cold_long_prompt_maps_its_live_tail_only(self):
+        alloc = self._alloc()
+        prompt = list(range(100, 150))  # 50 tokens: blocks 0..12
+        adm = alloc.admit(0, prompt, need_len=80)
+        assert adm.pages_shared == 0
+        # the full layers' row covers the request's whole lifetime
+        assert int((alloc.tables[0] != alloc.sentinel).sum()) == 20
+        # the window layers': from the block the first decode query (at
+        # 50) can read, (50 - 16 + 1) // 4 = 8, to 50 + 3 mapped ahead
+        assert self._mapped(alloc, 0) == list(range(8, 14))
+        assert alloc.window.stats()["paged_window_pages_mapped"] == 6
+        alloc.check_invariants()
+
+    def test_the_free_rule_by_position_and_the_mapping_ahead(self):
+        alloc = self._alloc()
+        alloc.admit(0, list(range(100, 150)), need_len=80)
+        alloc.register(0, list(range(100, 150)))
+        win = alloc.window
+        for pos in range(50, 78):  # the row's next query
+            win.extend(0, pos)
+            freed = win.release(0, pos)
+            mapped = self._mapped(alloc, 0)
+            # what the query can read is there (keys pos - 15 .. pos),
+            # and what a follow-up turn's hit at the end of the prompt's
+            # full pages (48) would read, as far as a chunk behind pos
+            kept_from = min(pos, max(48, pos - self.CHUNK))
+            assert mapped[0] == (kept_from - self.W + 1) // self.P
+            assert mapped[0] <= (pos - self.W + 1) // self.P
+            assert mapped[-1] >= pos // self.P
+            assert mapped == list(range(mapped[0], mapped[-1] + 1))
+            assert len(mapped) <= 9 and freed in (0, 1)
+            alloc.check_invariants()
+        # never past the request's extent (80 positions: 20 blocks)
+        assert self._mapped(alloc, 0)[-1] == 19
+        assert win.stats()["paged_window_pages_freed"] == 5
+        # let go is the row's reference: the prompt's indexed pages stay
+        # resident at refcount 0, the generated tokens' pages are free
+        assert win.in_use() == len(self._mapped(alloc, 0)) + 4
+        assert len(win._stamp) == 4  # blocks 8..11 of the prompt
+
+    def test_a_shared_prefix_whose_first_reader_moved_past_the_window(self):
+        """Row 0 admits a prompt, registers it and decodes on past the
+        window: its references on the prompt's window pages are gone,
+        the pages are cached. Row 1 then hits on the same prompt: it
+        takes references on the pages a query at the hit's end reads
+        (refcount 1, not 2: the first reader let go), and the full
+        layers' pages are shared by both (refcount 2)."""
+        alloc = self._alloc()
+        prompt = list(range(100, 149))  # 49 tokens: 12 full pages
+        alloc.admit(0, prompt, need_len=90)
+        alloc.register(0, prompt)
+        held = [int(alloc.window.tables[0, j]) for j in range(8, 12)]
+        for pos in range(49, 90):
+            alloc.window.extend(0, pos)
+            alloc.window.release(0, pos)
+        assert all(alloc.window._ref[pg] == 0 for pg in held)
+        follow = prompt + list(range(500, 510))
+        adm = alloc.admit(1, follow, need_len=80)
+        assert (adm.pages_shared, adm.scan_start) == (12, 48)
+        # a query at 48 reads keys 33..: blocks 8..11, the same pages
+        assert [int(alloc.window.tables[1, j]) for j in range(8, 12)] == held
+        assert all(alloc.window._ref[pg] == 1 for pg in held)
+        assert all(alloc._ref[int(pg)] == 2 for pg in alloc.tables[1, :12])
+        # fresh pages from the block the first decode query (at 59) reads
+        assert self._mapped(alloc, 1) == list(range(8, 16))
+        assert alloc.window.stats()["paged_window_hits_refused"] == 0
+        alloc.check_invariants()
+        alloc.free_slot(0)
+        alloc.free_slot(1)
+        alloc.check_invariants()
+        assert all(alloc.window._ref[pg] == 0 for pg in held)
+
+    def _evict(self, alloc, prompt, block):
+        """Drop the window page of `prompt`'s `block` as pressure would."""
+        win = alloc.window
+        key = alloc._walk_keys(
+            2, np.asarray(prompt, np.int32), 0, block + 1)[block]
+        alloc._walk.pop(2, None)
+        page = win._index.pop(key)
+        del win._key_of[page], win._stamp[page]
+        win._free.append(page)
+        alloc.check_invariants()
+
+    def test_a_hit_is_refused_where_a_window_page_was_evicted(self):
+        """The full layers' pages of the whole prompt are resident, one
+        window page a query at the hit's end reads is not: the hit is
+        cut back to the longest prefix whose window pages are all
+        there, or dropped, and counted."""
+        alloc = self._alloc()
+        short = list(range(100, 117))  # 17 tokens: blocks 0..3, all stored
+        alloc.admit(0, short, need_len=30)
+        alloc.register(0, short)
+        alloc.free_slot(0)
+        self._evict(alloc, short, 3)
+        adm = alloc.admit(1, short + [7, 8, 9], need_len=40)
+        # a query at 16 would read blocks 0..3; at 12 blocks 0..2 are there
+        assert (adm.pages_shared, adm.scan_start) == (3, 12)
+        assert alloc.window.stats()["paged_window_hits_refused"] == 1
+        assert alloc.hits == 1
+        alloc.free_slot(1)
+        # a long cold prompt stores its live tail alone (blocks 8..11 of
+        # 12): with block 10 gone no hit length has its window whole
+        prompt = list(range(300, 349))
+        alloc.admit(0, prompt, need_len=60)
+        alloc.register(0, prompt)
+        alloc.free_slot(0)
+        self._evict(alloc, prompt, 10)
+        adm = alloc.admit(1, prompt + [7, 8, 9], need_len=70)
+        assert (adm.pages_shared, adm.scan_start) == (0, 0)
+        assert alloc.window.stats()["paged_window_hits_refused"] == 2
+        # the full layers' pages were all there: without the window rule
+        # this would have been a hit of 12 pages
+        assert len(alloc.chain_pages(prompt)) == 12
+        alloc.check_invariants()
+
+    def test_a_hit_that_does_not_fit_a_rows_share_goes_cold(self):
+        """A hit and its suffix would hold more window pages than a
+        row's share of the arena (4 shared + 6 fresh > 9): refused, the
+        row is admitted cold on its live tail, which is what keeps every
+        later mapping ahead free or evictable."""
+        alloc = self._alloc(per_slot=9)
+        head = list(range(100, 140))  # 10 pages
+        alloc.admit(0, head + [1], need_len=50)
+        alloc.register(0, head + [1])
+        alloc.free_slot(0)
+        adm = alloc.admit(1, head + list(range(200, 218)), need_len=70)
+        assert adm.pages_shared == 0
+        assert alloc.window.stats()["paged_window_hits_refused"] == 1
+        assert len(self._mapped(alloc, 1)) == 6
+        alloc.free_slot(1)
+        # two tokens fewer and it fits: 4 shared + 5 fresh
+        adm = alloc.admit(1, head + list(range(200, 216)), need_len=70)
+        assert adm.pages_shared == 10 and len(self._mapped(alloc, 1)) == 9
+        alloc.check_invariants()
+
+    def test_exhaustion_is_all_or_nothing_for_both_kinds(self):
+        alloc = self._alloc(n_pages_w=6, slots=2)
+        alloc.admit(0, list(range(100, 150)), need_len=60)  # 6 window pages
+        before = alloc.tables.copy(), alloc.window.tables.copy()
+        with pytest.raises(PageExhaustedError, match="window page pool"):
+            alloc.admit(1, list(range(300, 330)), need_len=40)
+        np.testing.assert_array_equal(alloc.tables, before[0])
+        np.testing.assert_array_equal(alloc.window.tables, before[1])
+        alloc.check_invariants()
+        alloc.reset()
+        alloc.check_invariants()
+        assert alloc.window.in_use() == 0
+
+    @pytest.mark.parametrize("retain, refused", [(-1, False), (0, True)])
+    def test_the_cells_schedule_on_the_host_alone(self, retain, refused):
+        """scripts/window_pages_sim.py: the allocator under `mixed-ctx`
+        at the cell's sizes (32 slots, a window arena of 292 pages a
+        slot), no device. With the tail kept (`retain` = the chunk) no
+        follow-up turn finds its window pages gone and only sessions'
+        first turns are cold; without it some do, and re-run a whole
+        document. The books balance every 50 ticks either way."""
+        import json
+        import os
+        import subprocess
+        import sys
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        out = subprocess.run(
+            [sys.executable, os.path.join(root, "scripts",
+                                          "window_pages_sim.py"),
+             "--config", os.path.join(
+                 root, "benchmark", "configs",
+                 "smallthinker-21b-a3b-bf16-1chip.json"),
+             "--ticks", "1500", "--retain", str(retain)],
+            capture_output=True, text=True, timeout=300, check=True)
+        got = json.loads(out.stdout.strip().splitlines()[-1])
+        assert got["window_pages_a_slot"] == 292
+        assert (got["window_hits_refused"] > 0) == refused
+        assert got["calls"] > 2500 and got["window_pages_used_share_mean"] > 0.9
+        if not refused:
+            # 32 first turns, then a new session every 16 calls a client,
+            # three of four on a long document
+            assert got["cold_long_admissions"] <= 24 + got["calls"] * 3 // 64 + 8
+            assert got["pages_reused_share"] > 0.85
+
+    def test_no_window_means_no_window_work(self):
+        alloc = PageAllocator(16, 4, slots=2, table_width=8)
+        assert alloc.window is None
+        stats = alloc.stats()
+        assert {k: v for k, v in stats.items() if "window" in k} == {
+            "kv_window_pages_total": 0, "kv_window_pages_in_use": 0,
+            "paged_window_pages_freed": 0, "paged_window_hits_refused": 0,
+            "paged_window_pages_mapped": 0}
 
 
 class TestPagedBitIdentity:
